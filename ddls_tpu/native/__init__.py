@@ -8,9 +8,13 @@ engine first — cluster.py:_run_lookahead) implemented in C++ with flat
 array interfaces, loaded via ctypes (no pybind11 in the image).
 
 The library is compiled lazily with g++ on first use and cached under
-``_build/``; every entry point degrades gracefully (returns None /
+``_build/`` in a file named by the content hash of ``engine.cpp`` plus
+the compile flags (``build_key``): a library built from another
+commit's source, or with other flags, has another name and is never
+loaded. Every entry point degrades (returns None /
 ``native_available() is False``) when no toolchain is present, so the
-Python engines remain the source of truth and the fallback.
+Python engines remain the source of truth and the fallback — with a
+warning, because that fallback is ~50x slower.
 
 Contract: kernels are bit-exact with the host engines (f64, identical
 operation order) — golden stats tests must pass unchanged with the native
@@ -19,9 +23,11 @@ path enabled.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
+import warnings
 from typing import Optional, Tuple
 
 import numpy as np
@@ -29,7 +35,7 @@ import numpy as np
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "engine.cpp")
 _BUILD_DIR = os.path.join(_DIR, "_build")
-_LIB = os.path.join(_BUILD_DIR, "libddls_native.so")
+_CXX_FLAGS = ("-O2", "-std=c++17", "-shared", "-fPIC")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -40,23 +46,43 @@ _i32 = np.ctypeslib.ndpointer(dtype=np.int32, flags="C_CONTIGUOUS")
 _u8 = np.ctypeslib.ndpointer(dtype=np.uint8, flags="C_CONTIGUOUS")
 
 
-def _compile() -> bool:
+def build_key() -> str:
+    """Content hash of the kernel source plus the compile flags — the
+    artefact's identity. File mtimes say nothing once a tree has been
+    copied, so staleness is decided by content alone."""
+    digest = hashlib.sha256(" ".join(_CXX_FLAGS).encode())
+    with open(_SRC, "rb") as f:
+        digest.update(f.read())
+    return digest.hexdigest()[:16]
+
+
+def lib_path() -> str:
+    return os.path.join(_BUILD_DIR, f"libddls_native.{build_key()}.so")
+
+
+def _warn_fallback(why: str) -> None:
+    warnings.warn(f"native engine unavailable ({why}); falling back to "
+                  "the ~50x slower Python engines")
+
+
+def _compile(lib: str) -> bool:
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    if (os.path.exists(_LIB)
-            and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC)):
+    if os.path.exists(lib):
         return True
     # per-pid temp + atomic replace: concurrent first-use across processes
     # (parallel env workers, multi-host tests) must not interleave output
-    tmp = f"{_LIB}.tmp.{os.getpid()}"
-    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", _SRC, "-o", tmp]
+    tmp = f"{lib}.tmp.{os.getpid()}"
+    cmd = ["g++", *_CXX_FLAGS, _SRC, "-o", tmp]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,
                               timeout=120)
         if proc.returncode != 0:
+            _warn_fallback("g++ failed: " + proc.stderr.strip()[-500:])
             return False
-        os.replace(tmp, _LIB)
+        os.replace(tmp, lib)
         return True
-    except (OSError, subprocess.TimeoutExpired):
+    except (OSError, subprocess.TimeoutExpired) as err:
+        _warn_fallback(f"build did not run: {err!r}")
         return False
     finally:
         if os.path.exists(tmp):
@@ -100,11 +126,13 @@ def get_lib() -> Optional[ctypes.CDLL]:
         if _lib is not None or _load_failed:
             return _lib
         try:
-            if _compile():
-                _lib = _bind(ctypes.CDLL(_LIB))
+            lib = lib_path()
+            if _compile(lib):
+                _lib = _bind(ctypes.CDLL(lib))
             else:
                 _load_failed = True
-        except OSError:
+        except OSError as err:
+            _warn_fallback(f"load failed: {err!r}")
             _load_failed = True
     return _lib
 

@@ -134,8 +134,12 @@ class SebulbaCollector:
         batch_time = NamedSharding(actor_mesh, P(None, "dp"))
         batch_only = self._lane
         self.banks = jax.device_put(banks, self._lane)
-        self._state = jax.vmap(
-            lambda b: segment_init(et, b, self.memo_cfg))(self.banks)
+        # placed on the lane sharding: the same jit cache key as the
+        # state each collect returns (jax keys on the mesh an input's
+        # sharding names — rl/fused.py)
+        self._state = jax.device_put(jax.vmap(
+            lambda b: segment_init(et, b, self.memo_cfg))(self.banks),
+            self._lane)
         self._ep_len = np.zeros(B, np.int64)
 
         segment = make_segment_fn(et, ot, model, T, trace_obs=True,

@@ -164,7 +164,7 @@ class DepArrays:
     dense channels the job rides; ``pri`` the SRPT priorities (filled by
     the scheduler). One payload replaces the per-dep dict chain
     placer -> DepPlacement views -> schedule dicts -> channel mounts
-    (docs/round3_notes.md item 2: "dep placement -> schedule -> mount over
+    ("dep placement -> schedule -> mount over
     int arrays, Python dict mirrors as lazy views")."""
 
     __slots__ = ("edge_ids", "chan", "channels", "pri")

@@ -18,8 +18,8 @@ Usage::
     with telemetry.span("train.collect"):
         ...
     telemetry.inc("sim.lookahead_cache.hit")
-    telemetry.record_event("tpu_probe", phase="timeout",
-                           wedge_suspected=True)
+    telemetry.record_event("serve_degraded", bucket_idx=1,
+                           batch_fill=4)
     print(telemetry.snapshot())                  # JSON-friendly rollup
 
 Opt-in ``jax.profiler`` capture: ``enable(jax_trace_dir=...,

@@ -5,7 +5,7 @@ Three record types land here (all stamped with a wall-clock ``ts``):
 * ``{"type": "span", "name": ..., "dur_s": ...}`` — one per completed
   span (written by ``Registry._record_span``);
 * ``{"type": "event", "kind": ..., ...fields}`` — discrete occurrences
-  (TPU probe outcomes, degraded-mode transitions);
+  (degraded-mode transitions, ring segment lifecycles);
 * ``{"type": "snapshot", "data": {...}}`` — a full registry dump
   (``Registry.dump_snapshot``), the record ``scripts/telemetry_report.py``
   reads counters/histograms from.

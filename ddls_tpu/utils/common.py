@@ -240,33 +240,3 @@ def recursive_update(base: dict, overrides: Mapping[str, Any]) -> dict:
         else:
             base[key] = val
     return base
-
-
-def _pid_is_dead(pid: int) -> bool:
-    """True only when ``pid`` provably no longer exists (a
-    PermissionError means it exists under another uid — alive)."""
-    import os
-
-    try:
-        os.kill(pid, 0)
-    except ProcessLookupError:
-        return True
-    except (PermissionError, OSError):
-        return False
-    return False
-
-
-def lock_is_stale(path: str) -> bool:
-    """A ``.probe/tpu.lock`` whose recorded owner pid is provably dead
-    is stale (a hard-killed run cannot unlink its own lock; pid
-    liveness is the crash fallback — rl/fused.py ``chip_lock``). A lock
-    with NO parseable pid — e.g. written by an external wrapper — is
-    conservatively treated as live. Lives here rather than in rl/fused
-    because bench.py's probe consult must stay jax-import-free (the
-    CPU-fallback decision happens before jax is touched)."""
-    try:
-        with open(path) as f:
-            pid = int(f.read().strip())
-    except (OSError, ValueError):
-        return False
-    return _pid_is_dead(pid)

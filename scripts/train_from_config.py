@@ -17,8 +17,10 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import sys
+from typing import Optional
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -26,6 +28,10 @@ from ddls_tpu.config import load_config, save_config
 from ddls_tpu.train.compat import apply_reference_compat
 from ddls_tpu.train import Checkpointer, Launcher, Logger, make_epoch_loop
 from ddls_tpu.utils.common import seed_everything, unique_experiment_dir
+from ddls_tpu.utils.runtime import configure_compile_cache
+
+DEFAULT_CONFIG_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                   "ramp_job_partitioning_configs")
 
 
 def build_epoch_loop_kwargs(cfg: dict) -> dict:
@@ -60,18 +66,21 @@ def build_epoch_loop_kwargs(cfg: dict) -> dict:
     return kwargs
 
 
-def main(argv=None) -> int:
-    parser = argparse.ArgumentParser()
-    parser.add_argument("--config-path",
-                        default=os.path.join(os.path.dirname(__file__),
-                                             "ramp_job_partitioning_configs"))
-    parser.add_argument("--config-name", default="rllib_config")
-    parser.add_argument("overrides", nargs="*",
-                        help="dotted-path overrides, e.g. launcher.num_epochs=3")
-    args = parser.parse_args(argv)
+@dataclasses.dataclass
+class TrainRun:
+    """Everything one training run owns, built from a composed config."""
+    epoch_loop: object
+    launcher: Launcher
+    logger: Optional[Logger]
+    checkpointer: Optional[Checkpointer]
+    save_dir: Optional[str]
+    primary: bool
 
-    cfg = load_config(args.config_path, args.config_name, args.overrides)
-    apply_reference_compat(cfg)
+
+def build_run(cfg: dict) -> TrainRun:
+    """Seed, create the save dir, and build epoch loop + Launcher +
+    Logger + Checkpointer from a composed (and compat-applied) config.
+    The caller owns ``run.epoch_loop.close()``."""
     experiment = cfg.get("experiment", {})
 
     # XLA dump must be requested before the first backend init (SURVEY
@@ -126,25 +135,46 @@ def main(argv=None) -> int:
           f"{epoch_loop.rollout_length} steps on mesh "
           f"{dict(epoch_loop.mesh.shape)}")
 
-    launcher = Launcher(epoch_loop=epoch_loop, **cfg.get("launcher", {}))
-    logger = (Logger(path_to_save=save_dir, **cfg.get("logger", {}))
-              if primary else None)
-    checkpointer = (Checkpointer(path_to_save=save_dir,
-                                 **cfg.get("checkpointer", {}))
-                    if primary else None)
+    return TrainRun(
+        epoch_loop=epoch_loop,
+        launcher=Launcher(epoch_loop=epoch_loop,
+                          **cfg.get("launcher", {})),
+        logger=(Logger(path_to_save=save_dir, **cfg.get("logger", {}))
+                if primary else None),
+        checkpointer=(Checkpointer(path_to_save=save_dir,
+                                   **cfg.get("checkpointer", {}))
+                      if primary else None),
+        save_dir=save_dir, primary=primary)
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser()
+    parser.add_argument("--config-path", default=DEFAULT_CONFIG_PATH)
+    parser.add_argument("--config-name", default="rllib_config")
+    parser.add_argument("overrides", nargs="*",
+                        help="dotted-path overrides, e.g. launcher.num_epochs=3")
+    args = parser.parse_args(argv)
+
+    configure_compile_cache()
+    cfg = load_config(args.config_path, args.config_name, args.overrides)
+    apply_reference_compat(cfg)
+    run = build_run(cfg)
 
     from ddls_tpu.utils.profiling import jax_profiler_trace
 
-    jax_trace_dir = (os.path.join(save_dir, "jax_trace")
-                     if (primary and experiment.get("profile_jax")) else None)
+    jax_trace_dir = (os.path.join(run.save_dir, "jax_trace")
+                     if (run.primary and (cfg.get("experiment")
+                                          or {}).get("profile_jax"))
+                     else None)
     with jax_profiler_trace(jax_trace_dir):
-        summary = launcher.run(logger=logger, checkpointer=checkpointer)
+        summary = run.launcher.run(logger=run.logger,
+                                   checkpointer=run.checkpointer)
     if jax_trace_dir:
         print(f"Saved jax profiler trace under {jax_trace_dir}")
-    if primary:
+    if run.primary:
         print(f"Best checkpoint: {summary['best_checkpoint']} "
-              f"({epoch_loop.metric}={summary['best_metric_value']})")
-    epoch_loop.close()
+              f"({run.epoch_loop.metric}={summary['best_metric_value']})")
+    run.epoch_loop.close()
     return 0
 
 

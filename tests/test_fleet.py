@@ -592,7 +592,7 @@ def test_bench_serve_trace_fleet_smoke(capsys):
     rc = bench.main(["--mode", "serve", "--load", "trace",
                      "--replicas", "2", "--serve-requests", "48",
                      "--serve-rps", "400", "--serve-max-batch", "4",
-                     "--slo-ms", "100", "--probe-timeout", "120"])
+                     "--slo-ms", "100"])
     line = capsys.readouterr().out.strip().splitlines()[-1]
     payload = json.loads(line)
     assert rc == 0, payload
@@ -643,8 +643,7 @@ def test_bench_serve_poisson_records_reproducibility_triplet(capsys):
     import bench
 
     rc = bench.main(["--mode", "serve", "--serve-requests", "24",
-                     "--serve-rps", "400", "--serve-max-batch", "4",
-                     "--probe-timeout", "120"])
+                     "--serve-rps", "400", "--serve-max-batch", "4"])
     line = capsys.readouterr().out.strip().splitlines()[-1]
     payload = json.loads(line)
     assert rc == 0, payload

@@ -5,8 +5,7 @@ trace capture + JSONL round trip through ``scripts/trace_export.py`` and
 ``scripts/telemetry_report.py``, cross-backend diffing (seeded host vs
 C++ identical; a deliberately perturbed backend pinpointed at its first
 divergent event), the worker-process trace merge over the rollout close
-ack, the ``scripts/check_flight_gated.py`` tier-1 guard, and the bench
-probe wedge-state cache satellite."""
+ack, and the ``scripts/check_flight_gated.py`` tier-1 guard."""
 import json
 import os
 import subprocess
@@ -392,54 +391,3 @@ def test_check_flight_gated_flags_violations(tmp_path):
     assert "hot_module.py:6" in out.stdout
     assert "hot_module.py:5" not in out.stdout
     assert "enabled" in out.stdout  # the fix pointer
-
-
-# ------------------------------------- bench probe wedge-state cache
-def test_probe_cache_skips_on_recorded_wedge(tmp_path, monkeypatch):
-    import time
-
-    import bench
-
-    probe_dir = str(tmp_path / ".probe")
-    bench.record_probe_state("timeout", error="init timed out after "
-                                              "240s", probe_dir=probe_dir)
-    monkeypatch.setattr(bench, "probe_backend",
-                        lambda *a, **k: pytest.fail(
-                            "probe subprocess ran despite recorded "
-                            "wedge"))
-    err, reason = bench.probe_backend_cached(240.0, probe_dir=probe_dir)
-    assert reason == "recent_probe_timeout"
-    assert err is not None and "timed out" in err
-    # stale state probes normally again
-    state_path = os.path.join(probe_dir, bench.PROBE_STATE_FILE)
-    state = json.load(open(state_path))
-    state["ts"] = time.time() - 10 * bench.PROBE_STATE_TTL_S
-    json.dump(state, open(state_path, "w"))
-    monkeypatch.setattr(bench, "probe_backend", lambda *a, **k: None)
-    err, reason = bench.probe_backend_cached(240.0, probe_dir=probe_dir)
-    assert (err, reason) == (None, None)
-    # ... and the fresh success was recorded without enabling a skip
-    assert json.load(open(state_path))["outcome"] == "success"
-    err, reason = bench.probe_backend_cached(240.0, probe_dir=probe_dir)
-    assert (err, reason) == (None, None)
-
-
-def test_probe_cache_respects_tpu_lock(tmp_path, monkeypatch):
-    import bench
-
-    probe_dir = tmp_path / ".probe"
-    probe_dir.mkdir()
-    (probe_dir / "tpu.lock").touch()
-    monkeypatch.setattr(bench, "probe_backend",
-                        lambda *a, **k: pytest.fail(
-                            "probed while another owner holds the "
-                            "chip lock"))
-    err, reason = bench.probe_backend_cached(240.0,
-                                             probe_dir=str(probe_dir))
-    assert reason == "tpu_lock_held"
-    assert "tpu.lock" in err
-    # ttl 0 disables every skip path (--probe-ttl 0)
-    monkeypatch.setattr(bench, "probe_backend", lambda *a, **k: None)
-    err, reason = bench.probe_backend_cached(240.0, ttl_s=0,
-                                             probe_dir=str(probe_dir))
-    assert (err, reason) == (None, None)

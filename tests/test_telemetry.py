@@ -304,30 +304,6 @@ def test_fleet_serving_burst_disabled_guard(monkeypatch):
     assert dict(router.registry.counter_items())["fleet.requests"] == 24
 
 
-# ------------------------------------------------------------- probe events
-def test_probe_outcomes_recorded():
-    import bench
-
-    telemetry.enable()
-    err = bench.probe_backend(timeout=120, force_cpu=True)
-    assert err is None
-    c = telemetry.snapshot()["counters"]
-    assert c["event.tpu_probe.attempt"] == 1
-    assert c["event.tpu_probe.success"] == 1
-    assert "tpu.probe" in telemetry.span_summaries()
-
-
-def test_probe_timeout_marks_wedge_suspected():
-    import bench
-
-    telemetry.enable()
-    err = bench.probe_backend(timeout=0.001, force_cpu=True)
-    assert err is not None and "timed out" in err
-    c = telemetry.snapshot()["counters"]
-    assert c["event.tpu_probe.timeout"] == 1
-    assert c.get("event.tpu_probe.success") is None
-
-
 # ------------------------------------------------------- jax profiler hook
 def test_jax_trace_hook_wraps_configured_span(monkeypatch, tmp_path):
     import jax
