@@ -47,6 +47,9 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ddls_tpu import telemetry
+from ddls_tpu.telemetry import scopes, startup
+
 AUTOTUNE_CACHE_FILE = "fused_autotune.json"
 
 # -------------------------------------------------------------------------
@@ -371,11 +374,41 @@ def stacked_job_banks(et, env, n_lanes: int, n_jobs: int,
             for k in banks[0]}
 
 
-#: the compact episode-counter trace keys the fused program returns per
-#: decision step (the rest of the segment trace — obs fields, actions —
-#: stays INSIDE the program; only these [U, B, T] scalars ever leave)
+#: the compact trace keys the fused program returns per decision step
+#: (the rest of the segment trace — obs fields, actions — stays INSIDE
+#: the program; only these [U, B, T] scalars ever leave): the episode
+#: counters ``harvest_episodes`` reads, and the lookahead trip count
+#: (``make_segment_fn(trace_trips=True)``) that
+#: ``record_lookahead_trips`` reads while telemetry is on
 EPISODE_TRACE_KEYS = ("done", "ep_return", "ep_blocked", "ep_completed",
-                      "ep_arrived")
+                      "ep_arrived", "la_trips")
+
+#: ``sim.lookahead.trips_per_call`` buckets: the loop is bounded by
+#: ops + deps + 4 trips (13,556 at the degree-16 pads)
+_TRIP_BUCKETS = tuple(2.0 ** i for i in range(15))
+
+
+def record_lookahead_trips(ep_trace) -> None:
+    """Reduce a FETCHED ``[..., B, T]`` lookahead trip trace
+    (``la_trips``: each lane-step's own loop count) into the
+    ``sim.lookahead.*`` telemetry counters: ``calls`` — lane-steps whose
+    lookahead ran at least one trip (a memo hit and an action that runs
+    no lookahead run none); ``trips`` — their trips, summed;
+    ``lockstep_trips`` — summed over steps, the maximum over the lanes:
+    what the batched loop executed, since it runs while any lane's cond
+    holds and every lane that loops carries its count out;
+    ``lockstep_lane_trips`` — that times the lanes: the lane-trips the
+    device paid for. The caller gates on ``telemetry.enabled()``."""
+    own = np.asarray(ep_trace["la_trips"])
+    lockstep = int(own.max(axis=-2).sum())
+    telemetry.inc("sim.lookahead.calls", int((own > 0).sum()))
+    telemetry.inc("sim.lookahead.trips", int(own.sum()))
+    telemetry.inc("sim.lookahead.lockstep_trips", lockstep)
+    telemetry.inc("sim.lookahead.lockstep_lane_trips",
+                  lockstep * own.shape[-2])
+    for trips in own[own > 0].tolist():
+        telemetry.observe("sim.lookahead.trips_per_call", trips,
+                          buckets=_TRIP_BUCKETS)
 
 
 class FusedEpochDriver:
@@ -432,7 +465,8 @@ class FusedEpochDriver:
         # _kernel_obs values either way, so parity with the sequential
         # rebuild-from-fields path is unchanged
         segment = make_segment_fn(et, ot, model, T, trace_obs=True,
-                                  memo_cfg=self.memo_cfg)
+                                  memo_cfg=self.memo_cfg,
+                                  trace_trips=True)
         # one-lane fast path shared with DevicePPOCollector (a 1-wide
         # vmap halves the kernel's XLA:CPU throughput)
         lane_segment = vmap_segment_fn(segment, self.num_lanes)
@@ -464,6 +498,7 @@ class FusedEpochDriver:
             # them (the rng keys likewise, in fused_epoch).
             self._state = jax.device_put(self._state, lane)
         self._ep_len = np.zeros(B, np.int64)
+        self._first_call = True
 
         def obs_from_fields(jtype, frac, steps, n_occ, n_run):
             return _kernel_obs(ot, et, jtype, frac, steps, n_occ, n_run)
@@ -500,8 +535,9 @@ class FusedEpochDriver:
                 next_fields["jtype"], next_fields["frac"],
                 next_fields["steps"], next_fields["n_occupied"],
                 next_fields["n_running"])
-            _, last_values = batched_policy_apply(model, state.params,
-                                                  next_obs)
+            with jax.named_scope(scopes.POLICY_FORWARD):
+                _, last_values = batched_policy_apply(model, state.params,
+                                                      next_obs)
             last_values = last_values.astype(jnp.float32)
             if mesh is not None:
                 # pin the staged batch to the standalone train_step's
@@ -569,8 +605,28 @@ class FusedEpochDriver:
             # same jit cache key on every call (see __init__); a no-op
             # for the advanced keys a previous call returned
             crng, urng = jax.device_put((crng, urng), self._repl)
+        if self._first_call:
+            self._first_call = False
+            return self._first_epoch(state, crng, urng)
         (state, self._state, crng, urng, metrics,
          ep) = self._jit_epoch(state, self._state, crng, urng)
+        return state, (crng, urng), metrics, ep
+
+    def _first_epoch(self, state, crng, urng):
+        """The first call, under ``startup.first_epoch``: tracing,
+        lowering and compiling (or loading) the epoch program — jax's
+        own durations land beside the span as ``startup.jax.*`` — then
+        dispatch and the first execution, waited for here so that the
+        span holds all of it. One wait in a run's life; every later
+        call stays an asynchronous dispatch. Start-up ends here, so the
+        start-up registry is printed here, once."""
+        import jax
+
+        with startup.span("startup.first_epoch"):
+            (state, self._state, crng, urng, metrics,
+             ep) = self._jit_epoch(state, self._state, crng, urng)
+            jax.block_until_ready((state, ep))
+        print(startup.report(), flush=True)
         return state, (crng, urng), metrics, ep
 
     def memo_counters(self) -> Optional[Dict]:

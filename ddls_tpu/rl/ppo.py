@@ -31,6 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ddls_tpu.parallel.mesh import (place_state_tree,
                                     replicated_sharding, shard_batch)
+from ddls_tpu.telemetry import scopes
 
 
 def traj_donate_argnums(state_argnum: int, *traj_argnums: int):
@@ -284,6 +285,7 @@ class PPOLearner:
                               step=state.step + 1)
         return state, metrics
 
+    @jax.named_scope(scopes.PPO_UPDATE)
     def _train_step(self, state: TrainState, traj: Dict[str, jnp.ndarray],
                     last_values: jnp.ndarray, rng: jnp.ndarray):
         """One PPO update on a [T, B] trajectory batch.

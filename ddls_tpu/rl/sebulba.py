@@ -49,6 +49,7 @@ import numpy as np
 from ddls_tpu import telemetry
 from ddls_tpu.rl.fused import EPISODE_TRACE_KEYS
 from ddls_tpu.rl.ring import TrajRing
+from ddls_tpu.telemetry import scopes
 
 
 def split_meshes(actor_devices: Optional[int] = None, devices=None):
@@ -143,7 +144,8 @@ class SebulbaCollector:
         self._ep_len = np.zeros(B, np.int64)
 
         segment = make_segment_fn(et, ot, model, T, trace_obs=True,
-                                  memo_cfg=self.memo_cfg)
+                                  memo_cfg=self.memo_cfg,
+                                  trace_trips=True)
         lane_segment = vmap_segment_fn(segment, B)
 
         def actor_round(bb, params, sim_state, lane_rngs):
@@ -174,7 +176,9 @@ class SebulbaCollector:
                 next_fields["jtype"], next_fields["frac"],
                 next_fields["steps"], next_fields["n_occupied"],
                 next_fields["n_running"])
-            _, last_values = batched_policy_apply(model, params, next_obs)
+            with jax.named_scope(scopes.POLICY_FORWARD):
+                _, last_values = batched_policy_apply(model, params,
+                                                      next_obs)
             last_values = jax.lax.with_sharding_constraint(
                 last_values.astype(jnp.float32), batch_only)
             ep = {k: trace[k] for k in EPISODE_TRACE_KEYS}

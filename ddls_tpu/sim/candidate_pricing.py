@@ -171,7 +171,8 @@ def _evaluate(cluster, pending, backend: str):
     fn = batched_lookahead_fn(num_workers, num_channels)
     stacked = [np.stack(parts) for parts in
                zip(*(arrays_as_args(a) for a in batch))]
-    t, comm, comp, busy, ok = (np.asarray(x) for x in fn(*stacked))
+    t, comm, comp, busy, ok, _trips = (np.asarray(x)
+                                       for x in fn(*stacked))
     return [((float(t[i]), float(comm[i]), float(comp[i]), float(busy[i]))
              if bool(ok[i]) else None)
             for i in range(len(pending))]

@@ -610,7 +610,7 @@ class RampClusterEnvironment:
             # crash as loudly as they would on the host path
             return None
         fn = lookahead_fn(arrays.num_workers, arrays.num_channels)
-        t, comm, comp, busy, ok = (float(x) for x in fn(
+        t, comm, comp, busy, ok, _trips = (float(x) for x in fn(
             *arrays_as_args(arrays)))
         if not ok:
             raise RuntimeError(

@@ -55,7 +55,9 @@ from ddls_tpu.agents.block_search import block_shapes_for, factor_pairs
 from ddls_tpu.agents.partitioners import build_partition_action
 from ddls_tpu.graphs.readers import backward_op_id
 from ddls_tpu.sim import jax_memo
+from ddls_tpu.sim.jax_lookahead import jax_lookahead
 from ddls_tpu.sim.partition import partition_graph, partitioned_op_id
+from ddls_tpu.telemetry import scopes
 
 #: episode-kernel default: the in-kernel lookahead memo (sim/jax_memo.py)
 #: is ON for the episode builders at EVERY lane count — memoised and
@@ -1020,7 +1022,7 @@ def _episode_kernels(et: EpisodeTables):
             return inflate_duration_jax(t, jct, r0, sc_t0, sc_t1,
                                         sc_rate, affects)
 
-    def eval_cfg(bank, carry, row, cfg, memo=None):
+    def eval_cfg(bank, carry, row, cfg, memo=None, discard=None):
         """Evaluate ONE (job, degree) candidate against the live cluster
         state: placement, dep pricing, channel check, lookahead, SLA —
         everything a decision needs, minus the commit. XLA dead-code
@@ -1030,28 +1032,36 @@ def _episode_kernels(et: EpisodeTables):
         probed under the host memo-key signature (cfg row, canonical
         worker grouping, mounted dep times) and served from the table on
         a bitwise full-key hit — memoised and recomputed results are
-        bit-identical by construction, any precision mode."""
+        bit-identical by construction, any precision mode. ``discard``
+        (bool) marks a lane whose result the caller will throw away: it
+        joins the memo's hit mask in the lookahead's ``skip``, so under
+        ``vmap`` such a lane runs no trips (``ev["la_trips"]`` is the
+        loop's own count: 0 for a skipped lane)."""
         (t, mem, srv_job, chan_occ, slot_valid, slot_t_done, slot_mem,
          slot_servers, slot_chan) = carry
         dt = mem.dtype
         steps = bank["steps"][row].astype(dt)
         other_free = srv_job < 0
-        ots, new_mem, ok_place = jax_allocate_job(
-            mem, other_free, cfg, et.tables, st, pads)
-        times, is_flow, chan, op_score, dep_score, finite_ok = \
-            jax_price_and_score(ots, cfg, et.tables, st, pads,
-                                et.comm, et.pair_channel)
+        with jax.named_scope(scopes.SIM_ALLOCATE):
+            ots, new_mem, ok_place = jax_allocate_job(
+                mem, other_free, cfg, et.tables, st, pads)
+        with jax.named_scope(scopes.SIM_PRICE):
+            times, is_flow, chan, op_score, dep_score, finite_ok = \
+                jax_price_and_score(ots, cfg, et.tables, st, pads,
+                                    et.comm, et.pair_channel)
         occ_vals = chan_occ[jnp.clip(chan, 0)]
         ok_chan = jnp.all(~is_flow | (occ_vals < 0))
 
-        from ddls_tpu.sim.jax_lookahead import jax_lookahead
         op_valid = et.tables["op_valid"][cfg]
 
         def run_lookahead(skip=None):
             # ``skip`` is the memo probe's hit mask, threaded into the
             # lookahead while_loop cond (jax_memo.WIDE_PROBE_SURFACE) so
-            # hit lanes contribute zero trips to the batched loop
-            t_la, _, _, _, ok = jax_lookahead(
+            # hit lanes contribute zero trips to the batched loop; a
+            # lane the caller discards is masked out the same way
+            if discard is not None:
+                skip = discard if skip is None else skip | discard
+            t_la, _, _, _, ok, trips = jax_lookahead(
                 et.tables["op_compute"][cfg], op_valid,
                 jnp.where(op_valid, ots, -1), op_score,
                 et.tables["num_parents"][cfg], times,
@@ -1059,14 +1069,15 @@ def _episode_kernels(et: EpisodeTables):
                 et.tables["dep_dst"][cfg], et.tables["dep_mutual"][cfg],
                 is_flow, dep_score, chan[:, None],
                 num_workers=n_srv, num_channels=n_chan, skip=skip)
-            return t_la, ok
+            return t_la, ok, trips
 
         if memo is None:
-            t_step, ok_la = run_lookahead()
+            t_step, ok_la, trips = run_lookahead()
         else:
-            groups = jax_memo.canonical_groups(
-                jnp.where(op_valid, ots, -1), op_valid)
-            (t_step, ok_la), memo = jax_memo.memo_lookahead(
+            with jax.named_scope(scopes.SIM_MEMO_PROBE):
+                groups = jax_memo.canonical_groups(
+                    jnp.where(op_valid, ots, -1), op_valid)
+            (t_step, ok_la, trips), memo = jax_memo.memo_lookahead(
                 memo, cfg, groups, times, run_lookahead)
         jct = t_step * steps
         max_jct = (bank["sla_frac"][row].astype(dt)
@@ -1080,7 +1091,7 @@ def _episode_kernels(et: EpisodeTables):
         return {"ok_place": ok_place, "ok_chan": ok_chan,
                 "engine_ok": engine_ok, "sla_ok": sla_ok, "jct": jct,
                 "new_mem": new_mem, "srv_mask": srv_mask,
-                "chan_mask": chan_mask}, memo
+                "chan_mask": chan_mask, "la_trips": trips}, memo
 
     def price_all(bank, carry, row):
         """In-kernel candidate pricing: (placeable [n_deg], jct [n_deg])
@@ -1103,14 +1114,29 @@ def _episode_kernels(et: EpisodeTables):
                 ev["jct"])
 
     def decision(bank, carry, action, row, memo=None):
+        """Decide one queued job; returns ``(carry', (reward, accept,
+        cause, jct, la_trips), memo')``. ``la_trips`` (i32) is the
+        lookahead loop's own trip count for this decision: 0 on a memo
+        hit and on an action that runs no lookahead."""
         (t, mem, srv_job, chan_occ, slot_valid, slot_t_done, slot_mem,
          slot_servers, slot_chan) = carry
         dt = mem.dtype
         jtype = bank["type"][row]
         cfg = jtype * n_deg + deg_col[jnp.clip(action, 0)]
 
+        # actions outside the jitted degree set (odd > 1 — the host
+        # coerces masked-invalid actions to 0, partitioning_env.py:195)
+        # take the zero path instead of wrapping deg_col's -1 into
+        # another config row
+        action_ok = (action > 0) & (deg_col[jnp.clip(action, 0)] >= 0)
+
         def heavy(mm):
-            ev, mm = eval_cfg(bank, carry, row, cfg, mm)
+            # under vmap the cond below is a select and every lane runs
+            # this branch: a lane on the zero path is masked out of the
+            # lookahead loop, so the trips the batched loop executes are
+            # the maximum over lanes whose result is used
+            ev, mm = eval_cfg(bank, carry, row, cfg, mm,
+                              discard=~action_ok)
             accept = (ev["ok_place"] & ev["ok_chan"] & ev["sla_ok"]
                       & ev["engine_ok"])
             cause = jnp.where(
@@ -1120,19 +1146,15 @@ def _episode_kernels(et: EpisodeTables):
                                     jnp.where(~ev["sla_ok"], CAUSE_SLA,
                                               CAUSE_ACCEPTED))))
             return (accept, cause.astype(jnp.int32), ev["jct"],
-                    ev["new_mem"], ev["srv_mask"], ev["chan_mask"]), mm
+                    ev["new_mem"], ev["srv_mask"], ev["chan_mask"],
+                    ev["la_trips"]), mm
 
         def zero(mm):
             return (jnp.bool_(False), jnp.int32(CAUSE_NOT_HANDLED),
                     jnp.zeros((), dt), mem, jnp.zeros((n_srv,), bool),
-                    jnp.zeros((n_chan,), bool)), mm
+                    jnp.zeros((n_chan,), bool), jnp.int32(0)), mm
 
-        # actions outside the jitted degree set (odd > 1 — the host
-        # coerces masked-invalid actions to 0, partitioning_env.py:195)
-        # take the zero path instead of wrapping deg_col's -1 into
-        # another config row
-        action_ok = (action > 0) & (deg_col[jnp.clip(action, 0)] >= 0)
-        ((accept, cause, jct, new_mem, srv_mask, chan_mask),
+        ((accept, cause, jct, new_mem, srv_mask, chan_mask, la_trips),
          memo) = jax.lax.cond(action_ok, heavy, zero, memo)
 
         if scenario is not None:
@@ -1160,7 +1182,7 @@ def _episode_kernels(et: EpisodeTables):
 
         return ((t, mem2, srv_job2, chan_occ2, slot_valid2, slot_t_done2,
                  slot_mem2, slot_servers2, slot_chan2),
-                (reward.astype(dt), accept, cause, jct), memo)
+                (reward.astype(dt), accept, cause, jct, la_trips), memo)
 
     def advance(bank, carry, queue_row, ptr, next_arrival, done,
                 completed):
@@ -1210,7 +1232,8 @@ def _episode_kernels(et: EpisodeTables):
                     queue_row2, ptr2, next_arrival2, done2, completed2)
 
         s = carry + (queue_row, ptr, next_arrival, done, completed)
-        s = jax.lax.while_loop(cond, body, s)
+        with jax.named_scope(scopes.SIM_ADVANCE):
+            s = jax.lax.while_loop(cond, body, s)
         return s[:9], s[9], s[10], s[11], s[12], s[13]
 
     def init_state(bank):
@@ -1234,7 +1257,7 @@ def _episode_kernels(et: EpisodeTables):
 
     return _types.SimpleNamespace(decision=decision, advance=advance,
                                   init_state=init_state,
-                                  price_all=price_all)
+                                  price_all=price_all, eval_cfg=eval_cfg)
 
 
 def make_episode_fn(et: EpisodeTables,
@@ -1274,7 +1297,7 @@ def make_episode_fn(et: EpisodeTables,
             has_job = (queue_row >= 0) & ~done
 
             def run(mm):
-                new_carry, (reward, accept, cause, jct), mm = decision(
+                new_carry, (reward, accept, cause, jct, _), mm = decision(
                     bank, carry, action, jnp.clip(queue_row, 0), mm)
                 return (new_carry, reward, accept, cause, jct), mm
 
@@ -1413,45 +1436,47 @@ def _kernel_obs(ot: dict, et: EpisodeTables, jtype, frac, steps,
     Dynamic entries are computed with the host's formulas (f64) and the
     whole feature vector is cast to f32 like the host encoder, so the
     policy sees bit-identical inputs."""
+    import jax
     import jax.numpy as jnp
 
-    def norm(val, lo, hi):
-        return jnp.where(hi - lo == 0, 1.0, (val - lo) / (hi - lo))
+    with jax.named_scope(scopes.ENV_OBS):
+        def norm(val, lo, hi):
+            return jnp.where(hi - lo == 0, 1.0, (val - lo) / (hi - lo))
 
-    gf = jnp.asarray(ot["graph_features"])[jtype].astype(jnp.float64)
-    seq_ct = jnp.asarray(ot["orig_seq_sum"])[jtype] * steps
-    max_jct = frac * seq_ct
-    gf = gf.at[2].set(norm(seq_ct, *ot["seq_bounds"]))
-    gf = gf.at[3].set(norm(max_jct, *ot["jct_bounds"]))
-    gf = gf.at[4].set(norm(frac, *ot["frac_bounds"]))
-    gf = gf.at[5].set(frac)
-    gf = gf.at[8].set(norm(steps, *ot["steps_bounds"]))
-    n_srv = et.n_srv
-    gf = gf.at[15].set(n_occupied / n_srv)
-    gf = gf.at[16].set(n_running / n_srv)
+        gf = jnp.asarray(ot["graph_features"])[jtype].astype(jnp.float64)
+        seq_ct = jnp.asarray(ot["orig_seq_sum"])[jtype] * steps
+        max_jct = frac * seq_ct
+        gf = gf.at[2].set(norm(seq_ct, *ot["seq_bounds"]))
+        gf = gf.at[3].set(norm(max_jct, *ot["jct_bounds"]))
+        gf = gf.at[4].set(norm(frac, *ot["frac_bounds"]))
+        gf = gf.at[5].set(frac)
+        gf = gf.at[8].set(norm(steps, *ot["steps_bounds"]))
+        n_srv = et.n_srv
+        gf = gf.at[15].set(n_occupied / n_srv)
+        gf = gf.at[16].set(n_running / n_srv)
 
-    mask = _kernel_action_mask(ot, et, n_occupied)
-    n_feat = jnp.asarray(ot["graph_features"]).shape[1]
-    gf17 = jnp.clip(gf[:n_feat - mask.shape[0]], 0.0, 1.0)
-    parts = [gf17, mask.astype(jnp.float64)]
-    if ot.get("with_prices"):
-        if price_feats is None:
-            raise ValueError("obs tables carry price features; pass "
-                             "price_feats (envs/obs.py:_price_features)")
-        parts.append(price_feats.astype(jnp.float64))
-    gf = jnp.concatenate(parts)
+        mask = _kernel_action_mask(ot, et, n_occupied)
+        n_feat = jnp.asarray(ot["graph_features"]).shape[1]
+        gf17 = jnp.clip(gf[:n_feat - mask.shape[0]], 0.0, 1.0)
+        parts = [gf17, mask.astype(jnp.float64)]
+        if ot.get("with_prices"):
+            if price_feats is None:
+                raise ValueError("obs tables carry price features; pass "
+                                 "price_feats (envs/obs.py:_price_features)")
+            parts.append(price_feats.astype(jnp.float64))
+        gf = jnp.concatenate(parts)
 
-    return {
-        "action_set": jnp.arange(et.max_action + 1, dtype=jnp.int32),
-        "node_features": jnp.asarray(ot["node_features"])[jtype],
-        "edge_features": jnp.asarray(ot["edge_features"])[jtype],
-        "edges_src": jnp.asarray(ot["edges_src"])[jtype],
-        "edges_dst": jnp.asarray(ot["edges_dst"])[jtype],
-        "node_split": jnp.asarray(ot["node_split"])[jtype],
-        "edge_split": jnp.asarray(ot["edge_split"])[jtype],
-        "graph_features": gf.astype(jnp.float32),
-        "action_mask": mask.astype(jnp.int32),
-    }
+        return {
+            "action_set": jnp.arange(et.max_action + 1, dtype=jnp.int32),
+            "node_features": jnp.asarray(ot["node_features"])[jtype],
+            "edge_features": jnp.asarray(ot["edge_features"])[jtype],
+            "edges_src": jnp.asarray(ot["edges_src"])[jtype],
+            "edges_dst": jnp.asarray(ot["edges_dst"])[jtype],
+            "node_split": jnp.asarray(ot["node_split"])[jtype],
+            "edge_split": jnp.asarray(ot["edge_split"])[jtype],
+            "graph_features": gf.astype(jnp.float32),
+            "action_mask": mask.astype(jnp.int32),
+        }
 
 
 def make_policy_episode_fn(et: EpisodeTables, ot: dict, model,
@@ -1516,15 +1541,16 @@ def make_policy_episode_fn(et: EpisodeTables, ot: dict, model,
                     bank["steps"][row].astype(jnp.float64),
                     (srv_job >= 0).sum(), slot_valid.sum(),
                     price_feats=price_feats)
-                logits, value = model.apply(params, obs)
+                with jax.named_scope(scopes.POLICY_FORWARD):
+                    logits, value = model.apply(params, obs)
                 if greedy:
                     action = jnp.argmax(logits).astype(jnp.int32)
                 else:
                     action = jax.random.categorical(
                         step_rng, logits).astype(jnp.int32)
                 logp = jax.nn.log_softmax(logits)[action]
-                new_carry, (reward, accept, cause, jct), mm = k.decision(
-                    bank, carry, action, row, mm)
+                new_carry, (reward, accept, cause, jct, _), mm = \
+                    k.decision(bank, carry, action, row, mm)
                 return (new_carry, action, logp, value, reward, accept,
                         cause, jct), mm
 
@@ -1595,7 +1621,8 @@ def segment_init(et: EpisodeTables, bank,
 
 def make_segment_fn(et: EpisodeTables, ot: dict, model, n_steps: int,
                     trace_obs: bool = False,
-                    memo_cfg: Optional[jax_memo.MemoConfig] = None):
+                    memo_cfg: Optional[jax_memo.MemoConfig] = None,
+                    trace_trips: bool = False):
     """(bank, params, sim_state, rng) -> (new_sim_state, trace, next_fields)
 
     Exactly ``n_steps`` policy decisions per call — the [T, B] segment
@@ -1635,6 +1662,13 @@ def make_segment_fn(et: EpisodeTables, ot: dict, model, n_steps: int,
     keep ``trace_obs=False`` — shipping full padded obs through the
     per-collect device->host fetch is precisely what the compact trace
     exists to avoid.
+
+    ``trace_trips=True`` adds ``la_trips`` (i32): the lookahead loop's
+    own trip count of each decision (0 on a memo hit and on an action
+    that runs no lookahead). A program counter, not a simulation result
+    — the collectors that drain it (``EPISODE_TRACE_KEYS``) ask for it;
+    the host reduces it into the ``sim.lookahead.*`` telemetry counters
+    (`rl/fused.py:record_lookahead_trips`).
     """
     import jax
     import jax.numpy as jnp
@@ -1652,11 +1686,12 @@ def make_segment_fn(et: EpisodeTables, ot: dict, model, n_steps: int,
         row = jnp.clip(queue_row, 0)
         srv_job = carry[2]
         slot_valid = carry[4]
-        return {"jtype": bank["type"][row],
-                "frac": bank["sla_frac"][row].astype(jnp.float64),
-                "steps": bank["steps"][row].astype(jnp.float64),
-                "n_occupied": (srv_job >= 0).sum().astype(jnp.int32),
-                "n_running": slot_valid.sum().astype(jnp.int32)}
+        with jax.named_scope(scopes.ENV_OBS):
+            return {"jtype": bank["type"][row],
+                    "frac": bank["sla_frac"][row].astype(jnp.float64),
+                    "steps": bank["steps"][row].astype(jnp.float64),
+                    "n_occupied": (srv_job >= 0).sum().astype(jnp.int32),
+                    "n_running": slot_valid.sum().astype(jnp.int32)}
 
     def segment(bank, params, sim_state, rng):
         dt = et.tables["dep_size"].dtype
@@ -1675,13 +1710,14 @@ def make_segment_fn(et: EpisodeTables, ot: dict, model, n_steps: int,
             obs = _kernel_obs(ot, et, fields["jtype"], fields["frac"],
                               fields["steps"], fields["n_occupied"],
                               fields["n_running"])
-            logits, value = model.apply(params, obs)
+            with jax.named_scope(scopes.POLICY_FORWARD):
+                logits, value = model.apply(params, obs)
             action = jax.random.categorical(step_rng,
                                             logits).astype(jnp.int32)
             logp = jax.nn.log_softmax(logits)[action]
 
-            new_carry, (reward, accept, cause, jct), memo = k.decision(
-                bank, carry, action, row, memo)
+            (new_carry, (reward, accept, cause, jct, la_trips),
+             memo) = k.decision(bank, carry, action, row, memo)
             accepted, blocked, ret = counters
             # unlike the policy-episode kernel these counters need no
             # has_job guard: every segment step has a queued job by
@@ -1724,6 +1760,8 @@ def make_segment_fn(et: EpisodeTables, ot: dict, model, n_steps: int,
                    **fields}
             if trace_obs:
                 out["obs"] = obs
+            if trace_trips:
+                out["la_trips"] = la_trips
             if memo is not None:
                 out.update(jax_memo.memo_trace_counters(memo))
             return (state4, memo), out
@@ -1865,8 +1903,8 @@ def make_oracle_episode_fn(et: EpisodeTables, ot: dict,
                     jnp.where(best_deg >= 0, best_deg, first_valid)
                 ).astype(jnp.int32)
 
-                new_carry, (reward, accept, cause, jct), mm = k.decision(
-                    bank, carry, action, row, mm)
+                new_carry, (reward, accept, cause, jct, _), mm = \
+                    k.decision(bank, carry, action, row, mm)
                 return (new_carry, action, reward, accept, cause,
                         jct), mm
 

@@ -36,6 +36,8 @@ from typing import Dict, Tuple
 
 import numpy as np
 
+from ddls_tpu.telemetry import scopes
+
 BIG = np.float32(3.4e38)  # stands in for +inf inside the kernel
 
 
@@ -314,7 +316,13 @@ def jax_lookahead(op_remaining, op_valid, op_worker, op_score, num_parents,
                   dep_remaining, dep_valid, dep_src, dep_dst, dep_mutual,
                   dep_is_flow, dep_score, dep_channel,
                   *, num_workers: int, num_channels: int, skip=None):
-    """One-training-step lookahead; returns (t, comm_oh, comp_oh, busy, ok).
+    """One-training-step lookahead; returns
+    (t, comm_oh, comp_oh, busy, ok, trips).
+
+    ``trips`` is the loop's own iteration count (i32): 0 for a
+    ``skip``-masked lane, and under ``vmap`` each lane's OWN count — the
+    batched loop itself runs while ANY lane's cond holds, so the device
+    executes the maximum over the lanes.
 
     ``busy`` is the worker-busy time integral (sum over ticks of
     active-worker count x tick), the quantity utilisation stats divide by
@@ -430,12 +438,13 @@ def jax_lookahead(op_remaining, op_valid, op_worker, op_score, num_parents,
             jnp.zeros((N,), jnp.int32),
             jnp.zeros((), dt), jnp.zeros((), dt), jnp.zeros((), dt),
             jnp.zeros((), dt), jnp.int32(0), jnp.bool_(False))
-    out = jax.lax.while_loop(cond, body, init)
+    with jax.named_scope(scopes.SIM_LOOKAHEAD):
+        out = jax.lax.while_loop(cond, body, init)
     (_, _, op_done, dep_done, _, t, comm_oh, comp_oh, busy, it,
      stuck) = out
     finished = (jnp.all(op_done | ~op_valid)
                 & jnp.all(dep_done | ~dep_valid))
-    return t, comm_oh, comp_oh, busy, finished & ~stuck
+    return t, comm_oh, comp_oh, busy, finished & ~stuck, it
 
 
 def lookahead_fn(num_workers: int, num_channels: int):

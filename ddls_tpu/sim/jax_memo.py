@@ -74,6 +74,8 @@ from typing import Callable, Optional, Tuple, Union
 
 import numpy as np
 
+from ddls_tpu.telemetry import scopes
+
 #: host key builders this module mirrors — the lint engine's
 #: backend-surface-parity rule checks each still exists in
 #: ``sim/cluster.py``, so a host key-builder rename fails at lint time
@@ -201,7 +203,9 @@ def _bits(x):
 def memo_lookahead(memo: dict, cfg, groups, times,
                    compute: Callable[..., Tuple]):
     """Probe-or-compute one lookahead under the memo key (cfg, groups,
-    times); returns ``((t, ok), memo')``.
+    times); returns ``((t, ok, *extra), memo')`` — whatever ``compute``
+    returns beyond ``(t, ok)`` (the loop's trip count, 0 on a masked
+    hit lane) passes through untouched.
 
     Probe (batched — the wide-vmap form, ISSUE 17): hash the key onto a
     set, compare the FULL residual bitwise against every way, gather the
@@ -221,59 +225,64 @@ def memo_lookahead(memo: dict, cfg, groups, times,
     S, W = memo["key_cfg"].shape
     n_groups = memo["key_groups"].shape[-1]
 
-    cfg = jnp.asarray(cfg, jnp.int32)
-    tbits = _bits(times).reshape(-1)
-    payload = jnp.concatenate([
-        cfg.astype(jnp.uint32).reshape(1),
-        groups.astype(jnp.uint32),
-        tbits,
-    ])
-    weights = jnp.asarray(_hash_weights(1 + n_groups + tbits.shape[0]))
-    h = jnp.sum(payload * weights, dtype=jnp.uint32)
-    set_idx = (h % jnp.uint32(S)).astype(jnp.int32)
+    with jax.named_scope(scopes.SIM_MEMO_PROBE):
+        cfg = jnp.asarray(cfg, jnp.int32)
+        tbits = _bits(times).reshape(-1)
+        payload = jnp.concatenate([
+            cfg.astype(jnp.uint32).reshape(1),
+            groups.astype(jnp.uint32),
+            tbits,
+        ])
+        weights = jnp.asarray(_hash_weights(1 + n_groups + tbits.shape[0]))
+        h = jnp.sum(payload * weights, dtype=jnp.uint32)
+        set_idx = (h % jnp.uint32(S)).astype(jnp.int32)
 
-    way_cfg = memo["key_cfg"][set_idx]          # [W]
-    way_groups = memo["key_groups"][set_idx]    # [W, N]
-    way_times = memo["key_times"][set_idx]      # [W, M]
-    eq = ((way_cfg == cfg)
-          & jnp.all(way_groups == groups[None], axis=-1)
-          & jnp.all(_bits(way_times) == _bits(times)[None],
-                    axis=tuple(range(1, _bits(way_times).ndim))))
-    hit = eq.any()
-    way_hit = jnp.argmax(eq).astype(jnp.int32)
+        way_cfg = memo["key_cfg"][set_idx]          # [W]
+        way_groups = memo["key_groups"][set_idx]    # [W, N]
+        way_times = memo["key_times"][set_idx]      # [W, M]
+        eq = ((way_cfg == cfg)
+              & jnp.all(way_groups == groups[None], axis=-1)
+              & jnp.all(_bits(way_times) == _bits(times)[None],
+                        axis=tuple(range(1, _bits(way_times).ndim))))
+        hit = eq.any()
+        way_hit = jnp.argmax(eq).astype(jnp.int32)
 
     # batched gather/mask/select: serve the hit value from the table,
     # run the (skip-masked) lookahead for the miss case, keep whichever
     # the hit flag says. Bitwise-hit guarantee is preserved at every
     # width — hits serve previously computed bits verbatim, misses run
-    # the loop under their own cond exactly as unbatched.
-    t_c, ok_c = compute(hit)
-    t = jnp.where(hit, memo["val_t"][set_idx, way_hit], t_c)
-    ok = jnp.where(hit, memo["val_ok"][set_idx, way_hit], ok_c)
+    # the loop under their own cond exactly as unbatched. The lookahead
+    # keeps its own scope: the probe's scope is opened around it twice.
+    t_c, ok_c, *extra = compute(hit)
 
-    # miss insert: round-robin way per set; the write is a pair of
-    # where-gated dynamic-update-slices, cheap either way (and dead on
-    # the hit path only in the sense that it rewrites identical state)
-    way_ins = memo["rr"][set_idx] % jnp.int32(W)
-    miss = ~hit
-    evict = miss & (memo["key_cfg"][set_idx, way_ins] >= 0)
+    with jax.named_scope(scopes.SIM_MEMO_PROBE):
+        t = jnp.where(hit, memo["val_t"][set_idx, way_hit], t_c)
+        ok = jnp.where(hit, memo["val_ok"][set_idx, way_hit], ok_c)
 
-    def upd(arr, val):
-        old = arr[set_idx, way_ins]
-        return arr.at[set_idx, way_ins].set(jnp.where(miss, val, old))
+        # miss insert: round-robin way per set; the write is a pair of
+        # where-gated dynamic-update-slices, cheap either way (and dead
+        # on the hit path only in the sense that it rewrites identical
+        # state)
+        way_ins = memo["rr"][set_idx] % jnp.int32(W)
+        miss = ~hit
+        evict = miss & (memo["key_cfg"][set_idx, way_ins] >= 0)
 
-    memo = {
-        "key_cfg": upd(memo["key_cfg"], cfg),
-        "key_groups": upd(memo["key_groups"], groups),
-        "key_times": upd(memo["key_times"], times),
-        "val_t": upd(memo["val_t"], t),
-        "val_ok": upd(memo["val_ok"], ok),
-        "rr": memo["rr"].at[set_idx].add(miss.astype(jnp.int32)),
-        "hits": memo["hits"] + hit.astype(jnp.int32),
-        "misses": memo["misses"] + miss.astype(jnp.int32),
-        "evicts": memo["evicts"] + evict.astype(jnp.int32),
-    }
-    return (t, ok), memo
+        def upd(arr, val):
+            old = arr[set_idx, way_ins]
+            return arr.at[set_idx, way_ins].set(jnp.where(miss, val, old))
+
+        memo = {
+            "key_cfg": upd(memo["key_cfg"], cfg),
+            "key_groups": upd(memo["key_groups"], groups),
+            "key_times": upd(memo["key_times"], times),
+            "val_t": upd(memo["val_t"], t),
+            "val_ok": upd(memo["val_ok"], ok),
+            "rr": memo["rr"].at[set_idx].add(miss.astype(jnp.int32)),
+            "hits": memo["hits"] + hit.astype(jnp.int32),
+            "misses": memo["misses"] + miss.astype(jnp.int32),
+            "evicts": memo["evicts"] + evict.astype(jnp.int32),
+        }
+    return (t, ok, *extra), memo
 
 
 def memo_trace_counters(memo: dict) -> dict:

@@ -22,23 +22,30 @@ Usage::
                            batch_fill=4)
     print(telemetry.snapshot())                  # JSON-friendly rollup
 
-Opt-in ``jax.profiler`` capture: ``enable(jax_trace_dir=...,
-jax_trace_spans=("train.train_step",))`` makes the first matching span
-per process wrap a ``jax.profiler`` trace (TensorBoard/Perfetto), tying
-device timelines to the same span names the histograms use.
+Every live span of the global registry is also a
+``jax.profiler.TraceAnnotation`` named
+``ddls.<name>``: a profile taken while telemetry is on (an operator's
+``experiment.profile_jax=true``, the benchmark's traced window) shows
+the program's spans on the device's clock. The device program itself is
+named by ``jax.named_scope``s kept in ``telemetry/scopes.py``.
 
 Subsystems that need isolated, always-on metrics (serve's per-server
 stats) instantiate a private ``Registry(enabled=True)`` instead of the
 global one — multiple servers must never share counters, and their stats
-must keep working with global telemetry disabled.
+must keep working with global telemetry disabled. Start-up is the other
+always-on registry (``telemetry.startup``): ``startup.*`` spans time the
+once-per-process phases of building a run, where telemetry proper is
+still off — nothing per step may ever use it.
 """
 from __future__ import annotations
 
 import os
-from typing import Any, Dict, Optional, Sequence
+from typing import Any, Dict, Optional
 
+from ddls_tpu.telemetry import startup
 from ddls_tpu.telemetry.metrics import (DEFAULT_LATENCY_BUCKETS_S,
-                                        DEFAULT_WINDOW, NULL_SPAN, Counter,
+                                        DEFAULT_WINDOW, NULL_SPAN,
+                                        TRACE_ANNOTATION_PREFIX, Counter,
                                         Gauge, Histogram, NullSpan,
                                         Registry, Span, TransferSpan,
                                         aggregate_snapshots,
@@ -51,13 +58,14 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "Span", "NullSpan",
     "NULL_SPAN", "TransferSpan", "JsonlSink", "DEFAULT_LATENCY_BUCKETS_S",
     "DEFAULT_WINDOW", "percentile_from_bucket_counts", "overlap_summary",
-    "aggregate_snapshots", "tree_nbytes",
+    "aggregate_snapshots", "tree_nbytes", "TRACE_ANNOTATION_PREFIX",
+    "startup",
     "registry", "enabled", "enable", "disable", "span", "transfer", "inc",
     "observe", "set_gauge", "record_event", "snapshot", "span_summaries",
     "reset", "dump_snapshot", "clock_now", "record_span", "span_intervals",
 ]
 
-_GLOBAL = Registry(enabled=False)
+_GLOBAL = Registry(enabled=False, annotate_spans=True)
 
 # environment override for processes whose CLI has no telemetry flag
 # (subprocess env workers, the bench's sim-mode rider): a path enables
@@ -78,24 +86,16 @@ def enabled() -> bool:
 
 def enable(sink_path: Optional[str] = None,
            clock=None,
-           jax_trace_dir: Optional[str] = None,
-           jax_trace_spans: Sequence[str] = (),
            record_intervals: Optional[bool] = None) -> Registry:
     """Turn the global registry on (idempotent; existing metrics are
     kept — call ``reset()`` first for a fresh measurement window).
-    ``sink_path`` attaches a JSONL sink; ``jax_trace_dir`` +
-    ``jax_trace_spans`` arm the opt-in jax.profiler capture;
-    ``record_intervals=True`` keeps per-span (start, end) pairs in a
-    bounded ring for ``overlap_summary`` concurrency accounting."""
+    ``sink_path`` attaches a JSONL sink; ``record_intervals=True`` keeps
+    per-span (start, end) pairs in a bounded ring for
+    ``overlap_summary`` concurrency accounting."""
     if sink_path:
         _GLOBAL.sink = JsonlSink(sink_path)
     if clock is not None:
         _GLOBAL.clock = clock
-    if jax_trace_dir:
-        _GLOBAL.jax_trace_dir = str(jax_trace_dir)
-        _GLOBAL._jax_trace_done = False  # arm a fresh one-shot capture
-    if jax_trace_spans:
-        _GLOBAL.jax_trace_spans = frozenset(jax_trace_spans)
     if record_intervals is not None:
         _GLOBAL.record_intervals = bool(record_intervals)
     _GLOBAL.enabled = True

@@ -407,8 +407,7 @@ def to_perfetto(evts: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
     worker (jobs as duration slices), one per channel (flow mounts),
     instant markers for arrivals/decisions/blocks, and a running-jobs
     counter track from the tick events. Open in ui.perfetto.dev or
-    chrome://tracing — the same viewer as the jax profiler captures
-    telemetry's ``jax_trace_dir`` hook produces."""
+    chrome://tracing — the same viewer as a jax profiler capture."""
     summary = summarize(evts)
     jobs = summary["jobs"]
     out: List[Dict[str, Any]] = [

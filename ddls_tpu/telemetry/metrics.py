@@ -263,57 +263,53 @@ class NullSpan:
 NULL_SPAN = NullSpan()
 
 
+#: prefix of a span's name on the profiler's clock
+#: (``jax.profiler.TraceAnnotation``); trace readers select the
+#: program's spans by it
+TRACE_ANNOTATION_PREFIX = "ddls."
+
+
 class Span:
     """One timed block: ``with registry.span("collect"): ...`` records
     the duration into the registry's span histogram (and the JSONL sink
     when one is attached). ``duration_s`` is set on exit; ``elapsed()``
-    reads the running clock mid-span."""
+    reads the running clock mid-span.
 
-    __slots__ = ("_registry", "name", "_t0", "duration_s",
-                 "_owns_jax_trace")
+    In a registry built with ``annotate_spans`` (the process-global
+    one: its spans exist only while somebody measures) the block is
+    also a ``jax.profiler.TraceAnnotation`` named ``ddls.<name>``, so a
+    profile taken while the span runs (an operator's
+    ``experiment.profile_jax``, the benchmark's traced window) shows the
+    program's spans on the device's clock. The always-on private
+    registries (serve's per-request spans, start-up) do not annotate;
+    in a process that never imported jax (env workers) it is skipped."""
+
+    __slots__ = ("_registry", "name", "_t0", "duration_s", "_annotation")
 
     def __init__(self, registry: "Registry", name: str):
         self._registry = registry
         self.name = name
         self._t0 = 0.0
         self.duration_s = 0.0
-        self._owns_jax_trace = False
+        self._annotation = None
 
     def __enter__(self) -> "Span":
         reg = self._registry
-        # opt-in jax.profiler capture: ONE trace per process — the first
-        # configured span to enter owns it (jax supports a single active
-        # trace), stops it on ITS exit (instance ownership, so a nested
-        # or repeated same-name span can neither stop the outer trace
-        # early nor re-arm a second capture)
-        if (reg.jax_trace_dir and not reg._jax_tracing
-                and not reg._jax_trace_done
-                and self.name in reg.jax_trace_spans):
-            try:
-                import jax
-
-                jax.profiler.start_trace(str(reg.jax_trace_dir))
-                reg._jax_tracing = self.name
-                self._owns_jax_trace = True
-            except Exception:
-                pass  # profiling must never break the measured code
+        jax = sys.modules.get("jax") if reg.annotate_spans else None
+        if jax is not None:
+            self._annotation = jax.profiler.TraceAnnotation(
+                TRACE_ANNOTATION_PREFIX + self.name)
+            self._annotation.__enter__()
         self._t0 = reg.clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         reg = self._registry
         self.duration_s = reg.clock() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
         reg._record_span(self.name, self.duration_s, t0=self._t0)
-        if self._owns_jax_trace:
-            try:
-                import jax
-
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
-            reg._jax_tracing = None
-            reg._jax_trace_done = True
-            self._owns_jax_trace = False
         return False
 
     def elapsed(self) -> float:
@@ -405,14 +401,13 @@ class Registry:
 
     def __init__(self, enabled: bool = True,
                  clock: Callable[[], float] = time.perf_counter,
-                 sink=None):
+                 sink=None, annotate_spans: bool = False):
         self.enabled = bool(enabled)
         self.clock = clock
         self.sink = sink
-        self.jax_trace_dir: Optional[str] = None
-        self.jax_trace_spans: frozenset = frozenset()
-        self._jax_tracing: Optional[str] = None
-        self._jax_trace_done = False  # one capture per process/registry
+        # spans also enter a ``ddls.<name>`` profiler annotation
+        # (Span): for the global registry only, never a per-request one
+        self.annotate_spans = bool(annotate_spans)
         # opt-in (enable(record_intervals=True)): keep (name, t0, t1) for
         # every completed span so overlap/gap accounting can PROVE claimed
         # concurrency (e.g. train.update_device running under

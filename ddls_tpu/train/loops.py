@@ -23,6 +23,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 import numpy as np
 
 from ddls_tpu import telemetry
+from ddls_tpu.telemetry import startup
 from ddls_tpu.utils.common import (available_cores, get_class_from_path,
                                    seed_everything)
 
@@ -548,19 +549,20 @@ class RLEpochLoop:
         elif use_parallel_envs == "auto":
             # subprocess env workers only pay off with real cores to run on
             use_parallel_envs = available_cores() > 1
-        if use_parallel_envs:
-            self.vec_env = ParallelVectorEnv(
-                self.env_cls, self.env_config, self.num_envs,
-                seeds=[self._collect_seed + i
-                       for i in range(self.num_envs)],
-                backend=self.vec_env_backend)
-        else:
-            self.vec_env = VectorEnv(
-                [lambda: self.env_cls(**self.env_config)
-                 for _ in range(host_pool_size)],
-                seeds=[self._collect_seed + i
-                       for i in range(host_pool_size)])
-        self.vec_env.reset()
+        with startup.span("startup.env"):
+            if use_parallel_envs:
+                self.vec_env = ParallelVectorEnv(
+                    self.env_cls, self.env_config, self.num_envs,
+                    seeds=[self._collect_seed + i
+                           for i in range(self.num_envs)],
+                    backend=self.vec_env_backend)
+            else:
+                self.vec_env = VectorEnv(
+                    [lambda: self.env_cls(**self.env_config)
+                     for _ in range(host_pool_size)],
+                    seeds=[self._collect_seed + i
+                           for i in range(host_pool_size)])
+            self.vec_env.reset()
 
         template_env = getattr(self.vec_env, "envs", [None])[0]
         if template_env is not None:
@@ -572,10 +574,11 @@ class RLEpochLoop:
         # raw model config rides the fragment CONFIG frame so actor
         # hosts build the identical policy (frozen param-tree paths)
         self._model_config = model
-        self.model = self._build_model(n_actions, model)
-
-        obs0 = jax.tree_util.tree_map(np.asarray, self.vec_env.obs[0])
-        self.params = self.model.init(jax.random.PRNGKey(self.seed), obs0)
+        with startup.span("startup.model"):
+            self.model = self._build_model(n_actions, model)
+            obs0 = jax.tree_util.tree_map(np.asarray, self.vec_env.obs[0])
+            self.params = self.model.init(jax.random.PRNGKey(self.seed),
+                                          obs0)
 
         from ddls_tpu.models.policy import batched_policy_apply
         # replicated/fsdp build the exact 1-D dp mesh make_mesh always
@@ -666,10 +669,13 @@ class RLEpochLoop:
             # split BEFORE the learner builds: self.mesh becomes the
             # LEARNER sub-mesh (may fall back to pipelined, loudly)
             self._split_sebulba_mesh()
-        self.learner = self._make_learner()
-        self.state = self.learner.init_state(self.params)
+        with startup.span("startup.learner"):
+            self.learner = self._make_learner()
+            self.state = self.learner.init_state(self.params)
         if self.loop_mode == "fused":
-            self._build_fused()
+            # holds startup.device_tables and startup.job_banks
+            with startup.span("startup.fused_build"):
+                self._build_fused()
             return
         if self.loop_mode == "sebulba":
             self.collector = self._make_sebulba_collector()
@@ -848,8 +854,9 @@ class RLEpochLoop:
                                           build_obs_tables)
 
         env0 = self.vec_env.envs[0]
-        et = build_episode_tables(env0)
-        ot = build_obs_tables(env0, et)
+        with startup.span("startup.device_tables"):
+            et = build_episode_tables(env0)
+            ot = build_obs_tables(env0, et)
         return env0, et, ot
 
     def _device_bank_size(self, env0) -> int:
@@ -868,9 +875,10 @@ class RLEpochLoop:
         reproduce the collector's banks bit-for-bit)."""
         from ddls_tpu.rl.fused import stacked_job_banks
 
-        return stacked_job_banks(et, env0, n_lanes,
-                                 self._device_bank_size(env0),
-                                 seed_base=self._collect_seed)
+        with startup.span("startup.job_banks"):
+            return stacked_job_banks(et, env0, n_lanes,
+                                     self._device_bank_size(env0),
+                                     seed_base=self._collect_seed)
 
     def _collection_mesh(self, n_lanes: int):
         """The mesh lanes shard over, or None for single-device
@@ -1184,6 +1192,8 @@ class RLEpochLoop:
             return []
         import jax
 
+        from ddls_tpu.rl.fused import record_lookahead_trips
+
         harvester = (self.fused if self.fused is not None
                      else self.collector)
         ring, self._fused_episode_ring = self._fused_episode_ring, []
@@ -1194,6 +1204,8 @@ class RLEpochLoop:
         episodes: List[dict] = []
         for ep in fetched:
             episodes.extend(harvester.harvest_episodes(ep))
+            if telemetry.enabled():
+                record_lookahead_trips(ep)
         return episodes
 
     def _run_fused(self) -> Dict[str, Any]:

@@ -7,8 +7,9 @@ Usage::
 The input is a flight JSONL file (``ddls_tpu.telemetry.flight
 .save_jsonl``, or ``scripts/trace_diff.py run --save-a``; flight records
 inside a mixed telemetry sink are picked out automatically). The output
-opens in ui.perfetto.dev or chrome://tracing — the same viewer as the
-jax profiler captures telemetry's ``jax_trace_dir`` hook produces — with
+opens in ui.perfetto.dev or chrome://tracing — the same viewer as a jax
+profiler capture (``experiment.profile_jax``), where telemetry's spans
+appear as ``ddls.<name>`` annotations — with
 one row per worker (jobs as duration slices), one per channel (flow
 mounts), instant markers for arrivals/decisions/blocks, and a
 running-jobs counter track.

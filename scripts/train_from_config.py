@@ -25,6 +25,7 @@ from typing import Optional
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from ddls_tpu.config import load_config, save_config
+from ddls_tpu.telemetry import startup
 from ddls_tpu.train.compat import apply_reference_compat
 from ddls_tpu.train import Checkpointer, Launcher, Logger, make_epoch_loop
 from ddls_tpu.utils.common import seed_everything, unique_experiment_dir
@@ -77,10 +78,10 @@ class TrainRun:
     primary: bool
 
 
-def build_run(cfg: dict) -> TrainRun:
-    """Seed, create the save dir, and build epoch loop + Launcher +
-    Logger + Checkpointer from a composed (and compat-applied) config.
-    The caller owns ``run.epoch_loop.close()``."""
+def _prepare_experiment(cfg: dict):
+    """What comes before the epoch loop (``startup.config``): the
+    distributed runtime, the seeds, the save dir with the composed
+    config, wandb. Returns (primary, save_dir, wandb)."""
     experiment = cfg.get("experiment", {})
 
     # XLA dump must be requested before the first backend init (SURVEY
@@ -126,10 +127,23 @@ def build_run(cfg: dict) -> TrainRun:
             wandb = wandb_module
         except ImportError:
             print("wandb requested but not installed; continuing without it")
+    return primary, save_dir, wandb
 
-    algo_name = (cfg.get("algo") or {}).get("algo_name", "ppo")
-    epoch_loop = make_epoch_loop(algo_name, wandb=wandb,
-                                 **build_epoch_loop_kwargs(cfg))
+
+def build_run(cfg: dict) -> TrainRun:
+    """Seed, create the save dir, and build epoch loop + Launcher +
+    Logger + Checkpointer from a composed (and compat-applied) config.
+    The caller owns ``run.epoch_loop.close()``. Timed as
+    ``startup.build_run``, its phases nested inside it
+    (docs/telemetry.md lists them); what the process did before it is
+    ``startup.before_build``."""
+    startup.span_since_process_start("startup.before_build")
+    with startup.span("startup.build_run"):
+        with startup.span("startup.config"):
+            primary, save_dir, wandb = _prepare_experiment(cfg)
+        algo_name = (cfg.get("algo") or {}).get("algo_name", "ppo")
+        epoch_loop = make_epoch_loop(algo_name, wandb=wandb,
+                                     **build_epoch_loop_kwargs(cfg))
     print(f"Initialised {type(epoch_loop).__name__} ({algo_name}): "
           f"{epoch_loop.num_envs} envs x "
           f"{epoch_loop.rollout_length} steps on mesh "

@@ -93,7 +93,7 @@ def test_matches_host_engine(dataset_dir, actions):
         a = case["arrays"]
         key = (a.num_workers, a.num_channels)
         fn = fns.setdefault(key, lookahead_fn(*key))
-        t, comm, comp, busy, ok = fn(*arrays_as_args(a))
+        t, comm, comp, busy, ok, _trips = fn(*arrays_as_args(a))
         assert bool(ok), "array engine failed to converge"
         host_t, host_comm, host_comp = case["host"]
         assert float(t) == pytest.approx(host_t, rel=1e-4), \
@@ -117,7 +117,7 @@ def test_vmapped_batch(dataset_dir):
     fn = batched_lookahead_fn(W, C)
     batch = [np.stack([arrays_as_args(c["arrays"])[k] for c in cases])
              for k in range(13)]
-    t, comm, comp, busy, ok = fn(*batch)
+    t, comm, comp, busy, ok, _trips = fn(*batch)
     assert bool(np.all(ok))
     for bi, case in enumerate(cases):
         assert float(t[bi]) == pytest.approx(case["host"][0], rel=1e-4)

@@ -1,0 +1,258 @@
+"""What the fused training path says about itself from INSIDE the
+program (ISSUE 23): the ``jax.named_scope`` names on the lowered epoch
+program, the per-lane lookahead trip trace and the ``sim.lookahead.*``
+counters reduced from it, and the always-on ``startup.*`` spans of
+``build_run``."""
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import test_fused
+import test_jax_memo
+from ddls_tpu import telemetry
+from ddls_tpu.telemetry import scopes, startup
+
+pytestmark = pytest.mark.telemetry
+
+fused_dataset = test_fused.fused_dataset
+memo_env = test_jax_memo.memo_env
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(autouse=True)
+def _clean_registries():
+    def clean():
+        telemetry.disable()
+        telemetry.reset()
+        startup.registry().reset()
+
+    clean()
+    yield
+    clean()
+
+
+# ------------------------------------------------------------- scopes
+def test_every_scope_names_ops_of_the_fused_epoch_program(fused_dataset):
+    """Each name in ``telemetry/scopes.py`` is a path segment — bare or
+    wrapped by vmap/jvp/transpose — of some operation's ``op_name`` in
+    the lowered fused epoch program (the path a TPU profile carries per
+    instruction)."""
+    import re
+
+    loop = test_fused._make_fused_loop(fused_dataset)
+    try:
+        text = loop.fused.lower(loop.state).compile().as_text()
+    finally:
+        loop.close()
+    paths = set(re.findall(r'op_name="([^"]+)"', text))
+    assert paths, "no op_name metadata in the compiled program"
+    for scope in scopes.ALL:
+        rx = re.compile(rf"(?:^|[/;])(?:\w+\()*{scope}\)*(?:[/;]|$)")
+        assert any(rx.search(p) for p in paths), scope
+    assert len(set(scopes.ALL)) == len(scopes.ALL)
+
+
+# --------------------------------------------------------- trip counts
+def _segment_lanes(memo_env, n_lanes, T=12):
+    import jax
+
+    from ddls_tpu.sim.jax_env import (make_segment_fn, segment_init,
+                                      vmap_segment_fn)
+    from ddls_tpu.sim.jax_memo import MemoConfig
+
+    et, ot = memo_env["et"], memo_env["ot"]
+    model, params = test_jax_memo._ReplayPolicy(), memo_env["params"]
+    banks = test_jax_memo._lane_banks(memo_env, n_lanes)
+    mc = MemoConfig(n_sets=16, n_ways=2)
+    seg = make_segment_fn(et, ot, model, T, memo_cfg=mc, trace_trips=True)
+    states = jax.vmap(lambda b: segment_init(et, b, mc))(banks)
+    rngs = jax.random.split(jax.random.PRNGKey(3), n_lanes)
+    _, wide, _ = jax.jit(vmap_segment_fn(seg, n_lanes))(
+        banks, params, states, rngs)
+
+    def single(lane):
+        bank = jax.tree_util.tree_map(lambda x: x[lane], banks)
+        return seg(bank, params, segment_init(et, bank, mc),
+                   rngs[lane])[1]
+
+    return wide, single
+
+
+@pytest.mark.parametrize("n_lanes", [2, 8])
+def test_traced_trips_are_each_lanes_own_loop_count(memo_env, n_lanes):
+    """Lane by lane the vmapped segment's trip trace equals the
+    single-lane kernel's on the same decisions (there the count IS the
+    loop's ``it``); a memo hit reads 0. Every lane that loops carries
+    its count out, so the per-step maximum over the lanes is what the
+    batched loop executed."""
+    wide, single = _segment_lanes(memo_env, n_lanes)
+    own = np.asarray(wide["la_trips"])
+    assert own.shape == (n_lanes, 12) and own.dtype == np.int32
+    hits = np.diff(np.asarray(wide["memo_hits"]), axis=1, prepend=0)
+    assert hits.sum() > 0 and own.max() > 0
+    assert np.all(own[hits > 0] == 0), "a memo hit ran trips"
+    for lane in range(n_lanes):
+        alone = single(lane)
+        np.testing.assert_array_equal(np.asarray(alone["action"]),
+                                      np.asarray(wide["action"])[lane])
+        np.testing.assert_array_equal(np.asarray(alone["la_trips"]),
+                                      own[lane])
+
+
+def test_a_discarded_lane_runs_no_trips(memo_env):
+    """Under vmap the decision's ``cond`` is a select and every lane
+    runs the heavy branch; a lane whose action takes the zero path is
+    masked out of the lookahead loop (``skip``), so the loop's own count
+    — BEFORE the select — is 0 there, and what the batched loop runs is
+    the maximum over lanes whose result is used."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddls_tpu.sim.jax_env import _episode_kernels
+
+    et = memo_env["et"]
+    k = _episode_kernels(et)
+    bank = jax.tree_util.tree_map(
+        lambda x: x[0], test_jax_memo._lane_banks(memo_env, 1))
+    carry, row = k.init_state(bank)[0], jnp.int32(0)
+    n_deg = len(et.degrees)
+    cfg = bank["type"][row] * n_deg + (n_deg - 1)   # the largest degree
+
+    def trips_before_the_select(discard):
+        return k.eval_cfg(bank, carry, row, cfg,
+                          discard=discard)[0]["la_trips"]
+
+    discard = jnp.asarray([False, True, False, True])
+    trips = np.asarray(jax.jit(jax.vmap(trips_before_the_select))(discard))
+    assert trips[0] == trips[2] > 0
+    assert trips[1] == trips[3] == 0
+    # through the decision itself: action 0 takes the zero path
+    actions = jnp.where(discard, 0, et.degrees[-1]).astype(jnp.int32)
+    la = np.asarray(jax.jit(jax.vmap(
+        lambda a: k.decision(bank, carry, a, row)[1][4]))(actions))
+    assert la.tolist() == trips.tolist()
+
+
+def test_trip_counters_are_the_hosts_reduction_of_the_trace():
+    from ddls_tpu.rl.fused import record_lookahead_trips
+
+    # [U=1, B=3, T=2]: step 0 — lanes ran 5 and 9, one ran none (a hit
+    # or an action without a lookahead); step 1 — a miss of 7 only
+    ep = {"la_trips": np.array([[[5, 7], [9, 0], [0, 0]]], np.int32)}
+    telemetry.enable()
+    record_lookahead_trips(ep)
+    snap = telemetry.snapshot()
+    assert {k: v for k, v in snap["counters"].items()
+            if k.startswith("sim.lookahead.")} == {
+        "sim.lookahead.calls": 3, "sim.lookahead.trips": 21,
+        "sim.lookahead.lockstep_trips": 16,
+        "sim.lookahead.lockstep_lane_trips": 48}
+    hist = snap["histograms"]["sim.lookahead.trips_per_call"]
+    assert hist["count"] == 3 and hist["max"] == 9.0
+
+
+def test_fused_loop_counts_trips_only_while_telemetry_is_on(
+        fused_dataset):
+    loop = test_fused._make_fused_loop(fused_dataset,
+                                       metrics_sync_interval=1)
+    try:
+        loop.run()                       # telemetry off: the drain ran
+        assert telemetry.snapshot() == {}
+        telemetry.enable()
+        telemetry.reset()
+        loop.run()
+        counters = telemetry.snapshot()["counters"]
+        lanes, steps = loop.fused.num_lanes, 2 * 2      # U x T
+        assert 0 < counters["sim.lookahead.calls"] <= lanes * steps
+        assert (counters["sim.lookahead.lockstep_lane_trips"]
+                == lanes * counters["sim.lookahead.lockstep_trips"])
+        assert (counters["sim.lookahead.lockstep_trips"]
+                <= counters["sim.lookahead.trips"]
+                <= counters["sim.lookahead.lockstep_lane_trips"])
+        # memo counters keep their own path
+        assert counters["event.memo_counters"] >= 1
+    finally:
+        loop.close()
+
+
+# ------------------------------------------------------ start-up spans
+def test_build_run_leaves_each_startup_span_once(fused_dataset, tmp_path,
+                                                 capsys):
+    """``build_run`` at a tiny size: every phase once, the phases inside
+    ``startup.build_run`` and no longer than it together; the first
+    fused epoch is its own span with jax's durations beside it; global
+    telemetry stays off throughout."""
+    scripts = os.path.join(REPO, "scripts")
+    if scripts not in sys.path:
+        sys.path.insert(0, scripts)
+    import train_from_config
+
+    from ddls_tpu.config import load_config
+    from ddls_tpu.train.compat import apply_reference_compat
+
+    sys.path.insert(0, os.path.join(REPO, "tests", "benchmarks"))
+    import bench_tiny
+
+    cfg = load_config(
+        os.path.join(scripts, "ramp_job_partitioning_configs"),
+        "rllib_config",
+        [*bench_tiny.TINY_OVERRIDES, *bench_tiny.COMMON,
+         "epoch_loop.loop_mode=fused", "epoch_loop.updates_per_epoch=1",
+         "epoch_loop.fused_config={lanes: 4, segment_len: 2}",
+         "epoch_loop.num_envs=4", "epoch_loop.rollout_length=2",
+         "epoch_loop.n_devices=1", "experiment.train_seed=0",
+         f"experiment.path_to_save={tmp_path}", "experiment.name=t"])
+    apply_reference_compat(cfg)
+    loop = train_from_config.build_run(cfg).epoch_loop
+    try:
+        reg = startup.registry()
+        spans = {}
+        for name, t0, t1 in reg.span_intervals():
+            spans.setdefault(name, []).append((t0, t1))
+        phases = ["startup.config", "startup.env", "startup.model",
+                  "startup.learner", "startup.fused_build"]
+        nested = ["startup.device_tables", "startup.job_banks"]
+        for name in ["startup.build_run", *phases, *nested]:
+            assert len(spans.get(name, ())) == 1, (name, sorted(spans))
+        (b0, b1), = spans["startup.build_run"]
+        for name in phases:
+            (t0, t1), = spans[name]
+            assert b0 <= t0 <= t1 <= b1, name
+        f0, f1 = spans["startup.fused_build"][0]
+        for name in nested:
+            (t0, t1), = spans[name]
+            assert f0 <= t0 <= t1 <= f1, name
+        assert sum(spans[n][0][1] - spans[n][0][0]
+                   for n in phases) <= b1 - b0
+        # what the process did before build_run, once, ending where
+        # build_run starts
+        (p0, p1), = spans["startup.before_build"]
+        assert p0 < p1 <= b0
+        assert "startup.first_epoch" not in spans
+        assert "startup.jax.trace" in spans    # model init traced a jit
+
+        loop.run()
+        loop.run()
+        first = [(t0, t1) for n, t0, t1 in reg.span_intervals()
+                 if n == "startup.first_epoch"]
+        assert len(first) == 1, "only the first epoch is start-up"
+        f0, f1 = first[0]
+        inside = {n for n, t0, t1 in reg.span_intervals()
+                  if n.startswith("startup.jax.") and f0 <= t0 and t1 <= f1}
+        assert inside == {"startup.jax.trace", "startup.jax.lower",
+                          "startup.jax.compile"}
+        assert not telemetry.enabled() and telemetry.snapshot() == {}
+        # the registry's reader: every span name, once, in one line
+        line = capsys.readouterr().out.splitlines()
+        report, = [ln for ln in line if ln.startswith("[startup] ")]
+        assert report == startup.report()
+        seconds = json.loads(report[len("[startup] "):])
+        assert set(seconds) == {n.removeprefix("startup.")
+                                for n, _, _ in reg.span_intervals()}
+        assert seconds["first_epoch"] > 0
+    finally:
+        loop.close()

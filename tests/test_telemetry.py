@@ -35,8 +35,6 @@ def _clean_global_telemetry():
         reg = telemetry.registry()
         reg.sink = None
         reg.clock = time.perf_counter
-        reg.jax_trace_dir = None
-        reg.jax_trace_spans = frozenset()
 
     clean()
     yield
@@ -304,31 +302,64 @@ def test_fleet_serving_burst_disabled_guard(monkeypatch):
     assert dict(router.registry.counter_items())["fleet.requests"] == 24
 
 
-# ------------------------------------------------------- jax profiler hook
-def test_jax_trace_hook_wraps_configured_span(monkeypatch, tmp_path):
+# ------------------------------------------------- profiler annotation
+def test_live_span_is_a_ddls_trace_annotation(monkeypatch):
+    """One clock: a live span of an annotating registry (the global
+    one) is also a ``jax.profiler.TraceAnnotation`` named
+    ``ddls.<name>``, entered and left with it — how a profile shows the
+    program's spans beside the device's. Disabled telemetry opens none:
+    it still hands out the shared NULL_SPAN. An always-on private
+    registry (serve's per-request spans) opens none either."""
     import jax
 
     calls = []
-    monkeypatch.setattr(jax.profiler, "start_trace",
-                        lambda d: calls.append(("start", d)))
-    monkeypatch.setattr(jax.profiler, "stop_trace",
-                        lambda: calls.append(("stop", None)))
-    reg = telemetry.Registry(enabled=True)
-    reg.jax_trace_dir = str(tmp_path)
-    reg.jax_trace_spans = frozenset({"traced"})
-    with reg.span("untraced"):
+
+    class FakeAnnotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            calls.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            calls.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", FakeAnnotation)
+    assert telemetry.TRACE_ANNOTATION_PREFIX == "ddls."
+    with telemetry.span("off"):           # disabled: no annotation
         pass
-    assert calls == []
-    with reg.span("traced"):
-        with reg.span("traced"):  # nested: only the outer owns the trace
-            pass
-        # the inner same-name exit must NOT have stopped the outer trace
-        assert calls == [("start", str(tmp_path))]
-    assert calls == [("start", str(tmp_path)), ("stop", None)]
-    # one capture per process: later occurrences never re-arm the profiler
-    with reg.span("traced"):
+    assert telemetry.span("off") is telemetry.NULL_SPAN and calls == []
+
+    private = telemetry.Registry(enabled=True)
+    with private.span("serve.request") as sp:
         pass
-    assert calls == [("start", str(tmp_path)), ("stop", None)]
+    assert calls == [] and sp._annotation is None
+    assert private.span_summaries()["serve.request"]["count"] == 1
+
+    reg = telemetry.Registry(enabled=True, annotate_spans=True)
+    with reg.span("outer"):
+        with reg.span("inner"):
+            assert calls == [("enter", "ddls.outer"),
+                             ("enter", "ddls.inner")]
+    assert calls[2:] == [("exit", "ddls.inner"), ("exit", "ddls.outer")]
+    assert reg.span_summaries()["outer"]["count"] == 1
+
+    del calls[:]
+    telemetry.enable()
+    with telemetry.span("train.collect"):
+        pass
+    assert calls == [("enter", "ddls.train.collect"),
+                     ("exit", "ddls.train.collect")]
+
+
+def test_span_in_a_process_without_jax_skips_the_annotation(monkeypatch):
+    """Env workers never import jax; a span there must not."""
+    monkeypatch.delitem(sys.modules, "jax")
+    reg = telemetry.Registry(enabled=True, annotate_spans=True)
+    with reg.span("worker.step") as sp:
+        assert "jax" not in sys.modules
+    assert sp._annotation is None
+    assert reg.span_summaries()["worker.step"]["count"] == 1
 
 
 # ----------------------------------------------------------- sink + report
