@@ -388,7 +388,7 @@ EPISODE_TRACE_KEYS = ("done", "ep_return", "ep_blocked", "ep_completed",
 _TRIP_BUCKETS = tuple(2.0 ** i for i in range(15))
 
 
-def record_lookahead_trips(ep_trace) -> None:
+def record_lookahead_trips(ep_trace, pads) -> None:
     """Reduce a FETCHED ``[..., B, T]`` lookahead trip trace
     (``la_trips``: each lane-step's own loop count) into the
     ``sim.lookahead.*`` telemetry counters: ``calls`` — lane-steps whose
@@ -398,7 +398,11 @@ def record_lookahead_trips(ep_trace) -> None:
     what the batched loop executed, since it runs while any lane's cond
     holds and every lane that loops carries its count out;
     ``lockstep_lane_trips`` — that times the lanes: the lane-trips the
-    device paid for. The caller gates on ``telemetry.enabled()``."""
+    device paid for. From the tables' ``pads`` (a ``ConfigPads``), once
+    per drained epoch trace: ``dep_slots`` — the dep slots a trip
+    passes over (blocks x split^2) — and ``dep_slots_used`` — the
+    largest row's real deps; their ratio is what the block layout's
+    padding costs. The caller gates on ``telemetry.enabled()``."""
     own = np.asarray(ep_trace["la_trips"])
     lockstep = int(own.max(axis=-2).sum())
     telemetry.inc("sim.lookahead.calls", int((own > 0).sum()))
@@ -409,6 +413,8 @@ def record_lookahead_trips(ep_trace) -> None:
     for trips in own[own > 0].tolist():
         telemetry.observe("sim.lookahead.trips_per_call", trips,
                           buckets=_TRIP_BUCKETS)
+    telemetry.inc("sim.lookahead.dep_slots", int(pads.n_deps))
+    telemetry.inc("sim.lookahead.dep_slots_used", int(pads.n_deps_used))
 
 
 class FusedEpochDriver:

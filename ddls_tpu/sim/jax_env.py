@@ -55,7 +55,7 @@ from ddls_tpu.agents.block_search import block_shapes_for, factor_pairs
 from ddls_tpu.agents.partitioners import build_partition_action
 from ddls_tpu.graphs.readers import backward_op_id
 from ddls_tpu.sim import jax_memo
-from ddls_tpu.sim.jax_lookahead import jax_lookahead
+from ddls_tpu.sim.jax_lookahead import DepBlocks, jax_lookahead
 from ddls_tpu.sim.partition import partition_graph, partitioned_op_id
 from ddls_tpu.telemetry import scopes
 
@@ -167,19 +167,25 @@ def build_shape_tables(ramp_shape: Coord, max_split: int) -> ShapeTables:
 
 @dataclasses.dataclass
 class ConfigPads:
-    n_ops: int        # N: padded partitioned-op slots
-    n_deps: int       # M: padded dep slots
+    n_ops: int        # N = n_orig * max_split: op slot (o, k) = o*S + k
+    n_deps: int       # M = n_blocks * max_split**2: dep slot (b, i, j)
     n_fwd: int        # F: padded forward-op scan slots
     n_parents: int    # P: padded parent-candidate slots
-    max_split: int    # maximum sub-ops per op (block size)
+    max_split: int    # S: maximum sub-ops per op (block side)
     n_groups: int     # G: padded candidate collective groups
     group_edges: int  # Eg: padded edges per candidate group
     n_sync: int       # padded 2-edge sync pairs
     n_o2o: int        # padded one-to-one edges
+    n_orig: int       # No: padded original (unpartitioned) op slots
+    n_blocks: int     # B: padded dep blocks (original edges + cliques)
+    n_deps_used: int  # the largest row's real deps (the rest: padding)
 
 
 def config_tables_for(graph, degree: int, quantum: float) -> dict:
-    """Unpadded per-(model, degree) tables (numpy, f64).
+    """Unpadded per-(model, degree) tables (numpy, f64), in the HOST's
+    order (``partition_graph(...).finalize()``), with each sub-op's and
+    sub-dep's block coordinates beside them (`_block_coords`):
+    `stack_config_tables` lays the rows out by those.
 
     ``graph`` is the job's raw profile graph; ``degree`` the action (the
     per-op split cap fed to the SiP-ML rule, reference:
@@ -256,9 +262,14 @@ def config_tables_for(graph, degree: int, quantum: float) -> dict:
     cand = [g for g in grouping["groups"] if not g["sync"]]
     sync = [g for g in grouping["groups"] if g["sync"]]
     edge_size = arrays["edge_size"]
+    op_ok, dep_bij, blk_src, blk_dst = _block_coords(
+        graph, pgraph, split_fwd, n_forward)
 
     return {
         "n_ops": n, "n_deps": m,
+        "op_ok": op_ok, "dep_bij": dep_bij,
+        "blk_src": blk_src, "blk_dst": blk_dst,
+        "n_orig": len(graph.op_ids),
         "op_compute": arrays["compute"].astype(np.float64),
         "op_sorted_rank": arrays["op_sorted_rank"].astype(np.int32),
         "num_parents": arrays["num_parents"].astype(np.int32),
@@ -276,16 +287,74 @@ def config_tables_for(graph, degree: int, quantum: float) -> dict:
     }
 
 
+def _block_coords(graph, pgraph, split_fwd: Dict[str, int], n_forward: int):
+    """Where `partition_graph` put every sub-op and sub-dep, as block
+    coordinates (sim/partition.py:model_split): sub-op k of original op
+    o is (o, k); an original edge u -> v is the all-to-all block of its
+    split(u) x split(v) sub-deps, a split backward op u the clique
+    block u -> u without its diagonal, and a sub-dep is (block b,
+    source shard i, destination shard j). In ``pgraph.finalize()``
+    order: ``op_ok`` [n, 2], ``dep_bij`` [m, 3]; per block the original
+    op slot of its source and destination, ``blk_src`` / ``blk_dst``
+    [B] (original edges in ``graph.edge_ids`` order, then cliques)."""
+    arrays = pgraph.finalize()
+    split_of = dict(split_fwd)
+    for f_op, split in split_fwd.items():
+        split_of[backward_op_id(f_op, n_forward)] = split
+    o_slot = {str(int(op)): o for o, op in enumerate(graph.op_ids)}
+    sub = {}
+    for op_s, o in o_slot.items():
+        split = split_of.get(op_s, 1)
+        if split > 1:
+            for k in range(split):
+                sub[partitioned_op_id(op_s, k)] = (o, k)
+        else:
+            sub[op_s] = (o, 0)
+    op_ok = np.array([sub[op] for op in arrays["op_ids"]],
+                     np.int32).reshape(-1, 2)
+    block = {(o_slot[str(int(u))], o_slot[str(int(v))]): b
+             for b, (u, v) in enumerate(graph.edge_ids)}
+    dep_bij = np.zeros((pgraph.n_deps, 3), np.int32)
+    for e, (u, v) in enumerate(arrays["edge_ids"]):
+        (ou, i), (ov, j) = sub[u], sub[v]
+        dep_bij[e] = (block.setdefault((ou, ov), len(block)), i, j)
+    ends = np.array(sorted(block, key=block.get), np.int32).reshape(-1, 2)
+    return op_ok, dep_bij, ends[:, 0], ends[:, 1]
+
+
+def table_slots(c: dict, max_split: int) -> Tuple[np.ndarray, np.ndarray]:
+    """(op slot [n], dep slot [m]) of one `config_tables_for` row in the
+    stacked tables: host (``finalize()``) index -> table position."""
+    S = max_split
+    op_slot = c["op_ok"][:, 0] * S + c["op_ok"][:, 1]
+    b, i, j = c["dep_bij"].T
+    return op_slot.astype(np.int32), ((b * S + i) * S + j).astype(np.int32)
+
+
 def stack_config_tables(per_cfg: Sequence[dict],
                         shape_tables: ShapeTables) -> Tuple[dict, ConfigPads]:
-    """Pad + stack per-config tables along a leading cfg axis."""
+    """Pad + stack per-config tables along a leading cfg axis, in BLOCK
+    order: op slot (o, k) = o*S + k and dep slot (b, i, j) = (b*S + i)*S
+    + j (`_block_coords`), so that the lookahead reads a dep's source
+    and destination by broadcast and reduction, never per-element
+    indirection (sim/jax_lookahead.py:DepBlocks). Slots no sub-op or
+    sub-dep lands on are masked by ``op_valid`` / ``dep_valid``, as
+    trailing pads were. ``dep_edge`` keeps each dep's host edge index:
+    the one place flat order is semantic (`jax_price_and_score`'s SRPT
+    tie-break)."""
+    S = int(shape_tables.counts.max())
+    n_orig = max(c["n_orig"] for c in per_cfg)
+    n_blocks = max((len(c["blk_src"]) for c in per_cfg), default=1) or 1
     pads = ConfigPads(
-        n_ops=max(c["n_ops"] for c in per_cfg),
-        n_deps=max(c["n_deps"] for c in per_cfg),
+        n_ops=n_orig * S,
+        n_deps=n_blocks * S * S,
+        n_deps_used=max(c["n_deps"] for c in per_cfg),
+        n_orig=n_orig,
+        n_blocks=n_blocks,
         n_fwd=max(len(c["f_split"]) for c in per_cfg),
         n_parents=max((len(p) for c in per_cfg for p in c["f_parents"]),
                       default=1) or 1,
-        max_split=int(shape_tables.counts.max()),
+        max_split=S,
         n_groups=max((len(c["groups"]) for c in per_cfg), default=1) or 1,
         group_edges=max((len(g["edges"]) for c in per_cfg
                          for g in c["groups"]), default=1) or 1,
@@ -294,7 +363,6 @@ def stack_config_tables(per_cfg: Sequence[dict],
     )
     K = len(per_cfg)
     N, M, F, P = pads.n_ops, pads.n_deps, pads.n_fwd, pads.n_parents
-    S = pads.max_split
     G, Eg, Sy, O = (pads.n_groups, pads.group_edges, pads.n_sync,
                     pads.n_o2o)
 
@@ -313,6 +381,9 @@ def stack_config_tables(per_cfg: Sequence[dict],
         "dep_size": np.zeros((K, M), np.float64),
         "dep_mutual": np.zeros((K, M), bool),
         "dep_sorted_rank": np.zeros((K, M), np.int32),
+        "dep_edge": np.full((K, M), M, np.int32),
+        "blk_src": np.full((K, n_blocks), -1, np.int32),
+        "blk_dst": np.full((K, n_blocks), -1, np.int32),
         "f_valid": np.zeros((K, F), bool),
         "f_split": np.ones((K, F), np.int32),
         "f_mem": np.zeros((K, F), np.float64),
@@ -336,47 +407,55 @@ def stack_config_tables(per_cfg: Sequence[dict],
     }
     for k, c in enumerate(per_cfg):
         n, m, f = c["n_ops"], c["n_deps"], len(c["f_split"])
+        if c["op_ok"][:, 1].max(initial=0) >= S:
+            raise ValueError("a row splits an op more than max_split ways")
+        ops, deps = table_slots(c, S)
+        # host index -> slot for index-valued entries; -1 pads stay -1
+        op_at = np.append(ops, -1)
         out["n_ops"][k], out["n_deps"][k], out["n_fwd"][k] = n, m, f
-        out["op_valid"][k, :n] = True
-        out["op_compute"][k, :n] = c["op_compute"]
-        out["op_sorted_rank"][k, :n] = c["op_sorted_rank"]
-        out["num_parents"][k, :n] = c["num_parents"]
-        out["insertion_rank"][k, :n] = c["insertion_rank"]
-        out["dep_valid"][k, :m] = True
-        out["dep_src"][k, :m] = c["dep_src"]
-        out["dep_dst"][k, :m] = c["dep_dst"]
-        out["dep_size"][k, :m] = c["dep_size"]
-        out["dep_mutual"][k, :m] = c["dep_mutual"]
-        out["dep_sorted_rank"][k, :m] = c["dep_sorted_rank"]
+        out["op_valid"][k, ops] = True
+        out["op_compute"][k, ops] = c["op_compute"]
+        out["op_sorted_rank"][k, ops] = c["op_sorted_rank"]
+        out["num_parents"][k, ops] = c["num_parents"]
+        out["insertion_rank"][k, ops] = c["insertion_rank"]
+        out["dep_valid"][k, deps] = True
+        out["dep_src"][k, deps] = ops[c["dep_src"]]
+        out["dep_dst"][k, deps] = ops[c["dep_dst"]]
+        out["dep_size"][k, deps] = c["dep_size"]
+        out["dep_mutual"][k, deps] = c["dep_mutual"]
+        out["dep_sorted_rank"][k, deps] = c["dep_sorted_rank"]
+        out["dep_edge"][k, deps] = np.arange(m)
+        out["blk_src"][k, :len(c["blk_src"])] = c["blk_src"]
+        out["blk_dst"][k, :len(c["blk_dst"])] = c["blk_dst"]
         out["f_valid"][k, :f] = True
         out["f_split"][k, :f] = c["f_split"]
         out["f_mem"][k, :f] = c["f_mem"]
         for i, parents in enumerate(c["f_parents"]):
             out["f_parents"][k, i, :len(parents)] = parents
-        out["f_sub_fwd"][k, :f, :c["f_sub_fwd"].shape[1]] = c["f_sub_fwd"]
-        out["f_sub_bwd"][k, :f, :c["f_sub_bwd"].shape[1]] = c["f_sub_bwd"]
+        out["f_sub_fwd"][k, :f, :c["f_sub_fwd"].shape[1]] = \
+            op_at[c["f_sub_fwd"]]
+        out["f_sub_bwd"][k, :f, :c["f_sub_bwd"].shape[1]] = \
+            op_at[c["f_sub_bwd"]]
         for gi, g in enumerate(c["groups"]):
             ne = len(g["edges"])
             out["grp_valid"][k, gi] = True
-            out["grp_edges"][k, gi, :ne] = g["edges"]
-            out["grp_u"][k, gi, :ne] = g["u"]
-            out["grp_v"][k, gi, :ne] = g["v"]
+            out["grp_edges"][k, gi, :ne] = deps[g["edges"]]
+            out["grp_u"][k, gi, :ne] = ops[g["u"]]
+            out["grp_v"][k, gi, :ne] = ops[g["v"]]
             out["grp_edge_valid"][k, gi, :ne] = True
             out["grp_msg"][k, gi] = g["msg"]
         for si, g in enumerate(c["sync"]):
             out["sync_valid"][k, si] = True
             ne = len(g["edges"])
-            out["sync_edges"][k, si, :ne] = g["edges"]
-            out["sync_u"][k, si] = g["u"][0]
-            out["sync_v"][k, si] = g["v"][0]
+            out["sync_edges"][k, si, :ne] = deps[g["edges"]]
+            out["sync_u"][k, si] = ops[g["u"][0]]
+            out["sync_v"][k, si] = ops[g["v"][0]]
             out["sync_msg"][k, si] = g["msg"]
         no = len(c["o2o_edges"])
         out["o2o_valid"][k, :no] = True
-        out["o2o_edges"][k, :no] = c["o2o_edges"]
+        out["o2o_edges"][k, :no] = deps[c["o2o_edges"]]
         out["seq_compute"][k] = c["seq_compute"]
     return out, pads
-
-
 
 
 # =========================================================================
@@ -734,7 +813,9 @@ def jax_price_and_score(sc, cfg, tables, st: ShapeTables,
     # priced costs in edge order (agents/schedulers.py:_srpt_priorities)
     m = tables["n_deps"][cfg].astype(dt)
     cost_key = jnp.where(dep_valid, -times, jnp.asarray(jnp.inf, dt))
-    order = jnp.lexsort((jnp.arange(M), cost_key))
+    # "edge order" is the HOST's: the tables are in block order, so ties
+    # break on each slot's own edge index, not on its position
+    order = jnp.lexsort((tables["dep_edge"][cfg], cost_key))
     dep_pri = jnp.zeros((M,), dt).at[order].set(
         jnp.arange(M, dtype=dt))
     # the lookahead engines read dep priorities off the channel mounts, so
@@ -1068,7 +1149,9 @@ def _episode_kernels(et: EpisodeTables):
                 et.tables["dep_valid"][cfg], et.tables["dep_src"][cfg],
                 et.tables["dep_dst"][cfg], et.tables["dep_mutual"][cfg],
                 is_flow, dep_score, chan[:, None],
-                num_workers=n_srv, num_channels=n_chan, skip=skip)
+                num_workers=n_srv, num_channels=n_chan, skip=skip,
+                blocks=DepBlocks(et.tables["blk_src"][cfg],
+                                 et.tables["blk_dst"][cfg]))
             return t_la, ok, trips
 
         if memo is None:

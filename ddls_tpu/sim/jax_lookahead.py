@@ -32,7 +32,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import lru_cache as _lru_cache
-from typing import Dict, Tuple
+from typing import Dict, NamedTuple, Tuple
 
 import numpy as np
 
@@ -312,12 +312,125 @@ def build_native_lookahead_arrays(cluster, job,
         num_channels=max(n_chan, 1))
 
 
+class DepBlocks(NamedTuple):
+    """The block structure `partition_graph` gives a job's deps
+    (sim/jax_env.py:stack_config_tables), which lets the tick body read
+    a dep's endpoints by broadcast and reduction instead of per-element
+    gather and scatter. With S = the block side and B = ``src.shape[0]``:
+    op slot (o, k) = o*S + k over N = No*S, dep slot (b, i, j) =
+    (b*S + i)*S + j over E = B*S*S, and every valid dep has ``dep_src``
+    = src[b]*S + i and ``dep_dst`` = dst[b]*S + j. A flow dep's channel
+    is its ordered (source worker, destination worker) pair — one
+    channel per direction of a server pair, workers clipped at 0 as the
+    caller's channel lookup clips them — and ``dep_channel`` is not
+    read."""
+    src: object  # [B] i32 original-op slot of each block's source, -1 pad
+    dst: object  # [B] i32 ... of its destination
+
+
+def _flat_dep_ops(dep_src, dep_dst, dep_channel, num_channels):
+    """The tick body's three dep primitives for an ARBITRARY graph: one
+    gather or scatter per dep (the mounted-graph callers, and the
+    reference the block forms are tested against)."""
+    import jax.numpy as jnp
+
+    def src_done(op_done):
+        return op_done[dep_src]
+
+    def count_parents(parent_done, inc):
+        return parent_done.at[dep_dst].add(inc)
+
+    def nominate(dscores, flow_ready):
+        # per-channel highest-score ready flow dep (scatter-max); a dep
+        # is nominated iff it is the best on at least one of its channels
+        ch_best = jnp.full((num_channels,), -1.0)
+        for li in range(dep_channel.shape[1]):
+            ch_idx = dep_channel[:, li]
+            contrib = jnp.where(ch_idx >= 0, dscores, -1.0)
+            ch_best = ch_best.at[jnp.clip(ch_idx, 0)].max(contrib)
+        nominated = jnp.zeros(dscores.shape, bool)
+        for li in range(dep_channel.shape[1]):
+            ch_idx = dep_channel[:, li]
+            nominated = nominated | (
+                (ch_idx >= 0) & flow_ready
+                & (dscores >= ch_best[jnp.clip(ch_idx, 0)]) & (dscores > 0))
+        return nominated
+
+    return src_done, count_parents, nominate
+
+
+def _block_dep_ops(op_worker, blocks: DepBlocks, n_deps: int,
+                   num_workers: int):
+    """The same three primitives over :class:`DepBlocks` tables: dep
+    state is [B, S_i, S_j], op state [No, S], and nothing indexes per
+    dep. Integer counts and max are order-free, so each returns the
+    flat form's bits. The one-hot masks are loop-invariant: built here,
+    outside the ``while_loop``."""
+    import jax
+    import jax.numpy as jnp
+
+    B = blocks.src.shape[0]
+    S = int(round((n_deps // B) ** 0.5))
+    N = op_worker.shape[0]
+    if B * S * S != n_deps or N % S:
+        raise ValueError(f"({N}, {n_deps}) is not a block layout of {B} "
+                         "blocks")
+    No, W = N // S, num_workers
+    rows = jnp.arange(No, dtype=jnp.int32)
+    from_src = blocks.src[:, None] == rows[None, :]        # [B, No]
+    into_dst = blocks.dst[None, :] == rows[:, None]        # [No, B]
+    # endpoint workers of each block's rows and columns; an unplaced op
+    # (-1) rides server 0's channels exactly as the flat caller's
+    # clipped ``pair_channel`` lookup has it
+    worker = jnp.clip(op_worker, 0).reshape(No, S)
+    w_src = jnp.max(jnp.where(from_src[:, :, None], worker[None], 0), 1)
+    w_dst = jnp.max(jnp.where(into_dst.T[:, :, None], worker[None], 0), 1)
+    on_src = jax.nn.one_hot(w_src, W, dtype=bool)          # [B, S_i, W]
+    on_dst = jax.nn.one_hot(w_dst, W, dtype=bool)          # [B, S_j, W]
+
+    def src_done(op_done):
+        done = jnp.any(from_src[:, :, None] & op_done.reshape(No, S)[None],
+                       axis=1)                             # [B, S_i]
+        return jnp.broadcast_to(done[:, :, None], (B, S, S)).reshape(-1)
+
+    def count_parents(parent_done, inc):
+        into = inc.reshape(B, S, S).sum(axis=1, dtype=inc.dtype)  # [B, S_j]
+        add = jnp.sum(jnp.where(into_dst[:, :, None], into[None], 0),
+                      axis=1, dtype=inc.dtype)             # [No, S]
+        return parent_done + add.reshape(-1)
+
+    def nominate(dscores, flow_ready):
+        ds = dscores.reshape(B, S, S)
+        # best[X, Y] over deps whose source sits on X and destination on
+        # Y: max over j into Y, then over (b, i) into X
+        to_y = jnp.max(jnp.where(on_dst[:, None, :, :],
+                                 ds[:, :, :, None], -1.0), axis=2)
+        best = jnp.max(jnp.where(on_src[:, :, :, None],
+                                 to_y[:, :, None, :], -1.0), axis=(0, 1))
+        # ... and back: each dep reads best[X(b, i), Y(b, j)]
+        of_x = jnp.max(jnp.where(on_src[:, :, :, None],
+                                 best[None, None], -1.0), axis=2)
+        mine = jnp.max(jnp.where(on_dst[:, None, :, :],
+                                 of_x[:, :, None, :], -1.0), axis=3)
+        return flow_ready & (dscores >= mine.reshape(-1)) & (dscores > 0)
+
+    return src_done, count_parents, nominate
+
+
 def jax_lookahead(op_remaining, op_valid, op_worker, op_score, num_parents,
                   dep_remaining, dep_valid, dep_src, dep_dst, dep_mutual,
                   dep_is_flow, dep_score, dep_channel,
-                  *, num_workers: int, num_channels: int, skip=None):
+                  *, num_workers: int, num_channels: int, skip=None,
+                  blocks: DepBlocks | None = None):
     """One-training-step lookahead; returns
     (t, comm_oh, comp_oh, busy, ok, trips).
+
+    ``blocks`` chooses how the tick body reaches a dep's endpoints and
+    channel: None — per-dep gather/scatter through ``dep_src`` /
+    ``dep_dst`` / ``dep_channel``, for any graph; a :class:`DepBlocks`
+    — broadcast and reduction over the partitioner's (block, i, j)
+    layout, for tables laid out that way (the in-kernel env's). Same
+    tick, same bits.
 
     ``trips`` is the loop's own iteration count (i32): 0 for a
     ``skip``-masked lane, and under ``vmap`` each lane's OWN count — the
@@ -354,6 +467,12 @@ def jax_lookahead(op_remaining, op_valid, op_worker, op_score, num_parents,
 
     worker_onehot = (jax.nn.one_hot(op_worker, num_workers, dtype=jnp.float32)
                      .T)  # [W, N]; -1 (padding) one-hots to zeros
+    if blocks is None:
+        src_done, count_parents, nominate = _flat_dep_ops(
+            dep_src, dep_dst, dep_channel, num_channels)
+    else:
+        src_done, count_parents, nominate = _block_dep_ops(
+            op_worker, blocks, E, num_workers)
 
     def cond(state):
         (_, _, op_done, dep_done, _, _, _, _, _, it, stuck) = state
@@ -368,7 +487,7 @@ def jax_lookahead(op_remaining, op_valid, op_worker, op_score, num_parents,
 
         # 1. readiness (snapshotted BEFORE this tick's completions)
         ops_ready = op_valid & ~op_done & (parent_done >= num_parents)
-        deps_ready = dep_valid & ~dep_done & op_done[dep_src]
+        deps_ready = dep_valid & ~dep_done & src_done(op_done)
         flow_ready = deps_ready & dep_is_flow
         nonflow_ready = deps_ready & ~dep_is_flow
         any_nonflow = jnp.any(nonflow_ready)
@@ -384,20 +503,9 @@ def jax_lookahead(op_remaining, op_valid, op_worker, op_score, num_parents,
             & (worker_onehot > 0), axis=0)
         shortest_op = jnp.min(jnp.where(sel_ops, rem_op, BIG))
 
-        # 3. per-channel highest-score ready flow dep (scatter-max)
+        # 3. per-channel highest-score ready flow dep
         dscores = jnp.where(flow_ready, dep_score, -1.0)
-        ch_best = jnp.full((num_channels,), -1.0)
-        for li in range(dep_channel.shape[1]):
-            ch_idx = dep_channel[:, li]
-            contrib = jnp.where(ch_idx >= 0, dscores, -1.0)
-            ch_best = ch_best.at[jnp.clip(ch_idx, 0)].max(contrib)
-        # dep nominated iff it is the best on at least one of its channels
-        nominated = jnp.zeros((E,), bool)
-        for li in range(dep_channel.shape[1]):
-            ch_idx = dep_channel[:, li]
-            nominated = nominated | (
-                (ch_idx >= 0) & flow_ready
-                & (dscores >= ch_best[jnp.clip(ch_idx, 0)]) & (dscores > 0))
+        nominated = nominate(dscores, flow_ready)
         shortest_comm = jnp.where(
             any_nonflow, 0.0,
             jnp.min(jnp.where(nominated, rem_dep, BIG)))
@@ -420,7 +528,7 @@ def jax_lookahead(op_remaining, op_valid, op_worker, op_score, num_parents,
 
         # 6. non-mutual completed deps advance their child's parent count
         inc = (dep_now_done & ~dep_mutual).astype(jnp.int32)
-        parent_done2 = parent_done.at[dep_dst].add(inc)
+        parent_done2 = count_parents(parent_done, inc)
 
         ticked_ops = jnp.any(sel_ops)
         ticked_flows = (~any_nonflow) & jnp.any(flow_ready)

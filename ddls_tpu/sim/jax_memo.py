@@ -103,8 +103,8 @@ class MemoConfig:
     """Table geometry: ``n_sets`` x ``n_ways`` entries, round-robin way
     eviction per set. The default 64x2 holds 128 keys — comfortably
     above the distinct (model, degree, grouping, times) population of a
-    steady-state canonical episode, at ~13 MB of key residuals for the
-    degree-16 pads in f64 (N=480 groups + M=13072 times per entry)."""
+    steady-state canonical episode, at ~14 MB of key residuals for the
+    degree-16 pads in f64 (N=480 groups + M=13312 times per entry)."""
     n_sets: int = 64
     n_ways: int = 2
 

@@ -1205,7 +1205,7 @@ class RLEpochLoop:
         for ep in fetched:
             episodes.extend(harvester.harvest_episodes(ep))
             if telemetry.enabled():
-                record_lookahead_trips(ep)
+                record_lookahead_trips(ep, harvester.et.pads)
         return episodes
 
     def _run_fused(self) -> Dict[str, Any]:

@@ -319,7 +319,8 @@ class _EtStub:
 
         self.pads = ConfigPads(n_ops=4, n_deps=4, n_fwd=2, n_parents=1,
                                max_split=2, n_groups=1, group_edges=1,
-                               n_sync=1, n_o2o=1)
+                               n_sync=1, n_o2o=1, n_orig=2, n_blocks=1,
+                               n_deps_used=4)
         self.n_srv = 8
         self.n_chan = 1
         self.types = ["a"]

@@ -151,3 +151,263 @@ def test_cluster_opt_in_backend_matches_host(dataset_dir):
         pytest.approx(
             host["jobs_completed_mean_mounted_worker_utilisation_frac"],
             rel=1e-4))
+
+
+# ---------------------------------------------------------------------------
+# The block path (DepBlocks: broadcast + reduction over the partitioner's
+# (block, i, j) layout) against the flat path (one gather/scatter per dep)
+# on the in-kernel env's own tables: the same bits on all six outputs.
+# ---------------------------------------------------------------------------
+
+#: degree columns of the small build; with ``_BLOCK_QUANTUM`` the
+#: degree-16 row of ``translation_0`` splits its ops 16/16/4/16/12/4 ways
+#: (16x4 and 4x16 blocks), degree 1 gives 1x1 blocks
+_BLOCK_DEGREES = [1, 2, 4, 6, 8, 10, 12, 14, 16]
+_BLOCK_MODELS = ["cnn_0", "translation_0"]
+_BLOCK_QUANTUM = 0.25
+_BLOCK_ROWS = [(m, d) for m in _BLOCK_MODELS for d in _BLOCK_DEGREES]
+
+
+def _ramp_env(dataset_dir, shape, max_partitions, **jobs):
+    c, r, s = shape
+    return RampJobPartitioningEnvironment(
+        topology_config={"type": "ramp", "kwargs": {
+            "num_communication_groups": c,
+            "num_racks_per_communication_group": r,
+            "num_servers_per_rack": s,
+            "num_channels": 1,
+            "total_node_bandwidth": 1.6e12,
+            "intra_gpu_propagation_latency": 50e-9,
+            "worker_io_latency": 100e-9}},
+        node_config={"type_1": {"num_nodes": c * r * s, "workers_config": [
+            {"num_workers": 1, "worker": "A100"}]}},
+        jobs_config={
+            "path_to_files": dataset_dir,
+            "job_interarrival_time_dist": {
+                "_target_": "ddls_tpu.demands.distributions.Fixed",
+                "val": 100.0},
+            "replication_factor": 2,
+            "job_sampling_mode": "remove_and_repeat",
+            "num_training_steps": 3, **jobs},
+        max_partitions_per_op=max_partitions,
+        reward_function="job_acceptance",
+        max_simulation_run_time=1e5,
+        pad_obs_kwargs={"max_nodes": 150, "max_edges": 512})
+
+
+class _BlockBuild:
+    """Episode tables of one env + jitted (place, price) -> lookahead
+    arguments for a (cfg row, cluster state), and both lookahead paths."""
+
+    def __init__(self, env, quantum=None):
+        import jax
+        import jax.numpy as jnp
+
+        from ddls_tpu.sim import jax_env as je
+        from ddls_tpu.sim.jax_lookahead import DepBlocks, jax_lookahead
+
+        env.reset(seed=0)
+        self.et = et = je.build_episode_tables(env, quantum=quantum)
+        tb = et.tables
+
+        def arguments(cfg, other_free):
+            mem = jnp.full((et.n_srv,), et.worker_mem, tb["dep_size"].dtype)
+            ots, _, ok = je.jax_allocate_job(mem, other_free, cfg, tb,
+                                             et.st, et.pads)
+            times, is_flow, chan, op_score, dep_score, _ = \
+                je.jax_price_and_score(ots, cfg, tb, et.st, et.pads,
+                                       et.comm, et.pair_channel)
+            ov = tb["op_valid"][cfg]
+            return ((tb["op_compute"][cfg], ov, jnp.where(ov, ots, -1),
+                     op_score, tb["num_parents"][cfg], times,
+                     tb["dep_valid"][cfg], tb["dep_src"][cfg],
+                     tb["dep_dst"][cfg], tb["dep_mutual"][cfg], is_flow,
+                     dep_score, chan[:, None]),
+                    DepBlocks(tb["blk_src"][cfg], tb["blk_dst"][cfg]), ok)
+
+        def flat(args, blocks, skip=None):
+            del blocks
+            return jax_lookahead(*args, num_workers=et.n_srv,
+                                 num_channels=et.n_chan, skip=skip)
+
+        def block(args, blocks, skip=None):
+            return jax_lookahead(*args, num_workers=et.n_srv,
+                                 num_channels=et.n_chan, skip=skip,
+                                 blocks=blocks)
+
+        self.flat_fn, self.block_fn = flat, block
+        self.arguments = jax.jit(arguments)
+        self.flat, self.block = jax.jit(flat), jax.jit(block)
+        # two cluster states: empty, and one where every fourth server
+        # is another job's (placements land elsewhere; the widest rows
+        # cannot place at all, and BOTH paths then tick the same garbage)
+        self.states = [jnp.ones((et.n_srv,), bool),
+                       jnp.arange(et.n_srv) % 4 != 1]
+
+    def row(self, model, degree):
+        return (self.et.types.index(model) * len(self.et.degrees)
+                + self.et.degrees.index(degree))
+
+
+@pytest.fixture(scope="module")
+def block_build(tmp_path_factory):
+    from ddls_tpu.graphs.synthetic import generate_pipedream_txt_files
+
+    out = tmp_path_factory.mktemp("block_graphs")
+    generate_pipedream_txt_files(str(out), n_cnn=1, n_translation=1,
+                                 seed=31, min_ops=4, max_ops=5)
+    build = _BlockBuild(_ramp_env(str(out), (2, 2, 4), 16),
+                        quantum=_BLOCK_QUANTUM)
+    assert build.et.types == _BLOCK_MODELS
+    assert build.et.degrees == _BLOCK_DEGREES
+    return build
+
+
+def _assert_same_bits(got, want, what):
+    names = ("t", "comm_oh", "comp_oh", "busy", "ok", "trips")
+    for name, g, w in zip(names, got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and (g == w).all(), (what, name, g, w)
+
+
+@pytest.mark.parametrize("model,degree", _BLOCK_ROWS,
+                         ids=[f"{m}-{d}" for m, d in _BLOCK_ROWS])
+def test_block_path_is_flat_path(block_build, model, degree):
+    """Every (model, degree) row, placed on each cluster state: block
+    path == flat path with ``==`` on all six outputs."""
+    cfg = block_build.row(model, degree)
+    ran = 0
+    for state in block_build.states:
+        args, blocks, placed = block_build.arguments(cfg, state)
+        want = block_build.flat(args, blocks)
+        got = block_build.block(args, blocks)
+        _assert_same_bits(got, want, (model, degree, bool(placed)))
+        ran += int(want[5])
+        if bool(placed):
+            assert bool(want[4]), "a placed job's lookahead converges"
+    assert ran > 0
+    if (model, degree) == ("translation_0", 16):
+        splits = sorted(set(np.asarray(
+            block_build.et.tables["f_split"][cfg]).tolist()))
+        assert splits == [4, 12, 16], splits   # the uneven row
+
+
+@pytest.mark.parametrize("skip_every", [0, 3])
+def test_block_path_is_flat_path_vmapped(block_build, skip_every):
+    """Lanes of DIFFERENT rows under one vmap (the fused epoch's shape),
+    with and without a ``skip`` mask: each lane's six outputs equal the
+    flat path's, skipped lanes (0 trips, init accumulators) included."""
+    import jax
+    import jax.numpy as jnp
+
+    lanes = [(block_build.row(m, d), s) for m, d in _BLOCK_ROWS
+             for s in range(len(block_build.states))]
+    cfgs = jnp.asarray([c for c, _ in lanes], jnp.int32)
+    states = jnp.stack([block_build.states[s] for _, s in lanes])
+    args, blocks, _ = jax.vmap(block_build.arguments)(cfgs, states)
+    skip = (jnp.arange(len(lanes)) % skip_every == 1 if skip_every
+            else jnp.zeros(len(lanes), bool))
+    want = jax.jit(jax.vmap(block_build.flat_fn))(args, blocks, skip)
+    got = jax.jit(jax.vmap(block_build.block_fn))(args, blocks, skip)
+    _assert_same_bits(got, want, ("vmap", skip_every))
+    trips = np.asarray(want[5])
+    assert (trips[np.asarray(skip)] == 0).all()
+    assert (trips[~np.asarray(skip)] > 0).all()
+    assert len(set(trips.tolist())) > 4    # lanes really differ
+
+
+def _sub_jaxprs(eqn):
+    """(param name, jaxpr) of every jaxpr an equation carries (closed or
+    open, alone or in a tuple as ``cond``'s branches are)."""
+    for name, value in eqn.params.items():
+        for sub in (value if isinstance(value, (tuple, list)) else (value,)):
+            inner = getattr(sub, "jaxpr", sub)
+            if hasattr(inner, "eqns"):
+                yield name, inner
+
+
+def _while_bodies(jaxpr):
+    """Every ``while`` body jaxpr reachable from ``jaxpr``."""
+    for eqn in jaxpr.eqns:
+        for name, inner in _sub_jaxprs(eqn):
+            if eqn.primitive.name == "while" and name == "body_jaxpr":
+                yield inner
+            yield from _while_bodies(inner)
+
+
+def _per_dep_indexing(jaxpr, n_deps):
+    """Names of the gather/scatter equations in ``jaxpr`` (nested calls
+    included) that touch >= ``n_deps`` elements: an operand or an index
+    array that large is one address computation per dep."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather" or \
+                eqn.primitive.name.startswith("scatter"):
+            sizes = [int(np.prod(v.aval.shape)) for v in eqn.invars
+                     if hasattr(v.aval, "shape")]
+            if max(sizes) >= n_deps:
+                found.append(eqn.primitive.name)
+        for _, inner in _sub_jaxprs(eqn):
+            found += _per_dep_indexing(inner, n_deps)
+    return found
+
+
+def _lookahead_body(closed_jaxpr, n_deps):
+    """The lookahead's tick body: the ``while`` whose carry holds the
+    per-dep remaining times and done flags ([n_deps] f32 and bool)."""
+    bodies = [b for b in _while_bodies(closed_jaxpr.jaxpr)
+              if sum(v.aval.shape == (n_deps,) for v in b.invars) >= 2]
+    assert len(bodies) == 1, len(bodies)
+    return bodies[0]
+
+
+def test_env_lookahead_body_indexes_no_dep(block_build):
+    """The engagement pin: the tick body the in-kernel env traces (from
+    `make_episode_fn`) holds NO gather/scatter of ``pads.n_deps``
+    elements; the same walk over the flat path finds the four that were
+    95 % of the fused epoch (source gather, channel scatter-max and
+    read-back, parent-count scatter-add)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddls_tpu.sim import jax_env as je
+
+    et = block_build.et
+    M = et.pads.n_deps
+    bank = je.build_job_bank(et, [
+        {"model": m, "num_training_steps": 3, "sla_frac": 1.0,
+         "time_arrived": 100.0 * i} for i, m in enumerate(_BLOCK_MODELS)])
+    bank = {k: jnp.asarray(v) for k, v in bank.items()}
+    episode = je.make_episode_fn(et)
+    traced = jax.make_jaxpr(episode)(bank, jnp.asarray([16, 4], jnp.int32))
+    assert _per_dep_indexing(_lookahead_body(traced, M), M) == []
+
+    args, blocks, _ = block_build.arguments(0, block_build.states[0])
+    flat = jax.make_jaxpr(block_build.flat_fn)(args, blocks)
+    found = sorted(n.replace("_", "-")
+                   for n in _per_dep_indexing(_lookahead_body(flat, M), M))
+    assert found == ["gather", "gather", "scatter-add", "scatter-max"]
+
+
+def test_block_path_is_flat_path_at_the_benchmark_pads(tmp_path):
+    """The benchmark's own pads (480 op slots x 52 blocks x 16 x 16 =
+    13,312 dep slots: the shipped env_dev dataset on RAMP 4x4x2 at
+    degree 16) and the row whose lookahead is the fused cells' lockstep
+    maximum: cnn_1 at degree 8 on an empty cluster runs 152 trips."""
+    import shutil
+
+    from ddls_tpu.graphs.synthetic import generate_pipedream_txt_files
+
+    full, only = tmp_path / "all", tmp_path / "cnn_1"
+    full.mkdir(), only.mkdir()
+    generate_pipedream_txt_files(str(full), n_cnn=3, n_translation=2,
+                                 seed=0, min_ops=8, max_ops=16)
+    shutil.copy(full / "cnn_1.txt", only / "cnn_1.txt")
+    build = _BlockBuild(_ramp_env(str(only), (4, 4, 2), 16))
+    pads = build.et.pads
+    assert (pads.n_ops, pads.n_deps, pads.n_deps_used) == (480, 13312, 13072)
+    args, blocks, placed = build.arguments(build.row("cnn_1", 8),
+                                           build.states[0])
+    want = build.flat(args, blocks)
+    _assert_same_bits(build.block(args, blocks), want, "cnn_1-8")
+    assert bool(placed) and bool(want[4]) and int(want[5]) == 152

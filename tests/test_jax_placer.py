@@ -16,7 +16,8 @@ from ddls_tpu.agents.partitioners import build_partition_action
 from ddls_tpu.agents.placers import allocate_job
 from ddls_tpu.graphs.readers import read_graph_file
 from ddls_tpu.sim.jax_env import (build_shape_tables, config_tables_for,
-                                  jax_allocate_job, stack_config_tables)
+                                  jax_allocate_job, stack_config_tables,
+                                  table_slots)
 
 
 def _write_profile(path, n_fwd, rng):
@@ -62,7 +63,9 @@ def setup(request):
             cfg_meta.append((gi, dg))
     tables, pads = stack_config_tables(cfgs, st)
     jtables = {k: jnp.asarray(v) for k, v in tables.items()}
-    return ramp_shape, graphs, st, jtables, pads, cfg_meta
+    # the tables are in block order: host op index -> op slot, per row
+    op_slots = [table_slots(c, pads.max_split)[0] for c in cfgs]
+    return ramp_shape, graphs, st, jtables, pads, cfg_meta, op_slots
 
 
 def _random_state(rng, ramp_shape, occupancy_p):
@@ -82,7 +85,7 @@ def _random_state(rng, ramp_shape, occupancy_p):
 
 
 def test_full_job_parity_randomized(setup):
-    ramp_shape, graphs, st, jtables, pads, cfg_meta = setup
+    ramp_shape, graphs, st, jtables, pads, cfg_meta, op_slots = setup
     import jax
 
     fn = jax.jit(lambda mem, free, cfg: jax_allocate_job(
@@ -128,17 +131,18 @@ def test_full_job_parity_randomized(setup):
         assert len(host) == pgraph.n_ops
         for op_id, coord in host.items():
             code = (coord[0] * R + coord[1]) * S + coord[2]
-            assert ots[op_index[op_id]] == code, (
-                trial, cfg_meta[cfg], op_id, coord, ots[op_index[op_id]])
-        # all padded slots beyond the real ops stay unassigned
-        assert (ots[pgraph.n_ops:] == -1).all()
+            slot = op_slots[cfg][op_index[op_id]]
+            assert ots[slot] == code, (
+                trial, cfg_meta[cfg], op_id, coord, ots[slot])
+        # every slot no real op sits on stays unassigned
+        assert (np.delete(ots, op_slots[cfg]) == -1).all()
     assert n_checked_placed >= 8
 
 
 def test_memory_accounting_matches_host(setup):
     """New free-memory grid equals the host's mutated snapshot after a
     successful allocation (placement deducts fwd+bwd pair memory)."""
-    ramp_shape, graphs, st, jtables, pads, cfg_meta = setup
+    ramp_shape, graphs, st, jtables, pads, cfg_meta, _ = setup
     import jax
 
     fn = jax.jit(lambda mem, free, cfg: jax_allocate_job(

@@ -137,22 +137,51 @@ def test_a_discarded_lane_runs_no_trips(memo_env):
     assert la.tolist() == trips.tolist()
 
 
+#: the degree-16 pads of the shipped dataset (tests/test_jax_pricing.py
+#: counts them from the tables)
+_BENCH_PADS = dict(n_ops=480, n_deps=13312, n_fwd=15, n_parents=2,
+                   max_split=16, n_groups=1, group_edges=1, n_sync=1,
+                   n_o2o=1, n_orig=30, n_blocks=52, n_deps_used=13072)
+
+
 def test_trip_counters_are_the_hosts_reduction_of_the_trace():
     from ddls_tpu.rl.fused import record_lookahead_trips
+    from ddls_tpu.sim.jax_env import ConfigPads
 
     # [U=1, B=3, T=2]: step 0 — lanes ran 5 and 9, one ran none (a hit
     # or an action without a lookahead); step 1 — a miss of 7 only
     ep = {"la_trips": np.array([[[5, 7], [9, 0], [0, 0]]], np.int32)}
     telemetry.enable()
-    record_lookahead_trips(ep)
+    record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS))
     snap = telemetry.snapshot()
     assert {k: v for k, v in snap["counters"].items()
             if k.startswith("sim.lookahead.")} == {
         "sim.lookahead.calls": 3, "sim.lookahead.trips": 21,
         "sim.lookahead.lockstep_trips": 16,
-        "sim.lookahead.lockstep_lane_trips": 48}
+        "sim.lookahead.lockstep_lane_trips": 48,
+        "sim.lookahead.dep_slots": 13312,
+        "sim.lookahead.dep_slots_used": 13072}
     hist = snap["histograms"]["sim.lookahead.trips_per_call"]
     assert hist["count"] == 3 and hist["max"] == 9.0
+
+
+def test_block_fill_metric_reads_the_dep_slot_counters():
+    """The benchmark's ``lookahead_block_fill`` is the ratio of the two
+    dep-slot counters the trip drain adds per epoch trace, and reads
+    nothing (never raises) from a program that has none."""
+    from benchmarks import harness
+    from ddls_tpu.rl.fused import record_lookahead_trips
+    from ddls_tpu.sim.jax_env import ConfigPads
+
+    ep = {"la_trips": np.array([[[5, 7], [9, 0], [0, 0]]], np.int32)}
+    ctx = {"spans": {"bench": {"epoch": [(0.0, 1.0), (1.0, 2.0)]}}}
+    telemetry.enable()
+    assert harness.read_layer_metric("lookahead_block_fill", ctx) is None
+    for _ in range(2):
+        record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS))
+    assert harness.read_layer_metric("lookahead_dep_slots", ctx) == 13312
+    assert harness.read_layer_metric("lookahead_block_fill", ctx) == \
+        pytest.approx(100 * 13072 / 13312)
 
 
 def test_fused_loop_counts_trips_only_while_telemetry_is_on(
@@ -173,6 +202,12 @@ def test_fused_loop_counts_trips_only_while_telemetry_is_on(
         assert (counters["sim.lookahead.lockstep_trips"]
                 <= counters["sim.lookahead.trips"]
                 <= counters["sim.lookahead.lockstep_lane_trips"])
+        # one drained epoch trace: the tables' dep slots, once
+        pads = loop.fused.et.pads
+        assert counters["sim.lookahead.dep_slots"] == pads.n_deps \
+            == pads.n_blocks * pads.max_split ** 2
+        assert 0 < counters["sim.lookahead.dep_slots_used"] \
+            == pads.n_deps_used <= pads.n_deps
         # memo counters keep their own path
         assert counters["event.memo_counters"] >= 1
     finally:
