@@ -12,6 +12,9 @@ Additions over the reference:
 
 * ``synthetic`` config generates PipeDream-format profiles on the fly (the
   reference's datasets are not distributed with it);
+* ``architecture`` config (``{config: <file>, shapes: [{seq_len,
+  micro_batch}, ...]}``) writes one analytic profile per shape of a public
+  transformer architecture (``graphs/arch.py``), one model name per shape;
 * dataset-wide min/max stats for observation normalisation are identical in
   structure (reference: jobs_generator.py:276-333), including the
   fully-connected worst-case bound on partitioned dep totals.
@@ -29,8 +32,10 @@ import numpy as np
 
 from ddls_tpu.demands.distributions import Distribution, make_distribution
 from ddls_tpu.demands.job import Job, compute_immutable_details
+from ddls_tpu.graphs import arch
 from ddls_tpu.graphs.readers import read_graph_file
 from ddls_tpu.graphs.synthetic import generate_pipedream_txt_files
+from ddls_tpu.telemetry import startup
 
 
 class JobSampler:
@@ -103,10 +108,13 @@ class JobsGenerator:
                  num_training_steps: int = 1,
                  max_partitions_per_op_in_observation: int = 1,
                  synthetic: Optional[dict] = None,
+                 architecture: Optional[dict] = None,
                  device_type: str = "A100",
                  **kwargs):
-        if path_to_files is None and synthetic is None:
-            raise ValueError("need path_to_files or a synthetic config")
+        if path_to_files is None and synthetic is None \
+                and architecture is None:
+            raise ValueError("need path_to_files, a synthetic config or an "
+                             "architecture config")
         if job_interarrival_time_dist is None:
             raise ValueError(
                 "job_interarrival_time_dist is required (pass a Distribution "
@@ -114,50 +122,11 @@ class JobsGenerator:
         self.num_training_steps = num_training_steps
         self.device_type = device_type
         self.max_files = max_files
-        generated_paths = None
-        if synthetic is not None:
-            out_dir = synthetic.get("out_dir") or tempfile.mkdtemp(
-                prefix="ddls_tpu_jobs_")
-            kw = {k: v for k, v in synthetic.items() if k != "out_dir"}
-            # use exactly the files generated this run (a reused out_dir may
-            # hold stale profiles from a previous, differently-sized config)
-            generated_paths = generate_pipedream_txt_files(out_dir, **kw)
-            path_to_files = out_dir
-        self.path_to_files = path_to_files
-
-        file_paths = (sorted(generated_paths) if generated_paths is not None
-                      else discover_profile_files(path_to_files))
-        if not file_paths:
-            raise FileNotFoundError(
-                f"no .txt/.pbtxt graph profiles under {path_to_files}")
-        if max_files is not None:
-            file_paths = file_paths[:max_files]
-        # workload fingerprint for the cluster's memo-cache validity check:
-        # synthetic datasets are deterministic per config (seeded), so the
-        # config content identifies them regardless of the tmpdir they were
-        # written to; on-disk datasets fingerprint exactly the files loaded
-        # (post-max_files truncation), statted+digested at load time (not at
-        # reset time — the files could change on disk after this generator
-        # read them)
-        if synthetic is not None:
-            dataset_id = ("synthetic", repr(sorted(synthetic.items())))
-        else:
-            stats = []
-            for f in file_paths:
-                st = os.stat(f)
-                # content digest of head+tail bytes makes the check
-                # content-true: an in-place edit that preserves mtime and
-                # size (some sync tools, archive extraction) still changes
-                # the fingerprint and invalidates stale memo caches
-                with open(f, "rb") as fh:
-                    head = fh.read(4096)
-                    if st.st_size > 8192:
-                        fh.seek(-4096, os.SEEK_END)
-                    tail = fh.read(4096)
-                digest = hashlib.sha1(head + tail).hexdigest()
-                stats.append((os.path.basename(f), st.st_mtime_ns,
-                              st.st_size, digest))
-            dataset_id = ("files", path_to_files, tuple(stats))
+        # config -> profiles -> OpGraphs: set-up (an env is built once per
+        # run or per evaluation, never per step)
+        with startup.span("startup.job_graphs"):
+            graphs, dataset_id = self._load_graphs(
+                path_to_files, synthetic, architecture)
         self.workload_fingerprint = (dataset_id, num_training_steps,
                                      device_type, max_files)
 
@@ -172,7 +141,6 @@ class JobsGenerator:
             frac_dist = sampled
         self.frac_dist = frac_dist
 
-        graphs = [read_graph_file(p, device_type=device_type) for p in file_paths]
         model_to_immutable = {}
         prototypes: List[Job] = []
         for _ in range(replication_factor):
@@ -194,6 +162,73 @@ class JobsGenerator:
             max_partitions_per_op_in_observation)
         self.jobs_params = self._init_jobs_params(
             prototypes, max_partitions_per_op_in_observation)
+
+    def _load_graphs(self, path_to_files, synthetic, architecture):
+        """Write the profiles a ``synthetic`` or ``architecture`` config
+        asks for, read every profile into an ``OpGraph`` and fingerprint
+        the dataset; sets ``self.path_to_files``. Returns
+        (graphs, dataset_id)."""
+        generated_paths = None
+        if synthetic is not None:
+            path_to_files = synthetic.get("out_dir") or tempfile.mkdtemp(
+                prefix="ddls_tpu_jobs_")
+            kw = {k: v for k, v in synthetic.items() if k != "out_dir"}
+            # use exactly the files generated this run (a reused out_dir may
+            # hold stale profiles from a previous, differently-sized config)
+            generated_paths = generate_pipedream_txt_files(path_to_files,
+                                                           **kw)
+        elif architecture is not None:
+            arch_config = arch.load_arch_config(architecture["config"])
+            path_to_files = tempfile.mkdtemp(prefix="ddls_tpu_jobs_")
+            generated_paths = arch.write_profiles(
+                path_to_files, arch_config, architecture["shapes"])
+        self.path_to_files = path_to_files
+
+        file_paths = (sorted(generated_paths) if generated_paths is not None
+                      else discover_profile_files(path_to_files))
+        if not file_paths:
+            raise FileNotFoundError(
+                f"no .txt/.pbtxt graph profiles under {path_to_files}")
+        if self.max_files is not None:
+            file_paths = file_paths[:self.max_files]
+        # workload fingerprint for the cluster's memo-cache validity check:
+        # generated datasets are deterministic per config (seeded or
+        # analytic), so the config content identifies them regardless of
+        # the tmpdir they were written to; on-disk datasets fingerprint
+        # exactly the files loaded (post-max_files truncation),
+        # statted+digested at load time (not at reset time — the files
+        # could change on disk after this generator read them)
+        if synthetic is not None:
+            dataset_id = ("synthetic", repr(sorted(synthetic.items())))
+        elif architecture is not None:
+            dataset_id = arch.dataset_id(arch_config, architecture["shapes"])
+        else:
+            stats = []
+            for f in file_paths:
+                st = os.stat(f)
+                # content digest of head+tail bytes makes the check
+                # content-true: an in-place edit that preserves mtime and
+                # size (some sync tools, archive extraction) still changes
+                # the fingerprint and invalidates stale memo caches
+                with open(f, "rb") as fh:
+                    head = fh.read(4096)
+                    if st.st_size > 8192:
+                        fh.seek(-4096, os.SEEK_END)
+                    tail = fh.read(4096)
+                digest = hashlib.sha1(head + tail).hexdigest()
+                stats.append((os.path.basename(f), st.st_mtime_ns,
+                              st.st_size, digest))
+            dataset_id = ("files", path_to_files, tuple(stats))
+
+        graphs = [read_graph_file(p, device_type=self.device_type)
+                  for p in file_paths]
+        if architecture is not None:
+            for g in graphs:
+                model = g.meta["model"]
+                startup.set_gauge(f"graphs.arch.forward_ops.{model}",
+                                  len(g.forward_op_ids()))
+                startup.set_gauge(f"graphs.arch.edges.{model}", g.n_deps)
+        return graphs, dataset_id
 
     def __len__(self) -> int:
         return len(self.sampler)

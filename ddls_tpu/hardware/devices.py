@@ -123,6 +123,10 @@ class A100(Processor):
 
     device_type = "A100"
     memory_capacity = int(80e9)
+    #: roofline peaks of the worker: what ``sim/comm_model.py`` prices a
+    #: collective's parallel add with and ``graphs/arch.py`` an op's time
+    peak_flops = 130e12
+    memory_bandwidth = 2e12
 
 
 class TPUv4(Processor):

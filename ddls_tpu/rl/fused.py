@@ -377,11 +377,15 @@ def stacked_job_banks(et, env, n_lanes: int, n_jobs: int,
 #: the compact trace keys the fused program returns per decision step
 #: (the rest of the segment trace — obs fields, actions — stays INSIDE
 #: the program; only these [U, B, T] scalars ever leave): the episode
-#: counters ``harvest_episodes`` reads, and the lookahead trip count
+#: counters ``harvest_episodes`` reads, the lookahead trip count
 #: (``make_segment_fn(trace_trips=True)``) that
-#: ``record_lookahead_trips`` reads while telemetry is on
+#: ``record_lookahead_trips`` reads while telemetry is on, and the
+#: decision's job type and action, from which ``record_padding_fill``
+#: finds the (model, degree) row each decision ran. ``la_trips``,
+#: ``jtype`` and ``action`` are read only while telemetry is on; if the
+#: drain ever shows in ``device_idle_share``, gate the three together
 EPISODE_TRACE_KEYS = ("done", "ep_return", "ep_blocked", "ep_completed",
-                      "ep_arrived", "la_trips")
+                      "ep_arrived", "la_trips", "jtype", "action")
 
 #: ``sim.lookahead.trips_per_call`` buckets: the loop is bounded by
 #: ops + deps + 4 trips (13,556 at the degree-16 pads)
@@ -415,6 +419,35 @@ def record_lookahead_trips(ep_trace, pads) -> None:
                           buckets=_TRIP_BUCKETS)
     telemetry.inc("sim.lookahead.dep_slots", int(pads.n_deps))
     telemetry.inc("sim.lookahead.dep_slots_used", int(pads.n_deps_used))
+
+
+def record_padding_fill(ep_trace, et, ot) -> None:
+    """What padding cost the decisions that RAN, from a FETCHED
+    ``[..., B, T]`` trace and the tables it ran on. Over the lane-steps
+    whose lookahead looped (``la_trips`` > 0):
+    ``sim.lookahead.dep_slots_decided`` — the real deps of each
+    decision's own (model, degree) row, summed — beside
+    ``sim.lookahead.dep_slots_offered`` — those decisions x the dep
+    slots every trip passes over (``pads.n_deps``). Over every step:
+    ``env.obs.nodes_real`` — the queued job's graph nodes — beside
+    ``env.obs.nodes_padded`` — steps x the observation's node pad, the
+    GNN's padded work. The caller gates on ``telemetry.enabled()``."""
+    import jax
+
+    jtype = np.asarray(ep_trace["jtype"])
+    ran = np.asarray(ep_trace["la_trips"]) > 0
+    column = np.zeros(et.max_action + 1, np.int64)
+    column[et.degrees] = np.arange(len(et.degrees))
+    row = jtype[ran] * len(et.degrees) \
+        + column[np.asarray(ep_trace["action"])[ran]]
+    telemetry.inc("sim.lookahead.dep_slots_decided",
+                  int(jax.device_get(et.tables["n_deps"])[row].sum()))
+    telemetry.inc("sim.lookahead.dep_slots_offered",
+                  int(ran.sum()) * int(et.pads.n_deps))
+    telemetry.inc("env.obs.nodes_real",
+                  int(ot["node_split"][:, 0][jtype].sum()))
+    telemetry.inc("env.obs.nodes_padded",
+                  jtype.size * int(ot["node_features"].shape[1]))
 
 
 class FusedEpochDriver:

@@ -30,6 +30,8 @@ from typing import Sequence
 
 import numpy as np
 
+from ddls_tpu.hardware.devices import A100
+
 
 def effective_transceivers(cg: int, d: float, J: int = 1) -> float:
     """Usable transceivers per communicator for a subgroup of ``d`` devices in
@@ -43,8 +45,8 @@ def effective_transceivers(cg: int, d: float, J: int = 1) -> float:
 
 def parallel_add_time(data_sz: float,
                       devices: float,
-                      mem_frequency: float = 2e12,
-                      peak_flops: float = 130e12,
+                      mem_frequency: float = A100.memory_bandwidth,
+                      peak_flops: float = A100.peak_flops,
                       bytes_per_comp: int = 2) -> float:
     """Roofline estimate of the parallel-add compute inside a collective
     (reference: actions/utils.py:108-117)."""
@@ -63,8 +65,8 @@ def ramp_all_reduce_time(message_size: float,
                          network_comm_groups: int = 32,
                          data_rate: float = 1.6e12,
                          contending_racks: int = 1,
-                         mem_frequency: float = 2e12,
-                         peak_flops: float = 130e12,
+                         mem_frequency: float = A100.memory_bandwidth,
+                         peak_flops: float = A100.peak_flops,
                          bytes_per_comp: int = 2,
                          propagation_latency: float = 1.25e-6,
                          io_latency: float = 100e-9) -> float:

@@ -19,8 +19,12 @@ phases themselves. Inner jits report inside their outer program's
 trace, so a reader takes the UNION of a name's intervals
 (:func:`summary`), never their sum.
 
+Sizes that set-up fixes once (``set_gauge``: the job graphs' op and
+edge counts) are gauges of the same registry.
+
 ``report()`` is the registry's reader: one ``[startup] {...}`` line of
-seconds per span name, printed once when the first fused epoch ends.
+seconds per span name, then the gauges under their own names, printed
+once when the first fused epoch ends.
 """
 from __future__ import annotations
 
@@ -129,6 +133,15 @@ def span_since_process_start(name: str) -> None:
     _RECORDER.span_since_process_start(name)
 
 
+def set_gauge(name: str, value: float) -> None:
+    """A size fixed during set-up, read beside the spans."""
+    _RECORDER.registry.gauge(name).set(value)
+
+
+def gauges() -> Dict[str, float]:
+    return dict(_RECORDER.registry.snapshot().get("gauges", {}))
+
+
 def summary() -> Dict[str, float]:
     """Seconds under each start-up span name, in order of first
     completion: the union of the name's intervals."""
@@ -138,5 +151,5 @@ def summary() -> Dict[str, float]:
 def report() -> str:
     """The one line an operator (and a benchmark run's log) gets."""
     return "[startup] " + json.dumps(
-        {name.removeprefix("startup."): round(seconds, 3)
-         for name, seconds in summary().items()})
+        {**{name.removeprefix("startup."): round(seconds, 3)
+            for name, seconds in summary().items()}, **gauges()})

@@ -1192,7 +1192,8 @@ class RLEpochLoop:
             return []
         import jax
 
-        from ddls_tpu.rl.fused import record_lookahead_trips
+        from ddls_tpu.rl.fused import (record_lookahead_trips,
+                                       record_padding_fill)
 
         harvester = (self.fused if self.fused is not None
                      else self.collector)
@@ -1206,6 +1207,7 @@ class RLEpochLoop:
             episodes.extend(harvester.harvest_episodes(ep))
             if telemetry.enabled():
                 record_lookahead_trips(ep, harvester.et.pads)
+                record_padding_fill(ep, harvester.et, harvester.ot)
         return episodes
 
     def _run_fused(self) -> Dict[str, Any]:
