@@ -48,6 +48,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from ddls_tpu import telemetry
+from ddls_tpu.sim.jax_lookahead import MINOR_GAUGES
 from ddls_tpu.telemetry import scopes, startup
 
 AUTOTUNE_CACHE_FILE = "fused_autotune.json"
@@ -406,7 +407,12 @@ def record_lookahead_trips(ep_trace, pads) -> None:
     per drained epoch trace: ``dep_slots`` — the dep slots a trip
     passes over (blocks x split^2) — and ``dep_slots_used`` — the
     largest row's real deps; their ratio is what the block layout's
-    padding costs. The caller gates on ``telemetry.enabled()``."""
+    padding costs. And from the lookahead's own start-up gauges, set
+    when its batching rule ran in a trace
+    (`sim/jax_lookahead.py:_lane_batched_lookahead`; absent before):
+    ``minor_slots`` — the minor-axis extent of the dep state the loop
+    carries, in whole 128-wide registers — and ``minor_used`` — the
+    real slots of it. The caller gates on ``telemetry.enabled()``."""
     own = np.asarray(ep_trace["la_trips"])
     lockstep = int(own.max(axis=-2).sum())
     telemetry.inc("sim.lookahead.calls", int((own > 0).sum()))
@@ -419,6 +425,12 @@ def record_lookahead_trips(ep_trace, pads) -> None:
                           buckets=_TRIP_BUCKETS)
     telemetry.inc("sim.lookahead.dep_slots", int(pads.n_deps))
     telemetry.inc("sim.lookahead.dep_slots_used", int(pads.n_deps_used))
+    # the two gauges alone: ``startup.gauges()`` snapshots the whole
+    # start-up registry (thousands of jax spans), per drained epoch
+    for name in MINOR_GAUGES:
+        traced = startup.registry().gauge(name).value
+        if traced is not None:
+            telemetry.inc(name, int(traced))
 
 
 def record_padding_fill(ep_trace, et, ot) -> None:

@@ -328,6 +328,45 @@ class DepBlocks(NamedTuple):
     dst: object  # [B] i32 ... of its destination
 
 
+#: width of a TPU vector register: a minor axis shorter than this, or
+#: not a multiple of it, leaves lanes of every register empty
+REGISTER_WIDTH = 128
+
+#: start-up gauges the batching rule sets when it runs in a trace, and
+#: the counters the trip drain adds them to per epoch
+#: (rl/fused.py:record_lookahead_trips): the minor-axis extent of the
+#: dep state the loop carries, in whole registers, and its real slots
+MINOR_GAUGES = ("sim.lookahead.minor_slots", "sim.lookahead.minor_used")
+
+
+class _Layout(NamedTuple):
+    """What the tick body (:func:`_tick_loop`) leaves to the shape its
+    state is carried in: the three dep primitives (``src_done`` — has a
+    dep's source op completed; ``count_parents`` — add completed deps
+    onto their destination ops; ``nominate`` — is a ready flow dep the
+    best on its channel), the per-worker op selection, the reductions
+    of op or dep state to one value per lane, ``lanes`` (a per-lane
+    accumulator from a scalar), ``spread`` (a per-lane value, to what
+    broadcasts against op and dep state) and ``loop`` (``while_loop``
+    over a per-lane ``live``)."""
+    src_done: object
+    count_parents: object
+    nominate: object
+    select_ops: object
+    any: object
+    all: object
+    min: object
+    count: object
+    lanes: object
+    spread: object
+    loop: object
+
+
+def _block_side(n_deps: int, n_blocks: int) -> int:
+    """S of a (block, i, j) dep layout: ``n_deps`` = ``n_blocks`` * S * S."""
+    return int(round((n_deps // n_blocks) ** 0.5))
+
+
 def _flat_dep_ops(dep_src, dep_dst, dep_channel, num_channels):
     """The tick body's three dep primitives for an ARBITRARY graph: one
     gather or scatter per dep (the mounted-graph callers, and the
@@ -370,7 +409,7 @@ def _block_dep_ops(op_worker, blocks: DepBlocks, n_deps: int,
     import jax.numpy as jnp
 
     B = blocks.src.shape[0]
-    S = int(round((n_deps // B) ** 0.5))
+    S = _block_side(n_deps, B)
     N = op_worker.shape[0]
     if B * S * S != n_deps or N % S:
         raise ValueError(f"({N}, {n_deps}) is not a block layout of {B} "
@@ -417,6 +456,323 @@ def _block_dep_ops(op_worker, blocks: DepBlocks, n_deps: int,
     return src_done, count_parents, nominate
 
 
+def _job_layout(op_worker, num_workers: int, dep_ops) -> _Layout:
+    """ONE job: op state [N], dep state [E], scalar accumulators, the
+    dep primitives either builder above gives. Batched by ``jax.vmap``
+    it stays one job a lane, and XLA lays the lanes minor."""
+    import jax
+    import jax.numpy as jnp
+
+    worker_onehot = (jax.nn.one_hot(op_worker, num_workers, dtype=jnp.float32)
+                     .T)  # [W, N]; -1 (padding) one-hots to zeros
+
+    def select_ops(scores, ops_ready):
+        per_worker = worker_onehot * scores[None, :]  # [W, N]
+        best_score = per_worker.max(axis=1)           # [W]
+        has_op = best_score > 0
+        # an op is selected iff it is its worker's best ready op
+        return ops_ready & jnp.any(
+            (per_worker == best_score[:, None]) & (best_score[:, None] > 0)
+            & (worker_onehot > 0), axis=0)
+
+    return _Layout(*dep_ops, select_ops, jnp.any, jnp.all, jnp.min,
+                   jnp.sum, lanes=lambda x: x, spread=lambda x: x,
+                   loop=jax.lax.while_loop)
+
+
+def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
+                   num_workers: int) -> _Layout:
+    """L lanes of :class:`DepBlocks` tables, LANE-PACKED: op state is
+    [No, L*S] and dep state [B, S_i, L*S_j], the minor axis holding
+    (lane, shard) at ``lane*S + shard`` — the DESTINATION shard j for a
+    dep — so every select and reduction of a trip runs over L*S-wide
+    rows however few the lanes (16 shards x 32 lanes fill four vector
+    registers; 32 lanes alone a quarter of one). The lane is the MAJOR
+    part so that lanes sharded over devices stay sharded through the
+    merge (GSPMD splits a merged axis by its major factor only). A
+    per-lane value is [L], and repeated over the shards (``spread``)
+    where it meets either state. Nothing indexes per dep; integer
+    counts and max are order-free and every float op is elementwise per
+    lane, so each lane's bits are the flat form's. ``op_worker`` comes
+    packed; ``blocks`` holds [B, L] tables. Loop-invariant tables are
+    built here, outside the ``while_loop``."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+
+    L, W = n_lanes, num_workers
+    No, S = op_worker.shape[0], op_worker.shape[1] // L
+    B = blocks.src.shape[0]
+    rows = jnp.arange(No, dtype=jnp.int32)[:, None]        # [No, 1]
+    workers = jnp.arange(W, dtype=jnp.int32)[:, None]      # [W, 1]
+
+    def spread(x):
+        """[..., L] -> [..., L*S]: a lane's value on each of its slots."""
+        return jnp.repeat(x, S, axis=-1)
+
+    def over_shards(reduce, x):
+        """[..., L*S] -> [..., L]: each lane's slots, reduced."""
+        return reduce(x.reshape(x.shape[:-1] + (L, S)), axis=-1)
+
+    def over_lane(reduce):
+        """op or dep state -> [L]: all of a lane's slots, reduced."""
+        return lambda x: over_shards(
+            reduce, reduce(x, axis=tuple(range(x.ndim - 1))))
+
+    def from_source(x):
+        """[B, (l, i)] — a value of each block's source op shard — to
+        dep state [B, i, (l, j)]: the same for every destination j."""
+        return jnp.broadcast_to(
+            x.reshape(B, L, S).transpose(0, 2, 1)[..., None],
+            (B, S, L, S)).reshape(B, S, L * S)
+
+    # each block's endpoint rows, per (lane, shard) slot; an unplaced op
+    # (-1) rides server 0's channels exactly as the flat caller's
+    # clipped ``pair_channel`` lookup has it
+    src, dst = spread(blocks.src), spread(blocks.dst)      # [B, L*S]
+    worker = jnp.clip(op_worker, 0)
+
+    def endpoint_worker(row):
+        return jnp.max(jnp.where(row[:, None] == rows, worker, 0), axis=1)
+
+    w_src = from_source(endpoint_worker(src))              # [B, S_i, L*S]
+    w_dst = endpoint_worker(dst)                           # [B, L*S_j]
+
+    def src_done(op_done):
+        return from_source(jnp.any((src[:, None] == rows) & op_done, axis=1))
+
+    def count_parents(parent_done, inc):
+        into = inc.sum(axis=1, dtype=inc.dtype)            # [B, L*S_j]
+        return parent_done + jnp.sum(
+            jnp.where(dst == rows[:, None], into, 0), axis=1,
+            dtype=inc.dtype)
+
+    def nominate(dscores, flow_ready):
+        # best[X, Y] over the deps whose source sits on worker X and
+        # destination on Y: max over i into X, over b into Y, and last
+        # over the shards j — on [W, W, L*S], not on dep state. The
+        # one-hots are compared here, every trip: as loop invariants
+        # they would be W times the dep state, read twice a trip
+        on_src = w_src[:, :, None] == workers              # [B, S_i, W, L*S]
+        on_dst = w_dst[:, None, None] == workers           # [B, 1, W, L*S]
+        to_x = jnp.max(jnp.where(on_src, dscores[:, :, None], -1.0), axis=1)
+        best = spread(over_shards(jnp.max, jnp.max(
+            jnp.where(on_dst, to_x[:, :, None], -1.0), axis=0)))
+        # ... and back: each dep reads best[X(b, i), Y(b, j)]
+        of_y = jnp.max(jnp.where(on_dst, best, -1.0), axis=2)
+        mine = jnp.max(jnp.where(on_src, of_y[:, None], -1.0), axis=2)
+        return flow_ready & (dscores >= mine) & (dscores > 0)
+
+    def select_ops(scores, ops_ready):
+        mine = op_worker == workers[:, None]               # [W, No, L*S]
+        best = spread(over_shards(jnp.max, jnp.max(
+            jnp.where(mine, scores, 0.0), axis=1)))[:, None]
+        # an op is selected iff it is its worker's best ready op
+        return ops_ready & jnp.any(
+            mine & (scores == best) & (best > 0), axis=0)
+
+    def loop(live, tick, init):
+        # what jax's batching makes of ``while_loop``: run while any
+        # lane is live, and freeze the lanes that are not
+        def frozen_tick(state):
+            on = live(state)
+            on_slots = spread(on)
+            return jax.tree_util.tree_map(
+                lambda new, old: jnp.where(on if new.ndim == 1 else on_slots,
+                                           new, old), tick(state), state)
+
+        return jax.lax.while_loop(lambda state: jnp.any(live(state)),
+                                  frozen_tick, init)
+
+    return _Layout(src_done, count_parents, nominate, select_ops,
+                   over_lane(jnp.any), over_lane(jnp.all),
+                   over_lane(jnp.min),
+                   over_lane(partial(jnp.sum, dtype=jnp.int32)),
+                   lanes=lambda x: jnp.broadcast_to(x, (L,)), spread=spread,
+                   loop=loop)
+
+
+def _tick_loop(lay: _Layout, op_remaining, op_valid, op_score, num_parents,
+               dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
+               skip, max_iters: int):
+    """THE tick loop, in whatever shape ``lay`` carries its state;
+    returns (t, comm_oh, comp_oh, busy, ok, trips) per lane."""
+    import jax
+    import jax.numpy as jnp
+
+    # scalar accumulators follow the input dtype: f32 on the standard
+    # path, f64 when the caller runs under JAX_ENABLE_X64 (the jitted
+    # env-step parity mode, sim/jax_env.py)
+    dt = op_remaining.dtype
+
+    def cond(state):
+        (_, _, op_done, dep_done, _, _, _, _, _, it, stuck) = state
+        all_done = (lay.all(op_done | ~op_valid)
+                    & lay.all(dep_done | ~dep_valid))
+        live = (~all_done) & (it < max_iters) & (~stuck)
+        return live if skip is None else live & ~skip
+
+    def body(state):
+        (rem_op, rem_dep, op_done, dep_done, parent_done,
+         t, comm_oh, comp_oh, busy, it, stuck) = state
+
+        # 1. readiness (snapshotted BEFORE this tick's completions)
+        ops_ready = op_valid & ~op_done & (parent_done >= num_parents)
+        deps_ready = dep_valid & ~dep_done & lay.src_done(op_done)
+        flow_ready = deps_ready & dep_is_flow
+        nonflow_ready = deps_ready & ~dep_is_flow
+        any_nonflow = lay.any(nonflow_ready)
+
+        # 2. per-worker highest-score ready op
+        scores = jnp.where(ops_ready, op_score, -1.0)
+        sel_ops = lay.select_ops(scores, ops_ready)
+        shortest_op = lay.min(jnp.where(sel_ops, rem_op, BIG))
+
+        # 3. per-channel highest-score ready flow dep
+        dscores = jnp.where(flow_ready, dep_score, -1.0)
+        nominated = lay.nominate(dscores, flow_ready)
+        shortest_comm = jnp.where(
+            any_nonflow, 0.0,
+            lay.min(jnp.where(nominated, rem_dep, BIG)))
+
+        tick = jnp.minimum(shortest_op, shortest_comm)
+        new_stuck = tick >= BIG  # nothing can progress: host raises
+
+        # 4. advance ops
+        rem_op2 = jnp.where(
+            sel_ops, jnp.maximum(rem_op - lay.spread(tick), 0.0), rem_op)
+        op_now_done = sel_ops & (rem_op2 <= 0.0) & ~op_done
+        op_done2 = op_done | op_now_done
+
+        # 5. advance deps: the snapshot's non-flow deps if any, else ALL
+        # snapshot-ready flow deps (parallel-flow hack)
+        dep_tick_mask = jnp.where(lay.spread(any_nonflow), nonflow_ready,
+                                  flow_ready)
+        rem_dep2 = jnp.where(
+            dep_tick_mask, jnp.maximum(rem_dep - lay.spread(tick), 0.0),
+            rem_dep)
+        dep_now_done = dep_tick_mask & (rem_dep2 <= 0.0) & ~dep_done
+        dep_done2 = dep_done | dep_now_done
+
+        # 6. non-mutual completed deps advance their child's parent count
+        inc = (dep_now_done & ~dep_mutual).astype(jnp.int32)
+        parent_done2 = lay.count_parents(parent_done, inc)
+
+        ticked_ops = lay.any(sel_ops)
+        ticked_flows = (~any_nonflow) & lay.any(flow_ready)
+        safe_tick = jnp.where(new_stuck, 0.0, tick)
+        comp_oh2 = comp_oh + jnp.where(ticked_ops, safe_tick, 0.0)
+        comm_oh2 = comm_oh + jnp.where(ticked_flows, safe_tick, 0.0)
+        busy2 = busy + safe_tick * lay.count(sel_ops).astype(dt)
+        t2 = t + safe_tick
+
+        return (rem_op2, rem_dep2, op_done2, dep_done2, parent_done2,
+                t2, comm_oh2, comp_oh2, busy2, it + 1, stuck | new_stuck)
+
+    init = (op_remaining, dep_remaining,
+            jnp.zeros(op_remaining.shape, bool),
+            jnp.zeros(dep_remaining.shape, bool),
+            jnp.zeros(op_remaining.shape, jnp.int32),
+            lay.lanes(jnp.zeros((), dt)), lay.lanes(jnp.zeros((), dt)),
+            lay.lanes(jnp.zeros((), dt)), lay.lanes(jnp.zeros((), dt)),
+            lay.lanes(jnp.int32(0)), lay.lanes(jnp.bool_(False)))
+    with jax.named_scope(scopes.SIM_LOOKAHEAD):
+        out = lay.loop(cond, body, init)
+    (_, _, op_done, dep_done, _, t, comm_oh, comp_oh, busy, it,
+     stuck) = out
+    finished = (lay.all(op_done | ~op_valid)
+                & lay.all(dep_done | ~dep_valid))
+    return t, comm_oh, comp_oh, busy, finished & ~stuck, it
+
+
+def _lane_batched_lookahead(num_workers: int):
+    """The block-path lookahead of L jobs at once: every argument
+    carries a leading lane axis [L, ...] (``skip``: [L] or None) and so
+    do the six results. Batching it (``vmap``) folds the new axis into
+    the lanes and calls again at A*L, so any nest of vmaps around the
+    per-job call runs ONE loop whose lanes are their product.
+
+    The shape of that loop's state is chosen on L alone, by what fills
+    a vector register (:data:`REGISTER_WIDTH`). While the lanes alone
+    do not (L < 128) the state is LANE-PACKED (:func:`_packed_layout`):
+    (lane, shard) merged on the minor axis. From 128 lanes on the loop
+    is one job's (:func:`_block_dep_ops`) under ``jax.vmap``, whose
+    lanes XLA lays minor by itself: there the packed form is the slower
+    one (its worker x worker tables carry the shards too; at 320 lanes
+    a trip is 1.33 ms packed against 1.21, my chip run, PR 27)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddls_tpu.telemetry import startup
+
+    def one_job(op_remaining, op_valid, op_worker, op_score, num_parents,
+                dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
+                blocks, skip):
+        N, E = op_remaining.shape[0], dep_remaining.shape[0]
+        return _tick_loop(
+            _job_layout(op_worker, num_workers, _block_dep_ops(
+                op_worker, blocks, E, num_workers)),
+            op_remaining, op_valid, op_score, num_parents, dep_remaining,
+            dep_valid, dep_mutual, dep_is_flow, dep_score, skip, N + E + 4)
+
+    @jax.custom_batching.custom_vmap
+    def run(*args):
+        (op_remaining, op_valid, op_worker, op_score, num_parents,
+         dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
+         blocks, skip) = args
+        L, N = op_remaining.shape
+        if L >= REGISTER_WIDTH:
+            return jax.vmap(one_job)(*args)
+        E, B = dep_remaining.shape[1], blocks.src.shape[1]
+        S = _block_side(E, B)
+        if B * S * S != E or N % S:
+            raise ValueError(f"({N}, {E}) is not a block layout of {B} "
+                             "blocks")
+
+        def ops(x):      # [L, (o, k)] -> [No, (l, k)]
+            return x.reshape(L, N // S, S).transpose(1, 0, 2).reshape(
+                N // S, L * S)
+
+        def deps(x):     # [L, (b, i, j)] -> [B, S_i, (l, j)]
+            return x.reshape(L, B, S, S).transpose(1, 2, 0, 3).reshape(
+                B, S, L * S)
+
+        op_worker = ops(op_worker)
+        return _tick_loop(
+            _packed_layout(op_worker, DepBlocks(blocks.src.T, blocks.dst.T),
+                           L, num_workers),
+            ops(op_remaining), ops(op_valid), ops(op_score),
+            ops(num_parents), deps(dep_remaining), deps(dep_valid),
+            deps(dep_mutual), deps(dep_is_flow), deps(dep_score),
+            skip, N + E + 4)
+
+    @run.def_vmap
+    def fold_into_lanes(axis_size, in_batched, *args):
+        def fold(x, batched):
+            if not batched:
+                x = jnp.broadcast_to(x, (axis_size,) + x.shape)
+            return x.reshape((axis_size * x.shape[1],) + x.shape[2:])
+
+        args = jax.tree_util.tree_map(fold, args, tuple(in_batched))
+        lanes, n_deps, n_blocks = (args[0].shape[0], args[5].shape[1],
+                                   args[10].src.shape[1])
+        # what the loop traced last carries on the minor axis of its dep
+        # state, for the trip drain's counters
+        # (rl/fused.py:record_lookahead_trips): (lane, shard) slots, or
+        # the lanes alone
+        minor = lanes if lanes >= REGISTER_WIDTH else \
+            lanes * _block_side(n_deps, n_blocks)
+        for name, value in zip(MINOR_GAUGES, (
+                -(-minor // REGISTER_WIDTH) * REGISTER_WIDTH, minor)):
+            startup.set_gauge(name, value)
+        out = tuple(x.reshape((axis_size, -1) + x.shape[1:])
+                    for x in run(*args))
+        return out, (True,) * len(out)
+
+    return run
+
+
 def jax_lookahead(op_remaining, op_valid, op_worker, op_score, num_parents,
                   dep_remaining, dep_valid, dep_src, dep_dst, dep_mutual,
                   dep_is_flow, dep_score, dep_channel,
@@ -426,11 +782,15 @@ def jax_lookahead(op_remaining, op_valid, op_worker, op_score, num_parents,
     (t, comm_oh, comp_oh, busy, ok, trips).
 
     ``blocks`` chooses how the tick body reaches a dep's endpoints and
-    channel: None — per-dep gather/scatter through ``dep_src`` /
-    ``dep_dst`` / ``dep_channel``, for any graph; a :class:`DepBlocks`
-    — broadcast and reduction over the partitioner's (block, i, j)
-    layout, for tables laid out that way (the in-kernel env's). Same
-    tick, same bits.
+    channel, and the shape its state is carried in: None — per-dep
+    gather/scatter through ``dep_src`` / ``dep_dst`` / ``dep_channel``
+    over flat [N] / [E] state, for any graph; a :class:`DepBlocks` —
+    broadcast and reduction over the partitioner's (block, i, j)
+    layout, for tables laid out that way (the in-kernel env's); under
+    ``vmap`` (any nest of them) the lanes run as ONE loop whose state
+    is lane-packed while the lanes are few
+    (:func:`_lane_batched_lookahead`), and the unbatched call is that
+    loop at one lane. Same tick, same bits.
 
     ``trips`` is the loop's own iteration count (i32): 0 for a
     ``skip``-masked lane, and under ``vmap`` each lane's OWN count — the
@@ -446,113 +806,30 @@ def jax_lookahead(op_remaining, op_valid, op_worker, op_score, num_parents,
     ``skip`` (optional bool scalar) masks the while_loop cond: a True
     lane exits before its first body iteration and returns the (garbage)
     init accumulators — the memo probe's wide-vmap lever
-    (sim/jax_memo.py): jax batches ``lax.while_loop`` to run while ANY
-    lane's cond holds, select-freezing finished lanes, so seeding
-    memo-HIT lanes with ``skip=True`` makes the batched loop run exactly
-    to the max trip count over MISS lanes (zero when every lane hit).
-    Miss lanes iterate under their own cond regardless of neighbours, so
-    their results stay bit-identical to an unbatched run. ``None`` (the
-    default) traces the historical unmasked cond byte-for-byte.
+    (sim/jax_memo.py): a batched loop runs while ANY lane's cond holds,
+    select-freezing finished lanes, so seeding memo-HIT lanes with
+    ``skip=True`` makes it run exactly to the max trip count over MISS
+    lanes (zero when every lane hit). Miss lanes iterate under their own
+    cond regardless of neighbours, so their results stay bit-identical
+    to an unbatched run. ``None`` (the default) traces the historical
+    unmasked cond byte-for-byte.
     """
     import jax
-    import jax.numpy as jnp
 
-    N = op_remaining.shape[0]
-    E = dep_remaining.shape[0]
-    max_iters = N + E + 4
-    # scalar accumulators follow the input dtype: f32 on the standard
-    # path, f64 when the caller runs under JAX_ENABLE_X64 (the jitted
-    # env-step parity mode, sim/jax_env.py)
-    dt = op_remaining.dtype
-
-    worker_onehot = (jax.nn.one_hot(op_worker, num_workers, dtype=jnp.float32)
-                     .T)  # [W, N]; -1 (padding) one-hots to zeros
     if blocks is None:
-        src_done, count_parents, nominate = _flat_dep_ops(
-            dep_src, dep_dst, dep_channel, num_channels)
-    else:
-        src_done, count_parents, nominate = _block_dep_ops(
-            op_worker, blocks, E, num_workers)
-
-    def cond(state):
-        (_, _, op_done, dep_done, _, _, _, _, _, it, stuck) = state
-        all_done = (jnp.all(op_done | ~op_valid)
-                    & jnp.all(dep_done | ~dep_valid))
-        live = (~all_done) & (it < max_iters) & (~stuck)
-        return live if skip is None else live & ~skip
-
-    def body(state):
-        (rem_op, rem_dep, op_done, dep_done, parent_done,
-         t, comm_oh, comp_oh, busy, it, stuck) = state
-
-        # 1. readiness (snapshotted BEFORE this tick's completions)
-        ops_ready = op_valid & ~op_done & (parent_done >= num_parents)
-        deps_ready = dep_valid & ~dep_done & src_done(op_done)
-        flow_ready = deps_ready & dep_is_flow
-        nonflow_ready = deps_ready & ~dep_is_flow
-        any_nonflow = jnp.any(nonflow_ready)
-
-        # 2. per-worker highest-score ready op
-        scores = jnp.where(ops_ready, op_score, -1.0)
-        per_worker = worker_onehot * scores[None, :]  # [W, N]
-        best_score = per_worker.max(axis=1)           # [W]
-        has_op = best_score > 0
-        # an op is selected iff it is its worker's best ready op
-        sel_ops = ops_ready & jnp.any(
-            (per_worker == best_score[:, None]) & (best_score[:, None] > 0)
-            & (worker_onehot > 0), axis=0)
-        shortest_op = jnp.min(jnp.where(sel_ops, rem_op, BIG))
-
-        # 3. per-channel highest-score ready flow dep
-        dscores = jnp.where(flow_ready, dep_score, -1.0)
-        nominated = nominate(dscores, flow_ready)
-        shortest_comm = jnp.where(
-            any_nonflow, 0.0,
-            jnp.min(jnp.where(nominated, rem_dep, BIG)))
-
-        tick = jnp.minimum(shortest_op, shortest_comm)
-        new_stuck = tick >= BIG  # nothing can progress: host raises
-
-        # 4. advance ops
-        rem_op2 = jnp.where(sel_ops, jnp.maximum(rem_op - tick, 0.0), rem_op)
-        op_now_done = sel_ops & (rem_op2 <= 0.0) & ~op_done
-        op_done2 = op_done | op_now_done
-
-        # 5. advance deps: the snapshot's non-flow deps if any, else ALL
-        # snapshot-ready flow deps (parallel-flow hack)
-        dep_tick_mask = jnp.where(any_nonflow, nonflow_ready, flow_ready)
-        rem_dep2 = jnp.where(dep_tick_mask,
-                             jnp.maximum(rem_dep - tick, 0.0), rem_dep)
-        dep_now_done = dep_tick_mask & (rem_dep2 <= 0.0) & ~dep_done
-        dep_done2 = dep_done | dep_now_done
-
-        # 6. non-mutual completed deps advance their child's parent count
-        inc = (dep_now_done & ~dep_mutual).astype(jnp.int32)
-        parent_done2 = count_parents(parent_done, inc)
-
-        ticked_ops = jnp.any(sel_ops)
-        ticked_flows = (~any_nonflow) & jnp.any(flow_ready)
-        safe_tick = jnp.where(new_stuck, 0.0, tick)
-        comp_oh2 = comp_oh + jnp.where(ticked_ops, safe_tick, 0.0)
-        comm_oh2 = comm_oh + jnp.where(ticked_flows, safe_tick, 0.0)
-        busy2 = busy + safe_tick * jnp.sum(sel_ops).astype(dt)
-        t2 = t + safe_tick
-
-        return (rem_op2, rem_dep2, op_done2, dep_done2, parent_done2,
-                t2, comm_oh2, comp_oh2, busy2, it + 1, stuck | new_stuck)
-
-    init = (op_remaining, dep_remaining,
-            jnp.zeros((N,), bool), jnp.zeros((E,), bool),
-            jnp.zeros((N,), jnp.int32),
-            jnp.zeros((), dt), jnp.zeros((), dt), jnp.zeros((), dt),
-            jnp.zeros((), dt), jnp.int32(0), jnp.bool_(False))
-    with jax.named_scope(scopes.SIM_LOOKAHEAD):
-        out = jax.lax.while_loop(cond, body, init)
-    (_, _, op_done, dep_done, _, t, comm_oh, comp_oh, busy, it,
-     stuck) = out
-    finished = (jnp.all(op_done | ~op_valid)
-                & jnp.all(dep_done | ~dep_valid))
-    return t, comm_oh, comp_oh, busy, finished & ~stuck, it
+        N = op_remaining.shape[0]
+        E = dep_remaining.shape[0]
+        return _tick_loop(
+            _job_layout(op_worker, num_workers, _flat_dep_ops(
+                dep_src, dep_dst, dep_channel, num_channels)),
+            op_remaining, op_valid, op_score, num_parents, dep_remaining,
+            dep_valid, dep_mutual, dep_is_flow, dep_score, skip, N + E + 4)
+    one_lane = jax.tree_util.tree_map(
+        lambda x: x[None],
+        (op_remaining, op_valid, op_worker, op_score, num_parents,
+         dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
+         blocks, skip))
+    return tuple(x[0] for x in _lane_batched_lookahead(num_workers)(*one_lane))
 
 
 def lookahead_fn(num_workers: int, num_channels: int):

@@ -292,28 +292,89 @@ def test_block_path_is_flat_path(block_build, model, degree):
         assert splits == [4, 12, 16], splits   # the uneven row
 
 
-@pytest.mark.parametrize("skip_every", [0, 3])
-def test_block_path_is_flat_path_vmapped(block_build, skip_every):
-    """Lanes of DIFFERENT rows under one vmap (the fused epoch's shape),
-    with and without a ``skip`` mask: each lane's six outputs equal the
-    flat path's, skipped lanes (0 trips, init accumulators) included."""
+def _lanes(block_build, n):
+    """``n`` (cfg row, cluster state) lanes of DIFFERENT rows: the
+    uneven row (16/12/4) first, then a row the second state cannot
+    place, then every other (row, state) in turn."""
+    every = [(block_build.row(m, d), s) for m, d in _BLOCK_ROWS
+             for s in range(len(block_build.states))]
+    first = [(block_build.row("translation_0", 16), 0),
+             (block_build.row("cnn_0", 16), 1)]
+    order = first + [lane for lane in every if lane not in first]
+    return [order[i % len(order)] for i in range(n)]
+
+
+def _lane_arguments(block_build, lanes):
     import jax
     import jax.numpy as jnp
 
-    lanes = [(block_build.row(m, d), s) for m, d in _BLOCK_ROWS
-             for s in range(len(block_build.states))]
     cfgs = jnp.asarray([c for c, _ in lanes], jnp.int32)
     states = jnp.stack([block_build.states[s] for _, s in lanes])
-    args, blocks, _ = jax.vmap(block_build.arguments)(cfgs, states)
-    skip = (jnp.arange(len(lanes)) % skip_every == 1 if skip_every
-            else jnp.zeros(len(lanes), bool))
-    want = jax.jit(jax.vmap(block_build.flat_fn))(args, blocks, skip)
+    return jax.vmap(block_build.arguments)(cfgs, states)
+
+
+def _flat_per_lane(block_build, args, blocks, skip, n):
+    """The unbatched flat path, lane by lane, stacked."""
+    import jax
+
+    rows = [block_build.flat(*jax.tree_util.tree_map(
+        lambda x: x[lane], (args, blocks, skip))) for lane in range(n)]
+    return [np.stack([np.asarray(r[k]) for r in rows]) for k in range(6)]
+
+
+@pytest.mark.parametrize("skip_every", [0, 3])
+@pytest.mark.parametrize("n_lanes", [1, 2, 3, 8, 32, 40, 128])
+def test_block_path_is_flat_path_vmapped(block_build, n_lanes, skip_every):
+    """Lanes of DIFFERENT rows under one vmap (the fused epoch's shape:
+    the lane-packed loop at L lanes; from 128 on, one job a lane with
+    the lanes minor), with and without a ``skip`` mask: each lane's six
+    outputs equal the UNBATCHED flat path's, skipped lanes (0 trips,
+    init accumulators) included."""
+    import jax
+    import jax.numpy as jnp
+
+    args, blocks, placed = _lane_arguments(block_build,
+                                           _lanes(block_build, n_lanes))
+    skip = (jnp.arange(n_lanes) % skip_every == 1 if skip_every
+            else jnp.zeros(n_lanes, bool))
+    want = _flat_per_lane(block_build, args, blocks, skip, n_lanes)
     got = jax.jit(jax.vmap(block_build.block_fn))(args, blocks, skip)
-    _assert_same_bits(got, want, ("vmap", skip_every))
-    trips = np.asarray(want[5])
+    _assert_same_bits(got, want, ("vmap", n_lanes, skip_every))
+    trips = want[5]
     assert (trips[np.asarray(skip)] == 0).all()
     assert (trips[~np.asarray(skip)] > 0).all()
-    assert len(set(trips.tolist())) > 4    # lanes really differ
+    if n_lanes > 1:
+        assert not bool(placed[1])         # the unplaceable row
+    if n_lanes >= 8:
+        assert len(set(trips.tolist())) > 4    # lanes really differ
+
+
+def test_block_path_vmapped_with_unbatched_tables(block_build):
+    """One row on three cluster states: the row's tables (and its
+    ``blocks``) reach the vmap UNBATCHED, what placement and pricing
+    made of them batched; the packing rule broadcasts the former."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg = block_build.row("translation_0", 12)
+    states = block_build.states + [jnp.arange(block_build.et.n_srv) % 8 != 3]
+    per_state = [block_build.arguments(cfg, state)[0] for state in states]
+    batched = [False, False, True, True, False, True, False, False, False,
+               False, True, True, True]
+    args = tuple(jnp.stack([a[k] for a in per_state]) if on else
+                 per_state[0][k] for k, on in enumerate(batched))
+    for a in per_state[1:]:
+        assert all((a[k] == per_state[0][k]).all()
+                   for k, on in enumerate(batched) if not on)
+    _, blocks, _ = block_build.arguments(cfg, states[0])
+    got = jax.jit(jax.vmap(
+        block_build.block_fn,
+        in_axes=(tuple(0 if on else None for on in batched), None)))(
+            args, blocks)
+    want = [np.stack([np.asarray(block_build.flat(a, blocks)[k])
+                      for a in per_state]) for k in range(6)]
+    _assert_same_bits(got, want, "unbatched tables")
+    assert len(set(want[5].tolist())) > 1
 
 
 def _sub_jaxprs(eqn):
@@ -352,41 +413,98 @@ def _per_dep_indexing(jaxpr, n_deps):
     return found
 
 
-def _lookahead_body(closed_jaxpr, n_deps):
+def _lookahead_body(closed_jaxpr, dep_state):
     """The lookahead's tick body: the ``while`` whose carry holds the
-    per-dep remaining times and done flags ([n_deps] f32 and bool)."""
+    per-dep remaining times and done flags (f32 and bool of shape
+    ``dep_state``)."""
     bodies = [b for b in _while_bodies(closed_jaxpr.jaxpr)
-              if sum(v.aval.shape == (n_deps,) for v in b.invars) >= 2]
+              if sum(v.aval.shape == dep_state for v in b.invars) >= 2]
     assert len(bodies) == 1, len(bodies)
     return bodies[0]
 
 
+def test_block_path_nested_vmaps_pack_as_one_loop(block_build):
+    """`price_all`'s shape — a vmap over the cfg axis, cluster state
+    unbatched — inside a vmap over lanes (cluster states): ONE loop at
+    lanes x cfgs packed lanes, each (lane, cfg) result the unbatched
+    call's bits."""
+    import jax
+    import jax.numpy as jnp
+
+    cfgs = jnp.asarray([block_build.row("translation_0", d)
+                        for d in (2, 8, 16)], jnp.int32)
+    states = jnp.stack(block_build.states)
+
+    def one(cfg, state):
+        args, blocks, _ = block_build.arguments(cfg, state)
+        return block_build.block_fn(args, blocks)
+
+    nested = jax.vmap(jax.vmap(one, in_axes=(0, None)), in_axes=(None, 0))
+    got = jax.jit(nested)(cfgs, states)
+    want = [[block_build.flat(*block_build.arguments(cfg, state)[:2])
+             for cfg in cfgs] for state in states]
+    want = [np.asarray([[np.asarray(r[k]) for r in row] for row in want])
+            for k in range(6)]
+    _assert_same_bits(got, want, "nested")
+    pads = block_build.et.pads
+    S, L = pads.max_split, len(cfgs) * len(states)
+    _lookahead_body(jax.make_jaxpr(nested)(cfgs, states),
+                    (pads.n_blocks, S, S * L))
+
+
+#: equations of the flat path's tick body as jax 0.9 traces it: the
+#: ``blocks=None`` loop is not the packed form's to change
+_FLAT_BODY_EQNS = 110
+
+
 def test_env_lookahead_body_indexes_no_dep(block_build):
     """The engagement pin: the tick body the in-kernel env traces (from
-    `make_episode_fn`) holds NO gather/scatter of ``pads.n_deps``
-    elements; the same walk over the flat path finds the four that were
-    95 % of the fused epoch (source gather, channel scatter-max and
+    `make_episode_fn`: the lane-packed loop at one lane) holds NO
+    gather/scatter of ``pads.n_deps`` elements, and under a 32-lane
+    vmap its dep state is [B, S, 32 x S] — (lane, shard) on the minor
+    axis — and still holds none, as the 128-lane loop does, whose lanes
+    alone fill a register and whose state stays one job's a lane; the
+    same walk over the flat path,
+    whose loop is the one it always was, finds the four that were 95 %
+    of the fused epoch (source gather, channel scatter-max and
     read-back, parent-count scatter-add)."""
     import jax
     import jax.numpy as jnp
 
     from ddls_tpu.sim import jax_env as je
+    from ddls_tpu.telemetry import startup
 
     et = block_build.et
-    M = et.pads.n_deps
+    M, S, B = et.pads.n_deps, et.pads.max_split, et.pads.n_blocks
     bank = je.build_job_bank(et, [
         {"model": m, "num_training_steps": 3, "sla_frac": 1.0,
          "time_arrived": 100.0 * i} for i, m in enumerate(_BLOCK_MODELS)])
     bank = {k: jnp.asarray(v) for k, v in bank.items()}
     episode = je.make_episode_fn(et)
     traced = jax.make_jaxpr(episode)(bank, jnp.asarray([16, 4], jnp.int32))
-    assert _per_dep_indexing(_lookahead_body(traced, M), M) == []
+    assert _per_dep_indexing(_lookahead_body(traced, (B, S, S)), M) == []
+
+    args, blocks, _ = _lane_arguments(block_build, _lanes(block_build, 32))
+    lanes = jax.make_jaxpr(jax.vmap(block_build.block_fn))(args, blocks)
+    assert _per_dep_indexing(
+        _lookahead_body(lanes, (B, S, 32 * S)), M) == []
+    assert startup.gauges()["sim.lookahead.minor_used"] == 32 * S
+    args, blocks, _ = _lane_arguments(block_build, _lanes(block_build, 128))
+    lanes = jax.make_jaxpr(jax.vmap(block_build.block_fn))(args, blocks)
+    assert _per_dep_indexing(_lookahead_body(lanes, (128, M)), M) == []
+    assert startup.gauges()["sim.lookahead.minor_used"] == 128
 
     args, blocks, _ = block_build.arguments(0, block_build.states[0])
-    flat = jax.make_jaxpr(block_build.flat_fn)(args, blocks)
-    found = sorted(n.replace("_", "-")
-                   for n in _per_dep_indexing(_lookahead_body(flat, M), M))
+    flat = _lookahead_body(jax.make_jaxpr(block_build.flat_fn)(args, blocks),
+                           (M,))
+    found = sorted(n.replace("_", "-") for n in _per_dep_indexing(flat, M))
     assert found == ["gather", "gather", "scatter-add", "scatter-max"]
+    N = et.pads.n_ops
+    assert [(v.aval.shape, v.aval.dtype.name) for v in flat.invars[-11:]] == [
+        ((N,), "float32"), ((M,), "float32"), ((N,), "bool"), ((M,), "bool"),
+        ((N,), "int32"), ((), "float32"), ((), "float32"), ((), "float32"),
+        ((), "float32"), ((), "int32"), ((), "bool")]
+    assert len(flat.eqns) == _FLAT_BODY_EQNS
 
 
 def test_block_path_is_flat_path_at_the_benchmark_pads(tmp_path):

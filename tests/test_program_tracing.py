@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 import test_fused
+import test_jax_lookahead
 import test_jax_memo
 from ddls_tpu import telemetry
 from ddls_tpu.telemetry import scopes, startup
@@ -19,6 +20,7 @@ pytestmark = pytest.mark.telemetry
 
 fused_dataset = test_fused.fused_dataset
 memo_env = test_jax_memo.memo_env
+block_build = test_jax_lookahead.block_build
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -154,13 +156,23 @@ def test_trip_counters_are_the_hosts_reduction_of_the_trace():
     telemetry.enable()
     record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS))
     snap = telemetry.snapshot()
-    assert {k: v for k, v in snap["counters"].items()
-            if k.startswith("sim.lookahead.")} == {
+    counted = {
         "sim.lookahead.calls": 3, "sim.lookahead.trips": 21,
         "sim.lookahead.lockstep_trips": 16,
         "sim.lookahead.lockstep_lane_trips": 48,
         "sim.lookahead.dep_slots": 13312,
         "sim.lookahead.dep_slots_used": 13072}
+    assert {k: v for k, v in snap["counters"].items()
+            if k.startswith("sim.lookahead.")} == counted
+    # ... and what the lookahead's packing rule left in the start-up
+    # registry when it ran in a trace (none has, in this test)
+    startup.set_gauge("sim.lookahead.minor_slots", 128)
+    startup.set_gauge("sim.lookahead.minor_used", 48)
+    record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS))
+    assert {k: v for k, v in telemetry.snapshot()["counters"].items()
+            if k.startswith("sim.lookahead.")} == {
+        **{k: 2 * v for k, v in counted.items()},
+        "sim.lookahead.minor_slots": 128, "sim.lookahead.minor_used": 48}
     hist = snap["histograms"]["sim.lookahead.trips_per_call"]
     assert hist["count"] == 3 and hist["max"] == 9.0
 
@@ -182,6 +194,47 @@ def test_block_fill_metric_reads_the_dep_slot_counters():
     assert harness.read_layer_metric("lookahead_dep_slots", ctx) == 13312
     assert harness.read_layer_metric("lookahead_block_fill", ctx) == \
         pytest.approx(100 * 13072 / 13312)
+
+
+@pytest.fixture
+def block_lanes(block_build):
+    """The small block tables and three lanes' lookahead arguments."""
+    return block_build, test_jax_lookahead._lane_arguments(
+        block_build, test_jax_lookahead._lanes(block_build, 3))
+
+
+def test_minor_fill_metric_reads_what_the_traced_loop_carries(block_lanes):
+    """The benchmark's ``lookahead_minor_fill``: a lane-batched block
+    lookahead leaves the minor extent of its packed dep state in the
+    start-up registry when it is TRACED (the unbatched call, which no
+    rule batches, leaves nothing), the trip drain counts it per epoch
+    trace, and the metric is their ratio; nothing to read (never
+    raises) from a program that counts neither."""
+    import jax
+
+    from benchmarks import harness
+    from ddls_tpu.rl.fused import record_lookahead_trips
+    from ddls_tpu.sim.jax_env import ConfigPads
+
+    build, (args, blocks, _) = block_lanes
+    ep = {"la_trips": np.array([[[5, 7], [9, 0], [0, 0]]], np.int32)}
+    ctx = {"spans": {"bench": {"epoch": [(0.0, 1.0), (1.0, 2.0)]}}}
+    telemetry.enable()
+    one = jax.tree_util.tree_map(lambda x: x[0], (args, blocks))
+    jax.make_jaxpr(build.block_fn)(*one)
+    assert startup.gauges() == {}
+    record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS))
+    assert harness.read_layer_metric("lookahead_minor_fill", ctx) is None
+
+    jax.make_jaxpr(jax.vmap(build.block_fn))(args, blocks)
+    S = build.et.pads.max_split
+    assert startup.gauges() == {"sim.lookahead.minor_slots": 128,
+                                "sim.lookahead.minor_used": S * 3}
+    for _ in range(2):
+        record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS))
+    assert harness.read_layer_metric("lookahead_minor_slots", ctx) == 128
+    assert harness.read_layer_metric("lookahead_minor_fill", ctx) == \
+        pytest.approx(100 * S * 3 / 128)
 
 
 def test_fused_loop_counts_trips_only_while_telemetry_is_on(
@@ -286,8 +339,17 @@ def test_build_run_leaves_each_startup_span_once(fused_dataset, tmp_path,
         report, = [ln for ln in line if ln.startswith("[startup] ")]
         assert report == startup.report()
         seconds = json.loads(report[len("[startup] "):])
+        # ... then the gauges: the epoch program's lookahead loop is
+        # lane-packed, the driver's (few) lanes x 16 shards on its minor
+        # axis
+        assert loop.fused.num_lanes < 128
+        minor = loop.fused.et.pads.max_split * loop.fused.num_lanes
+        assert startup.gauges() == {
+            "sim.lookahead.minor_slots": -(-minor // 128) * 128,
+            "sim.lookahead.minor_used": minor}
         assert set(seconds) == {n.removeprefix("startup.")
-                                for n, _, _ in reg.span_intervals()}
+                                for n, _, _ in reg.span_intervals()} \
+            | set(startup.gauges())
         assert seconds["first_epoch"] > 0
     finally:
         loop.close()
