@@ -46,8 +46,8 @@ COMMON_OVERRIDES = ("eval_config.evaluation_interval=null",
                     "epoch_loop.metrics_sync_interval=1")
 LEG_A_OVERRIDES = ("epoch_loop.loop_mode=pipelined",
                    "epoch_loop.use_parallel_envs=auto")
-# lanes x segment_len pinned: the result must not depend on the
-# autotuner's ranking or on a .probe/fused_autotune.json left on disk
+# 8 lanes x 32 steps: what num_envs x rollout_length of the composed
+# config already say, pinned so that the leg's shape reads here
 LEG_B_OVERRIDES = ("epoch_loop.loop_mode=fused",
                    "epoch_loop.updates_per_epoch=1",
                    "epoch_loop.fused_config={lanes: 8, segment_len: 32}")

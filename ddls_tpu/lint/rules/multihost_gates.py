@@ -31,8 +31,7 @@ DEFAULT_GUARDED_CALLS = (
     "psum", "pmean", "all_gather", "all_reduce", "broadcast_one_to_all",
     # the fused epoch IS the sharded update (rl/fused.py): a gate that
     # desyncs which process dispatches it is the same hang as a desynced
-    # train_step — and the autotuner's fallback gate must stay a pure
-    # function of the cached config, never of probe wall-time or env
+    # train_step
     "fused_epoch",
 )
 
@@ -89,7 +88,7 @@ class MultihostGatesRule(Rule):
                "multi-host rules) — never wall clock, `random`, "
                "os.environ, or filesystem state")
     # train/ loops plus the fused epoch driver: its fused_epoch dispatch
-    # and autotuner fallback are collective-shaped decisions too
+    # is a collective-shaped decision too
     scope_dirs = ("ddls_tpu/train/", "ddls_tpu/rl/fused.py")
 
     def _guarded_calls(self, ctx: Context) -> Tuple[str, ...]:

@@ -26,24 +26,15 @@ drains them per ``metrics_sync_interval`` epochs, never per update
 (hot-path-transfer rule; the steady-state epoch passes
 ``jax.transfer_guard("disallow")``).
 
-Autotuner: ``autotune_fused`` enumerates (lanes, segment_len)
-factorisations of the requested per-update batch, ranks them by an
-estimated program size (monotonic in lanes, flat in segment_len — a
-scan's program does not grow with its length), probe-compiles them
-smallest-first, caches the first config that compiles keyed by workload
-signature (``.probe/fused_autotune.json``), and reports
-failure so the caller can raise. A successful probe warms the very
-executable training reuses (jax caches per (jit, shapes)), so probing
-costs nothing extra on the chosen config. Which config is FASTEST on a
-chip is not measured; pin ``lanes``/``segment_len`` where it matters.
+Shape: the driver takes its lane count from the banks it is given and
+its segment length as an argument; `train/loops.py:_build_fused` is the
+one place that decides them (``num_envs`` lanes x ``rollout_length``
+steps, or a ``fused_config`` pin that re-factorises the same batch).
+Which shape is FASTEST on a chip is a measurement: `PERF.md`.
 """
 from __future__ import annotations
 
-import dataclasses
-import hashlib
-import json
-import os
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
 
@@ -51,282 +42,12 @@ from ddls_tpu import telemetry
 from ddls_tpu.sim.jax_lookahead import MINOR_GAUGES
 from ddls_tpu.telemetry import scopes, startup
 
-AUTOTUNE_CACHE_FILE = "fused_autotune.json"
-
-# -------------------------------------------------------------------------
-# Program-size model (ranking only — see estimate_program_bytes).
-# -------------------------------------------------------------------------
-#: serialized-HLO bytes per element of captured config-table constants
-#: (tables are embedded in the program as literals)
-_TABLE_BYTES_PER_CELL = 10.0
-#: marginal serialized bytes per vmapped env lane: GSPMD/batching
-#: materialises per-lane buffer shapes and layouts in the module proto
-_BYTES_PER_LANE = 24_000.0
-#: fixed overhead of the epoch skeleton (scan plumbing, the scanned SGD
-#: update, optimiser state threading)
-_BASE_BYTES = 600_000.0
-
-
-def default_probe_dir() -> str:
-    """The ``.probe`` scratch dir holding the autotune cache.
-    Overridable via ``DDLS_TPU_PROBE_DIR`` for tests and relocated
-    checkouts."""
-    env = os.environ.get("DDLS_TPU_PROBE_DIR")
-    if env:
-        return env
-    repo_root = os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-    return os.path.join(repo_root, ".probe")
-
-
-# -------------------------------------------------------------------------
-# Autotuner: candidate enumeration, size model, probe-compile, cache.
-# -------------------------------------------------------------------------
-
-@dataclasses.dataclass
-class AutotuneResult:
-    """The chosen fused (lanes, segment_len) config and how it was
-    reached; ``probed`` records every candidate tried as
-    (lanes, segment_len, ok, error)."""
-    lanes: int
-    segment_len: int
-    estimated_bytes: int
-    actual_bytes: Optional[int]
-    source: str                      # "cache" | "probe" | "explicit"
-    probed: List[Tuple[int, int, bool, Optional[str]]] = \
-        dataclasses.field(default_factory=list)
-
-    def as_dict(self) -> dict:
-        return {"lanes": self.lanes, "segment_len": self.segment_len,
-                "estimated_program_bytes": self.estimated_bytes,
-                "actual_program_bytes": self.actual_bytes,
-                "source": self.source,
-                "probed": [{"lanes": l, "segment_len": s, "ok": ok,
-                            "error": err}
-                           for l, s, ok, err in self.probed]}
-
-
-def table_cells(et) -> int:
-    """Total elements across the episode tables' captured constants —
-    the dominant static contribution to fused-program size (reads
-    ``.size`` attributes only; never fetches the device arrays)."""
-    return int(sum(int(np.prod(getattr(v, "shape", ()) or (1,)))
-                   for v in et.tables.values()))
-
-
-def memo_table_cells(et, memo_cfg) -> int:
-    """Captured-constant contribution of the in-kernel lookahead memo
-    (sim/jax_memo.py): the key-hash weights (1 + N + 2M u32 words) are
-    embedded as program literals. The memo TABLE itself is a carried
-    ARGUMENT, not a constant — it costs argument traffic and HBM, not
-    serialized-program bytes. ``memo_cfg`` is the knob value ("auto" /
-    MemoConfig / None); "auto" counts the cells because it turns the
-    memo on at every lane count (the wide-vmap probe, ISSUE 17)."""
-    if memo_cfg is None:
-        return 0
-    return 1 + int(et.pads.n_ops) + 2 * int(et.pads.n_deps)
-
-
-def estimate_program_bytes(lanes: int, segment_len: int,
-                           n_table_cells: int,
-                           n_memo_cells: int = 0) -> int:
-    """Estimated serialized-program size of the fused epoch.
-
-    A RANKING model, not a measurement: program size grows with vmap
-    WIDTH while `lax.scan` keeps it flat in segment length and update
-    count. Monotonic in ``lanes``, constant in ``segment_len``. Probe
-    compilation supplies the actual size
-    (``AutotuneResult.actual_bytes``) for the artifact.
-    """
-    del segment_len  # scans do not grow the program with their length
-    return int(_BASE_BYTES
-               + _TABLE_BYTES_PER_CELL * (n_table_cells + n_memo_cells)
-               + _BYTES_PER_LANE * lanes)
-
-
-def candidate_configs(total_steps: int, dp: int,
-                      max_lanes: int) -> List[Tuple[int, int]]:
-    """(lanes, segment_len) factorisations of one update's
-    ``total_steps`` batch, smallest-estimated-program (fewest lanes)
-    first. Lanes must divide the batch, stay within ``max_lanes`` (the
-    requested num_envs — more lanes than asked would change workload
-    semantics upward), and divide evenly over the mesh's ``dp`` axis so
-    sharded collection stays collective-free."""
-    out = []
-    for lanes in range(1, max_lanes + 1):
-        if total_steps % lanes:
-            continue
-        if dp > 1 and lanes % dp:
-            continue
-        out.append((lanes, total_steps // lanes))
-    out.sort(key=lambda ls: ls[0])
-    return out
-
-
-def workload_signature(et, total_steps: int, updates_per_epoch: int,
-                       dp: int, max_lanes: int = 0,
-                       extra: str = "", memo_cfg="auto") -> str:
-    """Cache key for the autotuned config: everything the compiled
-    program's size depends on — pad bounds, topology size, the
-    model/degree config set, batch factorisation inputs (including the
-    lane cap: a cached config must never carry more lanes than the
-    current run's num_envs allows), mesh width, and the lookahead-memo
-    knob (a memo-on lanes=1 program is a different program than a
-    memo-off one) — hashed so a changed workload can never serve a
-    stale config."""
-    pads = dataclasses.asdict(et.pads)
-    payload = json.dumps({
-        "pads": pads, "n_srv": et.n_srv, "n_chan": et.n_chan,
-        "types": list(et.types), "degrees": list(et.degrees),
-        "max_action": et.max_action, "total_steps": total_steps,
-        "updates_per_epoch": updates_per_epoch, "dp": dp,
-        "max_lanes": max_lanes, "extra": extra,
-        "memo": repr(memo_cfg)}, sort_keys=True)
-    return hashlib.sha256(payload.encode()).hexdigest()[:24]
-
-
-def _cache_path(probe_dir: str) -> str:
-    return os.path.join(probe_dir, AUTOTUNE_CACHE_FILE)
-
-
-def load_cached_config(probe_dir: str, key: str) -> Optional[dict]:
-    """Best-effort read of a cached autotune decision (missing/corrupt
-    cache means probe again — never an error)."""
-    try:
-        with open(_cache_path(probe_dir)) as f:
-            return json.load(f).get(key)
-    except (OSError, ValueError):
-        return None
-
-
-def store_cached_config(probe_dir: str, key: str, entry: dict) -> None:
-    """Best-effort atomic upsert of one autotune decision."""
-    path = _cache_path(probe_dir)
-    try:
-        os.makedirs(probe_dir, exist_ok=True)
-        try:
-            with open(path) as f:
-                data = json.load(f)
-        except (OSError, ValueError):
-            data = {}
-        data[key] = entry
-        tmp = path + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump(data, f, indent=1, sort_keys=True)
-        os.replace(tmp, path)
-    except OSError:
-        pass
-
-
-def probe_compile(build_fn: Callable[[], "FusedEpochDriver"], state
-                  ) -> Tuple[Optional["FusedEpochDriver"], bool,
-                             Optional[int], Optional[str]]:
-    """Build + compile one candidate's fused program. On success the
-    compiled executable is already in the jit cache — the first
-    training epoch pays no second compile. A candidate the compiler
-    rejects (program too large, out of memory) is reported, not raised,
-    so the caller can try the next one. Returns
-    (driver, ok, actual_program_bytes, error).
-    """
-    try:
-        driver = build_fn()
-        lowered = driver.lower(state)
-        size = len(lowered.as_text())
-        lowered.compile()
-    except Exception as e:  # compiler rejection, OOM, ...
-        return None, False, None, f"{type(e).__name__}: {e}"
-    return driver, True, size, None
-
-
-def autotune_fused(build_driver: Callable[[int, int],
-                                          "FusedEpochDriver"],
-                   state, et, total_steps: int, updates_per_epoch: int,
-                   dp: int, max_lanes: int,
-                   probe_dir: Optional[str] = None,
-                   signature_extra: str = "",
-                   lanes: Optional[int] = None,
-                   segment_len: Optional[int] = None,
-                   memo_cfg="auto"
-                   ) -> Tuple[Optional["FusedEpochDriver"],
-                              AutotuneResult]:
-    """Pick a compilable (lanes, segment_len) config and build its
-    driver.
-
-    Explicit ``lanes``/``segment_len`` skip probing entirely (tests,
-    pinned production configs). Otherwise: cache hit → build that config
-    without probing (the gate stays deterministic given the cached
-    config — multi-host rule); cache miss → probe-compile candidates
-    smallest-estimated-first, cache the winner. Returns
-    (driver, result); driver is None when nothing compiled — the caller
-    must raise, never train on another path silently.
-    """
-    probe_dir = probe_dir or default_probe_dir()
-    cells = table_cells(et) + memo_table_cells(et, memo_cfg)
-    if lanes is not None or segment_len is not None:
-        if lanes is None or segment_len is None:
-            raise ValueError("pass both lanes and segment_len (or "
-                             "neither, for autotuning)")
-        if lanes * segment_len != total_steps:
-            raise ValueError(
-                f"lanes ({lanes}) x segment_len ({segment_len}) must "
-                f"equal the per-update batch ({total_steps})")
-        return build_driver(lanes, segment_len), AutotuneResult(
-            lanes=lanes, segment_len=segment_len,
-            estimated_bytes=estimate_program_bytes(lanes, segment_len,
-                                                   cells),
-            actual_bytes=None, source="explicit")
-
-    key = workload_signature(et, total_steps, updates_per_epoch, dp,
-                             max_lanes=max_lanes, extra=signature_extra,
-                             memo_cfg=memo_cfg)
-    cached = load_cached_config(probe_dir, key)
-    if cached is not None:
-        # a hand-edited/corrupt entry is re-probed, never obeyed: the
-        # cached config must satisfy every constraint the prober
-        # enforces (lane cap, exact batch factorisation, dp divide)
-        cl = int(cached.get("lanes", 0))
-        cs = int(cached.get("segment_len", 0))
-        if (cl < 1 or cl > max_lanes or cl * cs != total_steps
-                or (dp > 1 and cl % dp)):
-            cached = None
-    if cached is not None:
-        cl, cs = int(cached["lanes"]), int(cached["segment_len"])
-        return build_driver(cl, cs), AutotuneResult(
-            lanes=cl, segment_len=cs,
-            estimated_bytes=int(cached.get("estimated_bytes", 0)),
-            actual_bytes=cached.get("actual_bytes"),
-            source="cache")
-
-    probed: List[Tuple[int, int, bool, Optional[str]]] = []
-    for cand_lanes, cand_seg in candidate_configs(total_steps, dp,
-                                                  max_lanes):
-        driver, ok, size, err = probe_compile(
-            lambda cl=cand_lanes, cs=cand_seg: build_driver(cl, cs),
-            state)
-        probed.append((cand_lanes, cand_seg, ok, err))
-        if ok:
-            est = estimate_program_bytes(cand_lanes, cand_seg, cells)
-            store_cached_config(probe_dir, key, {
-                "lanes": cand_lanes, "segment_len": cand_seg,
-                "estimated_bytes": est, "actual_bytes": size})
-            return driver, AutotuneResult(
-                lanes=cand_lanes, segment_len=cand_seg,
-                estimated_bytes=est, actual_bytes=size, source="probe",
-                probed=probed)
-    return None, AutotuneResult(
-        lanes=0, segment_len=0, estimated_bytes=0, actual_bytes=None,
-        source="failed", probed=probed)
-
-
-# -------------------------------------------------------------------------
-# The fused epoch driver.
-# -------------------------------------------------------------------------
 
 def horizon_bank_jobs(env, seed: int,
                       explicit: Optional[int] = None) -> int:
     """Jobs per lane bank: the explicit config when given, else sized to
     cover the sim horizon — the ONE sizing home for the device
-    collector, the fused loop, and the bench (an under-sized bank ends
+    collector and the fused loop (an under-sized bank ends
     in-kernel episodes early: arrival_t=inf silently truncates them).
 
     Sizing provisions for the SUM of interarrivals, not its mean: a
@@ -362,9 +83,8 @@ def stacked_job_banks(et, env, n_lanes: int, n_jobs: int,
     """Per-lane job banks sampled from ``env``'s own workload machinery,
     stacked along a leading lane axis. Lane i draws with seed
     ``seed_base + 7559 * i + 17`` — THE device-collection seed formula
-    (one home: the training loop and the bench both build their banks
-    here, so fused lanes == num_envs reproduce the device collector's
-    banks bit-for-bit and the two callers can never drift)."""
+    (one home, so fused lanes == num_envs reproduce the device
+    collector's banks bit-for-bit)."""
     import jax.numpy as jnp
 
     from ddls_tpu.sim.jax_env import sample_job_bank
@@ -633,8 +353,9 @@ class FusedEpochDriver:
 
     # ------------------------------------------------------------- run
     def lower(self, state):
-        """Lower (trace, no compile/execute) the fused program for the
-        autotuner's probe-compile and size measurement."""
+        """Lower (trace, no compile/execute) the fused program: what
+        the benchmark reads the program's scratch bytes from, and what
+        tests compare as text."""
         import jax
 
         crng = urng = jax.random.PRNGKey(0)
@@ -674,8 +395,16 @@ class FusedEpochDriver:
         import jax
 
         with startup.span("startup.first_epoch"):
-            (state, self._state, crng, urng, metrics,
-             ep) = self._jit_epoch(state, self._state, crng, urng)
+            try:
+                (state, self._state, crng, urng, metrics,
+                 ep) = self._jit_epoch(state, self._state, crng, urng)
+            except Exception as err:
+                # the compiler's own error, with the shape it refused
+                err.add_note(
+                    "fused epoch program: "
+                    f"{self.num_lanes} lanes x {self.segment_len} steps "
+                    f"x {self.updates_per_epoch} updates")
+                raise
             jax.block_until_ready((state, ep))
         print(startup.report(), flush=True)
         return state, (crng, urng), metrics, ep

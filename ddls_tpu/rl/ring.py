@@ -213,13 +213,13 @@ class TrajRing:
         self._cond = threading.Condition()
         self._next = 0  # round-robin lease cursor
         # ledger counters (host ints; fetched once at reporting
-        # boundaries — bench's `ring` block, telemetry_report's section)
+        # boundaries — loops.ring_stats(), telemetry_report's section)
         self.leases = 0
         self.stalls = 0
         self.publishes = 0
         self.releases = 0
         # exact occupancy histogram: occupied-segment count at each
-        # lease, index = occupancy (the bench/report artifact)
+        # lease, index = occupancy (the report artifact)
         self.occupancy_counts = [0] * (segments + 1)
         self._params_age_sum = 0
         self._params_age_n = 0
@@ -355,8 +355,8 @@ class TrajRing:
 
     # ------------------------------------------- consumer token protocol
     # The ONE authoritative implementation of the two-phase handoff
-    # (train/loops.py and bench.py both call these — the verdict/token
-    # choice must never fork between consumers).
+    # (every consumer calls these — the verdict/token choice must never
+    # fork between consumers; train/loops.py is the model).
     def note_staged(self, seg: RingSegment, staged_tree,
                     generation: Optional[int] = None) -> None:
         """Phase 1, at staging time: probe the alias verdict ONCE per
@@ -397,7 +397,7 @@ class TrajRing:
 
     def stats(self) -> Dict[str, Any]:
         """Ledger counters as one host-side dict (no device fetch):
-        the bench JSON `ring` block / report section payload."""
+        the run ledger's `ring` block / report section payload."""
         with self._cond:
             return {
                 "segments": len(self.segments),

@@ -1192,7 +1192,7 @@ class RolloutCollector:
         if segment is not None:
             # ownership passes to the learner; the caller MUST run the
             # two-phase token protocol (ring.note_staged/note_update —
-            # train/loops.py and bench.py are the models), quoting the
+            # train/loops.py is the model), quoting the
             # generation so a late token can't release a recycled
             # segment
             ring.publish(segment)

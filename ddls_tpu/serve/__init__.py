@@ -14,18 +14,17 @@ See docs/serving.md for the design and its invariants; the entry points:
   control loop over the per-replica telemetry registries
   (serve/autoscale.py);
 * ``ddls_tpu.serve.loadgen`` — seeded, fingerprinted open-loop traces
-  (diurnal + bursts + heavy-tailed sizes) and the SLO/goodput rollup;
+  (diurnal + bursts + heavy-tailed sizes);
 * :class:`ObsBucketer` / :func:`default_buckets` / :func:`fit_buckets`
   — (max_nodes, max_edges) bucket ladders;
 * :class:`MicrobatchEngine` — flush-on-fill-or-deadline queueing;
 * :func:`load_checkpoint_params` — checkpoint -> policy variables without
   a training loop;
 * ``scripts/serve_policy.py`` — stdin/JSON front end (``--replicas N``
-  routes through the fleet Router);
-* ``bench.py --mode serve`` — offered-load throughput/latency
-  measurement (``--load trace --replicas N`` drives the fleet under the
-  open-loop trace with coordinated-omission-correct p99/p999 and
-  SLO/goodput accounting).
+  routes through the fleet Router).
+
+Serving speed is measured by ``benchmarks/run.py`` (``benchmarks/paths/
+serve.py``; PERF.md says which serve cells are listed).
 """
 from ddls_tpu.serve.autoscale import (AutoscaleConfig, AutoscaleController,
                                       AutoscaleDecision, Autoscaler)

@@ -1,12 +1,12 @@
 """Telemetry-driven autoscaling for the serving fleet (ISSUE 8).
 
-The control loop closes over the SAME counters the bench reports: the
+The control loop closes over the SAME counters a load driver reports: the
 Router's ``autoscale_snapshot()`` reads each replica's private
 ``ServeStats`` registry (rolling windowed p99, batch-occupancy window)
 plus the live microbatch queue depths, and :class:`Autoscaler.decide`
 maps that snapshot to a target replica count. Nothing else feeds the
-decision — if the bench JSON says the fleet was slow, the autoscaler saw
-the same numbers.
+decision — if a driver's result line says the fleet was slow, the
+autoscaler saw the same numbers.
 
 Design rules:
 
@@ -35,7 +35,7 @@ from typing import Any, Dict, List, NamedTuple, Optional
 
 @dataclass
 class AutoscaleConfig:
-    """Watermarks are in the bench's own units: ``target_p99_ms`` wall
+    """Watermarks are in the serve stats' own units: ``target_p99_ms`` wall
     milliseconds (the SLO-adjacent latency budget), queue depths in
     requests per replica, occupancy as batch-fill fraction."""
     min_replicas: int = 1
@@ -107,8 +107,8 @@ class AutoscaleController:
     ``step()`` snapshots the fleet, decides, applies the change through
     ``Router.scale_to`` (drain-then-retire on the way down), and records
     the decision. ``decisions`` keeps the full (snapshot, decision,
-    resolved) history — the bench embeds it so a scaling trajectory is
-    part of the measurement artifact."""
+    resolved) history, so a scaling trajectory can ride a measurement
+    artifact."""
 
     def __init__(self, router, autoscaler: Optional[Autoscaler] = None):
         self.router = router

@@ -28,7 +28,8 @@ def default_buckets(max_nodes: int, max_edges: Optional[int] = None,
     """A halving ladder ending at the dataset bound: e.g. 32 nodes ->
     [(8, e/4), (16, e/2), (32, e)]. ``max_edges`` defaults to the
     fully-connected bound (the reference's own pad policy; pass the
-    dataset's true dep bound for tight buckets, as bench.py does)."""
+    dataset's true dep bound for tight buckets, as
+    ``scripts/serve_policy.py --selftest`` does)."""
     if max_nodes < 1:
         raise ValueError(f"max_nodes must be >= 1, got {max_nodes}")
     if max_edges is None:

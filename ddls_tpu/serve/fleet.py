@@ -30,7 +30,7 @@ design rules:
 
 Everything is single-threaded and clock-parameterised like the rest of
 the serve stack (``submit``/``poll`` take an optional ``now``), so tests
-and the bench drive time deterministically; quota and shed decisions are
+and load drivers drive time deterministically; quota and shed decisions are
 pure functions of the submitted timestamps — a seeded trace replays to
 identical decisions.
 """
@@ -228,7 +228,7 @@ class Router:
         the default factories share it).
     warm_replica : optional hook run on every replica the Router builds
         (initial fleet AND autoscale scale-ups) BEFORE it joins the
-        routing set — the bench passes its per-bucket compile warmer so
+        routing set — a driver passes its per-bucket compile warmer so
         a scale-up never serves its first batches cold (first-flush XLA
         compile would otherwise land inside the measured serving
         window; true pre-built warm pools are ROADMAP next-tier).
@@ -272,7 +272,7 @@ class Router:
         self._pending: Dict[Tuple[int, int], Tuple[int, Optional[str]]] = {}
         self._quotas: Dict[str, TokenBucket] = {}
         # final registry snapshots of autoscale-retired replicas: the
-        # bench aggregate must keep counting traffic a replica served
+        # aggregate must keep counting traffic a replica served
         # before a scale-down event (rids never reuse, keys are stable)
         self._retired_snapshots: Dict[str, Dict[str, Any]] = {}
         self._sizes: deque = deque(maxlen=SIZE_WINDOW)
@@ -514,7 +514,7 @@ class Router:
     # ------------------------------------------------------------ readbacks
     def autoscale_snapshot(self) -> Dict[str, Any]:
         """The autoscaler's input, built from the SAME per-replica
-        registries the bench reports (ISSUE 8: the counters close the
+        registries a driver reports (ISSUE 8: the counters close the
         loop): live queue depths, rolling windowed p99 over the fleet's
         latency samples, mean batch occupancy. JSON-round-trippable so
         decisions are reproducible from a stored snapshot."""
@@ -573,7 +573,7 @@ class Router:
         }
 
     def registry_snapshots(self) -> Dict[str, Any]:
-        """Per-registry snapshots keyed for the bench/report surface:
+        """Per-registry snapshots keyed for the report surface:
         ``fleet`` (router admission counters), one ``r<id>`` per replica
         (its private ServeStats registry — retired replicas contribute
         their final pre-retirement snapshot, so a scale-down never
@@ -587,17 +587,6 @@ class Router:
             "aggregate": telemetry.aggregate_snapshots(list(per.values())),
             **per,
         }
-
-    def reset_stats(self) -> None:
-        """Fresh measurement window (bench warmup discipline): new
-        ServeStats per replica, retired-replica snapshots dropped,
-        fresh router registry counters."""
-        for rep in self.replica_set.replicas:
-            rep.server.stats = type(rep.server.stats)()
-        self._retired_snapshots = {}
-        self.registry = telemetry.Registry(enabled=True)
-        self.registry.gauge("fleet.replicas").set(
-            len(self.replica_set.replicas))
 
 
 def build_fleet(model, params, n_replicas: int = 1,

@@ -1,12 +1,11 @@
-"""Trace-driven open-loop load for the serving bench (ISSUE 8).
+"""Trace-driven open-loop load for the serving stack (ISSUE 8).
 
 A closed Poisson process at a constant rate is the friendliest load a
 server ever sees. Production traffic is not that: rates follow a diurnal
 cycle, bursts arrive on top of it, job sizes are heavy-tailed, and
 tenants are skewed. This module generates such a trace — **seeded and
-fingerprinted**, so every bench line names exactly the load it measured
-and two rounds are comparable — and defines the latency/SLO accounting
-the bench reports over it:
+fingerprinted**, so a result can name exactly the load it measured and
+two rounds are comparable. How a driver must use it:
 
 * **Open-loop**: request *i* is scheduled at ``arrival_s[i]``
   regardless of how the server is doing — arrivals never wait for
@@ -17,15 +16,11 @@ the bench reports over it:
   submit precisely for this). A stalled server therefore charges its
   stall to every request that arrived during it — p99/p999 stay honest
   exactly in overload, where the naive measurement is most wrong.
-* **SLO/goodput**: a request *attains* the SLO when it got an actual
-  decision (policy or heuristic fallback — sheds are explicit refusals
-  and never count) within the budget, measured from scheduled arrival.
-  ``goodput_rps`` is attaining requests per second of trace time.
 
 The arrival process is a non-homogeneous Poisson approximation
 (interarrival ``Exp(1)/rate(t)`` at the current instant's rate) with
 ``rate(t) = base_rps * diurnal(t) * burst(t)``; sizes draw a Pareto tail
-mapped into ``[0, 1)`` ranks (the bench maps ranks onto its obs pool
+mapped into ``[0, 1)`` ranks (a driver maps ranks onto its obs pool
 sorted by graph size); tenants draw from a 1/(k+1) zipf-ish weighting.
 Everything is a pure function of the seed + knobs: same seed, same
 fingerprint, bit-same trace.
@@ -39,7 +34,7 @@ import argparse
 import hashlib
 import json
 import math
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
@@ -79,8 +74,7 @@ def generate_trace(n_requests: int, base_rps: float, seed: int = 0,
                    n_tenants: int = 4) -> Dict[str, Any]:
     """One seeded open-loop trace. ``diurnal_amplitude=0`` and
     ``burst_factor=1`` degrade to a plain Poisson process at
-    ``base_rps`` (what the bench's ``--load poisson`` fleet path uses,
-    so poisson runs are fingerprinted through the same machinery)."""
+    ``base_rps``, fingerprinted through the same machinery."""
     if n_requests < 1:
         raise ValueError(f"n_requests must be >= 1, got {n_requests}")
     if base_rps <= 0:
@@ -126,7 +120,7 @@ def generate_trace(n_requests: int, base_rps: float, seed: int = 0,
 def trace_fingerprint(trace: Dict[str, Any]) -> str:
     """Stable 16-hex-digit content fingerprint: meta knobs + the arrival
     / size arrays (rounded to ns / 1e-12 so the fingerprint survives
-    JSON round-trips) + tenants. Two bench lines with equal fingerprints
+    JSON round-trips) + tenants. Two result lines with equal fingerprints
     measured the identical offered load."""
     h = hashlib.sha256()
     meta = trace.get("meta") or {}
@@ -141,8 +135,8 @@ def trace_fingerprint(trace: Dict[str, Any]) -> str:
 
 
 def validate_trace(trace: Dict[str, Any]) -> None:
-    """Schema validator (the ``--selftest`` surface, also run by the
-    bench before driving a trace): raises ``ValueError`` naming the
+    """Schema validator (the ``--selftest`` surface; run it before
+    driving a trace): raises ``ValueError`` naming the
     first violated invariant."""
     if not isinstance(trace, dict):
         raise ValueError(f"trace must be a dict, got {type(trace)}")
@@ -197,44 +191,6 @@ def trace_from_jsonable(obj: Dict[str, Any]) -> Dict[str, Any]:
     }
     validate_trace(trace)
     return trace
-
-
-# ------------------------------------------------------------ SLO accounting
-def slo_summary(responses: Sequence[Any], slo_s: float,
-                duration_s: float) -> Dict[str, Any]:
-    """Coordinated-omission-correct latency + SLO rollup over a bench
-    run's responses (anything with ``.action``/``.source``/
-    ``.latency_s``, latencies measured from SCHEDULED arrivals).
-
-    Percentiles (p50/p99/p999) are over DECIDED requests only; sheds
-    are explicit refusals reported via ``shed_rate`` (folding their
-    ~0 s refusal latency into the percentiles would bias them low
-    exactly when shedding is protecting the tail). ``slo_attainment``
-    and ``goodput_rps`` charge sheds as misses: attainment is
-    ``decided within budget / offered``."""
-    n_offered = len(responses)
-    decided = [r for r in responses if r.source != "shed"]
-    shed = n_offered - len(decided)
-    fallback = sum(1 for r in decided if r.source == "fallback")
-    lats = np.asarray([r.latency_s for r in decided], dtype=np.float64)
-    attained = int(np.sum(lats <= float(slo_s))) if len(lats) else 0
-
-    def pct(q):
-        return (float(np.percentile(lats, q)) * 1e3 if len(lats)
-                else None)
-
-    return {
-        "n_offered": n_offered,
-        "n_decided": len(decided),
-        "p50_latency_ms": pct(50),
-        "p99_latency_ms": pct(99),
-        "p999_latency_ms": pct(99.9),
-        "slo_ms": float(slo_s) * 1e3,
-        "slo_attainment": (attained / n_offered) if n_offered else 0.0,
-        "goodput_rps": (attained / duration_s) if duration_s > 0 else 0.0,
-        "shed_rate": (shed / n_offered) if n_offered else 0.0,
-        "degraded_rate": (fallback / n_offered) if n_offered else 0.0,
-    }
 
 
 # ------------------------------------------------------------------ selftest

@@ -6,7 +6,7 @@ device round-trip. Requests wait in a per-bucket queue until either the
 batch fills (``max_batch``) or the *oldest* request's latency budget
 (``deadline_s``) expires; the flush hands one same-bucket batch to the
 forward. The engine is clock-parameterised (callers pass ``now``) so tests
-and the bench drive it deterministically without sleeping.
+and load drivers drive it deterministically without sleeping.
 
 The engine never drops a request: saturation is signalled to the caller at
 ``submit`` time (``would_saturate``), and the caller answers those from the

@@ -26,8 +26,9 @@ over from the training-side measurements:
   server never blocks on the device and never drops a request.
 
 The server is single-threaded and clock-parameterised: ``submit``/``poll``
-take an optional ``now`` so tests and the bench drive time deterministically;
-production callers just let it default to ``time.perf_counter``.
+take an optional ``now`` so tests and load drivers drive time
+deterministically; production callers just let it default to
+``time.perf_counter``.
 """
 from __future__ import annotations
 
@@ -169,7 +170,7 @@ class ServeStats:
     pinned bit-equal by tests/test_serve.py). ``summary()`` keeps its
     JSON shape; percentiles/occupancy read the histograms' trailing
     ``STATS_WINDOW`` windows — the exact semantics the hand-rolled deques
-    had. ``registry.snapshot()`` is the bench/report surface.
+    had. ``registry.snapshot()`` is the report surface.
     """
 
     def __init__(self, registry: Optional[telemetry.Registry] = None):
@@ -489,7 +490,7 @@ class PolicyServer:
 
         # fallback answers complete at the clock's now, not the (possibly
         # backdated) arrival instant `now` — a caller submitting arrivals
-        # late (bench.py's real-time loop reaching a request after a
+        # late (a real-time driving loop reaching a request after a
         # blocking forward) must still see that wait in latency
         if self.degraded:
             self._resolve_fallback(rid, obs, self.clock(), reason="degraded")

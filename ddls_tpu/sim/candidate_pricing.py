@@ -5,9 +5,9 @@ The integration point the jax-lookahead go/no-go named (VERDICT r2 next
 #3; docs/jax_lookahead_gonogo.md point 2): a policy/heuristic deciding a
 job's partition degree wants the lookahead outcome of all ~16 candidate
 actions, not just the one it takes. Pricing them one-by-one through the
-host tick engine costs ~100 ms each at bench scale; here each candidate's
-control-plane (partition -> first-fit placement -> SRPT schedules ->
-pricing) runs on host over the array pipeline, and the tick engines
+host tick engine costs ~100 ms each at RAMP-32 scale (a CPU timing);
+here each candidate's control-plane (partition -> first-fit placement
+-> SRPT schedules -> pricing) runs on host over the array pipeline, and the tick engines
 evaluate the batch — the C++ engine per candidate (~0.2 ms, bit-exact
 f64; the default everywhere), or the opt-in vmapped jitted call (kept
 for parity testing; host-dispatched, not measured on the current chip).

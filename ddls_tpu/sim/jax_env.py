@@ -64,7 +64,7 @@ from ddls_tpu.telemetry import scopes
 #: recomputed lookaheads are bitwise identical by construction, so the
 #: x64 parity suites run with it enabled unchanged, and the batched
 #: probe masks hit lanes out of the lookahead while_loop so multi-lane
-#: vmap callers (es_device, bench vmap8) hit the cache too (ISSUE 17;
+#: vmap callers (es_device, multi-lane collectors) hit the cache too (ISSUE 17;
 #: each vmapped lane carries its own table).
 DEFAULT_EPISODE_MEMO = jax_memo.MemoConfig()
 
@@ -1864,8 +1864,8 @@ def vmap_segment_fn(segment, n_lanes: int):
     a leading B axis``. Real lane counts vmap; ONE lane takes a
     squeeze/expand fast path instead — batching a singleton lane axis
     through the decision kernels costs ~2x on XLA:CPU (measured
-    docs/perf_round8.md: 738 -> 392 decisions/s at the degree-2 bench
-    regime), and a 1-wide vmap buys nothing anywhere. Shared by the
+    docs/perf_round8.md: 738 -> 392 decisions/s at degree 2, a CPU
+    timing), and a 1-wide vmap buys nothing anywhere. Shared by the
     device collector and the fused epoch driver so the two paths stay
     the same compiled math at every lane count."""
     import jax

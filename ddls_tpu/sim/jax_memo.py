@@ -56,7 +56,7 @@ lanes — zero when every lane hits — and the per-lane ``.at[].set``
 insertions scatter back through vmap's batching rule. The lanes=1
 canonical 13x therefore generalises to every width, and
 ``resolve_memo_cfg``'s ``"auto"`` enables the memo at ALL widths
-(es_device, bench vmap8, multi-lane fused/collector lanes). Miss lanes
+(es_device, multi-lane fused/collector lanes). Miss lanes
 iterate under their own cond regardless of neighbours, so memo-on and
 memo-off stay bit-identical at every width.
 
@@ -131,8 +131,7 @@ def resolve_memo_cfg(memo_cfg: Union[str, MemoConfig, None],
 
 def _hash_weights(n_words: int) -> np.ndarray:
     """Deterministic odd u32 multipliers for the key hash (embedded as
-    program constants; counted by the fused autotuner's size model via
-    ``rl/fused.py:memo_table_cells``). The hash only picks the set — the
+    program constants). The hash only picks the set — the
     bitwise residual compare makes its quality a perf knob, not a
     correctness one."""
     r = np.random.RandomState(0x5EED)
@@ -297,7 +296,7 @@ def summarize_counters(memo: dict) -> dict:
     lane-stacked) memo state — the ONE summary home shared by
     `DevicePPOCollector.memo_counters` and
     `FusedEpochDriver.memo_counters`. One explicit device fetch of three
-    small arrays; call at drain/reporting boundaries only (bench JSON,
+    small arrays; call at drain/reporting boundaries only (result lines,
     logging), never on a per-collect/per-epoch hot path."""
     import jax
 

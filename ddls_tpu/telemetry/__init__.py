@@ -1,6 +1,6 @@
 """Unified telemetry layer: spans, counters, gauges, latency histograms
 (ISSUE 3) — one vocabulary for timing/attribution evidence across the
-simulator, the train loops, the serve stack, and bench.py.
+simulator, the train loops and the serve stack; ``benchmarks/`` reads it.
 
 The process-global registry here is **disabled by default** and the
 module-level API is a near-no-op while it stays disabled: one bool check,
@@ -68,9 +68,9 @@ __all__ = [
 _GLOBAL = Registry(enabled=False, annotate_spans=True)
 
 # environment override for processes whose CLI has no telemetry flag
-# (subprocess env workers, the bench's sim-mode rider): a path enables
-# the global registry with a JSONL sink at import of the entry point
-# that consults it (bench.py, scripts/serve_policy.py)
+# (subprocess env workers): a path enables the global registry with a
+# JSONL sink at import of the entry point that consults it
+# (scripts/serve_policy.py)
 SINK_ENV_VAR = "DDLS_TELEMETRY_JSONL"
 
 

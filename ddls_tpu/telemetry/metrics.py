@@ -3,8 +3,9 @@ latency histograms, span tracing, and a registry with snapshot/reset.
 
 The substrate Podracer (arXiv 2104.06272) and MSRL (arXiv 2210.00882)
 attribute their scaling wins to: per-stage instrumentation of the
-actor/learner dataflow, here shared by the simulator, the train loops,
-the serve stack, and bench.py so every perf claim speaks one vocabulary.
+actor/learner dataflow, here shared by the simulator, the train loops
+and the serve stack, and read by ``benchmarks/``, so every perf claim
+speaks one vocabulary.
 
 Design rules (ISSUE 3):
 
@@ -458,22 +459,6 @@ class Registry:
         with self._lock:
             return [(n, c.value) for n, c in self._counters.items()]
 
-    # ---------------------------------------------------- state swapping
-    def metrics_state(self) -> tuple:
-        """Opaque handle to the CURRENT metric dicts. ``reset()`` swaps
-        in fresh dicts rather than mutating, so a caller that needs a
-        private measurement window (bench.main) can save this, reset,
-        measure, and hand the handle back to ``restore_metrics_state`` —
-        the previous owner's metrics come back untouched."""
-        with self._lock:
-            return (self._counters, self._gauges, self._histograms,
-                    self._spans)
-
-    def restore_metrics_state(self, state: tuple) -> None:
-        with self._lock:
-            (self._counters, self._gauges, self._histograms,
-             self._spans) = state
-
     # --------------------------------------------------------------- spans
     def span(self, name: str) -> Span:
         return Span(self, name)
@@ -618,7 +603,7 @@ class Registry:
 def aggregate_snapshots(snaps: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
     """Merge several ``Registry.snapshot()`` dicts into one fleet-level
     rollup (ISSUE 8: N serving replicas each keep a PRIVATE always-on
-    registry; the bench/report surface needs the fleet total without the
+    registry; the report surface needs the fleet total without the
     replicas ever sharing live metric objects).
 
     Exact merges only: counters and gauges sum, histogram ``count`` /

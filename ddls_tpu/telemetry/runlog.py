@@ -1,4 +1,4 @@
-"""Run ledger (ISSUE 18): every bench/train/conformance/serve run leaves
+"""Run ledger (ISSUE 18): a train/conformance/serve run can leave
 one fingerprinted, diffable directory.
 
 A :class:`RunLedger` owns a run directory holding:
@@ -11,18 +11,16 @@ A :class:`RunLedger` owns a run directory holding:
   ``telemetry.timeline`` correlate multi-process runs by clock offset.
 * ``telemetry.jsonl`` — the JSONL sink for the run's window: spans,
   events, transfer-ledger records, snapshots (see telemetry/sink.py).
-* ``result.json`` — every result payload the run emitted (bench's JSON
-  line, the train loop's final results, conformance's report doc).
+* ``result.json`` — every result payload the run emitted (the train
+  loop's final results, conformance's report doc).
 * ``snapshot.json`` — the final registry snapshot plus named counter
   blocks (ring ledger stats, memo counters, fleet rollups).
 
 The ledger is OPT-IN and composes with the existing telemetry window
 discipline: ``open()`` saves the global registry's (enabled, sink) pair,
 points the sink at the run directory, and ``finalize()`` restores both —
-so bench.main's save/reset/restore window wraps it cleanly. Metrics are
-NOT reset here; the caller owns the measurement window. When both a
-``--telemetry-jsonl`` path and a run dir are given, the run dir's sink
-wins for the window (documented in docs/telemetry.md).
+so a caller's own enable/restore window wraps it cleanly. Metrics are
+NOT reset here; the caller owns the measurement window.
 
 Hot-path contract: nothing here is ever called per step — ``open`` /
 ``record_result`` / ``add_block`` / ``finalize`` run at run boundaries
@@ -71,7 +69,7 @@ def _git_sha(repo_dir: Optional[str] = None) -> Optional[Dict[str, Any]]:
 def _device_summary() -> Optional[Dict[str, Any]]:
     """Topology of an ALREADY-initialized jax backend; None otherwise.
     Never triggers backend init: a manifest is also written by
-    host-only processes (bench sim mode, conformance), and
+    host-only processes (conformance), and
     ``jax.devices()`` on a cold process would open the default backend
     — an accelerator that may belong to another process."""
     from ddls_tpu.utils.runtime import jax_process_state
@@ -205,9 +203,8 @@ class RunLedger:
             _write_json(self.manifest_path, manifest)
 
     def record_result(self, payload: Dict[str, Any]) -> None:
-        """Append one result payload (the same dict bench's ``emit``
-        prints) and rewrite ``result.json`` — called at reporting
-        boundaries only."""
+        """Append one result payload and rewrite ``result.json`` —
+        called at reporting boundaries only."""
         if not self._opened:
             return
         self._results.append(payload)

@@ -229,11 +229,15 @@ class RLEpochLoop:
       drained per ``metrics_sync_interval`` epochs in one batched fetch
       — never per update — so the steady-state epoch is transfer-free
       (pinned under ``jax.transfer_guard`` in tests/test_fused.py).
-      ``fused_config`` tunes the lane/segment autotuner: ``lanes`` +
-      ``segment_len`` pin the config explicitly (skipping the
-      probe-compile), ``probe_dir`` relocates the autotune cache; when
-      no candidate compiles the build RAISES, naming every probed
-      config. Learners without the scan-based in-kernel contract
+      The program's shape is what the user already said: ``num_envs``
+      lanes x ``rollout_length`` steps, ``num_envs`` meaning what it
+      means on every other loop mode. ``fused_config={"lanes",
+      "segment_len"}`` re-factorises the same per-update batch
+      (both keys, their product unchanged, lanes a multiple of the
+      mesh's ``dp`` axis; anything else raises before a driver is
+      built). A program that does not compile raises the compiler's
+      own error, naming the shape — it never trains on another path.
+      Learners without the scan-based in-kernel contract
       (DQN: host replay insertion; ES: population fitness on host envs)
       reject fused before any env construction.
     """
@@ -407,10 +411,9 @@ class RLEpochLoop:
         # pipeline_depth + 2)
         self.sebulba_config = dict(sebulba_config or {})
         self.actor_mesh = None
-        # fused runtime state: the driver, its autotune decision and
-        # the undrained compact episode-counter traces
+        # fused runtime state: the driver and the undrained compact
+        # episode-counter traces
         self.fused = None
-        self.autotune_result = None
         self._fused_episode_ring: List[Any] = []
         self.metrics_sync_interval = max(int(metrics_sync_interval or 1), 1)
         self.pipeline_depth = int(pipeline_depth or 0)
@@ -734,44 +737,48 @@ class RLEpochLoop:
         return self.learner._train_step
 
     def _build_fused(self) -> None:
-        """Autotune a (lanes, segment_len) config and build the fused
-        epoch driver. ``loop_mode='fused'`` was asked for explicitly:
-        when no candidate compiles this raises, naming every probed
-        config — it never trains on another path instead."""
-        from ddls_tpu.rl import fused as fused_mod
+        """Build the fused epoch driver at ``num_envs`` lanes x
+        ``rollout_length`` steps, or at the ``fused_config`` pin that
+        re-factorises the same per-update batch (validated here, before
+        any bank is sampled or driver built)."""
+        from ddls_tpu.rl.fused import FusedEpochDriver
+
+        cfg = self.fused_config
+        unknown = sorted(set(cfg) - {"lanes", "segment_len"})
+        if unknown:
+            raise ValueError(
+                f"fused_config takes 'lanes' and 'segment_len' only, "
+                f"got {unknown}")
+        if len(cfg) == 1:
+            raise ValueError(
+                "fused_config pins 'lanes' and 'segment_len' together "
+                f"(or neither), got only {sorted(cfg)}")
+        lanes = int(cfg.get("lanes", self.num_envs))
+        segment_len = int(cfg.get("segment_len", self.rollout_length))
+        total = self.num_envs * self.rollout_length
+        if lanes < 1 or lanes * segment_len != total:
+            raise ValueError(
+                f"fused_config lanes ({lanes}) x segment_len "
+                f"({segment_len}) must equal the per-update batch "
+                f"num_envs x rollout_length ({self.num_envs} x "
+                f"{self.rollout_length} = {total})")
+        dp = int(self.mesh.shape["dp"])
+        if lanes % dp:
+            raise ValueError(
+                f"loop_mode='fused': {lanes} lanes do not divide over "
+                f"the mesh's dp axis ({dp})")
 
         env0, et, ot = self._device_tables()
-        dp = int(self.mesh.shape["dp"])
-        total = self.rollout_length * self.num_envs
-        cfg = self.fused_config
-        step_fn = self._fused_step_fn()
         sh_fn = getattr(self.learner, "_state_shardings", None)
         state_shardings = (sh_fn(self.state) if sh_fn is not None
                            else getattr(self.learner, "_replicated",
                                         None))
-
-        def build_driver(lanes, segment_len):
-            return fused_mod.FusedEpochDriver(
-                et, ot, self.model,
-                self._stacked_banks(et, env0, lanes), segment_len,
-                self.updates_per_epoch, train_step_fn=step_fn,
-                state_shardings=state_shardings, mesh=self.mesh,
-                memo_cfg=self._memo_knob())
-
-        self.fused, self.autotune_result = fused_mod.autotune_fused(
-            build_driver, self.state, et, total,
-            self.updates_per_epoch, dp, max_lanes=self.num_envs,
-            probe_dir=cfg.get("probe_dir"),
-            signature_extra=(f"{type(self.learner).__name__}|"
-                             f"{self.model!r}"),
-            lanes=cfg.get("lanes"),
-            segment_len=cfg.get("segment_len"),
+        self.fused = FusedEpochDriver(
+            et, ot, self.model, self._stacked_banks(et, env0, lanes),
+            segment_len, self.updates_per_epoch,
+            train_step_fn=self._fused_step_fn(),
+            state_shardings=state_shardings, mesh=self.mesh,
             memo_cfg=self._memo_knob())
-        if self.fused is None:
-            raise RuntimeError(
-                "loop_mode='fused': no (lanes, segment_len) config "
-                "compiled — probed "
-                f"{[(l, s, e) for l, s, _, e in self.autotune_result.probed]}")
 
     def _split_sebulba_mesh(self) -> None:
         """Partition the configured training mesh into the actor
@@ -1161,7 +1168,7 @@ class RLEpochLoop:
         """The trajectory ring's ledger counters (rl/ring.py stats:
         segments/leases/stalls/occupancy/mean params-age), or None when
         no ring is installed. Host ints only — safe to fetch at a
-        reporting boundary (the bench JSON line's ``ring`` block)."""
+        reporting boundary (the run ledger's ``ring`` block)."""
         ring = getattr(self.vec_env, "traj_ring", None)
         if ring is None:
             # the sebulba device-mode ring lives on the collector, not

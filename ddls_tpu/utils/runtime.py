@@ -1,7 +1,7 @@
 """Process start-up policy: compile-cache placement and backend selection.
 
-Every entry point (train/bench/serve scripts, ``chip_smoke.py``,
-``__graft_entry__.py``, tests/conftest.py) calls
+Every entry point (train/serve scripts, ``benchmarks/run.py``,
+``chip_smoke.py``, ``__graft_entry__.py``, tests/conftest.py) calls
 :func:`configure_compile_cache` before its first jax op, so one
 persistent XLA compilation cache is shared by every process of a run.
 The program uses the backend JAX gives it and never switches platform
@@ -65,7 +65,7 @@ def pin_cpu_platform() -> None:
     """Pin THIS process to the CPU backend before its first jax op.
 
     For children of a process that holds the accelerator (env workers,
-    actor hosts, the bench's simulator rider) and for host-only modes:
+    actor hosts) and for host-only modes:
     a chip belongs to one process, so a child that let jax pick its
     default backend would fail or hang on the parent's chip. The env
     var covers grandchildren; ``jax.config.update`` covers a jax that

@@ -9,7 +9,7 @@ shm unlink pairing.
 
 Run: ``python scripts/lint.py`` (rc 0 clean, 1 flagged; tier-1 via
 tests/test_lint.py). ``--json`` emits machine-readable findings (rule
-id, file, line, message, suppression state) for bench/report tooling;
+id, file, line, message, suppression state) for report tooling;
 ``--rules a,b`` restricts the run; ``--paths`` scans alternate roots
 (the self-tests use synthetic trees).
 

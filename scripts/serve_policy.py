@@ -87,8 +87,7 @@ def obs_from_json(obj: dict) -> dict:
 
 def build_model_from_config(config_path, config_name, overrides):
     """(model, n_actions, graph_feature_dim) — checkpoint-faithful model
-    construction lives with the serve subsystem (bench.py
-    --serve-checkpoint shares it)."""
+    construction lives with the serve subsystem."""
     from ddls_tpu.serve import build_model_from_config as _build
 
     return _build(config_path, config_name, overrides)
@@ -144,20 +143,64 @@ def template_obs(max_nodes: int, max_edges: int, n_actions: int,
     }
 
 
+def dataset_pad_bounds(dataset_dir: str) -> dict:
+    """Max op/dep counts over a dataset's graph files: the tight
+    observation pad and the top of the serving bucket ladder (padded
+    rows are fully masked, so a tighter pad changes no output bit)."""
+    from ddls_tpu.demands.jobs_generator import discover_profile_files
+    from ddls_tpu.graphs.readers import read_graph_file
+
+    paths = discover_profile_files(dataset_dir)
+    if not paths:
+        # max_nodes=0 would read as "padding disabled" downstream and
+        # break obs stacking with a far-away shape error
+        raise FileNotFoundError(f"no graph profiles in {dataset_dir}")
+    graphs = [read_graph_file(path) for path in paths]
+    return {"max_nodes": max(g.n_ops for g in graphs),
+            "max_edges": max(g.n_deps for g in graphs)}
+
+
+def selftest_obs_pool(dataset_dir: str, bounds: dict, n_obs: int) -> list:
+    """Real encoded observations: step one canonical-scenario env over
+    the dataset with random valid actions and snapshot each decision's
+    obs (the arriving population a deployed server would see)."""
+    from ddls_tpu.envs import RampJobPartitioningEnvironment
+    from ddls_tpu.scenarios import ScenarioSpec, env_kwargs
+
+    spec = ScenarioSpec(name="serve_selftest", pad_obs=dict(bounds))
+    env = RampJobPartitioningEnvironment(
+        **env_kwargs(spec, dataset_dir=dataset_dir))
+    obs = env.reset(seed=0)
+    rng = np.random.RandomState(0)
+    pool = []
+    while len(pool) < n_obs:
+        pool.append({k: np.copy(v) for k, v in obs.items()})
+        valid = np.flatnonzero(np.asarray(obs["action_mask"]))
+        obs, _, done, _ = env.step(int(rng.choice(valid)))
+        if done:
+            obs = env.reset(seed=len(pool))
+    return pool
+
+
 def run_selftest(args) -> int:
     """End-to-end smoke on CPU: real env obs -> bucketed batched serving,
     then a forced-saturation fallback pass. One JSON line, rc 0 on ok."""
+    import tempfile
+
     import jax
 
-    import bench
     from ddls_tpu.envs.baselines import FixedDegreePacking
+    from ddls_tpu.graphs.synthetic import generate_pipedream_txt_files
     from ddls_tpu.models.policy import GNNPolicy
     from ddls_tpu.serve import PolicyServer, default_buckets
 
-    dataset_dir = bench._make_dataset()
-    pool = bench._serve_obs_pool(dataset_dir, args.selftest_requests)
+    with tempfile.TemporaryDirectory(prefix="serve_selftest_") as dataset_dir:
+        generate_pipedream_txt_files(dataset_dir, n_cnn=3, n_translation=2,
+                                     seed=0, min_ops=8, max_ops=16)
+        bounds = dataset_pad_bounds(dataset_dir)
+        pool = selftest_obs_pool(dataset_dir, bounds,
+                                 args.selftest_requests)
     n_actions = int(np.asarray(pool[0]["action_mask"]).shape[0])
-    bounds = bench._dataset_pad_bounds(dataset_dir)
     buckets = default_buckets(bounds["max_nodes"], bounds["max_edges"])
     model = GNNPolicy(n_actions=n_actions)
     params = model.init(jax.random.PRNGKey(0),

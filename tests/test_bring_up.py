@@ -2,7 +2,6 @@
 backend an entry point accepts, what a child process may touch, and
 which native library gets loaded. All CPU, all cheap — the chip-side
 proof is ``chip_smoke.py`` itself."""
-import json
 import os
 import shutil
 import subprocess
@@ -82,62 +81,26 @@ def test_cache_thresholds_respect_the_callers_environment(monkeypatch):
 def test_require_accelerator_rejects_an_unrequested_cpu(monkeypatch):
     monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
     with pytest.raises(RuntimeError, match="needs an accelerator"):
-        runtime.require_accelerator("the bench")
+        runtime.require_accelerator("serve_policy.py")
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    summary = runtime.require_accelerator("the bench")
+    summary = runtime.require_accelerator("serve_policy.py")
     assert summary["platform"] == "cpu"
     assert set(summary) == {"platform", "device_kind", "device_count"}
 
 
-@pytest.mark.parametrize("mode", ["ppo", "jaxenv", "serve"])
-def test_bench_accelerator_modes_fail_on_unrequested_cpu(
-        mode, monkeypatch, capsys):
+def test_serve_policy_fails_on_unrequested_cpu(monkeypatch, capsys):
     """JAX quietly picks the CPU when it finds no chip; an accelerator
-    measurement must not: rc != 0, an error line, no value — and no
-    platform switch afterwards."""
+    entry point must not: rc != 0, an error line — and no platform
+    switch afterwards."""
     import jax
 
-    import bench
-
-    monkeypatch.delenv("JAX_PLATFORMS")
-    rc = bench.main(["--mode", mode])
-    payload = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rc != 0
-    assert payload["value"] is None
-    assert "needs an accelerator" in payload["error"]
-    assert "JAX_PLATFORMS" not in os.environ
-    assert jax.config.jax_platforms == "cpu"  # the conftest's, untouched
-
-
-def test_serve_policy_fails_on_unrequested_cpu(monkeypatch, capsys):
     import serve_policy
 
     monkeypatch.delenv("JAX_PLATFORMS")
     assert serve_policy.main([]) != 0
     assert "needs an accelerator" in capsys.readouterr().err
-
-
-def test_every_bench_result_line_names_its_device(capsys):
-    import bench
-
-    rc = bench.main(["--mode", "sim", "--sim-seconds", "0.2",
-                     "--num-envs", "2"])
-    payload = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rc == 0, payload
-    assert payload["platform"] == "cpu"
-    assert payload["device_kind"] == "cpu"
-    assert payload["device_count"] == 8
-
-
-def test_unknown_accelerator_kind_is_an_error_not_a_null_mfu():
-    import bench
-
-    dev = types.SimpleNamespace
-    assert bench.peak_flops(dev(platform="tpu",
-                                device_kind="TPU v5 lite")) == 197e12
-    assert bench.peak_flops(dev(platform="cpu", device_kind="cpu")) is None
-    with pytest.raises(KeyError, match="TPU v99"):
-        bench.peak_flops(dev(platform="tpu", device_kind="TPU v99"))
+    assert "JAX_PLATFORMS" not in os.environ
+    assert jax.config.jax_platforms == "cpu"  # the conftest's, untouched
 
 
 # ----------------------------------------------------- native artefact

@@ -163,6 +163,54 @@ class TestRoutingBitEquality:
         for fid, obs in zip(fids, reqs):
             assert out[fid].action == solo.serve_one(obs).action
 
+    def test_trace_driven_two_replica_fleet_answers_everything(
+            self, model_params):
+        """The loadgen trace end to end through a REAL two-replica
+        fleet: arrivals drive the clock, size ranks pick the graph,
+        tenants ride the affinity router — every request is answered
+        exactly once by the policy, latencies are measured from the
+        SCHEDULED arrival, both replicas serve, and the per-replica
+        registries add up to the aggregate."""
+        from ddls_tpu.serve import loadgen
+
+        model, params, _ = model_params
+        trace = loadgen.generate_trace(n_requests=48, base_rps=400.0,
+                                       seed=1, diurnal_period_s=0.4,
+                                       burst_period_s=0.2)
+        loadgen.validate_trace(trace)
+        rng = np.random.default_rng(7)
+        pool = sorted(
+            (_rand_obs(rng, int(rng.integers(2, bn + 1)),
+                       int(rng.integers(1, be + 1)), bn, be)
+             for bn, be in BUCKETS * 6),
+            key=lambda o: int(o["node_split"][0]))
+        clock = _FakeClock()
+        router = _make_fleet(model, params, clock, n_replicas=2)
+        fids, out = [], []
+        for t, frac, tenant in zip(trace["arrival_s"], trace["size_frac"],
+                                   trace["tenant"]):
+            clock.t = float(t)
+            fids.append(router.submit(pool[int(frac * len(pool))],
+                                      now=clock.t, tenant=tenant))
+            out.extend(router.poll(now=clock.t))
+        clock.t = float(trace["arrival_s"][-1]) + 1.0
+        out.extend(router.drain(now=clock.t))
+        assert sorted(r.request_id for r in out) == sorted(fids)
+        assert all(r.source == "policy" for r in out)
+        assert {r.replica for r in out} == {0, 1}
+        arrived = dict(zip(fids, trace["arrival_s"]))
+        assert all(0.0 <= r.latency_s
+                   <= clock.t - arrived[r.request_id] + 1e-9 for r in out)
+        # the deadline (10 ms) bounds every wait but the final drain's
+        assert sum(r.latency_s <= 0.01 + 1e-9 for r in out) >= 40
+        snaps = router.registry_snapshots()
+        assert {"fleet", "aggregate", "r0", "r1"} <= set(snaps)
+        per_replica = [snaps[k]["counters"]["serve.requests"]
+                       for k in ("r0", "r1")]
+        assert all(n > 0 for n in per_replica)
+        assert (snaps["aggregate"]["counters"]["serve.requests"]
+                == sum(per_replica) == 48)
+
     def test_affinity_pins_tenant_to_one_replica(self):
         clock = _FakeClock()
         router = _stub_fleet(clock, n_replicas=3)
@@ -474,8 +522,6 @@ class TestAutoscale:
         snaps = router.registry_snapshots()
         assert "r1" in snaps  # the retired replica's final snapshot
         assert snaps["aggregate"]["counters"]["serve.requests"] == 6
-        router.reset_stats()  # fresh window drops retired history
-        assert "r1" not in router.registry_snapshots()
 
     def test_warm_replica_hook_runs_on_initial_and_scale_up(self):
         """The warm hook runs for the initial fleet and for every
@@ -544,28 +590,32 @@ class TestLoadgen:
             loadgen.validate_trace(
                 dict(a, size_frac=np.asarray(a["size_frac"]) + 1.0))
 
-    def test_slo_summary_coordinated_omission_accounting(self):
-        from ddls_tpu.serve import FleetResponse, loadgen
+    def test_poisson_trace_is_a_function_of_seed_rate_and_n(self):
+        """``diurnal_amplitude=0`` + ``burst_factor=1`` is the plain
+        Poisson process: the same (seed, rate, n) gives the bit-same
+        arrival trace and fingerprint twice, another seed another
+        trace, and the arrivals run at the offered rate."""
+        from ddls_tpu.serve import loadgen
 
-        def resp(latency, source):
-            return FleetResponse(request_id=0, action=None
-                                 if source == "shed" else 8,
-                                 source=source, reason="batched",
-                                 replica=0, bucket_idx=0,
-                                 latency_s=latency)
+        def poisson(seed, rate=400.0, n=2000):
+            return loadgen.generate_trace(
+                n_requests=n, base_rps=rate, seed=seed,
+                diurnal_amplitude=0.0, burst_factor=1.0)
 
-        responses = ([resp(0.01, "policy")] * 6
-                     + [resp(0.2, "fallback")] * 2
-                     + [resp(0.0, "shed")] * 2)
-        s = loadgen.slo_summary(responses, slo_s=0.05, duration_s=2.0)
-        assert s["n_offered"] == 10 and s["n_decided"] == 8
-        # sheds are excluded from the percentiles (their ~0 s refusal
-        # must not deflate the tail) but charged as SLO misses
-        assert s["p999_latency_ms"] == pytest.approx(200.0)
-        assert s["slo_attainment"] == pytest.approx(0.6)
-        assert s["goodput_rps"] == pytest.approx(3.0)
-        assert s["shed_rate"] == pytest.approx(0.2)
-        assert s["degraded_rate"] == pytest.approx(0.2)
+        a, b, c = poisson(1), poisson(1), poisson(2)
+        loadgen.validate_trace(a)
+        np.testing.assert_array_equal(a["arrival_s"], b["arrival_s"])
+        np.testing.assert_array_equal(a["size_frac"], b["size_frac"])
+        assert list(a["tenant"]) == list(b["tenant"])
+        assert loadgen.trace_fingerprint(a) == loadgen.trace_fingerprint(b)
+        assert len(loadgen.trace_fingerprint(a)) == 16
+        assert not np.array_equal(a["arrival_s"], c["arrival_s"])
+        # 2,000 Exp(1/400) gaps: the mean is within 5 sigma / sqrt(n)
+        gaps = np.diff(np.concatenate([[0.0], a["arrival_s"]]))
+        assert gaps.mean() == pytest.approx(1 / 400.0, rel=5 / 2000 ** 0.5)
+        assert (loadgen.generate_trace(n_requests=2000, base_rps=400.0,
+                                       seed=1)["arrival_s"][-1]
+                != a["arrival_s"][-1])  # the shaped default is another load
 
     def test_loadgen_selftest_script(self):
         """CI satellite: the trace-schema validator runs as a tier-1
@@ -578,76 +628,3 @@ class TestLoadgen:
         payload = json.loads(out.stdout.strip().splitlines()[-1])
         assert payload["selftest"] == "ok"
         assert payload["rejected_malformed"] == 4
-
-
-# ------------------------------------------------------------------- bench
-def test_bench_serve_trace_fleet_smoke(capsys):
-    """Acceptance: `bench.py --mode serve --load trace --replicas 2`
-    emits one JSON line with coordinated-omission-correct p50/p99/p999,
-    SLO attainment + goodput, per-replica occupancy, shed and degraded
-    rates, and the (seed, fingerprint, replicas) reproducibility
-    triplet."""
-    import bench
-
-    rc = bench.main(["--mode", "serve", "--load", "trace",
-                     "--replicas", "2", "--serve-requests", "48",
-                     "--serve-rps", "400", "--serve-max-batch", "4",
-                     "--slo-ms", "100"])
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    payload = json.loads(line)
-    assert rc == 0, payload
-    assert payload["metric"] == "serve_decisions_per_sec"
-    assert payload["value"] > 0
-    assert payload["p50_latency_ms"] is not None
-    assert payload["p99_latency_ms"] >= payload["p50_latency_ms"]
-    assert payload["p999_latency_ms"] >= payload["p99_latency_ms"]
-    assert 0.0 <= payload["slo_attainment"] <= 1.0
-    assert payload["goodput_rps"] >= 0.0
-    assert 0.0 <= payload["shed_rate"] <= 1.0
-    assert 0.0 <= payload["degraded_rate"] <= 1.0
-    assert payload["replicas"] == 2
-    assert len(payload["per_replica"]) == 2
-    for s in payload["per_replica"].values():
-        assert "batch_occupancy" in s and "p99_latency_ms" in s
-    load = payload["load"]
-    assert load["mode"] == "trace" and load["seed"] == 1
-    assert len(load["fingerprint"]) == 16
-    # the same seed + knobs must reproduce the same fingerprint
-    from ddls_tpu.serve import loadgen
-
-    trace = loadgen.generate_trace(
-        n_requests=48, base_rps=400.0, seed=1,
-        diurnal_period_s=load["diurnal_period_s"],
-        diurnal_amplitude=load["diurnal_amplitude"],
-        burst_factor=load["burst_factor"],
-        burst_period_s=load["burst_period_s"],
-        burst_duty=load["burst_duty"],
-        size_tail_alpha=load["size_tail_alpha"],
-        n_tenants=load["n_tenants"])
-    assert loadgen.trace_fingerprint(trace) == load["fingerprint"]
-    # per-replica registries rode the telemetry section, with the exact
-    # multi-registry aggregate alongside
-    serve_tele = payload["telemetry"]["serve"]
-    assert "fleet" in serve_tele and "aggregate" in serve_tele
-    replica_keys = [k for k in serve_tele
-                    if k.startswith("r") and k[1:].isdigit()]
-    assert len(replica_keys) == 2
-    agg = serve_tele["aggregate"]["counters"]["serve.requests"]
-    assert agg == sum(serve_tele[k]["counters"]["serve.requests"]
-                      for k in replica_keys)
-
-
-def test_bench_serve_poisson_records_reproducibility_triplet(capsys):
-    """Satellite: the legacy single-replica Poisson line now names its
-    load seed, arrival fingerprint, and resolved replica count."""
-    import bench
-
-    rc = bench.main(["--mode", "serve", "--serve-requests", "24",
-                     "--serve-rps", "400", "--serve-max-batch", "4"])
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    payload = json.loads(line)
-    assert rc == 0, payload
-    assert payload["replicas"] == 1
-    assert payload["load"]["mode"] == "poisson"
-    assert payload["load"]["seed"] == 1
-    assert len(payload["load"]["fingerprint"]) == 16

@@ -11,10 +11,13 @@ process-global).
 
 In-process (f32): the steady-state fused epoch is transfer-free under
 ``jax.transfer_guard("disallow")``; DQN/ES reject loop_mode='fused'
-loudly before any env construction; a fused loop whose program does
-not compile RAISES (never another loop mode); the autotuner units
-(candidate ranking, size model, cache, probe failure) run device-free.
+loudly before any env construction; an unpinned fused loop builds
+``num_envs`` lanes x ``rollout_length`` steps whatever a cache file of
+the retired shape tuner says; a ``fused_config`` pin is validated
+before a driver is built; a fused loop whose program does not compile
+RAISES the compiler's error with the shape (never another loop mode).
 """
+import json
 import os
 import subprocess
 import sys
@@ -201,9 +204,7 @@ def test_fused_epoch_transfer_free_then_harvests(fused_dataset):
             assert np.isfinite(r["learner"]["total_loss"])
             assert r["learner"]["num_updates"] == 2
             assert r["env_steps_this_iter"] == 2 * 2 * 8  # U * T * B
-        assert loop.autotune_result.source == "explicit"
-        assert (loop.autotune_result.lanes,
-                loop.autotune_result.segment_len) == (8, 2)
+        assert (loop.fused.num_lanes, loop.fused.segment_len) == (8, 2)
         # ONE compile for all three epochs: the first call's sim state
         # and rng keys are placed on the mesh exactly as the program
         # returns them (jax keys its jit cache on the mesh an input's
@@ -229,22 +230,105 @@ def test_fused_epoch_transfer_free_then_harvests(fused_dataset):
         loop.close()
 
 
+# ======================================================== the shape rule
+@pytest.mark.parametrize("num_envs,rollout_length", [(8, 2), (4, 4)])
+def test_unpinned_fused_shape_is_num_envs_by_rollout_length(
+        fused_dataset, num_envs, rollout_length):
+    """``num_envs`` means on a fused loop what it means on every other
+    loop mode: without a pin the program has ``num_envs`` lanes of
+    ``rollout_length`` steps (on this 2-device mesh the retired tuner
+    built 2 lanes x 8 steps for both)."""
+    loop = _make_fused_loop(fused_dataset, fused_config=None,
+                            n_devices=2, num_envs=num_envs,
+                            rollout_length=rollout_length)
+    try:
+        assert (loop.fused.num_lanes,
+                loop.fused.segment_len) == (num_envs, rollout_length)
+    finally:
+        loop.close()
+
+
+@pytest.mark.parametrize("lanes,segment_len", [(4, 4), (2, 8)])
+def test_fused_config_pin_refactorises_the_same_batch(fused_dataset, lanes,
+                                                      segment_len):
+    """A pin that is NOT num_envs x rollout_length: the driver and its
+    job banks take the pinned lane count, and an epoch still steps
+    U x num_envs x rollout_length env steps."""
+    loop = _make_fused_loop(
+        fused_dataset, n_devices=2,
+        fused_config={"lanes": lanes, "segment_len": segment_len})
+    try:
+        assert (loop.fused.num_lanes,
+                loop.fused.segment_len) == (lanes, segment_len)
+        assert {int(v.shape[0]) for v in loop.fused._banks.values()} == {
+            lanes}
+        assert loop.fused.env_steps_per_epoch == 2 * 8 * 2
+    finally:
+        loop.close()
+
+
+def test_fused_autotune_cache_on_disk_is_not_read(fused_dataset,
+                                                  monkeypatch, tmp_path):
+    """No file on disk shapes a run. The entry below is keyed as the
+    retired tuner keyed THIS workload (computed at commit cafe656) and
+    names another valid factorisation; that tuner obeyed it."""
+    entry = {"41539d2c4fdb214ca6bea76f": {
+        "lanes": 4, "segment_len": 4,
+        "estimated_bytes": 1, "actual_bytes": 1}}
+    cache = tmp_path / "fused_autotune.json"
+    cache.write_text(json.dumps(entry))
+    monkeypatch.setenv("DDLS_TPU_PROBE_DIR", str(tmp_path))
+    loop = _make_fused_loop(fused_dataset, fused_config=None,
+                            n_devices=2)
+    try:
+        assert (loop.fused.num_lanes, loop.fused.segment_len) == (8, 2)
+    finally:
+        loop.close()
+    assert json.loads(cache.read_text()) == entry
+    assert os.listdir(tmp_path) == ["fused_autotune.json"]
+
+
 # ====================================================== loud rejections
-def test_fused_that_cannot_compile_raises_not_falls_back(fused_dataset,
-                                                         monkeypatch,
-                                                         tmp_path):
-    """An explicitly requested loop_mode='fused' whose program does not
-    compile raises, naming every probed config — it never trains on
-    loop_mode='pipelined' behind a warning."""
+@pytest.mark.parametrize("fused_config,match", [
+    ({"lanes": 8}, "together"),
+    ({"lanes": 4, "segment_len": 2}, "per-update batch"),
+    ({"lanes": 4, "segment_len": 4}, "dp axis"),
+    ({"lanes": 8, "segment_len": 2, "probe_dir": "/tmp/x"}, "probe_dir"),
+], ids=["half_a_pin", "product_mismatch", "lanes_not_multiple_of_dp",
+        "unknown_key"])
+def test_fused_config_pin_is_validated_before_any_driver(
+        fused_dataset, monkeypatch, fused_config, match):
+    """A pin re-factorises the SAME per-update batch (8 envs x 2 steps
+    on the 8-device mesh here) or raises — before a bank is sampled."""
     from ddls_tpu.rl import fused as fused_mod
 
-    monkeypatch.setattr(
-        fused_mod, "probe_compile",
-        lambda build_fn, state: (None, False, None,
-                                 "XlaRuntimeError: program too large"))
-    with pytest.raises(RuntimeError, match="program too large"):
-        _make_fused_loop(fused_dataset,
-                         fused_config={"probe_dir": str(tmp_path)})
+    def no_banks(*a, **kw):
+        raise AssertionError("banks sampled before the pin was checked")
+
+    monkeypatch.setattr(fused_mod, "stacked_job_banks", no_banks)
+    with pytest.raises(ValueError, match=match):
+        _make_fused_loop(fused_dataset, fused_config=fused_config)
+
+
+def test_fused_that_cannot_compile_raises_not_falls_back(fused_dataset):
+    """An explicitly requested loop_mode='fused' whose program does not
+    compile raises the compiler's own error, naming the shape — it
+    never trains on loop_mode='pipelined' behind a warning."""
+    loop = _make_fused_loop(fused_dataset)
+
+    def refuse(*args):
+        raise RuntimeError("RESOURCE_EXHAUSTED: program too large")
+
+    loop.fused._jit_epoch = refuse
+    try:
+        with pytest.raises(RuntimeError, match="program too large") as ei:
+            loop.run()
+        assert any("8 lanes x 2 steps x 2 updates" in note
+                   for note in ei.value.__notes__)
+        assert loop.loop_mode == "fused"
+        assert getattr(loop, "collector", None) is None
+    finally:
+        loop.close()
 
 
 @pytest.mark.parametrize("algo", ["apex_dqn", "es"])
@@ -267,167 +351,6 @@ def test_fused_rejects_multiprocess_and_bad_mode():
                         loop_mode="bogus")
 
 
-# ====================================================== autotuner units
-def test_candidate_configs_rank_and_divide():
-    from ddls_tpu.rl.fused import candidate_configs
-
-    # dp=1: every divisor of the batch up to max_lanes, fewest first
-    assert candidate_configs(64, 1, 8) == [(1, 64), (2, 32), (4, 16),
-                                           (8, 8)]
-    # dp=4: lanes must divide over the dp axis
-    assert candidate_configs(64, 4, 16) == [(4, 16), (8, 8), (16, 4)]
-    # lanes never exceed the requested num_envs
-    assert candidate_configs(64, 4, 4) == [(4, 16)]
-
-
-def test_estimate_monotonic_in_lanes_flat_in_segment():
-    from ddls_tpu.rl.fused import estimate_program_bytes
-
-    cells = 10_000
-    assert (estimate_program_bytes(1, 64, cells)
-            < estimate_program_bytes(8, 8, cells)
-            < estimate_program_bytes(64, 1, cells))
-    # a lax.scan's program does not grow with its length
-    assert (estimate_program_bytes(4, 16, cells)
-            == estimate_program_bytes(4, 1024, cells))
-    # captured table constants count
-    assert (estimate_program_bytes(4, 16, cells)
-            < estimate_program_bytes(4, 16, cells * 10))
-
-
-def test_autotune_cache_roundtrip(tmp_path):
-    from ddls_tpu.rl.fused import (load_cached_config,
-                                   store_cached_config)
-
-    probe_dir = str(tmp_path / "probe")
-    assert load_cached_config(probe_dir, "k") is None
-    store_cached_config(probe_dir, "k", {"lanes": 2, "segment_len": 8,
-                                         "estimated_bytes": 123,
-                                         "actual_bytes": 456})
-    got = load_cached_config(probe_dir, "k")
-    assert got == {"lanes": 2, "segment_len": 8,
-                   "estimated_bytes": 123, "actual_bytes": 456}
-    # corrupt cache reads as a miss, never an error
-    with open(os.path.join(probe_dir, "fused_autotune.json"), "w") as f:
-        f.write("not json")
-    assert load_cached_config(probe_dir, "k") is None
-
-
-class _EtStub:
-    def __init__(self):
-        from ddls_tpu.sim.jax_env import ConfigPads
-
-        self.pads = ConfigPads(n_ops=4, n_deps=4, n_fwd=2, n_parents=1,
-                               max_split=2, n_groups=1, group_edges=1,
-                               n_sync=1, n_o2o=1, n_orig=2, n_blocks=1,
-                               n_deps_used=4)
-        self.n_srv = 8
-        self.n_chan = 1
-        self.types = ["a"]
-        self.degrees = [1, 2]
-        self.max_action = 2
-        self.tables = {"t": np.zeros((4, 4))}
-
-
-class _FailingDriver:
-    def lower(self, state):
-        raise RuntimeError("compiler rejected the program")
-
-
-def test_autotune_reports_failure_when_nothing_compiles(tmp_path):
-    """Every candidate failing to compile returns (None, result) so the
-    caller can raise; every probed config and its error ride the
-    result."""
-    from ddls_tpu.rl.fused import autotune_fused
-
-    driver, result = autotune_fused(
-        lambda lanes, seg: _FailingDriver(), state=None, et=_EtStub(),
-        total_steps=8, updates_per_epoch=1, dp=1, max_lanes=2,
-        probe_dir=str(tmp_path))
-    assert driver is None
-    assert result.source == "failed"
-    assert [(l, s) for l, s, _, _ in result.probed] == [(1, 8), (2, 4)]
-    assert all(not ok for _, _, ok, _ in result.probed)
-    assert all("compiler rejected" in err
-               for _, _, _, err in result.probed)
-    # nothing cached on failure
-    assert not os.path.exists(
-        os.path.join(str(tmp_path), "fused_autotune.json"))
-
-
-def test_autotune_explicit_config_validation(tmp_path):
-    from ddls_tpu.rl.fused import autotune_fused
-
-    with pytest.raises(ValueError, match="both lanes and segment_len"):
-        autotune_fused(lambda l, s: None, None, _EtStub(), 8, 1, 1, 2,
-                       probe_dir=str(tmp_path), lanes=2)
-    with pytest.raises(ValueError, match="must equal the per-update"):
-        autotune_fused(lambda l, s: None, None, _EtStub(), 8, 1, 1, 2,
-                       probe_dir=str(tmp_path), lanes=2, segment_len=2)
-
-
-def test_autotune_cache_hit_skips_probing(tmp_path):
-    """The fused-vs-fallback gate is a pure function of the cached
-    config (multihost rule): a cache hit builds the cached config and
-    never probe-compiles."""
-    from ddls_tpu.rl.fused import (autotune_fused, store_cached_config,
-                                   workload_signature)
-
-    et = _EtStub()
-    key = workload_signature(et, 8, 1, 1, max_lanes=8, extra="x")
-    store_cached_config(str(tmp_path), key,
-                        {"lanes": 2, "segment_len": 4,
-                         "estimated_bytes": 7, "actual_bytes": 9})
-    built = []
-    driver, result = autotune_fused(
-        lambda lanes, seg: built.append((lanes, seg)) or "driver",
-        state=None, et=et, total_steps=8, updates_per_epoch=1, dp=1,
-        max_lanes=8, probe_dir=str(tmp_path), signature_extra="x")
-    assert driver == "driver"
-    assert built == [(2, 4)]
-    assert result.source == "cache"
-    assert (result.lanes, result.segment_len) == (2, 4)
-    assert result.actual_bytes == 9 and result.probed == []
-
-
-def test_workload_signature_keys_everything(tmp_path):
-    from ddls_tpu.rl.fused import workload_signature
-
-    et = _EtStub()
-    base = workload_signature(et, 8, 1, 1)
-    assert workload_signature(et, 8, 1, 1) == base
-    assert workload_signature(et, 16, 1, 1) != base  # batch
-    assert workload_signature(et, 8, 2, 1) != base   # updates/epoch
-    assert workload_signature(et, 8, 1, 2) != base   # mesh width
-    # the lane cap keys too: a cached config can never carry more
-    # lanes than the current run's num_envs allows
-    assert workload_signature(et, 8, 1, 1, max_lanes=4) != base
-    assert workload_signature(et, 8, 1, 1, extra="m") != base
-
-
-def test_autotune_cache_rejects_tampered_entries(tmp_path):
-    """A cached config must satisfy every constraint the prober
-    enforces — lane cap, exact batch factorisation, dp divisibility —
-    or it is re-probed, never obeyed."""
-    from ddls_tpu.rl.fused import (autotune_fused, store_cached_config,
-                                   workload_signature)
-
-    et = _EtStub()
-    key = workload_signature(et, 8, 1, 1, max_lanes=8, extra="x")
-    # segment_len tampered: lanes * segment_len != total_steps
-    store_cached_config(str(tmp_path), key,
-                        {"lanes": 2, "segment_len": 8,
-                         "estimated_bytes": 7, "actual_bytes": 9})
-    driver, result = autotune_fused(
-        lambda lanes, seg: _FailingDriver(), state=None, et=et,
-        total_steps=8, updates_per_epoch=1, dp=1, max_lanes=8,
-        probe_dir=str(tmp_path), signature_extra="x")
-    # the tampered entry was ignored and probing ran (and failed here)
-    assert result.source == "failed"
-    assert len(result.probed) >= 1
-
-
-# ================================================= LazyMetrics (fused)
 def test_lazy_metrics_stacked_dict_mean():
     """The fused epoch shape: one dict of [U]-stacked device arrays,
     reduced as the f64 mean per key (bit-matching the sequential loop's

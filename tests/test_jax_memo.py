@@ -453,14 +453,12 @@ def test_fused_lanes1_memo_on_transfer_free_then_reports(memo_env,
                                                          monkeypatch):
     """The fused loop at lanes=1 (the narrowest program) resolves the
     memo ON, its steady-state epoch stays transfer-free under
-    ``jax.transfer_guard`` (ISSUE 13 acceptance), and the bench-facing
+    ``jax.transfer_guard`` (ISSUE 13 acceptance), and the memo
     counters surface only at the reporting boundary."""
     import jax
 
     from ddls_tpu.train import make_epoch_loop
 
-    monkeypatch.setenv("DDLS_TPU_PROBE_DIR", os.path.join(
-        memo_env["dataset"], "probe"))
     loop = make_epoch_loop(
         "ppo",
         path_to_env_cls=ENV_CLS,

@@ -181,7 +181,7 @@ def test_fsdp_learner_shards_large_kernels_and_trains():
 def test_wide_gnn_fsdp_fits_per_device_budget():
     """ISSUE 19 acceptance: a wide-GNN config whose replicated state
     exceeds a per-device budget trains under fsdp with lower measured
-    peak live bytes (numbers: docs/perf_round13.md / BENCH_r09.json)."""
+    peak live bytes (numbers: docs/perf_round13.md)."""
     BUDGET = 2 * 1024 * 1024  # bytes per device
     model = GNNPolicy(n_actions=trl.N_ACTIONS, out_features_msg=64,
                       out_features_hidden=128, out_features_node=64,

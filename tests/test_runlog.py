@@ -5,9 +5,7 @@ save→swap→restore of global telemetry state), the Perfetto timeline
 builder over synthetic run dirs (span slices, transfer flow arrows,
 ring lifecycle async slices, counter tracks), the end-to-end acceptance
 path — ledger-enabled pipelined AND sebulba training runs merged into
-one trace — and the ``scripts/perf_history.py --check --json`` tier-1
-smoke (structural gate over the committed BENCH artifacts; no bench
-execution).
+one trace.
 """
 import json
 import os
@@ -57,8 +55,8 @@ def test_run_ledger_roundtrip(tmp_path):
     from ddls_tpu.telemetry.runlog import RunLedger, load_run_dir
 
     run_dir = tmp_path / "run"
-    ledger = RunLedger(str(run_dir), kind="bench:sim",
-                       argv=["bench.py", "--mode", "sim"],
+    ledger = RunLedger(str(run_dir), kind="conformance",
+                       argv=["conformance.py", "--spec", "canonical"],
                        config={"num_envs": 4},
                        scenario_fingerprint="abc123")
     assert not telemetry.enabled()
@@ -79,8 +77,8 @@ def test_run_ledger_roundtrip(tmp_path):
 
     run = load_run_dir(str(run_dir))
     man = run["manifest"]
-    assert man["kind"] == "bench:sim"
-    assert man["argv"] == ["bench.py", "--mode", "sim"]
+    assert man["kind"] == "conformance"
+    assert man["argv"] == ["conformance.py", "--spec", "canonical"]
     assert man["config"]["num_envs"] == 4
     assert man["config"]["warmed"] is True  # update_config rewrote it
     assert man["scenario_fingerprint"] == "abc123"
@@ -99,9 +97,8 @@ def test_run_ledger_roundtrip(tmp_path):
 
 
 def test_run_ledger_preserves_active_sink(tmp_path):
-    """A ledger opened inside an existing telemetry window (bench.py's
-    save/enable/restore) must put the PRIOR sink back on finalize, not
-    leave its own."""
+    """A ledger opened inside an existing telemetry window must put
+    the PRIOR sink back on finalize, not leave its own."""
     from ddls_tpu.telemetry import JsonlSink
     from ddls_tpu.telemetry.runlog import RunLedger
 
@@ -321,49 +318,3 @@ def test_pipelined_transfer_free_pin_survives_ledger(runlog_dataset,
             loop.run()
     finally:
         loop.close()
-
-
-# ------------------------------------------------- perf_history (tier-1)
-def test_perf_history_check_json_smoke():
-    """`perf_history.py --check --json` over the committed BENCH
-    artifacts: rc 0, every artifact parses, rows non-empty, rounds
-    monotone — the structural regression gate rides tier-1 without
-    executing any bench."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts",
-                                      "perf_history.py"),
-         "--check", "--json"],
-        capture_output=True, text=True, timeout=120)
-    assert out.returncode == 0, out.stdout + out.stderr
-    doc = json.loads(out.stdout)
-    assert doc["ok"] is True
-    assert doc["structural_problems"] == []
-    assert len(doc["rows"]) >= 10
-    assert all(e["error"] is None for e in doc["artifacts"])
-
-
-def test_perf_history_regression_gate(tmp_path):
-    """--fresh compares a fresh bench line against history: within
-    tolerance passes, a big drop fails with rc 1."""
-    sys.path.insert(0, os.path.join(REPO, "scripts"))
-    try:
-        import perf_history
-    finally:
-        sys.path.pop(0)
-    entries = perf_history.collect_history(sorted(
-        __import__("glob").glob(os.path.join(REPO, "BENCH_r*.json"))))
-    base = perf_history.latest_value(entries, "ppo_env_steps_per_sec")
-    assert base is not None and base["value"] > 0
-    ok_line = tmp_path / "fresh_ok.json"
-    ok_line.write_text(json.dumps({
-        "metric": "ppo_env_steps_per_sec", "value": base["value"]}))
-    verdict = perf_history.regression_check(
-        entries, str(ok_line), "ppo_env_steps_per_sec", 0.3)
-    assert verdict["ok"] is True
-    bad_line = tmp_path / "fresh_bad.json"
-    bad_line.write_text(json.dumps({
-        "metric": "ppo_env_steps_per_sec",
-        "value": base["value"] * 0.5}))
-    verdict = perf_history.regression_check(
-        entries, str(bad_line), "ppo_env_steps_per_sec", 0.3)
-    assert verdict["ok"] is False and "regressed" in verdict["reason"]

@@ -15,8 +15,8 @@ decisions.
 Also pinned: deadline flushes of partial batches, saturation/dead-device
 degradation to the FixedDegreePacking fallback (answers agree with the
 checkpoint-extracted rule; no request is ever dropped), the
-``serve_policy.py --selftest`` front end, and the ``bench.py --mode
-serve`` JSON contract.
+``serve_policy.py --selftest`` front end and its dataset pad bounds, and
+the server registry's accounting of a mixed request stream.
 """
 import json
 import os
@@ -665,58 +665,83 @@ def test_serve_policy_selftest_script():
     assert payload["n_fallback_saturated"] > 0
 
 
-def test_bench_serve_smoke(capsys):
-    """Acceptance: `bench.py --mode serve` emits one JSON line with
-    decisions/sec, p50/p99 latency, batch occupancy and fallback rate on
-    the CPU smoke path."""
-    import bench
-
-    rc = bench.main(["--mode", "serve", "--serve-requests", "48",
-                     "--serve-rps", "400", "--serve-max-batch", "4"])
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    payload = json.loads(line)
-    assert rc == 0, payload
-    assert payload["metric"] == "serve_decisions_per_sec"
-    assert payload["value"] > 0
-    assert payload["p50_latency_ms"] is not None
-    assert payload["p99_latency_ms"] >= payload["p50_latency_ms"]
-    assert 0.0 < payload["batch_occupancy"] <= 1.0
-    assert 0.0 <= payload["fallback_rate"] <= 1.0
-    assert payload["num_requests"] == 48
-    assert payload["n_compiles"] <= len(payload["buckets"])
-    # ISSUE 3 acceptance: the JSON line carries a telemetry section whose
-    # histogram-derived p50/p99 agree with the existing latency fields
-    # (same trailing window; the top-level fields are rounded to 3 dp)
-    tele = payload["telemetry"]
-    assert "bench.run" in tele["spans"]
-    lat = tele["serve"]["histograms"]["serve.latency_s"]
+def test_policy_server_registry_accounts_for_every_request(model_params):
+    """Mixed-size requests through a real server: every request is
+    counted once, every flush has one cause, each bucket compiles at
+    most once, and the registry's latency histogram IS the summary's
+    p50/p99 (same trailing window)."""
+    clock = _FakeClock()
+    server = _make_server(model_params, clock=clock, deadline_s=0.01)
+    rng = np.random.default_rng(11)
+    out = []
+    for i in range(48):
+        bn, be = BUCKETS[int(rng.integers(0, 2))]
+        clock.t = i * 0.004
+        server.submit(_rand_obs(rng, int(rng.integers(2, bn + 1)),
+                                int(rng.integers(1, be + 1)), bn, be),
+                      now=clock.t)
+        out.extend(server.poll(now=clock.t))
+    clock.t += 1.0
+    out.extend(server.drain(now=clock.t))
+    assert sorted(r.request_id for r in out) == list(range(48))
+    assert all(r.source == "policy" for r in out)
+    summary = server.stats.summary()
+    assert summary["n_requests"] == 48
+    assert 0.0 < summary["batch_occupancy"] <= 1.0
+    assert summary["fallback_rate"] == 0.0
+    assert server.stats.n_compiles <= len(BUCKETS)
+    snap = server.stats.registry.snapshot()
+    lat = snap["histograms"]["serve.latency_s"]
     assert lat["count"] == 48
-    assert lat["p50"] * 1e3 == pytest.approx(payload["p50_latency_ms"],
-                                             abs=5e-4)
-    assert lat["p99"] * 1e3 == pytest.approx(payload["p99_latency_ms"],
-                                             abs=5e-4)
-    serve_counters = tele["serve"]["counters"]
-    assert serve_counters["serve.requests"] == 48
-    assert sum(v for k, v in serve_counters.items()
+    assert lat["p50"] * 1e3 == pytest.approx(summary["p50_latency_ms"])
+    assert lat["p99"] * 1e3 == pytest.approx(summary["p99_latency_ms"])
+    assert summary["p99_latency_ms"] >= summary["p50_latency_ms"]
+    counters = snap["counters"]
+    assert counters["serve.requests"] == 48
+    assert sum(v for k, v in counters.items()
                if k.startswith("serve.flush_cause.")) == \
-        serve_counters["serve.flushes"]
+        counters["serve.flushes"]
+    assert {"fill", "deadline"} <= {
+        k.rsplit(".", 1)[1] for k in counters
+        if k.startswith("serve.flush_cause.")}
 
 
-def test_bench_pad_bounds_cache_fingerprints_dataset(tmp_path):
-    """ADVICE r5 item 4: regenerating the dataset at the same path must
-    invalidate the cached pad bounds."""
-    import bench
+def _serve_policy():
+    sys.path.insert(0, os.path.join(REPO, "scripts"))
+    try:
+        import serve_policy
+    finally:
+        sys.path.pop(0)
+    return serve_policy
+
+
+def test_dataset_pad_bounds_reads_the_files_every_time(tmp_path):
+    """The selftest's pad bounds are a straight read of the dataset: a
+    dataset regenerated at the same path with other graph sizes gives
+    other bounds (no cache to go stale)."""
+    from ddls_tpu.graphs.readers import read_graph_file
     from ddls_tpu.graphs.synthetic import generate_pipedream_txt_files
 
+    sp = _serve_policy()
     d = str(tmp_path / "ds")
     os.makedirs(d)
     generate_pipedream_txt_files(d, n_cnn=1, n_translation=0, seed=0,
                                  min_ops=4, max_ops=6)
-    b1 = bench._dataset_pad_bounds(d)
+    b1 = sp.dataset_pad_bounds(d)
+    graphs = [read_graph_file(os.path.join(d, f)) for f in os.listdir(d)]
+    assert b1 == {"max_nodes": max(g.n_ops for g in graphs),
+                  "max_edges": max(g.n_deps for g in graphs)}
     for f in os.listdir(d):
         os.remove(os.path.join(d, f))
     generate_pipedream_txt_files(d, n_cnn=2, n_translation=1, seed=1,
                                  min_ops=10, max_ops=14)
-    b2 = bench._dataset_pad_bounds(d)
+    b2 = sp.dataset_pad_bounds(d)
     assert b2["max_nodes"] >= 10
     assert b2 != b1
+
+
+def test_dataset_pad_bounds_rejects_a_dataset_without_graphs(tmp_path):
+    """max_nodes=0 would read as "padding disabled" downstream: an empty
+    dataset fails at the source."""
+    with pytest.raises(FileNotFoundError, match="no graph profiles"):
+        _serve_policy().dataset_pad_bounds(str(tmp_path))

@@ -2,9 +2,8 @@
 injected clock, thread-safety, snapshot/reset semantics, the
 disabled-path guard on the env hot loop (no metrics, no per-step
 allocations — by counter), probe-outcome events, the JSONL sink +
-report script, serve stats on telemetry primitives, and the bench
-`telemetry` JSON section (sim mode; serve mode is asserted where the
-serve bench smoke already runs, tests/test_serve.py)."""
+report script, serve stats on telemetry primitives, and env-worker
+counters crossing the process boundary."""
 import json
 import os
 import subprocess
@@ -150,10 +149,8 @@ def test_disabled_api_is_near_noop():
     assert telemetry.snapshot() == {}
 
 
-def _tiny_env(dataset_dir):
-    from ddls_tpu.envs import RampJobPartitioningEnvironment
-
-    return RampJobPartitioningEnvironment(
+def _tiny_env_kwargs(dataset_dir):
+    return dict(
         topology_config={"type": "ramp", "kwargs": {
             "num_communication_groups": 2,
             "num_racks_per_communication_group": 2,
@@ -181,6 +178,12 @@ def _tiny_env(dataset_dir):
         reward_function_kwargs={"fail_reward": -1, "success_reward": 1},
         max_simulation_run_time=2e4,
         pad_obs_kwargs={"max_nodes": 64, "max_edges": 256})
+
+
+def _tiny_env(dataset_dir):
+    from ddls_tpu.envs import RampJobPartitioningEnvironment
+
+    return RampJobPartitioningEnvironment(**_tiny_env_kwargs(dataset_dir))
 
 
 def _step_env(env, n_steps, seed=0):
@@ -455,29 +458,38 @@ def test_serve_stats_histogram_agrees_with_exact_percentiles():
     # two ServeStats never share counters (private registries)
     other = ServeStats()
     assert other.n_fallback == 0 and other.summary()["n_flushes"] == 0
-    # registry snapshot is the bench/report surface
+    # registry snapshot is the report surface
     snap = stats.registry.snapshot()
     assert snap["histograms"]["serve.latency_s"]["count"] == 200
 
 
-# ------------------------------------------------------------- bench section
-def test_bench_sim_mode_emits_telemetry_section(capsys):
-    import bench
+# ------------------------------------------------- env-worker boundary
+def test_env_worker_counters_cross_the_process_boundary(dataset_dir):
+    """Host episodes stepped in spawned env workers under
+    ``telemetry.enable()``: the workers mirror the parent's switch and
+    their sim cache/backend counters ride the close ack into the
+    parent's registry (the parent itself stepped no env)."""
+    from ddls_tpu.envs import RampJobPartitioningEnvironment
+    from ddls_tpu.rl.rollout import ParallelVectorEnv
 
-    rc = bench.main(["--mode", "sim", "--sim-seconds", "0.5",
-                     "--num-envs", "2"])
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    payload = json.loads(line)
-    assert rc == 0, payload
-    tele = payload["telemetry"]
-    assert "bench.warmup" in tele["spans"]
-    assert "bench.run" in tele["spans"]
-    # the run span IS the measurement window: value = steps / duration
-    assert tele["spans"]["bench.run"]["total_s"] >= 0.5
-    # sim cache counters crossed the env-worker process boundary
-    counters = tele.get("counters", {})
+    telemetry.enable()
+    vec = ParallelVectorEnv(RampJobPartitioningEnvironment,
+                            _tiny_env_kwargs(dataset_dir), num_envs=2,
+                            backend="pipe")
+    try:
+        obs = vec.reset()
+        for _ in range(4):
+            actions = [int(np.flatnonzero(np.asarray(o["action_mask"]))[-1])
+                       for o in obs]
+            obs, _, _ = vec.step(np.asarray(actions))
+        before = telemetry.snapshot().get("counters", {})
+        assert not any(k.startswith("sim.") for k in before), before
+    finally:
+        vec.close()
+    counters = telemetry.snapshot()["counters"]
     assert any(k.startswith("sim.lookahead_cache.") for k in counters), \
         counters
+    assert any(k.startswith("sim.lookahead.backend.") for k in counters)
 
 
 # =============================== transfer ledger + run ledger (ISSUE 18)
