@@ -55,7 +55,8 @@ from ddls_tpu.agents.block_search import block_shapes_for, factor_pairs
 from ddls_tpu.agents.partitioners import build_partition_action
 from ddls_tpu.graphs.readers import backward_op_id
 from ddls_tpu.sim import jax_memo
-from ddls_tpu.sim.jax_lookahead import DepBlocks, jax_lookahead
+from ddls_tpu.sim.jax_lookahead import (DepBlocks, block_endpoints,
+                                        jax_lookahead)
 from ddls_tpu.sim.partition import partition_graph, partitioned_op_id
 from ddls_tpu.telemetry import scopes
 
@@ -173,9 +174,6 @@ class ConfigPads:
     n_parents: int    # P: padded parent-candidate slots
     max_split: int    # S: maximum sub-ops per op (block side)
     n_groups: int     # G: padded candidate collective groups
-    group_edges: int  # Eg: padded edges per candidate group
-    n_sync: int       # padded 2-edge sync pairs
-    n_o2o: int        # padded one-to-one edges
     n_orig: int       # No: padded original (unpartitioned) op slots
     n_blocks: int     # B: padded dep blocks (original edges + cliques)
     n_deps_used: int  # the largest row's real deps (the rest: padding)
@@ -322,6 +320,42 @@ def _block_coords(graph, pgraph, split_fwd: Dict[str, int], n_forward: int):
     return op_ok, dep_bij, ends[:, 0], ends[:, 1]
 
 
+#: how a block's deps are priced (``blk_kind``; 0 = a padded block)
+BLK_CANDIDATE, BLK_SYNC, BLK_O2O = 1, 2, 3
+
+
+def _block_pricing(c: dict) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """(kind [B], candidate group [B] or -1, sync message size [B]) of
+    one `config_tables_for` row's blocks, from the grouping's edge
+    lists. Raises where a block's deps are not all priced one way (two
+    kinds, two candidate groups, or sync pairs of different message
+    sizes): `jax_price_and_score` gives a block ONE group value."""
+    m = c["n_deps"]
+    kind = np.zeros(m, np.int32)
+    grp = np.full(m, -1, np.int32)
+    msg = np.zeros(m, np.float64)
+    for gi, g in enumerate(c["groups"]):
+        kind[g["edges"]], grp[g["edges"]] = BLK_CANDIDATE, gi
+    for g in c["sync"]:
+        kind[g["edges"]], msg[g["edges"]] = BLK_SYNC, g["msg"]
+    kind[c["o2o_edges"]] = BLK_O2O
+    if not kind.all():
+        raise ValueError("a dep is in no collective group and not "
+                         "one-to-one")
+    block = c["dep_bij"][:, 0]
+    first = np.zeros(len(c["blk_src"]), np.int64)
+    first[block[::-1]] = np.arange(m)[::-1]   # each block's first dep
+    split = ((kind != kind[first][block]) | (grp != grp[first][block])
+             | (msg != msg[first][block]))
+    if split.any():
+        b = int(block[np.argmax(split)])
+        raise ValueError(
+            f"block {b} ({int(c['blk_src'][b])} -> {int(c['blk_dst'][b])})"
+            " is split between collective groups: its deps are not "
+            "priced one way")
+    return kind[first], grp[first], msg[first]
+
+
 def table_slots(c: dict, max_split: int) -> Tuple[np.ndarray, np.ndarray]:
     """(op slot [n], dep slot [m]) of one `config_tables_for` row in the
     stacked tables: host (``finalize()``) index -> table position."""
@@ -341,7 +375,17 @@ def stack_config_tables(per_cfg: Sequence[dict],
     sub-dep lands on are masked by ``op_valid`` / ``dep_valid``, as
     trailing pads were. ``dep_edge`` keeps each dep's host edge index:
     the one place flat order is semantic (`jax_price_and_score`'s SRPT
-    tie-break)."""
+    tie-break).
+
+    `group_collectives` (sim/actions.py) claims whole out-edge sets of a
+    forward op's shards and whole in-edge sets of its backward op's, so
+    a block's deps are priced ONE way (`_block_pricing` checks it, row
+    by row): ``blk_kind`` says which (:data:`BLK_CANDIDATE` collective,
+    :data:`BLK_SYNC` clique, :data:`BLK_O2O`; 0 = padding), ``blk_grp``
+    the candidate group of a block's deps (-1: none) — read the other
+    way, a group's member blocks, whose rows and columns are its member
+    ops — and ``blk_msg`` a clique's message size, the same for each of
+    its 2-edge sync pairs. Pricing needs no index table beyond these."""
     S = int(shape_tables.counts.max())
     n_orig = max(c["n_orig"] for c in per_cfg)
     n_blocks = max((len(c["blk_src"]) for c in per_cfg), default=1) or 1
@@ -356,15 +400,10 @@ def stack_config_tables(per_cfg: Sequence[dict],
                       default=1) or 1,
         max_split=S,
         n_groups=max((len(c["groups"]) for c in per_cfg), default=1) or 1,
-        group_edges=max((len(g["edges"]) for c in per_cfg
-                         for g in c["groups"]), default=1) or 1,
-        n_sync=max((len(c["sync"]) for c in per_cfg), default=1) or 1,
-        n_o2o=max((len(c["o2o_edges"]) for c in per_cfg), default=1) or 1,
     )
     K = len(per_cfg)
     N, M, F, P = pads.n_ops, pads.n_deps, pads.n_fwd, pads.n_parents
-    G, Eg, Sy, O = (pads.n_groups, pads.group_edges, pads.n_sync,
-                    pads.n_o2o)
+    G = pads.n_groups
 
     out = {
         "n_ops": np.zeros(K, np.int32),
@@ -376,14 +415,15 @@ def stack_config_tables(per_cfg: Sequence[dict],
         "num_parents": np.zeros((K, N), np.int32),
         "insertion_rank": np.zeros((K, N), np.int32),
         "dep_valid": np.zeros((K, M), bool),
-        "dep_src": np.zeros((K, M), np.int32),
-        "dep_dst": np.zeros((K, M), np.int32),
         "dep_size": np.zeros((K, M), np.float64),
         "dep_mutual": np.zeros((K, M), bool),
         "dep_sorted_rank": np.zeros((K, M), np.int32),
         "dep_edge": np.full((K, M), M, np.int32),
         "blk_src": np.full((K, n_blocks), -1, np.int32),
         "blk_dst": np.full((K, n_blocks), -1, np.int32),
+        "blk_kind": np.zeros((K, n_blocks), np.int32),
+        "blk_grp": np.full((K, n_blocks), -1, np.int32),
+        "blk_msg": np.zeros((K, n_blocks), np.float64),
         "f_valid": np.zeros((K, F), bool),
         "f_split": np.ones((K, F), np.int32),
         "f_mem": np.zeros((K, F), np.float64),
@@ -391,18 +431,7 @@ def stack_config_tables(per_cfg: Sequence[dict],
         "f_sub_fwd": np.full((K, F, S), -1, np.int32),
         "f_sub_bwd": np.full((K, F, S), -1, np.int32),
         "grp_valid": np.zeros((K, G), bool),
-        "grp_edges": np.full((K, G, Eg), -1, np.int32),
-        "grp_u": np.zeros((K, G, Eg), np.int32),
-        "grp_v": np.zeros((K, G, Eg), np.int32),
-        "grp_edge_valid": np.zeros((K, G, Eg), bool),
         "grp_msg": np.zeros((K, G), np.float64),
-        "sync_valid": np.zeros((K, Sy), bool),
-        "sync_edges": np.full((K, Sy, 2), -1, np.int32),
-        "sync_u": np.zeros((K, Sy), np.int32),
-        "sync_v": np.zeros((K, Sy), np.int32),
-        "sync_msg": np.zeros((K, Sy), np.float64),
-        "o2o_valid": np.zeros((K, O), bool),
-        "o2o_edges": np.zeros((K, O), np.int32),
         "seq_compute": np.zeros(K, np.float64),
     }
     for k, c in enumerate(per_cfg):
@@ -419,14 +448,15 @@ def stack_config_tables(per_cfg: Sequence[dict],
         out["num_parents"][k, ops] = c["num_parents"]
         out["insertion_rank"][k, ops] = c["insertion_rank"]
         out["dep_valid"][k, deps] = True
-        out["dep_src"][k, deps] = ops[c["dep_src"]]
-        out["dep_dst"][k, deps] = ops[c["dep_dst"]]
         out["dep_size"][k, deps] = c["dep_size"]
         out["dep_mutual"][k, deps] = c["dep_mutual"]
         out["dep_sorted_rank"][k, deps] = c["dep_sorted_rank"]
         out["dep_edge"][k, deps] = np.arange(m)
         out["blk_src"][k, :len(c["blk_src"])] = c["blk_src"]
         out["blk_dst"][k, :len(c["blk_dst"])] = c["blk_dst"]
+        for name, per_block in zip(("blk_kind", "blk_grp", "blk_msg"),
+                                   _block_pricing(c)):
+            out[name][k, :len(per_block)] = per_block
         out["f_valid"][k, :f] = True
         out["f_split"][k, :f] = c["f_split"]
         out["f_mem"][k, :f] = c["f_mem"]
@@ -436,24 +466,8 @@ def stack_config_tables(per_cfg: Sequence[dict],
             op_at[c["f_sub_fwd"]]
         out["f_sub_bwd"][k, :f, :c["f_sub_bwd"].shape[1]] = \
             op_at[c["f_sub_bwd"]]
-        for gi, g in enumerate(c["groups"]):
-            ne = len(g["edges"])
-            out["grp_valid"][k, gi] = True
-            out["grp_edges"][k, gi, :ne] = deps[g["edges"]]
-            out["grp_u"][k, gi, :ne] = ops[g["u"]]
-            out["grp_v"][k, gi, :ne] = ops[g["v"]]
-            out["grp_edge_valid"][k, gi, :ne] = True
-            out["grp_msg"][k, gi] = g["msg"]
-        for si, g in enumerate(c["sync"]):
-            out["sync_valid"][k, si] = True
-            ne = len(g["edges"])
-            out["sync_edges"][k, si, :ne] = deps[g["edges"]]
-            out["sync_u"][k, si] = ops[g["u"][0]]
-            out["sync_v"][k, si] = ops[g["v"][0]]
-            out["sync_msg"][k, si] = g["msg"]
-        no = len(c["o2o_edges"])
-        out["o2o_valid"][k, :no] = True
-        out["o2o_edges"][k, :no] = deps[c["o2o_edges"]]
+        out["grp_valid"][k, :len(c["groups"])] = True
+        out["grp_msg"][k, :len(c["groups"])] = [g["msg"] for g in c["groups"]]
         out["seq_compute"][k] = c["seq_compute"]
     return out, pads
 
@@ -685,21 +699,33 @@ def _jnp_all_reduce_time(msg, n_servers, n_racks, n_cgs, *, x, rate,
 
 
 def jax_price_and_score(sc, cfg, tables, st: ShapeTables,
-                        pads: ConfigPads, comm: dict, pair_channel):
+                        pads: ConfigPads, comm: dict):
     """Price every dep of one placed job and build the SRPT lookahead
     scores — the array mirror of `assign_dep_run_times`
     (sim/actions.py:436), `SRPTOpScheduler`/`SRPTDepScheduler`
     (agents/schedulers.py) and the score assembly in
     `build_native_lookahead_arrays` (sim/jax_lookahead.py:186).
 
+    Every operand reaches its dep through the block layout
+    (`stack_config_tables`): a dep (b, i, j) runs from the server of
+    row i of block b to the server of its column j, and is priced by
+    its block's kind — broadcasts, reductions and one-hots over the
+    servers, never an index per dep or per sub-op (one gather or
+    scatter of M elements runs element by element on the chip).
+
     ``sc`` [N] per-op server codes (grid-flattened, -1 pads). Returns
-    (times [M], is_flow [M], chan [M], op_score [N], dep_score [M]).
+    (times [M], is_flow [M], pair_used [n_srv, n_srv], op_score [N],
+    dep_score [M], finite_ok): ``pair_used[x, y]`` — does a flow dep
+    run from server x to server y — is all the channel checks need of
+    a single-channel complete topology (`eval_cfg`).
     """
+    import jax
     import jax.numpy as jnp
 
     C, R, S = st.ramp_shape
     n_srv = C * R * S
-    M, N = pads.n_deps, pads.n_ops
+    M, B, side, G = (pads.n_deps, pads.n_blocks, pads.max_split,
+                     pads.n_groups)
     x = float(comm["x"])
     rate, prop, io = comm["rate"], comm["prop"], comm["io"]
 
@@ -707,27 +733,27 @@ def jax_price_and_score(sc, cfg, tables, st: ShapeTables,
     c_of_np = codes // (R * S)
     r_of_np = (codes // S) % R
     s_of_np = codes % S
-    c_of = jnp.asarray(c_of_np, jnp.int32)
-    r_of = jnp.asarray(r_of_np, jnp.int32)
-    s_of = jnp.asarray(s_of_np, jnp.int32)
 
-    dep_valid = tables["dep_valid"][cfg]
-    dep_src = tables["dep_src"][cfg]
-    dep_dst = tables["dep_dst"][cfg]
-    dep_size = tables["dep_size"][cfg]
+    dep_valid = tables["dep_valid"][cfg].reshape(B, side, side)
+    dep_size = tables["dep_size"][cfg].reshape(B, side, side)
+    blk_kind = tables["blk_kind"][cfg]                    # [B]
+    blk_grp = tables["blk_grp"][cfg]
 
-    scp = jnp.clip(sc, 0)
-    sc_src = scp[jnp.clip(dep_src, 0)]
-    sc_dst = scp[jnp.clip(dep_dst, 0)]
+    # a dep's endpoints: the servers on its block's row and column
+    _, _, src_rows, dst_rows = block_endpoints(
+        sc, DepBlocks(tables["blk_src"][cfg], tables["blk_dst"][cfg]), side)
+    sc_src, sc_dst = src_rows[:, :, None], dst_rows[:, None, :]
+    same = sc_src == sc_dst
     # THE flow predicate, traced: mirrors OpGraph.flow_mask_from_codes
     # (graphs/op_graph.py:268) — the canonical numpy helper cannot run
     # under trace, so this is the one sanctioned re-statement; its parity
     # with the native path is pinned by tests/test_jax_pricing.py's
     # is_flow comparison
-    is_flow = dep_valid & (dep_size > 0) & (sc_src != sc_dst)  # ddls-lint: allow(flow-mask) -- the one sanctioned traced mirror of flow_mask_from_codes: the numpy helper cannot run under jit trace; parity pinned by test_jax_pricing.py
+    is_flow = dep_valid & (dep_size > 0) & ~same  # ddls-lint: allow(flow-mask) -- the one sanctioned traced mirror of flow_mask_from_codes: the numpy helper cannot run under jit trace; parity pinned by test_jax_pricing.py
 
     dt = dep_size.dtype
-    times = jnp.zeros((M + 1,), dt)
+    on_src = jax.nn.one_hot(src_rows, n_srv, dtype=bool)  # [B, S_i, W]
+    on_dst = jax.nn.one_hot(dst_rows, n_srv, dtype=bool)  # [B, S_j, W]
 
     def span_counts(present):
         """Distinct (s, r, c) component counts among present servers;
@@ -737,27 +763,22 @@ def jax_price_and_score(sc, cfg, tables, st: ShapeTables,
             return ((present.astype(dt) @ onehot) > 0).sum(-1).astype(dt)
         return (cnt(s_of_np, S), cnt(r_of_np, R), cnt(c_of_np, C))
 
-    # ---- candidate collective groups (symmetry-tested)
+    # ---- candidate collective groups (symmetry-tested). A group's
+    # edges are its member blocks' deps, so the servers of its sources
+    # (u) and destinations (v), counted with multiplicity, are its
+    # blocks' row and column servers times the deps on each row and
+    # column: equal multisets of u- and v-codes = equal histograms
     grp_valid = tables["grp_valid"][cfg]              # [G]
-    grp_edges = tables["grp_edges"][cfg]              # [G, Eg]
-    grp_u = tables["grp_u"][cfg]
-    grp_v = tables["grp_v"][cfg]
-    grp_ev = tables["grp_edge_valid"][cfg]            # [G, Eg]
     grp_msg = tables["grp_msg"][cfg]                  # [G]
-
-    u_codes = scp[jnp.clip(grp_u, 0)]
-    v_codes = scp[jnp.clip(grp_v, 0)]
-    sentinel = jnp.int32(n_srv + 1)
-    u_sorted = jnp.sort(jnp.where(grp_ev, u_codes, sentinel), axis=1)
-    v_sorted = jnp.sort(jnp.where(grp_ev, v_codes, sentinel), axis=1)
-    symmetric = jnp.all(u_sorted == v_sorted, axis=1) & grp_valid
-
-    G, Eg = grp_u.shape
-    rows = jnp.broadcast_to(jnp.arange(G)[:, None], (G, 2 * Eg))
-    both = jnp.concatenate([u_codes, v_codes], axis=1)
-    both_valid = jnp.concatenate([grp_ev, grp_ev], axis=1)
-    present = jnp.zeros((G, n_srv), bool).at[
-        rows, jnp.clip(both, 0, n_srv - 1)].max(both_valid)
+    on_row = dep_valid.sum(2, dtype=jnp.int32)        # [B, S_i] deps a row
+    on_col = dep_valid.sum(1, dtype=jnp.int32)        # [B, S_j]
+    blk_u = jnp.sum(jnp.where(on_src, on_row[:, :, None], 0), 1)  # [B, W]
+    blk_v = jnp.sum(jnp.where(on_dst, on_col[:, :, None], 0), 1)
+    member = blk_grp[:, None] == jnp.arange(G, dtype=jnp.int32)   # [B, G]
+    grp_u = jnp.sum(jnp.where(member[:, :, None], blk_u[:, None, :], 0), 0)
+    grp_v = jnp.sum(jnp.where(member[:, :, None], blk_v[:, None, :], 0), 0)
+    symmetric = jnp.all(grp_u == grp_v, axis=1) & grp_valid
+    present = (grp_u + grp_v) > 0                     # [G, n_srv]
     n_in_group = present.sum(-1)
     cnt_s, cnt_r, cnt_c = span_counts(present)
     grp_time = _jnp_all_reduce_time(
@@ -765,45 +786,29 @@ def jax_price_and_score(sc, cfg, tables, st: ShapeTables,
         jnp.maximum(cnt_c, 1.0), x=x, rate=rate, prop=prop, io=io)
     grp_time = jnp.where(n_in_group <= 1, jnp.zeros_like(grp_time),
                          grp_time)
+    of_blk = jnp.clip(blk_grp, 0)                     # B indices, not M
+    blk_collective = (blk_kind == BLK_CANDIDATE) & symmetric[of_blk]
 
-    # edges of asymmetric groups fall back to one-to-one pricing
+    # ---- one-to-one pricing: the static one-to-one edges, and the
+    # edges of asymmetric groups, which fall back to it
     # (assign_dep_run_times's extra_e path, sim/actions.py:505-540)
-    e_size = tables["dep_size"][cfg][jnp.clip(grp_edges, 0)]
-    e_same = u_codes == v_codes
-    e_o2o = jnp.where(e_same | (e_size == 0), jnp.zeros_like(e_size),
-                      prop + 2 * io + e_size / rate)
-    e_val = jnp.where(symmetric[:, None], grp_time[:, None], e_o2o)
-    times = times.at[jnp.where(grp_ev, grp_edges, M)].set(e_val)
+    o2o_time = jnp.where(same | (dep_size == 0), jnp.zeros_like(dep_size),
+                         prop + 2 * io + dep_size / rate)
 
     # ---- sync pairs (always collectives; 2 servers or same-server zero)
-    sync_valid = tables["sync_valid"][cfg]            # [Sy]
-    sync_edges = tables["sync_edges"][cfg]            # [Sy, 2]
-    sync_u = scp[jnp.clip(tables["sync_u"][cfg], 0)]
-    sync_v = scp[jnp.clip(tables["sync_v"][cfg], 0)]
-    sync_msg = tables["sync_msg"][cfg]
-    same = sync_u == sync_v
-    scnt_s = jnp.where(s_of[sync_u] == s_of[sync_v], 1.0, 2.0)
-    scnt_r = jnp.where(r_of[sync_u] == r_of[sync_v], 1.0, 2.0)
-    scnt_c = jnp.where(c_of[sync_u] == c_of[sync_v], 1.0, 2.0)
-    sync_time = _jnp_all_reduce_time(sync_msg, scnt_s, scnt_r, scnt_c,
-                                     x=x, rate=rate, prop=prop, io=io)
+    def spans(comp):
+        return jnp.where(comp(sc_src) == comp(sc_dst), 1.0, 2.0)
+    sync_time = _jnp_all_reduce_time(
+        tables["blk_msg"][cfg][:, None, None], spans(lambda c: c % S),
+        spans(lambda c: (c // S) % R), spans(lambda c: c // (R * S)),
+        x=x, rate=rate, prop=prop, io=io)
     sync_time = jnp.where(same, jnp.zeros_like(sync_time), sync_time)
-    sv = sync_valid[:, None] & (sync_edges >= 0)
-    times = times.at[jnp.where(sv, sync_edges, M)].set(
-        jnp.broadcast_to(sync_time[:, None], sync_edges.shape))
 
-    # ---- static one-to-one edges
-    o2o_valid = tables["o2o_valid"][cfg]
-    o2o_edges = tables["o2o_edges"][cfg]
-    o_size = tables["dep_size"][cfg][jnp.clip(o2o_edges, 0)]
-    o_src = sc_src[jnp.clip(o2o_edges, 0)]
-    o_dst = sc_dst[jnp.clip(o2o_edges, 0)]
-    o_val = jnp.where((o_src == o_dst) | (o_size == 0),
-                      jnp.zeros_like(o_size),
-                      prop + 2 * io + o_size / rate)
-    times = times.at[jnp.where(o2o_valid, o2o_edges, M)].set(o_val)
-
-    times = times[:M]
+    times = jnp.where(
+        (blk_kind == BLK_SYNC)[:, None, None], sync_time,
+        jnp.where(blk_collective[:, None, None],
+                  grp_time[of_blk][:, None, None], o2o_time)).reshape(M)
+    dep_valid, is_flow = dep_valid.reshape(M), is_flow.reshape(M)
     # the cluster zeroes non-flow dep run times at mount
     # (cluster.py:_register_running_job:708-718); SRPT ranking below uses
     # the RAW priced times because the schedulers run before the mount
@@ -816,8 +821,9 @@ def jax_price_and_score(sc, cfg, tables, st: ShapeTables,
     # "edge order" is the HOST's: the tables are in block order, so ties
     # break on each slot's own edge index, not on its position
     order = jnp.lexsort((tables["dep_edge"][cfg], cost_key))
-    dep_pri = jnp.zeros((M,), dt).at[order].set(
-        jnp.arange(M, dtype=dt))
+    # each dep's rank = the inverse permutation, by a second sort: a
+    # scatter of M elements is the loop the first paragraph names
+    dep_pri = jnp.argsort(order).astype(dt)
     # the lookahead engines read dep priorities off the channel mounts, so
     # only FLOW deps carry their SRPT rank; non-flows score with priority 0
     # (build_native_lookahead_arrays:249-263 prices flow_idx only)
@@ -839,14 +845,47 @@ def jax_price_and_score(sc, cfg, tables, st: ShapeTables,
     op_score = op_pri * (n + 1) + (
         n - tables["op_sorted_rank"][cfg].astype(dt))
 
-    # ---- channels (single-channel complete topology: the direct link)
-    chan = jnp.where(is_flow,
-                     pair_channel[sc_src, sc_dst], jnp.int32(-1))
+    # ---- channels (single-channel complete topology: a flow rides the
+    # direct link of its ordered server pair): which pairs carry one,
+    # over j into the destination server, then over (b, i) from the source
+    to_dst = jnp.any(is_flow.reshape(B, side, side)[:, :, :, None]
+                     & on_dst[:, None, :, :], axis=2)  # [B, S_i, W]
+    pair_used = jnp.any(on_src[:, :, :, None] & to_dst[:, :, None, :],
+                        axis=(0, 1))                   # [W, W]
     # the host raises on non-finite priced times (comm_model.py:99-100,
     # actions.py:541-543); a traced kernel cannot, so callers must treat
     # finite_ok=False as that hard failure
     finite_ok = jnp.all(jnp.isfinite(mounted_times))
-    return mounted_times, is_flow, chan, op_score, dep_score, finite_ok
+    return mounted_times, is_flow, pair_used, op_score, dep_score, finite_ok
+
+
+def pair_channel_one_hot(pair_channel, n_chan: int):
+    """Which channel an ordered server pair's direct link is, as a
+    one-hot [n_srv, n_srv, n_chan] bool (the diagonal, -1, is no
+    channel): `placement_masks`'s static operand."""
+    import jax.numpy as jnp
+
+    return jnp.asarray(
+        np.asarray(pair_channel)[:, :, None] == np.arange(n_chan))
+
+
+def placement_masks(ots, op_valid, pair_used, pair_is_chan, chan_occ):
+    """What a placed job would occupy, and whether it may: (ok_chan —
+    no channel its flows ride is another job's; chan_mask [n_chan] —
+    those channels; srv_mask [n_srv] — the servers its ops sit on).
+    ``pair_used`` [n_srv, n_srv] is `jax_price_and_score`'s,
+    ``pair_is_chan`` `pair_channel_one_hot`'s: reductions over the
+    servers, where a per-dep channel vector would be indexed dep by
+    dep."""
+    import jax.numpy as jnp
+
+    chan_mask = jnp.any(pair_is_chan & pair_used[:, :, None], axis=(0, 1))
+    ok_chan = ~jnp.any(chan_mask & (chan_occ >= 0))
+    n_srv = pair_used.shape[0]
+    srv_mask = jnp.any(
+        (ots[:, None] == jnp.arange(n_srv, dtype=ots.dtype))
+        & op_valid[:, None], axis=0)
+    return ok_chan, chan_mask, srv_mask
 
 
 # =========================================================================
@@ -1077,6 +1116,7 @@ def _episode_kernels(et: EpisodeTables):
     deg_col = jnp.asarray(deg_col)
     eps = et.eps
     sim_end = et.sim_end
+    pair_is_chan = pair_channel_one_hot(et.pair_channel, n_chan)
 
     # scenario inflation mirror (ddls_tpu/scenarios/failures.py): same
     # shared f64 formula the host applies at lookahead registration —
@@ -1127,13 +1167,11 @@ def _episode_kernels(et: EpisodeTables):
             ots, new_mem, ok_place = jax_allocate_job(
                 mem, other_free, cfg, et.tables, st, pads)
         with jax.named_scope(scopes.SIM_PRICE):
-            times, is_flow, chan, op_score, dep_score, finite_ok = \
-                jax_price_and_score(ots, cfg, et.tables, st, pads,
-                                    et.comm, et.pair_channel)
-        occ_vals = chan_occ[jnp.clip(chan, 0)]
-        ok_chan = jnp.all(~is_flow | (occ_vals < 0))
-
+            times, is_flow, pair_used, op_score, dep_score, finite_ok = \
+                jax_price_and_score(ots, cfg, et.tables, st, pads, et.comm)
         op_valid = et.tables["op_valid"][cfg]
+        ok_chan, chan_mask, srv_mask = placement_masks(
+            ots, op_valid, pair_used, pair_is_chan, chan_occ)
 
         def run_lookahead(skip=None):
             # ``skip`` is the memo probe's hit mask, threaded into the
@@ -1146,9 +1184,8 @@ def _episode_kernels(et: EpisodeTables):
                 et.tables["op_compute"][cfg], op_valid,
                 jnp.where(op_valid, ots, -1), op_score,
                 et.tables["num_parents"][cfg], times,
-                et.tables["dep_valid"][cfg], et.tables["dep_src"][cfg],
-                et.tables["dep_dst"][cfg], et.tables["dep_mutual"][cfg],
-                is_flow, dep_score, chan[:, None],
+                et.tables["dep_valid"][cfg], None, None,
+                et.tables["dep_mutual"][cfg], is_flow, dep_score, None,
                 num_workers=n_srv, num_channels=n_chan, skip=skip,
                 blocks=DepBlocks(et.tables["blk_src"][cfg],
                                  et.tables["blk_dst"][cfg]))
@@ -1167,10 +1204,6 @@ def _episode_kernels(et: EpisodeTables):
                    * et.tables["seq_compute"][cfg].astype(dt) * steps)
         sla_ok = ~(jct > max_jct)
         engine_ok = ok_la & finite_ok
-        srv_mask = jnp.zeros((n_srv,), bool).at[
-            jnp.clip(ots, 0)].max(op_valid & (ots >= 0))
-        chan_mask = jnp.zeros((n_chan,), bool).at[
-            jnp.clip(chan, 0)].max(is_flow)
         return {"ok_place": ok_place, "ok_chan": ok_chan,
                 "engine_ok": engine_ok, "sla_ok": sla_ok, "jct": jct,
                 "new_mem": new_mem, "srv_mask": srv_mask,
@@ -1341,6 +1374,57 @@ def _episode_kernels(et: EpisodeTables):
     return _types.SimpleNamespace(decision=decision, advance=advance,
                                   init_state=init_state,
                                   price_all=price_all, eval_cfg=eval_cfg)
+
+
+#: start-up gauge (`price_dep_indexed_ops`): 0 says pricing and the
+#: channel / server checks reach every dep by block
+PRICE_GAUGE = "sim.price.dep_indexed_ops"
+
+
+def dep_indexed_ops(jaxpr, n_indices: int) -> List[str]:
+    """The gather / scatter equations of ``jaxpr`` (nested jaxprs
+    included, the lookahead's own call left out) that take
+    ``n_indices`` index vectors or more: on the chip each such vector
+    is one serial address computation, so an equation of a dep's worth
+    of them is a loop over the deps whatever else the program does. A
+    row read of a table (one index, a row-long slice) is not one."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "custom_vmap_call":      # jax_lookahead's batching rule
+            continue
+        if name == "gather" or name.startswith("scatter"):
+            if int(np.prod(eqn.invars[1].aval.shape[:-1])) >= n_indices:
+                found.append(name)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    found += dep_indexed_ops(inner, n_indices)
+    return found
+
+
+def price_dep_indexed_ops(et: EpisodeTables) -> int:
+    """How many equations of one traced `eval_cfg` (placement, pricing,
+    channel and server checks; the lookahead excluded) still index by
+    dep: gathers and scatters of at least ``n_blocks * max_split``
+    indices — one per block row, a sixteenth of the dep pad M, so any
+    index of a dep's worth counts and a read of a B- or G-long table
+    does not. Abstract trace, nothing runs."""
+    import jax
+
+    k = _episode_kernels(et)
+    dt = et.tables["dep_size"].dtype
+    bank = {"steps": jax.ShapeDtypeStruct((1,), dt),
+            "sla_frac": jax.ShapeDtypeStruct((1,), dt)}
+    carry = jax.eval_shape(
+        lambda: k.init_state({"arrival_t": jax.numpy.zeros((2,), dt)})[0])
+    i32 = jax.ShapeDtypeStruct((), np.int32)
+    traced = jax.make_jaxpr(
+        lambda bank, carry, row, cfg: k.eval_cfg(bank, carry, row, cfg)[0])(
+            bank, carry, i32, i32)
+    return len(dep_indexed_ops(traced.jaxpr,
+                               et.pads.n_blocks * et.pads.max_split))
 
 
 def make_episode_fn(et: EpisodeTables,

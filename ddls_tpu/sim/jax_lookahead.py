@@ -398,6 +398,27 @@ def _flat_dep_ops(dep_src, dep_dst, dep_channel, num_channels):
     return src_done, count_parents, nominate
 
 
+def block_endpoints(op_worker, blocks: DepBlocks, side: int):
+    """Where a block's deps start and end, without an index per dep:
+    ``from_src`` [B, No] / ``into_dst`` [No, B] (is original op o the
+    block's source / destination) and ``w_src`` [B, S_i] / ``w_dst``
+    [B, S_j], the worker of the sub-op on each of the block's rows and
+    columns, selected from per-sub-op ``op_worker`` [No * S] by those
+    one-hots. An unplaced op (-1) reads as worker 0 — it rides server
+    0's channels exactly as the flat forms' clipped lookups have it —
+    and so does every row of a padded block (-1)."""
+    import jax.numpy as jnp
+
+    No = op_worker.shape[0] // side
+    rows = jnp.arange(No, dtype=jnp.int32)
+    from_src = blocks.src[:, None] == rows[None, :]        # [B, No]
+    into_dst = blocks.dst[None, :] == rows[:, None]        # [No, B]
+    worker = jnp.clip(op_worker, 0).reshape(No, side)
+    w_src = jnp.max(jnp.where(from_src[:, :, None], worker[None], 0), 1)
+    w_dst = jnp.max(jnp.where(into_dst.T[:, :, None], worker[None], 0), 1)
+    return from_src, into_dst, w_src, w_dst
+
+
 def _block_dep_ops(op_worker, blocks: DepBlocks, n_deps: int,
                    num_workers: int):
     """The same three primitives over :class:`DepBlocks` tables: dep
@@ -415,15 +436,7 @@ def _block_dep_ops(op_worker, blocks: DepBlocks, n_deps: int,
         raise ValueError(f"({N}, {n_deps}) is not a block layout of {B} "
                          "blocks")
     No, W = N // S, num_workers
-    rows = jnp.arange(No, dtype=jnp.int32)
-    from_src = blocks.src[:, None] == rows[None, :]        # [B, No]
-    into_dst = blocks.dst[None, :] == rows[:, None]        # [No, B]
-    # endpoint workers of each block's rows and columns; an unplaced op
-    # (-1) rides server 0's channels exactly as the flat caller's
-    # clipped ``pair_channel`` lookup has it
-    worker = jnp.clip(op_worker, 0).reshape(No, S)
-    w_src = jnp.max(jnp.where(from_src[:, :, None], worker[None], 0), 1)
-    w_dst = jnp.max(jnp.where(into_dst.T[:, :, None], worker[None], 0), 1)
+    from_src, into_dst, w_src, w_dst = block_endpoints(op_worker, blocks, S)
     on_src = jax.nn.one_hot(w_src, W, dtype=bool)          # [B, S_i, W]
     on_dst = jax.nn.one_hot(w_dst, W, dtype=bool)          # [B, S_j, W]
 

@@ -857,13 +857,15 @@ class RLEpochLoop:
     def _device_tables(self):
         """Static jitted-env tables from the template env (shared by the
         device collector and the fused epoch driver)."""
-        from ddls_tpu.sim.jax_env import (build_episode_tables,
-                                          build_obs_tables)
+        from ddls_tpu.sim.jax_env import (PRICE_GAUGE, build_episode_tables,
+                                          build_obs_tables,
+                                          price_dep_indexed_ops)
 
         env0 = self.vec_env.envs[0]
         with startup.span("startup.device_tables"):
             et = build_episode_tables(env0)
             ot = build_obs_tables(env0, et)
+        startup.set_gauge(PRICE_GAUGE, price_dep_indexed_ops(et))
         return env0, et, ot
 
     def _device_bank_size(self, env0) -> int:

@@ -203,6 +203,8 @@ class _BlockBuild:
         import jax
         import jax.numpy as jnp
 
+        from flat_pricing import block_endpoint_slots
+
         from ddls_tpu.sim import jax_env as je
         from ddls_tpu.sim.jax_lookahead import DepBlocks, jax_lookahead
 
@@ -214,16 +216,24 @@ class _BlockBuild:
             mem = jnp.full((et.n_srv,), et.worker_mem, tb["dep_size"].dtype)
             ots, _, ok = je.jax_allocate_job(mem, other_free, cfg, tb,
                                              et.st, et.pads)
-            times, is_flow, chan, op_score, dep_score, _ = \
+            times, is_flow, _, op_score, dep_score, _ = \
                 je.jax_price_and_score(ots, cfg, tb, et.st, et.pads,
-                                       et.comm, et.pair_channel)
+                                       et.comm)
             ov = tb["op_valid"][cfg]
+            blocks = DepBlocks(tb["blk_src"][cfg], tb["blk_dst"][cfg])
+            # the flat path's per-dep endpoints and channel, which the
+            # tables no longer carry (pricing reads the blocks)
+            dep_src, dep_dst = block_endpoint_slots(
+                blocks.src, blocks.dst, et.pads.max_split)
+            scp = jnp.clip(ots, 0)
+            chan = jnp.where(
+                is_flow, et.pair_channel[scp[jnp.clip(dep_src, 0)],
+                                         scp[jnp.clip(dep_dst, 0)]], -1)
             return ((tb["op_compute"][cfg], ov, jnp.where(ov, ots, -1),
                      op_score, tb["num_parents"][cfg], times,
-                     tb["dep_valid"][cfg], tb["dep_src"][cfg],
-                     tb["dep_dst"][cfg], tb["dep_mutual"][cfg], is_flow,
-                     dep_score, chan[:, None]),
-                    DepBlocks(tb["blk_src"][cfg], tb["blk_dst"][cfg]), ok)
+                     tb["dep_valid"][cfg], dep_src, dep_dst,
+                     tb["dep_mutual"][cfg], is_flow, dep_score,
+                     chan[:, None]), blocks, ok)
 
         def flat(args, blocks, skip=None):
             del blocks

@@ -142,8 +142,8 @@ def test_a_discarded_lane_runs_no_trips(memo_env):
 #: the degree-16 pads of the shipped dataset (tests/test_jax_pricing.py
 #: counts them from the tables)
 _BENCH_PADS = dict(n_ops=480, n_deps=13312, n_fwd=15, n_parents=2,
-                   max_split=16, n_groups=1, group_edges=1, n_sync=1,
-                   n_o2o=1, n_orig=30, n_blocks=52, n_deps_used=13072)
+                   max_split=16, n_groups=1, n_orig=30, n_blocks=52,
+                   n_deps_used=13072)
 
 
 def test_trip_counters_are_the_hosts_reduction_of_the_trace():
@@ -344,9 +344,12 @@ def test_build_run_leaves_each_startup_span_once(fused_dataset, tmp_path,
         # axis
         assert loop.fused.num_lanes < 128
         minor = loop.fused.et.pads.max_split * loop.fused.num_lanes
+        # ... and pricing and the channel / server checks of the
+        # program's `eval_cfg` index no dep
         assert startup.gauges() == {
             "sim.lookahead.minor_slots": -(-minor // 128) * 128,
-            "sim.lookahead.minor_used": minor}
+            "sim.lookahead.minor_used": minor,
+            "sim.price.dep_indexed_ops": 0}
         assert set(seconds) == {n.removeprefix("startup.")
                                 for n, _, _ in reg.span_intervals()} \
             | set(startup.gauges())
