@@ -13,8 +13,10 @@ Additions over the reference:
 * ``synthetic`` config generates PipeDream-format profiles on the fly (the
   reference's datasets are not distributed with it);
 * ``architecture`` config (``{config: <file>, shapes: [{seq_len,
-  micro_batch}, ...]}``) writes one analytic profile per shape of a public
-  transformer architecture (``graphs/arch.py``), one model name per shape;
+  micro_batch}, ...]}``, and the deployment's cut ``layers`` /
+  ``experts_held`` where it has one) writes one analytic profile per shape
+  of a public transformer architecture (``graphs/arch.py``), one model
+  name per shape;
 * dataset-wide min/max stats for observation normalisation are identical in
   structure (reference: jobs_generator.py:276-333), including the
   fully-connected worst-case bound on partitioned dep totals.
@@ -178,10 +180,18 @@ class JobsGenerator:
             generated_paths = generate_pipedream_txt_files(path_to_files,
                                                            **kw)
         elif architecture is not None:
-            arch_config = arch.load_arch_config(architecture["config"])
+            unknown = set(architecture) - {"config", "shapes", "layers",
+                                           "experts_held"}
+            if unknown:
+                raise ValueError(f"architecture: unknown keys {unknown}")
+            arch_file = arch.load_arch_file(architecture["config"])
+            # (config, shapes, the cut, the family's stated sizes)
+            family = (arch_file["config"], architecture["shapes"],
+                      architecture.get("layers"),
+                      architecture.get("experts_held"),
+                      arch_file.get("training_state"))
             path_to_files = tempfile.mkdtemp(prefix="ddls_tpu_jobs_")
-            generated_paths = arch.write_profiles(
-                path_to_files, arch_config, architecture["shapes"])
+            generated_paths = arch.write_profiles(path_to_files, *family)
         self.path_to_files = path_to_files
 
         file_paths = (sorted(generated_paths) if generated_paths is not None
@@ -201,7 +211,7 @@ class JobsGenerator:
         if synthetic is not None:
             dataset_id = ("synthetic", repr(sorted(synthetic.items())))
         elif architecture is not None:
-            dataset_id = arch.dataset_id(arch_config, architecture["shapes"])
+            dataset_id = arch.dataset_id(*family)
         else:
             stats = []
             for f in file_paths:
@@ -228,6 +238,17 @@ class JobsGenerator:
                 startup.set_gauge(f"graphs.arch.forward_ops.{model}",
                                   len(g.forward_op_ids()))
                 startup.set_gauge(f"graphs.arch.edges.{model}", g.n_deps)
+                # what the job occupies, and the most one of its deps /
+                # sync-clique edges is sized by before any split
+                ops = g.op_ids
+                startup.set_gauge(f"graphs.arch.resident_bytes.{model}",
+                                  sum(g.memory_cost(o) for o in ops))
+                startup.set_gauge(f"graphs.arch.payload_bytes_max.{model}",
+                                  max(g.payload(o) for o in ops))
+                startup.set_gauge(
+                    f"graphs.arch.sync_bytes_max.{model}",
+                    max(g.sync_size(o) for o in ops
+                        if not g.is_forward(o)))
         return graphs, dataset_id
 
     def __len__(self) -> int:

@@ -1,21 +1,43 @@
 """Architecture config -> PipeDream ``.txt`` training-job profile.
 
-A public ``config.json`` of a decoder-only transformer with routed
-SwiGLU experts (the OLMoE family: MHA/GQA attention with q/k norms and
-RoPE, a softmax top-k router, no shared expert), a sequence length and a
-micro-batch in sequences become one forward-pass profile in the format
-``graphs/readers.py:_parse_pipedream_txt`` reads, so reader -> mirror ->
-``Job`` stays the one path every job takes.
+A public ``config.json`` of a decoder-only transformer, a sequence
+length and a micro-batch in sequences become one forward-pass profile in
+the format ``graphs/readers.py:_parse_pipedream_txt`` reads, so reader ->
+mirror -> ``Job`` stays the one path every job takes. What a layer is
+made of is chosen from the config's KEYS, never from its name:
 
-Ops, per layer and in order (``LAYER_OPS``): input RMSNorm; QKV
-projection (the q/k RMSNorms and RoPE folded in); attention core (causal
-softmax(QK^T)V, flash-style: the S x S scores are never written);
-output projection + residual; post-attention RMSNorm; router
-(hidden -> experts, softmax, top-k); expert group (all experts, SwiGLU,
-k per token, balanced routing); combine + residual. Before them the
-embedding, after them the final norm and the LM head (+ loss). Edges:
-the chain, the two residual skips per layer, router -> combine (the
-routing weights) and post-attention norm -> expert group.
+* attention — ``kv_lora_rank`` present: multi-head latent attention
+  (low-rank q and kv paths), and with ``index_topk`` a learned-sparse
+  core behind an indexer (:func:`_latent_sparse_attention`); absent:
+  MHA/GQA with q/k norms and RoPE (:func:`_full_attention`);
+* feed-forward — the first ``first_k_dense_replace`` layers (0 when the
+  key is absent) are dense SwiGLU at ``intermediate_size``; the others
+  route over ``n_routed_experts`` / ``num_experts`` SwiGLU experts,
+  with ``n_shared_experts`` always-on ones beside them when the key is
+  there; ``scoring_func: sigmoid`` picks the bias-corrected sigmoid
+  router, otherwise softmax top-k;
+* ``num_nextn_predict_layers``: that many multi-token-prediction
+  modules (the DeepSeek-V3 form) after the last layer.
+
+Two families are built today: OLMoE (full attention, softmax router:
+embedding, L x [InputNorm, QKVProj, AttnCore, OutProjResidual,
+PostAttnNorm, Router, Experts, CombineResidual], FinalNorm, LMHeadLoss)
+and ``glm_moe_dsa`` (equations beside each op below, ``x`` the normed
+hidden state). Ops are at one granularity in both: each norm, each
+projection, the indexer's projections, index score + top-k, the
+attention core, out-projection + residual, router, shared expert,
+expert group, combine + residual; there is an edge for every true data
+dependency and no other.
+
+**The cut** (``jobs_config.architecture``'s ``layers`` and
+``experts_held``; absent = the config's own): ``layers: {leading_dense,
+following}`` keeps that many dense and expert layers (the others lie on
+further pods as pipeline stages), ``experts_held`` is this pod's share
+of every expert layer's routed experts: the router keeps its published
+width and its experts per token, the expert group holds ``experts_held``
+experts and computes their part for the tokens routed to them (balanced:
+tokens x k x held / experts token-expert pairs); the shared expert and
+everything else are whole. No code stands in for the absent pods.
 
 Costs are ANALYTIC, not profiled (:func:`op_costs` is the whole model):
 
@@ -27,8 +49,16 @@ Costs are ANALYTIC, not profiled (:func:`op_costs` is the whole model):
 * ``backward_compute_time = 2 x forward``;
 * ``activation_size`` = the op's output tensor at ``ACT_BYTES`` an
   element (bf16);
-* ``parameter_size`` = parameters x ``PARAM_BYTES`` (training state:
-  bf16 weight and gradient, fp32 master weight and two Adam moments).
+* ``parameter_size`` = parameters x the resident bytes of one (training
+  state: bf16 weight and gradient, fp32 master weight and two Adam
+  moments = ``PARAM_BYTES``);
+* ``sync_size`` = parameters x the bytes of one that a backward weight
+  sync moves — written ONLY when the architecture file's wrapper states
+  ``training_state`` (beside ``source_url``). A profile with it is a
+  STATED graph (``graphs/readers.py``): an op occupies activation +
+  parameter state, its mirror the activation's gradient, deps carry
+  activations and sync cliques ``sync_size``. Without it the reference's
+  one ``memory_cost`` sizes all three, as for every profiled graph.
 
 FLOPs are 2 per multiply-accumulate plus the small elementwise terms
 written beside each op below; causal attention counts half of S x S.
@@ -38,21 +68,20 @@ from __future__ import annotations
 import hashlib
 import json
 import os
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 from ddls_tpu.hardware.devices import A100
 
 #: bytes of one activation element and of one weight element read (bf16)
 ACT_BYTES = 2
-#: bytes of one parameter in ``parameter_size``: the training state a
-#: worker holds for it (bf16 weight 2 + bf16 gradient 2 + fp32 master 4 +
-#: two fp32 Adam moments 8). The simulator has one ``memory_cost`` =
-#: activation + parameter per op, which occupies the worker AND sizes
-#: every dep of a split op (sim/partition.py:model_split), so this state
-#: is also what a split op's collective moves (ROADMAP R1b)
+#: bytes of one parameter in ``parameter_size`` where the architecture
+#: file states no ``training_state``: the training state a worker holds
+#: for it (bf16 weight 2 + bf16 gradient 2 + fp32 master 4 + two fp32
+#: Adam moments 8)
 PARAM_BYTES = 16
 BACKWARD_OVER_FORWARD = 2.0
 
+#: the OLMoE layer, in profile order
 LAYER_OPS = ("InputNorm", "QKVProj", "AttnCore", "OutProjResidual",
              "PostAttnNorm", "Router", "Experts", "CombineResidual")
 
@@ -60,100 +89,262 @@ _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 
-def load_arch_config(path: str) -> dict:
+def load_arch_file(path: str) -> dict:
     """The architecture file: ``{"source_url": ..., "config": {...}}``
-    (the public ``config.json``'s shape keys). A relative path that does
-    not exist from the working directory is taken from the checkout's
-    root."""
+    (the public ``config.json``'s shape keys) and, where the family
+    states them, ``"training_state": {"resident_bytes_per_parameter",
+    "synced_bytes_per_parameter"}``. A relative path that does not exist
+    from the working directory is taken from the checkout's root."""
     if not os.path.isabs(path) and not os.path.exists(path):
         path = os.path.join(_REPO, path)
     with open(path) as fh:
-        body = json.load(fh)
-    return body["config"]
+        return json.load(fh)
 
 
-def op_costs(config: dict, seq_len: int, micro_batch: int) -> List[dict]:
-    """Forward ops in profile order, each ``{"op_type", "flops",
-    "bytes", "out_elems", "params"}``: FLOPs and bytes moved of one
-    forward pass over ``micro_batch`` sequences of ``seq_len`` tokens,
-    elements of the output tensor, parameters held."""
+def load_arch_config(path: str) -> dict:
+    return load_arch_file(path)["config"]
+
+
+def sparse_keys(seq_len: int, topk: int) -> int:
+    """Keys a causal top-``topk`` sparse core reads over one sequence:
+    query t (1-based) sees min(t, topk) of them."""
+    S, K = int(seq_len), int(topk)
+    if S <= K:
+        return S * (S + 1) // 2
+    return K * (K + 1) // 2 + (S - K) * K
+
+
+def resolve_cut(config: dict, layers: Optional[dict] = None,
+                experts_held: Optional[int] = None) -> Dict[str, int]:
+    """``{"leading_dense", "following", "experts_held"}`` with what the
+    cut leaves out taken from the config."""
+    dense = int(config.get("first_k_dense_replace") or 0)
+    cut = {"leading_dense": dense,
+           "following": int(config["num_hidden_layers"]) - dense,
+           "experts_held": int(config.get("n_routed_experts")
+                               or config["num_experts"])}
+    if layers is not None:
+        unknown = set(layers) - {"leading_dense", "following"}
+        if unknown:
+            raise ValueError(f"architecture layers: unknown {unknown}")
+        cut.update({k: int(v) for k, v in layers.items()})
+    if experts_held is not None:
+        cut["experts_held"] = int(experts_held)
+    return cut
+
+
+class _Graph:
+    """Forward ops (1-based ids in insertion order) and their edges."""
+
+    def __init__(self):
+        self.ops: List[dict] = []
+        self.edges: List[Tuple[int, int]] = []
+
+    def add(self, op_type, flops, nbytes, out_elems, params,
+            inputs: Sequence[int] = ()) -> int:
+        self.ops.append({"op_type": op_type, "flops": float(flops),
+                         "bytes": float(nbytes),
+                         "out_elems": float(out_elems),
+                         "params": float(params)})
+        node = len(self.ops)
+        self.edges += [(u, node) for u in inputs]
+        return node
+
+    def profile_edges(self) -> List[Tuple[int, int]]:
+        """Edges as the profile lists them: the chain (an op to the op
+        written right after it) first, the others in the order added."""
+        chain = [e for e in self.edges if e[1] == e[0] + 1]
+        return chain + [e for e in self.edges if e[1] != e[0] + 1]
+
+
+def build_graph(config: dict, seq_len: int, micro_batch: int,
+                layers: Optional[dict] = None,
+                experts_held: Optional[int] = None) -> _Graph:
+    """The forward pass over ``micro_batch`` sequences of ``seq_len``
+    tokens: per op the FLOPs and bytes moved, elements of the output
+    tensor and parameters held; per data dependency an edge."""
+    cut = resolve_cut(config, layers, experts_held)
     H = int(config["hidden_size"])
     heads = int(config["num_attention_heads"])
-    kv_heads = int(config.get("num_key_value_heads") or heads)
-    head_dim = int(config.get("head_dim") or H // heads)
-    inter = int(config["intermediate_size"])
-    E = int(config["num_experts"])
-    k = int(config["num_experts_per_tok"])
     V = int(config["vocab_size"])
-    L = int(config["num_hidden_layers"])
+    E = int(config.get("n_routed_experts") or config["num_experts"])
+    k = int(config["num_experts_per_tok"])
+    dense_inter = int(config["intermediate_size"])
+    expert_inter = int(config.get("moe_intermediate_size") or dense_inter)
+    shared_inter = int(config.get("n_shared_experts") or 0) * expert_inter
+    sigmoid_router = config.get("scoring_func") == "sigmoid"
+    n_mtp = int(config.get("num_nextn_predict_layers") or 0)
+    held = cut["experts_held"]
     S, B = int(seq_len), int(micro_batch)
     T = S * B                        # tokens of the step
-    q, kv = heads * head_dim, kv_heads * head_dim
-    qkv = q + 2 * kv
+    # token-expert pairs the held experts see under balanced routing
+    pairs = T * k * held // E if T * k * held % E == 0 else T * k * held / E
     A = ACT_BYTES
+    g = _Graph()
 
-    def op(op_type, flops, nbytes, out_elems, params):
-        return {"op_type": op_type, "flops": float(flops),
-                "bytes": float(nbytes), "out_elems": float(out_elems),
-                "params": float(params)}
-
-    def norm(op_type):
+    def norm(op_type, inputs, tokens=T):
         # square, mean, rsqrt-scale, weight: 4 per element
-        return op(op_type, 4 * T * H, A * (2 * T * H + H), T * H, H)
+        return g.add(op_type, 4 * tokens * H,
+                     A * (2 * tokens * H + H), tokens * H, H, inputs)
 
-    layer = [
-        norm("InputNorm"),
+    def _full_attention(stream):
+        """MHA/GQA as OLMoE has it; returns OutProjResidual."""
+        kv_heads = int(config.get("num_key_value_heads") or heads)
+        head_dim = int(config.get("head_dim") or H // heads)
+        q, kv = heads * head_dim, kv_heads * head_dim
+        qkv = q + 2 * kv
+        x = norm("InputNorm", [stream])
         # x W_qkv; q/k RMSNorm (4 per element) and RoPE (3) on q and k
-        op("QKVProj", 2 * T * H * qkv + 7 * T * (q + kv),
-           A * (T * H + H * qkv + q + kv + T * qkv), T * qkv,
-           H * qkv + q + kv),
+        proj = g.add("QKVProj", 2 * T * H * qkv + 7 * T * (q + kv),
+                     A * (T * H + H * qkv + q + kv + T * qkv), T * qkv,
+                     H * qkv + q + kv, [x])
         # QK^T and PV over the causal half of S x S, softmax 5 a score
-        op("AttnCore", B * heads * S * S * (2 * head_dim + 2.5),
-           A * (T * qkv + T * q), T * q, 0),
-        op("OutProjResidual", 2 * T * q * H + T * H,
-           A * (T * q + q * H + 2 * T * H), T * H, q * H),
-        norm("PostAttnNorm"),
-        # x W_r, softmax over E (5 a logit); out: k weights + k indices
-        op("Router", 2 * T * H * E + 5 * T * E,
-           A * (T * H + H * E + 2 * T * k), 2 * T * k, H * E),
-        # gate, up, down for k experts a token, silu * up (4 a value);
-        # balanced routing: every expert that has a token is read once
-        op("Experts", 2 * T * k * 3 * H * inter + 4 * T * k * inter,
-           A * (2 * T * k * H + min(E, T * k) * 3 * H * inter),
-           T * k * H, E * 3 * H * inter),
-        # weighted sum of k expert outputs + residual
-        op("CombineResidual", 2 * T * k * H + T * H,
-           A * (T * k * H + T * k + 2 * T * H), T * H, 0),
-    ]
-    assert tuple(o["op_type"] for o in layer) == LAYER_OPS
-    ops = [op("Embedding", 0, A * 2 * T * H + 4 * T, T * H, V * H)]
-    for _ in range(L):
-        ops.extend(dict(o) for o in layer)
-    ops.append(norm("FinalNorm"))
+        core = g.add("AttnCore", B * heads * S * S * (2 * head_dim + 2.5),
+                     A * (T * qkv + T * q), T * q, 0, [proj])
+        return g.add("OutProjResidual", 2 * T * q * H + T * H,
+                     A * (T * q + q * H + 2 * T * H), T * H, q * H,
+                     [core, stream])
+
+    def _latent_sparse_attention(stream):
+        """MLA with the DSA indexer and top-k sparse core; returns
+        OutProjResidual."""
+        rq, rkv = int(config["q_lora_rank"]), int(config["kv_lora_rank"])
+        dn, dr = (int(config["qk_nope_head_dim"]),
+                  int(config["qk_rope_head_dim"]))
+        dv = int(config["v_head_dim"])
+        ni, di = int(config["index_n_heads"]), int(config["index_head_dim"])
+        topk = int(config["index_topk"])
+        dqk = dn + dr
+        x = norm("InputNorm", [stream])
+        # c_q = RMSNorm(x W_qa): H -> rq, norm 4 an element
+        c_q = g.add("QAProj", 2 * T * H * rq + 4 * T * rq,
+                    A * (T * H + H * rq + rq + T * rq), T * rq,
+                    H * rq + rq, [x])
+        # q = c_q W_qb: rq -> heads x (nope + rope), RoPE (3) on the rope
+        q = g.add("QBProj", 2 * T * rq * heads * dqk + 3 * T * heads * dr,
+                  A * (T * rq + rq * heads * dqk + T * heads * dqk),
+                  T * heads * dqk, rq * heads * dqk, [c_q])
+        # [c_kv ; k_r] = x W_kva: H -> rkv + rope; RMSNorm (4) on c_kv,
+        # RoPE (3) on the one k_r all heads share
+        c_kv = g.add("KVAProj",
+                     2 * T * H * (rkv + dr) + 4 * T * rkv + 3 * T * dr,
+                     A * (T * H + H * (rkv + dr) + rkv + T * (rkv + dr)),
+                     T * (rkv + dr), H * (rkv + dr) + rkv, [x])
+        # [k_n ; v] = c_kv W_kvb: rkv -> heads x (nope + v)
+        kv = g.add("KVBProj", 2 * T * rkv * heads * (dn + dv),
+                   A * (T * rkv + rkv * heads * (dn + dv)
+                        + T * heads * (dn + dv)),
+                   T * heads * (dn + dv), rkv * heads * (dn + dv), [c_kv])
+        # indexer: q^I = c_q W_Iq (rq -> ni x di), k^I = Norm(x W_Ik)
+        # (H -> di, norm 4), w = x W_Iw (H -> ni); RoPE (3) on the rope
+        # part of each q^I head and of k^I
+        index_out = ni * di + di + ni
+        index_w = rq * ni * di + H * di + H * ni
+        idx = g.add("IndexerProj",
+                    2 * T * index_w + 4 * T * di + 3 * T * (ni + 1) * dr,
+                    A * (T * rq + T * H + index_w + di + T * index_out),
+                    T * index_out, index_w + di, [c_q, x])
+        # I[t,s] = sum_j w[t,j] ReLU(q^I[t,j] . k^I[s]) over the causal
+        # half of S x S (dot 2 di, ReLU and weighted sum 2), top-k of
+        # each row; the S x S scores are never written, out = indices
+        select = g.add("IndexScoreTopK", B * S * S / 2 * ni * (2 * di + 2),
+                       A * (T * index_out + T * min(S, topk)),
+                       T * min(S, topk), 0, [idx])
+        # o_t = sum_{s in S_t} softmax(q_t . [k_n,s ; k_r,s]) v_s: a
+        # query reads min(t, topk) keys; QK^T 2 dqk, PV 2 dv, softmax 5
+        core = g.add("SparseAttnCore",
+                     B * sparse_keys(S, topk) * heads * (2 * dqk + 2 * dv + 5),
+                     A * (T * heads * dqk + T * heads * (dn + dv) + T * dr
+                          + T * min(S, topk) + T * heads * dv),
+                     T * heads * dv, 0, [q, kv, c_kv, select])
+        return g.add("OutProjResidual", 2 * T * heads * dv * H + T * H,
+                     A * (T * heads * dv + heads * dv * H + 2 * T * H),
+                     T * H, heads * dv * H, [core, stream])
+
+    attention = (_latent_sparse_attention if "kv_lora_rank" in config
+                 else _full_attention)
+
+    def layer(stream, dense):
+        """One decoder layer on the residual stream op ``stream``;
+        returns the op that carries the stream out."""
+        out_proj = attention(stream)
+        x = norm("PostAttnNorm", [out_proj])
+        if dense:
+            # gate, up, down, silu * up (4 a value), + residual
+            return g.add("DenseMLPResidual",
+                         2 * T * 3 * H * dense_inter + 4 * T * dense_inter
+                         + T * H,
+                         A * (3 * T * H + 3 * H * dense_inter),
+                         T * H, 3 * H * dense_inter, [x, out_proj])
+        if sigmoid_router:
+            # s = sigmoid(x W_r), top-k of s + b (5 a logit), weights
+            # s_sel / sum s_sel x scale (3 a selected expert)
+            router = g.add("Router", 2 * T * H * E + 5 * T * E + 3 * T * k,
+                           A * (T * H + H * E + E + 2 * T * k), 2 * T * k,
+                           H * E + E, [x])
+        else:
+            # x W_r, softmax over E (5 a logit); out: k weights + indices
+            router = g.add("Router", 2 * T * H * E + 5 * T * E,
+                           A * (T * H + H * E + 2 * T * k), 2 * T * k,
+                           H * E, [x])
+        shared = []
+        if shared_inter:
+            # the always-on experts: gate, up, down, silu * up
+            shared = [g.add(
+                "SharedExpert",
+                2 * T * 3 * H * shared_inter + 4 * T * shared_inter,
+                A * (2 * T * H + 3 * H * shared_inter), T * H,
+                3 * H * shared_inter, [x])]
+        # gate, up, down for the pairs routed to the experts held here,
+        # silu * up (4 a value); balanced routing: every held expert
+        # that has a token is read once
+        experts = g.add("Experts",
+                        2 * pairs * 3 * H * expert_inter
+                        + 4 * pairs * expert_inter,
+                        A * (2 * pairs * H
+                             + min(held, pairs) * 3 * H * expert_inter),
+                        pairs * H, held * 3 * H * expert_inter, [router])
+        # weighted sum of the routed outputs (+ the shared one) + residual
+        combine = g.add("CombineResidual",
+                        2 * pairs * H + T * H * (1 + len(shared)),
+                        A * (pairs * H + pairs
+                             + T * H * (2 + len(shared))),
+                        T * H, 0, [experts, out_proj, router, *shared])
+        g.edges.append((x, experts))
+        return combine
+
+    embedding = g.add("Embedding", 0, A * 2 * T * H + 4 * T, T * H, V * H)
+    stream = embedding
+    for i in range(cut["leading_dense"] + cut["following"]):
+        stream = layer(stream, dense=i < cut["leading_dense"])
+    streams = [stream]
+    for _ in range(n_mtp):
+        # h' = [RMSNorm(h_t) ; RMSNorm(Emb(x_{t+1}))] W_eh (2H -> H),
+        # then one full expert layer; the embedding is the main model's
+        # output shifted by a token
+        h = norm("MTPHiddenNorm", [streams[-1]])
+        e = norm("MTPEmbedNorm", [embedding])
+        proj = g.add("MTPProj", 2 * T * 2 * H * H,
+                     A * (2 * T * H + 2 * H * H + T * H), T * H,
+                     2 * H * H, [h, e])
+        streams.append(layer(proj, dense=False))
+    # the final norm and the head (+ loss) hold their parameters once
+    # and run once per stream: the main model's, then each MTP module's
+    tokens = T * len(streams)
+    final = norm("FinalNorm", streams, tokens)
     # logits + softmax cross-entropy (5 a logit); output = the logits
-    ops.append(op("LMHeadLoss", 2 * T * H * V + 5 * T * V,
-                  A * (T * H + H * V + T * V), T * V, H * V))
-    return ops
+    g.add("LMHeadLoss", 2 * tokens * H * V + 5 * tokens * V,
+          A * (tokens * H + H * V + tokens * V), tokens * V, H * V, [final])
+    return g
 
 
-def forward_edges(num_layers: int) -> List[Tuple[int, int]]:
-    """1-based (u, v) edges of the forward pass: the chain, then per
-    layer the two residual skips, router -> combine and post-attention
-    norm -> experts."""
-    n_layer = len(LAYER_OPS)
-    n = 1 + num_layers * n_layer + 2
-    edges = [(i, i + 1) for i in range(1, n)]
-    at = {name: i for i, name in enumerate(LAYER_OPS)}
-    for layer in range(num_layers):
-        first = 2 + layer * n_layer          # this layer's InputNorm
-        stream_in = first - 1                # embedding / previous combine
-        edges += [
-            (stream_in, first + at["OutProjResidual"]),
-            (first + at["OutProjResidual"], first + at["CombineResidual"]),
-            (first + at["Router"], first + at["CombineResidual"]),
-            (first + at["PostAttnNorm"], first + at["Experts"]),
-        ]
-    return edges
+def op_costs(config: dict, seq_len: int, micro_batch: int,
+             layers: Optional[dict] = None,
+             experts_held: Optional[int] = None) -> List[dict]:
+    """Forward ops in profile order, each ``{"op_type", "flops",
+    "bytes", "out_elems", "params"}``."""
+    return build_graph(config, seq_len, micro_batch, layers,
+                       experts_held).ops
 
 
 def forward_time(cost: dict) -> float:
@@ -163,20 +354,30 @@ def forward_time(cost: dict) -> float:
                cost["bytes"] / A100.memory_bandwidth)
 
 
-def profile_text(config: dict, seq_len: int, micro_batch: int) -> str:
+def profile_text(config: dict, seq_len: int, micro_batch: int,
+                 layers: Optional[dict] = None,
+                 experts_held: Optional[int] = None,
+                 training_state: Optional[dict] = None) -> str:
     """The PipeDream ``.txt`` profile. Times carry 17 significant
     digits (a 17 us op must not round to 0.000017)."""
+    resident, synced = PARAM_BYTES, None
+    if training_state is not None:
+        resident = training_state["resident_bytes_per_parameter"]
+        synced = training_state["synced_bytes_per_parameter"]
+    graph = build_graph(config, seq_len, micro_batch, layers, experts_held)
     lines = []
-    costs = op_costs(config, seq_len, micro_batch)
-    for i, cost in enumerate(costs, start=1):
+    for i, cost in enumerate(graph.ops, start=1):
         fwd = forward_time(cost)
-        lines.append(
+        line = (
             f"node{i} -- {cost['op_type']}(id={i}) -- "
             f"forward_compute_time={fwd:.17g}, "
             f"backward_compute_time={BACKWARD_OVER_FORWARD * fwd:.17g}, "
             f"activation_size={cost['out_elems'] * ACT_BYTES:.1f}, "
-            f"parameter_size={cost['params'] * PARAM_BYTES:.1f}")
-    for u, v in forward_edges(int(config["num_hidden_layers"])):
+            f"parameter_size={cost['params'] * resident:.1f}")
+        if synced is not None:
+            line += f", sync_size={cost['params'] * synced:.1f}"
+        lines.append(line)
+    for u, v in graph.profile_edges():
         lines.append(f"node{u} -- node{v}")
     return "\n".join(lines) + "\n"
 
@@ -186,7 +387,10 @@ def model_name(config: dict, seq_len: int, micro_batch: int) -> str:
 
 
 def write_profiles(out_dir: str, config: dict,
-                   shapes: Sequence[Dict[str, int]]) -> List[str]:
+                   shapes: Sequence[Dict[str, int]],
+                   layers: Optional[dict] = None,
+                   experts_held: Optional[int] = None,
+                   training_state: Optional[dict] = None) -> List[str]:
     """One profile per ``{"seq_len", "micro_batch"}`` shape, named by
     the model type and the shape (the file's stem is the job's model
     name); returns the paths."""
@@ -199,20 +403,26 @@ def write_profiles(out_dir: str, config: dict,
         path = os.path.join(
             out_dir, model_name(config, seq_len, micro_batch) + ".txt")
         with open(path, "w") as fh:
-            fh.write(profile_text(config, seq_len, micro_batch))
+            fh.write(profile_text(config, seq_len, micro_batch, layers,
+                                  experts_held, training_state))
         paths.append(path)
     return paths
 
 
-def dataset_id(config: dict, shapes: Sequence[Dict[str, int]]) -> tuple:
+def dataset_id(config: dict, shapes: Sequence[Dict[str, int]],
+               layers: Optional[dict] = None,
+               experts_held: Optional[int] = None,
+               training_state: Optional[dict] = None) -> tuple:
     """What identifies the generated profiles wherever they were
-    written: the config's content, the shapes and the cost model's
-    constants."""
-    body = json.dumps(
-        {"config": config,
-         "shapes": [[int(s["seq_len"]), int(s["micro_batch"])]
-                    for s in shapes],
-         "costs": [ACT_BYTES, PARAM_BYTES, BACKWARD_OVER_FORWARD,
-                   A100.peak_flops, A100.memory_bandwidth]},
-        sort_keys=True)
-    return ("architecture", hashlib.sha1(body.encode()).hexdigest())
+    written: the config's content, the shapes, the cut and stated sizes
+    (where given) and the cost model's constants."""
+    body = {"config": config,
+            "shapes": [[int(s["seq_len"]), int(s["micro_batch"])]
+                       for s in shapes],
+            "costs": [ACT_BYTES, PARAM_BYTES, BACKWARD_OVER_FORWARD,
+                      A100.peak_flops, A100.memory_bandwidth]}
+    stated = {"layers": layers, "experts_held": experts_held,
+              "training_state": training_state}
+    body.update({k: v for k, v in stated.items() if v is not None})
+    return ("architecture", hashlib.sha1(
+        json.dumps(body, sort_keys=True).encode()).hexdigest())

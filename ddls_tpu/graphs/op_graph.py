@@ -28,6 +28,13 @@ class OpGraph:
     Node attributes: ``compute`` (profiled run time on ``device_type``),
     ``memory`` (bytes resident), ``is_forward`` (pass type), and an optional
     fwd<->bwd ``counterpart`` mapping. Edge attribute: ``size`` (bytes moved).
+
+    An op may also STATE what the partitioner otherwise takes from
+    ``memory`` (sim/partition.py): its ``payload`` — the bytes its
+    out-edges carry — and its ``sync`` size — the bytes each edge of its
+    backward weight-sync clique carries. Unstated (``None``, every
+    profiled and synthetic graph), both read as ``memory``: the
+    reference's semantics, where resident bytes size every dep.
     """
 
     def __init__(self, device_type: str = "A100"):
@@ -36,6 +43,8 @@ class OpGraph:
         self._memory: Dict[str, float] = {}
         self._is_forward: Dict[str, bool] = {}
         self._counterpart: Dict[str, Optional[str]] = {}
+        self._payload: Dict[str, Optional[float]] = {}
+        self._sync: Dict[str, Optional[float]] = {}
         self._edge_size: Dict[EdgeId, float] = {}
         self._succ: Dict[str, Dict[str, None]] = {}
         self._pred: Dict[str, Dict[str, None]] = {}
@@ -48,7 +57,9 @@ class OpGraph:
                compute: float,
                memory: float,
                is_forward: bool = True,
-               counterpart: Optional[str] = None) -> None:
+               counterpart: Optional[str] = None,
+               payload: Optional[float] = None,
+               sync: Optional[float] = None) -> None:
         op_id = str(op_id)
         if op_id in self._compute:
             raise ValueError(f"op {op_id!r} already exists in graph")
@@ -56,6 +67,8 @@ class OpGraph:
         self._memory[op_id] = float(memory)
         self._is_forward[op_id] = bool(is_forward)
         self._counterpart[op_id] = counterpart
+        self._payload[op_id] = None if payload is None else float(payload)
+        self._sync[op_id] = None if sync is None else float(sync)
         self._succ.setdefault(op_id, {})
         self._pred.setdefault(op_id, {})
         self._cache = None
@@ -78,7 +91,8 @@ class OpGraph:
             del self._edge_size[(u, op_id)]
             del self._succ[u][op_id]
         for table in (self._compute, self._memory, self._is_forward,
-                      self._counterpart, self._succ, self._pred):
+                      self._counterpart, self._payload, self._sync,
+                      self._succ, self._pred):
             del table[op_id]
         self._cache = None
 
@@ -94,6 +108,8 @@ class OpGraph:
         out._memory = dict(self._memory)
         out._is_forward = dict(self._is_forward)
         out._counterpart = dict(self._counterpart)
+        out._payload = dict(self._payload)
+        out._sync = dict(self._sync)
         out._edge_size = dict(self._edge_size)
         out._succ = {k: dict(v) for k, v in self._succ.items()}
         out._pred = {k: dict(v) for k, v in self._pred.items()}
@@ -128,6 +144,24 @@ class OpGraph:
 
     def memory_cost(self, op_id: str) -> float:
         return self._memory[str(op_id)]
+
+    def stated_payload(self, op_id: str) -> Optional[float]:
+        """Bytes the op's out-edges carry, where the op states them."""
+        return self._payload[str(op_id)]
+
+    def stated_sync(self, op_id: str) -> Optional[float]:
+        """Bytes an edge of the op's sync clique carries, where stated."""
+        return self._sync[str(op_id)]
+
+    def payload(self, op_id: str) -> float:
+        """What a dep out of the op is sized by: the stated payload, or
+        the op's resident bytes (the reference's rule)."""
+        stated = self._payload[str(op_id)]
+        return self._memory[str(op_id)] if stated is None else stated
+
+    def sync_size(self, op_id: str) -> float:
+        stated = self._sync[str(op_id)]
+        return self._memory[str(op_id)] if stated is None else stated
 
     def is_forward(self, op_id: str) -> bool:
         return self._is_forward[str(op_id)]
@@ -173,7 +207,8 @@ class OpGraph:
         out = OpGraph(self.device_type)
         for op in self.forward_op_ids():
             out.add_op(op, self._compute[op], self._memory[op],
-                       is_forward=True, counterpart=self._counterpart[op])
+                       is_forward=True, counterpart=self._counterpart[op],
+                       payload=self._payload[op], sync=self._sync[op])
         for (u, v), size in self._edge_size.items():
             if out.has_op(u) and out.has_op(v):
                 out.add_edge(u, v, size)

@@ -14,6 +14,17 @@ the reference (ddls/utils.py:110-476):
 * an op's ``memory_cost`` is ``activation + parameter`` size and its
   ``compute_cost`` is the profiled forward (resp. backward) time
   (ddls/utils.py:426-431).
+
+A PipeDream profile whose node lines carry a fifth stat, ``sync_size``
+(``graphs/arch.py`` writes it for a family that states its training
+state), is a STATED graph: what an op holds, what its out-edges carry and
+what its backward weight sync moves are three numbers. The forward op
+holds its activation + parameter state, its mirror the activation's
+gradient only (a job's resident state is parameter state + 2 x
+activations, not the parameter state twice); both state their payload
+(the activation) and their sync size (``sync_size``: the gradient), which
+``sim/partition.py`` sizes deps and sync cliques by. A profile without the
+stat is read exactly as the reference reads it.
 """
 from __future__ import annotations
 
@@ -34,6 +45,7 @@ def _parse_pipedream_txt(path: str) -> Tuple[Dict[str, dict], List[Tuple[str, st
 
     Node line:  ``node<i> -- <OpType>(...) -- forward_compute_time=..,
     backward_compute_time=.., activation_size=.., parameter_size=..``
+    and, in a stated profile, ``, sync_size=..`` (-> ``vals["sync"]``)
     Edge line:  ``node<u> -- node<v>``
     (reference parser: ddls/utils.py:278-340).
     """
@@ -65,6 +77,9 @@ def _parse_pipedream_txt(path: str) -> Tuple[Dict[str, dict], List[Tuple[str, st
                         # activations; total = sum (reference: ddls/utils.py:322-324)
                         val = float(np.sum(val))
                     vals[name] = float(val)
+                for field in stats[4:]:
+                    if field.startswith("sync_size="):
+                        vals["sync"] = float(field.split("=")[1])
                 vals["op_type"] = parts[1].split("(")[0]
                 nodes[node_id] = vals
             else:
@@ -85,22 +100,32 @@ def graph_from_pipedream_txt(path: str,
                              verbose: bool = False) -> OpGraph:
     nodes, fwd_edges = _parse_pipedream_txt(path)
     n = len(nodes)
+    stated = any("sync" in vals for vals in nodes.values())
+
+    def sizes(vals: dict, is_forward: bool) -> dict:
+        """memory / payload / sync of an op or of its mirror."""
+        if not stated:
+            return {"memory": vals["activation"] + vals["parameter"]}
+        held = vals["parameter"] if is_forward else 0.0
+        return {"memory": vals["activation"] + held,
+                "payload": vals["activation"],
+                "sync": vals.get("sync", 0.0)}
 
     g = OpGraph(device_type)
     # forward ops
     for op_id, vals in nodes.items():
         g.add_op(op_id,
                  compute=vals["forward"],
-                 memory=vals["activation"] + vals["parameter"],
                  is_forward=True,
-                 counterpart=backward_op_id(op_id, n))
+                 counterpart=backward_op_id(op_id, n),
+                 **sizes(vals, True))
     # mirrored backward ops
     for op_id, vals in nodes.items():
         g.add_op(backward_op_id(op_id, n),
                  compute=vals["backward"],
-                 memory=vals["activation"] + vals["parameter"],
                  is_forward=False,
-                 counterpart=op_id)
+                 counterpart=op_id,
+                 **sizes(vals, False))
 
     activation = {op: vals["activation"] for op, vals in nodes.items()}
     for bop, fop in ((backward_op_id(op, n), op) for op in nodes):

@@ -39,6 +39,7 @@ from typing import Callable, Dict, Optional, Tuple
 import numpy as np
 
 from ddls_tpu import telemetry
+from ddls_tpu.sim.jax_env import MASK_GAUGES
 from ddls_tpu.sim.jax_lookahead import MINOR_GAUGES
 from ddls_tpu.telemetry import scopes, startup
 
@@ -102,15 +103,30 @@ def stacked_job_banks(et, env, n_lanes: int, n_jobs: int,
 #: (``make_segment_fn(trace_trips=True)``) that
 #: ``record_lookahead_trips`` reads while telemetry is on, and the
 #: decision's job type and action, from which ``record_padding_fill``
-#: finds the (model, degree) row each decision ran. ``la_trips``,
-#: ``jtype`` and ``action`` are read only while telemetry is on; if the
-#: drain ever shows in ``device_idle_share``, gate the three together
+#: finds the (model, degree) row each decision ran, and its verdict and
+#: the occupied-server count it saw (``record_decisions``). ``la_trips``,
+#: ``jtype``, ``action``, ``accepted`` and ``n_occupied`` are read only
+#: while telemetry is on; if the drain ever shows in
+#: ``device_idle_share``, gate the five together
 EPISODE_TRACE_KEYS = ("done", "ep_return", "ep_blocked", "ep_completed",
-                      "ep_arrived", "la_trips", "jtype", "action")
+                      "ep_arrived", "la_trips", "jtype", "action",
+                      "accepted", "n_occupied")
 
 #: ``sim.lookahead.trips_per_call`` buckets: the loop is bounded by
 #: ops + deps + 4 trips (13,556 at the degree-16 pads)
 _TRIP_BUCKETS = tuple(2.0 ** i for i in range(15))
+
+
+def _count_startup_gauges(names) -> None:
+    """Add each set start-up gauge onto the telemetry counter of its
+    name, once per drained epoch trace (telemetry is off while a program
+    is traced or built, so such sizes live in the start-up registry).
+    The named gauges alone: ``startup.gauges()`` snapshots the whole
+    start-up registry (thousands of jax spans)."""
+    for name in names:
+        value = startup.registry().gauge(name).value
+        if value is not None:
+            telemetry.inc(name, int(value))
 
 
 def record_lookahead_trips(ep_trace, pads) -> None:
@@ -145,12 +161,7 @@ def record_lookahead_trips(ep_trace, pads) -> None:
                           buckets=_TRIP_BUCKETS)
     telemetry.inc("sim.lookahead.dep_slots", int(pads.n_deps))
     telemetry.inc("sim.lookahead.dep_slots_used", int(pads.n_deps_used))
-    # the two gauges alone: ``startup.gauges()`` snapshots the whole
-    # start-up registry (thousands of jax spans), per drained epoch
-    for name in MINOR_GAUGES:
-        traced = startup.registry().gauge(name).value
-        if traced is not None:
-            telemetry.inc(name, int(traced))
+    _count_startup_gauges(MINOR_GAUGES)
 
 
 def record_padding_fill(ep_trace, et, ot) -> None:
@@ -180,6 +191,25 @@ def record_padding_fill(ep_trace, et, ot) -> None:
                   int(ot["node_split"][:, 0][jtype].sum()))
     telemetry.inc("env.obs.nodes_padded",
                   jtype.size * int(ot["node_features"].shape[1]))
+
+
+def record_decisions(ep_trace, et) -> None:
+    """What the decisions met, from a FETCHED ``[..., B, T]`` trace:
+    ``env.decisions.offered`` — decisions taken — beside
+    ``env.decisions.accepted`` — those whose job was mounted;
+    ``env.cluster.occupied_servers`` — the servers other jobs held when
+    each decision was taken, summed — beside ``env.cluster.servers`` —
+    decisions x the cluster's servers. And from the mask's start-up
+    gauges (`sim/jax_env.py:mask_rows_on_empty_cluster`), once per
+    drained epoch trace: ``env.mask.rows_offered`` / ``rows_placeable``.
+    The caller gates on ``telemetry.enabled()``."""
+    accepted = np.asarray(ep_trace["accepted"])
+    telemetry.inc("env.decisions.offered", int(accepted.size))
+    telemetry.inc("env.decisions.accepted", int(accepted.sum()))
+    telemetry.inc("env.cluster.occupied_servers",
+                  int(np.asarray(ep_trace["n_occupied"]).sum()))
+    telemetry.inc("env.cluster.servers", int(accepted.size) * et.n_srv)
+    _count_startup_gauges(MASK_GAUGES)
 
 
 class FusedEpochDriver:

@@ -65,7 +65,8 @@ class ScenarioSpec:
     # fabric: any hardware/topologies.py build_topology config
     topology: dict = dataclasses.field(default_factory=_canonical_topology)
     node_config: dict = dataclasses.field(default_factory=_canonical_nodes)
-    # workload: graphs/synthetic.py generate_pipedream_txt_files knobs
+    # workload: graphs/synthetic.py generate_pipedream_txt_files knobs,
+    # or {"architecture": <JobsGenerator's architecture config>}
     jobs: dict = dataclasses.field(default_factory=lambda: {
         "n_cnn": 2, "n_translation": 1, "seed": 0,
         "min_ops": 4, "max_ops": 6})
@@ -262,6 +263,8 @@ def jobs_config(spec: ScenarioSpec, dataset_dir: Optional[str] = None) -> dict:
     }
     if dataset_dir is not None:
         cfg["path_to_files"] = dataset_dir
+    elif "architecture" in spec.jobs:
+        cfg["architecture"] = dict(spec.jobs["architecture"])
     else:
         cfg["synthetic"] = dict(spec.jobs)
     return cfg

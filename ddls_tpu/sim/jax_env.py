@@ -1427,6 +1427,45 @@ def price_dep_indexed_ops(et: EpisodeTables) -> int:
                                et.pads.n_blocks * et.pads.max_split))
 
 
+#: start-up gauges (`mask_rows_on_empty_cluster`), in this order
+MASK_GAUGES = ("env.mask.rows_offered", "env.mask.rows_placeable")
+
+
+def mask_rows_on_empty_cluster(env, et: EpisodeTables, ot: dict
+                               ) -> Tuple[int, int]:
+    """Of the (model, degree) rows the action mask offers on an EMPTY
+    cluster (`_kernel_action_mask` at 0 occupied servers; action 0 is no
+    row), how many the allocator places there: the host's
+    `agents/placers.py:allocate_job`, which `jax_allocate_job` mirrors,
+    on an all-free RAMP at full memory (~0.1 s for 24 rows of 131 ops;
+    the jitted allocator over the same rows costs seconds of tracing at
+    every start). The mask knows occupancy and block shapes, not memory
+    or a row's splits, so the difference is what it offers and placement
+    then blocks. Once, at set-up."""
+    from ddls_tpu.agents.placers import allocate_job
+
+    topo = env.cluster.topology
+    graphs = {proto.details["model"]: proto.graph
+              for proto in env.cluster.jobs_generator.sampler.prototypes}
+    servers = {topo.parse_server_id(s) for s in topo.server_ids}
+    mask = np.asarray(_kernel_action_mask(ot, et, 0))
+    offered = placeable = 0
+    for model in et.types:
+        graph = graphs[model]
+        forward = graph.forward_view()
+        for degree in (d for d in et.degrees if mask[d]):
+            action = build_partition_action(
+                graph, env.min_op_run_time_quantum, degree)
+            split_fwd = {op: n for op, n in action.items() if n > 1}
+            ramp = {s: {"mem": et.worker_mem, "job_idxs": set()}
+                    for s in servers}
+            offered += 1
+            placeable += allocate_job(
+                ramp, topo.shape, forward, graph, split_fwd, servers,
+                topo.shape, 0) is not None
+    return offered, placeable
+
+
 def make_episode_fn(et: EpisodeTables,
                     memo_cfg: Optional[jax_memo.MemoConfig]
                     = DEFAULT_EPISODE_MEMO):
@@ -1835,7 +1874,10 @@ def make_segment_fn(et: EpisodeTables, ot: dict, model, n_steps: int,
     that runs no lookahead). A program counter, not a simulation result
     — the collectors that drain it (``EPISODE_TRACE_KEYS``) ask for it;
     the host reduces it into the ``sim.lookahead.*`` telemetry counters
-    (`rl/fused.py:record_lookahead_trips`).
+    (`rl/fused.py:record_lookahead_trips`). With it rides ``accepted``
+    (bool), the decision's verdict, which with the ``n_occupied`` field
+    the decision saw becomes ``env.decisions.*`` / ``env.cluster.*``
+    (`rl/fused.py:record_decisions`).
     """
     import jax
     import jax.numpy as jnp
@@ -1929,6 +1971,7 @@ def make_segment_fn(et: EpisodeTables, ot: dict, model, n_steps: int,
                 out["obs"] = obs
             if trace_trips:
                 out["la_trips"] = la_trips
+                out["accepted"] = accept
             if memo is not None:
                 out.update(jax_memo.memo_trace_counters(memo))
             return (state4, memo), out

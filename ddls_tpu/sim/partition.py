@@ -22,6 +22,17 @@ at creation time from the neighbour's memory at that moment; when a neighbour
 is split later the edge is destroyed and recreated, which reproduces the
 reference's last-writer-wins attribute application.
 
+**Stated graphs** (``OpGraph.stated_payload`` / ``stated_sync``; an
+architecture-built profile whose family states its training state,
+graphs/readers.py): what an op holds is one size, what its deps carry
+another. ``data_split`` sizes every dep by its PRODUCER's payload (its
+activation) and each split of either endpoint divides it by n, so a dep
+between an op split n ways and one split m ways carries payload / (n m)
+and the n x m of them the payload once; a clique edge carries the op's
+stated sync size (its bf16 gradient) / n. An unstated op's payload and
+sync size ARE its memory cost and its deps follow the reference's rules
+above, bit for bit.
+
 Sub-op id scheme: ``str(int(op)) + chr(97 + i)``
 (reference: agents/placers/utils.py:324).
 """
@@ -46,9 +57,11 @@ def data_split(graph: OpGraph) -> OpGraph:
                    compute=graph.compute_cost(op),
                    memory=graph.memory_cost(op),
                    is_forward=graph.is_forward(op),
-                   counterpart=graph.counterpart(op))
+                   counterpart=graph.counterpart(op),
+                   payload=graph.stated_payload(op),
+                   sync=graph.stated_sync(op))
     for u, v in graph.edge_ids:
-        out.add_edge(str(int(u)), str(int(v)), size=graph.memory_cost(u))
+        out.add_edge(str(int(u)), str(int(v)), size=graph.payload(u))
     out.meta = dict(graph.meta)
     return out
 
@@ -74,15 +87,27 @@ def model_split(graph: OpGraph,
             compute = g.compute_cost(node_id) / n
             memory = g.memory_cost(node_id) / n
             is_fwd = g.is_forward(node_id)
-            in_sizes = {p: g.memory_cost(p) / n for p in in_nbrs}
-            out_sizes = {c: g.memory_cost(c) / n for c in out_nbrs}
+            payload = sync = None
+            if g.stated_payload(node_id) is None:
+                in_sizes = {p: g.memory_cost(p) / n for p in in_nbrs}
+                out_sizes = {c: g.memory_cost(c) / n for c in out_nbrs}
+            else:
+                payload = g.stated_payload(node_id) / n
+                sync = g.stated_sync(node_id) / n
+                # a stated dep already is its producer's payload over
+                # the splits it has crossed: this one divides it again
+                in_sizes = {p: g.edge_size(p, node_id) / n
+                            for p in in_nbrs}
+                out_sizes = {c: g.edge_size(node_id, c) / n
+                             for c in out_nbrs}
 
             g.remove_op(node_id)
             sub_ids = [partitioned_op_id(node_id, i) for i in range(n)]
             for i, sub in enumerate(sub_ids):
                 other = partitioned_op_id(b_op if not is_backward_pass else f_op, i)
                 g.add_op(sub, compute=compute, memory=memory,
-                         is_forward=is_fwd, counterpart=other)
+                         is_forward=is_fwd, counterpart=other,
+                         payload=payload, sync=sync)
             for sub in sub_ids:
                 for p in in_nbrs:
                     g.add_edge(p, sub, size=in_sizes[p])
@@ -90,11 +115,13 @@ def model_split(graph: OpGraph,
                     g.add_edge(sub, c, size=out_sizes[c])
             if is_backward_pass:
                 # all-to-all weight-sync clique between backward sub-ops,
-                # each direction sized at the sub-op memory cost
+                # each direction sized at the sub-op's sync size (its
+                # memory cost unless stated)
+                clique = memory if sync is None else sync
                 for a in sub_ids:
                     for b in sub_ids:
                         if a != b:
-                            g.add_edge(a, b, size=memory)
+                            g.add_edge(a, b, size=clique)
     return g
 
 

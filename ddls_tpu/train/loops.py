@@ -857,8 +857,10 @@ class RLEpochLoop:
     def _device_tables(self):
         """Static jitted-env tables from the template env (shared by the
         device collector and the fused epoch driver)."""
-        from ddls_tpu.sim.jax_env import (PRICE_GAUGE, build_episode_tables,
+        from ddls_tpu.sim.jax_env import (MASK_GAUGES, PRICE_GAUGE,
+                                          build_episode_tables,
                                           build_obs_tables,
+                                          mask_rows_on_empty_cluster,
                                           price_dep_indexed_ops)
 
         env0 = self.vec_env.envs[0]
@@ -866,6 +868,9 @@ class RLEpochLoop:
             et = build_episode_tables(env0)
             ot = build_obs_tables(env0, et)
         startup.set_gauge(PRICE_GAUGE, price_dep_indexed_ops(et))
+        for name, rows in zip(MASK_GAUGES,
+                              mask_rows_on_empty_cluster(env0, et, ot)):
+            startup.set_gauge(name, rows)
         return env0, et, ot
 
     def _device_bank_size(self, env0) -> int:
@@ -1201,7 +1206,8 @@ class RLEpochLoop:
             return []
         import jax
 
-        from ddls_tpu.rl.fused import (record_lookahead_trips,
+        from ddls_tpu.rl.fused import (record_decisions,
+                                       record_lookahead_trips,
                                        record_padding_fill)
 
         harvester = (self.fused if self.fused is not None
@@ -1217,6 +1223,7 @@ class RLEpochLoop:
             if telemetry.enabled():
                 record_lookahead_trips(ep, harvester.et.pads)
                 record_padding_fill(ep, harvester.et, harvester.ot)
+                record_decisions(ep, harvester.et)
         return episodes
 
     def _run_fused(self) -> Dict[str, Any]:

@@ -1,0 +1,117 @@
+"""An independent plain count of a ``glm_moe_dsa`` training step: per op
+the parameters, the forward FLOPs and the elements of the output tensor,
+written straight from the layer equations (ISSUE 30, Tentpole step 1) and
+importing nothing from ``ddls_tpu/graphs/arch.py``, which
+``tests/test_arch_graphs.py`` holds to it op by op.
+
+Conventions: 2 FLOPs a multiply-accumulate; RMSNorm 4 an element; RoPE 3
+an element it turns; softmax / sigmoid-and-select 5 a score; SwiGLU's
+silu * up 4 a value; a residual or a sum of streams 1 an element. ``x``
+is the normed hidden state, T = S x B tokens.
+"""
+
+
+def keys_read(S, topk):
+    """sum over queries t = 1..S of min(t, topk)."""
+    return sum(min(t, topk) for t in range(1, S + 1))
+
+
+def _rmsnorm(name, tokens, width):
+    return (name, width, 4 * tokens * width, tokens * width)
+
+
+def _attention(c, S, B):
+    """MLA + DSA indexer + sparse core + out-projection, 9 ops after
+    the input norm."""
+    T = S * B
+    H, n = c["hidden_size"], c["num_attention_heads"]
+    rq, rkv = c["q_lora_rank"], c["kv_lora_rank"]
+    dn, dr, dv = (c["qk_nope_head_dim"], c["qk_rope_head_dim"],
+                  c["v_head_dim"])
+    ni, di, topk = c["index_n_heads"], c["index_head_dim"], c["index_topk"]
+    kept = min(S, topk)
+    return [
+        _rmsnorm("InputNorm", T, H),
+        # c_q = RMSNorm(x W_qa)
+        ("QAProj", H * rq + rq, 2 * T * H * rq + 4 * T * rq, T * rq),
+        # q = c_q W_qb, RoPE on the 64 of each head
+        ("QBProj", rq * n * (dn + dr),
+         2 * T * rq * n * (dn + dr) + 3 * T * n * dr, T * n * (dn + dr)),
+        # [c_kv ; k_r] = x W_kva, RMSNorm(c_kv), RoPE(k_r)
+        ("KVAProj", H * (rkv + dr) + rkv,
+         2 * T * H * (rkv + dr) + 4 * T * rkv + 3 * T * dr, T * (rkv + dr)),
+        # [k_n ; v] = c_kv W_kvb
+        ("KVBProj", rkv * n * (dn + dv), 2 * T * rkv * n * (dn + dv),
+         T * n * (dn + dv)),
+        # q^I = c_q W_Iq ; k^I = Norm(x W_Ik) ; w = x W_Iw ; RoPE on the
+        # rope part of the ni query heads and of the one key
+        ("IndexerProj", rq * ni * di + H * di + H * ni + di,
+         2 * T * (rq * ni * di + H * di + H * ni) + 4 * T * di
+         + 3 * T * (ni + 1) * dr,
+         T * (ni * di + di + ni)),
+        # I[t,s] = sum_j w[t,j] ReLU(q^I[t,j] . k^I[s]), s <= t; top-k
+        ("IndexScoreTopK", 0, B * S * S / 2 * ni * (2 * di + 2), T * kept),
+        # o_t = sum_{s in S_t} softmax(q_t . [k_n ; k_r] / sqrt(dqk)) v_s
+        ("SparseAttnCore", 0,
+         B * keys_read(S, topk) * n * (2 * (dn + dr) + 2 * dv + 5),
+         T * n * dv),
+        # y = o W_o + residual
+        ("OutProjResidual", n * dv * H, 2 * T * n * dv * H + T * H, T * H),
+        _rmsnorm("PostAttnNorm", T, H),
+    ]
+
+
+def _dense_layer(c, S, B):
+    T, H, I = S * B, c["hidden_size"], c["intermediate_size"]
+    return _attention(c, S, B) + [
+        ("DenseMLPResidual", 3 * H * I,
+         2 * T * 3 * H * I + 4 * T * I + T * H, T * H)]
+
+
+def _expert_layer(c, S, B, held):
+    T, H = S * B, c["hidden_size"]
+    E, k = c["n_routed_experts"], c["num_experts_per_tok"]
+    I = c["moe_intermediate_size"]
+    Is = c["n_shared_experts"] * I
+    pairs = T * k * held / E          # balanced routing, this pod's share
+    return _attention(c, S, B) + [
+        # s = sigmoid(x W_r), top-k of s + b, weights normalised, scaled
+        ("Router", H * E + E, 2 * T * H * E + 5 * T * E + 3 * T * k,
+         2 * T * k),
+        ("SharedExpert", 3 * H * Is, 2 * T * 3 * H * Is + 4 * T * Is, T * H),
+        ("Experts", held * 3 * H * I,
+         2 * pairs * 3 * H * I + 4 * pairs * I, pairs * H),
+        # shared + weighted routed + residual
+        ("CombineResidual", 0, 2 * pairs * H + 2 * T * H, T * H)]
+
+
+def plain_counts(c, S, B, leading_dense=None, following=None, held=None):
+    """[(op_type, parameters, forward FLOPs, output elements)] in the
+    profile's order: embedding, dense layers, expert layers, the MTP
+    modules, final norm, head + loss."""
+    if leading_dense is None:
+        leading_dense = c["first_k_dense_replace"]
+    if following is None:
+        following = c["num_hidden_layers"] - c["first_k_dense_replace"]
+    if held is None:
+        held = c["n_routed_experts"]
+    T, H, V = S * B, c["hidden_size"], c["vocab_size"]
+    ops = [("Embedding", V * H, 0, T * H)]
+    for _ in range(leading_dense):
+        ops += _dense_layer(c, S, B)
+    for _ in range(following):
+        ops += _expert_layer(c, S, B, held)
+    streams = 1
+    for _ in range(c["num_nextn_predict_layers"]):
+        # h' = [RMSNorm(h) ; RMSNorm(Emb(x_{t+1}))] W_eh, one expert layer
+        ops += [_rmsnorm("MTPHiddenNorm", T, H),
+                _rmsnorm("MTPEmbedNorm", T, H),
+                ("MTPProj", 2 * H * H, 2 * T * 2 * H * H, T * H)]
+        ops += _expert_layer(c, S, B, held)
+        streams += 1
+    # final norm and head: parameters once, a pass per stream
+    ops += [_rmsnorm("FinalNorm", streams * T, H),
+            ("LMHeadLoss", H * V,
+             2 * streams * T * H * V + 5 * streams * T * V,
+             streams * T * V)]
+    return ops

@@ -258,9 +258,20 @@ def test_generator_takes_an_architecture_like_any_other_source(tmp_path):
     assert gen.workload_fingerprint[0] == arch.dataset_id(TINY, TINY_SHAPES)
     assert JobsGenerator(**jobs).workload_fingerprint \
         == gen.workload_fingerprint          # another temp dir, same id
-    assert startup.gauges() == {
+    gauges = startup.gauges()
+    assert {k: v for k, v in gauges.items() if "_bytes" not in k} == {
         f"graphs.arch.{what}.{m}": n for m in models
         for what, n in (("forward_ops", 19), ("edges", 53))}
+    # an unstated family: what a dep or a sync edge is sized by is the
+    # largest op's whole memory cost
+    for m in models:
+        graph = next(p.graph for p in gen.sampler.prototypes
+                     if p.details["model"] == m)
+        biggest = max(graph.memory_cost(o) for o in graph.op_ids)
+        assert gauges[f"graphs.arch.resident_bytes.{m}"] == sum(
+            graph.memory_cost(o) for o in graph.op_ids)
+        assert gauges[f"graphs.arch.payload_bytes_max.{m}"] == biggest
+        assert gauges[f"graphs.arch.sync_bytes_max.{m}"] == biggest
     assert [n for n, _, _ in startup.registry().span_intervals()] \
         == ["startup.job_graphs"] * 2
     report = json.loads(startup.report()[len("[startup] "):])
@@ -394,3 +405,428 @@ def test_in_kernel_episode_replays_the_host_oracle(tmp_path, x64, rtol):
     assert "max_acceptable_job_completion_time_exceeded" \
         in verdict["causes"], verdict
     assert {1, 16} & set(verdict["degrees"]) and verdict["max_jct"] > 1.0
+
+
+# =========================================================== glm_moe_dsa
+GLM_FILE = "ddls_tpu/graphs/arch_configs/glm_5.json"
+#: the deployment's cut (env_glm5_32.yaml): 3 dense + 4 expert layers +
+#: the MTP module, 64 of the 256 routed experts
+GLM_CUT = {"layers": {"leading_dense": 3, "following": 4},
+           "experts_held": 64}
+#: 1 dense + 2 expert layers + MTP, hidden 64, 8 experts (2 a token, 1
+#: shared), index top-16
+TINY_GLM = {"model_type": "tinyglm", "hidden_size": 64,
+            "num_attention_heads": 4, "q_lora_rank": 32,
+            "kv_lora_rank": 16, "qk_nope_head_dim": 12,
+            "qk_rope_head_dim": 4, "v_head_dim": 16, "index_n_heads": 2,
+            "index_head_dim": 8, "index_topk": 16,
+            "intermediate_size": 128, "moe_intermediate_size": 32,
+            "n_routed_experts": 8, "n_shared_experts": 1,
+            "num_experts_per_tok": 2, "first_k_dense_replace": 1,
+            "num_hidden_layers": 3, "num_nextn_predict_layers": 1,
+            "scoring_func": "sigmoid", "vocab_size": 256}
+STATE = {"resident_bytes_per_parameter": 16,
+         "synced_bytes_per_parameter": 2}
+OLMOE_SHA256 = {
+    1: "e37d732fdece58a9690ddecfee7e1b2c59e8a469047e40e1633973723963d263",
+    2: "89ce01d98f6416e189da20c491a8b1cbe80ceb430a9aa3a3d0ae5dde06915a0a",
+    4: "8aba20ad93284818802a2662483d29cafe48784ba07276e1c2de6260229824e0",
+    8: "433fef7186272ee4ca9bbf18c6d416d744267f3ef95029eb6958b15ebb96f305"}
+
+
+@pytest.fixture(scope="module")
+def glm():
+    return arch.load_arch_config(GLM_FILE)
+
+
+@pytest.mark.parametrize("micro_batch", [1, 2, 4, 8])
+def test_olmoe_profiles_are_text_equal_to_the_parents(olmoe, micro_batch):
+    """The builder was rewritten around layer kinds; what it writes for
+    the family the benchmark already runs is pinned to PR 29's bytes."""
+    import hashlib
+
+    text = arch.profile_text(olmoe, 4096, micro_batch)
+    assert "sync_size" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == OLMOE_SHA256[micro_batch]
+
+
+@pytest.mark.parametrize("case", ["tiny_s8", "tiny_s32", "tiny_cut",
+                                  "glm5_8k_cut", "glm5_64k_cut"])
+def test_op_costs_equal_the_plain_count_op_by_op(glm, case):
+    """`tests/plain_arch_counts.py` is written from the equations and
+    imports nothing of the builder: parameters, FLOPs and output
+    elements agree on every op, below and above the indexer's top-k."""
+    from plain_arch_counts import plain_counts
+
+    config, seq_len, micro_batch, cut, plain_cut = {
+        "tiny_s8": (TINY_GLM, 8, 3, {}, {}),
+        "tiny_s32": (TINY_GLM, 32, 2, {}, {}),
+        "tiny_cut": (TINY_GLM, 32, 4,
+                     {"layers": {"leading_dense": 1, "following": 1},
+                      "experts_held": 2},
+                     {"leading_dense": 1, "following": 1, "held": 2}),
+        "glm5_8k_cut": (glm, 8192, 4, GLM_CUT,
+                        {"leading_dense": 3, "following": 4, "held": 64}),
+        "glm5_64k_cut": (glm, 65536, 1, GLM_CUT,
+                         {"leading_dense": 3, "following": 4, "held": 64}),
+    }[case]
+    built = arch.op_costs(config, seq_len, micro_batch, **cut)
+    plain = plain_counts(config, seq_len, micro_batch, **plain_cut)
+    assert [o["op_type"] for o in built] == [p[0] for p in plain]
+    for i, (o, (kind, params, flops, out)) in enumerate(zip(built, plain)):
+        assert o["params"] == params, (i, kind)
+        assert o["flops"] == pytest.approx(flops, rel=1e-12), (i, kind)
+        assert o["out_elems"] == pytest.approx(out, rel=1e-12), (i, kind)
+
+
+@pytest.mark.parametrize("quantity", ["parameters", "active_parameters"])
+def test_full_depth_totals_are_the_published_model(glm, quantity):
+    """All 78 layers and 256 experts: 743.9 B parameters with the MTP
+    module apart (the family's published 744 B), ~40 B active a token."""
+    whole = arch.op_costs({**glm, "num_nextn_predict_layers": 0}, 4096, 1)
+    total = sum(o["params"] for o in whole)
+    if quantity == "parameters":
+        assert len(whole) == 1 + 3 * 11 + 75 * 14 + 2
+        assert total == pytest.approx(743.9e9, rel=1e-4)
+        with_mtp = sum(o["params"] for o in arch.op_costs(glm, 4096, 1))
+        # one more expert layer and the 2H x H projection
+        assert with_mtp - total == pytest.approx(9.877e9 + 0.0755e9,
+                                                 rel=1e-3)
+    else:
+        idle = (glm["n_routed_experts"] - glm["num_experts_per_tok"]) \
+            * 3 * glm["hidden_size"] * glm["moe_intermediate_size"] * 75
+        assert total - idle == pytest.approx(40e9, rel=0.06)
+
+
+@pytest.mark.parametrize("seq_len,keys", [
+    (2048, 2048 * 2049 // 2),
+    (2049, 2048 * 2049 // 2 + 2048),
+    (65536, 2048 * 2049 // 2 + (65536 - 2048) * 2048)])
+def test_sparse_core_reads_min_t_topk_keys_a_query(glm, seq_len, keys):
+    assert arch.sparse_keys(seq_len, glm["index_topk"]) == keys
+    core = next(o for o in arch.op_costs(glm, seq_len, 1, **GLM_CUT)
+                if o["op_type"] == "SparseAttnCore")
+    heads = glm["num_attention_heads"]
+    assert core["flops"] == keys * heads * (
+        2 * glm["qk_head_dim"] + 2 * glm["v_head_dim"] + 5)
+    # the indexer stays S^2: it passes the core as the sequence grows
+    index = next(o for o in arch.op_costs(glm, seq_len, 1, **GLM_CUT)
+                 if o["op_type"] == "IndexScoreTopK")
+    assert index["flops"] == seq_len ** 2 / 2 * 32 * (2 * 128 + 2)
+    assert (index["flops"] > core["flops"]) == (seq_len > 16384)
+
+
+@pytest.mark.parametrize("case", ["tiny", "glm5"])
+def test_the_four_shares_add_up_to_the_uncut_layer(glm, case):
+    """Four pods hold a quarter of the routed experts each: their expert
+    groups' FLOPs and parameters sum to the uncut layer's, and every
+    other op — what each pod computes alike, the shared expert among
+    it — is the uncut layer's own, counted once."""
+    config, seq_len, held = {"tiny": (TINY_GLM, 32, 2),
+                             "glm5": (glm, 8192, 64)}[case]
+    layers = {"leading_dense": 0, "following": 1}
+    uncut = arch.op_costs(config, seq_len, 2, layers=layers)
+    share = arch.op_costs(config, seq_len, 2, layers=layers,
+                          experts_held=held)
+    assert [o["op_type"] for o in share] == [o["op_type"] for o in uncut]
+    assert 4 * held == config["n_routed_experts"]
+    for a, b in zip(share, uncut):
+        if a["op_type"] == "Experts":
+            for key in ("flops", "params", "out_elems"):
+                assert 4 * a[key] == b[key], key
+        elif a["op_type"] == "CombineResidual":
+            # the weighted sum runs over this pod's pairs; the shared
+            # expert's add and the residual are whole
+            T = seq_len * 2
+            k, H = config["num_experts_per_tok"], config["hidden_size"]
+            assert a["flops"] == 2 * T * k * H / 4 + 2 * T * H
+            assert b["flops"] == 2 * T * k * H + 2 * T * H
+        else:
+            assert a == b, a["op_type"]
+
+
+def test_the_cut_keeps_every_layer_kind_and_no_dangling_edge(glm, tmp_path):
+    path, = arch.write_profiles(
+        str(tmp_path), glm, [{"seq_len": 8192, "micro_batch": 1}],
+        GLM_CUT["layers"], GLM_CUT["experts_held"], STATE)
+    assert os.path.basename(path) == "glm_moe_dsa_s8192_b1.txt"
+    nodes, edges = _parse_pipedream_txt(path)
+    kinds = [n["op_type"] for n in nodes.values()]
+    attention = ["InputNorm", "QAProj", "QBProj", "KVAProj", "KVBProj",
+                 "IndexerProj", "IndexScoreTopK", "SparseAttnCore",
+                 "OutProjResidual", "PostAttnNorm"]
+    dense = attention + ["DenseMLPResidual"]
+    expert = attention + ["Router", "SharedExpert", "Experts",
+                          "CombineResidual"]
+    assert kinds == (["Embedding"] + dense * 3 + expert * 4
+                     + ["MTPHiddenNorm", "MTPEmbedNorm", "MTPProj"] + expert
+                     + ["FinalNorm", "LMHeadLoss"])
+    assert len(kinds) == 109 and len(edges) == 173
+    # every op but the embedding consumes something, every op but the
+    # loss is consumed; the head sees the main stream and the MTP's
+    ids = set(nodes)
+    assert {v for _, v in edges} == ids - {"1"}
+    assert {u for u, _ in edges} == ids - {"109"}
+    final = str(kinds.index("FinalNorm") + 1)
+    assert sorted(int(u) for u, v in edges if v == final) == [90, 107]
+    # true data dependencies: c_q feeds W_qb and the indexer, x three
+    # projections, k_r (with c_kv) the core
+    first = 2                                   # layer 0's InputNorm
+    at = {name: first + i for i, name in enumerate(attention)}
+    consumers = lambda u: sorted(int(v) for s, v in edges if int(s) == u)
+    assert consumers(at["QAProj"]) == [at["QBProj"], at["IndexerProj"]]
+    assert consumers(at["InputNorm"]) == [at["QAProj"], at["KVAProj"],
+                                          at["IndexerProj"]]
+    assert consumers(at["KVAProj"]) == [at["KVBProj"],
+                                        at["SparseAttnCore"]]
+    graph = read_graph_file(path)
+    assert (len(graph.forward_op_ids()), graph.n_ops, graph.n_deps) \
+        == (109, 218, 347)
+    assert sum(n["parameter"] for n in nodes.values()) \
+        == pytest.approx(261.3e9, rel=1e-3)
+
+
+def test_glm_env_yaml_states_what_its_comments_derive(glm):
+    """env_glm5_32.yaml: the cut and the shapes as data, the arrival gap
+    and horizon derived from the builder's graph, env_olmoe32's pads
+    rule, and nothing else changed from env_olmoe32."""
+    import math
+
+    from ddls_tpu.config import load_config
+
+    def env(name):
+        return load_config(
+            os.path.join(REPO, "scripts/ramp_job_partitioning_configs"),
+            "rllib_config", [f"env_config={name}"])["env_config"]
+
+    cfg, base = env("env_glm5_32"), env("env_olmoe32")
+    jobs = cfg["jobs_config"]
+    family = jobs["architecture"]
+    assert family["config"] == GLM_FILE
+    assert {k: family[k] for k in GLM_CUT} == GLM_CUT
+    shapes = family["shapes"]
+    assert [(s["seq_len"], s["micro_batch"]) for s in shapes] == [
+        (8192, 1), (8192, 4), (32768, 1), (65536, 1)]
+    steps = jobs["num_training_steps"]
+    lengths = [steps * (1 + arch.BACKWARD_OVER_FORWARD) * sum(
+        arch.forward_time(c) for c in arch.op_costs(glm, **s, **GLM_CUT))
+        for s in shapes]
+    gap = np.mean(lengths) / 25
+    two_figures = round(gap, 1 - int(math.floor(math.log10(gap))))
+    assert jobs["job_interarrival_time_dist"]["val"] == two_figures == 7.5
+    assert cfg["max_simulation_run_time"] == pytest.approx(400 * two_figures)
+    assert cfg["pad_obs_kwargs"] == {"max_nodes": 50 * -(-218 // 50),
+                                     "max_edges": 256 * -(-347 // 256)}
+    # the rest is env_olmoe32's
+    changed = {"jobs_config", "max_simulation_run_time", "pad_obs_kwargs"}
+    assert {k: v for k, v in cfg.items() if k not in changed} \
+        == {k: v for k, v in base.items() if k not in changed}
+    for key in set(jobs) - {"architecture", "job_interarrival_time_dist"}:
+        assert jobs[key] == base["jobs_config"][key], key
+
+
+# ------------------------------------------------------ the three sizes
+def _partition_digest(graph, splits):
+    """sha256 over every op's memory and every dep's size of the graph
+    partitioned with forward op i split ``splits[i % len(splits)]``
+    ways."""
+    import hashlib
+
+    from ddls_tpu.sim.partition import partition_graph
+
+    action = {str(int(op)): splits[i % len(splits)]
+              for i, op in enumerate(graph.forward_op_ids())}
+    part = partition_graph(graph, action)
+    h = hashlib.sha256()
+    for op in part.op_ids:
+        h.update(f"{op}:{part.memory_cost(op).hex()};".encode())
+    for u, v in part.edge_ids:
+        h.update(f"{u}>{v}:{part.edge_size(u, v).hex()};".encode())
+    return part.n_ops, part.n_deps, h.hexdigest()
+
+
+@pytest.mark.parametrize("family", ["synthetic_chains", "olmoe"])
+def test_unstated_graphs_partition_bit_equal_to_the_parents(
+        olmoe, tmp_path, family):
+    """A profile that states only activation_size and parameter_size is
+    sized as the reference sizes it: digests taken at PR 29's tree."""
+    from ddls_tpu.graphs.synthetic import generate_pipedream_txt_files
+
+    if family == "olmoe":
+        path, = arch.write_profiles(
+            str(tmp_path), olmoe, [{"seq_len": 4096, "micro_batch": 2}])
+        got = [_partition_digest(read_graph_file(path), (4, 2, 1, 8))]
+        want = [(974, 7139, "ff58e4521c3f5654c037506005be76216fbcf3cda"
+                 "4e3af6097c5d62ee252bba0")]
+    else:
+        paths = generate_pipedream_txt_files(
+            str(tmp_path), n_cnn=1, n_translation=1, seed=3, min_ops=5,
+            max_ops=7)
+        got = [_partition_digest(read_graph_file(p), (1, 2, 4, 2, 6))
+               for p in sorted(paths)]
+        want = [(36, 140, "d1fbd463b605dfab68d2da9465e698e1e22676aae5349"
+                 "e77f2567f7e43eb5919"),
+                (32, 119, "003d5dc661c465ae96dccd9b2e51c1cd29a7de4b9b201"
+                 "b4e3a604001bedda1c4")]
+    assert got == want
+    graph = read_graph_file(path if family == "olmoe" else sorted(paths)[0])
+    assert all(graph.stated_payload(o) is None
+               and graph.stated_sync(o) is None for o in graph.op_ids)
+
+
+def _stated_tiny_graph(directory):
+    path, = arch.write_profiles(
+        str(directory), TINY_GLM, [{"seq_len": 32, "micro_batch": 4096}],
+        training_state=STATE)
+    return path, read_graph_file(path)
+
+
+def test_a_stated_job_occupies_parameter_state_and_two_activations(
+        tmp_path):
+    path, graph = _stated_tiny_graph(tmp_path)
+    nodes, _ = _parse_pipedream_txt(path)
+    params = sum(o["params"] for o in arch.op_costs(TINY_GLM, 32, 4096))
+    activations = sum(n["activation"] for n in nodes.values())
+    assert sum(n["parameter"] for n in nodes.values()) == params * 16
+    assert sum(n["sync"] for n in nodes.values()) == params * 2
+    assert sum(graph.memory_cost(o) for o in graph.op_ids) \
+        == params * 16 + 2 * activations
+    n = len(nodes)
+    for op, vals in nodes.items():
+        mirror = str(2 * n - (int(op) - 1))
+        assert graph.memory_cost(op) == vals["activation"] \
+            + vals["parameter"]
+        assert graph.memory_cost(mirror) == vals["activation"]
+        for node in (op, mirror):
+            assert graph.payload(node) == vals["activation"]
+            assert graph.sync_size(node) == vals["sync"]
+
+
+@pytest.mark.parametrize("degree", [2, 4])
+def test_stated_deps_carry_activations_and_syncs_carry_gradients(
+        tmp_path, degree):
+    """`partition_graph` on a stated graph: every dep is its producer's
+    activation divided by each split it crosses, every clique edge the
+    op's bf16 gradient / n; ops hold memory / n."""
+    from ddls_tpu.sim.partition import partition_graph
+
+    path, graph = _stated_tiny_graph(tmp_path)
+    nodes, _ = _parse_pipedream_txt(path)
+    n_fwd = len(nodes)
+    # every other forward op split, the rest whole
+    split = {op: degree if int(op) % 2 else 1 for op in nodes}
+    part = partition_graph(graph, dict(split))
+
+    def original(sub):              # "12b" -> ("12", forward op "5")
+        op = sub.rstrip("abcdefghijklmnop")
+        fwd = op if int(op) <= n_fwd else str(2 * n_fwd - (int(op) - 1))
+        return op, fwd
+
+    cliques = deps = 0
+    for u, v in part.edge_ids:
+        (ou, fu), (ov, fv) = original(u), original(v)
+        size = part.edge_size(u, v)
+        if ou == ov:                # two sub-ops of one backward op
+            assert int(ou) > n_fwd and u != v
+            assert size == nodes[fu]["sync"] / split[fu]
+            cliques += 1
+        else:
+            assert size == nodes[fu]["activation"] / split[fu] / split[fv]
+            deps += 1
+    assert cliques == sum(degree * (degree - 1)
+                          for s in split.values() if s > 1)
+    assert deps > 0
+    for sub in part.op_ids:
+        op, fwd = original(sub)
+        assert part.memory_cost(sub) == graph.memory_cost(op) / split[fwd]
+
+
+def _tiny_glm_arch_file(directory) -> str:
+    path = os.path.join(str(directory), "tinyglm.json")
+    with open(path, "w") as fh:
+        json.dump({"source_url": "test-local", "training_state": STATE,
+                   "config": TINY_GLM}, fh)
+    return path
+
+
+#: ops of 8 us - 6 ms and steps of ~0.2 s at the first, a ragged row at
+#: the second
+TINY_GLM_SHAPES = [{"seq_len": 32, "micro_batch": 2 ** 19},
+                   {"seq_len": 32, "micro_batch": 4096}]
+
+
+def _tiny_glm_env(arch_file, **over):
+    jobs = dict(
+        architecture={"config": arch_file, "shapes": TINY_GLM_SHAPES,
+                      "layers": {"leading_dense": 1, "following": 1},
+                      "experts_held": 4},
+        job_interarrival_time_dist={
+            "_target_": "ddls_tpu.demands.distributions.Fixed", "val": 0.4},
+        max_acceptable_job_completion_time_frac_dist={
+            "_target_": "ddls_tpu.demands.distributions.Uniform",
+            "min_val": 0.1, "max_val": 1.0, "decimals": 2},
+        replication_factor=10, job_sampling_mode="remove_and_repeat",
+        shuffle_files=True, num_training_steps=20)
+    # degrees to 8: the kernel's pads, and its compile, are a quarter
+    # of the degree-16 ones
+    over.setdefault("max_partitions_per_op", 8)
+    return _tiny_env(arch_file, jobs_config=jobs,
+                     max_simulation_run_time=16.0,
+                     pad_obs_kwargs={"max_nodes": 100, "max_edges": 192},
+                     **over)
+
+
+@pytest.mark.parametrize("x64,rtol", [(True, 1e-9), (False, 1e-4)],
+                         ids=["x64_1e-9", "f32_1e-4"])
+def test_stated_job_in_kernel_replays_the_host_oracle(tmp_path, x64, rtol):
+    """A tiny STATED glm_moe_dsa job family (cut to 1 dense + 1 expert
+    layer + MTP, 4 of 8 experts) through reader -> mirror -> Job -> the
+    jitted episode kernel against the float64 Python oracle: accepted
+    and cause exactly, JCT to the tolerance; zero-size sync edges (ops
+    without parameters) included."""
+    driver = EPISODE_DRIVER.replace(
+        "t._tiny_env(", "t._tiny_glm_env(").format(
+        repo=REPO, tests=os.path.join(REPO, "tests"),
+        benchmarks=os.path.join(REPO, "tests", "benchmarks"),
+        arch_file=_tiny_glm_arch_file(tmp_path), seed=5, x64=x64, rtol=rtol)
+    out = subprocess.run(
+        [sys.executable, "-c", driver], capture_output=True, text=True,
+        timeout=900, env={**os.environ, "JAX_PLATFORMS": "cpu",
+                          "JAX_ENABLE_X64": "1" if x64 else "0"})
+    assert out.returncode == 0, out.stderr[-3000:]
+    verdict = json.loads(out.stdout.strip().splitlines()[-1])
+    assert verdict["mismatch"] is None, verdict
+    assert verdict["decisions"] == 24
+    assert 0 < verdict["accepted"] < 24, verdict
+    assert len(verdict["causes"]) >= 3, verdict
+
+
+def test_conformance_host_native_leg_on_a_stated_spec(tmp_path):
+    """`scripts/conformance.py --spec <file> --legs host_native`: the
+    C++ engine steps a stated job family bit-exactly with the host."""
+    from ddls_tpu.scenarios import get_spec
+    from ddls_tpu.scenarios.conformance import run_conformance
+    from ddls_tpu.scenarios.spec import ScenarioSpec
+
+    env = _tiny_glm_env(_tiny_glm_arch_file(tmp_path))
+    spec_file = tmp_path / "stated_spec.json"
+    spec_file.write_text(ScenarioSpec(
+        name="stated_tinyglm",
+        topology=env.cluster.topology_config,
+        node_config={"type_1": {"num_nodes": 32, "workers_config": [
+            {"num_workers": 1, "worker": "A100"}]}},
+        jobs={"architecture": {
+            "config": _tiny_glm_arch_file(tmp_path),
+            "shapes": TINY_GLM_SHAPES,
+            "layers": {"leading_dense": 1, "following": 1},
+            "experts_held": 4}},
+        arrival={"kind": "fixed", "interarrival": 0.4},
+        num_training_steps=20, max_partitions_per_op=8,
+        min_op_run_time_quantum=QUANTUM, sim_seconds=16.0,
+        pad_obs={"max_nodes": 100, "max_edges": 192}).to_json())
+    report = run_conformance(get_spec(str(spec_file)), seed=3,
+                             max_decisions=30, legs=["host_native"])
+    leg, = report["legs"]
+    assert leg["status"] == "ok", leg
+    assert leg["rtol"] == 0.0 and leg["decisions"] == 30

@@ -346,10 +346,19 @@ def test_build_run_leaves_each_startup_span_once(fused_dataset, tmp_path,
         minor = loop.fused.et.pads.max_split * loop.fused.num_lanes
         # ... and pricing and the channel / server checks of the
         # program's `eval_cfg` index no dep
-        assert startup.gauges() == {
+        # ... and of the rows the mask offers on an empty cluster the
+        # allocator places every one (small synthetic jobs)
+        gauges = startup.gauges()
+        offered = gauges["env.mask.rows_offered"]
+        assert offered == len(loop.fused.et.types) * sum(
+            bool(loop.fused.ot["shapes_exist"][d]) or d == 1
+            for d in loop.fused.et.degrees)
+        assert gauges == {
             "sim.lookahead.minor_slots": -(-minor // 128) * 128,
             "sim.lookahead.minor_used": minor,
-            "sim.price.dep_indexed_ops": 0}
+            "sim.price.dep_indexed_ops": 0,
+            "env.mask.rows_offered": offered,
+            "env.mask.rows_placeable": offered}
         assert set(seconds) == {n.removeprefix("startup.")
                                 for n, _, _ in reg.span_intervals()} \
             | set(startup.gauges())
