@@ -15,6 +15,14 @@ scripts/ramp_job_partitioning_configs/model/gnn.yaml:
 
 All ops are fixed-shape w.r.t. the padded node/edge counts; padding is
 removed by masks, so the module is jit/vmap/pjit-safe.
+
+``GNN`` takes one graph or a batch (leading axes on every argument, one
+graph each). The parameterised modules are row-wise and run on the rows
+flattened to rank 2 (``[G·N, F]``, ``[G·E, F]``); only a round's two indexed
+operations — read each edge's source row, average each node's mailbox — see
+the graphs, through the ``ops.segment.EdgeAggregator`` the GNN builds once
+and every round shares (index gather + segment sum, or per-graph incidence
+contractions on the TPU's MXU: ``ops/segment.py``).
 """
 from __future__ import annotations
 
@@ -23,7 +31,7 @@ from typing import Callable, Sequence
 import flax.linen as nn
 import jax.numpy as jnp
 
-from ddls_tpu.ops.segment import masked_segment_mean
+from ddls_tpu.ops.segment import EdgeAggregator, edge_aggregator
 
 ACTIVATIONS = {
     "relu": nn.relu,
@@ -61,7 +69,8 @@ class FeatureModule(nn.Module):
 
 
 class MeanPoolLayer(nn.Module):
-    """One round of message passing + mean aggregation (single sample)."""
+    """One round of message passing + mean aggregation over node rows
+    ``[G·N, F]`` and edge rows ``[G·E, F]`` (G = 1: a single sample)."""
 
     out_features_msg: int
     out_features_reduce: int
@@ -72,10 +81,8 @@ class MeanPoolLayer(nn.Module):
     def __call__(self,
                  node_feats: jnp.ndarray,
                  edge_feats: jnp.ndarray,
-                 edges_src: jnp.ndarray,
-                 edges_dst: jnp.ndarray,
-                 node_mask: jnp.ndarray,
-                 edge_mask: jnp.ndarray) -> jnp.ndarray:
+                 edges: EdgeAggregator,
+                 node_mask: jnp.ndarray) -> jnp.ndarray:
         half = self.out_features_msg // 2
         node_int = FeatureModule(half, self.module_depth, self.activation,
                                  name="node_module")(node_feats)
@@ -86,15 +93,14 @@ class MeanPoolLayer(nn.Module):
                                       name="reduce_module")
 
         # message along each edge + a zero-edge self-message per node
-        messages = jnp.concatenate([node_int[edges_src], edge_int], axis=-1)
+        messages = jnp.concatenate(
+            [edges.gather_src(node_int), edge_int], axis=-1)
         self_state = jnp.concatenate(
             [node_int, jnp.zeros_like(node_int)], axis=-1)
 
         embedded_msgs = reduce_module(messages)
         embedded_self = reduce_module(self_state)
-        out = masked_segment_mean(embedded_msgs, edges_dst, edge_mask,
-                                  num_segments=node_feats.shape[0],
-                                  extra=embedded_self)
+        out = edges.mean_to_dst(embedded_msgs, extra=embedded_self)
         return out * node_mask[:, None]
 
 
@@ -116,9 +122,14 @@ class GNN(nn.Module):
         dims: Sequence[int] = (
             [self.out_features_hidden] * (self.num_rounds - 1)
             + [self.out_features_node])
-        h = node_feats
+        edges = edge_aggregator(edges_src, edges_dst, edge_mask,
+                                n_nodes=node_feats.shape[-2])
+        # the parameterised modules are row-wise: rank-2 rows throughout
+        h = node_feats.reshape((-1, node_feats.shape[-1]))
+        edge_rows = edge_feats.reshape((-1, edge_feats.shape[-1]))
+        node_rows_mask = node_mask.reshape(-1)
         for i, dim in enumerate(dims):
             h = MeanPoolLayer(self.out_features_msg, dim, self.module_depth,
                               self.activation, name=f"round_{i}")(
-                h, edge_feats, edges_src, edges_dst, node_mask, edge_mask)
-        return h
+                h, edge_rows, edges, node_rows_mask)
+        return h.reshape(node_feats.shape[:-1] + h.shape[-1:])

@@ -6,7 +6,7 @@ an online "partition this arriving job" service. Three design rules carried
 over from the training-side measurements:
 
 * **Fixed compile shapes.** Every bucket runs ONE XLA program: the
-  flattened mega-graph forward (``GNNPolicy.flat_batched`` — never a vmapped
+  flat-rows batched forward (``GNNPolicy.flat_batched`` — never a vmapped
   apply, round-5 invariant) at a fixed batch size ``max_batch``. Partial
   flushes are padded by replicating the first request's rows; at a fixed
   program a request's output rows are bit-identical whatever rides in the
@@ -106,8 +106,9 @@ def _validate_obs(obs: Dict[str, Any], widths: Dict[str, int]) -> None:
                              f"edge_split={m} entries, got shape "
                              f"{arr.shape}")
         # REAL edges must point at REAL nodes of THIS graph: in the
-        # flat-batched mega-graph an out-of-range endpoint escapes its
-        # slot (dst + k*N lands in a neighbour's node rows) and the
+        # flat-batched mega-graph (the aggregation's index form,
+        # ops/segment.py) an out-of-range endpoint escapes its slot
+        # (dst + k*N lands in a neighbour's node rows) and the
         # scatter silently changes a CO-BATCHED client's embedding —
         # the one way a request could break "batching never changes an
         # answer". Padded edges beyond edge_split are masked; no

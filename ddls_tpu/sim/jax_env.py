@@ -59,6 +59,7 @@ from ddls_tpu.sim.jax_lookahead import (DepBlocks, block_endpoints,
                                         jax_lookahead)
 from ddls_tpu.sim.partition import partition_graph, partitioned_op_id
 from ddls_tpu.telemetry import scopes
+from ddls_tpu.utils.jaxprs import indexed_ops
 
 #: episode-kernel default: the in-kernel lookahead memo (sim/jax_memo.py)
 #: is ON for the episode builders at EVERY lane count — memoised and
@@ -1382,26 +1383,9 @@ PRICE_GAUGE = "sim.price.dep_indexed_ops"
 
 
 def dep_indexed_ops(jaxpr, n_indices: int) -> List[str]:
-    """The gather / scatter equations of ``jaxpr`` (nested jaxprs
-    included, the lookahead's own call left out) that take
-    ``n_indices`` index vectors or more: on the chip each such vector
-    is one serial address computation, so an equation of a dep's worth
-    of them is a loop over the deps whatever else the program does. A
-    row read of a table (one index, a row-long slice) is not one."""
-    found = []
-    for eqn in jaxpr.eqns:
-        name = eqn.primitive.name
-        if name == "custom_vmap_call":      # jax_lookahead's batching rule
-            continue
-        if name == "gather" or name.startswith("scatter"):
-            if int(np.prod(eqn.invars[1].aval.shape[:-1])) >= n_indices:
-                found.append(name)
-        for value in eqn.params.values():
-            for sub in value if isinstance(value, (tuple, list)) else (value,):
-                inner = getattr(sub, "jaxpr", sub)
-                if hasattr(inner, "eqns"):
-                    found += dep_indexed_ops(inner, n_indices)
-    return found
+    """`utils/jaxprs.py:indexed_ops` with the lookahead's own call
+    (`jax_lookahead`'s batching rule) left out."""
+    return indexed_ops(jaxpr, n_indices, skip=("custom_vmap_call",))
 
 
 def price_dep_indexed_ops(et: EpisodeTables) -> int:

@@ -769,6 +769,7 @@ class RLEpochLoop:
                 f"the mesh's dp axis ({dp})")
 
         env0, et, ot = self._device_tables()
+        self._set_aggregate_gauges(lanes * segment_len)
         sh_fn = getattr(self.learner, "_state_shardings", None)
         state_shardings = (sh_fn(self.state) if sh_fn is not None
                            else getattr(self.learner, "_replicated",
@@ -779,6 +780,25 @@ class RLEpochLoop:
             train_step_fn=self._fused_step_fn(),
             state_shardings=state_shardings, mesh=self.mesh,
             memo_cfg=self._memo_knob())
+
+    def _set_aggregate_gauges(self, batch: int) -> None:
+        """The start-up gauges of the GNN's aggregation in the update
+        (`models/policy.py:aggregate_gauges`), at the update's
+        minibatch of the template env's padded observation."""
+        import jax
+
+        from ddls_tpu.models.policy import (AGGREGATE_GAUGES,
+                                            aggregate_gauges)
+
+        cfg = getattr(self.learner, "cfg", None)
+        minibatch = min(int(getattr(cfg, "sgd_minibatch_size", batch)),
+                        batch)
+        obs = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct((minibatch,) + x.shape, x.dtype),
+            self.vec_env.obs[0])
+        for name, value in zip(AGGREGATE_GAUGES, aggregate_gauges(
+                self.apply_fn, self.params, obs)):
+            startup.set_gauge(name, value)
 
     def _split_sebulba_mesh(self) -> None:
         """Partition the configured training mesh into the actor

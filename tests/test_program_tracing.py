@@ -348,7 +348,14 @@ def test_build_run_leaves_each_startup_span_once(fused_dataset, tmp_path,
         # program's `eval_cfg` index no dep
         # ... and of the rows the mask offers on an empty cluster the
         # allocator places every one (small synthetic jobs)
+        # ... and the GNN's aggregation in the update, at the update's
+        # minibatch of the padded observation: the index form on a CPU
+        # (6 scatter-adds + 4 gathers of >= B*E indices)
         gauges = startup.gauges()
+        obs0 = loop.vec_env.obs[0]
+        minibatch = min(loop.learner.cfg.sgd_minibatch_size, 4 * 2)
+        incidence = (minibatch * obs0["node_features"].shape[0]
+                     * obs0["edge_features"].shape[0])
         offered = gauges["env.mask.rows_offered"]
         assert offered == len(loop.fused.et.types) * sum(
             bool(loop.fused.ot["shapes_exist"][d]) or d == 1
@@ -358,10 +365,65 @@ def test_build_run_leaves_each_startup_span_once(fused_dataset, tmp_path,
             "sim.lookahead.minor_used": minor,
             "sim.price.dep_indexed_ops": 0,
             "env.mask.rows_offered": offered,
-            "env.mask.rows_placeable": offered}
+            "env.mask.rows_placeable": offered,
+            "gnn.aggregate.indexed_ops": 10,
+            "gnn.aggregate.incidence_elems": incidence}
+        order = list(seconds)
+        assert (order.index("sim.price.dep_indexed_ops")
+                < order.index("gnn.aggregate.indexed_ops")
+                < order.index("gnn.aggregate.incidence_elems"))
         assert set(seconds) == {n.removeprefix("startup.")
                                 for n, _, _ in reg.span_intervals()} \
             | set(startup.gauges())
         assert seconds["first_epoch"] > 0
     finally:
         loop.close()
+
+
+
+# ------------------------------------------ the aggregation's gauge
+@pytest.mark.parametrize("form,indexed", [("segment", 10), ("dense", 0)])
+def test_aggregate_gauge_counts_the_updates_indexed_ops(form, indexed,
+                                                        monkeypatch):
+    """`gnn.aggregate.indexed_ops` over the forward + backward of one
+    update minibatch: 6 scatter-adds + 4 gathers of >= B*E indices in
+    the index form, none when every aggregation is a contraction; and
+    the PPO minibatch step's own traced program says the same (what it
+    holds beside them — `take_along_axis` over B actions — is under the
+    threshold). `gnn.aggregate.incidence_elems` is B*N*E."""
+    import jax
+
+    import test_rl
+    from ddls_tpu.models import policy
+    from ddls_tpu.ops import segment
+    from ddls_tpu.parallel import make_mesh
+    from ddls_tpu.utils.jaxprs import indexed_ops
+
+    monkeypatch.setattr(segment, "aggregate_form",
+                        lambda platform, n_nodes, n_edges: form)
+    B, N, E = 8, test_rl.MAX_NODES, test_rl.MAX_EDGES
+    rng = np.random.RandomState(3)
+    obs = test_rl._fake_obs(rng, (B,))
+    model = policy.GNNPolicy(n_actions=test_rl.N_ACTIONS)
+    params = model.init(jax.random.PRNGKey(0),
+                        jax.tree_util.tree_map(lambda x: x[0], obs))
+    learner = test_rl._make_learner(make_mesh(1), model)
+    spec = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), obs)
+    assert policy.aggregate_gauges(learner.apply_fn, params, spec) == (
+        indexed, B * N * E)
+
+    state = learner.init_state(params)
+    per_sample = jax.ShapeDtypeStruct((B,), np.float32)
+    minibatch = {"obs": spec,
+                 "actions": jax.ShapeDtypeStruct((B,), np.int32),
+                 **{k: per_sample for k in (
+                     "old_logp", "old_values", "advantages",
+                     "value_targets")}}
+    step = jax.make_jaxpr(learner._minibatch_step)(state, minibatch)
+    found = indexed_ops(step.jaxpr, B * E)
+    assert len(found) == indexed
+    assert sorted(set(found)) == (["gather", "scatter-add"] if indexed
+                                  else [])
+    assert found.count("gather") == (4 if indexed else 0)
+    assert indexed_ops(step.jaxpr, B)     # the action read, the shuffle
