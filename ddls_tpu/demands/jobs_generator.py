@@ -39,6 +39,12 @@ from ddls_tpu.graphs.readers import read_graph_file
 from ddls_tpu.graphs.synthetic import generate_pipedream_txt_files
 from ddls_tpu.telemetry import startup
 
+#: start-up gauges of an ``architecture`` job source that the fused loop
+#: counts once per drained epoch trace: the sum over the bank's models of
+#: ``graphs.arch.quadratic_time_share.<model>`` and the models summed
+#: over, so that a window's counters give the bank's mean as a ratio
+BANK_GAUGES = ("graphs.arch.quadratic_time_shares", "graphs.arch.models")
+
 
 class JobSampler:
     """Sample jobs from a pool (reference Sampler: ddls/utils.py:50).
@@ -186,7 +192,8 @@ class JobsGenerator:
                 raise ValueError(f"architecture: unknown keys {unknown}")
             arch_file = arch.load_arch_file(architecture["config"])
             # (config, shapes, the cut, the family's stated sizes)
-            family = (arch_file["config"], architecture["shapes"],
+            family = (arch.builder_config(arch_file),
+                      architecture["shapes"],
                       architecture.get("layers"),
                       architecture.get("experts_held"),
                       arch_file.get("training_state"))
@@ -233,6 +240,7 @@ class JobsGenerator:
         graphs = [read_graph_file(p, device_type=self.device_type)
                   for p in file_paths]
         if architecture is not None:
+            shares = []
             for g in graphs:
                 model = g.meta["model"]
                 startup.set_gauge(f"graphs.arch.forward_ops.{model}",
@@ -249,6 +257,24 @@ class JobsGenerator:
                     f"graphs.arch.sync_bytes_max.{model}",
                     max(g.sync_size(o) for o in ops
                         if not g.is_forward(o)))
+                # layer kinds, and the share of a degree-1 forward pass
+                # in ops whose FLOPs grow as S^2, from the profile's own
+                # op names and times
+                types = g.meta["op_types"]
+                for kind, core in (("full", "AttnCore"),
+                                   ("window", "WindowAttnCore")):
+                    startup.set_gauge(
+                        f"graphs.arch.layers_{kind}.{model}",
+                        sum(t == core for t in types.values()))
+                share = sum(g.compute_cost(o) for o, t in types.items()
+                            if t in arch.QUADRATIC_OPS) \
+                    / sum(g.compute_cost(o) for o in types)
+                startup.set_gauge(
+                    f"graphs.arch.quadratic_time_share.{model}", share)
+                shares.append(share)
+            # the bank's mean share is the first over the second
+            for name, value in zip(BANK_GAUGES, (sum(shares), len(shares))):
+                startup.set_gauge(name, value)
         return graphs, dataset_id
 
     def __len__(self) -> int:

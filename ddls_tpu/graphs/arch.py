@@ -4,35 +4,50 @@ A public ``config.json`` of a decoder-only transformer, a sequence
 length and a micro-batch in sequences become one forward-pass profile in
 the format ``graphs/readers.py:_parse_pipedream_txt`` reads, so reader ->
 mirror -> ``Job`` stays the one path every job takes. What a layer is
-made of is chosen from the config's KEYS, never from its name:
+made of is chosen per LAYER from the config's KEYS, never from its name:
 
 * attention — ``kv_lora_rank`` present: multi-head latent attention
   (low-rank q and kv paths), and with ``index_topk`` a learned-sparse
   core behind an indexer (:func:`_latent_sparse_attention`); absent:
-  MHA/GQA with q/k norms and RoPE (:func:`_full_attention`);
-* feed-forward — the first ``first_k_dense_replace`` layers (0 when the
-  key is absent) are dense SwiGLU at ``intermediate_size``; the others
-  route over ``n_routed_experts`` / ``num_experts`` SwiGLU experts,
-  with ``n_shared_experts`` always-on ones beside them when the key is
-  there; ``scoring_func: sigmoid`` picks the bias-corrected sigmoid
+  MHA/GQA (:func:`_gqa_attention`), whose core is causal over the whole
+  sequence or, on layer i of a ``hybrid_layer_pattern`` list with
+  ``pattern[i] == 1``, over a ``sliding_window`` with the ``swa_*``
+  head counts and sizes. Each part is counted where a key states it:
+  ``v_head_dim`` a v head size beside q and k's ``head_dim``,
+  ``partial_rotary_factor`` RoPE on that part of each q/k head (absent:
+  the whole head), ``attention_value_scale`` scaled values,
+  ``use_qk_norm`` a q/k RMSNorm, ``add_swa_attention_sink_bias`` /
+  ``add_full_attention_sink_bias`` a learnable sink logit a head;
+* feed-forward — dense SwiGLU at ``intermediate_size`` on layer i where
+  a ``moe_layer_freq`` LIST has a 0 (a scalar or no key: on the first
+  ``first_k_dense_replace`` layers); the others route over
+  ``n_routed_experts`` / ``num_experts`` SwiGLU experts, with
+  ``n_shared_experts`` always-on ones beside them when the key gives a
+  number; ``scoring_func: sigmoid`` picks the bias-corrected sigmoid
   router, otherwise softmax top-k;
 * ``num_nextn_predict_layers``: that many multi-token-prediction
   modules (the DeepSeek-V3 form) after the last layer.
 
-Two families are built today: OLMoE (full attention, softmax router:
+Three families are built today: OLMoE (full attention, softmax router:
 embedding, L x [InputNorm, QKVProj, AttnCore, OutProjResidual,
-PostAttnNorm, Router, Experts, CombineResidual], FinalNorm, LMHeadLoss)
-and ``glm_moe_dsa`` (equations beside each op below, ``x`` the normed
-hidden state). Ops are at one granularity in both: each norm, each
-projection, the indexer's projections, index score + top-k, the
-attention core, out-projection + residual, router, shared expert,
-expert group, combine + residual; there is an edge for every true data
-dependency and no other.
+PostAttnNorm, Router, Experts, CombineResidual], FinalNorm, LMHeadLoss),
+``glm_moe_dsa`` and ``mimo_v2_flash`` (OLMoE's 8-op layer with
+WindowAttnCore on the window layers and DenseMLPResidual on the dense
+one; equations beside each op below, ``x`` the normed hidden state).
+Ops are at one granularity in all: each norm, each projection, the
+indexer's projections, index score + top-k, the attention core,
+out-projection + residual, router, shared expert, expert group,
+combine + residual; there is an edge for every true data dependency
+and no other.
 
 **The cut** (``jobs_config.architecture``'s ``layers`` and
 ``experts_held``; absent = the config's own): ``layers: {leading_dense,
-following}`` keeps that many dense and expert layers (the others lie on
-further pods as pipeline stages), ``experts_held`` is this pod's share
+following}`` keeps the first ``leading_dense + following`` layers of the
+published stack — layer i of the job is layer i of the config's
+per-layer lists, so a stage keeps the pattern's own order and ratio, and
+``leading_dense`` has to be the dense layers those lists start with; the
+others lie on further pods as pipeline stages. ``experts_held`` is this
+pod's share
 of every expert layer's routed experts: the router keeps its published
 width and its experts per token, the expert group holds ``experts_held``
 experts and computes their part for the tokens routed to them (balanced:
@@ -93,32 +108,52 @@ def load_arch_file(path: str) -> dict:
     """The architecture file: ``{"source_url": ..., "config": {...}}``
     (the public ``config.json``'s shape keys) and, where the family
     states them, ``"training_state": {"resident_bytes_per_parameter",
-    "synced_bytes_per_parameter"}``. A relative path that does not exist
-    from the working directory is taken from the checkout's root."""
+    "synced_bytes_per_parameter"}`` and ``"modeling": {...}``
+    (:func:`builder_config`). A relative path that does not exist from
+    the working directory is taken from the checkout's root."""
     if not os.path.isabs(path) and not os.path.exists(path):
         path = os.path.join(_REPO, path)
     with open(path) as fh:
         return json.load(fh)
 
 
+def builder_config(arch_file: dict) -> dict:
+    """What :func:`build_graph` reads: the public config's keys and,
+    over them, the wrapper's ``modeling`` block — what the family's
+    modeling code fixes and its ``config.json`` leaves unsaid, under the
+    key other public configs say it by (OLMoE's q/k RMSNorm:
+    ``use_qk_norm``)."""
+    return {**arch_file["config"], **arch_file.get("modeling", {})}
+
+
 def load_arch_config(path: str) -> dict:
-    return load_arch_file(path)["config"]
+    return builder_config(load_arch_file(path))
 
 
-def sparse_keys(seq_len: int, topk: int) -> int:
-    """Keys a causal top-``topk`` sparse core reads over one sequence:
-    query t (1-based) sees min(t, topk) of them."""
-    S, K = int(seq_len), int(topk)
+def attended_keys(seq_len: int, limit: int) -> int:
+    """Keys a causal core reads over one sequence when query t (1-based)
+    sees min(t, limit) of them: a top-``limit`` sparse core, a sliding
+    window of ``limit``, and with ``limit >= seq_len`` the full causal
+    triangle S (S + 1) / 2."""
+    S, K = int(seq_len), int(limit)
     if S <= K:
         return S * (S + 1) // 2
     return K * (K + 1) // 2 + (S - K) * K
+
+
+def _leading_zeros(values: Sequence[int]) -> int:
+    return next((i for i, v in enumerate(values) if v), len(values))
 
 
 def resolve_cut(config: dict, layers: Optional[dict] = None,
                 experts_held: Optional[int] = None) -> Dict[str, int]:
     """``{"leading_dense", "following", "experts_held"}`` with what the
     cut leaves out taken from the config."""
-    dense = int(config.get("first_k_dense_replace") or 0)
+    freq = config.get("moe_layer_freq")
+    if isinstance(freq, list):
+        dense = _leading_zeros(freq)
+    else:
+        dense = int(config.get("first_k_dense_replace") or 0)
     cut = {"leading_dense": dense,
            "following": int(config["num_hidden_layers"]) - dense,
            "experts_held": int(config.get("n_routed_experts")
@@ -130,6 +165,14 @@ def resolve_cut(config: dict, layers: Optional[dict] = None,
         cut.update({k: int(v) for k, v in layers.items()})
     if experts_held is not None:
         cut["experts_held"] = int(experts_held)
+    if isinstance(freq, list):
+        # kinds come from the list: the cut only says how many layers
+        total = cut["leading_dense"] + cut["following"]
+        if total > len(freq) \
+                or _leading_zeros(freq[:total]) != cut["leading_dense"]:
+            raise ValueError(
+                f"architecture layers: {cut} departs from moe_layer_freq "
+                f"{freq[:total]} (of {len(freq)} layers)")
     return cut
 
 
@@ -187,22 +230,58 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
         return g.add(op_type, 4 * tokens * H,
                      A * (2 * tokens * H + H), tokens * H, H, inputs)
 
-    def _full_attention(stream):
-        """MHA/GQA as OLMoE has it; returns OutProjResidual."""
-        kv_heads = int(config.get("num_key_value_heads") or heads)
-        head_dim = int(config.get("head_dim") or H // heads)
-        q, kv = heads * head_dim, kv_heads * head_dim
-        qkv = q + 2 * kv
+    def _gqa_attention(stream, window):
+        """MHA/GQA with a full causal core or, with ``window``, a
+        sliding-window one at the ``swa_*`` sizes; returns
+        OutProjResidual."""
+        def size(key, default=None):
+            value = config.get("swa_" + key) if window else None
+            return int(value or config.get(key) or default)
+
+        n = size("num_attention_heads")
+        kv_heads = size("num_key_value_heads", n)
+        d_qk = size("head_dim", H // n)
+        d_v = size("v_head_dim", d_qk)
+        q, kk, vv = n * d_qk, kv_heads * d_qk, kv_heads * d_v
+        qkv = q + kk + vv
+        # RoPE (3 an element) on the rotary part of each q and k head;
+        # where stated, q/k RMSNorm (4 an element, a weight an element
+        # of a token's q and k) and v <- attention_value_scale . v (1)
+        rotary = round(float(config.get("partial_rotary_factor") or 1)
+                       * d_qk)
+        qk_norm = bool(config.get("use_qk_norm"))
+        elementwise = 3 * T * (n + kv_heads) * rotary \
+            + qk_norm * 4 * T * (q + kk) \
+            + ("attention_value_scale" in config) * T * vv
+        norm_weights = qk_norm * (q + kk)
         x = norm("InputNorm", [stream])
-        # x W_qkv; q/k RMSNorm (4 per element) and RoPE (3) on q and k
-        proj = g.add("QKVProj", 2 * T * H * qkv + 7 * T * (q + kv),
-                     A * (T * H + H * qkv + q + kv + T * qkv), T * qkv,
-                     H * qkv + q + kv, [x])
-        # QK^T and PV over the causal half of S x S, softmax 5 a score
-        core = g.add("AttnCore", B * heads * S * S * (2 * head_dim + 2.5),
-                     A * (T * qkv + T * q), T * q, 0, [proj])
-        return g.add("OutProjResidual", 2 * T * q * H + T * H,
-                     A * (T * q + q * H + 2 * T * H), T * H, q * H,
+        # [q ; k ; v] = x W_qkv
+        proj = g.add("QKVProj", 2 * T * H * qkv + elementwise,
+                     A * (T * H + H * qkv + norm_weights + T * qkv),
+                     T * qkv, H * qkv + norm_weights, [x])
+        # o_t = sum_s softmax_s(q_t . k_s / sqrt(d_qk)) v_s over the
+        # keys a query sees: QK^T 2 d_qk, PV 2 d_v, softmax 5 a key; a
+        # learnable sink logit a head in the denominator (1 a query and
+        # head) where stated
+        sinks = n if config.get(
+            "add_swa_attention_sink_bias" if window
+            else "add_full_attention_sink_bias") else 0
+        if window:
+            keys = attended_keys(S, int(config["sliding_window"]))
+        elif "v_head_dim" in config:
+            keys = attended_keys(S, S)
+        else:
+            # one head size: the causal half of S x S without its
+            # diagonal (1 / S of it), as OLMoE's pinned profiles count
+            keys = S * S / 2
+        core = g.add("WindowAttnCore" if window else "AttnCore",
+                     B * keys * n * (2 * d_qk + 2 * d_v + 5) + T * sinks,
+                     A * (T * qkv + sinks + T * n * d_v),
+                     T * n * d_v, sinks, [proj])
+        # y = o W_o (n d_v -> H) + residual
+        o = n * d_v
+        return g.add("OutProjResidual", 2 * T * o * H + T * H,
+                     A * (T * o + o * H + 2 * T * H), T * H, o * H,
                      [core, stream])
 
     def _latent_sparse_attention(stream):
@@ -253,7 +332,7 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
         # o_t = sum_{s in S_t} softmax(q_t . [k_n,s ; k_r,s]) v_s: a
         # query reads min(t, topk) keys; QK^T 2 dqk, PV 2 dv, softmax 5
         core = g.add("SparseAttnCore",
-                     B * sparse_keys(S, topk) * heads * (2 * dqk + 2 * dv + 5),
+                     B * attended_keys(S, topk) * heads * (2 * dqk + 2 * dv + 5),
                      A * (T * heads * dqk + T * heads * (dn + dv) + T * dr
                           + T * min(S, topk) + T * heads * dv),
                      T * heads * dv, 0, [q, kv, c_kv, select])
@@ -261,13 +340,25 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
                      A * (T * heads * dv + heads * dv * H + 2 * T * H),
                      T * H, heads * dv * H, [core, stream])
 
-    attention = (_latent_sparse_attention if "kv_lora_rank" in config
-                 else _full_attention)
+    pattern = config.get("hybrid_layer_pattern")
+    freq = config.get("moe_layer_freq")
+    if n_mtp and isinstance(pattern, list):
+        raise ValueError("hybrid_layer_pattern gives no kind for a "
+                         "multi-token-prediction module's layer")
 
-    def layer(stream, dense):
-        """One decoder layer on the residual stream op ``stream``;
+    def layer(stream, i=None):
+        """Decoder layer ``i`` of the published stack (None: an MTP
+        module's expert layer) on the residual stream op ``stream``;
         returns the op that carries the stream out."""
-        out_proj = attention(stream)
+        if "kv_lora_rank" in config:
+            out_proj = _latent_sparse_attention(stream)
+        else:
+            out_proj = _gqa_attention(
+                stream, window=isinstance(pattern, list) and pattern[i] == 1)
+        if isinstance(freq, list):
+            dense = freq[i] == 0
+        else:
+            dense = i is not None and i < cut["leading_dense"]
         x = norm("PostAttnNorm", [out_proj])
         if dense:
             # gate, up, down, silu * up (4 a value), + residual
@@ -316,7 +407,7 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
     embedding = g.add("Embedding", 0, A * 2 * T * H + 4 * T, T * H, V * H)
     stream = embedding
     for i in range(cut["leading_dense"] + cut["following"]):
-        stream = layer(stream, dense=i < cut["leading_dense"])
+        stream = layer(stream, i)
     streams = [stream]
     for _ in range(n_mtp):
         # h' = [RMSNorm(h_t) ; RMSNorm(Emb(x_{t+1}))] W_eh (2H -> H),
@@ -327,7 +418,7 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
         proj = g.add("MTPProj", 2 * T * 2 * H * H,
                      A * (2 * T * H + 2 * H * H + T * H), T * H,
                      2 * H * H, [h, e])
-        streams.append(layer(proj, dense=False))
+        streams.append(layer(proj))
     # the final norm and the head (+ loss) hold their parameters once
     # and run once per stream: the main model's, then each MTP module's
     tokens = T * len(streams)
@@ -352,6 +443,11 @@ def forward_time(cost: dict) -> float:
     its compute and its memory roofline."""
     return max(cost["flops"] / A100.peak_flops,
                cost["bytes"] / A100.memory_bandwidth)
+
+
+#: forward ops whose FLOPs grow as S^2: a full causal core and the
+#: sparse indexer's score (a windowed or top-k core grows as S)
+QUADRATIC_OPS = ("AttnCore", "IndexScoreTopK")
 
 
 def profile_text(config: dict, seq_len: int, micro_batch: int,

@@ -146,6 +146,8 @@ def graph_from_pipedream_txt(path: str,
 
     g.meta["file_path"] = path
     g.meta["model"] = _model_name_from_path(path)
+    # the profile's own name of each forward op ("AttnCore", "Conv2d")
+    g.meta["op_types"] = {op: vals["op_type"] for op, vals in nodes.items()}
     if verbose:
         print(f"loaded {path}: {g}")
     return g

@@ -39,6 +39,7 @@ from typing import Callable, Dict, Optional, Tuple
 import numpy as np
 
 from ddls_tpu import telemetry
+from ddls_tpu.demands.jobs_generator import BANK_GAUGES
 from ddls_tpu.sim.jax_env import MASK_GAUGES
 from ddls_tpu.sim.jax_lookahead import MINOR_GAUGES
 from ddls_tpu.telemetry import scopes, startup
@@ -126,7 +127,7 @@ def _count_startup_gauges(names) -> None:
     for name in names:
         value = startup.registry().gauge(name).value
         if value is not None:
-            telemetry.inc(name, int(value))
+            telemetry.inc(name, value)
 
 
 def record_lookahead_trips(ep_trace, pads) -> None:
@@ -193,23 +194,33 @@ def record_padding_fill(ep_trace, et, ot) -> None:
                   jtype.size * int(ot["node_features"].shape[1]))
 
 
-def record_decisions(ep_trace, et) -> None:
+def record_decisions(ep_trace, et, ot) -> None:
     """What the decisions met, from a FETCHED ``[..., B, T]`` trace:
     ``env.decisions.offered`` — decisions taken — beside
     ``env.decisions.accepted`` — those whose job was mounted;
+    ``env.decisions.offered_longest`` / ``accepted_longest`` — the same
+    two over the decisions on the bank's job type with the largest
+    degree-1 step time (``ot["orig_seq_sum"]``);
     ``env.cluster.occupied_servers`` — the servers other jobs held when
     each decision was taken, summed — beside ``env.cluster.servers`` —
     decisions x the cluster's servers. And from the mask's start-up
     gauges (`sim/jax_env.py:mask_rows_on_empty_cluster`), once per
-    drained epoch trace: ``env.mask.rows_offered`` / ``rows_placeable``.
+    drained epoch trace: ``env.mask.rows_offered`` / ``rows_placeable``
+    and, where an architecture built the jobs, its
+    ``demands/jobs_generator.py:BANK_GAUGES``.
     The caller gates on ``telemetry.enabled()``."""
     accepted = np.asarray(ep_trace["accepted"])
+    longest = np.asarray(ep_trace["jtype"]) \
+        == int(np.argmax(ot["orig_seq_sum"]))
     telemetry.inc("env.decisions.offered", int(accepted.size))
     telemetry.inc("env.decisions.accepted", int(accepted.sum()))
+    telemetry.inc("env.decisions.offered_longest", int(longest.sum()))
+    telemetry.inc("env.decisions.accepted_longest",
+                  int(accepted[longest].sum()))
     telemetry.inc("env.cluster.occupied_servers",
                   int(np.asarray(ep_trace["n_occupied"]).sum()))
     telemetry.inc("env.cluster.servers", int(accepted.size) * et.n_srv)
-    _count_startup_gauges(MASK_GAUGES)
+    _count_startup_gauges((*MASK_GAUGES, *BANK_GAUGES))
 
 
 class FusedEpochDriver:

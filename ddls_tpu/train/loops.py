@@ -1243,7 +1243,7 @@ class RLEpochLoop:
             if telemetry.enabled():
                 record_lookahead_trips(ep, harvester.et.pads)
                 record_padding_fill(ep, harvester.et, harvester.ot)
-                record_decisions(ep, harvester.et)
+                record_decisions(ep, harvester.et, harvester.ot)
         return episodes
 
     def _run_fused(self) -> Dict[str, Any]:

@@ -1,8 +1,9 @@
-"""An independent plain count of a ``glm_moe_dsa`` training step: per op
-the parameters, the forward FLOPs and the elements of the output tensor,
-written straight from the layer equations (ISSUE 30, Tentpole step 1) and
-importing nothing from ``ddls_tpu/graphs/arch.py``, which
-``tests/test_arch_graphs.py`` holds to it op by op.
+"""Independent plain counts of a ``glm_moe_dsa`` and of a
+``mimo_v2_flash`` training step: per op the parameters, the forward
+FLOPs and the elements of the output tensor, written straight from the
+layer equations (ISSUE 30 and ISSUE 32, Tentpole step 1) and importing
+nothing from ``ddls_tpu/graphs/arch.py``, which
+``tests/test_arch_graphs.py`` holds to them op by op.
 
 Conventions: 2 FLOPs a multiply-accumulate; RMSNorm 4 an element; RoPE 3
 an element it turns; softmax / sigmoid-and-select 5 a score; SwiGLU's
@@ -114,4 +115,71 @@ def plain_counts(c, S, B, leading_dense=None, following=None, held=None):
             ("LMHeadLoss", H * V,
              2 * streams * T * H * V + 5 * streams * T * V,
              streams * T * V)]
+    return ops
+
+
+# ========================================================== mimo_v2_flash
+def _mimo_attention(c, S, B, window):
+    """GQA with split head sizes: q and k heads of ``head_dim``, v heads
+    of ``v_head_dim``; a full causal core, or a sliding-window one with
+    its own kv head count and a sink logit a head. 4 ops after the
+    input norm."""
+    T, H = S * B, c["hidden_size"]
+    pre = "swa_" if window else ""
+    n = c[pre + "num_attention_heads"]
+    g = c[pre + "num_key_value_heads"]
+    dqk, dv = c[pre + "head_dim"], c[pre + "v_head_dim"]
+    rotary = round(c["partial_rotary_factor"] * dqk)
+    width = n * dqk + g * dqk + g * dv
+    if window:
+        keys = keys_read(S, c["sliding_window"])
+        sink = n if c["add_swa_attention_sink_bias"] else 0
+        name = "WindowAttnCore"
+    else:
+        keys = S * (S + 1) // 2
+        sink = n if c["add_full_attention_sink_bias"] else 0
+        name = "AttnCore"
+    return [
+        _rmsnorm("InputNorm", T, H),
+        # [q ; k ; v] = x W_qkv, RoPE on the rotary part of the q and k
+        # heads, v scaled
+        ("QKVProj", H * width,
+         2 * T * H * width + 3 * T * (n + g) * rotary + T * g * dv,
+         T * width),
+        # o_t = sum_s exp(q_t.k_s) v_s / (exp(b_h) + sum_s exp(q_t.k_s))
+        (name, sink, B * keys * n * (2 * dqk + 2 * dv + 5) + T * sink,
+         T * n * dv),
+        ("OutProjResidual", n * dv * H, 2 * T * n * dv * H + T * H, T * H),
+        _rmsnorm("PostAttnNorm", T, H),
+    ]
+
+
+def plain_counts_mimo(c, S, B, layers=None, held=None):
+    """[(op_type, parameters, forward FLOPs, output elements)] of the
+    first ``layers`` layers of the published per-layer lists."""
+    if layers is None:
+        layers = c["num_hidden_layers"]
+    if held is None:
+        held = c["n_routed_experts"]
+    T, H, V = S * B, c["hidden_size"], c["vocab_size"]
+    E, k = c["n_routed_experts"], c["num_experts_per_tok"]
+    pairs = T * k * held / E
+    ops = [("Embedding", V * H, 0, T * H)]
+    for i in range(layers):
+        ops += _mimo_attention(c, S, B, c["hybrid_layer_pattern"][i] == 1)
+        if c["moe_layer_freq"][i] == 0:
+            I = c["intermediate_size"]
+            ops.append(("DenseMLPResidual", 3 * H * I,
+                        2 * T * 3 * H * I + 4 * T * I + T * H, T * H))
+            continue
+        I = c["moe_intermediate_size"]
+        ops += [
+            ("Router", H * E + E, 2 * T * H * E + 5 * T * E + 3 * T * k,
+             2 * T * k),
+            ("Experts", held * 3 * H * I,
+             2 * pairs * 3 * H * I + 4 * pairs * I, pairs * H),
+            # weighted routed outputs + residual: no shared expert
+            ("CombineResidual", 0, 2 * pairs * H + T * H, T * H)]
+    ops += [_rmsnorm("FinalNorm", T, H),
+            ("LMHeadLoss", H * V, 2 * T * H * V + 5 * T * V, T * V)]
     return ops

@@ -11,6 +11,7 @@ import sys
 import numpy as np
 import pytest
 
+from ddls_tpu.demands.jobs_generator import BANK_GAUGES
 from ddls_tpu.graphs import arch
 from ddls_tpu.graphs.readers import _parse_pipedream_txt, read_graph_file
 
@@ -20,7 +21,7 @@ OLMOE_FILE = "ddls_tpu/graphs/arch_configs/olmoe_1b_7b_0125.json"
 #: 2 layers, hidden 64, 4 experts, 2 per token, sequence 32
 TINY = {"model_type": "tinymoe", "hidden_size": 64,
         "num_attention_heads": 4, "num_key_value_heads": 4,
-        "intermediate_size": 32, "num_experts": 4,
+        "use_qk_norm": True, "intermediate_size": 32, "num_experts": 4,
         "num_experts_per_tok": 2, "num_hidden_layers": 2,
         "vocab_size": 256}
 #: micro-batches that put the tiny preset's op times where the full
@@ -259,9 +260,16 @@ def test_generator_takes_an_architecture_like_any_other_source(tmp_path):
     assert JobsGenerator(**jobs).workload_fingerprint \
         == gen.workload_fingerprint          # another temp dir, same id
     gauges = startup.gauges()
-    assert {k: v for k, v in gauges.items() if "_bytes" not in k} == {
+    assert gauges.pop(BANK_GAUGES[1]) == 2
+    shares = {k: v for k, v in gauges.items() if "_time_share" in k}
+    assert {k: v for k, v in gauges.items()
+            if "_bytes" not in k and k not in shares} == {
         f"graphs.arch.{what}.{m}": n for m in models
-        for what, n in (("forward_ops", 19), ("edges", 53))}
+        for what, n in (("forward_ops", 19), ("edges", 53),
+                        ("layers_full", 2), ("layers_window", 0))}
+    assert sorted(shares) == sorted(
+        [BANK_GAUGES[0]]
+        + [f"graphs.arch.quadratic_time_share.{m}" for m in models])
     # an unstated family: what a dep or a sync edge is sized by is the
     # largest op's whole memory cost
     for m in models:
@@ -439,6 +447,29 @@ def glm():
     return arch.load_arch_config(GLM_FILE)
 
 
+@pytest.mark.parametrize("stated", ["modeling_block", "config_key",
+                                    "nowhere"])
+def test_qk_norm_is_counted_where_a_key_states_it(olmoe, stated):
+    """OLMoE's ``config.json`` has no key for its q/k RMSNorm: the
+    architecture file's ``modeling`` block states ``use_qk_norm`` and
+    `builder_config` lays it over the config. The builder counts the
+    norm (a weight and 4 FLOPs an element of a token's q and k) by that
+    key alone, wherever it was stated."""
+    published = arch.load_arch_file(OLMOE_FILE)["config"]
+    assert "use_qk_norm" not in published and olmoe["use_qk_norm"] is True
+    config = {"modeling_block": olmoe,
+              "config_key": {**published, "use_qk_norm": True},
+              "nowhere": published}[stated]
+    T, H = 4096, published["hidden_size"]
+    qk = 2 * H                       # 16 q heads and 16 k heads of 128
+    proj = next(c for c in arch.op_costs(config, T, 1)
+                if c["op_type"] == "QKVProj")
+    normed = stated != "nowhere"
+    assert proj["params"] == H * 3 * H + normed * qk
+    # x W_qkv, RoPE 3 an element of q and k, the norm 4
+    assert proj["flops"] == 2 * T * H * 3 * H + (3 + 4 * normed) * T * qk
+
+
 @pytest.mark.parametrize("micro_batch", [1, 2, 4, 8])
 def test_olmoe_profiles_are_text_equal_to_the_parents(olmoe, micro_batch):
     """The builder was rewritten around layer kinds; what it writes for
@@ -504,7 +535,7 @@ def test_full_depth_totals_are_the_published_model(glm, quantity):
     (2049, 2048 * 2049 // 2 + 2048),
     (65536, 2048 * 2049 // 2 + (65536 - 2048) * 2048)])
 def test_sparse_core_reads_min_t_topk_keys_a_query(glm, seq_len, keys):
-    assert arch.sparse_keys(seq_len, glm["index_topk"]) == keys
+    assert arch.attended_keys(seq_len, glm["index_topk"]) == keys
     core = next(o for o in arch.op_costs(glm, seq_len, 1, **GLM_CUT)
                 if o["op_type"] == "SparseAttnCore")
     heads = glm["num_attention_heads"]
@@ -830,3 +861,485 @@ def test_conformance_host_native_leg_on_a_stated_spec(tmp_path):
     leg, = report["legs"]
     assert leg["status"] == "ok", leg
     assert leg["rtol"] == 0.0 and leg["decisions"] == 30
+
+
+# ========================================================= mimo_v2_flash
+MIMO_FILE = "ddls_tpu/graphs/arch_configs/mimo_v2_flash.json"
+#: the deployment's cut (env_mimo_32.yaml): layers 0-6 of the published
+#: lists, 64 of the 256 routed experts
+MIMO_CUT = {"layers": {"leading_dense": 1, "following": 6},
+            "experts_held": 64}
+MIMO_SHAPES = [(8192, 4), (32768, 1), (65536, 1), (262144, 1)]
+#: 4 layers (attention F W W F; feed-forward D E E E), hidden 64, q/k
+#: heads of 24 beside v heads of 16, 1 kv head on full layers and 2 on
+#: window layers, window 16, 8 experts (2 a token, none shared)
+TINY_MIMO = {"model_type": "tinymimo", "hidden_size": 64,
+             "num_attention_heads": 4, "num_key_value_heads": 1,
+             "head_dim": 24, "v_head_dim": 16,
+             "swa_num_attention_heads": 4, "swa_num_key_value_heads": 2,
+             "swa_head_dim": 24, "swa_v_head_dim": 16,
+             "partial_rotary_factor": 0.334, "attention_value_scale": 0.707,
+             "sliding_window": 16, "hybrid_layer_pattern": [0, 1, 1, 0],
+             "moe_layer_freq": [0, 1, 1, 1],
+             "add_swa_attention_sink_bias": True,
+             "add_full_attention_sink_bias": False,
+             "intermediate_size": 128, "moe_intermediate_size": 32,
+             "n_routed_experts": 8, "n_shared_experts": None,
+             "num_experts_per_tok": 2, "num_hidden_layers": 4,
+             "scoring_func": "sigmoid", "vocab_size": 256}
+GLM_SHA256 = {
+    (8192, 1):
+        "a35b7d33627fa75c547574150e7600ff3e45eb34ecba07455c1f6225f2930f84",
+    (8192, 4):
+        "f7f69f829bc6fb32d96501e42f1de8055f4f1d5d604cc91c95a2f4fba79c6892",
+    (32768, 1):
+        "796597f437747b3801d9899db80e46b8f708a258cd7ec0e771eb1fa2f1ad75a4",
+    (65536, 1):
+        "9fb3efb29c5d2176570e6986500d4af38838d39d6c8097928826af318f4ad07e"}
+
+
+@pytest.fixture(scope="module")
+def mimo():
+    return arch.load_arch_config(MIMO_FILE)
+
+
+@pytest.mark.parametrize("shape", sorted(GLM_SHA256))
+def test_glm_profiles_are_text_equal_to_the_parents(shape):
+    """`_full_attention` was generalised under both older families: what
+    the builder writes for GLM-5's four stated shapes is pinned to PR
+    31's bytes (OLMoE's four: `test_olmoe_profiles_are_text_equal_...`)."""
+    import hashlib
+
+    family = arch.load_arch_file(GLM_FILE)
+    text = arch.profile_text(family["config"], *shape, GLM_CUT["layers"],
+                             GLM_CUT["experts_held"],
+                             family["training_state"])
+    assert hashlib.sha256(text.encode()).hexdigest() == GLM_SHA256[shape]
+
+
+@pytest.mark.parametrize("case", ["tiny_s8", "tiny_s200", "tiny_cut",
+                                  "mimo_8k_cut", "mimo_256k_cut",
+                                  "mimo_whole"])
+def test_mimo_op_costs_equal_the_plain_count_op_by_op(mimo, case):
+    """`plain_counts_mimo` is written from the equations and imports
+    nothing of the builder: parameters, FLOPs and output elements agree
+    on every op, below and above the window, with both kinds of each
+    per-layer list, split head sizes and per-kind kv heads."""
+    from plain_arch_counts import plain_counts_mimo
+
+    config, seq_len, micro_batch, cut, plain_cut = {
+        "tiny_s8": (TINY_MIMO, 8, 3, {}, {}),
+        "tiny_s200": (TINY_MIMO, 200, 2, {}, {}),
+        "tiny_cut": (TINY_MIMO, 32, 4,
+                     {"layers": {"leading_dense": 1, "following": 2},
+                      "experts_held": 2}, {"layers": 3, "held": 2}),
+        "mimo_8k_cut": (mimo, 8192, 4, MIMO_CUT, {"layers": 7, "held": 64}),
+        "mimo_256k_cut": (mimo, 262144, 1, MIMO_CUT,
+                          {"layers": 7, "held": 64}),
+        "mimo_whole": (mimo, 4096, 1, {}, {}),
+    }[case]
+    built = arch.op_costs(config, seq_len, micro_batch, **cut)
+    plain = plain_counts_mimo(config, seq_len, micro_batch, **plain_cut)
+    assert [o["op_type"] for o in built] == [p[0] for p in plain]
+    for i, (o, (kind, params, flops, out)) in enumerate(zip(built, plain)):
+        assert o["params"] == params, (i, kind)
+        assert o["flops"] == pytest.approx(flops, rel=1e-12), (i, kind)
+        assert o["out_elems"] == pytest.approx(out, rel=1e-12), (i, kind)
+
+
+@pytest.mark.parametrize("quantity", ["parameters", "active_parameters"])
+def test_mimo_full_depth_totals_are_the_published_model(mimo, quantity):
+    """All 48 layers and 256 experts: 308.8 B parameters (published
+    309 B), ~15.4 B active a token (published A15 B)."""
+    whole = arch.op_costs(mimo, 4096, 1)
+    total = sum(o["params"] for o in whole)
+    if quantity == "parameters":
+        # embedding, a 6-op dense layer, 47 8-op expert layers, norm, head
+        assert len(whole) == 1 + 6 + 47 * 8 + 2
+        assert total == pytest.approx(308.8e9, rel=1e-4)
+        kinds = [o["op_type"] for o in whole]
+        assert (kinds.count("AttnCore"), kinds.count("WindowAttnCore")) \
+            == (9, 39)
+        assert (kinds.count("DenseMLPResidual"), kinds.count("Experts")) \
+            == (1, 47)
+    else:
+        idle = (mimo["n_routed_experts"] - mimo["num_experts_per_tok"]) \
+            * 3 * mimo["hidden_size"] * mimo["moe_intermediate_size"] * 47
+        assert total - idle == pytest.approx(15.4e9, rel=5e-3)
+
+
+@pytest.mark.parametrize("seq_len", [100, 128, 129, 262144])
+def test_window_core_reads_min_t_w_keys_a_query(mimo, seq_len):
+    """At S <= w the window is the causal triangle; above it a query
+    sees w keys: the closed form against the sum itself."""
+    w = mimo["sliding_window"]
+    keys = sum(min(t, w) for t in range(1, seq_len + 1))
+    assert arch.attended_keys(seq_len, w) == keys
+    assert arch.attended_keys(seq_len, seq_len) \
+        == seq_len * (seq_len + 1) // 2
+    costs = arch.op_costs(mimo, seq_len, 1, **MIMO_CUT)
+    window = next(o for o in costs if o["op_type"] == "WindowAttnCore")
+    full = next(o for o in costs if o["op_type"] == "AttnCore")
+    per_pair = 64 * (2 * 192 + 2 * 128 + 5)
+    assert window["flops"] == keys * per_pair + seq_len * 64
+    assert window["params"] == 64                 # a sink logit a head
+    assert full["flops"] == seq_len * (seq_len + 1) // 2 * per_pair
+    assert full["params"] == 0                    # no sink on full layers
+    assert (window["flops"] < full["flops"]) == (seq_len > w)
+
+
+def test_kv_heads_differ_by_layer_kind_and_out_projections_agree(mimo):
+    """A window layer's QKVProj holds 8 kv heads and a full layer's 4,
+    q and k at 192 and v at 128 a head; both out-projections are 64 x
+    128 = 8192 -> 4096."""
+    costs = arch.op_costs(mimo, 8192, 1, **MIMO_CUT)
+    kinds = [o["op_type"] for o in costs]
+    full_core, window_core = kinds.index("AttnCore"), kinds.index(
+        "WindowAttnCore")
+    H = 4096
+    assert costs[full_core - 1]["op_type"] == "QKVProj"
+    assert costs[full_core - 1]["params"] == H * (64 * 192 + 4 * 192
+                                                  + 4 * 128)
+    assert costs[window_core - 1]["params"] == H * (64 * 192 + 8 * 192
+                                                    + 8 * 128)
+    for core in (full_core, window_core):
+        out = costs[core + 1]
+        assert out["op_type"] == "OutProjResidual"
+        assert out["params"] == 8192 * H
+        assert costs[core]["out_elems"] == 8192 * 8192    # T x n x d_v
+    # RoPE on round(0.334 x 192) = 64 of each q and k head, v scaled
+    T = 8192
+    assert costs[full_core - 1]["flops"] == 2 * T * H * (
+        64 * 192 + 4 * 192 + 4 * 128) + 3 * T * 68 * 64 + T * 4 * 128
+
+
+@pytest.mark.parametrize("case", ["tiny", "mimo"])
+def test_the_four_mimo_shares_add_up_to_the_uncut_layer(mimo, case):
+    """Four pods hold a quarter of the routed experts each: their expert
+    groups' FLOPs and parameters sum to the uncut layer's, and every
+    other op — what each pod computes alike; there is no shared expert —
+    is the uncut layer's own, counted once."""
+    config, seq_len, held = {"tiny": (TINY_MIMO, 32, 2),
+                             "mimo": (mimo, 8192, 64)}[case]
+    layers = {"leading_dense": 1, "following": 1}
+    uncut = arch.op_costs(config, seq_len, 2, layers=layers)
+    share = arch.op_costs(config, seq_len, 2, layers=layers,
+                          experts_held=held)
+    assert [o["op_type"] for o in share] == [o["op_type"] for o in uncut]
+    assert 4 * held == config["n_routed_experts"]
+    assert "SharedExpert" not in {o["op_type"] for o in uncut}
+    seen = set()
+    for a, b in zip(share, uncut):
+        seen.add(a["op_type"])
+        if a["op_type"] == "Experts":
+            for key in ("flops", "params", "out_elems"):
+                assert 4 * a[key] == b[key], key
+        elif a["op_type"] == "CombineResidual":
+            T = seq_len * 2
+            k, H = config["num_experts_per_tok"], config["hidden_size"]
+            assert a["flops"] == 2 * T * k * H / 4 + T * H
+            assert b["flops"] == 2 * T * k * H + T * H
+        else:
+            assert a == b, a["op_type"]
+    assert {"Experts", "CombineResidual", "Router", "WindowAttnCore",
+            "AttnCore", "DenseMLPResidual"} <= seen
+
+
+def test_the_mimo_cut_keeps_the_lists_order_and_no_dangling_edge(
+        mimo, tmp_path):
+    path, = arch.write_profiles(
+        str(tmp_path), mimo, [{"seq_len": 8192, "micro_batch": 4}],
+        MIMO_CUT["layers"], MIMO_CUT["experts_held"], STATE)
+    assert os.path.basename(path) == "mimo_v2_flash_s8192_b4.txt"
+    nodes, edges = _parse_pipedream_txt(path)
+    kinds = [n["op_type"] for n in nodes.values()]
+
+    def layer(core, feed_forward):
+        return ["InputNorm", "QKVProj", core, "OutProjResidual",
+                "PostAttnNorm", *feed_forward]
+
+    expert = ["Router", "Experts", "CombineResidual"]
+    F, W = "AttnCore", "WindowAttnCore"
+    # layers 0-6 of the published lists: F W W W W F W; D E E E E E E
+    assert mimo["hybrid_layer_pattern"][:7] == [0, 1, 1, 1, 1, 0, 1]
+    assert mimo["moe_layer_freq"][:7] == [0, 1, 1, 1, 1, 1, 1]
+    assert kinds == (["Embedding"] + layer(F, ["DenseMLPResidual"])
+                     + sum((layer(core, expert)
+                            for core in (W, W, W, W, F, W)), [])
+                     + ["FinalNorm", "LMHeadLoss"])
+    assert len(kinds) == 57 and len(edges) == 82
+    ids = set(nodes)
+    assert {v for _, v in edges} == ids - {"1"}
+    assert {u for u, _ in edges} == ids - {"57"}
+    graph = read_graph_file(path)
+    assert (len(graph.forward_op_ids()), graph.n_ops, graph.n_deps) \
+        == (57, 114, 165)
+    assert sum(n["parameter"] for n in nodes.values()) \
+        == pytest.approx(188.3e9, rel=1e-3)
+    # a stated graph: parameters x 16 B + 2 x activations
+    assert sum(graph.memory_cost(o) for o in graph.op_ids) \
+        == pytest.approx(251.7e9, rel=1e-3)
+
+
+@pytest.mark.parametrize("case", ["default", "one_period", "dense_count",
+                                  "too_deep", "scalar_freq"])
+def test_a_cut_takes_the_lists_first_layers_or_is_refused(mimo, glm, case):
+    """With per-layer lists the cut says only HOW MANY layers: the
+    default is the lists' own leading zeros and length, and a cut whose
+    kinds would depart from the lists is not expressible."""
+    if case == "default":
+        assert arch.resolve_cut(mimo) == {
+            "leading_dense": 1, "following": 47, "experts_held": 256}
+    elif case == "one_period":
+        assert arch.resolve_cut(mimo, **MIMO_CUT) == {
+            "leading_dense": 1, "following": 6, "experts_held": 64}
+    elif case == "dense_count":
+        for layers in ({"leading_dense": 0, "following": 4},
+                       {"leading_dense": 2, "following": 4}):
+            with pytest.raises(ValueError, match="moe_layer_freq"):
+                arch.resolve_cut(mimo, layers)
+    elif case == "too_deep":
+        with pytest.raises(ValueError, match="moe_layer_freq"):
+            arch.resolve_cut(mimo, {"leading_dense": 1, "following": 48})
+    else:
+        # a scalar moe_layer_freq reads first_k_dense_replace, as before
+        assert glm["moe_layer_freq"] == 1
+        assert arch.resolve_cut(glm)["leading_dense"] == 3
+        assert arch.resolve_cut(glm, {"leading_dense": 0, "following": 1}
+                                )["following"] == 1
+
+
+def _kind_gauges(arch_file, shapes, **cut):
+    """The layer-kind gauges `jobs_generator` sets for a family, per
+    model name: ``{"layers_full", "layers_window",
+    "quadratic_time_share"}``."""
+    from ddls_tpu.demands.jobs_generator import JobsGenerator
+    from ddls_tpu.telemetry import startup
+
+    startup.registry().reset()
+    JobsGenerator(
+        architecture={"config": arch_file, "shapes": [
+            {"seq_len": s, "micro_batch": b} for s, b in shapes], **cut},
+        job_interarrival_time_dist={
+            "_target_": "ddls_tpu.demands.distributions.Fixed", "val": 1.0},
+        replication_factor=1, num_training_steps=20)
+    gauges = startup.gauges()
+    startup.registry().reset()
+    kinds = {}
+    for name, value in gauges.items():
+        what, _, model = name[len("graphs.arch."):].partition(".")
+        if what in ("layers_full", "layers_window", "quadratic_time_share"):
+            kinds.setdefault(model, {})[what] = value
+    return kinds
+
+
+@pytest.mark.parametrize("family", ["olmoe", "glm", "mimo"])
+def test_generator_counts_layers_and_the_quadratic_share(family):
+    """What `jobs_generator` sets as start-up gauges from the profile's
+    own op names and times: layers by the kind of their core, and the
+    share of a degree-1 forward pass in ops whose FLOPs grow as S^2
+    (full cores; GLM-5's index score)."""
+    if family == "olmoe":
+        kinds = _kind_gauges(OLMOE_FILE, [(4096, 1)])
+        assert kinds["olmoe_s4096_b1"]["layers_full"] == 16
+        assert kinds["olmoe_s4096_b1"]["layers_window"] == 0
+        assert 0.05 < kinds["olmoe_s4096_b1"]["quadratic_time_share"] < 0.2
+    elif family == "glm":
+        kinds = _kind_gauges(GLM_FILE, [(8192, 1), (65536, 1)], **GLM_CUT)
+        for k in kinds.values():      # latent-sparse: neither kind
+            assert (k["layers_full"], k["layers_window"]) == (0, 0)
+        assert kinds["glm_moe_dsa_s8192_b1"]["quadratic_time_share"] \
+            < kinds["glm_moe_dsa_s65536_b1"]["quadratic_time_share"] < 0.5
+    else:
+        kinds = _kind_gauges(MIMO_FILE, MIMO_SHAPES, **MIMO_CUT)
+        shares = []
+        for (s, b) in MIMO_SHAPES:
+            k = kinds[f"mimo_v2_flash_s{s}_b{b}"]
+            assert (k["layers_full"], k["layers_window"]) == (2, 5)
+            shares.append(k["quadratic_time_share"])
+        # the two full cores: 8.5 % of an 8k x 4 step, 75 % of a 256k one
+        assert shares == pytest.approx([0.0853, 0.2717, 0.4273, 0.7490],
+                                       abs=1e-4)
+
+
+def test_mimo_env_yaml_states_what_its_comments_derive(mimo):
+    """env_mimo_32.yaml: the cut and the shapes as data, the arrival gap
+    and horizon derived from the builder's graph, env_olmoe32's pads
+    rule, and nothing else changed from env_glm5_32."""
+    import math
+
+    from ddls_tpu.config import load_config
+
+    def env(name):
+        return load_config(
+            os.path.join(REPO, "scripts/ramp_job_partitioning_configs"),
+            "rllib_config", [f"env_config={name}"])["env_config"]
+
+    cfg, base = env("env_mimo_32"), env("env_glm5_32")
+    jobs = cfg["jobs_config"]
+    family = jobs["architecture"]
+    assert family["config"] == MIMO_FILE
+    assert {k: family[k] for k in MIMO_CUT} == MIMO_CUT
+    shapes = family["shapes"]
+    assert [(s["seq_len"], s["micro_batch"]) for s in shapes] == MIMO_SHAPES
+    assert shapes[-1]["seq_len"] == mimo["max_position_embeddings"]
+    steps = jobs["num_training_steps"]
+    lengths = [steps * (1 + arch.BACKWARD_OVER_FORWARD) * sum(
+        arch.forward_time(c) for c in arch.op_costs(mimo, **s, **MIMO_CUT))
+        for s in shapes]
+    assert lengths == pytest.approx([59.960, 75.306, 191.527, 1748.057],
+                                    abs=1e-3)
+    gap = np.mean(lengths) / 25
+    two_figures = round(gap, 1 - int(math.floor(math.log10(gap))))
+    assert jobs["job_interarrival_time_dist"]["val"] == two_figures == 21
+    assert cfg["max_simulation_run_time"] == pytest.approx(400 * two_figures)
+    assert cfg["pad_obs_kwargs"] == {"max_nodes": 50 * -(-114 // 50),
+                                     "max_edges": 256 * -(-165 // 256)}
+    # the shortest op at degree 1 is over 16 quanta: no ragged row
+    shortest = min(arch.forward_time(c) for s in shapes
+                   for c in arch.op_costs(mimo, **s, **MIMO_CUT))
+    assert shortest == pytest.approx(268.4e-6, rel=1e-3)
+    assert shortest > 16 * cfg["min_op_run_time_quantum"]
+    # the rest is env_glm5_32's
+    changed = {"jobs_config", "max_simulation_run_time", "pad_obs_kwargs"}
+    assert {k: v for k, v in cfg.items() if k not in changed} \
+        == {k: v for k, v in base.items() if k not in changed}
+    for key in set(jobs) - {"architecture", "job_interarrival_time_dist"}:
+        assert jobs[key] == base["jobs_config"][key], key
+
+
+def _tiny_mimo_arch_file(directory) -> str:
+    path = os.path.join(str(directory), "tinymimo.json")
+    with open(path, "w") as fh:
+        json.dump({"source_url": "test-local", "training_state": STATE,
+                   "config": TINY_MIMO}, fh)
+    return path
+
+
+#: steps of ~0.2 s with every op memory-bound (the full core 4.5 % of
+#: the pass), and of ~0.65 s at 16,384 tokens a sequence, where the one
+#: full core is 83 % of the pass: a 0.18 s op beside 0.6 ms ones
+TINY_MIMO_SHAPES = {
+    "short": [{"seq_len": 32, "micro_batch": 2 ** 19},
+              {"seq_len": 32, "micro_batch": 4096}],
+    "long": [{"seq_len": 16384, "micro_batch": 512},
+             {"seq_len": 32, "micro_batch": 2 ** 19}]}
+
+
+def _tiny_mimo_env(arch_file, shapes="short", **over):
+    jobs = dict(
+        architecture={"config": arch_file,
+                      "shapes": TINY_MIMO_SHAPES[shapes],
+                      "layers": {"leading_dense": 1, "following": 2},
+                      "experts_held": 4},
+        job_interarrival_time_dist={
+            "_target_": "ddls_tpu.demands.distributions.Fixed", "val": 0.4},
+        max_acceptable_job_completion_time_frac_dist={
+            "_target_": "ddls_tpu.demands.distributions.Uniform",
+            "min_val": 0.1, "max_val": 1.0, "decimals": 2},
+        replication_factor=10, job_sampling_mode="remove_and_repeat",
+        shuffle_files=True, num_training_steps=20)
+    over.setdefault("max_partitions_per_op", 8)
+    return _tiny_env(arch_file, jobs_config=jobs,
+                     max_simulation_run_time=16.0,
+                     pad_obs_kwargs={"max_nodes": 50, "max_edges": 128},
+                     **over)
+
+
+@pytest.mark.parametrize("shapes,x64,rtol", [
+    ("short", True, 1e-9), ("short", False, 1e-4), ("long", False, 1e-4)],
+    ids=["short_x64_1e-9", "short_f32_1e-4", "long_f32_1e-4"])
+def test_mimo_job_in_kernel_replays_the_host_oracle(tmp_path, shapes, x64,
+                                                    rtol):
+    """A tiny STATED mimo_v2_flash job family (layers F W W; D E E; 4 of
+    8 experts) through reader -> mirror -> Job -> the jitted episode
+    kernel against the float64 Python oracle: accepted and cause
+    exactly, JCT to the tolerance; ``long`` puts 83 % of a step into the
+    one full core (a 0.18 s op beside 0.6 ms ones)."""
+    if shapes == "long":
+        long = TINY_MIMO_SHAPES["long"][0]
+        share = _kind_gauges(
+            _tiny_mimo_arch_file(tmp_path),
+            [(long["seq_len"], long["micro_batch"])],
+            layers={"leading_dense": 1, "following": 2}, experts_held=4)
+        assert share["tinymimo_s16384_b512"]["quadratic_time_share"] > 0.5
+    driver = EPISODE_DRIVER.replace(
+        "t._tiny_env({arch_file!r})",
+        f"t._tiny_mimo_env({{arch_file!r}}, {shapes!r})").format(
+        repo=REPO, tests=os.path.join(REPO, "tests"),
+        benchmarks=os.path.join(REPO, "tests", "benchmarks"),
+        arch_file=_tiny_mimo_arch_file(tmp_path), seed=5, x64=x64,
+        rtol=rtol)
+    out = subprocess.run(
+        [sys.executable, "-c", driver], capture_output=True, text=True,
+        timeout=900, env={**os.environ, "JAX_PLATFORMS": "cpu",
+                          "JAX_ENABLE_X64": "1" if x64 else "0"})
+    assert out.returncode == 0, out.stderr[-3000:]
+    verdict = json.loads(out.stdout.strip().splitlines()[-1])
+    assert verdict["mismatch"] is None, verdict
+    assert verdict["decisions"] == 24
+    assert 0 < verdict["accepted"] < 24, verdict
+    assert len(verdict["causes"]) >= 2, verdict
+
+
+def test_generator_sets_the_layer_kind_gauges(tmp_path):
+    """`graphs.arch.layers_full` / `layers_window` /
+    `quadratic_time_share` per model and the bank's mean share, beside
+    the older `graphs.arch.*` gauges, in the `[startup]` line."""
+    from ddls_tpu.demands.jobs_generator import JobsGenerator
+    from ddls_tpu.telemetry import startup
+
+    startup.registry().reset()
+    JobsGenerator(
+        architecture={"config": _tiny_mimo_arch_file(tmp_path),
+                      "shapes": TINY_MIMO_SHAPES["long"],
+                      "layers": {"leading_dense": 1, "following": 2},
+                      "experts_held": 4},
+        job_interarrival_time_dist={
+            "_target_": "ddls_tpu.demands.distributions.Fixed", "val": 1.0},
+        replication_factor=2, num_training_steps=20)
+    gauges = startup.gauges()
+    models = ("tinymimo_s16384_b512", "tinymimo_s32_b524288")
+    for m in models:
+        assert gauges[f"graphs.arch.layers_full.{m}"] == 1
+        assert gauges[f"graphs.arch.layers_window.{m}"] == 2
+        assert gauges[f"graphs.arch.forward_ops.{m}"] == 25
+    shares = [gauges[f"graphs.arch.quadratic_time_share.{m}"]
+              for m in models]
+    assert shares == pytest.approx([0.8347, 0.0450], abs=1e-4)
+    # the bank's mean as a ratio of two gauges, as the benchmark reads it
+    assert [gauges[name] for name in BANK_GAUGES] == [sum(shares), 2]
+    report = json.loads(startup.report()[len("[startup] "):])
+    assert [report[name] for name in BANK_GAUGES] == [sum(shares), 2]
+    startup.registry().reset()
+
+
+def test_decisions_on_the_longest_job_type_are_counted():
+    """`record_decisions` reduces the drained ``jtype`` / ``accepted``
+    traces into `env.decisions.offered_longest` / `accepted_longest`:
+    the decisions on the type with the largest degree-1 step time."""
+    import types
+
+    from ddls_tpu import telemetry
+    from ddls_tpu.rl.fused import record_decisions
+
+    et = types.SimpleNamespace(n_srv=32)
+    ot = {"orig_seq_sum": np.array([3.0, 87.4, 9.6])}
+    trace = {"jtype": np.array([[1, 0, 1], [2, 1, 0]]),
+             "accepted": np.array([[1, 1, 0], [0, 1, 1]]),
+             "n_occupied": np.array([[0, 4, 8], [8, 8, 12]])}
+    was = telemetry.enabled()
+    telemetry.enable()
+    telemetry.reset()
+    try:
+        record_decisions(trace, et, ot)
+        counters = telemetry.snapshot()["counters"]
+    finally:
+        telemetry.reset()
+        if not was:
+            telemetry.disable()
+    assert counters["env.decisions.offered"] == 6
+    assert counters["env.decisions.accepted"] == 4
+    assert counters["env.decisions.offered_longest"] == 3
+    assert counters["env.decisions.accepted_longest"] == 2
+    assert counters["env.cluster.servers"] == 6 * 32
