@@ -41,7 +41,8 @@ import numpy as np
 from ddls_tpu import telemetry
 from ddls_tpu.demands.jobs_generator import BANK_GAUGES
 from ddls_tpu.sim.jax_env import MASK_GAUGES
-from ddls_tpu.sim.jax_lookahead import MINOR_GAUGES
+from ddls_tpu.sim.jax_lookahead import (MINOR_GAUGES, stage_trips,
+                                        stage_widths)
 from ddls_tpu.telemetry import scopes, startup
 
 
@@ -137,10 +138,17 @@ def record_lookahead_trips(ep_trace, pads) -> None:
     lookahead ran at least one trip (a memo hit and an action that runs
     no lookahead run none); ``trips`` — their trips, summed;
     ``lockstep_trips`` — summed over steps, the maximum over the lanes:
-    what the batched loop executed, since it runs while any lane's cond
-    holds and every lane that loops carries its count out;
-    ``lockstep_lane_trips`` — that times the lanes: the lane-trips the
-    device paid for. From the tables' ``pads`` (a ``ConfigPads``), once
+    the trips the lockstep of loops executed, since it runs while any
+    lane's cond holds and every lane that loops carries its count out;
+    ``stage_trips.<W>`` — those trips by the width they ran at: the
+    lockstep runs in stages of falling width
+    (`sim/jax_lookahead.py:stage_widths`, the function the kernel
+    itself calls, on the trace's lanes and the tables' block side), a
+    stage ending at the trip after which the next width holds the
+    lanes still live, which each step's own counts give
+    (`stage_trips`); ``lockstep_lane_trips`` — each stage's trips times
+    its width, summed: the lane-trips the device paid for. From the
+    tables' ``pads`` (a ``ConfigPads``), once
     per drained epoch trace: ``dep_slots`` — the dep slots a trip
     passes over (blocks x split^2) — and ``dep_slots_used`` — the
     largest row's real deps; their ratio is what the block layout's
@@ -151,12 +159,16 @@ def record_lookahead_trips(ep_trace, pads) -> None:
     carries, in whole 128-wide registers — and ``minor_used`` — the
     real slots of it. The caller gates on ``telemetry.enabled()``."""
     own = np.asarray(ep_trace["la_trips"])
-    lockstep = int(own.max(axis=-2).sum())
+    widths = stage_widths(own.shape[-2], int(pads.max_split))
+    by_width = stage_trips(np.moveaxis(own, -2, -1), widths).reshape(
+        -1, len(widths)).sum(axis=0)
     telemetry.inc("sim.lookahead.calls", int((own > 0).sum()))
     telemetry.inc("sim.lookahead.trips", int(own.sum()))
-    telemetry.inc("sim.lookahead.lockstep_trips", lockstep)
+    telemetry.inc("sim.lookahead.lockstep_trips", int(by_width.sum()))
     telemetry.inc("sim.lookahead.lockstep_lane_trips",
-                  lockstep * own.shape[-2])
+                  int(by_width @ np.asarray(widths)))
+    for width, trips in zip(widths, by_width.tolist()):
+        telemetry.inc(f"sim.lookahead.stage_trips.{width}", trips)
     for trips in own[own > 0].tolist():
         telemetry.observe("sim.lookahead.trips_per_call", trips,
                           buckets=_TRIP_BUCKETS)

@@ -31,6 +31,7 @@ static shapes; invalid slots carry ``valid=False`` masks.
 from __future__ import annotations
 
 import dataclasses
+from fractions import Fraction
 from functools import lru_cache as _lru_cache
 from typing import Dict, NamedTuple, Tuple
 
@@ -332,6 +333,17 @@ class DepBlocks(NamedTuple):
 #: not a multiple of it, leaves lanes of every register empty
 REGISTER_WIDTH = 128
 
+#: what each next width of the lane schedule (:func:`stage_widths`) is
+#: of the last, before rounding up to whole registers, and how many
+#: widths under the first it may hold: every stage is one more loop to
+#: trace and compile. PERF.md section 6 (PR 33) has the chip runs
+STAGE_RATIO = Fraction(1, 2)
+MAX_NARROWER_STAGES = 5
+
+#: the ``vmap`` axis of the one-job-a-lane form, over which a stage
+#: counts its live lanes
+LANE_AXIS = "lookahead_lanes"
+
 #: start-up gauges the batching rule sets when it runs in a trace, and
 #: the counters the trip drain adds them to per epoch
 #: (rl/fused.py:record_lookahead_trips): the minor-axis extent of the
@@ -488,9 +500,21 @@ def _job_layout(op_worker, num_workers: int, dep_ops) -> _Layout:
             (per_worker == best_score[:, None]) & (best_score[:, None] > 0)
             & (worker_onehot > 0), axis=0)
 
+    def loop(live, tick, init, fit):
+        if not fit:
+            return jax.lax.while_loop(live, tick, init)
+        # a stage of the lane schedule (:func:`stage_widths`), one job
+        # a lane under ``vmap``: tick while more lanes are live than the
+        # next width holds; jax's batching freezes the lanes that are not
+        def more(state):
+            on = live(state)
+            return on & (jax.lax.psum(on.astype(jnp.int32), LANE_AXIS) > fit)
+
+        return jax.lax.while_loop(more, tick, init)
+
     return _Layout(*dep_ops, select_ops, jnp.any, jnp.all, jnp.min,
                    jnp.sum, lanes=lambda x: x, spread=lambda x: x,
-                   loop=jax.lax.while_loop)
+                   loop=loop)
 
 
 def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
@@ -585,9 +609,11 @@ def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
         return ops_ready & jnp.any(
             mine & (scores == best) & (best > 0), axis=0)
 
-    def loop(live, tick, init):
+    def loop(live, tick, init, fit):
         # what jax's batching makes of ``while_loop``: run while any
-        # lane is live, and freeze the lanes that are not
+        # lane is live — as a stage of the lane schedule
+        # (:func:`stage_widths`), while more are than the next width
+        # holds (``fit``) — and freeze the lanes that are not
         def frozen_tick(state):
             on = live(state)
             on_slots = spread(on)
@@ -595,8 +621,11 @@ def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
                 lambda new, old: jnp.where(on if new.ndim == 1 else on_slots,
                                            new, old), tick(state), state)
 
-        return jax.lax.while_loop(lambda state: jnp.any(live(state)),
-                                  frozen_tick, init)
+        def more(state):
+            on = live(state)
+            return jnp.sum(on, dtype=jnp.int32) > fit if fit else jnp.any(on)
+
+        return jax.lax.while_loop(more, frozen_tick, init)
 
     return _Layout(src_done, count_parents, nominate, select_ops,
                    over_lane(jnp.any), over_lane(jnp.all),
@@ -608,9 +637,13 @@ def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
 
 def _tick_loop(lay: _Layout, op_remaining, op_valid, op_score, num_parents,
                dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
-               skip, max_iters: int):
+               skip, max_iters: int, state=None, fit: int = 0):
     """THE tick loop, in whatever shape ``lay`` carries its state;
-    returns (t, comm_oh, comp_oh, busy, ok, trips) per lane."""
+    returns (t, comm_oh, comp_oh, busy, ok, trips) per lane, and the
+    state the loop left. As a stage of the lane schedule
+    (:func:`stage_widths`) it starts from the ``state`` an earlier
+    stage left (None: a job's start) and stops once the next width
+    holds the live lanes (``fit``; 0: when none is live)."""
     import jax
     import jax.numpy as jnp
 
@@ -683,20 +716,61 @@ def _tick_loop(lay: _Layout, op_remaining, op_valid, op_score, num_parents,
         return (rem_op2, rem_dep2, op_done2, dep_done2, parent_done2,
                 t2, comm_oh2, comp_oh2, busy2, it + 1, stuck | new_stuck)
 
-    init = (op_remaining, dep_remaining,
-            jnp.zeros(op_remaining.shape, bool),
-            jnp.zeros(dep_remaining.shape, bool),
-            jnp.zeros(op_remaining.shape, jnp.int32),
-            lay.lanes(jnp.zeros((), dt)), lay.lanes(jnp.zeros((), dt)),
-            lay.lanes(jnp.zeros((), dt)), lay.lanes(jnp.zeros((), dt)),
-            lay.lanes(jnp.int32(0)), lay.lanes(jnp.bool_(False)))
+    if state is None:
+        state = (op_remaining, dep_remaining,
+                 jnp.zeros(op_remaining.shape, bool),
+                 jnp.zeros(dep_remaining.shape, bool),
+                 jnp.zeros(op_remaining.shape, jnp.int32),
+                 lay.lanes(jnp.zeros((), dt)), lay.lanes(jnp.zeros((), dt)),
+                 lay.lanes(jnp.zeros((), dt)), lay.lanes(jnp.zeros((), dt)),
+                 lay.lanes(jnp.int32(0)), lay.lanes(jnp.bool_(False)))
     with jax.named_scope(scopes.SIM_LOOKAHEAD):
-        out = lay.loop(cond, body, init)
+        out = lay.loop(cond, body, state, fit)
     (_, _, op_done, dep_done, _, t, comm_oh, comp_oh, busy, it,
      stuck) = out
     finished = (lay.all(op_done | ~op_valid)
                 & lay.all(dep_done | ~dep_valid))
-    return t, comm_oh, comp_oh, busy, finished & ~stuck, it
+    return (t, comm_oh, comp_oh, busy, finished & ~stuck, it), out
+
+
+def stage_widths(n_lanes: int, side: int) -> list:
+    """The lane counts the block-path loop runs at, one stage each: a
+    short, strictly descending list that starts at ``n_lanes``. Each
+    next width is the last one times :data:`STAGE_RATIO`, rounded UP to
+    whole vector registers of the form that width runs in — multiples
+    of ``REGISTER_WIDTH // side`` lanes while lane-packed, of
+    :data:`REGISTER_WIDTH` from 128 lanes on — or, where that is no
+    narrower, the next whole width below; it ends at one register's
+    worth of packed lanes, or after :data:`MAX_NARROWER_STAGES` widths
+    under ``n_lanes``. Lanes that already fit one register (the
+    unbatched call among them) give ``[n_lanes]``: one loop."""
+    packed = max(REGISTER_WIDTH // side, 1)
+
+    def whole(width, up):
+        unit = REGISTER_WIDTH if width >= REGISTER_WIDTH else packed
+        return (-(-width // unit) if up else width // unit) * unit
+
+    widths = [n_lanes]
+    while widths[-1] > packed and len(widths) <= MAX_NARROWER_STAGES:
+        last = widths[-1]
+        width = whole(-(-last * STAGE_RATIO.numerator
+                        // STAGE_RATIO.denominator), up=True)
+        widths.append(width if width < last else whole(last - 1, up=False))
+    return widths
+
+
+def stage_trips(own, widths):
+    """The trips each stage of ``widths`` (:func:`stage_widths`) runs,
+    [..., stages], from every lane's OWN trip count (``own``,
+    [..., lanes]: one call's lanes last). All lanes tick in lockstep
+    from trip 0 and a lane is live for its own first trips, so a stage
+    ends at the trip after which the next width holds the lanes still
+    live — the count of the lane that many places from the longest —
+    and the last stage at the longest lane's."""
+    longest_first = -np.sort(-np.asarray(own), axis=-1)
+    ends = np.stack([longest_first[..., width]
+                     for width in widths[1:] + [0]], axis=-1)
+    return np.diff(ends, axis=-1, prepend=0)
 
 
 def _lane_batched_lookahead(num_workers: int):
@@ -704,16 +778,31 @@ def _lane_batched_lookahead(num_workers: int):
     carries a leading lane axis [L, ...] (``skip``: [L] or None) and so
     do the six results. Batching it (``vmap``) folds the new axis into
     the lanes and calls again at A*L, so any nest of vmaps around the
-    per-job call runs ONE loop whose lanes are their product.
+    per-job call runs ONE lockstep of loops whose lanes are their
+    product.
 
-    The shape of that loop's state is chosen on L alone, by what fills
-    a vector register (:data:`REGISTER_WIDTH`). While the lanes alone
-    do not (L < 128) the state is LANE-PACKED (:func:`_packed_layout`):
-    (lane, shard) merged on the minor axis. From 128 lanes on the loop
-    is one job's (:func:`_block_dep_ops`) under ``jax.vmap``, whose
-    lanes XLA lays minor by itself: there the packed form is the slower
-    one (its worker x worker tables carry the shards too; at 320 lanes
-    a trip is 1.33 ms packed against 1.21, my chip run, PR 27)."""
+    The shape of a loop's state is chosen on its lanes alone, by what
+    fills a vector register (:data:`REGISTER_WIDTH`). While the lanes
+    alone do not (under 128) the state is LANE-PACKED
+    (:func:`_packed_layout`): (lane, shard) merged on the minor axis.
+    From 128 lanes on the loop is one job's (:func:`_block_dep_ops`)
+    under ``jax.vmap``, whose lanes XLA lays minor by itself: there the
+    packed form is the slower one (its worker x worker tables carry the
+    shards too; at 320 lanes a trip is 1.33 ms packed against 1.21, my
+    chip run, PR 27).
+
+    A trip costs what the lanes it carries cost, and most lanes finish
+    long before the longest (a memo hit ``skip``s from trip 0), so the
+    lockstep runs in STAGES of falling width (:func:`stage_widths`, on
+    L and the block side alone): a stage ticks until the next width
+    holds the lanes still live, which are then gathered — whole lanes,
+    once a stage, outside the loops — into the next stage's state. A
+    lane's ticks do not depend on which lanes share its loop, so every
+    lane's six results are the one-loop program's bits.
+    ``run.staged`` is the same function with each stage's trip count
+    beside the results, for tests."""
+    from functools import partial
+
     import jax
     import jax.numpy as jnp
 
@@ -721,27 +810,28 @@ def _lane_batched_lookahead(num_workers: int):
 
     def one_job(op_remaining, op_valid, op_worker, op_score, num_parents,
                 dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
-                blocks, skip):
+                blocks, skip, state=None, fit=0):
         N, E = op_remaining.shape[0], dep_remaining.shape[0]
         return _tick_loop(
             _job_layout(op_worker, num_workers, _block_dep_ops(
                 op_worker, blocks, E, num_workers)),
             op_remaining, op_valid, op_score, num_parents, dep_remaining,
-            dep_valid, dep_mutual, dep_is_flow, dep_score, skip, N + E + 4)
+            dep_valid, dep_mutual, dep_is_flow, dep_score, skip, N + E + 4,
+            state, fit)
 
-    @jax.custom_batching.custom_vmap
-    def run(*args):
+    def one_loop(args, state=None, fit=0):
+        """The loop at ``args``' own lane count, in that count's form;
+        ``state`` comes and goes a lane a row (it goes only from a
+        stage that has a successor: ``fit``)."""
         (op_remaining, op_valid, op_worker, op_score, num_parents,
          dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
          blocks, skip) = args
         L, N = op_remaining.shape
         if L >= REGISTER_WIDTH:
-            return jax.vmap(one_job)(*args)
+            return jax.vmap(partial(one_job, fit=fit), axis_name=LANE_AXIS)(
+                *args, state)
         E, B = dep_remaining.shape[1], blocks.src.shape[1]
         S = _block_side(E, B)
-        if B * S * S != E or N % S:
-            raise ValueError(f"({N}, {E}) is not a block layout of {B} "
-                             "blocks")
 
         def ops(x):      # [L, (o, k)] -> [No, (l, k)]
             return x.reshape(L, N // S, S).transpose(1, 0, 2).reshape(
@@ -751,14 +841,72 @@ def _lane_batched_lookahead(num_workers: int):
             return x.reshape(L, B, S, S).transpose(1, 2, 0, 3).reshape(
                 B, S, L * S)
 
+        def lane_ops(x):     # ... and back
+            return x.reshape(N // S, L, S).transpose(1, 0, 2).reshape(L, N)
+
+        def lane_deps(x):
+            return x.reshape(B, S, L, S).transpose(2, 0, 1, 3).reshape(L, E)
+
+        def pack(state, ops, deps):
+            rem_op, rem_dep, op_done, dep_done, parent_done = state[:5]
+            return (ops(rem_op), deps(rem_dep), ops(op_done), deps(dep_done),
+                    ops(parent_done)) + tuple(state[5:])
+
         op_worker = ops(op_worker)
-        return _tick_loop(
+        out, left = _tick_loop(
             _packed_layout(op_worker, DepBlocks(blocks.src.T, blocks.dst.T),
                            L, num_workers),
             ops(op_remaining), ops(op_valid), ops(op_score),
             ops(num_parents), deps(dep_remaining), deps(dep_valid),
             deps(dep_mutual), deps(dep_is_flow), deps(dep_score),
-            skip, N + E + 4)
+            skip, N + E + 4,
+            None if state is None else pack(state, ops, deps), fit)
+        return out, pack(left, lane_ops, lane_deps) if fit else None
+
+    def rows(x, lanes):
+        """Whole lanes of ``x``: ``lanes`` are distinct and in range."""
+        return x.at[lanes].get(unique_indices=True,
+                               mode="promise_in_bounds")
+
+    def staged(*args):
+        op_remaining, dep_remaining, blocks, skip = (args[0], args[5],
+                                                     args[10], args[11])
+        (L, N), E, B = op_remaining.shape, dep_remaining.shape[1], \
+            blocks.src.shape[1]
+        S = _block_side(E, B)
+        if B * S * S != E or N % S:
+            raise ValueError(f"({N}, {E}) is not a block layout of {B} "
+                             "blocks")
+        widths = stage_widths(L, S)
+        if len(widths) == 1:
+            return one_loop(args)[0], ()
+        part, state = one_loop(args, fit=widths[1])
+        results, lanes, ran = part, jnp.arange(L), [jnp.max(part[5])]
+        for width, fit in zip(widths[1:], widths[2:] + [0]):
+            # the lanes still live first, in their order, then as many
+            # of the others (frozen: they carry their results along) as
+            # fill the width
+            ok, trips, stuck = part[4], part[5], state[-1]
+            live = ~ok & ~stuck & (trips < N + E + 4)
+            if skip is not None:
+                live = live & ~rows(skip, lanes)
+            keep = jnp.argsort(~live, stable=True)[:width]
+            lanes = rows(lanes, keep)
+            part, state = one_loop(
+                jax.tree_util.tree_map(lambda x: rows(x, lanes), args),
+                jax.tree_util.tree_map(lambda x: rows(x, keep), state), fit)
+            ran.append(jnp.max(part[5] - rows(trips, keep)))
+            results = tuple(
+                x.at[lanes].set(y, unique_indices=True,
+                                mode="promise_in_bounds")
+                for x, y in zip(results, part))
+        return results, tuple(ran)
+
+    @jax.custom_batching.custom_vmap
+    def run(*args):
+        return staged(*args)[0]
+
+    run.staged = staged
 
     @run.def_vmap
     def fold_into_lanes(axis_size, in_batched, *args):
@@ -770,8 +918,9 @@ def _lane_batched_lookahead(num_workers: int):
         args = jax.tree_util.tree_map(fold, args, tuple(in_batched))
         lanes, n_deps, n_blocks = (args[0].shape[0], args[5].shape[1],
                                    args[10].src.shape[1])
-        # what the loop traced last carries on the minor axis of its dep
-        # state, for the trip drain's counters
+        # what the lockstep traced last carries on the minor axis of its
+        # dep state in its first (widest) stage, for the trip drain's
+        # counters
         # (rl/fused.py:record_lookahead_trips): (lane, shard) slots, or
         # the lanes alone
         minor = lanes if lanes >= REGISTER_WIDTH else \
@@ -836,7 +985,8 @@ def jax_lookahead(op_remaining, op_valid, op_worker, op_score, num_parents,
             _job_layout(op_worker, num_workers, _flat_dep_ops(
                 dep_src, dep_dst, dep_channel, num_channels)),
             op_remaining, op_valid, op_score, num_parents, dep_remaining,
-            dep_valid, dep_mutual, dep_is_flow, dep_score, skip, N + E + 4)
+            dep_valid, dep_mutual, dep_is_flow, dep_score, skip,
+            N + E + 4)[0]
     one_lane = jax.tree_util.tree_map(
         lambda x: x[None],
         (op_remaining, op_valid, op_worker, op_score, num_parents,

@@ -323,31 +323,39 @@ def _lane_arguments(block_build, lanes):
     return jax.vmap(block_build.arguments)(cfgs, states)
 
 
-def _flat_per_lane(block_build, args, blocks, skip, n):
-    """The unbatched flat path, lane by lane, stacked."""
+def _flat_per_lane(block_build, args, blocks, skip, lanes):
+    """The unbatched flat path of every lane, stacked; ``lanes`` names
+    each lane's (cfg row, cluster state), and lanes of one name and one
+    ``skip`` share their arguments, so the first of them runs for all."""
     import jax
 
-    rows = [block_build.flat(*jax.tree_util.tree_map(
-        lambda x: x[lane], (args, blocks, skip))) for lane in range(n)]
-    return [np.stack([np.asarray(r[k]) for r in rows]) for k in range(6)]
+    kinds, flat = list(zip(lanes, np.asarray(skip).tolist())), {}
+    for lane, kind in enumerate(kinds):
+        if kind not in flat:
+            flat[kind] = block_build.flat(*jax.tree_util.tree_map(
+                lambda x: x[lane], (args, blocks, skip)))
+    return [np.stack([np.asarray(flat[kind][k]) for kind in kinds])
+            for k in range(6)]
 
 
 @pytest.mark.parametrize("skip_every", [0, 3])
-@pytest.mark.parametrize("n_lanes", [1, 2, 3, 8, 32, 40, 128])
+@pytest.mark.parametrize("n_lanes", [1, 2, 3, 8, 24, 32, 40, 48, 80, 128,
+                                     160, 320])
 def test_block_path_is_flat_path_vmapped(block_build, n_lanes, skip_every):
     """Lanes of DIFFERENT rows under one vmap (the fused epoch's shape:
     the lane-packed loop at L lanes; from 128 on, one job a lane with
-    the lanes minor), with and without a ``skip`` mask: each lane's six
+    the lanes minor; past one register's worth of lanes, in stages of
+    falling width), with and without a ``skip`` mask: each lane's six
     outputs equal the UNBATCHED flat path's, skipped lanes (0 trips,
     init accumulators) included."""
     import jax
     import jax.numpy as jnp
 
-    args, blocks, placed = _lane_arguments(block_build,
-                                           _lanes(block_build, n_lanes))
+    lanes = _lanes(block_build, n_lanes)
+    args, blocks, placed = _lane_arguments(block_build, lanes)
     skip = (jnp.arange(n_lanes) % skip_every == 1 if skip_every
             else jnp.zeros(n_lanes, bool))
-    want = _flat_per_lane(block_build, args, blocks, skip, n_lanes)
+    want = _flat_per_lane(block_build, args, blocks, skip, lanes)
     got = jax.jit(jax.vmap(block_build.block_fn))(args, blocks, skip)
     _assert_same_bits(got, want, ("vmap", n_lanes, skip_every))
     trips = want[5]
@@ -357,6 +365,162 @@ def test_block_path_is_flat_path_vmapped(block_build, n_lanes, skip_every):
         assert not bool(placed[1])         # the unplaceable row
     if n_lanes >= 8:
         assert len(set(trips.tolist())) > 4    # lanes really differ
+
+
+#: (lanes, block side) -> the widths the lockstep runs at
+_STAGE_WIDTHS = [
+    (1, 16, [1]), (8, 16, [8]), (9, 16, [9, 8]), (24, 16, [24, 16, 8]),
+    (32, 16, [32, 16, 8]), (48, 16, [48, 24, 16, 8]),
+    (80, 16, [80, 40, 24, 16, 8]), (128, 16, [128, 64, 32, 16, 8]),
+    (160, 16, [160, 80, 40, 24, 16, 8]),
+    (320, 16, [320, 256, 128, 64, 32, 16]),
+    (2880, 16, [2880, 1536, 768, 384, 256, 128]),
+    (16, 4, [16]), (40, 4, [40, 32]), (100, 4, [100, 64, 32])]
+
+
+@pytest.mark.parametrize("n_lanes,side,widths", _STAGE_WIDTHS,
+                         ids=[f"{n}x{s}" for n, s, _ in _STAGE_WIDTHS])
+def test_stage_widths(n_lanes, side, widths):
+    """The schedule is a function of the lanes and the block side
+    alone: strictly descending from the lanes, whole registers of the
+    form each width runs in, at most five widths under the first, and
+    one loop for lanes that fit one register (the unbatched call)."""
+    from ddls_tpu.sim.jax_lookahead import (MAX_NARROWER_STAGES,
+                                            REGISTER_WIDTH, stage_widths)
+
+    assert stage_widths(n_lanes, side) == widths
+    assert widths[0] == n_lanes and len(widths) <= 1 + MAX_NARROWER_STAGES
+    assert all(a > b for a, b in zip(widths, widths[1:]))
+    for width in widths[1:]:
+        unit = REGISTER_WIDTH if width >= REGISTER_WIDTH \
+            else REGISTER_WIDTH // side
+        assert width % unit == 0, (width, unit)
+
+
+def _block_arguments(args, blocks, skip):
+    """``_lane_arguments``' outputs as the lane-batched lookahead takes
+    them: without the flat path's per-dep endpoints and channel."""
+    return (*args[:7], *args[9:12], blocks, skip)
+
+
+def _staged(block_build):
+    """The lane-batched lookahead with each stage's own trip count
+    beside the six results, jitted, on ``_lane_arguments``' outputs."""
+    import jax
+
+    from ddls_tpu.sim.jax_lookahead import _lane_batched_lookahead
+
+    staged = _lane_batched_lookahead(block_build.et.n_srv).staged
+    return jax.jit(lambda *lane_arguments: staged(
+        *_block_arguments(*lane_arguments)))
+
+
+_STAGED_MIXES = ("rows", "all_skip", "one_live", "longest_first",
+                 "longest_last")
+
+
+@pytest.mark.parametrize("mix", _STAGED_MIXES)
+@pytest.mark.parametrize("n_lanes", [24, 80, 320])
+def test_stages_run_the_trips_the_host_reckons(block_build, n_lanes, mix):
+    """Lane mixes that cross every stage boundary (different rows, an
+    unplaceable one among them; every lane skipped; all but one; the
+    longest lane first, and last): every lane's six results are the
+    unbatched flat path's bits, and each stage's loop ran exactly the
+    trips `stage_trips` reckons from the lanes' own counts — what
+    `record_lookahead_trips` charges the device for."""
+    import jax.numpy as jnp
+
+    from ddls_tpu.sim.jax_lookahead import stage_trips, stage_widths
+
+    lanes = _lanes(block_build, n_lanes)
+    skip = {"rows": jnp.arange(n_lanes) % 5 == 2,
+            "all_skip": jnp.ones(n_lanes, bool),
+            "one_live": jnp.arange(n_lanes) != n_lanes // 2}.get(
+                mix, jnp.zeros(n_lanes, bool))
+    if mix.startswith("longest"):
+        args, blocks, _ = _lane_arguments(block_build, lanes)
+        own = _flat_per_lane(block_build, args, blocks, skip, lanes)[5]
+        longest = lanes[int(own.argmax())]
+        others = [lane for lane, trips in zip(lanes, own)
+                  if trips < own.max()]
+        others = [others[i % len(others)] for i in range(n_lanes - 1)]
+        lanes = [longest] + others if mix == "longest_first" \
+            else others + [longest]
+    args, blocks, placed = _lane_arguments(block_build, lanes)
+    want = _flat_per_lane(block_build, args, blocks, skip, lanes)
+    got, ran = _staged(block_build)(args, blocks, skip)
+    _assert_same_bits(got, want, (n_lanes, mix))
+    own = want[5]
+    assert not np.asarray(placed).all()        # an unplaceable row
+    assert (own > 0).sum() == {"all_skip": 0, "one_live": 1}.get(
+        mix, int((~np.asarray(skip)).sum()))
+    if mix.startswith("longest"):
+        assert (own == own.max()).sum() == 1
+        assert int(own.argmax()) == (0 if mix == "longest_first"
+                                     else n_lanes - 1)
+    widths = stage_widths(n_lanes, block_build.et.pads.max_split)
+    assert [int(r) for r in ran] == stage_trips(own, widths).tolist()
+    assert sum(int(r) for r in ran) == own.max()
+    if mix == "rows":
+        assert sum(int(r) > 0 for r in ran) >= 3, ran  # stages that tick
+    if mix in ("all_skip", "one_live"):
+        assert [int(r) for r in ran[:-1]] == [0] * (len(widths) - 1)
+
+
+def _one_loop_program(num_workers):
+    """The lane-packed loop as it ran before it ran in stages: every
+    lane in ONE loop to the longest lane's last trip."""
+    from ddls_tpu.sim import jax_lookahead as jl
+
+    def run(op_remaining, op_valid, op_worker, op_score, num_parents,
+            dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
+            blocks, skip):
+        (L, N), E, B = op_remaining.shape, dep_remaining.shape[1], \
+            blocks.src.shape[1]
+        S = jl._block_side(E, B)
+
+        def ops(x):
+            return x.reshape(L, N // S, S).transpose(1, 0, 2).reshape(
+                N // S, L * S)
+
+        def deps(x):
+            return x.reshape(L, B, S, S).transpose(1, 2, 0, 3).reshape(
+                B, S, L * S)
+
+        return jl._tick_loop(
+            jl._packed_layout(ops(op_worker),
+                              jl.DepBlocks(blocks.src.T, blocks.dst.T), L,
+                              num_workers),
+            ops(op_remaining), ops(op_valid), ops(op_score),
+            ops(num_parents), deps(dep_remaining), deps(dep_valid),
+            deps(dep_mutual), deps(dep_is_flow), deps(dep_score), skip,
+            N + E + 4)[0]
+
+    return run
+
+
+@pytest.mark.parametrize("with_skip", [False, True])
+@pytest.mark.parametrize("n_lanes", [1, 8])
+def test_one_register_of_lanes_traces_the_one_loop_program(
+        block_build, n_lanes, with_skip):
+    """Lanes that fit one vector register run no stages: the traced
+    program is the one loop's, text for text (the unbatched call — the
+    episode kernel, the fidelity replay — is that loop at one lane)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddls_tpu.sim.jax_lookahead import (_lane_batched_lookahead,
+                                            stage_widths)
+
+    n_srv, S = block_build.et.n_srv, block_build.et.pads.max_split
+    assert stage_widths(n_lanes, S) == [n_lanes]
+    args, blocks, _ = _lane_arguments(block_build,
+                                      _lanes(block_build, n_lanes))
+    args = _block_arguments(
+        args, blocks, jnp.arange(n_lanes) % 3 == 1 if with_skip else None)
+    staged = _lane_batched_lookahead(n_srv).staged
+    assert str(jax.make_jaxpr(lambda *a: staged(*a)[0])(*args)) == \
+        str(jax.make_jaxpr(_one_loop_program(n_srv))(*args))
 
 
 def test_block_path_vmapped_with_unbatched_tables(block_build):

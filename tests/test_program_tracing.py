@@ -160,6 +160,7 @@ def test_trip_counters_are_the_hosts_reduction_of_the_trace():
         "sim.lookahead.calls": 3, "sim.lookahead.trips": 21,
         "sim.lookahead.lockstep_trips": 16,
         "sim.lookahead.lockstep_lane_trips": 48,
+        "sim.lookahead.stage_trips.3": 16,
         "sim.lookahead.dep_slots": 13312,
         "sim.lookahead.dep_slots_used": 13072}
     assert {k: v for k, v in snap["counters"].items()
@@ -175,6 +176,53 @@ def test_trip_counters_are_the_hosts_reduction_of_the_trace():
         "sim.lookahead.minor_slots": 128, "sim.lookahead.minor_used": 48}
     hist = snap["histograms"]["sim.lookahead.trips_per_call"]
     assert hist["count"] == 3 and hist["max"] == 9.0
+
+
+def _lockstep_by_hand(own, widths):
+    """Walk one call's lockstep trip by trip: the width steps down when
+    the next one holds the lanes still live. Trips run at each width."""
+    ran, stage, trip = [0] * len(widths), 0, 0
+    while (own > trip).any():
+        while stage + 1 < len(widths) and \
+                (own > trip).sum() <= widths[stage + 1]:
+            stage += 1
+        ran[stage] += 1
+        trip += 1
+    return ran
+
+
+@pytest.mark.parametrize("lanes,hit_share", [
+    (3, 0.0), (24, 0.3), (48, 0.5), (80, 0.5), (80, 1.0), (320, 0.1),
+    (320, 0.85)])
+def test_paid_lane_trips_are_the_stages_widths_times_their_trips(
+        lanes, hit_share):
+    """`sim.lookahead.lockstep_lane_trips` is what the staged lockstep
+    paid: over every step of a [U, B, T] trace, each width of the
+    kernel's own `stage_widths` times the trips a walk of that step's
+    lockstep runs at it; `stage_trips.<W>` are those trips by width,
+    `lockstep_trips` their sum (the longest lane's count, as before)."""
+    from ddls_tpu.rl.fused import record_lookahead_trips
+    from ddls_tpu.sim.jax_env import ConfigPads
+    from ddls_tpu.sim.jax_lookahead import stage_widths
+
+    rng = np.random.default_rng(lanes)
+    own = rng.integers(1, 153, size=(2, lanes, 3)).astype(np.int32)
+    own[rng.random(own.shape) < hit_share] = 0
+    widths = stage_widths(lanes, 16)
+    by_hand = np.sum([_lockstep_by_hand(own[u, :, t], widths)
+                      for u in range(2) for t in range(3)], axis=0)
+    telemetry.enable()
+    record_lookahead_trips({"la_trips": own}, ConfigPads(**_BENCH_PADS))
+    counters = telemetry.snapshot()["counters"]
+    assert [counters[f"sim.lookahead.stage_trips.{w}"] for w in widths] \
+        == by_hand.tolist()
+    assert counters["sim.lookahead.lockstep_trips"] == by_hand.sum() \
+        == own.max(axis=1).sum()
+    assert counters["sim.lookahead.lockstep_lane_trips"] \
+        == int(by_hand @ np.asarray(widths))
+    assert counters["sim.lookahead.trips"] == own.sum() \
+        <= counters["sim.lookahead.lockstep_lane_trips"] \
+        <= lanes * counters["sim.lookahead.lockstep_trips"]
 
 
 def test_block_fill_metric_reads_the_dep_slot_counters():
