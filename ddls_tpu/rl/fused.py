@@ -43,6 +43,7 @@ from ddls_tpu.demands.jobs_generator import BANK_GAUGES
 from ddls_tpu.sim.jax_env import MASK_GAUGES
 from ddls_tpu.sim.jax_lookahead import (MINOR_GAUGES, stage_trips,
                                         stage_widths)
+from ddls_tpu.sim.jax_memo import MemoCounters
 from ddls_tpu.telemetry import scopes, startup
 
 
@@ -108,16 +109,11 @@ def stacked_job_banks(et, env, n_lanes: int, n_jobs: int,
 #: finds the (model, degree) row each decision ran, and its verdict and
 #: the occupied-server count it saw (``record_decisions``). ``la_trips``,
 #: ``jtype``, ``action``, ``accepted`` and ``n_occupied`` are read only
-#: while telemetry is on; if the drain ever shows in
-#: ``device_idle_share``, gate the five together
+#: while telemetry is on and are NOT gated on it (ROADMAP D13): the
+#: traced run must be the program the untraced run measures
 EPISODE_TRACE_KEYS = ("done", "ep_return", "ep_blocked", "ep_completed",
                       "ep_arrived", "la_trips", "jtype", "action",
                       "accepted", "n_occupied")
-
-#: ``sim.lookahead.trips_per_call`` buckets: the loop is bounded by
-#: ops + deps + 4 trips (13,556 at the degree-16 pads)
-_TRIP_BUCKETS = tuple(2.0 ** i for i in range(15))
-
 
 def _count_startup_gauges(names) -> None:
     """Add each set start-up gauge onto the telemetry counter of its
@@ -134,9 +130,9 @@ def _count_startup_gauges(names) -> None:
 def record_lookahead_trips(ep_trace, pads) -> None:
     """Reduce a FETCHED ``[..., B, T]`` lookahead trip trace
     (``la_trips``: each lane-step's own loop count) into the
-    ``sim.lookahead.*`` telemetry counters: ``calls`` — lane-steps whose
-    lookahead ran at least one trip (a memo hit and an action that runs
-    no lookahead run none); ``trips`` — their trips, summed;
+    ``sim.lookahead.*`` telemetry counters: ``trips`` — the trips of the
+    lane-steps whose lookahead ran (a memo hit and an action that runs
+    no lookahead run none), summed;
     ``lockstep_trips`` — summed over steps, the maximum over the lanes:
     the trips the lockstep of loops executed, since it runs while any
     lane's cond holds and every lane that loops carries its count out;
@@ -162,16 +158,12 @@ def record_lookahead_trips(ep_trace, pads) -> None:
     widths = stage_widths(own.shape[-2], int(pads.max_split))
     by_width = stage_trips(np.moveaxis(own, -2, -1), widths).reshape(
         -1, len(widths)).sum(axis=0)
-    telemetry.inc("sim.lookahead.calls", int((own > 0).sum()))
     telemetry.inc("sim.lookahead.trips", int(own.sum()))
     telemetry.inc("sim.lookahead.lockstep_trips", int(by_width.sum()))
     telemetry.inc("sim.lookahead.lockstep_lane_trips",
                   int(by_width @ np.asarray(widths)))
     for width, trips in zip(widths, by_width.tolist()):
         telemetry.inc(f"sim.lookahead.stage_trips.{width}", trips)
-    for trips in own[own > 0].tolist():
-        telemetry.observe("sim.lookahead.trips_per_call", trips,
-                          buckets=_TRIP_BUCKETS)
     telemetry.inc("sim.lookahead.dep_slots", int(pads.n_deps))
     telemetry.inc("sim.lookahead.dep_slots_used", int(pads.n_deps_used))
     _count_startup_gauges(MINOR_GAUGES)
@@ -182,14 +174,13 @@ def record_padding_fill(ep_trace, et, ot) -> None:
     ``[..., B, T]`` trace and the tables it ran on. Over the lane-steps
     whose lookahead looped (``la_trips`` > 0):
     ``sim.lookahead.dep_slots_decided`` — the real deps of each
-    decision's own (model, degree) row, summed — beside
+    decision's own (model, degree) row (``et.row_deps``, the tables'
+    host copy: nothing is fetched here), summed — beside
     ``sim.lookahead.dep_slots_offered`` — those decisions x the dep
     slots every trip passes over (``pads.n_deps``). Over every step:
     ``env.obs.nodes_real`` — the queued job's graph nodes — beside
     ``env.obs.nodes_padded`` — steps x the observation's node pad, the
     GNN's padded work. The caller gates on ``telemetry.enabled()``."""
-    import jax
-
     jtype = np.asarray(ep_trace["jtype"])
     ran = np.asarray(ep_trace["la_trips"]) > 0
     column = np.zeros(et.max_action + 1, np.int64)
@@ -197,7 +188,7 @@ def record_padding_fill(ep_trace, et, ot) -> None:
     row = jtype[ran] * len(et.degrees) \
         + column[np.asarray(ep_trace["action"])[ran]]
     telemetry.inc("sim.lookahead.dep_slots_decided",
-                  int(jax.device_get(et.tables["n_deps"])[row].sum()))
+                  int(et.row_deps[row].sum()))
     telemetry.inc("sim.lookahead.dep_slots_offered",
                   int(ran.sum()) * int(et.pads.n_deps))
     telemetry.inc("env.obs.nodes_real",
@@ -235,7 +226,7 @@ def record_decisions(ep_trace, et, ot) -> None:
     _count_startup_gauges((*MASK_GAUGES, *BANK_GAUGES))
 
 
-class FusedEpochDriver:
+class FusedEpochDriver(MemoCounters):
     """One jitted collect→update epoch over the in-kernel environment.
 
     Counterpart of `DevicePPOCollector` + the standalone jitted
@@ -461,17 +452,6 @@ class FusedEpochDriver:
             jax.block_until_ready((state, ep))
         print(startup.report(), flush=True)
         return state, (crng, urng), metrics, ep
-
-    def memo_counters(self) -> Optional[Dict]:
-        """Cumulative in-kernel memo counters {hits, misses, evicts,
-        hit_rate} summed over lanes (drain/reporting boundaries only —
-        sim/jax_memo.py:summarize_counters); None when the memo is
-        off."""
-        from ddls_tpu.sim.jax_memo import summarize_counters
-
-        if self.memo_cfg is None:
-            return None
-        return summarize_counters(self._state[1])
 
     # --------------------------------------------------------- harvest
     def harvest_episodes(self, ep_trace) -> list:

@@ -14,12 +14,14 @@ dispatch.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict
 
 import numpy as np
 
+from ddls_tpu.sim.jax_memo import MemoCounters
 
-class DevicePPOCollector:
+
+class DevicePPOCollector(MemoCounters):
     """Drop-in counterpart of `rl/rollout.py:RolloutCollector` whose envs
     live on device. ``banks`` is a dict of stacked job-bank arrays with a
     leading B axis (same shapes per bank).
@@ -177,17 +179,6 @@ class DevicePPOCollector:
                 "last_values": np.asarray(last_values, np.float32),
                 "env_steps": self.rollout_length * self.num_envs,
                 "episodes": self._harvest_episodes(trace)}
-
-    def memo_counters(self) -> Optional[Dict]:
-        """Cumulative in-kernel memo counters {hits, misses, evicts,
-        hit_rate}, summed over lanes (drain/reporting boundaries only —
-        sim/jax_memo.py:summarize_counters); None when the memo is
-        off."""
-        from ddls_tpu.sim.jax_memo import summarize_counters
-
-        if self.memo_cfg is None:
-            return None
-        return summarize_counters(self._state[1])
 
     def _harvest_episodes(self, trace) -> list:
         """Episode records at done boundaries, from the traced in-kernel

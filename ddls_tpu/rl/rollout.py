@@ -560,12 +560,11 @@ class ParallelVectorEnv:
     def _install_slabs(self, slabs) -> None:
         """Broadcast the slab spec and wait for every worker's attach ack
         (after which step replies stop carrying obs payloads)."""
-        with telemetry.span("rollout.shm.setup"):
-            spec = slabs.spec()
-            for i in range(self.num_envs):
-                self._send(i, ("shm_open", spec))
-            for conn in self._conns:
-                self._recv(conn)
+        spec = slabs.spec()
+        for i in range(self.num_envs):
+            self._send(i, ("shm_open", spec))
+        for conn in self._conns:
+            self._recv(conn)
         self._slabs = slabs
         self._cur_row = 0
         self._obs_nbytes = slabs.obs_nbytes
@@ -688,12 +687,11 @@ class ParallelVectorEnv:
                           f"trajectory ring ({e}); keeping the single "
                           "slab")
             return None
-        with telemetry.span("rollout.ring.setup"):
-            specs = ring.specs()
-            for i in range(self.num_envs):
-                self._send(i, ("ring_open", specs))
-            for conn in self._conns:
-                self._recv(conn)
+        specs = ring.specs()
+        for i in range(self.num_envs):
+            self._send(i, ("ring_open", specs))
+        for conn in self._conns:
+            self._recv(conn)
         self._ring = ring
         return ring
 
@@ -813,7 +811,6 @@ class ParallelVectorEnv:
         self._obs_cache = None
         self.completed_episodes.extend(records[i] for i in sorted(records))
         if telemetry.enabled():
-            telemetry.inc("rollout.ipc.replies", B)
             telemetry.inc("rollout.obs.bytes_slab", self._obs_nbytes * B)
         return _LazyObsList(self), rewards, dones
 
@@ -860,7 +857,6 @@ class ParallelVectorEnv:
             records[i] for i in sorted(records))
         self._stacked_cache = state["stacked"]
         if telemetry.enabled():
-            telemetry.inc("rollout.ipc.replies", B)
             telemetry.inc("rollout.obs.bytes_pipe", self._obs_nbytes * B)
             telemetry.inc("rollout.obs.bytes_stack", self._obs_nbytes * B)
         return list(self.obs), rewards, dones
@@ -891,7 +887,6 @@ class ParallelVectorEnv:
             if record is not None:
                 self.completed_episodes.append(record)
         if telemetry.enabled():
-            telemetry.inc("rollout.ipc.replies", len(indices))
             telemetry.inc("rollout.obs.bytes_pipe",
                           self._obs_nbytes * len(indices))
         return [self.obs[i] for i in indices], rewards, dones

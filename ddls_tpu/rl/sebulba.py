@@ -49,6 +49,7 @@ import numpy as np
 from ddls_tpu import telemetry
 from ddls_tpu.rl.fused import EPISODE_TRACE_KEYS
 from ddls_tpu.rl.ring import TrajRing
+from ddls_tpu.sim.jax_memo import MemoCounters
 from ddls_tpu.telemetry import scopes
 
 
@@ -77,7 +78,7 @@ def split_meshes(actor_devices: Optional[int] = None, devices=None):
     return (make_mesh(devices=devs[:a]), make_mesh(devices=devs[a:]))
 
 
-class SebulbaCollector:
+class SebulbaCollector(MemoCounters):
     """Actor-side collector of the Sebulba split: ``collect(params,
     rng)`` runs one [T, B] segment batch entirely on the ACTOR sub-mesh
     and returns DEVICE trajectories for the learner to ``shard_traj``
@@ -224,17 +225,6 @@ class SebulbaCollector:
                 "ring": self.ring,
                 "ring_segment": seg,
                 "ring_generation": seg.generation}
-
-    def memo_counters(self) -> Optional[Dict]:
-        """Cumulative in-kernel memo counters {hits, misses, evicts,
-        hit_rate}, summed over lanes (drain/reporting boundaries only —
-        sim/jax_memo.py:summarize_counters); None when the memo is
-        off."""
-        from ddls_tpu.sim.jax_memo import summarize_counters
-
-        if self.memo_cfg is None:
-            return None
-        return summarize_counters(self._state[1])
 
     def harvest_episodes(self, ep_trace) -> list:
         """Episode records from a FETCHED [B, T] episode-counter trace

@@ -927,6 +927,9 @@ class EpisodeTables:
     """Everything static for a jitted canonical-RAMP episode."""
     st: ShapeTables
     tables: dict               # stacked config tables (jnp arrays)
+    row_deps: np.ndarray       # host copy of tables["n_deps"]: each
+    #                            (model, degree) row's real deps, for
+    #                            host reducers (rl/fused.py) — no fetch
     pads: ConfigPads
     types: List[str]           # model name -> type index (list order)
     degrees: List[int]         # action degree -> cfg column (list order)
@@ -1009,7 +1012,8 @@ def build_episode_tables(env, max_degree: Optional[int] = None,
             "res": [int(r) for r in sr.win_res],
         }
     return EpisodeTables(
-        st=st, tables=jt, pads=pads, types=types, degrees=degrees,
+        st=st, tables=jt, row_deps=tables["n_deps"], pads=pads,
+        types=types, degrees=degrees,
         comm={"x": topo.num_communication_groups,
               "rate": topo.channel_bandwidth,
               "prop": topo.intra_gpu_propagation_latency,

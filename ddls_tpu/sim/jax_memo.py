@@ -291,18 +291,55 @@ def memo_trace_counters(memo: dict) -> dict:
             "memo_evicts": memo["evicts"]}
 
 
-def summarize_counters(memo: dict) -> dict:
-    """{hits, misses, evicts, hit_rate} from a carried (possibly
-    lane-stacked) memo state — the ONE summary home shared by
-    `DevicePPOCollector.memo_counters` and
-    `FusedEpochDriver.memo_counters`. One explicit device fetch of three
-    small arrays; call at drain/reporting boundaries only (result lines,
-    logging), never on a per-collect/per-epoch hot path."""
-    import jax
+#: the memo state's cumulative counters (``[lanes]`` i32 each, or
+#: scalars for one lane)
+COUNTER_KEYS = ("hits", "misses", "evicts")
 
-    vals = jax.device_get({k: memo[k]
-                           for k in ("hits", "misses", "evicts")})
-    out = {k: int(np.sum(v)) for k, v in vals.items()}
+
+def counter_arrays(memo: dict) -> dict:
+    """The carried memo state's counter arrays, still on the device: no
+    fetch. A drain boundary that already fetches something batches
+    them in (train/loops.py)."""
+    return {k: memo[k] for k in COUNTER_KEYS}
+
+
+def summarize_fetched(vals: dict) -> dict:
+    """{hits, misses, evicts, hit_rate} from FETCHED counter arrays,
+    summed over lanes: host arithmetic alone."""
+    out = {k: int(np.sum(vals[k])) for k in COUNTER_KEYS}
     total = out["hits"] + out["misses"]
     out["hit_rate"] = out["hits"] / total if total else 0.0
     return out
+
+
+def summarize_counters(memo: dict) -> dict:
+    """{hits, misses, evicts, hit_rate} from a carried (possibly
+    lane-stacked) memo state. One explicit device fetch of three small
+    arrays; call at drain/reporting boundaries only (result lines,
+    logging), never on a per-collect/per-epoch hot path."""
+    import jax
+
+    return summarize_fetched(jax.device_get(counter_arrays(memo)))
+
+
+class MemoCounters:
+    """The memo-counter readbacks of a driver that carries ``(sim state,
+    memo state)`` as ``self._state`` and its ``self.memo_cfg`` — the ONE
+    home shared by `DevicePPOCollector`, `FusedEpochDriver` and
+    `SebulbaCollector`."""
+
+    def memo_counters(self) -> Optional[dict]:
+        """Cumulative in-kernel memo counters {hits, misses, evicts,
+        hit_rate} summed over lanes (`summarize_counters`: one fetch,
+        drain/reporting boundaries only); None when the memo is off."""
+        if self.memo_cfg is None:
+            return None
+        return summarize_counters(self._state[1])
+
+    def memo_counter_arrays(self) -> Optional[dict]:
+        """The same counters as device arrays, unfetched, for a drain
+        boundary to batch into the fetch it already makes
+        (train/loops.py); None when the memo is off."""
+        if self.memo_cfg is None:
+            return None
+        return counter_arrays(self._state[1])

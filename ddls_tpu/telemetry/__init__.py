@@ -50,6 +50,7 @@ from ddls_tpu.telemetry.metrics import (DEFAULT_LATENCY_BUCKETS_S,
                                         Registry, Span, TransferSpan,
                                         aggregate_snapshots,
                                         overlap_summary,
+                                        per_epoch_sums,
                                         percentile_from_bucket_counts,
                                         tree_nbytes)
 from ddls_tpu.telemetry.sink import JsonlSink
@@ -58,7 +59,8 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "Span", "NullSpan",
     "NULL_SPAN", "TransferSpan", "JsonlSink", "DEFAULT_LATENCY_BUCKETS_S",
     "DEFAULT_WINDOW", "percentile_from_bucket_counts", "overlap_summary",
-    "aggregate_snapshots", "tree_nbytes", "TRACE_ANNOTATION_PREFIX",
+    "aggregate_snapshots", "per_epoch_sums", "tree_nbytes",
+    "TRACE_ANNOTATION_PREFIX",
     "startup",
     "registry", "enabled", "enable", "disable", "span", "transfer", "inc",
     "observe", "set_gauge", "record_event", "snapshot", "span_summaries",

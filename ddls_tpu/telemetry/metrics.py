@@ -36,7 +36,8 @@ import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
@@ -733,3 +734,27 @@ def overlap_summary(intervals: Sequence[Tuple[str, float, float]],
             {"dur_s": g, "start": s, "end": e}
             for g, s, e in gaps[:max(top_gaps, 0)]],
     }
+
+
+#: the span whose successive starts delimit a fused run's epochs
+EPOCH_MARKER = "train.fused_epoch"
+
+
+def per_epoch_sums(intervals: Sequence[Tuple[str, float, float]],
+                   names) -> List[float]:
+    """Per-epoch sums of a fused run's spans (docs/telemetry.md, "a
+    fused epoch's five spans"): an epoch runs from one ``EPOCH_MARKER``
+    span's start to the next one's, and a span belongs to the epoch it
+    STARTS in. Returns the seconds of the ``names`` spans, one sum an
+    epoch; ``[]`` where no marker span was recorded. Sources as for
+    ``overlap_summary``; the benchmark's ``program_span_per_epoch``
+    reader and ``scripts/telemetry_report.py`` both group through it."""
+    starts = sorted(t0 for name, t0, _ in intervals
+                    if name == EPOCH_MARKER)
+    sums = [0.0] * len(starts)
+    for name, t0, t1 in intervals:
+        if name in names:
+            epoch = bisect.bisect_right(starts, t0) - 1
+            if epoch >= 0:
+                sums[epoch] += t1 - t0
+    return sums

@@ -415,6 +415,9 @@ class RLEpochLoop:
         # episode-counter traces
         self.fused = None
         self._fused_episode_ring: List[Any] = []
+        # the epoch whose drain boundary already waited for the device
+        # under ``train.device_wait`` (telemetry-only, _device_wait)
+        self._device_waited_epoch = -1
         self.metrics_sync_interval = max(int(metrics_sync_interval or 1), 1)
         self.pipeline_depth = int(pipeline_depth or 0)
         if self.pipeline_depth < 0:
@@ -1145,6 +1148,23 @@ class RLEpochLoop:
         self._metrics_ring.append(lazy)
         return lazy
 
+    def _device_wait(self, tree) -> None:
+        """Telemetry-only (the caller gates on ``telemetry.enabled()``):
+        wait for the device BEFORE a drain boundary's first fetch, under
+        ``train.device_wait``, so that the wait (the device is busy, not
+        idle) is one span and ``train.host_sync`` holds the
+        device->host copies alone. Once a boundary: the boundary's
+        second fetch (the episode trace, an output of the program the
+        first wait covered) opens no second span. With telemetry off
+        the first fetch blocks as it always did."""
+        if self._device_waited_epoch == self.epoch_counter:
+            return
+        import jax
+
+        self._device_waited_epoch = self.epoch_counter
+        with telemetry.span("train.device_wait"):
+            jax.block_until_ready(tree)
+
     def _maybe_sync_metrics(self, force: bool = False) -> None:
         """Drain the unsynced-metrics ring in ONE batched device fetch
         when a sync boundary is reached (every ``metrics_sync_interval``
@@ -1158,33 +1178,56 @@ class RLEpochLoop:
         from ddls_tpu.train.metrics import LazyMetrics
 
         ring, self._metrics_ring = self._metrics_ring, []
+        # fused / sebulba alone split the wait from the copy, and record
+        # the memo's counters with the episode drain, where they ride
+        # the trace's fetch; the other loops keep their spans as they are
+        drains_episodes = self.loop_mode in ("fused", "sebulba")
+        if drains_episodes and telemetry.enabled():
+            self._device_wait(([lm.device_values() for lm in ring],
+                               self._fused_episode_ring))
         with telemetry.span("train.host_sync"):
             with telemetry.transfer("drain.metrics", "d2h") as tr:
                 if telemetry.enabled():
                     for lm in ring:
                         tr.add(lm.device_values())
                 LazyMetrics.materialize_group(ring)
-        self._record_memo_drain()
+        if not drains_episodes and telemetry.enabled():
+            self._record_memo_drain()
 
-    def _record_memo_drain(self) -> None:
-        """Telemetry-only memo-counter event at a sync boundary (the
-        timeline's memo hit-rate counter track): a drain is already a
-        sanctioned device-fetch boundary, and the fetch only happens
-        while telemetry is enabled (local arrays — no collective, so a
-        per-process telemetry toggle stays multi-host safe)."""
-        if not telemetry.enabled():
-            return
+    def _memo_source(self):
+        """The driver that carries the in-kernel lookahead memo (None
+        where the loop collects on the host)."""
         source = self.fused if self.fused is not None else getattr(
             self, "collector", None)
-        fn = getattr(source, "memo_counters", None)
-        if fn is None:
-            return
-        try:
-            counters = fn()
-        except Exception:
-            return
-        if counters:
-            telemetry.record_event("memo_counters", **counters)
+        return source if hasattr(source, "memo_counter_arrays") else None
+
+    def _record_memo_drain(self, fetched: Optional[dict] = None) -> None:
+        """Telemetry-only memo-counter event at a sync boundary (the
+        timeline's memo hit-rate counter track); the caller gates on
+        ``telemetry.enabled()``. ``fetched`` are the three counter
+        arrays where they rode the boundary's batched fetch; otherwise
+        ONE ledgered fetch of their own (``drain.memo``; local arrays —
+        no collective, so a per-process telemetry toggle stays
+        multi-host safe), which may find the state donated by a
+        background collection: observability never breaks training."""
+        import jax
+
+        from ddls_tpu.sim.jax_memo import summarize_fetched
+
+        if fetched is None:
+            source = self._memo_source()
+            arrays = (source.memo_counter_arrays()
+                      if source is not None else None)
+            if arrays is None:
+                return
+            try:
+                with telemetry.transfer("drain.memo", "d2h") as tr:
+                    tr.add(arrays)
+                    fetched = jax.device_get(arrays)
+            except Exception:
+                return
+        telemetry.record_event("memo_counters",
+                               **summarize_fetched(fetched))
 
     def sync_metrics(self) -> None:
         """Force-drain any unsynced metrics (checkpoint/shutdown/test
@@ -1205,17 +1248,25 @@ class RLEpochLoop:
         return ring.stats() if ring is not None else None
 
     # ------------------------------------------------------- fused epoch
+    @property
+    def _episode_harvester(self):
+        """The driver that owns the pending episode traces:
+        ``self.fused`` ([U, B, T] traces) or the sebulba collector
+        ([B, T] traces) — both keep host-side episode lengths, so
+        drains must stay in collection order."""
+        return self.fused if self.fused is not None else self.collector
+
     def _maybe_drain_fused_episodes(self, force: bool = False
                                     ) -> List[dict]:
-        """Drain the fused/sebulba epochs' compact episode-counter
-        traces in ONE batched fetch and harvest episode records, at the
-        SAME sync boundaries as the metrics ring (every
-        ``metrics_sync_interval`` epochs, an eval epoch, or ``force``)
-        — never per update. The gate is deterministic (epoch counter +
-        config only — multi-host rules). The harvester is the owning
-        driver: ``self.fused`` ([U, B, T] traces) or the sebulba
-        collector ([B, T] traces) — both keep host-side episode
-        lengths, so drains must stay in collection order."""
+        """Fetch the fused/sebulba epochs' compact episode-counter
+        traces in ONE batched device_get, at the SAME sync boundaries as
+        the metrics ring (every ``metrics_sync_interval`` epochs, an
+        eval epoch, or ``force``) — never per update. The gate is
+        deterministic (epoch counter + config only — multi-host rules).
+        Returns the fetched traces (host arrays, collection order) for
+        ``_finalize_drained`` to harvest; while telemetry is on they are
+        first reduced into its counters, under
+        ``train.telemetry_reduce``."""
         if not self._fused_episode_ring:
             return []
         is_eval = bool(self.evaluation_interval
@@ -1230,21 +1281,30 @@ class RLEpochLoop:
                                        record_lookahead_trips,
                                        record_padding_fill)
 
-        harvester = (self.fused if self.fused is not None
-                     else self.collector)
+        harvester = self._episode_harvester
         ring, self._fused_episode_ring = self._fused_episode_ring, []
+        # telemetry-only: the memo's counter arrays ride the trace's
+        # fetch — no round trip of their own — where no background
+        # collection can donate the state they live in meanwhile
+        memo = None
+        if telemetry.enabled():
+            if not self.pipeline_depth:
+                memo = harvester.memo_counter_arrays()
+            self._device_wait((ring, memo))
         with telemetry.span("train.host_sync"):
             with telemetry.transfer("drain.episodes", "d2h") as tr:
-                tr.add(ring)
-                fetched = jax.device_get(ring)
-        episodes: List[dict] = []
-        for ep in fetched:
-            episodes.extend(harvester.harvest_episodes(ep))
-            if telemetry.enabled():
-                record_lookahead_trips(ep, harvester.et.pads)
-                record_padding_fill(ep, harvester.et, harvester.ot)
-                record_decisions(ep, harvester.et, harvester.ot)
-        return episodes
+                tr.add((ring, memo))
+                fetched, memo = jax.device_get((ring, memo))
+        if telemetry.enabled():
+            # what runs only because telemetry is on: the instrument's
+            # own cost, under its own name
+            with telemetry.span("train.telemetry_reduce"):
+                self._record_memo_drain(memo)
+                for ep in fetched:
+                    record_lookahead_trips(ep, harvester.et.pads)
+                    record_padding_fill(ep, harvester.et, harvester.ot)
+                    record_decisions(ep, harvester.et, harvester.ot)
+        return fetched
 
     def _run_fused(self) -> Dict[str, Any]:
         """One fused epoch: ONE device dispatch runs
@@ -1254,7 +1314,15 @@ class RLEpochLoop:
         ``metrics_sync_interval`` under ``train.host_sync`` — the
         steady-state epoch performs NO device→host transfer. Episode
         summaries therefore appear on drain epochs (covering every
-        epoch since the last drain), not per epoch."""
+        epoch since the last drain), not per epoch.
+
+        Five spans tile the call (docs/telemetry.md): ``train.
+        fused_epoch`` (the dispatch), at a drain boundary
+        ``train.device_wait`` (telemetry-only: the wait for the device,
+        taken out of the first fetch), ``train.host_sync`` (the two
+        device->host copies) and ``train.telemetry_reduce``
+        (telemetry-only: the instrument's own reductions), and
+        ``train.harvest`` (episode records, summary, bookkeeping)."""
         from ddls_tpu.train.metrics import LazyMetrics
 
         start = time.time()
@@ -1271,14 +1339,31 @@ class RLEpochLoop:
         self._metrics_ring.append(lazy)
         self._fused_episode_ring.append(ep)
         self._maybe_sync_metrics()
-        episodes = self._maybe_drain_fused_episodes()
+        fetched = self._maybe_drain_fused_episodes()
         results: Dict[str, Any] = {
             "epoch_counter": self.epoch_counter,
             "env_steps_this_iter": env_steps,
             "total_env_steps": self.total_env_steps,
             "learner": lazy,
         }
-        return self._finalize_results(results, episodes, start)
+        return self._finalize_drained(results, fetched, start)
+
+    def _finalize_drained(self, results: Dict[str, Any],
+                          fetched: List[dict], start: float
+                          ) -> Dict[str, Any]:
+        """The fused / sebulba epilogue, under ``train.harvest``:
+        episode records from the drained traces, their summary and the
+        bookkeeping — host work that runs with telemetry off too."""
+        with telemetry.span("train.harvest"):
+            return self._finalize_results(
+                results, self._harvest_drained(fetched), start)
+
+    def _harvest_drained(self, fetched: List[dict]) -> List[dict]:
+        """Episode records of the drained traces, in collection order
+        (the harvester keeps host-side episode lengths; a loop that
+        drains nothing — DQN, ES — has none)."""
+        return [record for ep in fetched for record
+                in self._episode_harvester.harvest_episodes(ep)]
 
     def run(self) -> Dict[str, Any]:
         """Collect one trajectory batch and apply one PPO update.
@@ -1332,20 +1417,20 @@ class RLEpochLoop:
             extras["segment_transit_s"] = transit
         learner_metrics = self._harvest_metrics(metrics, extras=extras)
         self._maybe_sync_metrics()
-        episodes = out["episodes"]
-        if self.loop_mode == "sebulba":
-            # episode counters stay device-resident until the drain
-            # boundary (fused discipline: the steady-state epoch stays
-            # transfer-free)
-            self._fused_episode_ring.append(out["ep_pending"])
-            episodes = self._maybe_drain_fused_episodes()
         results: Dict[str, Any] = {
             "epoch_counter": self.epoch_counter,
             "env_steps_this_iter": out["env_steps"],
             "total_env_steps": self.total_env_steps,
             "learner": learner_metrics,
         }
-        return self._finalize_results(results, episodes, start)
+        if self.loop_mode == "sebulba":
+            # episode counters stay device-resident until the drain
+            # boundary (fused discipline: the steady-state epoch stays
+            # transfer-free)
+            self._fused_episode_ring.append(out["ep_pending"])
+            return self._finalize_drained(
+                results, self._maybe_drain_fused_episodes(), start)
+        return self._finalize_results(results, out["episodes"], start)
 
     def _finalize_results(self, results: Dict[str, Any],
                           episodes: List[dict], start: float) -> Dict[str, Any]:
@@ -1594,19 +1679,17 @@ class RLEpochLoop:
         # harvested (completed episodes must not vanish with the loop);
         # no run() remains to return them, so they land on
         # ``undrained_episodes`` for callers that aggregate records
-        self.undrained_episodes = self._maybe_drain_fused_episodes(
-            force=True)
+        self.undrained_episodes = self._harvest_drained(
+            self._maybe_drain_fused_episodes(force=True))
         if self.run_ledger is not None:
             # run-boundary counter blocks for snapshot.json (host ints /
             # already-fetched values only — one memo fetch, no per-epoch
             # cost)
-            source = (self.fused if self.fused is not None
-                      else getattr(self, "collector", None))
-            memo_fn = getattr(source, "memo_counters", None)
+            source = self._memo_source()
             memo = None
-            if memo_fn is not None:
+            if source is not None:
                 try:
-                    memo = memo_fn()
+                    memo = source.memo_counters()
                 except Exception:
                     memo = None
             if memo and telemetry.enabled():

@@ -156,6 +156,37 @@ def _overlap_section(intervals: List[tuple]) -> List[str]:
     return lines + [""]
 
 
+#: a fused epoch's spans by what they are (docs/telemetry.md, "a fused
+#: epoch's five spans"): the wait for the device, the host time an epoch
+#: pays with telemetry off too, and what the instrument itself adds —
+#: the sums the benchmark's ``epoch_*_p50_*`` metrics read
+EPOCH_PARTS = (
+    ("device wait", ("train.device_wait",)),
+    ("host (telemetry off too)",
+     ("train.fused_epoch", "train.host_sync", "train.harvest")),
+    ("observer (telemetry only)", ("train.telemetry_reduce",)),
+)
+
+
+def _epoch_anatomy_section(intervals: List[tuple]) -> List[str]:
+    """A fused run's epochs from inside the program: per epoch
+    (``telemetry.per_epoch_sums``: delimited by successive
+    ``train.fused_epoch`` starts), each of ``EPOCH_PARTS`` — median and
+    max over the epochs."""
+    from ddls_tpu.telemetry import per_epoch_sums
+
+    rows = [(label, np.asarray(per_epoch_sums(intervals, names)))
+            for label, names in EPOCH_PARTS]
+    if not rows[0][1].size:
+        return []
+    lines = [f"== epoch anatomy ({rows[0][1].size} fused epochs; "
+             "per-epoch sums of the program's spans) ==",
+             f"{'part':<28}{'p50_ms':>12}{'max_ms':>12}"]
+    lines += [f"{label:<28}{np.median(values) * 1e3:>12.3f}"
+              f"{values.max() * 1e3:>12.3f}" for label, values in rows]
+    return lines + [""]
+
+
 def _flight_section(flight_events: List[dict]) -> List[str]:
     """Trace summary: events by kind, blocks by cause, per-job
     lifecycle (arrival -> decision -> placement -> outcome)."""
@@ -482,6 +513,7 @@ def render_report(path: str) -> List[str]:
         lines += [""]
     if span_intervals:
         lines += _overlap_section(span_intervals)
+        lines += _epoch_anatomy_section(span_intervals)
     snapshot_sections = (_walk_snapshot(last_snapshot)
                          if last_snapshot else {})
     if transfers:

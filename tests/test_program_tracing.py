@@ -157,7 +157,7 @@ def test_trip_counters_are_the_hosts_reduction_of_the_trace():
     record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS))
     snap = telemetry.snapshot()
     counted = {
-        "sim.lookahead.calls": 3, "sim.lookahead.trips": 21,
+        "sim.lookahead.trips": 21,
         "sim.lookahead.lockstep_trips": 16,
         "sim.lookahead.lockstep_lane_trips": 48,
         "sim.lookahead.stage_trips.3": 16,
@@ -174,8 +174,9 @@ def test_trip_counters_are_the_hosts_reduction_of_the_trace():
             if k.startswith("sim.lookahead.")} == {
         **{k: 2 * v for k, v in counted.items()},
         "sim.lookahead.minor_slots": 128, "sim.lookahead.minor_used": 48}
-    hist = snap["histograms"]["sim.lookahead.trips_per_call"]
-    assert hist["count"] == 3 and hist["max"] == 9.0
+    # counters alone: no per-lane-step histogram (PR 34 removed
+    # ``trips_per_call``, which nothing read)
+    assert "histograms" not in telemetry.snapshot()
 
 
 def _lockstep_by_hand(own, widths):
@@ -296,8 +297,8 @@ def test_fused_loop_counts_trips_only_while_telemetry_is_on(
         telemetry.reset()
         loop.run()
         counters = telemetry.snapshot()["counters"]
-        lanes, steps = loop.fused.num_lanes, 2 * 2      # U x T
-        assert 0 < counters["sim.lookahead.calls"] <= lanes * steps
+        lanes = loop.fused.num_lanes
+        assert 0 < counters["sim.lookahead.trips"]
         assert (counters["sim.lookahead.lockstep_lane_trips"]
                 == lanes * counters["sim.lookahead.lockstep_trips"])
         assert (counters["sim.lookahead.lockstep_trips"]
@@ -313,6 +314,325 @@ def test_fused_loop_counts_trips_only_while_telemetry_is_on(
         assert counters["event.memo_counters"] >= 1
     finally:
         loop.close()
+
+
+# ----------------------------------------- the fused epoch's anatomy
+#: the five spans that tile ``run()`` of a fused epoch (PR 34)
+EPOCH_SPANS = ("train.fused_epoch", "train.device_wait",
+               "train.host_sync", "train.telemetry_reduce",
+               "train.harvest")
+
+
+@pytest.fixture(scope="module")
+def warm_fused_loop(fused_dataset):
+    """One tiny fused loop, its program compiled and one epoch run,
+    draining every epoch (as the benchmark's mixes do)."""
+    loop = test_fused._make_fused_loop(fused_dataset,
+                                       metrics_sync_interval=1)
+    loop.run()
+    yield loop
+    loop.close()
+
+
+def _count_calls(monkeypatch, module, name):
+    """Count calls of ``module.name`` whose argument holds a device
+    array (a host tree costs no transfer and no wait)."""
+    import jax
+
+    calls, inner = [], getattr(module, name)
+
+    def counted(tree, *args, **kwargs):
+        if any(isinstance(leaf, jax.Array)
+               for leaf in jax.tree_util.tree_leaves(tree)):
+            calls.append(tree)
+        return inner(tree, *args, **kwargs)
+
+    monkeypatch.setattr(module, name, counted)
+    return calls
+
+
+def test_five_spans_tile_every_fused_epoch(warm_fused_loop):
+    """Over 5 fused epochs with telemetry on: one ``train.fused_epoch``
+    an epoch and, at each drain boundary, one ``train.device_wait``, one
+    ``train.harvest``, one ``train.telemetry_reduce`` and the two
+    copies under ``train.host_sync``; in program order, none nested in
+    another, and together >= 90 % of ``run()``'s wall on the registry's
+    clock."""
+    from benchmarks.reduce import xplane
+
+    loop = warm_fused_loop
+    telemetry.enable(record_intervals=True)
+    telemetry.reset()
+    walls = []
+    for _ in range(5):
+        t0 = telemetry.clock_now()
+        loop.run()
+        walls.append((t0, telemetry.clock_now()))
+    telemetry.disable()
+    intervals = [iv for iv in telemetry.span_intervals()
+                 if iv[0] in EPOCH_SPANS]
+    for t0, t1 in walls:
+        inside = sorted((iv for iv in intervals if t0 <= iv[1] < t1),
+                        key=lambda iv: iv[1])
+        assert [name for name, _, _ in inside] == [
+            "train.fused_epoch", "train.device_wait", "train.host_sync",
+            "train.host_sync", "train.telemetry_reduce", "train.harvest"]
+        assert all(a[2] <= b[1] for a, b in zip(inside, inside[1:]))
+    covered = xplane.total(xplane.union((a, b) for _, a, b in intervals))
+    assert covered >= 0.9 * sum(t1 - t0 for t0, t1 in walls)
+    # the copies are ledgered under the spans that hold them, and the
+    # memo's counters rode the episode trace's fetch
+    counters = telemetry.snapshot()["counters"]
+    assert counters["transfer.drain.metrics.calls"] == 5
+    assert counters["transfer.drain.episodes.calls"] == 5
+    assert "transfer.drain.memo.calls" not in counters
+    assert counters["event.memo_counters"] == 5
+
+
+def test_device_wait_opens_at_the_sync_boundary_only(fused_dataset):
+    """With ``metrics_sync_interval`` 3, epochs 1-2 wait for nothing
+    (and copy nothing); epoch 3 opens the one wait, and the harvest
+    span closes every epoch."""
+    loop = test_fused._make_fused_loop(fused_dataset,
+                                       metrics_sync_interval=3)
+    try:
+        telemetry.enable()
+        for epoch, waits in ((1, 0), (2, 0), (3, 1)):
+            loop.run()
+            spans = telemetry.snapshot()["spans"]
+            assert spans["train.fused_epoch"]["count"] == epoch
+            assert spans["train.harvest"]["count"] == epoch
+            assert spans.get("train.device_wait",
+                             {"count": 0})["count"] == waits
+            assert ("train.host_sync" in spans) == bool(waits)
+        assert spans["train.host_sync"]["count"] == 2
+        assert spans["train.telemetry_reduce"]["count"] == 1
+    finally:
+        loop.close()
+
+
+def test_telemetry_off_opens_no_span_and_waits_for_nothing(
+        warm_fused_loop, monkeypatch):
+    """Off, ``run()`` is the epoch it was: every ``telemetry.span`` /
+    ``transfer`` hands out the ``NULL_SPAN`` singleton (no ``Span`` is
+    allocated), nothing is recorded, the loop calls no
+    ``block_until_ready`` (the first fetch blocks, as it always did),
+    and what only the instrument needs (the wait, the memo's counter
+    arrays, the memo event) is gated at its call site."""
+    import jax
+
+    from ddls_tpu.telemetry import metrics
+
+    def no_span(*args, **kwargs):
+        raise AssertionError("a Span was allocated with telemetry off")
+
+    def not_off(*args, **kwargs):
+        raise AssertionError("telemetry-only work ran with telemetry off")
+
+    monkeypatch.setattr(metrics.Span, "__init__", no_span)
+    monkeypatch.setattr(metrics.TransferSpan, "__init__", no_span)
+    loop = warm_fused_loop
+    monkeypatch.setattr(loop, "_device_wait", not_off)
+    monkeypatch.setattr(loop, "_record_memo_drain", not_off)
+    monkeypatch.setattr(loop.fused, "memo_counter_arrays", not_off)
+    waits = _count_calls(monkeypatch, jax, "block_until_ready")
+    assert telemetry.span("train.device_wait") is telemetry.NULL_SPAN
+    loop.run()
+    assert waits == [] and telemetry.snapshot() == {}
+
+
+def test_telemetry_on_adds_no_transfer_to_a_drain_boundary(
+        warm_fused_loop, monkeypatch):
+    """A drain boundary makes two device->host fetches with telemetry
+    off (the metrics, the episode traces) and the same two with it on:
+    the memo's counters ride the second, ``record_padding_fill`` reads
+    the tables' host copy, and the one wait is the ``train.device_wait``
+    span's."""
+    import jax
+
+    from ddls_tpu.rl import fused
+
+    loop = warm_fused_loop
+    fetches = _count_calls(monkeypatch, jax, "device_get")
+    waits = _count_calls(monkeypatch, jax, "block_until_ready")
+    loop.run()
+    assert (len(fetches), len(waits)) == (2, 0)
+    telemetry.enable()
+    telemetry.reset()
+    del fetches[:]
+    loop.run()
+    assert (len(fetches), len(waits)) == (2, 1)
+    counters = telemetry.snapshot()["counters"]
+    assert {k: v for k, v in counters.items()
+            if k.startswith("transfer.") and k.endswith(".calls")} == {
+        "transfer.drain.metrics.calls": 1,
+        "transfer.drain.episodes.calls": 1}
+    # ... and the reducers fetch nothing: fed a host trace under a
+    # guard that refuses every device->host transfer
+    ep = {k: np.zeros((2, loop.fused.num_lanes, 2), np.int32)
+          for k in fused.EPISODE_TRACE_KEYS}
+    ep["la_trips"][0, :, 0] = 5
+    ep["action"][:] = loop.fused.et.degrees[-1]
+    del fetches[:]
+    with jax.transfer_guard_device_to_host("disallow"):
+        fused.record_padding_fill(ep, loop.fused.et, loop.fused.ot)
+    assert fetches == []
+    assert telemetry.snapshot()["counters"][
+        "sim.lookahead.dep_slots_decided"] == counters[
+        "sim.lookahead.dep_slots_decided"] + loop.fused.num_lanes * int(
+        loop.fused.et.row_deps[len(loop.fused.et.degrees) - 1])
+    assert np.array_equal(loop.fused.et.row_deps,
+                          np.asarray(loop.fused.et.tables["n_deps"]))
+
+
+def test_a_background_collection_keeps_the_memo_fetch_apart(
+        warm_fused_loop, monkeypatch):
+    """Where a background collection may donate the collector's state
+    meanwhile (``pipeline_depth`` >= 1), the memo's counters do not
+    ride the trace's fetch: ONE ledgered fetch of their own
+    (``drain.memo``), inside ``train.telemetry_reduce`` — at most one
+    transfer more than with telemetry off."""
+    import jax
+
+    loop = warm_fused_loop
+    monkeypatch.setattr(loop, "pipeline_depth", 1)
+    fetches = _count_calls(monkeypatch, jax, "device_get")
+    telemetry.enable(record_intervals=True)
+    telemetry.reset()
+    loop.run()
+    assert len(fetches) == 3
+    counters = telemetry.snapshot()["counters"]
+    assert counters["transfer.drain.memo.calls"] == 1
+    assert counters["event.memo_counters"] == 1
+    spans = {name: (t0, t1) for name, t0, t1
+             in telemetry.span_intervals()}
+    lo, hi = spans["train.telemetry_reduce"]
+    assert lo <= spans["transfer.drain.memo"][0] \
+        and spans["transfer.drain.memo"][1] <= hi
+
+
+def test_report_renders_the_epochs_the_program_grouped(warm_fused_loop,
+                                                       tmp_path):
+    """An operator's report and the benchmark's reader see one anatomy:
+    ``telemetry.per_epoch_sums`` groups the registry's intervals by
+    ``train.fused_epoch`` starts, and ``scripts/telemetry_report.py``
+    renders the same sums, part by part, from the sink's span records
+    of the same run."""
+    sys.path.insert(0, os.path.join(REPO, "scripts"))
+    import telemetry_report
+
+    sink = tmp_path / "run.jsonl"
+    telemetry.enable(sink_path=str(sink), record_intervals=True)
+    try:
+        telemetry.reset()
+        for _ in range(3):
+            warm_fused_loop.run()
+    finally:
+        telemetry.disable()
+        telemetry.registry().sink.close()
+        telemetry.registry().sink = None
+    intervals = telemetry.span_intervals()
+    assert telemetry.per_epoch_sums(
+        [("train.collect", 0.0, 1.0)], {"train.collect"}) == []
+    report = telemetry_report.render_report(str(sink))
+    at = report.index("== epoch anatomy (3 fused epochs; per-epoch sums "
+                      "of the program's spans) ==")
+    assert set(EPOCH_SPANS) == {
+        name for _, names in telemetry_report.EPOCH_PARTS
+        for name in names}
+    for line, (label, names) in zip(report[at + 2:],
+                                    telemetry_report.EPOCH_PARTS):
+        sums = telemetry.per_epoch_sums(intervals, names)
+        assert len(sums) == 3 and all(v > 0 for v in sums)
+        assert line.startswith(label)
+        p50, top = (float(x) for x in line[len(label):].split())
+        assert p50 == pytest.approx(np.median(sums) * 1e3, abs=2e-3)
+        assert top == pytest.approx(max(sums) * 1e3, abs=2e-3)
+
+
+#: every ``program_counter`` name a metric listed in BENCHMARK.json
+#: reads through ``telemetry_counter`` (directly or as a ratio's part)
+def _listed_counter_names():
+    from benchmarks import harness
+
+    bench = harness.read_json(os.path.join(REPO, "BENCHMARK.json"))
+    names, todo = set(), [m["name"] for m in bench["per_layer"]]
+    while todo:
+        source = harness.read_json(os.path.join(
+            harness.BENCH_DIR, "layer_metrics", todo.pop() + ".json")
+        )["source"]
+        if source["kind"] == "telemetry_counter":
+            names.add(source["counter"])
+        elif source["kind"] == "metric_ratio":
+            todo += [source["num"], source["den"]]
+    return names
+
+
+def test_listed_counters_are_the_parents_on_the_same_trace(
+        warm_fused_loop):
+    """Every counter a listed benchmark metric reads, reduced from one
+    drained trace, equals the parent's reduction of that trace (PR 33's
+    formulas, written out here), bit for bit."""
+    from ddls_tpu.rl.fused import (record_decisions,
+                                   record_lookahead_trips,
+                                   record_padding_fill)
+    from ddls_tpu.sim.jax_lookahead import stage_trips, stage_widths
+
+    et, ot = warm_fused_loop.fused.et, warm_fused_loop.fused.ot
+    rng = np.random.default_rng(34)
+    shape = (2, 8, 3)
+    ep = {"la_trips": rng.integers(0, 40, shape).astype(np.int32),
+          "jtype": rng.integers(0, len(et.types), shape).astype(np.int32),
+          "action": rng.choice(et.degrees, shape).astype(np.int32),
+          "accepted": rng.integers(0, 2, shape).astype(np.int32),
+          "n_occupied": rng.integers(0, et.n_srv, shape).astype(np.int32)}
+    ep["la_trips"][rng.random(shape) < 0.4] = 0
+    telemetry.enable()
+    record_lookahead_trips(ep, et.pads)
+    record_padding_fill(ep, et, ot)
+    record_decisions(ep, et, ot)
+    counters = telemetry.snapshot()["counters"]
+
+    own, ran = ep["la_trips"], ep["la_trips"] > 0
+    widths = stage_widths(8, int(et.pads.max_split))
+    by_width = stage_trips(np.moveaxis(own, -2, -1), widths).reshape(
+        -1, len(widths)).sum(axis=0)
+    column = np.zeros(et.max_action + 1, np.int64)
+    column[et.degrees] = np.arange(len(et.degrees))
+    row = ep["jtype"][ran] * len(et.degrees) + column[ep["action"][ran]]
+    longest = ep["jtype"] == int(np.argmax(ot["orig_seq_sum"]))
+    parent = {
+        "sim.lookahead.trips": int(own.sum()),
+        "sim.lookahead.lockstep_trips": int(own.max(axis=1).sum()),
+        "sim.lookahead.lockstep_lane_trips":
+            int(by_width @ np.asarray(widths)),
+        "sim.lookahead.dep_slots": int(et.pads.n_deps),
+        "sim.lookahead.dep_slots_used": int(et.pads.n_deps_used),
+        "sim.lookahead.dep_slots_decided":
+            int(np.asarray(et.tables["n_deps"])[row].sum()),
+        "sim.lookahead.dep_slots_offered":
+            int(ran.sum()) * int(et.pads.n_deps),
+        "env.obs.nodes_real":
+            int(ot["node_split"][:, 0][ep["jtype"]].sum()),
+        "env.obs.nodes_padded":
+            ep["jtype"].size * int(ot["node_features"].shape[1]),
+        "env.decisions.offered": ep["accepted"].size,
+        "env.decisions.accepted": int(ep["accepted"].sum()),
+        "env.decisions.offered_longest": int(longest.sum()),
+        "env.decisions.accepted_longest":
+            int(ep["accepted"][longest].sum()),
+        "env.cluster.occupied_servers": int(ep["n_occupied"].sum()),
+        "env.cluster.servers": ep["accepted"].size * et.n_srv,
+    }
+    assert {k: counters[k] for k in parent} == parent
+    # what is left of the listed names are start-up gauges counted once
+    # a drained trace (the lookahead's minor axis, the mask's rows, an
+    # architecture's bank): none set in this process, none counted
+    rest = _listed_counter_names() - set(parent)
+    assert rest and all(
+        name.startswith(("sim.lookahead.minor_", "env.mask.rows_",
+                         "graphs.arch.")) for name in rest)
+    assert not rest & set(counters)
 
 
 # ------------------------------------------------------ start-up spans

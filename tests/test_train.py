@@ -168,6 +168,10 @@ def test_rl_epoch_loop_end_to_end(dataset_dir, tmp_path):
         assert spans["train.host_sync"]["count"] == 1
         assert "train.update_device" in spans
         assert all(s["count"] == 1 for s in spans.values())
+        # the fused epoch's anatomy (PR 34) is the fused / sebulba
+        # loops' alone: this loop keeps the spans it had
+        assert not {"train.device_wait", "train.telemetry_reduce",
+                    "train.harvest"} & set(spans)
     finally:
         telemetry.reset()
         telemetry.disable()
