@@ -17,7 +17,9 @@ tie ranks, candidate block shapes per split — and everything that depends on
 cluster state (free memory, server/channel occupancy, running jobs, the
 arrival clock) lives in small state arrays. A decision is then: gather the
 config row -> scan the padded forward-op sequence placing each op (parent
-co-location, else generic first-fit block search) -> price deps (collective
+co-location, else generic first-fit block search; servers, anchor cells
+and op slots reached by comparison and reduction over static tables,
+never by an index per element: `jax_allocate_job`) -> price deps (collective
 symmetry test + the RAMP all-reduce formula) -> SRPT scores -> the jitted
 lookahead -> SLA gate -> masked commit. The episode loop advances the event
 clock (completions, arrivals) between decisions exactly like
@@ -86,6 +88,14 @@ class ShapeTables:
     in exactly the host's scan order (`block_shapes_for` + the diagonal
     fallback + the trailing (s,1,1)), with shapes whose origin span is
     empty already dropped (the host skips them inside `first_fit_block`).
+
+    ``anchor_valid`` / ``member`` / ``servers_of`` are the same geometry
+    spelled out per (shape, anchor cell) — is the anchor inside the
+    shape's origin span with every cell of its block inside the ramp,
+    which servers the block covers, and those servers in
+    `enumerate_block` order — so that the placement scan reaches a
+    block's servers by comparison and reduction over these tables,
+    never by an index per cell (`jax_allocate_job`).
     """
     ramp_shape: Coord
     shapes: List[Coord]            # distinct shapes (S==-1 -> diagonal)
@@ -95,6 +105,9 @@ class ShapeTables:
     bases: np.ndarray              # [n_shapes, 3] i32 modulo base per axis
     spans: np.ndarray              # [n_shapes, 3] i32 origin span extents
     diagonal: np.ndarray           # [n_shapes] bool
+    anchor_valid: np.ndarray       # [n_shapes, n_cells] bool
+    member: np.ndarray             # [n_shapes, n_cells, n_srv] bool
+    servers_of: np.ndarray         # [n_shapes, n_cells, MAX_CELLS] i32, -1 pad
 
 
 def _shape_span(shape: Coord, meta: Coord) -> Coord:
@@ -158,9 +171,45 @@ def build_shape_tables(ramp_shape: Coord, max_split: int) -> ShapeTables:
         bases[i] = ((ramp_shape[0] + 1, ramp_shape[1] + 1, ramp_shape[2])
                     if sh[2] == -1 else ramp_shape)
         spans[i] = _shape_span(sh, meta)
+    anchor_valid, member, servers_of = _block_membership(
+        meta, offsets, counts, bases, spans)
     return ShapeTables(ramp_shape=meta, shapes=distinct, row=row,
                        offsets=offsets, counts=counts, bases=bases,
-                       spans=spans, diagonal=diagonal)
+                       spans=spans, diagonal=diagonal,
+                       anchor_valid=anchor_valid, member=member,
+                       servers_of=servers_of)
+
+
+def _block_membership(ramp_shape: Coord, offsets, counts, bases, spans):
+    """(anchor_valid [n_shapes, n_cells], member [n_shapes, n_cells,
+    n_srv], servers_of [n_shapes, n_cells, MAX_CELLS]) of every distinct
+    shape anchored at every cell of the ramp (cells and servers both in
+    grid-flattened order): `enumerate_block`'s modulo per cell, the
+    explicit in-ramp test the diagonal's (dim + 1) wrap needs, and the
+    origin span. The host scans diagonal origins k over meta[2] + 2
+    values, but k and k - S alias the same block, so the k < S anchors
+    cover every class in the same first-fit order. Invalid anchors hold
+    no member and no server."""
+    C, R, S = ramp_shape
+    dims = np.array(ramp_shape)
+    n_cells = C * R * S
+    n_shapes, max_cells, _ = offsets.shape
+    origins = np.stack(np.unravel_index(np.arange(n_cells), ramp_shape), -1)
+    anchor_valid = np.zeros((n_shapes, n_cells), bool)
+    member = np.zeros((n_shapes, n_cells, n_cells), bool)
+    servers_of = np.full((n_shapes, n_cells, max_cells), -1, np.int32)
+    for i in range(n_shapes):
+        cnt = int(counts[i])
+        cells = (origins[:, None, :] + offsets[i, None, :cnt]) % bases[i]
+        in_ramp = (cells < dims).all(axis=(1, 2))
+        in_span = (origins < np.minimum(spans[i], (C, R, S))).all(axis=1)
+        valid = in_ramp & in_span
+        codes = np.ravel_multi_index(
+            tuple(cells[valid].transpose(2, 0, 1)), ramp_shape)
+        anchor_valid[i] = valid
+        servers_of[i, valid, :cnt] = codes
+        member[i][np.nonzero(valid)[0][:, None], codes] = True
+    return anchor_valid, member, servers_of
 
 
 # =========================================================================
@@ -386,7 +435,15 @@ def stack_config_tables(per_cfg: Sequence[dict],
     the candidate group of a block's deps (-1: none) — read the other
     way, a group's member blocks, whose rows and columns are its member
     ops — and ``blk_msg`` a clique's message size, the same for each of
-    its 2-edge sync pairs. Pricing needs no index table beyond these."""
+    its 2-edge sync pairs. Pricing needs no index table beyond these.
+
+    ``op_fwd`` [cfg, n_orig] is the placement scan's way back from its
+    forward-op slots to the op slots: original op o (forward or its
+    backward mirror) is placed with forward slot ``op_fwd[o]`` (-1: a
+    padded o), and shard k of either sits on server k of that slot's
+    block — which holds because the sub-ops of forward slot f are the
+    op slots o*S + 0 .. o*S + split - 1 of ONE original op o
+    (`_forward_slot_of_ops` checks it, row by row, and raises)."""
     S = int(shape_tables.counts.max())
     n_orig = max(c["n_orig"] for c in per_cfg)
     n_blocks = max((len(c["blk_src"]) for c in per_cfg), default=1) or 1
@@ -431,6 +488,7 @@ def stack_config_tables(per_cfg: Sequence[dict],
         "f_parents": np.full((K, F, P), -1, np.int32),
         "f_sub_fwd": np.full((K, F, S), -1, np.int32),
         "f_sub_bwd": np.full((K, F, S), -1, np.int32),
+        "op_fwd": np.full((K, n_orig), -1, np.int32),
         "grp_valid": np.zeros((K, G), bool),
         "grp_msg": np.zeros((K, G), np.float64),
         "seq_compute": np.zeros(K, np.float64),
@@ -467,80 +525,103 @@ def stack_config_tables(per_cfg: Sequence[dict],
             op_at[c["f_sub_fwd"]]
         out["f_sub_bwd"][k, :f, :c["f_sub_bwd"].shape[1]] = \
             op_at[c["f_sub_bwd"]]
+        out["op_fwd"][k] = _forward_slot_of_ops(
+            out["f_sub_fwd"][k], out["f_sub_bwd"][k], out["f_split"][k],
+            out["f_valid"][k], n_orig)
         out["grp_valid"][k, :len(c["groups"])] = True
         out["grp_msg"][k, :len(c["groups"])] = [g["msg"] for g in c["groups"]]
         out["seq_compute"][k] = c["seq_compute"]
     return out, pads
 
 
+def _forward_slot_of_ops(f_sub_fwd, f_sub_bwd, f_split, f_valid,
+                         n_orig: int) -> np.ndarray:
+    """[n_orig] forward scan slot of each original op of one stacked
+    row (-1: no forward slot places it). Raises unless the row is in
+    block order the way `jax_allocate_job` assumes: the ``f_split[f]``
+    valid sub-op slots of forward slot f (and of its backward mirror)
+    are o*S + 0, o*S + 1, ... of one original op o, and no two forward
+    slots share an o."""
+    S = f_sub_fwd.shape[1]
+    op_fwd = np.full(n_orig, -1, np.int32)
+    shard = np.arange(S)
+    for f in np.nonzero(f_valid)[0]:
+        for name, sub in (("f_sub_fwd", f_sub_fwd[f]),
+                          ("f_sub_bwd", f_sub_bwd[f])):
+            o, rem = divmod(int(sub[0]), S)
+            want = np.where(shard < f_split[f], o * S + shard, -1)
+            if sub[0] < 0 or rem or not (sub == want).all():
+                raise ValueError(
+                    f"{name}[{f}] = {sub.tolist()} is not the first "
+                    f"{int(f_split[f])} op slots of one original op: the "
+                    "tables are not in block order")
+            if op_fwd[o] >= 0:
+                raise ValueError(
+                    f"original op {o} is placed by forward slots "
+                    f"{int(op_fwd[o])} and {int(f)}")
+            op_fwd[o] = f
+    return op_fwd
+
+
 # =========================================================================
 # The scan-ified allocate_job kernel.
 # =========================================================================
 
-def _anchor_masks(free_flat, st: ShapeTables):
+def _anchor_masks(free, st: ShapeTables):
     """[n_shapes, n_cells] anchor-validity masks for EVERY distinct shape
-    given the flat free-server grid (True = free of other jobs AND enough
-    memory — block_ok's conjunction, agents/block_search.py:84-101).
-
-    Shapes and cell counts are static, so the per-cell gathers unroll at
-    trace time into pure vector ops on the [C, R, S] grid. Diagonal
-    anchors gather through the (dim+1) modulo with explicit in-ramp
-    masking (enumerate_block's S == -1 layout)."""
+    given the flat free-server vector (True = free of other jobs AND
+    enough memory — block_ok's conjunction, agents/block_search.py:84-101):
+    an anchor stands where the static geometry allows one
+    (``st.anchor_valid``) and none of its block's members
+    (``st.member``) is taken — ONE contraction of the taken servers with
+    the static 0/1 membership table (counts <= max_split: exact in a
+    single bf16 pass), a matmul over the lanes under their ``vmap``."""
     import jax.numpy as jnp
 
-    C, R, S = st.ramp_shape
-    free = free_flat.reshape(C, R, S)
-    ii, jj, kk = np.meshgrid(np.arange(C), np.arange(R), np.arange(S),
-                             indexing="ij")
-    masks = []
-    for si in range(len(st.shapes)):
-        cnt = int(st.counts[si])
-        span = st.spans[si]
-        base = st.bases[si]
-        ok = jnp.ones((C, R, S), bool)
-        for t in range(cnt):
-            off = st.offsets[si, t]
-            ci = (ii + int(off[0])) % int(base[0])
-            cj = (jj + int(off[1])) % int(base[1])
-            ck = (kk + int(off[2])) % int(base[2])
-            in_ramp = (ci < C) & (cj < R) & (ck < S)
-            cell_free = free[np.clip(ci, 0, C - 1),
-                             np.clip(cj, 0, R - 1),
-                             np.clip(ck, 0, S - 1)]
-            ok = ok & jnp.asarray(in_ramp) & cell_free
-        # origin span: the host scans diagonal origins k over
-        # meta[2] + 2 values, but k and k - S alias the same block, so
-        # the k < S anchors cover every class in the same first-fit order
-        in_span = jnp.asarray((ii < int(span[0])) & (jj < int(span[1]))
-                              & (kk < min(int(span[2]), S)))
-        masks.append((ok & in_span).reshape(-1))
-    return jnp.stack(masks)
+    taken = jnp.einsum("v,scv->sc", (~free).astype(jnp.float32),
+                       jnp.asarray(st.member, jnp.float32))
+    return jnp.asarray(st.anchor_valid) & (taken == 0)
 
 
 def _first_fit_from_masks(masks, shape_row):
     """First-fit over a (traced) per-split shape-order row: returns
     (shape_id, origin_rank, found) — the first shape in row order with any
     valid anchor, and its smallest lexicographic anchor, exactly
-    `first_fit_block`'s (shape order, then origin lex order) semantics."""
+    `first_fit_block`'s (shape order, then origin lex order) semantics.
+    Every shape's verdict is reduced once; a candidate reads its shape's
+    by comparison with the shape ids."""
     import jax.numpy as jnp
 
-    n_cells = masks.shape[1]
+    n_shapes, n_cells = masks.shape
     big = jnp.int32(n_cells + 1)
     lex = jnp.arange(n_cells, dtype=jnp.int32)
+    # a shape's first anchor, `big` where it has none
+    rank = jnp.where(masks, lex, big).min(axis=1)          # [n_shapes]
+    is_shape = shape_row[:, None] == jnp.arange(n_shapes)  # [row, n_shapes]
+    cand_rank = jnp.where(is_shape, rank, big).min(axis=1)
+    cand_valid = cand_rank < big
 
     best_shape = jnp.int32(-1)
     best_rank = big
     found = jnp.bool_(False)
     for p in range(shape_row.shape[0]):
-        sid = shape_row[p]
-        mask = masks[jnp.clip(sid, 0)] & (sid >= 0)
-        any_valid = mask.any()
-        rank = jnp.where(mask, lex, big).min()
-        take = any_valid & ~found
-        best_shape = jnp.where(take, sid, best_shape)
-        best_rank = jnp.where(take, rank, best_rank)
-        found = found | any_valid
+        take = cand_valid[p] & ~found
+        best_shape = jnp.where(take, shape_row[p], best_shape)
+        best_rank = jnp.where(take, cand_rank[p], best_rank)
+        found = found | cand_valid[p]
     return best_shape, best_rank, found
+
+
+def _select_row(table, index, fill):
+    """``table[index]`` of a small table with rows along axis 0 — for
+    each ``index`` of any shape — as a comparison with the row numbers
+    and a max over them; an index that names no row reads ``fill``
+    (which no entry may lie under)."""
+    import jax.numpy as jnp
+
+    hit = index[..., None] == jnp.arange(table.shape[0])
+    hit = hit.reshape(hit.shape + (1,) * (table.ndim - 1))
+    return jnp.where(hit, table, fill).max(axis=index.ndim)
 
 
 def jax_allocate_job(mem, other_free, cfg, tables, st: ShapeTables,
@@ -548,7 +629,19 @@ def jax_allocate_job(mem, other_free, cfg, tables, st: ShapeTables,
     """Scan-ified `allocate_job` (agents/placers.py:103; reference
     placers/utils.py:532): walk the padded forward-op sequence in topo
     order; per op try parent co-location then the generic first-fit block
-    search; scatter memory + op->server assignments between steps.
+    search, and commit memory + the op's servers between steps.
+
+    The scan reaches servers, cells and op slots by comparison, broadcast
+    and reduction over static tables, never by an index per element (on
+    the chip each index vector of a gather or scatter is one serial
+    address computation, and under the lanes' ``vmap`` there is one a
+    lane): anchor masks from ``st.member``, a block's servers from
+    ``st.servers_of``, a parent's servers, the room check and the memory
+    commit through ``[max_split] x [n_srv]`` one-hots, and the
+    op -> server map assembled ONCE after the scan from the per-forward-op
+    record the scan carries (the tables' ``op_fwd``: block order,
+    `stack_config_tables`). tests/indexed_placer.py keeps the indexed
+    scan this replaced as the bitwise reference.
 
     ``mem`` [n_srv] free memory per server; ``other_free`` [n_srv] bool
     (True = not occupied by another job; constant during one job's
@@ -559,97 +652,74 @@ def jax_allocate_job(mem, other_free, cfg, tables, st: ShapeTables,
     import jax
     import jax.numpy as jnp
 
-    C, R, S = st.ramp_shape
-    Smax = pads.max_split
-    F, N = pads.n_fwd, pads.n_ops
+    n_cells, n_srv = st.member.shape[1:]
+    Smax, F = pads.max_split, pads.n_fwd
 
-    row_table = jnp.asarray(st.row)
-    offsets_t = jnp.asarray(st.offsets)
-    bases_t = jnp.asarray(st.bases)
-
-    f_valid = tables["f_valid"][cfg]
-    f_split = tables["f_split"][cfg]
-    f_mem = tables["f_mem"][cfg]
-    f_parents = tables["f_parents"][cfg]
-    f_sub_fwd = tables["f_sub_fwd"][cfg]
-    f_sub_bwd = tables["f_sub_bwd"][cfg]
-
+    # a block's servers by (shape, anchor cell), + 1: a one-hot row of
+    # the found anchor contracts with it to the servers + 1, and no
+    # anchor to 0 (whole numbers; HIGHEST keeps them exact past the 256
+    # a single bf16 pass holds)
+    servers_of1 = jnp.asarray(st.servers_of.reshape(-1, Smax) + 1,
+                              jnp.float32)
+    anchor = jnp.arange(servers_of1.shape[0])
     lane = jnp.arange(Smax)
+    server = jnp.arange(n_srv)
 
-    def body(carry, f):
-        (mem, op_servers, op_count, ots, ok) = carry
-        valid = f_valid[f]
-        split = f_split[f]
-        per_mem = f_mem[f]
-        parents = f_parents[f]
-        sub_fwd = f_sub_fwd[f]
-        sub_bwd = f_sub_bwd[f]
+    f_split = tables["f_split"][cfg]
+    # every forward op's candidate shapes, in find_sub_block order
+    f_shapes = _select_row(jnp.asarray(st.row), f_split, -1)
+
+    def body(carry, op):
+        (mem, op_servers, op_count, ok) = carry
+        f, valid, split, per_mem, parents, shape_row = op
+        room = mem >= per_mem
 
         # ---- parent co-location (placers.py:49-77): first parent whose
         # server count equals split and whose servers all have room
+        p_servers = _select_row(op_servers, parents, -1)    # [P, Smax]
+        p_count = _select_row(op_count, parents, 0)         # [P]
+        on_full = ((p_servers[:, :, None] == server) & ~room).any(axis=-1)
+        mem_ok = ~((lane < p_count[:, None]) & on_full).any(axis=-1)
+        okp = (parents >= 0) & (p_count > 0) & (p_count == split) & mem_ok
         colo_found = jnp.bool_(False)
         colo_servers = jnp.full((Smax,), -1, jnp.int32)
         for pi in range(parents.shape[0]):
-            p = parents[pi]
-            servers = op_servers[jnp.clip(p, 0)]
-            cnt = op_count[jnp.clip(p, 0)]
-            active = lane < cnt
-            mem_ok = jnp.all(~active
-                             | (mem[jnp.clip(servers, 0)] >= per_mem))
-            okp = (p >= 0) & (cnt > 0) & (cnt == split) & mem_ok
-            take = okp & ~colo_found
-            colo_servers = jnp.where(take, servers, colo_servers)
-            colo_found = colo_found | okp
+            take = okp[pi] & ~colo_found
+            colo_servers = jnp.where(take, p_servers[pi], colo_servers)
+            colo_found = colo_found | okp[pi]
 
         # ---- regular symmetric block search (find_sub_block order)
-        free = other_free & (mem >= per_mem)
-        masks = _anchor_masks(free, st)
-        shape_row = row_table[jnp.clip(split, 0, row_table.shape[0] - 1)]
+        masks = _anchor_masks(other_free & room, st)
         sid, rank, block_found = _first_fit_from_masks(masks, shape_row)
-
-        origin = jnp.stack([rank // (R * S), (rank // S) % R,
-                            rank % S]).astype(jnp.int32)
-        offs = offsets_t[jnp.clip(sid, 0)]              # [MAX_CELLS, 3]
-        base = bases_t[jnp.clip(sid, 0)]                # [3]
-        cells = (origin[None, :] + offs) % base[None, :]
-        block_servers = ((cells[:, 0] * R + cells[:, 1]) * S
-                         + cells[:, 2]).astype(jnp.int32)
-        if block_servers.shape[0] < Smax:
-            block_servers = jnp.pad(block_servers,
-                                    (0, Smax - block_servers.shape[0]))
-        else:
-            block_servers = block_servers[:Smax]
+        at = jnp.where(block_found, sid * n_cells + rank, -1) == anchor
+        block_servers = jnp.dot(
+            at.astype(jnp.float32), servers_of1,
+            precision=jax.lax.Precision.HIGHEST).astype(jnp.int32) - 1
 
         servers = jnp.where(colo_found, colo_servers, block_servers)
         placed_ok = colo_found | block_found
 
-        # ---- masked commit of this op's fwd+bwd sub-op pairs. Inactive
-        # lanes scatter into a trailing dummy slot so they can never
-        # collide with a real index.
+        # ---- masked commit of this op's fwd+bwd sub-op pairs: a block's
+        # servers are distinct, so at most one term lands on a server
         active = (lane < split) & placed_ok & valid & (servers >= 0)
-        srv = jnp.clip(servers, 0)
-        mem = mem - jnp.zeros_like(mem).at[srv].add(
-            jnp.where(active, per_mem, jnp.zeros_like(per_mem)))
-        idx_f = jnp.where(active & (sub_fwd >= 0), sub_fwd, N)
-        idx_b = jnp.where(active & (sub_bwd >= 0), sub_bwd, N)
-        ots = ots.at[idx_f].set(servers)
-        ots = ots.at[idx_b].set(servers)
-
-        write = valid & placed_ok
-        op_servers = jnp.where(write, op_servers.at[f].set(servers),
-                               op_servers)
-        op_count = jnp.where(write, op_count.at[f].set(split), op_count)
-        return ((mem, op_servers, op_count, ots,
-                 ok & (placed_ok | ~valid)), None)
+        lands = active[:, None] & (servers[:, None] == server)
+        mem = mem - jnp.where(lands, per_mem, 0).sum(axis=0)
+        op_servers = op_servers.at[f].set(jnp.where(active, servers, -1))
+        op_count = op_count.at[f].set(
+            jnp.where(valid & placed_ok, split, 0))
+        return (mem, op_servers, op_count, ok & (placed_ok | ~valid)), None
 
     init = (mem,
             jnp.full((F, Smax), -1, jnp.int32),
             jnp.zeros((F,), jnp.int32),
-            jnp.full((N + 1,), -1, jnp.int32),   # +1 dummy scatter slot
             jnp.bool_(True))
-    carry, _ = jax.lax.scan(body, init, jnp.arange(F, dtype=jnp.int32))
-    (new_mem, _, _, ots, ok) = carry
-    return ots[:N], new_mem, ok
+    ops = (jnp.arange(F, dtype=jnp.int32), tables["f_valid"][cfg], f_split,
+           tables["f_mem"][cfg], tables["f_parents"][cfg], f_shapes)
+    (new_mem, op_servers, _, ok), _ = jax.lax.scan(body, init, ops)
+    # shard k of an op (or of its backward mirror) sits on server k of
+    # its forward slot's block: op slot (o, k) = o * Smax + k
+    ots = _select_row(op_servers, tables["op_fwd"][cfg], -1)
+    return ots.reshape(-1), new_mem, ok
 
 
 # =========================================================================
@@ -1413,6 +1483,30 @@ def price_dep_indexed_ops(et: EpisodeTables) -> int:
             bank, carry, i32, i32)
     return len(dep_indexed_ops(traced.jaxpr,
                                et.pads.n_blocks * et.pads.max_split))
+
+
+#: start-up gauge (`allocate_indexed_ops`): 0 says the placement scan
+#: reaches servers, cells and op slots without an index per element
+ALLOCATE_GAUGE = "sim.allocate.indexed_ops"
+
+
+def allocate_indexed_ops(tables: dict, st: ShapeTables,
+                         pads: ConfigPads) -> int:
+    """How many equations of one traced `jax_allocate_job` (the scan's
+    body and the assembly after it) are gathers or scatters of at least
+    ``max_split`` index vectors — one per server of a block, the fewest
+    a per-cell, per-server or per-sub-op index issues; a row read of a
+    table (one index) does not count. Abstract trace, nothing runs."""
+    import jax
+
+    n_srv = int(np.prod(st.ramp_shape))
+    traced = jax.make_jaxpr(
+        lambda mem, other_free, cfg: jax_allocate_job(
+            mem, other_free, cfg, tables, st, pads))(
+        jax.ShapeDtypeStruct((n_srv,), tables["f_mem"].dtype),
+        jax.ShapeDtypeStruct((n_srv,), bool),
+        jax.ShapeDtypeStruct((), np.int32))
+    return len(indexed_ops(traced.jaxpr, pads.max_split))
 
 
 #: start-up gauges (`mask_rows_on_empty_cluster`), in this order

@@ -880,7 +880,8 @@ class RLEpochLoop:
     def _device_tables(self):
         """Static jitted-env tables from the template env (shared by the
         device collector and the fused epoch driver)."""
-        from ddls_tpu.sim.jax_env import (MASK_GAUGES, PRICE_GAUGE,
+        from ddls_tpu.sim.jax_env import (ALLOCATE_GAUGE, MASK_GAUGES,
+                                          PRICE_GAUGE, allocate_indexed_ops,
                                           build_episode_tables,
                                           build_obs_tables,
                                           mask_rows_on_empty_cluster,
@@ -891,6 +892,8 @@ class RLEpochLoop:
             et = build_episode_tables(env0)
             ot = build_obs_tables(env0, et)
         startup.set_gauge(PRICE_GAUGE, price_dep_indexed_ops(et))
+        startup.set_gauge(ALLOCATE_GAUGE,
+                          allocate_indexed_ops(et.tables, et.st, et.pads))
         for name, rows in zip(MASK_GAUGES,
                               mask_rows_on_empty_cluster(env0, et, ot)):
             startup.set_gauge(name, rows)

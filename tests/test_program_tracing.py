@@ -713,7 +713,8 @@ def test_build_run_leaves_each_startup_span_once(fused_dataset, tmp_path,
         assert loop.fused.num_lanes < 128
         minor = loop.fused.et.pads.max_split * loop.fused.num_lanes
         # ... and pricing and the channel / server checks of the
-        # program's `eval_cfg` index no dep
+        # program's `eval_cfg` index no dep, nor its placement scan a
+        # cell, a server or a sub-op
         # ... and of the rows the mask offers on an empty cluster the
         # allocator places every one (small synthetic jobs)
         # ... and the GNN's aggregation in the update, at the update's
@@ -732,12 +733,14 @@ def test_build_run_leaves_each_startup_span_once(fused_dataset, tmp_path,
             "sim.lookahead.minor_slots": -(-minor // 128) * 128,
             "sim.lookahead.minor_used": minor,
             "sim.price.dep_indexed_ops": 0,
+            "sim.allocate.indexed_ops": 0,
             "env.mask.rows_offered": offered,
             "env.mask.rows_placeable": offered,
             "gnn.aggregate.indexed_ops": 10,
             "gnn.aggregate.incidence_elems": incidence}
         order = list(seconds)
         assert (order.index("sim.price.dep_indexed_ops")
+                == order.index("sim.allocate.indexed_ops") - 1
                 < order.index("gnn.aggregate.indexed_ops")
                 < order.index("gnn.aggregate.incidence_elems"))
         assert set(seconds) == {n.removeprefix("startup.")
