@@ -261,11 +261,13 @@ class JobsGenerator:
                 # in ops whose FLOPs grow as S^2, from the profile's own
                 # op names and times
                 types = g.meta["op_types"]
-                for kind, core in (("full", "AttnCore"),
-                                   ("window", "WindowAttnCore")):
+                for gauge, op_type in (
+                        ("layers_full", "AttnCore"),
+                        ("layers_window", "WindowAttnCore"),
+                        ("shared_expert_layers", "SharedExpert")):
                     startup.set_gauge(
-                        f"graphs.arch.layers_{kind}.{model}",
-                        sum(t == core for t in types.values()))
+                        f"graphs.arch.{gauge}.{model}",
+                        sum(t == op_type for t in types.values()))
                 share = sum(g.compute_cost(o) for o, t in types.items()
                             if t in arch.QUADRATIC_OPS) \
                     / sum(g.compute_cost(o) for o in types)

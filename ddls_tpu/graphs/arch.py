@@ -10,30 +10,38 @@ made of is chosen per LAYER from the config's KEYS, never from its name:
   (low-rank q and kv paths), and with ``index_topk`` a learned-sparse
   core behind an indexer (:func:`_latent_sparse_attention`); absent:
   MHA/GQA (:func:`_gqa_attention`), whose core is causal over the whole
-  sequence or, on layer i of a ``hybrid_layer_pattern`` list with
-  ``pattern[i] == 1``, over a ``sliding_window`` with the ``swa_*``
-  head counts and sizes. Each part is counted where a key states it:
+  sequence or, on a window layer (:func:`window_layers`: layer i of a
+  ``hybrid_layer_pattern`` list with ``pattern[i] == 1``, or of a
+  ``layer_types`` list with ``"sliding_attention"``), over a
+  ``sliding_window`` with the ``swa_*`` head counts and sizes where the
+  config has them. Each part is counted where a key states it:
   ``v_head_dim`` a v head size beside q and k's ``head_dim``,
   ``partial_rotary_factor`` RoPE on that part of each q/k head (absent:
   the whole head), ``attention_value_scale`` scaled values,
   ``use_qk_norm`` a q/k RMSNorm, ``add_swa_attention_sink_bias`` /
   ``add_full_attention_sink_bias`` a learnable sink logit a head;
+  ``causal_core_count: half_square`` (OLMoE's ``modeling`` block, whose
+  profiles are pinned so) a full core as S x S / 2 keys, the triangle
+  without its diagonal — every other config counts S (S + 1) / 2;
 * feed-forward — dense SwiGLU at ``intermediate_size`` on layer i where
   a ``moe_layer_freq`` LIST has a 0 (a scalar or no key: on the first
-  ``first_k_dense_replace`` layers); the others route over
-  ``n_routed_experts`` / ``num_experts`` SwiGLU experts, with
-  ``n_shared_experts`` always-on ones beside them when the key gives a
-  number; ``scoring_func: sigmoid`` picks the bias-corrected sigmoid
-  router, otherwise softmax top-k;
+  ``first_k_dense_replace`` / ``num_dense_layers`` layers); the others
+  route over ``n_routed_experts`` / ``num_experts`` SwiGLU experts, with
+  ``n_shared_experts`` / ``num_shared_experts`` always-on ones beside
+  them when the key gives a number; ``scoring_func`` / ``score_func:
+  sigmoid`` picks the bias-corrected sigmoid router (under the second
+  name its selected weights are normalised where ``route_norm`` and
+  scaled where ``route_scale`` say so), otherwise softmax top-k;
 * ``num_nextn_predict_layers``: that many multi-token-prediction
   modules (the DeepSeek-V3 form) after the last layer.
 
-Three families are built today: OLMoE (full attention, softmax router:
+Four families are built today: OLMoE (full attention, softmax router:
 embedding, L x [InputNorm, QKVProj, AttnCore, OutProjResidual,
 PostAttnNorm, Router, Experts, CombineResidual], FinalNorm, LMHeadLoss),
-``glm_moe_dsa`` and ``mimo_v2_flash`` (OLMoE's 8-op layer with
+``glm_moe_dsa``, ``mimo_v2_flash`` (OLMoE's 8-op layer with
 WindowAttnCore on the window layers and DenseMLPResidual on the dense
-one; equations beside each op below, ``x`` the normed hidden state).
+one) and ``afmoe`` (Trinity: the same layer with a SharedExpert, 9 ops;
+equations beside each op below, ``x`` the normed hidden state).
 Ops are at one granularity in all: each norm, each projection, the
 indexer's projections, index score + top-k, the attention core,
 out-projection + residual, router, shared expert, expert group,
@@ -76,7 +84,8 @@ Costs are ANALYTIC, not profiled (:func:`op_costs` is the whole model):
   one ``memory_cost`` sizes all three, as for every profiled graph.
 
 FLOPs are 2 per multiply-accumulate plus the small elementwise terms
-written beside each op below; causal attention counts half of S x S.
+written beside each op below; a causal core counts the keys each query
+sees (:func:`attended_keys`).
 """
 from __future__ import annotations
 
@@ -145,6 +154,36 @@ def _leading_zeros(values: Sequence[int]) -> int:
     return next((i for i, v in enumerate(values) if v), len(values))
 
 
+#: ``layer_types`` entries and whether the layer's core is a window
+LAYER_TYPES = {"sliding_attention": True, "full_attention": False}
+
+
+def window_layers(config: dict) -> Optional[List[bool]]:
+    """Per layer of the published stack, whether its attention core
+    reads a sliding window: ``hybrid_layer_pattern`` (1 = window) or
+    ``layer_types`` (``"sliding_attention"``); None where the config has
+    neither list (every core is full). An unknown ``layer_types`` string
+    is refused, and so is a list that ``global_attn_every_n_layers``
+    (every n-th layer full, the others window) contradicts."""
+    pattern = config.get("hybrid_layer_pattern")
+    if isinstance(pattern, list):
+        return [kind == 1 for kind in pattern]
+    types = config.get("layer_types")
+    if not isinstance(types, list):
+        return None
+    unknown = sorted(set(types) - set(LAYER_TYPES))
+    if unknown:
+        raise ValueError(f"layer_types: unknown {unknown} (known: "
+                         f"{sorted(LAYER_TYPES)})")
+    window = [LAYER_TYPES[kind] for kind in types]
+    every = config.get("global_attn_every_n_layers")
+    if every and window != [(i + 1) % int(every) != 0
+                            for i in range(len(window))]:
+        raise ValueError(f"layer_types {types} disagrees with "
+                         f"global_attn_every_n_layers {every}")
+    return window
+
+
 def resolve_cut(config: dict, layers: Optional[dict] = None,
                 experts_held: Optional[int] = None) -> Dict[str, int]:
     """``{"leading_dense", "following", "experts_held"}`` with what the
@@ -153,7 +192,8 @@ def resolve_cut(config: dict, layers: Optional[dict] = None,
     if isinstance(freq, list):
         dense = _leading_zeros(freq)
     else:
-        dense = int(config.get("first_k_dense_replace") or 0)
+        dense = int(config.get("first_k_dense_replace")
+                    or config.get("num_dense_layers") or 0)
     cut = {"leading_dense": dense,
            "following": int(config["num_hidden_layers"]) - dense,
            "experts_held": int(config.get("n_routed_experts")
@@ -173,6 +213,15 @@ def resolve_cut(config: dict, layers: Optional[dict] = None,
             raise ValueError(
                 f"architecture layers: {cut} departs from moe_layer_freq "
                 f"{freq[:total]} (of {len(freq)} layers)")
+    types = config.get("layer_types")
+    if isinstance(types, list):
+        # a dense count stated beside per-layer attention kinds: the
+        # cut keeps the stack's first layers, dense ones as published
+        total = cut["leading_dense"] + cut["following"]
+        if total > len(types) or cut["leading_dense"] != min(dense, total):
+            raise ValueError(
+                f"architecture layers: {cut} departs from layer_types "
+                f"(of {len(types)} layers, the first {dense} dense)")
     return cut
 
 
@@ -214,8 +263,18 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
     k = int(config["num_experts_per_tok"])
     dense_inter = int(config["intermediate_size"])
     expert_inter = int(config.get("moe_intermediate_size") or dense_inter)
-    shared_inter = int(config.get("n_shared_experts") or 0) * expert_inter
-    sigmoid_router = config.get("scoring_func") == "sigmoid"
+    shared_inter = int(config.get("n_shared_experts")
+                       or config.get("num_shared_experts") or 0) * expert_inter
+    sigmoid_router = "sigmoid" in (config.get("scoring_func"),
+                                   config.get("score_func"))
+    # FLOPs a selected expert's weight costs the sigmoid router: the
+    # sum and the divide of s_sel / sum s_sel, then the scale. Under
+    # ``score_func`` the config states each (``route_norm``,
+    # ``route_scale``); under ``scoring_func`` all three are counted
+    routed_weight = 3
+    if "score_func" in config:
+        routed_weight = 2 * bool(config.get("route_norm")) \
+            + (config.get("route_scale") is not None)
     n_mtp = int(config.get("num_nextn_predict_layers") or 0)
     held = cut["experts_held"]
     S, B = int(seq_len), int(micro_batch)
@@ -268,12 +327,13 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
             else "add_full_attention_sink_bias") else 0
         if window:
             keys = attended_keys(S, int(config["sliding_window"]))
-        elif "v_head_dim" in config:
-            keys = attended_keys(S, S)
-        else:
-            # one head size: the causal half of S x S without its
-            # diagonal (1 / S of it), as OLMoE's pinned profiles count
+        elif config.get("causal_core_count") == "half_square":
+            # the causal half of S x S without its diagonal (1 / S of
+            # it): what OLMoE's architecture file states, its profiles
+            # being pinned so
             keys = S * S / 2
+        else:
+            keys = attended_keys(S, S)
         core = g.add("WindowAttnCore" if window else "AttnCore",
                      B * keys * n * (2 * d_qk + 2 * d_v + 5) + T * sinks,
                      A * (T * qkv + sinks + T * n * d_v),
@@ -340,10 +400,10 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
                      A * (T * heads * dv + heads * dv * H + 2 * T * H),
                      T * H, heads * dv * H, [core, stream])
 
-    pattern = config.get("hybrid_layer_pattern")
+    window = window_layers(config)
     freq = config.get("moe_layer_freq")
-    if n_mtp and isinstance(pattern, list):
-        raise ValueError("hybrid_layer_pattern gives no kind for a "
+    if n_mtp and window is not None:
+        raise ValueError("a per-layer attention list gives no kind for a "
                          "multi-token-prediction module's layer")
 
     def layer(stream, i=None):
@@ -354,7 +414,7 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
             out_proj = _latent_sparse_attention(stream)
         else:
             out_proj = _gqa_attention(
-                stream, window=isinstance(pattern, list) and pattern[i] == 1)
+                stream, window=window is not None and window[i])
         if isinstance(freq, list):
             dense = freq[i] == 0
         else:
@@ -370,7 +430,8 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
         if sigmoid_router:
             # s = sigmoid(x W_r), top-k of s + b (5 a logit), weights
             # s_sel / sum s_sel x scale (3 a selected expert)
-            router = g.add("Router", 2 * T * H * E + 5 * T * E + 3 * T * k,
+            router = g.add("Router",
+                           2 * T * H * E + 5 * T * E + routed_weight * T * k,
                            A * (T * H + H * E + E + 2 * T * k), 2 * T * k,
                            H * E + E, [x])
         else:

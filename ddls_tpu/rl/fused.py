@@ -40,7 +40,8 @@ import numpy as np
 
 from ddls_tpu import telemetry
 from ddls_tpu.demands.jobs_generator import BANK_GAUGES
-from ddls_tpu.sim.jax_env import MASK_GAUGES
+from ddls_tpu.sim.jax_env import (CAUSE_ACCEPTED, CAUSE_OP_PLACEMENT,
+                                  MASK_GAUGES)
 from ddls_tpu.sim.jax_lookahead import (MINOR_GAUGES, stage_trips,
                                         stage_widths)
 from ddls_tpu.sim.jax_memo import MemoCounters
@@ -108,12 +109,12 @@ def stacked_job_banks(et, env, n_lanes: int, n_jobs: int,
 #: decision's job type and action, from which ``record_padding_fill``
 #: finds the (model, degree) row each decision ran, and its verdict and
 #: the occupied-server count it saw (``record_decisions``). ``la_trips``,
-#: ``jtype``, ``action``, ``accepted`` and ``n_occupied`` are read only
+#: ``jtype``, ``action``, ``cause`` and ``n_occupied`` are read only
 #: while telemetry is on and are NOT gated on it (ROADMAP D13): the
 #: traced run must be the program the untraced run measures
 EPISODE_TRACE_KEYS = ("done", "ep_return", "ep_blocked", "ep_completed",
                       "ep_arrived", "la_trips", "jtype", "action",
-                      "accepted", "n_occupied")
+                      "cause", "n_occupied")
 
 def _count_startup_gauges(names) -> None:
     """Add each set start-up gauge onto the telemetry counter of its
@@ -200,7 +201,10 @@ def record_padding_fill(ep_trace, et, ot) -> None:
 def record_decisions(ep_trace, et, ot) -> None:
     """What the decisions met, from a FETCHED ``[..., B, T]`` trace:
     ``env.decisions.offered`` — decisions taken — beside
-    ``env.decisions.accepted`` — those whose job was mounted;
+    ``env.decisions.accepted`` — those whose job was mounted (``cause``
+    is ``CAUSE_ACCEPTED``) — and ``env.decisions.blocked_placement`` —
+    those whose job then failed ``op_placement`` (no run of servers with
+    the memory: what the mask offered and a memory-aware one would not);
     ``env.decisions.offered_longest`` / ``accepted_longest`` — the same
     two over the decisions on the bank's job type with the largest
     degree-1 step time (``ot["orig_seq_sum"]``);
@@ -212,11 +216,14 @@ def record_decisions(ep_trace, et, ot) -> None:
     and, where an architecture built the jobs, its
     ``demands/jobs_generator.py:BANK_GAUGES``.
     The caller gates on ``telemetry.enabled()``."""
-    accepted = np.asarray(ep_trace["accepted"])
+    cause = np.asarray(ep_trace["cause"])
+    accepted = cause == CAUSE_ACCEPTED
     longest = np.asarray(ep_trace["jtype"]) \
         == int(np.argmax(ot["orig_seq_sum"]))
     telemetry.inc("env.decisions.offered", int(accepted.size))
     telemetry.inc("env.decisions.accepted", int(accepted.sum()))
+    telemetry.inc("env.decisions.blocked_placement",
+                  int((cause == CAUSE_OP_PLACEMENT).sum()))
     telemetry.inc("env.decisions.offered_longest", int(longest.sum()))
     telemetry.inc("env.decisions.accepted_longest",
                   int(accepted[longest].sum()))

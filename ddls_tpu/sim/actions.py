@@ -361,7 +361,7 @@ def group_collectives(original_job: Job,
                 fwd_deps.extend(graph.out_edges(f_sub))
                 b_sub = partitioned_op_id(b_op, i)
                 for (u, v) in graph.in_edges(b_sub):
-                    if u in graph.successors(v):
+                    if graph.has_edge(v, u):
                         key = frozenset((u, v))
                         if key not in seen_sync:
                             seen_sync.add(key)
@@ -400,26 +400,29 @@ def build_grouping_arrays(original: Job, partitioned: Job,
 
     cand, sync, o2o = group_collectives(original, partitioned, split_fwd)
     arrays = partitioned.graph.finalize()
-    eidx, oidx = arrays["edge_index"], arrays["op_index"]
-    sizes = arrays["edge_size"]
+    eidx, sizes = arrays["edge_index"], arrays["edge_size"]
+    # a dep's endpoints as op indices
+    src, dst = arrays["edge_src"], arrays["edge_dst"]
+
+    def edge_indices(group):
+        return np.fromiter((eidx[d] for d in group), np.int64, len(group))
 
     def pack(group, is_sync):
-        e = np.fromiter((eidx[d] for d in group), np.int64, len(group))
-        u = np.fromiter((oidx[d[0]] for d in group), np.int64, len(group))
-        v = np.fromiter((oidx[d[1]] for d in group), np.int64, len(group))
+        e = edge_indices(group)
+        u, v = src[e], dst[e]
         # plain-list mirrors: groups are mostly tiny (2-edge sync pairs),
         # where Python set/sort constants beat numpy's per-call overhead
         return {"edges": e, "u": u, "v": v,
                 "u_list": u.tolist(), "v_list": v.tolist(),
                 "msg": float(sizes[e].sum()), "sync": is_sync}
 
+    o2o_edges = edge_indices(o2o)
     return {
         "groups": ([pack(g, False) for g in cand]
                    + [pack(g, True) for g in sync]),
-        "o2o_edges": np.fromiter((eidx[d] for d in o2o), np.int64,
-                                 len(o2o)),
-        "o2o_u": np.fromiter((oidx[d[0]] for d in o2o), np.int64, len(o2o)),
-        "o2o_v": np.fromiter((oidx[d[1]] for d in o2o), np.int64, len(o2o)),
+        "o2o_edges": o2o_edges,
+        "o2o_u": src[o2o_edges],
+        "o2o_v": dst[o2o_edges],
     }
 
 

@@ -1020,6 +1020,11 @@ class EpisodeTables:
     scenario: Optional[dict] = None
 
 
+#: the last workload's stacked tables (`build_episode_tables`): one
+#: entry, replaced when another workload's are built
+_STACKED_TABLES: dict = {}
+
+
 def build_episode_tables(env, max_degree: Optional[int] = None,
                          quantum: Optional[float] = None) -> EpisodeTables:
     """Assemble the static side of the jitted episode from a host env
@@ -1049,12 +1054,22 @@ def build_episode_tables(env, max_degree: Optional[int] = None,
     degrees = [d for d in range(1, max_degree + 1)
                if d == 1 or d % 2 == 0]
 
-    st = build_shape_tables(topo.shape, min(max_degree, topo.num_workers))
-    cfgs = []
-    for m in types:
-        for d in degrees:
-            cfgs.append(config_tables_for(model_graphs[m], d, quantum))
-    tables, pads = stack_config_tables(cfgs, st)
+    # the stacked tables are a function of the job graphs, the degrees,
+    # the quantum and the topology's shape alone; a process that builds
+    # them twice for one workload (a training loop's, then the fidelity
+    # replay's: `scenarios/conformance.py:jitted_decision_events`) builds
+    # them once — 26 s at 570-op graphs. Keyed by the identity the
+    # cluster's own memo caches trust (`cluster.py:_workload_fingerprint`)
+    fingerprint = getattr(gen, "workload_fingerprint", None)
+    key = (fingerprint, tuple(types), max_degree, quantum, topo.shape)
+    if fingerprint is None or _STACKED_TABLES.get("key") != key:
+        st = build_shape_tables(topo.shape,
+                                min(max_degree, topo.num_workers))
+        cfgs = [config_tables_for(model_graphs[m], d, quantum)
+                for m in types for d in degrees]
+        _STACKED_TABLES.update(
+            key=key, value=(st, *stack_config_tables(cfgs, st)))
+    st, tables, pads = _STACKED_TABLES["value"]
     jt = {k: jnp.asarray(v) for k, v in tables.items()}
 
     from ddls_tpu.envs.rewards import JobAcceptance
@@ -1229,10 +1244,12 @@ def _episode_kernels(et: EpisodeTables):
         worker grouping, mounted dep times) and served from the table on
         a bitwise full-key hit — memoised and recomputed results are
         bit-identical by construction, any precision mode. ``discard``
-        (bool) marks a lane whose result the caller will throw away: it
-        joins the memo's hit mask in the lookahead's ``skip``, so under
-        ``vmap`` such a lane runs no trips (``ev["la_trips"]`` is the
-        loop's own count: 0 for a skipped lane)."""
+        (bool) marks a lane whose result the caller will throw away, and
+        a job that did not place has no lookahead to run (the host drops
+        it before pricing): either joins the memo's hit mask in the
+        lookahead's ``skip``, so under ``vmap`` such a lane runs no trips
+        (``ev["la_trips"]`` is the loop's own count: 0 for a skipped
+        lane), and neither probes nor enters the memo."""
         (t, mem, srv_job, chan_occ, slot_valid, slot_t_done, slot_mem,
          slot_servers, slot_chan) = carry
         dt = mem.dtype
@@ -1247,14 +1264,17 @@ def _episode_kernels(et: EpisodeTables):
         op_valid = et.tables["op_valid"][cfg]
         ok_chan, chan_mask, srv_mask = placement_masks(
             ots, op_valid, pair_used, pair_is_chan, chan_occ)
+        # an unplaced job stays out of loop and memo alike: its key is
+        # that of the ops that placed, which a complete placement can
+        # share (sim/jax_memo.py, "COMPLETE placement only")
+        void = ~ok_place if discard is None else discard | ~ok_place
 
         def run_lookahead(skip=None):
             # ``skip`` is the memo probe's hit mask, threaded into the
             # lookahead while_loop cond (jax_memo.WIDE_PROBE_SURFACE) so
             # hit lanes contribute zero trips to the batched loop; a
-            # lane the caller discards is masked out the same way
-            if discard is not None:
-                skip = discard if skip is None else skip | discard
+            # void lane is masked out the same way
+            skip = void if skip is None else skip | void
             t_la, _, _, _, ok, trips = jax_lookahead(
                 et.tables["op_compute"][cfg], op_valid,
                 jnp.where(op_valid, ots, -1), op_score,
@@ -1273,7 +1293,7 @@ def _episode_kernels(et: EpisodeTables):
                 groups = jax_memo.canonical_groups(
                     jnp.where(op_valid, ots, -1), op_valid)
             (t_step, ok_la, trips), memo = jax_memo.memo_lookahead(
-                memo, cfg, groups, times, run_lookahead)
+                memo, cfg, groups, times, run_lookahead, void)
         jct = t_step * steps
         max_jct = (bank["sla_frac"][row].astype(dt)
                    * et.tables["seq_compute"][cfg].astype(dt) * steps)
@@ -1507,6 +1527,18 @@ def allocate_indexed_ops(tables: dict, st: ShapeTables,
         jax.ShapeDtypeStruct((n_srv,), bool),
         jax.ShapeDtypeStruct((), np.int32))
     return len(indexed_ops(traced.jaxpr, pads.max_split))
+
+
+def ragged_forward_ops(et: EpisodeTables) -> Dict[str, int]:
+    """Per job type, the forward ops that the SiP-ML rule splits fewer
+    ways than the top degree asks for (an op under that many quanta), in
+    the type's top-degree row: what makes the row's blocks ragged."""
+    f_split = np.asarray(et.tables["f_split"])
+    f_valid = np.asarray(et.tables["f_valid"])
+    n, top = len(et.degrees), et.degrees[-1]
+    rows = {model: (i + 1) * n - 1 for i, model in enumerate(et.types)}
+    return {model: int((f_valid[row] & (f_split[row] < top)).sum())
+            for model, row in rows.items()}
 
 
 #: start-up gauges (`mask_rows_on_empty_cluster`), in this order
@@ -1956,9 +1988,10 @@ def make_segment_fn(et: EpisodeTables, ot: dict, model, n_steps: int,
     that runs no lookahead). A program counter, not a simulation result
     — the collectors that drain it (``EPISODE_TRACE_KEYS``) ask for it;
     the host reduces it into the ``sim.lookahead.*`` telemetry counters
-    (`rl/fused.py:record_lookahead_trips`). With it rides ``accepted``
-    (bool), the decision's verdict, which with the ``n_occupied`` field
-    the decision saw becomes ``env.decisions.*`` / ``env.cluster.*``
+    (`rl/fused.py:record_lookahead_trips`). With it rides ``cause``
+    (i32, a ``CAUSE_*`` code), the decision's verdict (accepted where it
+    is ``CAUSE_ACCEPTED``), which with the ``n_occupied`` field the
+    decision saw becomes ``env.decisions.*`` / ``env.cluster.*``
     (`rl/fused.py:record_decisions`).
     """
     import jax
@@ -2053,7 +2086,7 @@ def make_segment_fn(et: EpisodeTables, ot: dict, model, n_steps: int,
                 out["obs"] = obs
             if trace_trips:
                 out["la_trips"] = la_trips
-                out["accepted"] = accept
+                out["cause"] = cause
             if memo is not None:
                 out.update(jax_memo.memo_trace_counters(memo))
             return (state4, memo), out

@@ -39,6 +39,11 @@ ranks monotonically and cancel in every comparison the engine makes.
 Hash collisions cannot break any of this: the probe compares the FULL
 key residual bitwise (u32 bit patterns, so ``-0.0``/NaN can only miss,
 never alias), so a collision is a miss, never a wrong entry.
+All of it holds for a COMPLETE placement only: the key is made of the
+ops that placed, so a placement that stopped two ops short has the key
+of the complete one that puts those two on a server of their own, and
+a lookahead of it ends stuck. The host never looks ahead at a job it
+could not place; here such a probe is ``void`` (:func:`memo_lookahead`).
 
 Bitwise-hit guarantee: a hit serves a value previously computed by the
 SAME compiled ``jax_lookahead`` on bit-identical inputs, so memo-on and
@@ -200,11 +205,15 @@ def _bits(x):
 
 
 def memo_lookahead(memo: dict, cfg, groups, times,
-                   compute: Callable[..., Tuple]):
+                   compute: Callable[..., Tuple], void=None):
     """Probe-or-compute one lookahead under the memo key (cfg, groups,
     times); returns ``((t, ok, *extra), memo')`` — whatever ``compute``
     returns beyond ``(t, ok)`` (the loop's trip count, 0 on a masked
-    hit lane) passes through untouched.
+    hit lane) passes through untouched. ``void`` (bool, optional) marks
+    a probe whose key does not determine the engine's inputs (a job
+    that did not place: the key holds the placed ops alone) or whose
+    result nobody reads: it counts as neither hit nor miss and inserts
+    nothing; what it returns is the caller's to throw away.
 
     Probe (batched — the wide-vmap form, ISSUE 17): hash the key onto a
     set, compare the FULL residual bitwise against every way, gather the
@@ -244,6 +253,9 @@ def memo_lookahead(memo: dict, cfg, groups, times,
               & jnp.all(_bits(way_times) == _bits(times)[None],
                         axis=tuple(range(1, _bits(way_times).ndim))))
         hit = eq.any()
+        miss = ~hit
+        if void is not None:
+            hit, miss = hit & ~void, miss & ~void
         way_hit = jnp.argmax(eq).astype(jnp.int32)
 
     # batched gather/mask/select: serve the hit value from the table,
@@ -263,7 +275,6 @@ def memo_lookahead(memo: dict, cfg, groups, times,
         # on the hit path only in the sense that it rewrites identical
         # state)
         way_ins = memo["rr"][set_idx] % jnp.int32(W)
-        miss = ~hit
         evict = miss & (memo["key_cfg"][set_idx, way_ins] >= 0)
 
         def upd(arr, val):

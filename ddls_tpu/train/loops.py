@@ -885,7 +885,8 @@ class RLEpochLoop:
                                           build_episode_tables,
                                           build_obs_tables,
                                           mask_rows_on_empty_cluster,
-                                          price_dep_indexed_ops)
+                                          price_dep_indexed_ops,
+                                          ragged_forward_ops)
 
         env0 = self.vec_env.envs[0]
         with startup.span("startup.device_tables"):
@@ -897,6 +898,11 @@ class RLEpochLoop:
         for name, rows in zip(MASK_GAUGES,
                               mask_rows_on_empty_cluster(env0, et, ot)):
             startup.set_gauge(name, rows)
+        # beside the gauges an architecture job source set for the model
+        for model, ragged in ragged_forward_ops(et).items():
+            if startup.registry().gauge(
+                    f"graphs.arch.forward_ops.{model}").value is not None:
+                startup.set_gauge(f"graphs.arch.ragged_ops.{model}", ragged)
         return env0, et, ot
 
     def _device_bank_size(self, env0) -> int:

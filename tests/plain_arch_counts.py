@@ -1,8 +1,9 @@
-"""Independent plain counts of a ``glm_moe_dsa`` and of a
-``mimo_v2_flash`` training step: per op the parameters, the forward
-FLOPs and the elements of the output tensor, written straight from the
-layer equations (ISSUE 30 and ISSUE 32, Tentpole step 1) and importing
-nothing from ``ddls_tpu/graphs/arch.py``, which
+"""Independent plain counts of a ``glm_moe_dsa``, of a
+``mimo_v2_flash`` and of an ``afmoe`` (Trinity) training step: per op
+the parameters, the forward FLOPs and the elements of the output tensor
+(for ``afmoe`` also the bytes moved and the edges), written straight
+from the layer equations (ISSUE 30, 32 and 36, Tentpole step 1) and
+importing nothing from ``ddls_tpu/graphs/arch.py``, which
 ``tests/test_arch_graphs.py`` holds to them op by op.
 
 Conventions: 2 FLOPs a multiply-accumulate; RMSNorm 4 an element; RoPE 3
@@ -183,3 +184,80 @@ def plain_counts_mimo(c, S, B, layers=None, held=None):
     ops += [_rmsnorm("FinalNorm", T, H),
             ("LMHeadLoss", H * V, 2 * T * H * V + 5 * T * V, T * V)]
     return ops
+
+
+# ================================================================= afmoe
+def plain_counts_trinity(c, S, B):
+    """``(ops, edges)`` of a WHOLE ``afmoe`` model (Trinity-Mini: nothing
+    is cut) over B sequences of S tokens. ``ops``: ``[(op_type,
+    parameters, forward FLOPs, output elements, bytes moved)]`` in the
+    profile's order; ``edges``: ``{(producer, consumer)}`` by 1-based
+    position in ``ops``, one for every tensor an op reads from another.
+
+    Layer i (x the normed stream, T = S B tokens, H = hidden_size, n q
+    heads and g kv heads of d): ``[q ; k ; v] = x W_qkv`` (H -> n d +
+    2 g d), RoPE on the whole of each q and k head; ``o_t = sum_s
+    softmax_s(q_t . k_s / sqrt(d)) v_s`` over the min(t, sliding_window)
+    latest keys where ``layer_types[i]`` is ``sliding_attention`` and
+    over all t keys where it is ``full_attention``; ``y = o W_o +
+    stream``. The first ``num_dense_layers`` layers then run SwiGLU at
+    ``intermediate_size``; the others ``s = sigmoid(x W_r)`` (H ->
+    ``num_experts``), the top ``num_experts_per_tok`` of ``s + b``,
+    weights ``s_sel / sum s_sel x route_scale``, a shared SwiGLU at
+    ``num_shared_experts x moe_intermediate_size`` on every token, the
+    experts' SwiGLU at ``moe_intermediate_size`` over the T k balanced
+    token-expert pairs, and their weighted sum + the shared output +
+    the stream. Bytes: every tensor and weight read or written once at
+    2 B an element (token ids 4 B)."""
+    T, H, V = S * B, c["hidden_size"], c["vocab_size"]
+    n, g, d = (c["num_attention_heads"], c["num_key_value_heads"],
+               c["head_dim"])
+    E, k = c["num_experts"], c["num_experts_per_tok"]
+    I, Ie = c["intermediate_size"], c["moe_intermediate_size"]
+    Is = c["num_shared_experts"] * Ie
+    width = n * d + 2 * g * d
+    pairs = T * k
+    ops, edges = [], set()
+
+    def add(kind, params, flops, out, nbytes, reads=()):
+        ops.append((kind, params, flops, out, nbytes))
+        edges.update((r, len(ops)) for r in reads)
+        return len(ops)
+
+    def rmsnorm(kind, reads):
+        return add(kind, H, 4 * T * H, T * H, 2 * (2 * T * H + H), reads)
+
+    stream = add("Embedding", V * H, 0, T * H, 2 * 2 * T * H + 4 * T)
+    for i, kind in enumerate(c["layer_types"]):
+        x = rmsnorm("InputNorm", [stream])
+        qkv = add("QKVProj", H * width,
+                  2 * T * H * width + 3 * T * (n + g) * d, T * width,
+                  2 * (T * H + H * width + T * width), [x])
+        if kind == "sliding_attention":
+            keys, core = keys_read(S, c["sliding_window"]), "WindowAttnCore"
+        else:
+            keys, core = S * (S + 1) // 2, "AttnCore"
+        o = add(core, 0, B * keys * n * (2 * d + 2 * d + 5), T * n * d,
+                2 * (T * width + T * n * d), [qkv])
+        y = add("OutProjResidual", n * d * H, 2 * T * n * d * H + T * H,
+                T * H, 2 * (T * n * d + n * d * H + 2 * T * H),
+                [o, stream])
+        x = rmsnorm("PostAttnNorm", [y])
+        if i < c["num_dense_layers"]:
+            stream = add("DenseMLPResidual", 3 * H * I,
+                         2 * T * 3 * H * I + 4 * T * I + T * H, T * H,
+                         2 * (3 * T * H + 3 * H * I), [x, y])
+            continue
+        r = add("Router", H * E + E, 2 * T * H * E + 5 * T * E + 3 * T * k,
+                2 * T * k, 2 * (T * H + H * E + E + 2 * T * k), [x])
+        sh = add("SharedExpert", 3 * H * Is, 2 * T * 3 * H * Is + 4 * T * Is,
+                 T * H, 2 * (2 * T * H + 3 * H * Is), [x])
+        ex = add("Experts", E * 3 * H * Ie,
+                 2 * pairs * 3 * H * Ie + 4 * pairs * Ie, pairs * H,
+                 2 * (2 * pairs * H + min(E, pairs) * 3 * H * Ie), [r, x])
+        stream = add("CombineResidual", 0, 2 * pairs * H + 2 * T * H, T * H,
+                     2 * (pairs * H + pairs + 3 * T * H), [ex, y, r, sh])
+    f = rmsnorm("FinalNorm", [stream])
+    add("LMHeadLoss", H * V, 2 * T * H * V + 5 * T * V, T * V,
+        2 * (T * H + H * V + T * V), [f])
+    return ops, edges

@@ -266,7 +266,8 @@ def test_generator_takes_an_architecture_like_any_other_source(tmp_path):
             if "_bytes" not in k and k not in shares} == {
         f"graphs.arch.{what}.{m}": n for m in models
         for what, n in (("forward_ops", 19), ("edges", 53),
-                        ("layers_full", 2), ("layers_window", 0))}
+                        ("layers_full", 2), ("layers_window", 0),
+                        ("shared_expert_layers", 0))}
     assert sorted(shares) == sorted(
         [BANK_GAUGES[0]]
         + [f"graphs.arch.quadratic_time_share.{m}" for m in models])
@@ -1128,7 +1129,8 @@ def _kind_gauges(arch_file, shapes, **cut):
     kinds = {}
     for name, value in gauges.items():
         what, _, model = name[len("graphs.arch."):].partition(".")
-        if what in ("layers_full", "layers_window", "quadratic_time_share"):
+        if what in ("layers_full", "layers_window", "quadratic_time_share",
+                    "shared_expert_layers"):
             kinds.setdefault(model, {})[what] = value
     return kinds
 
@@ -1315,31 +1317,516 @@ def test_generator_sets_the_layer_kind_gauges(tmp_path):
 
 
 def test_decisions_on_the_longest_job_type_are_counted():
-    """`record_decisions` reduces the drained ``jtype`` / ``accepted``
-    traces into `env.decisions.offered_longest` / `accepted_longest`:
-    the decisions on the type with the largest degree-1 step time."""
+    """`record_decisions` reduces the drained ``jtype`` / ``cause``
+    traces into `env.decisions.offered_longest` / `accepted_longest`
+    (the decisions on the type with the largest degree-1 step time) and
+    `env.decisions.blocked_placement` (those that failed
+    ``op_placement``); a decision is accepted where its cause is
+    ``CAUSE_ACCEPTED``."""
     import types
 
     from ddls_tpu import telemetry
     from ddls_tpu.rl.fused import record_decisions
+    from ddls_tpu.sim import jax_env
 
+    A, N, P, S = (jax_env.CAUSE_ACCEPTED, jax_env.CAUSE_NOT_HANDLED,
+                  jax_env.CAUSE_OP_PLACEMENT, jax_env.CAUSE_SLA)
     et = types.SimpleNamespace(n_srv=32)
     ot = {"orig_seq_sum": np.array([3.0, 87.4, 9.6])}
     trace = {"jtype": np.array([[1, 0, 1], [2, 1, 0]]),
-             "accepted": np.array([[1, 1, 0], [0, 1, 1]]),
+             "cause": np.array([[A, A, P], [S, A, A]]),
              "n_occupied": np.array([[0, 4, 8], [8, 8, 12]])}
     was = telemetry.enabled()
     telemetry.enable()
     telemetry.reset()
     try:
         record_decisions(trace, et, ot)
-        counters = telemetry.snapshot()["counters"]
+        counters = dict(telemetry.snapshot()["counters"])
+        telemetry.reset()
+        record_decisions({**trace, "cause": np.array([[P, N, P],
+                                                      [P, S, N]])}, et, ot)
+        blocked = dict(telemetry.snapshot()["counters"])
     finally:
         telemetry.reset()
         if not was:
             telemetry.disable()
     assert counters["env.decisions.offered"] == 6
     assert counters["env.decisions.accepted"] == 4
+    assert counters["env.decisions.blocked_placement"] == 1
     assert counters["env.decisions.offered_longest"] == 3
     assert counters["env.decisions.accepted_longest"] == 2
     assert counters["env.cluster.servers"] == 6 * 32
+    assert (blocked["env.decisions.accepted"],
+            blocked["env.decisions.blocked_placement"]) == (0, 3)
+
+
+# ================================================================= afmoe
+TRINITY_FILE = "ddls_tpu/graphs/arch_configs/trinity_mini.json"
+TRINITY_SHAPES = [(8192, 4), (32768, 1), (65536, 1), (131072, 1)]
+#: 4 layers (attention S S S F by ``layer_types``; feed-forward D E E E by
+#: ``num_dense_layers``), hidden 64, 4 q / 1 kv heads of 16, window 16,
+#: 8 experts (2 a token) beside 1 shared, under afmoe's key names
+TINY_TRINITY = {"model_type": "tinyafmoe", "hidden_size": 64,
+                "num_attention_heads": 4, "num_key_value_heads": 1,
+                "head_dim": 16, "sliding_window": 16,
+                "layer_types": ["sliding_attention"] * 3
+                + ["full_attention"],
+                "global_attn_every_n_layers": 4, "num_dense_layers": 1,
+                "intermediate_size": 128, "moe_intermediate_size": 32,
+                "num_experts": 8, "num_shared_experts": 1,
+                "num_experts_per_tok": 2, "num_hidden_layers": 4,
+                "score_func": "sigmoid", "route_norm": True,
+                "route_scale": 2.826, "vocab_size": 256}
+
+
+@pytest.fixture(scope="module")
+def trinity():
+    return arch.load_arch_config(TRINITY_FILE)
+
+
+def _tiny_trinity_arch_file(directory) -> str:
+    path = os.path.join(str(directory), "tinyafmoe.json")
+    with open(path, "w") as fh:
+        json.dump({"source_url": "test-local", "training_state": STATE,
+                   "config": TINY_TRINITY}, fh)
+    return path
+
+
+@pytest.mark.parametrize("case", ["tiny_s8", "tiny_s200", "trinity_8k",
+                                  "trinity_128k"])
+def test_trinity_op_costs_equal_the_plain_count_op_by_op(trinity, case):
+    """`plain_counts_trinity` is written from the equations and imports
+    nothing of the builder: op names, parameters, FLOPs, output
+    elements, bytes moved and the edge set agree, below and above the
+    window, at two tiny and two real shapes — nothing is cut."""
+    from plain_arch_counts import plain_counts_trinity
+
+    config, seq_len, micro_batch = {
+        "tiny_s8": (TINY_TRINITY, 8, 3), "tiny_s200": (TINY_TRINITY, 200, 2),
+        "trinity_8k": (trinity, 8192, 4),
+        "trinity_128k": (trinity, 131072, 1)}[case]
+    built = arch.build_graph(config, seq_len, micro_batch)
+    plain, edges = plain_counts_trinity(config, seq_len, micro_batch)
+    assert [o["op_type"] for o in built.ops] == [p[0] for p in plain]
+    for i, (o, (kind, params, flops, out, nbytes)) in enumerate(
+            zip(built.ops, plain)):
+        assert o["params"] == params, (i, kind)
+        assert o["flops"] == pytest.approx(flops, rel=1e-12), (i, kind)
+        assert o["out_elems"] == out, (i, kind)
+        assert o["bytes"] == pytest.approx(nbytes, rel=1e-12), (i, kind)
+    assert set(built.edges) == edges and len(built.edges) == len(edges)
+
+
+@pytest.mark.parametrize("quantity", ["parameters", "active_parameters"])
+def test_trinity_whole_is_the_published_model(trinity, quantity):
+    """All 32 layers, 128 experts and the whole vocabulary: 25.855 B
+    parameters from the config's keys alone (published 26 B), 3.2 B
+    active a token (published A3B)."""
+    whole = arch.op_costs(trinity, 4096, 1)
+    total = sum(o["params"] for o in whole)
+    assert arch.resolve_cut(trinity) == {
+        "leading_dense": 2, "following": 30, "experts_held": 128}
+    if quantity == "parameters":
+        # embedding, two 6-op dense layers, 30 9-op expert layers, norm,
+        # head
+        assert len(whole) == 1 + 2 * 6 + 30 * 9 + 2 == 285
+        assert total == 25_855_399_680
+        assert total == pytest.approx(25.855e9, rel=2e-5)
+    else:
+        idle = (trinity["num_experts"] - trinity["num_experts_per_tok"]) \
+            * 3 * trinity["hidden_size"] * trinity["moe_intermediate_size"] \
+            * 30
+        assert total - idle == pytest.approx(3.2e9, rel=5e-3)
+
+
+def test_layer_types_give_the_kinds_in_order_and_no_dangling_edge(
+        trinity, tmp_path):
+    """24 `WindowAttnCore` + 8 `AttnCore` in S S S F order, 2
+    `DenseMLPResidual` then 30 x (`Router`, `SharedExpert`, `Experts`,
+    `CombineResidual`); every op but the first has a producer and every
+    op but the last a consumer; a stated graph of 586.0 GB."""
+    family = arch.load_arch_file(TRINITY_FILE)
+    assert family["training_state"] == STATE and "modeling" not in family
+    path, = arch.write_profiles(
+        str(tmp_path), trinity, [{"seq_len": 8192, "micro_batch": 4}],
+        training_state=STATE)
+    assert os.path.basename(path) == "afmoe_s8192_b4.txt"
+    nodes, edges = _parse_pipedream_txt(path)
+    kinds = [n["op_type"] for n in nodes.values()]
+
+    def layer(core, feed_forward):
+        return ["InputNorm", "QKVProj", core, "OutProjResidual",
+                "PostAttnNorm", *feed_forward]
+
+    expert = ["Router", "SharedExpert", "Experts", "CombineResidual"]
+    cores = ["WindowAttnCore", "WindowAttnCore", "WindowAttnCore",
+             "AttnCore"] * 8
+    assert [k for k in kinds if k.endswith("AttnCore")] == cores
+    assert kinds == (["Embedding"]
+                     + sum((layer(core, ["DenseMLPResidual"] if i < 2
+                                  else expert)
+                            for i, core in enumerate(cores)), [])
+                     + ["FinalNorm", "LMHeadLoss"])
+    assert len(kinds) == 285 and len(edges) == 438
+    ids = set(nodes)
+    assert {v for _, v in edges} == ids - {"1"}
+    assert {u for u, _ in edges} == ids - {"285"}
+    graph = read_graph_file(path)
+    assert (len(graph.forward_op_ids()), graph.n_ops, graph.n_deps) \
+        == (285, 570, 877)
+    assert sum(n["parameter"] for n in nodes.values()) \
+        == pytest.approx(413.7e9, rel=1e-4)
+    assert sum(graph.memory_cost(o) for o in graph.op_ids) \
+        == pytest.approx(586.0e9, rel=1e-4)
+    # no q/k norm, sink, value scale or partial rotary: none is stated
+    proj = next(o for o in arch.op_costs(trinity, 8192, 4)
+                if o["op_type"] == "QKVProj")
+    T, H, width = 32768, 2048, 32 * 128 + 2 * 4 * 128
+    assert proj["params"] == H * width
+    assert proj["flops"] == 2 * T * H * width + 3 * T * 36 * 128
+
+
+@pytest.mark.parametrize("seq_len", [2047, 2048, 2049, 131072])
+def test_trinity_window_core_against_brute_force(trinity, seq_len):
+    """A sliding layer's query reads min(t, 2048) keys, a full layer's
+    all t: the closed form against the sum itself, at the window's edge
+    and at the longest context."""
+    w = trinity["sliding_window"]
+    assert w == 2048
+    keys = sum(min(t, w) for t in range(1, seq_len + 1))
+    assert arch.attended_keys(seq_len, w) == keys
+    costs = arch.op_costs(trinity, seq_len, 1)
+    window = next(o for o in costs if o["op_type"] == "WindowAttnCore")
+    full = next(o for o in costs if o["op_type"] == "AttnCore")
+    per_pair = 32 * (4 * 128 + 5)
+    assert window["flops"] == keys * per_pair and window["params"] == 0
+    assert full["flops"] == seq_len * (seq_len + 1) // 2 * per_pair
+    assert (window["flops"] < full["flops"]) == (seq_len > w)
+
+
+@pytest.mark.parametrize("case", [
+    "unknown_string", "every_n_mismatch", "dense_count", "too_deep",
+    "prefix", "mtp"])
+def test_afmoe_keys_are_checked_against_each_other(trinity, case):
+    """`layer_types` is the per-layer list: an unknown string, a list
+    `global_attn_every_n_layers` contradicts, and a cut that departs
+    from the list or from `num_dense_layers` are refused."""
+    if case == "unknown_string":
+        types = ["sliding_attention", "linear_attention"] * 16
+        with pytest.raises(ValueError, match="linear_attention"):
+            arch.op_costs({**trinity, "layer_types": types}, 64, 1)
+    elif case == "every_n_mismatch":
+        for every in (3, 8):
+            with pytest.raises(ValueError,
+                               match="global_attn_every_n_layers"):
+                arch.op_costs({**trinity,
+                               "global_attn_every_n_layers": every}, 64, 1)
+        # without the key the list stands alone
+        config = {k: v for k, v in trinity.items()
+                  if k != "global_attn_every_n_layers"}
+        assert arch.op_costs(config, 64, 1) == arch.op_costs(trinity, 64, 1)
+    elif case == "dense_count":
+        for layers in ({"leading_dense": 0, "following": 8},
+                       {"leading_dense": 3, "following": 5}):
+            with pytest.raises(ValueError, match="layer_types"):
+                arch.resolve_cut(trinity, layers)
+    elif case == "too_deep":
+        with pytest.raises(ValueError, match="layer_types"):
+            arch.resolve_cut(trinity, {"leading_dense": 2, "following": 31})
+    elif case == "prefix":
+        # the stack's first layers, in the list's order: one whole period
+        cut = {"leading_dense": 2, "following": 2}
+        assert arch.resolve_cut(trinity, cut)["following"] == 2
+        kinds = [o["op_type"]
+                 for o in arch.op_costs(trinity, 64, 1, layers=cut)]
+        assert [k for k in kinds if k.endswith("AttnCore")] == [
+            "WindowAttnCore"] * 3 + ["AttnCore"]
+        assert (kinds.count("DenseMLPResidual"), kinds.count("Experts")) \
+            == (2, 2)
+    else:
+        with pytest.raises(ValueError, match="multi-token-prediction"):
+            arch.op_costs({**trinity, "num_nextn_predict_layers": 1}, 64, 1)
+
+
+@pytest.mark.parametrize("family", ["trinity", "olmoe", "olmoe_published",
+                                    "mimo"])
+def test_only_a_stated_half_square_drops_the_diagonal(trinity, olmoe, mimo,
+                                                      family):
+    """The one fork in `_gqa_attention` is keyed on what OLMoE's
+    architecture file states (`modeling.causal_core_count`), not on the
+    absence of `v_head_dim`: Trinity has one head size too and counts
+    the diagonal, as MiMo does; OLMoE's published keys alone would."""
+    S = 4096
+    published = arch.load_arch_file(OLMOE_FILE)["config"]
+    assert olmoe["causal_core_count"] == "half_square"
+    assert "causal_core_count" not in published
+    for config in (trinity, published):
+        assert "v_head_dim" not in config
+    config, heads, per_pair = {
+        "trinity": (trinity, 32, 4 * 128 + 5),
+        "olmoe": (olmoe, 16, 4 * 128 + 5),
+        "olmoe_published": (published, 16, 4 * 128 + 5),
+        "mimo": (mimo, 64, 2 * 192 + 2 * 128 + 5)}[family]
+    core = next(o for o in arch.op_costs(config, S, 1)
+                if o["op_type"] == "AttnCore")
+    keys = S * S / 2 if family == "olmoe" else S * (S + 1) // 2
+    assert core["flops"] == keys * heads * per_pair
+
+
+def test_router_weight_flops_follow_route_norm_and_route_scale(trinity):
+    """Under `score_func` the sigmoid router's per-selected-expert work
+    is what the config states: 2 for `route_norm`, 1 for `route_scale`;
+    `scoring_func` configs (GLM-5, MiMo: pinned) count all three."""
+    T, H, E, k = 64, 2048, 128, 8
+    base = 2 * T * H * E + 5 * T * E
+
+    def router(config):
+        return next(o for o in arch.op_costs(config, T, 1)
+                    if o["op_type"] == "Router")
+
+    assert router(trinity)["flops"] == base + 3 * T * k
+    assert router(trinity)["params"] == H * E + E
+    assert router({**trinity, "route_norm": False})["flops"] \
+        == base + T * k
+    assert router({**trinity, "route_scale": None})["flops"] \
+        == base + 2 * T * k
+    renamed = {k_: v for k_, v in trinity.items()
+               if k_ not in ("score_func", "route_norm", "route_scale")}
+    assert router({**renamed, "scoring_func": "sigmoid"})["flops"] \
+        == base + 3 * T * k
+    # neither name: the softmax router, no selection bias
+    assert router(renamed)["params"] == H * E
+
+
+TRINITY_SHA256 = {
+    (8192, 4):
+        "31cd86399c1e6a53f4099c6ece8596e3f027e10174e5e6bd24d795588a7cb8f0",
+    (32768, 1):
+        "d09d8fc5fd9cf136f4bcdb1070ba8745438b3ec3a82b7ccc8fc433257993156e",
+    (65536, 1):
+        "e4cf6a995a44a5ab0756d6db6c1c4375e6794bc8fb5a4da14414dcc4f5e45dad",
+    (131072, 1):
+        "5d15e437b33d882df9a99d771193362b292b7eee7c2e2fab2b10c599b089107a"}
+
+
+@pytest.mark.parametrize("shape", TRINITY_SHAPES)
+def test_trinity_profiles_are_pinned(shape):
+    """The four profiles of `trinity_ramp32.train_fused`, byte for
+    byte: a later change to a shared count shows here."""
+    import hashlib
+
+    family = arch.load_arch_file(TRINITY_FILE)
+    text = arch.profile_text(arch.builder_config(family), *shape,
+                             training_state=family["training_state"])
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == TRINITY_SHA256[shape]
+
+
+def test_trinity_env_yaml_states_what_its_comments_derive(trinity):
+    """env_trinity_32.yaml: NO cut, the shapes as data, the arrival gap
+    and horizon derived from the builder's graph, env_olmoe32's pads
+    rule, ragged rows in the two 32,768-token shapes, and nothing else
+    changed from env_mimo_32."""
+    import math
+
+    from ddls_tpu.config import load_config
+
+    def env(name):
+        return load_config(
+            os.path.join(REPO, "scripts/ramp_job_partitioning_configs"),
+            "rllib_config", [f"env_config={name}"])["env_config"]
+
+    cfg, base = env("env_trinity_32"), env("env_mimo_32")
+    jobs = cfg["jobs_config"]
+    family = jobs["architecture"]
+    assert set(family) == {"config", "shapes"}      # whole: no cut keys
+    assert family["config"] == TRINITY_FILE
+    shapes = family["shapes"]
+    assert [(s["seq_len"], s["micro_batch"]) for s in shapes] \
+        == TRINITY_SHAPES
+    assert shapes[-1]["seq_len"] == trinity["max_position_embeddings"]
+    steps = jobs["num_training_steps"]
+    lengths = [steps * (1 + arch.BACKWARD_OVER_FORWARD) * sum(
+        arch.forward_time(c) for c in arch.op_costs(trinity, **s))
+        for s in shapes]
+    assert lengths == pytest.approx([105.435, 131.184, 328.342, 919.429],
+                                    abs=1e-3)
+    gap = np.mean(lengths) / 25
+    two_figures = round(gap, 1 - int(math.floor(math.log10(gap))))
+    assert jobs["job_interarrival_time_dist"]["val"] == two_figures == 15
+    assert cfg["max_simulation_run_time"] == pytest.approx(400 * two_figures)
+    assert cfg["pad_obs_kwargs"] == {"max_nodes": 50 * -(-570 // 50),
+                                     "max_edges": 256 * -(-877 // 256)}
+    # 13 quanta: the norms, routers and embedding of a 32,768-token step
+    # split 14 ways at degree 16; every op of the longer shapes is over
+    # 16 quanta
+    quantum, top = cfg["min_op_run_time_quantum"], cfg[
+        "max_partitions_per_op"]
+    ragged = [sorted(o["op_type"] for o in arch.op_costs(trinity, **s)
+                     if arch.forward_time(o) < top * quantum)
+              for s in shapes]
+    assert ragged[0] == ragged[1] == sorted(
+        ["InputNorm"] * 32 + ["PostAttnNorm"] * 32 + ["Router"] * 30
+        + ["Embedding", "FinalNorm"])
+    assert ragged[2] == ragged[3] == []
+    assert {int(arch.forward_time(o) / quantum)
+            for o in arch.op_costs(trinity, **shapes[1])
+            if arch.forward_time(o) < top * quantum} == {13}
+    # a job's memory: parameter state + 2 x activations
+    state = 16 * sum(o["params"] for o in arch.op_costs(trinity, 64, 1))
+    jobs_gb = [(state + 2 * arch.ACT_BYTES * sum(
+        o["out_elems"] for o in arch.op_costs(trinity, **s))) / 1e9
+        for s in shapes]
+    assert jobs_gb == pytest.approx([586.0, 586.0, 758.3, 1103.0], abs=0.05)
+    # the rest is env_mimo_32's
+    changed = {"jobs_config", "max_simulation_run_time", "pad_obs_kwargs"}
+    assert {k: v for k, v in cfg.items() if k not in changed} \
+        == {k: v for k, v in base.items() if k not in changed}
+    for key in set(jobs) - {"architecture", "job_interarrival_time_dist"}:
+        assert jobs[key] == base["jobs_config"][key], key
+
+
+#: steps of ~0.32 s with every op over 8 quanta, and a shape whose ops
+#: are 9-50 us: ragged rows at every degree above 1
+TINY_TRINITY_SHAPES = [{"seq_len": 32, "micro_batch": 2 ** 19},
+                       {"seq_len": 32, "micro_batch": 4096}]
+
+
+def _tiny_trinity_env(arch_file, **over):
+    """The whole tiny model (no cut) on env_trinity_32's cluster."""
+    jobs = dict(
+        architecture={"config": arch_file, "shapes": TINY_TRINITY_SHAPES},
+        job_interarrival_time_dist={
+            "_target_": "ddls_tpu.demands.distributions.Fixed", "val": 0.4},
+        max_acceptable_job_completion_time_frac_dist={
+            "_target_": "ddls_tpu.demands.distributions.Uniform",
+            "min_val": 0.1, "max_val": 1.0, "decimals": 2},
+        replication_factor=10, job_sampling_mode="remove_and_repeat",
+        shuffle_files=True, num_training_steps=20)
+    over.setdefault("max_partitions_per_op", 8)
+    return _tiny_env(arch_file, jobs_config=jobs,
+                     max_simulation_run_time=16.0,
+                     pad_obs_kwargs={"max_nodes": 100, "max_edges": 192},
+                     **over)
+
+
+#: beside the verdict, the accepted decisions that ran a RAGGED row (the
+#: 4,096-sequence shape above degree 1) while an earlier job still ran
+TRINITY_DRIVER = EPISODE_DRIVER.replace(
+    "t._tiny_env(", "t._tiny_trinity_env(").replace(
+    'print(json.dumps({{', '''model = {{e["job_idx"]: e["model"] for e in events
+         if e["kind"] == "job_arrived"}}
+ends, ragged_loaded = [], 0
+for e in host:
+    if e["accepted"]:
+        ragged_loaded += (model[e["job_idx"]].endswith("_b4096")
+                          and e["degree"] > 1
+                          and any(end > e["t"] for end in ends))
+        ends.append(e["t"] + e["jct"])
+print(json.dumps({{
+    "ragged_loaded": int(ragged_loaded),''')
+
+
+@pytest.mark.parametrize("x64,rtol", [(True, 1e-9), (False, 1e-4)],
+                         ids=["x64_1e-9", "f32_1e-4"])
+def test_trinity_job_in_kernel_replays_the_host_oracle(tmp_path, x64, rtol):
+    """A tiny STATED afmoe job family, WHOLE (attention S S S F;
+    feed-forward D E E E with a shared expert), through reader ->
+    mirror -> Job -> the jitted episode kernel against the float64
+    Python oracle: accepted and cause exactly, JCT to the tolerance,
+    with a ragged row accepted on a cluster that holds a running job."""
+    from ddls_tpu.sim.jax_env import build_partition_action
+
+    arch_file = _tiny_trinity_arch_file(tmp_path)
+    path = arch.write_profiles(str(tmp_path / "p"), TINY_TRINITY,
+                               TINY_TRINITY_SHAPES[1:],
+                               training_state=STATE)[0]
+    splits = set(build_partition_action(read_graph_file(path), QUANTUM,
+                                        8).values())
+    assert len(splits) > 1 and max(splits) < 8, splits      # ragged
+    driver = TRINITY_DRIVER.format(
+        repo=REPO, tests=os.path.join(REPO, "tests"),
+        benchmarks=os.path.join(REPO, "tests", "benchmarks"),
+        arch_file=arch_file, seed=5, x64=x64, rtol=rtol)
+    out = subprocess.run(
+        [sys.executable, "-c", driver], capture_output=True, text=True,
+        timeout=900, env={**os.environ, "JAX_PLATFORMS": "cpu",
+                          "JAX_ENABLE_X64": "1" if x64 else "0"})
+    assert out.returncode == 0, out.stderr[-3000:]
+    verdict = json.loads(out.stdout.strip().splitlines()[-1])
+    assert verdict["mismatch"] is None, verdict
+    assert verdict["decisions"] == 24
+    assert 0 < verdict["accepted"] < 24, verdict
+    assert verdict["ragged_loaded"] >= 1, verdict
+    assert len(verdict["causes"]) >= 2, verdict
+
+
+def test_generator_and_tables_set_the_trinity_gauges(tmp_path):
+    """`graphs.arch.shared_expert_layers.<model>` from the profile's op
+    names (`jobs_generator`), and `graphs.arch.ragged_ops.<model>` from
+    the top-degree row's splits (`sim/jax_env.py:ragged_forward_ops`,
+    set by `train/loops.py:_device_tables` beside them)."""
+    from ddls_tpu.sim.jax_env import (build_episode_tables,
+                                      ragged_forward_ops)
+    from ddls_tpu.telemetry import startup
+
+    startup.registry().reset()
+    env = _tiny_trinity_env(_tiny_trinity_arch_file(tmp_path))
+    env.reset(seed=0)
+    gauges = startup.gauges()
+    models = ("tinyafmoe_s32_b4096", "tinyafmoe_s32_b524288")
+    for m in models:
+        assert gauges[f"graphs.arch.shared_expert_layers.{m}"] == 3
+        assert gauges[f"graphs.arch.layers_full.{m}"] == 1
+        assert gauges[f"graphs.arch.layers_window.{m}"] == 3
+        assert gauges[f"graphs.arch.forward_ops.{m}"] == 36
+    et = build_episode_tables(env)
+    # every op of the short shape is under 8 quanta, none of the long
+    assert ragged_forward_ops(et) == {models[0]: 36, models[1]: 0}
+    startup.registry().reset()
+    # GLM-5 has a shared expert a layer too, MiMo none
+    glm = _kind_gauges(GLM_FILE, [(8192, 1)], **GLM_CUT)
+    assert glm["glm_moe_dsa_s8192_b1"]["shared_expert_layers"] == 4 + 1
+    mimo = _kind_gauges(MIMO_FILE, MIMO_SHAPES[:1], **MIMO_CUT)
+    assert mimo["mimo_v2_flash_s8192_b4"]["shared_expert_layers"] == 0
+
+
+def test_stacked_tables_are_built_once_a_workload(tmp_path, monkeypatch):
+    """`build_episode_tables` keeps the last workload's stacked tables:
+    a second env of the same job source, degrees, quantum and topology
+    (the fidelity replay's, after the training loop's) reuses them, and
+    another quantum or another job source builds anew."""
+    from ddls_tpu.sim import jax_env
+
+    arch_file = _tiny_trinity_arch_file(tmp_path)
+    calls = []
+    real = jax_env.config_tables_for
+    monkeypatch.setattr(
+        jax_env, "config_tables_for",
+        lambda graph, degree, quantum: calls.append(degree)
+        or real(graph, degree, quantum))
+
+    def tables(**over):
+        env = _tiny_trinity_env(arch_file, **over)
+        env.reset(seed=0)
+        return jax_env.build_episode_tables(env)
+
+    jax_env._STACKED_TABLES.clear()
+    first = tables()
+    rows = len(first.types) * len(first.degrees)
+    assert len(calls) == rows == 2 * 5
+    again = tables()
+    assert len(calls) == rows                       # nothing rebuilt
+    assert again.pads == first.pads
+    for name, value in first.tables.items():
+        assert np.array_equal(np.asarray(value),
+                              np.asarray(again.tables[name])), name
+    tables(min_op_run_time_quantum=2 * QUANTUM)     # another quantum
+    assert len(calls) == 2 * rows
+    tables(max_partitions_per_op=4)                 # other degrees
+    assert len(calls) == 2 * rows + 2 * 3
+    env = _tiny_env(_tiny_arch_file(tmp_path))      # another job source
+    env.reset(seed=0)
+    other = jax_env.build_episode_tables(env)
+    assert other.types != first.types
+    assert len(calls) == 2 * rows + 2 * 3 + 2 * 9

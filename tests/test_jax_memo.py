@@ -54,7 +54,7 @@ def _key(seed, n_ops=4, n_deps=6):
     return jnp.int32(0), groups, times
 
 
-def _probe(memo, key, value):
+def _probe(memo, key, value, ok=True, void=None):
     from ddls_tpu.sim.jax_memo import memo_lookahead
 
     import jax.numpy as jnp
@@ -63,8 +63,34 @@ def _probe(memo, key, value):
     # caller threads into jax_lookahead's while_loop cond); a plain
     # value ignores it
     (t, ok), memo = memo_lookahead(
-        memo, *key, lambda skip: (jnp.float32(value), jnp.bool_(True)))
+        memo, *key, lambda skip: (jnp.float32(value), jnp.bool_(ok)),
+        None if void is None else jnp.bool_(void))
     return float(t), memo
+
+
+@pytest.mark.parametrize("void_first", [True, False])
+def test_a_void_probe_neither_counts_nor_enters_the_table(void_first):
+    """A job that did not place probes under the key of the ops that
+    DID place, which a later complete placement can share: its "stuck"
+    must not be what that one is served (PR 36: Trinity-Mini's head on a
+    ninth server, 8,192 x 4 at degree 1)."""
+    from ddls_tpu.sim.jax_memo import MemoConfig, memo_init
+
+    memo = memo_init(_EtStub(), MemoConfig(n_sets=1, n_ways=1))
+    a = _key(1)
+    if void_first:
+        before = {k: np.asarray(v) for k, v in memo.items()}
+        _, memo = _probe(memo, a, 0.0, ok=False, void=True)
+        for k in before:
+            assert np.array_equal(before[k], np.asarray(memo[k])), k
+    t, memo = _probe(memo, a, 1.5, void=False)
+    assert t == 1.5 and int(memo["misses"]) == 1
+    # resident now: a void probe of the same key changes nothing either
+    _, memo = _probe(memo, a, 0.0, ok=False, void=True)
+    assert (int(memo["hits"]), int(memo["misses"]),
+            int(memo["evicts"])) == (0, 1, 0)
+    t, memo = _probe(memo, a, 9.5, void=False)
+    assert t == 1.5 and int(memo["hits"]) == 1
 
 
 def test_forced_hash_collision_recomputes_never_serves_colliding_entry():

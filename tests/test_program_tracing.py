@@ -105,12 +105,8 @@ def test_traced_trips_are_each_lanes_own_loop_count(memo_env, n_lanes):
                                       own[lane])
 
 
-def test_a_discarded_lane_runs_no_trips(memo_env):
-    """Under vmap the decision's ``cond`` is a select and every lane
-    runs the heavy branch; a lane whose action takes the zero path is
-    masked out of the lookahead loop (``skip``), so the loop's own count
-    — BEFORE the select — is 0 there, and what the batched loop runs is
-    the maximum over lanes whose result is used."""
+def _one_lane_kernels(memo_env):
+    """(et, kernels, lane 0's bank, its initial carry, job row 0)."""
     import jax
     import jax.numpy as jnp
 
@@ -120,7 +116,19 @@ def test_a_discarded_lane_runs_no_trips(memo_env):
     k = _episode_kernels(et)
     bank = jax.tree_util.tree_map(
         lambda x: x[0], test_jax_memo._lane_banks(memo_env, 1))
-    carry, row = k.init_state(bank)[0], jnp.int32(0)
+    return et, k, bank, k.init_state(bank)[0], jnp.int32(0)
+
+
+def test_a_discarded_lane_runs_no_trips(memo_env):
+    """Under vmap the decision's ``cond`` is a select and every lane
+    runs the heavy branch; a lane whose action takes the zero path is
+    masked out of the lookahead loop (``skip``), so the loop's own count
+    — BEFORE the select — is 0 there, and what the batched loop runs is
+    the maximum over lanes whose result is used."""
+    import jax
+    import jax.numpy as jnp
+
+    et, k, bank, carry, row = _one_lane_kernels(memo_env)
     n_deg = len(et.degrees)
     cfg = bank["type"][row] * n_deg + (n_deg - 1)   # the largest degree
 
@@ -137,6 +145,77 @@ def test_a_discarded_lane_runs_no_trips(memo_env):
     la = np.asarray(jax.jit(jax.vmap(
         lambda a: k.decision(bank, carry, a, row)[1][4]))(actions))
     assert la.tolist() == trips.tolist()
+
+
+def test_an_unplaced_job_runs_no_trips_and_stays_out_of_the_memo(memo_env):
+    """The host drops a job it could not place before any lookahead; in
+    the kernel such a lane is masked out of the loop and its probe is
+    void (`sim/jax_memo.py`): the table and its counters are untouched,
+    and the same job on an empty cluster then misses and enters."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddls_tpu.sim import jax_memo
+
+    et, k, bank, carry, row = _one_lane_kernels(memo_env)
+    cfg = bank["type"][row] * len(et.degrees)        # degree 1
+    memo0 = jax_memo.memo_init(et, jax_memo.MemoConfig(2, 1))
+
+    @jax.jit
+    def probe(mem, memo):
+        ev, memo = k.eval_cfg(bank, (carry[0], mem) + carry[2:], row, cfg,
+                              memo)
+        return ev["ok_place"], ev["la_trips"], memo
+
+    ok, trips, memo = probe(jnp.zeros_like(carry[1]), memo0)
+    assert not bool(ok) and int(trips) == 0
+    for key in memo0:
+        assert np.array_equal(np.asarray(memo0[key]),
+                              np.asarray(memo[key])), key
+    ok, trips, memo = probe(carry[1], memo)
+    assert bool(ok) and int(trips) > 0
+    assert (int(memo["hits"]), int(memo["misses"])) == (0, 1)
+
+
+@pytest.mark.parametrize("jtype", [0, 1])
+def test_a_placement_that_stops_short_never_answers_the_complete_one(
+        memo_env, jtype):
+    """Servers with room for one large op each: on m of them the
+    degree-1 job stops ops short, on m + 1 it places — and the ops that
+    placed are the SAME groups with the same dep times, so both probe
+    one memo key. The short one's lookahead would end stuck; stored, it
+    answered the complete one with ``engine_ok`` False (PR 36: the
+    benchmark's replay of 570-op jobs met it at decision 20 of its
+    twelfth seed)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddls_tpu.sim import jax_memo
+
+    et, k, bank, carry, row = _one_lane_kernels(memo_env)
+    cfg = jnp.int32(jtype * len(et.degrees))         # degree 1
+    f_mem = np.asarray(et.tables["f_mem"])[int(cfg)]
+    unit = float(f_mem[np.asarray(et.tables["f_valid"])[int(cfg)]].max())
+    memo0 = jax_memo.memo_init(et, jax_memo.MemoConfig(2, 1))
+
+    @jax.jit
+    def probe(n_servers, memo):
+        mem = jnp.where(jnp.arange(et.n_srv) < n_servers, unit, 0.0)
+        ev, memo = k.eval_cfg(
+            bank, (carry[0], mem.astype(carry[1].dtype)) + carry[2:], row,
+            cfg, memo)
+        return (ev["ok_place"], ev["engine_ok"], ev["jct"]), memo
+
+    enough = next(m for m in range(1, et.n_srv + 1)
+                  if bool(probe(m, memo0)[0][0]))
+    assert enough > 1, "the job must need more than one such server"
+    (placed, _, _), after_short = probe(enough - 1, memo0)
+    assert not bool(placed)
+    got, memo = probe(enough, after_short)
+    want, _ = probe(enough, memo0)
+    assert bool(got[0]) and bool(got[1]), "served the short one's stuck"
+    assert float(got[2]) == float(want[2])
+    assert (int(memo["hits"]), int(memo["misses"])) == (0, 1)
 
 
 #: the degree-16 pads of the shipped dataset (tests/test_jax_pricing.py
@@ -576,6 +655,7 @@ def test_listed_counters_are_the_parents_on_the_same_trace(
     from ddls_tpu.rl.fused import (record_decisions,
                                    record_lookahead_trips,
                                    record_padding_fill)
+    from ddls_tpu.sim.jax_env import CAUSE_ACCEPTED, CAUSE_OP_PLACEMENT
     from ddls_tpu.sim.jax_lookahead import stage_trips, stage_widths
 
     et, ot = warm_fused_loop.fused.et, warm_fused_loop.fused.ot
@@ -584,7 +664,7 @@ def test_listed_counters_are_the_parents_on_the_same_trace(
     ep = {"la_trips": rng.integers(0, 40, shape).astype(np.int32),
           "jtype": rng.integers(0, len(et.types), shape).astype(np.int32),
           "action": rng.choice(et.degrees, shape).astype(np.int32),
-          "accepted": rng.integers(0, 2, shape).astype(np.int32),
+          "cause": rng.integers(0, 6, shape).astype(np.int32),
           "n_occupied": rng.integers(0, et.n_srv, shape).astype(np.int32)}
     ep["la_trips"][rng.random(shape) < 0.4] = 0
     telemetry.enable()
@@ -601,6 +681,7 @@ def test_listed_counters_are_the_parents_on_the_same_trace(
     column[et.degrees] = np.arange(len(et.degrees))
     row = ep["jtype"][ran] * len(et.degrees) + column[ep["action"][ran]]
     longest = ep["jtype"] == int(np.argmax(ot["orig_seq_sum"]))
+    accepted = ep["cause"] == CAUSE_ACCEPTED
     parent = {
         "sim.lookahead.trips": int(own.sum()),
         "sim.lookahead.lockstep_trips": int(own.max(axis=1).sum()),
@@ -616,13 +697,14 @@ def test_listed_counters_are_the_parents_on_the_same_trace(
             int(ot["node_split"][:, 0][ep["jtype"]].sum()),
         "env.obs.nodes_padded":
             ep["jtype"].size * int(ot["node_features"].shape[1]),
-        "env.decisions.offered": ep["accepted"].size,
-        "env.decisions.accepted": int(ep["accepted"].sum()),
+        "env.decisions.offered": accepted.size,
+        "env.decisions.accepted": int(accepted.sum()),
+        "env.decisions.blocked_placement":
+            int((ep["cause"] == CAUSE_OP_PLACEMENT).sum()),
         "env.decisions.offered_longest": int(longest.sum()),
-        "env.decisions.accepted_longest":
-            int(ep["accepted"][longest].sum()),
+        "env.decisions.accepted_longest": int(accepted[longest].sum()),
         "env.cluster.occupied_servers": int(ep["n_occupied"].sum()),
-        "env.cluster.servers": ep["accepted"].size * et.n_srv,
+        "env.cluster.servers": accepted.size * et.n_srv,
     }
     assert {k: counters[k] for k in parent} == parent
     # what is left of the listed names are start-up gauges counted once
