@@ -1735,6 +1735,57 @@ def build_obs_tables(env, et: EpisodeTables) -> dict:
     }
 
 
+#: the per-type arrays of ``build_obs_tables`` padded along their axis 1
+#: (the node rows first, then the three per-edge arrays)
+OBS_PAD_KEYS = ("node_features", "edge_features", "edges_src", "edges_dst")
+
+#: start-up gauges, in this order: the (node, edge) pads the device
+#: tables carry, then the pads the env's ``pad_obs_kwargs`` configure
+OBS_PAD_GAUGES = ("env.obs.node_pad", "env.obs.edge_pad",
+                  "env.obs.node_pad_configured",
+                  "env.obs.edge_pad_configured")
+
+
+def obs_pads(ot: dict) -> Tuple[int, int]:
+    """The (node, edge) pad a set of observation tables carries."""
+    return (int(ot["node_features"].shape[1]),
+            int(ot["edge_features"].shape[1]))
+
+
+def fit_obs_tables(ot: dict) -> dict:
+    """``build_obs_tables``' rows on the smallest rung of the package's
+    halving ladder (`serve/bucketing.py:default_buckets`: pad, pad / 2,
+    pad / 4, both axes together) that holds the bank's LARGEST graph —
+    ``max(node_split)`` x ``max(edge_split)``, static numpy, known before
+    anything is traced. The pad is a container (RLlib's fixed-shape
+    observations, envs/obs.py); no parameter's shape depends on it, real
+    rows come first and the rest is zeros, so cutting the four padded
+    arrays along axis 1 IS `envs/obs.py:pad_obs_to` at the rung, bit
+    for bit, and the programs fed by the tables (collect, forward, PPO
+    update) stop computing rows that are masked to zero afterwards.
+
+    A ladder and not a tight fit: a bank whose largest graph fits no
+    rung under its pad gets ``ot`` back — the SAME arrays, so the same
+    lowered program and the same trajectories as without the fit. No
+    option: the rule reads the tables."""
+    from ddls_tpu.serve.bucketing import default_buckets
+
+    n_max = int(np.max(ot["node_split"]))
+    e_max = int(np.max(ot["edge_split"]))
+    pads = obs_pads(ot)
+    rung = next((n, e) for n, e in default_buckets(*pads)
+                if n >= n_max and e >= e_max)
+    if rung == pads:
+        return ot
+    n, e = rung
+    fitted = dict(ot)
+    fitted["node_features"] = np.ascontiguousarray(
+        ot["node_features"][:, :n])
+    for key in OBS_PAD_KEYS[1:]:
+        fitted[key] = np.ascontiguousarray(ot[key][:, :e])
+    return fitted
+
+
 def _kernel_action_mask(ot: dict, et: EpisodeTables, n_occupied):
     """The obs action mask (envs/obs.py:action_is_valid) from occupancy:
     0 always; 1 needs a free worker; even a needs a <= free workers AND

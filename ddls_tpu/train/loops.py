@@ -772,7 +772,7 @@ class RLEpochLoop:
                 f"the mesh's dp axis ({dp})")
 
         env0, et, ot = self._device_tables()
-        self._set_aggregate_gauges(lanes * segment_len)
+        self._set_aggregate_gauges(lanes * segment_len, ot)
         sh_fn = getattr(self.learner, "_state_shardings", None)
         state_shardings = (sh_fn(self.state) if sh_fn is not None
                            else getattr(self.learner, "_replicated",
@@ -784,21 +784,24 @@ class RLEpochLoop:
             state_shardings=state_shardings, mesh=self.mesh,
             memo_cfg=self._memo_knob())
 
-    def _set_aggregate_gauges(self, batch: int) -> None:
+    def _set_aggregate_gauges(self, batch: int, ot) -> None:
         """The start-up gauges of the GNN's aggregation in the update
         (`models/policy.py:aggregate_gauges`), at the update's
-        minibatch of the template env's padded observation."""
+        minibatch of the observation the device tables ``ot`` carry:
+        the template env's, its padded arrays at the tables' pads."""
         import jax
 
         from ddls_tpu.models.policy import (AGGREGATE_GAUGES,
                                             aggregate_gauges)
+        from ddls_tpu.sim.jax_env import OBS_PAD_KEYS
 
         cfg = getattr(self.learner, "cfg", None)
         minibatch = min(int(getattr(cfg, "sgd_minibatch_size", batch)),
                         batch)
-        obs = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct((minibatch,) + x.shape, x.dtype),
-            self.vec_env.obs[0])
+        obs = {key: jax.ShapeDtypeStruct(
+            (minibatch,) + (ot[key].shape[1:] if key in OBS_PAD_KEYS
+                            else x.shape), x.dtype)
+            for key, x in self.vec_env.obs[0].items()}
         for name, value in zip(AGGREGATE_GAUGES, aggregate_gauges(
                 self.apply_fn, self.params, obs)):
             startup.set_gauge(name, value)
@@ -881,17 +884,22 @@ class RLEpochLoop:
         """Static jitted-env tables from the template env (shared by the
         device collector and the fused epoch driver)."""
         from ddls_tpu.sim.jax_env import (ALLOCATE_GAUGE, MASK_GAUGES,
-                                          PRICE_GAUGE, allocate_indexed_ops,
+                                          OBS_PAD_GAUGES, PRICE_GAUGE,
+                                          allocate_indexed_ops,
                                           build_episode_tables,
-                                          build_obs_tables,
+                                          build_obs_tables, fit_obs_tables,
                                           mask_rows_on_empty_cluster,
-                                          price_dep_indexed_ops,
+                                          obs_pads, price_dep_indexed_ops,
                                           ragged_forward_ops)
 
         env0 = self.vec_env.envs[0]
         with startup.span("startup.device_tables"):
             et = build_episode_tables(env0)
-            ot = build_obs_tables(env0, et)
+            configured = build_obs_tables(env0, et)
+            ot = fit_obs_tables(configured)
+        for name, pad in zip(OBS_PAD_GAUGES,
+                             obs_pads(ot) + obs_pads(configured)):
+            startup.set_gauge(name, pad)
         startup.set_gauge(PRICE_GAUGE, price_dep_indexed_ops(et))
         startup.set_gauge(ALLOCATE_GAUGE,
                           allocate_indexed_ops(et.tables, et.st, et.pads))

@@ -730,6 +730,7 @@ def test_build_run_leaves_each_startup_span_once(fused_dataset, tmp_path,
     import train_from_config
 
     from ddls_tpu.config import load_config
+    from ddls_tpu.sim import jax_env
     from ddls_tpu.train.compat import apply_reference_compat
 
     sys.path.insert(0, os.path.join(REPO, "tests", "benchmarks"))
@@ -799,14 +800,20 @@ def test_build_run_leaves_each_startup_span_once(fused_dataset, tmp_path,
         # cell, a server or a sub-op
         # ... and of the rows the mask offers on an empty cluster the
         # allocator places every one (small synthetic jobs)
+        # ... and the pads the device tables carry — the rung of the
+        # halving ladder that holds the largest synthetic graph — beside
+        # the env's configured ones
         # ... and the GNN's aggregation in the update, at the update's
-        # minibatch of the padded observation: the index form on a CPU
-        # (6 scatter-adds + 4 gathers of >= B*E indices)
+        # minibatch of the observation the tables carry: the index form
+        # on a CPU (6 scatter-adds + 4 gathers of >= B*E indices)
         gauges = startup.gauges()
         obs0 = loop.vec_env.obs[0]
+        configured = (obs0["node_features"].shape[0],
+                      obs0["edge_features"].shape[0])
+        carried = jax_env.obs_pads(loop.fused.ot)
+        assert (configured, carried) == ((150, 512), (38, 128))
         minibatch = min(loop.learner.cfg.sgd_minibatch_size, 4 * 2)
-        incidence = (minibatch * obs0["node_features"].shape[0]
-                     * obs0["edge_features"].shape[0])
+        incidence = minibatch * carried[0] * carried[1]
         offered = gauges["env.mask.rows_offered"]
         assert offered == len(loop.fused.et.types) * sum(
             bool(loop.fused.ot["shapes_exist"][d]) or d == 1
@@ -818,6 +825,7 @@ def test_build_run_leaves_each_startup_span_once(fused_dataset, tmp_path,
             "sim.allocate.indexed_ops": 0,
             "env.mask.rows_offered": offered,
             "env.mask.rows_placeable": offered,
+            **dict(zip(jax_env.OBS_PAD_GAUGES, carried + configured)),
             "gnn.aggregate.indexed_ops": 10,
             "gnn.aggregate.incidence_elems": incidence}
         order = list(seconds)
