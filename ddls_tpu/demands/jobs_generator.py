@@ -257,22 +257,30 @@ class JobsGenerator:
                     f"graphs.arch.sync_bytes_max.{model}",
                     max(g.sync_size(o) for o in ops
                         if not g.is_forward(o)))
-                # layer kinds, and the share of a degree-1 forward pass
-                # in ops whose FLOPs grow as S^2, from the profile's own
-                # op names and times
+                # layer kinds, and the shares of a degree-1 forward pass
+                # in ops whose FLOPs grow as S^2 and in the linear
+                # cores, from the profile's own op names and times
                 types = g.meta["op_types"]
                 for gauge, op_type in (
                         ("layers_full", "AttnCore"),
                         ("layers_window", "WindowAttnCore"),
+                        ("layers_linear", "LinearAttnCore"),
+                        ("layers_block_sparse", "BlockSparseAttnCore"),
                         ("shared_expert_layers", "SharedExpert")):
                     startup.set_gauge(
                         f"graphs.arch.{gauge}.{model}",
                         sum(t == op_type for t in types.values()))
-                share = sum(g.compute_cost(o) for o, t in types.items()
-                            if t in arch.QUADRATIC_OPS) \
-                    / sum(g.compute_cost(o) for o in types)
+
+                def time_share(op_types):
+                    return sum(g.compute_cost(o) for o, t in types.items()
+                               if t in op_types) \
+                        / sum(g.compute_cost(o) for o in types)
+
+                share = time_share(arch.QUADRATIC_OPS)
                 startup.set_gauge(
                     f"graphs.arch.quadratic_time_share.{model}", share)
+                startup.set_gauge(f"graphs.arch.linear_time_share.{model}",
+                                  time_share(("LinearAttnCore",)))
                 shares.append(share)
             # the bank's mean share is the first over the second
             for name, value in zip(BANK_GAUGES, (sum(shares), len(shares))):

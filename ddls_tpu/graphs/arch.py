@@ -8,9 +8,17 @@ made of is chosen per LAYER from the config's KEYS, never from its name:
 
 * attention — ``kv_lora_rank`` present: multi-head latent attention
   (low-rank q and kv paths), and with ``index_topk`` a learned-sparse
-  core behind an indexer (:func:`_latent_sparse_attention`); absent:
-  MHA/GQA (:func:`_gqa_attention`), whose core is causal over the whole
-  sequence or, on a window layer (:func:`window_layers`: layer i of a
+  core behind an indexer (:func:`_latent_sparse_attention`); layer i of
+  a ``mixer_types`` list with ``"lightning-attn"``: linear attention
+  (:func:`_linear_attention`: a d x d state a head scanned in chunks of
+  ``lightning_chunk_size``, cost linear in the sequence, at the
+  ``lightning_*`` sizes); otherwise MHA/GQA (:func:`_gqa_attention`),
+  whose core is causal over the whole sequence, or — where the config
+  has a ``sparse_config`` and the sequence is longer than its
+  ``dense_len`` — block-sparse behind compressed-key scores (``KCompress``,
+  ``BlockScoreTopK``, ``BlockSparseAttnCore``: the JOB'S GRAPH THEN
+  DEPENDS ON ITS SEQUENCE LENGTH), or, on a window layer
+  (:func:`window_layers`: layer i of a
   ``hybrid_layer_pattern`` list with ``pattern[i] == 1``, or of a
   ``layer_types`` list with ``"sliding_attention"``), over a
   ``sliding_window`` with the ``swa_*`` head counts and sizes where the
@@ -18,12 +26,17 @@ made of is chosen per LAYER from the config's KEYS, never from its name:
   ``v_head_dim`` a v head size beside q and k's ``head_dim``,
   ``partial_rotary_factor`` RoPE on that part of each q/k head (absent:
   the whole head), ``attention_value_scale`` scaled values,
-  ``use_qk_norm`` a q/k RMSNorm, ``add_swa_attention_sink_bias`` /
+  ``use_qk_norm`` / ``qk_norm`` a q/k RMSNorm, ``attn_use_rope: false``
+  no RoPE, ``attn_use_output_gate`` (the linear mixer:
+  ``use_output_gate``) a ``GateProj`` whose sigmoid gates the core's
+  output, ``use_output_norm`` an RMSNorm on the linear core's output,
+  ``add_swa_attention_sink_bias`` /
   ``add_full_attention_sink_bias`` a learnable sink logit a head;
   ``causal_core_count: half_square`` (OLMoE's ``modeling`` block, whose
   profiles are pinned so) a full core as S x S / 2 keys, the triangle
   without its diagonal — every other config counts S (S + 1) / 2;
-* feed-forward — dense SwiGLU at ``intermediate_size`` on layer i where
+* feed-forward — dense SwiGLU at ``intermediate_size`` on every layer
+  of a config with NO expert key (a dense model), else on layer i where
   a ``moe_layer_freq`` LIST has a 0 (a scalar or no key: on the first
   ``first_k_dense_replace`` / ``num_dense_layers`` layers); the others
   route over ``n_routed_experts`` / ``num_experts`` SwiGLU experts, with
@@ -33,17 +46,25 @@ made of is chosen per LAYER from the config's KEYS, never from its name:
   name its selected weights are normalised where ``route_norm`` and
   scaled where ``route_scale`` say so), otherwise softmax top-k;
 * ``num_nextn_predict_layers``: that many multi-token-prediction
-  modules (the DeepSeek-V3 form) after the last layer.
+  modules (the DeepSeek-V3 form) after the last layer;
+* muP scalars, elementwise where a key states them: ``scale_emb`` 1 an
+  embedding element, ``scale_depth`` (/ sqrt(layers)) 1 an element of
+  each residual branch, ``dim_model_base`` (hidden_size / it) 1 an
+  element the head reads.
 
-Four families are built today: OLMoE (full attention, softmax router:
+Five families are built today: OLMoE (full attention, softmax router:
 embedding, L x [InputNorm, QKVProj, AttnCore, OutProjResidual,
 PostAttnNorm, Router, Experts, CombineResidual], FinalNorm, LMHeadLoss),
 ``glm_moe_dsa``, ``mimo_v2_flash`` (OLMoE's 8-op layer with
 WindowAttnCore on the window layers and DenseMLPResidual on the dense
-one) and ``afmoe`` (Trinity: the same layer with a SharedExpert, 9 ops;
-equations beside each op below, ``x`` the normed hidden state).
+one) and ``afmoe`` (Trinity: the same layer with a SharedExpert, 9 ops) and
+``minicpm_sala`` (dense: [InputNorm, QKVProj, GateProj, LinearAttnCore
+or AttnCore or the three sparse ops, OutProjResidual, PostAttnNorm,
+DenseMLPResidual], 7 or 9 ops; equations beside each op below, ``x``
+the normed hidden state).
 Ops are at one granularity in all: each norm, each projection, the
-indexer's projections, index score + top-k, the attention core,
+indexer's projections, index score + top-k, key compression, block
+score + top-k, the gate's projection, the attention core,
 out-projection + residual, router, shared expert, expert group,
 combine + residual; there is an edge for every true data dependency
 and no other.
@@ -150,12 +171,40 @@ def attended_keys(seq_len: int, limit: int) -> int:
     return K * (K + 1) // 2 + (S - K) * K
 
 
+def compressed_keys(seq_len: int, kernel_size: int,
+                    kernel_stride: int) -> int:
+    """Compressed keys a causal block score reads over one sequence:
+    key j is the mean of tokens j stride + 1 .. j stride + kernel and
+    query t (1-based) sees it once t >= j stride + kernel, so it sees
+    (t - kernel) // stride + 1 of them from t = kernel on."""
+    span = int(seq_len) - int(kernel_size)
+    if span < 0:
+        return 0
+    whole, rest = divmod(span, int(kernel_stride))
+    return int(kernel_stride) * whole * (whole - 1) // 2 \
+        + whole * (rest + 1) + span + 1
+
+
 def _leading_zeros(values: Sequence[int]) -> int:
     return next((i for i, v in enumerate(values) if v), len(values))
 
 
 #: ``layer_types`` entries and whether the layer's core is a window
 LAYER_TYPES = {"sliding_attention": True, "full_attention": False}
+
+
+def _layer_kinds(config: dict, key: str, known: dict) -> Optional[list]:
+    """``known``'s value for each entry of the config's per-layer list
+    ``key``; None where the config has no such list. An unknown string
+    is refused."""
+    types = config.get(key)
+    if not isinstance(types, list):
+        return None
+    unknown = sorted(set(types) - set(known))
+    if unknown:
+        raise ValueError(f"{key}: unknown {unknown} (known: "
+                         f"{sorted(known)})")
+    return [known[kind] for kind in types]
 
 
 def window_layers(config: dict) -> Optional[List[bool]]:
@@ -168,43 +217,66 @@ def window_layers(config: dict) -> Optional[List[bool]]:
     pattern = config.get("hybrid_layer_pattern")
     if isinstance(pattern, list):
         return [kind == 1 for kind in pattern]
-    types = config.get("layer_types")
-    if not isinstance(types, list):
-        return None
-    unknown = sorted(set(types) - set(LAYER_TYPES))
-    if unknown:
-        raise ValueError(f"layer_types: unknown {unknown} (known: "
-                         f"{sorted(LAYER_TYPES)})")
-    window = [LAYER_TYPES[kind] for kind in types]
+    window = _layer_kinds(config, "layer_types", LAYER_TYPES)
     every = config.get("global_attn_every_n_layers")
-    if every and window != [(i + 1) % int(every) != 0
-                            for i in range(len(window))]:
-        raise ValueError(f"layer_types {types} disagrees with "
-                         f"global_attn_every_n_layers {every}")
+    if window is not None and every and window != [
+            (i + 1) % int(every) != 0 for i in range(len(window))]:
+        raise ValueError(f"layer_types {config['layer_types']} disagrees "
+                         f"with global_attn_every_n_layers {every}")
     return window
+
+
+#: ``mixer_types`` entries and whether the layer's mixer is the linear
+#: kind (else softmax GQA, full or block-sparse by the sequence length)
+MIXER_TYPES = {"lightning-attn": True, "minicpm4": False}
+
+
+def linear_layers(config: dict) -> Optional[List[bool]]:
+    """Per layer of the published stack, whether its mixer is linear
+    attention: ``mixer_types`` (``"lightning-attn"``); None where the
+    config has no such list. An unknown string is refused."""
+    return _layer_kinds(config, "mixer_types", MIXER_TYPES)
+
+
+def routed_experts(config: dict) -> int:
+    """Routed experts an expert layer has; 0 for a config with no expert
+    key (a dense model: every layer's feed-forward is dense)."""
+    return int(config.get("n_routed_experts")
+               or config.get("num_experts") or 0)
 
 
 def resolve_cut(config: dict, layers: Optional[dict] = None,
                 experts_held: Optional[int] = None) -> Dict[str, int]:
     """``{"leading_dense", "following", "experts_held"}`` with what the
-    cut leaves out taken from the config."""
+    cut leaves out taken from the config. A config with no expert key is
+    dense throughout: no layer follows the dense ones and there is no
+    expert to hold (``experts_held`` 0; stating one is refused)."""
     freq = config.get("moe_layer_freq")
-    if isinstance(freq, list):
+    experts = routed_experts(config)
+    if not experts:
+        dense = int(config["num_hidden_layers"])
+    elif isinstance(freq, list):
         dense = _leading_zeros(freq)
     else:
         dense = int(config.get("first_k_dense_replace")
                     or config.get("num_dense_layers") or 0)
     cut = {"leading_dense": dense,
            "following": int(config["num_hidden_layers"]) - dense,
-           "experts_held": int(config.get("n_routed_experts")
-                               or config["num_experts"])}
+           "experts_held": experts}
     if layers is not None:
         unknown = set(layers) - {"leading_dense", "following"}
         if unknown:
             raise ValueError(f"architecture layers: unknown {unknown}")
         cut.update({k: int(v) for k, v in layers.items()})
     if experts_held is not None:
+        if not experts:
+            raise ValueError(
+                f"architecture experts_held: {experts_held} of a config "
+                f"with no expert key (a dense model holds none)")
         cut["experts_held"] = int(experts_held)
+    if not experts and cut["following"]:
+        raise ValueError(f"architecture layers: {cut} of a config with "
+                         f"no expert key (no layer follows the dense ones)")
     if isinstance(freq, list):
         # kinds come from the list: the cut only says how many layers
         total = cut["leading_dense"] + cut["following"]
@@ -222,6 +294,11 @@ def resolve_cut(config: dict, layers: Optional[dict] = None,
             raise ValueError(
                 f"architecture layers: {cut} departs from layer_types "
                 f"(of {len(types)} layers, the first {dense} dense)")
+    mixers = config.get("mixer_types")
+    if isinstance(mixers, list) \
+            and cut["leading_dense"] + cut["following"] > len(mixers):
+        raise ValueError(f"architecture layers: {cut} departs from "
+                         f"mixer_types (of {len(mixers)} layers)")
     return cut
 
 
@@ -259,8 +336,8 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
     H = int(config["hidden_size"])
     heads = int(config["num_attention_heads"])
     V = int(config["vocab_size"])
-    E = int(config.get("n_routed_experts") or config["num_experts"])
-    k = int(config["num_experts_per_tok"])
+    E = routed_experts(config)          # 0: a dense model
+    k = int(config.get("num_experts_per_tok") or 0)
     dense_inter = int(config["intermediate_size"])
     expert_inter = int(config.get("moe_intermediate_size") or dense_inter)
     shared_inter = int(config.get("n_shared_experts")
@@ -280,8 +357,17 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
     S, B = int(seq_len), int(micro_batch)
     T = S * B                        # tokens of the step
     # token-expert pairs the held experts see under balanced routing
-    pairs = T * k * held // E if T * k * held % E == 0 else T * k * held / E
+    pairs = 0
+    if E:
+        pairs = T * k * held // E if T * k * held % E == 0 \
+            else T * k * held / E
     A = ACT_BYTES
+    # muP's scalars, elementwise where a key states them: x scale_emb an
+    # embedding element, x scale_depth / sqrt(layers) an element of each
+    # residual branch, / (hidden_size / dim_model_base) an element the
+    # head reads
+    branch_scale = T * H * ("scale_depth" in config)
+    qk_norm = bool(config.get("use_qk_norm") or config.get("qk_norm"))
     g = _Graph()
 
     def norm(op_type, inputs, tokens=T):
@@ -289,8 +375,51 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
         return g.add(op_type, 4 * tokens * H,
                      A * (2 * tokens * H + H), tokens * H, H, inputs)
 
+    def _projections(stream, n, kv_heads, d_qk, d_v, rotary, gate,
+                     value_scale=False):
+        """InputNorm, ``QKVProj`` and, where the mixer is gated,
+        ``GateProj``; returns (QKVProj, GateProj or None, the width of
+        [q ; k ; v])."""
+        q, kk, vv = n * d_qk, kv_heads * d_qk, kv_heads * d_v
+        qkv = q + kk + vv
+        # RoPE (3 an element) on the rotary part of each q and k head;
+        # where stated, q/k RMSNorm (4 an element, a weight an element
+        # of a token's q and k) and v <- attention_value_scale . v (1)
+        elementwise = 3 * T * (n + kv_heads) * rotary \
+            + qk_norm * 4 * T * (q + kk) \
+            + value_scale * T * vv
+        norm_weights = qk_norm * (q + kk)
+        x = norm("InputNorm", [stream])
+        # [q ; k ; v] = x W_qkv
+        proj = g.add("QKVProj", 2 * T * H * qkv + elementwise,
+                     A * (T * H + H * qkv + norm_weights + T * qkv),
+                     T * qkv, H * qkv + norm_weights, [x])
+        gate_proj = None
+        if gate:
+            # g = x W_g (H -> n d_v): one gate an element of the core's
+            # output
+            gate_proj = g.add("GateProj", 2 * T * H * n * d_v,
+                              A * (T * H + H * n * d_v + T * n * d_v),
+                              T * n * d_v, H * n * d_v, [x])
+        return proj, gate_proj, qkv
+
+    def _out_proj(core, stream, gate_proj, o, out_norm=False):
+        """y = o W_o (o -> H) + residual; where stated, o RMS-normed
+        first (4 an element, a weight an element) and gated, o .
+        sigmoid(g) (3 an element: sigmoid 2 as in silu . up's 4, the
+        product 1)."""
+        gated = gate_proj is not None
+        return g.add("OutProjResidual",
+                     2 * T * o * H + T * H + branch_scale
+                     + gated * 3 * T * o + out_norm * 4 * T * o,
+                     A * (T * o + gated * T * o + o * H + out_norm * o
+                          + 2 * T * H),
+                     T * H, o * H + out_norm * o,
+                     [core, stream] + [gate_proj] * gated)
+
     def _gqa_attention(stream, window):
-        """MHA/GQA with a full causal core or, with ``window``, a
+        """MHA/GQA with a full causal core, past a ``sparse_config``'s
+        ``dense_len`` a block-sparse one, or, with ``window``, a
         sliding-window one at the ``swa_*`` sizes; returns
         OutProjResidual."""
         def size(key, default=None):
@@ -301,23 +430,12 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
         kv_heads = size("num_key_value_heads", n)
         d_qk = size("head_dim", H // n)
         d_v = size("v_head_dim", d_qk)
-        q, kk, vv = n * d_qk, kv_heads * d_qk, kv_heads * d_v
-        qkv = q + kk + vv
-        # RoPE (3 an element) on the rotary part of each q and k head;
-        # where stated, q/k RMSNorm (4 an element, a weight an element
-        # of a token's q and k) and v <- attention_value_scale . v (1)
         rotary = round(float(config.get("partial_rotary_factor") or 1)
-                       * d_qk)
-        qk_norm = bool(config.get("use_qk_norm"))
-        elementwise = 3 * T * (n + kv_heads) * rotary \
-            + qk_norm * 4 * T * (q + kk) \
-            + ("attention_value_scale" in config) * T * vv
-        norm_weights = qk_norm * (q + kk)
-        x = norm("InputNorm", [stream])
-        # [q ; k ; v] = x W_qkv
-        proj = g.add("QKVProj", 2 * T * H * qkv + elementwise,
-                     A * (T * H + H * qkv + norm_weights + T * qkv),
-                     T * qkv, H * qkv + norm_weights, [x])
+                       * d_qk) * bool(config.get("attn_use_rope", True))
+        proj, gate_proj, qkv = _projections(
+            stream, n, kv_heads, d_qk, d_v, rotary,
+            gate=config.get("attn_use_output_gate"),
+            value_scale="attention_value_scale" in config)
         # o_t = sum_s softmax_s(q_t . k_s / sqrt(d_qk)) v_s over the
         # keys a query sees: QK^T 2 d_qk, PV 2 d_v, softmax 5 a key; a
         # learnable sink logit a head in the denominator (1 a query and
@@ -325,24 +443,100 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
         sinks = n if config.get(
             "add_swa_attention_sink_bias" if window
             else "add_full_attention_sink_bias") else 0
-        if window:
-            keys = attended_keys(S, int(config["sliding_window"]))
-        elif config.get("causal_core_count") == "half_square":
-            # the causal half of S x S without its diagonal (1 / S of
-            # it): what OLMoE's architecture file states, its profiles
-            # being pinned so
-            keys = S * S / 2
+        pair = n * (2 * d_qk + 2 * d_v + 5)
+
+        def block_sparse_core(sparse):
+            """The core of a sequence longer than ``sparse_config``'s
+            ``dense_len`` (InfLLM v2): selection shared by the q heads
+            of a kv head, no indexer heads."""
+            kernel, stride = (int(sparse["kernel_size"]),
+                              int(sparse["kernel_stride"]))
+            block, topk = int(sparse["block_size"]), int(sparse["topk"])
+            # K^c_j = mean of `kernel` keys every `stride` (kernel an
+            # element: the sum and the scale), a kv head
+            rows = (S - kernel) // stride + 1
+            compressed = B * rows * kv_heads * d_qk
+            kc = g.add("KCompress", compressed * kernel,
+                       A * (T * kv_heads * d_qk + compressed), compressed,
+                       0, [proj])
+            # r[t, j] = sum over a group's q heads of softmax_j(q_t,h .
+            # K^c_j / sqrt(d)) over the compressed keys behind the query
+            # (dot 2 d, softmax 5), max-pooled to blocks, top-k blocks a
+            # query and kv head; the scores are never written, out =
+            # the indices
+            kept = min(-(-S // block), topk)
+            select = g.add("BlockScoreTopK",
+                           B * compressed_keys(S, kernel, stride) * n
+                           * (2 * d_qk + 5),
+                           A * (T * n * d_qk + compressed
+                                + T * kv_heads * kept),
+                           T * kv_heads * kept, 0, [proj, kc])
+            # softmax attention over the selected blocks, a sliding
+            # window and the initial blocks: min(t, reach) keys a query
+            reach = topk * block + int(sparse["window_size"]) \
+                + int(sparse["init_blocks"]) * block
+            return g.add("BlockSparseAttnCore",
+                         B * attended_keys(S, reach) * pair,
+                         A * (T * qkv + T * kv_heads * kept
+                              + T * n * d_v),
+                         T * n * d_v, 0, [proj, select])
+
+        sparse = config.get("sparse_config")
+        if sparse and not window and S > int(sparse["dense_len"]):
+            core = block_sparse_core(sparse)
         else:
-            keys = attended_keys(S, S)
-        core = g.add("WindowAttnCore" if window else "AttnCore",
-                     B * keys * n * (2 * d_qk + 2 * d_v + 5) + T * sinks,
-                     A * (T * qkv + sinks + T * n * d_v),
-                     T * n * d_v, sinks, [proj])
-        # y = o W_o (n d_v -> H) + residual
-        o = n * d_v
-        return g.add("OutProjResidual", 2 * T * o * H + T * H,
-                     A * (T * o + o * H + 2 * T * H), T * H, o * H,
-                     [core, stream])
+            if window:
+                keys = attended_keys(S, int(config["sliding_window"]))
+            elif config.get("causal_core_count") == "half_square":
+                # the causal half of S x S without its diagonal (1 / S
+                # of it): what OLMoE's architecture file states, its
+                # profiles being pinned so
+                keys = S * S / 2
+            else:
+                keys = attended_keys(S, S)
+            core = g.add("WindowAttnCore" if window else "AttnCore",
+                         B * keys * pair + T * sinks,
+                         A * (T * qkv + sinks + T * n * d_v),
+                         T * n * d_v, sinks, [proj])
+        return _out_proj(core, stream, gate_proj, n * d_v,
+                         out_norm=bool(config.get("attn_use_output_norm")))
+
+    def _linear_attention(stream):
+        """Lightning linear attention at the ``lightning_*`` sizes: per
+        head, with a fixed decay l (no parameter), S_t = l S_{t-1} +
+        k_t^T v_t (d x d) and o_t = lightning_scale . q_t S_t, scanned
+        in chunks of ``lightning_chunk_size``; returns
+        OutProjResidual."""
+        n, kv_heads = (int(config["lightning_nh"]),
+                       int(config["lightning_nkv"]))
+        d = int(config["lightning_head_dim"])
+        chunk = int(config["lightning_chunk_size"])
+        proj, gate_proj, qkv = _projections(
+            stream, n, kv_heads, d, d,
+            rotary=d * bool(config.get("lightning_use_rope")),
+            gate=config.get("use_output_gate"))
+
+        def chunk_flops(c):
+            # intra-chunk [(Q K^T) . M] V over the causal half (2 d, the
+            # decay mask 1, 2 d a pair), inter-chunk Q S_prev and the
+            # update K^T V (2 d^2 a token each), the output's decay, sum
+            # and scale and the update's decayed keys (4 d a token), the
+            # state's decay and add (2 d^2)
+            return c * (c + 1) // 2 * (4 * d + 1) + 4 * c * d * d \
+                + 4 * c * d + 2 * d * d
+
+        whole, rest = divmod(S, chunk)
+        chunks = whole + (rest > 0)
+        # bytes: q, k, v in, o out and a d x d state a head and chunk
+        # written once (what the backward pass restarts from); linear in
+        # S at a fixed chunk
+        core = g.add("LinearAttnCore",
+                     B * n * (whole * chunk_flops(chunk)
+                              + (rest > 0) * chunk_flops(rest)),
+                     A * (T * qkv + T * n * d + B * chunks * n * d * d),
+                     T * n * d, 0, [proj])
+        return _out_proj(core, stream, gate_proj, n * d,
+                         out_norm=bool(config.get("use_output_norm")))
 
     def _latent_sparse_attention(stream):
         """MLA with the DSA indexer and top-k sparse core; returns
@@ -396,13 +590,12 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
                      A * (T * heads * dqk + T * heads * (dn + dv) + T * dr
                           + T * min(S, topk) + T * heads * dv),
                      T * heads * dv, 0, [q, kv, c_kv, select])
-        return g.add("OutProjResidual", 2 * T * heads * dv * H + T * H,
-                     A * (T * heads * dv + heads * dv * H + 2 * T * H),
-                     T * H, heads * dv * H, [core, stream])
+        return _out_proj(core, stream, None, heads * dv)
 
     window = window_layers(config)
+    linear = linear_layers(config)
     freq = config.get("moe_layer_freq")
-    if n_mtp and window is not None:
+    if n_mtp and (window is not None or linear is not None):
         raise ValueError("a per-layer attention list gives no kind for a "
                          "multi-token-prediction module's layer")
 
@@ -412,10 +605,14 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
         returns the op that carries the stream out."""
         if "kv_lora_rank" in config:
             out_proj = _latent_sparse_attention(stream)
+        elif linear is not None and linear[i]:
+            out_proj = _linear_attention(stream)
         else:
             out_proj = _gqa_attention(
                 stream, window=window is not None and window[i])
-        if isinstance(freq, list):
+        if not E:
+            dense = True
+        elif isinstance(freq, list):
             dense = freq[i] == 0
         else:
             dense = i is not None and i < cut["leading_dense"]
@@ -424,7 +621,7 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
             # gate, up, down, silu * up (4 a value), + residual
             return g.add("DenseMLPResidual",
                          2 * T * 3 * H * dense_inter + 4 * T * dense_inter
-                         + T * H,
+                         + T * H + branch_scale,
                          A * (3 * T * H + 3 * H * dense_inter),
                          T * H, 3 * H * dense_inter, [x, out_proj])
         if sigmoid_router:
@@ -458,14 +655,16 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
                         pairs * H, held * 3 * H * expert_inter, [router])
         # weighted sum of the routed outputs (+ the shared one) + residual
         combine = g.add("CombineResidual",
-                        2 * pairs * H + T * H * (1 + len(shared)),
+                        2 * pairs * H + T * H * (1 + len(shared))
+                        + branch_scale,
                         A * (pairs * H + pairs
                              + T * H * (2 + len(shared))),
                         T * H, 0, [experts, out_proj, router, *shared])
         g.edges.append((x, experts))
         return combine
 
-    embedding = g.add("Embedding", 0, A * 2 * T * H + 4 * T, T * H, V * H)
+    embedding = g.add("Embedding", T * H * ("scale_emb" in config),
+                      A * 2 * T * H + 4 * T, T * H, V * H)
     stream = embedding
     for i in range(cut["leading_dense"] + cut["following"]):
         stream = layer(stream, i)
@@ -485,7 +684,8 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
     tokens = T * len(streams)
     final = norm("FinalNorm", streams, tokens)
     # logits + softmax cross-entropy (5 a logit); output = the logits
-    g.add("LMHeadLoss", 2 * tokens * H * V + 5 * tokens * V,
+    g.add("LMHeadLoss", 2 * tokens * H * V + 5 * tokens * V
+          + tokens * H * ("dim_model_base" in config),
           A * (tokens * H + H * V + tokens * V), tokens * V, H * V, [final])
     return g
 
@@ -506,9 +706,10 @@ def forward_time(cost: dict) -> float:
                cost["bytes"] / A100.memory_bandwidth)
 
 
-#: forward ops whose FLOPs grow as S^2: a full causal core and the
-#: sparse indexer's score (a windowed or top-k core grows as S)
-QUADRATIC_OPS = ("AttnCore", "IndexScoreTopK")
+#: forward ops whose FLOPs grow as S^2: a full causal core, the sparse
+#: indexer's score and the block score over compressed keys (a windowed,
+#: top-k or linear core grows as S)
+QUADRATIC_OPS = ("AttnCore", "IndexScoreTopK", "BlockScoreTopK")
 
 
 def profile_text(config: dict, seq_len: int, micro_batch: int,

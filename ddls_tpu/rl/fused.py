@@ -170,6 +170,14 @@ def record_lookahead_trips(ep_trace, pads) -> None:
     _count_startup_gauges(MINOR_GAUGES)
 
 
+def _chosen_rows(et, jtype, action):
+    """The stacked tables' (job type, degree) row of each decision
+    (action 0, no row, reads the type's degree-1 row)."""
+    column = np.zeros(et.max_action + 1, np.int64)
+    column[et.degrees] = np.arange(len(et.degrees))
+    return jtype * len(et.degrees) + column[action]
+
+
 def record_padding_fill(ep_trace, et, ot) -> None:
     """What padding cost the decisions that RAN, from a FETCHED
     ``[..., B, T]`` trace and the tables it ran on. Over the lane-steps
@@ -184,10 +192,8 @@ def record_padding_fill(ep_trace, et, ot) -> None:
     GNN's padded work. The caller gates on ``telemetry.enabled()``."""
     jtype = np.asarray(ep_trace["jtype"])
     ran = np.asarray(ep_trace["la_trips"]) > 0
-    column = np.zeros(et.max_action + 1, np.int64)
-    column[et.degrees] = np.arange(len(et.degrees))
-    row = jtype[ran] * len(et.degrees) \
-        + column[np.asarray(ep_trace["action"])[ran]]
+    row = _chosen_rows(et, jtype[ran],
+                       np.asarray(ep_trace["action"])[ran])
     telemetry.inc("sim.lookahead.dep_slots_decided",
                   int(et.row_deps[row].sum()))
     telemetry.inc("sim.lookahead.dep_slots_offered",
@@ -208,6 +214,10 @@ def record_decisions(ep_trace, et, ot) -> None:
     ``env.decisions.offered_longest`` / ``accepted_longest`` — the same
     two over the decisions on the bank's job type with the largest
     degree-1 step time (``ot["orig_seq_sum"]``);
+    ``env.decisions.offered_ragged`` / ``accepted_ragged`` — the same
+    two over the decisions whose chosen (job type, degree) row is ragged
+    (``et.row_ragged`` > 0: some forward op split fewer ways than the
+    degree; 0 where no row of the bank is);
     ``env.cluster.occupied_servers`` — the servers other jobs held when
     each decision was taken, summed — beside ``env.cluster.servers`` —
     decisions x the cluster's servers. And from the mask's start-up
@@ -218,8 +228,11 @@ def record_decisions(ep_trace, et, ot) -> None:
     The caller gates on ``telemetry.enabled()``."""
     cause = np.asarray(ep_trace["cause"])
     accepted = cause == CAUSE_ACCEPTED
-    longest = np.asarray(ep_trace["jtype"]) \
-        == int(np.argmax(ot["orig_seq_sum"]))
+    jtype = np.asarray(ep_trace["jtype"])
+    longest = jtype == int(np.argmax(ot["orig_seq_sum"]))
+    action = np.asarray(ep_trace["action"])
+    ragged = (action > 0) & (et.row_ragged[_chosen_rows(et, jtype, action)]
+                             > 0)
     telemetry.inc("env.decisions.offered", int(accepted.size))
     telemetry.inc("env.decisions.accepted", int(accepted.sum()))
     telemetry.inc("env.decisions.blocked_placement",
@@ -227,6 +240,9 @@ def record_decisions(ep_trace, et, ot) -> None:
     telemetry.inc("env.decisions.offered_longest", int(longest.sum()))
     telemetry.inc("env.decisions.accepted_longest",
                   int(accepted[longest].sum()))
+    telemetry.inc("env.decisions.offered_ragged", int(ragged.sum()))
+    telemetry.inc("env.decisions.accepted_ragged",
+                  int(accepted[ragged].sum()))
     telemetry.inc("env.cluster.occupied_servers",
                   int(np.asarray(ep_trace["n_occupied"]).sum()))
     telemetry.inc("env.cluster.servers", int(accepted.size) * et.n_srv)

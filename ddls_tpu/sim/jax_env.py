@@ -1000,6 +1000,9 @@ class EpisodeTables:
     row_deps: np.ndarray       # host copy of tables["n_deps"]: each
     #                            (model, degree) row's real deps, for
     #                            host reducers (rl/fused.py) — no fetch
+    row_ragged: np.ndarray     # per row, the forward ops the SiP-ML rule
+    #                            splits fewer ways than the row's degree
+    #                            (`ragged_rows`): what makes it ragged
     pads: ConfigPads
     types: List[str]           # model name -> type index (list order)
     degrees: List[int]         # action degree -> cfg column (list order)
@@ -1097,7 +1100,8 @@ def build_episode_tables(env, max_degree: Optional[int] = None,
             "res": [int(r) for r in sr.win_res],
         }
     return EpisodeTables(
-        st=st, tables=jt, row_deps=tables["n_deps"], pads=pads,
+        st=st, tables=jt, row_deps=tables["n_deps"],
+        row_ragged=ragged_rows(tables, len(types), degrees), pads=pads,
         types=types, degrees=degrees,
         comm={"x": topo.num_communication_groups,
               "rate": topo.channel_bandwidth,
@@ -1529,16 +1533,23 @@ def allocate_indexed_ops(tables: dict, st: ShapeTables,
     return len(indexed_ops(traced.jaxpr, pads.max_split))
 
 
+def ragged_rows(tables: dict, n_types: int, degrees: Sequence[int]
+                ) -> np.ndarray:
+    """Per (job type, degree) row of the stacked tables (type-major),
+    the forward ops that the SiP-ML rule splits fewer ways than the
+    row's degree asks for (an op under that many quanta): what makes the
+    row's blocks ragged. From the host's numpy tables: no fetch."""
+    degree = np.tile(np.asarray(degrees), n_types)[:, None]
+    return (np.asarray(tables["f_valid"])
+            & (np.asarray(tables["f_split"]) < degree)).sum(axis=1)
+
+
 def ragged_forward_ops(et: EpisodeTables) -> Dict[str, int]:
-    """Per job type, the forward ops that the SiP-ML rule splits fewer
-    ways than the top degree asks for (an op under that many quanta), in
-    the type's top-degree row: what makes the row's blocks ragged."""
-    f_split = np.asarray(et.tables["f_split"])
-    f_valid = np.asarray(et.tables["f_valid"])
-    n, top = len(et.degrees), et.degrees[-1]
-    rows = {model: (i + 1) * n - 1 for i, model in enumerate(et.types)}
-    return {model: int((f_valid[row] & (f_split[row] < top)).sum())
-            for model, row in rows.items()}
+    """Per job type, the ragged forward ops of its TOP-degree row
+    (`ragged_rows`)."""
+    n = len(et.degrees)
+    return {model: int(et.row_ragged[(i + 1) * n - 1])
+            for i, model in enumerate(et.types)}
 
 
 #: start-up gauges (`mask_rows_on_empty_cluster`), in this order

@@ -1,10 +1,11 @@
 """Independent plain counts of a ``glm_moe_dsa``, of a
-``mimo_v2_flash`` and of an ``afmoe`` (Trinity) training step: per op
-the parameters, the forward FLOPs and the elements of the output tensor
-(for ``afmoe`` also the bytes moved and the edges), written straight
-from the layer equations (ISSUE 30, 32 and 36, Tentpole step 1) and
-importing nothing from ``ddls_tpu/graphs/arch.py``, which
-``tests/test_arch_graphs.py`` holds to them op by op.
+``mimo_v2_flash``, of an ``afmoe`` (Trinity) and of a ``minicpm_sala``
+training step: per op the parameters, the forward FLOPs and the elements
+of the output tensor (for ``afmoe`` and ``minicpm_sala`` also the bytes
+moved and the edges), written straight from the layer equations (ISSUE
+30, 32, 36 and 39, Tentpole step 1) and importing nothing from
+``ddls_tpu/graphs/arch.py``, which ``tests/test_arch_graphs.py`` holds
+to them op by op.
 
 Conventions: 2 FLOPs a multiply-accumulate; RMSNorm 4 an element; RoPE 3
 an element it turns; softmax / sigmoid-and-select 5 a score; SwiGLU's
@@ -259,5 +260,145 @@ def plain_counts_trinity(c, S, B):
                      2 * (pairs * H + pairs + 3 * T * H), [ex, y, r, sh])
     f = rmsnorm("FinalNorm", [stream])
     add("LMHeadLoss", H * V, 2 * T * H * V + 5 * T * V, T * V,
+        2 * (T * H + H * V + T * V), [f])
+    return ops, edges
+
+
+# ========================================================== minicpm_sala
+def compressed_keys_seen(S, kernel_size, kernel_stride):
+    """sum over queries t = 1..S of the compressed keys wholly behind
+    them: key j is the mean of tokens j stride + 1 .. j stride + kernel,
+    seen by query t once t >= j stride + kernel."""
+    return sum((t - kernel_size) // kernel_stride + 1
+               for t in range(kernel_size, S + 1))
+
+
+def plain_counts_sala(c, S, B):
+    """``(ops, edges)`` of a WHOLE ``minicpm_sala`` model (MiniCPM-SALA:
+    dense, nothing cut) over B sequences of S tokens, in the shape
+    :func:`plain_counts_trinity` returns. ``c`` is the public config
+    with the architecture file's ``modeling`` block over it
+    (``sparse_config``, ``lightning_chunk_size``). Written from ISSUE
+    39's equations; T = S B, H = hidden_size, x the normed stream.
+
+    Both mixers: ``[q ; k ; v] = x W`` with RMSNorm on q and k
+    (``qk_norm``: 4 an element, a weight an element), ``g = x W_g``
+    where the mixer's gate key is true, ``y = scale_depth / sqrt(L) .
+    ((o [RMSNormed] . sigmoid(g)) W_o) + stream`` (gate 3 an element:
+    sigmoid 2 as in silu . up's 4, the product 1; the branch scale 1 an
+    element of y), then SwiGLU at ``intermediate_size`` under the same
+    branch scale. ``scale_emb`` costs 1 an embedding element and
+    ``hidden_size / dim_model_base`` 1 an element the head reads.
+
+    ``lightning-attn`` (n = ``lightning_nh`` q and ``lightning_nkv`` kv
+    heads of ``lightning_head_dim``, RoPE on q and k): per head, with a
+    fixed decay l, ``S_t = l S_{t-1} + k_t^T v_t`` (d x d) and ``o_t =
+    lightning_scale . q_t S_t``, in chunks of C tokens: intra-chunk
+    ``[(Q K^T) . M] V`` over the causal half of the chunk (2 d, 1, 2 d
+    a pair), inter-chunk ``Q S_prev`` and the update ``K^T V`` (2 d^2 a
+    token each), the output's decay, sum and scale (3 an element), the
+    update's decayed keys (1 an element) and the state's decay and add
+    (2 d^2 a chunk); bytes: q, k, v in, o out, a d x d state a head and
+    chunk written once. ``use_output_norm`` / ``use_output_gate`` are
+    this mixer's.
+
+    ``minicpm4`` (n q heads and ``num_key_value_heads`` kv heads of
+    ``head_dim``; ``attn_use_rope`` false: no RoPE; its gate is
+    ``attn_use_output_gate``, it has no output norm): up to
+    ``sparse_config.dense_len`` tokens a full causal core (t keys a
+    query). Beyond: ``KCompress`` (the mean of ``kernel_size`` keys
+    every ``kernel_stride``: kernel_size an element), ``BlockScoreTopK``
+    (softmax over the compressed keys behind the query, 2 d + 5 a score
+    and q head; out = ``topk`` block indices a query and kv head; the
+    scores are never written) and ``BlockSparseAttnCore`` (softmax
+    attention over min(t, topk block_size + window_size + init_blocks
+    block_size) keys)."""
+    T, H, V, I = S * B, c["hidden_size"], c["vocab_size"], c[
+        "intermediate_size"]
+    sparse, C = c["sparse_config"], c["lightning_chunk_size"]
+    ops, edges = [], set()
+
+    def add(kind, params, flops, out, nbytes, reads=()):
+        ops.append((kind, params, flops, out, nbytes))
+        edges.update((r, len(ops)) for r in reads)
+        return len(ops)
+
+    def rmsnorm(kind, reads):
+        return add(kind, H, 4 * T * H, T * H, 2 * (2 * T * H + H), reads)
+
+    def out_proj(o, stream, gate, width, normed):
+        return add("OutProjResidual", width * H + normed * width,
+                   2 * T * width * H + 2 * T * H + 3 * T * width
+                   + normed * 4 * T * width,
+                   T * H,
+                   2 * (2 * T * width + width * H + normed * width
+                        + 2 * T * H),
+                   [o, stream, gate])
+
+    stream = add("Embedding", V * H, T * H, T * H, 2 * 2 * T * H + 4 * T)
+    for kind in c["mixer_types"]:
+        x = rmsnorm("InputNorm", [stream])
+        if kind == "lightning-attn":
+            n, g, d = (c["lightning_nh"], c["lightning_nkv"],
+                       c["lightning_head_dim"])
+            width = (n + 2 * g) * d
+            normed = (n + g) * d
+            qkv = add("QKVProj", H * width + normed,
+                      2 * T * H * width + 4 * T * normed
+                      + 3 * T * (n + g) * d, T * width,
+                      2 * (T * H + H * width + normed + T * width), [x])
+            gate = add("GateProj", H * n * d, 2 * T * H * n * d, T * n * d,
+                       2 * (T * H + H * n * d + T * n * d), [x])
+            chunks = [C] * (S // C) + [S % C] * (S % C > 0)
+            flops = sum(k * (k + 1) // 2 * (4 * d + 1) + 4 * k * d * d
+                        + 4 * k * d + 2 * d * d for k in chunks)
+            o = add("LinearAttnCore", 0, B * n * flops, T * n * d,
+                    2 * (T * width + T * n * d
+                         + B * len(chunks) * n * d * d), [qkv])
+            y = out_proj(o, stream, gate, n * d, normed=1)
+        else:
+            assert kind == "minicpm4", kind
+            n, g, d = (c["num_attention_heads"], c["num_key_value_heads"],
+                       c["head_dim"])
+            width = (n + 2 * g) * d
+            normed = (n + g) * d
+            qkv = add("QKVProj", H * width + normed,
+                      2 * T * H * width + 4 * T * normed, T * width,
+                      2 * (T * H + H * width + normed + T * width), [x])
+            gate = add("GateProj", H * n * d, 2 * T * H * n * d, T * n * d,
+                       2 * (T * H + H * n * d + T * n * d), [x])
+            pair = n * (2 * d + 2 * d + 5)
+            if S <= sparse["dense_len"]:
+                o = add("AttnCore", 0, B * S * (S + 1) // 2 * pair,
+                        T * n * d, 2 * (T * width + T * n * d), [qkv])
+            else:
+                ks, stride = sparse["kernel_size"], sparse["kernel_stride"]
+                rows = (S - ks) // stride + 1
+                kc = add("KCompress", 0, B * rows * g * d * ks,
+                         B * rows * g * d,
+                         2 * (T * g * d + B * rows * g * d), [qkv])
+                blocks = -(-S // sparse["block_size"])
+                kept = min(blocks, sparse["topk"])
+                select = add(
+                    "BlockScoreTopK", 0,
+                    B * compressed_keys_seen(S, ks, stride) * n
+                    * (2 * d + 5),
+                    T * g * kept,
+                    2 * (T * n * d + B * rows * g * d + T * g * kept),
+                    [qkv, kc])
+                reach = (sparse["topk"] * sparse["block_size"]
+                         + sparse["window_size"]
+                         + sparse["init_blocks"] * sparse["block_size"])
+                o = add("BlockSparseAttnCore", 0,
+                        B * keys_read(S, reach) * pair, T * n * d,
+                        2 * (T * width + T * g * kept + T * n * d),
+                        [qkv, select])
+            y = out_proj(o, stream, gate, n * d, normed=0)
+        x = rmsnorm("PostAttnNorm", [y])
+        stream = add("DenseMLPResidual", 3 * H * I,
+                     2 * T * 3 * H * I + 4 * T * I + 2 * T * H, T * H,
+                     2 * (3 * T * H + 3 * H * I), [x, y])
+    f = rmsnorm("FinalNorm", [stream])
+    add("LMHeadLoss", H * V, 2 * T * H * V + 5 * T * V + T * H, T * V,
         2 * (T * H + H * V + T * V), [f])
     return ops, edges

@@ -267,10 +267,12 @@ def test_generator_takes_an_architecture_like_any_other_source(tmp_path):
         f"graphs.arch.{what}.{m}": n for m in models
         for what, n in (("forward_ops", 19), ("edges", 53),
                         ("layers_full", 2), ("layers_window", 0),
+                        ("layers_linear", 0), ("layers_block_sparse", 0),
                         ("shared_expert_layers", 0))}
     assert sorted(shares) == sorted(
         [BANK_GAUGES[0]]
-        + [f"graphs.arch.quadratic_time_share.{m}" for m in models])
+        + [f"graphs.arch.{kind}_time_share.{m}" for m in models
+           for kind in ("quadratic", "linear")])
     # an unstated family: what a dep or a sync edge is sized by is the
     # largest op's whole memory cost
     for m in models:
@@ -1110,10 +1112,8 @@ def test_a_cut_takes_the_lists_first_layers_or_is_refused(mimo, glm, case):
                                 )["following"] == 1
 
 
-def _kind_gauges(arch_file, shapes, **cut):
-    """The layer-kind gauges `jobs_generator` sets for a family, per
-    model name: ``{"layers_full", "layers_window",
-    "quadratic_time_share"}``."""
+def _arch_gauges(arch_file, shapes, **cut):
+    """Every start-up gauge `jobs_generator` sets for a family."""
     from ddls_tpu.demands.jobs_generator import JobsGenerator
     from ddls_tpu.telemetry import startup
 
@@ -1126,8 +1126,15 @@ def _kind_gauges(arch_file, shapes, **cut):
         replication_factor=1, num_training_steps=20)
     gauges = startup.gauges()
     startup.registry().reset()
+    return gauges
+
+
+def _kind_gauges(arch_file, shapes, **cut):
+    """The layer-kind gauges `jobs_generator` sets for a family, per
+    model name: ``{"layers_full", "layers_window",
+    "quadratic_time_share"}``."""
     kinds = {}
-    for name, value in gauges.items():
+    for name, value in _arch_gauges(arch_file, shapes, **cut).items():
         what, _, model = name[len("graphs.arch."):].partition(".")
         if what in ("layers_full", "layers_window", "quadratic_time_share",
                     "shared_expert_layers"):
@@ -1321,8 +1328,10 @@ def test_decisions_on_the_longest_job_type_are_counted():
     traces into `env.decisions.offered_longest` / `accepted_longest`
     (the decisions on the type with the largest degree-1 step time) and
     `env.decisions.blocked_placement` (those that failed
-    ``op_placement``); a decision is accepted where its cause is
-    ``CAUSE_ACCEPTED``."""
+    ``op_placement``) and `env.decisions.offered_ragged` /
+    `accepted_ragged` (those whose chosen (type, degree) row has a
+    forward op split fewer ways than the degree); a decision is
+    accepted where its cause is ``CAUSE_ACCEPTED``."""
     import types
 
     from ddls_tpu import telemetry
@@ -1331,9 +1340,14 @@ def test_decisions_on_the_longest_job_type_are_counted():
 
     A, N, P, S = (jax_env.CAUSE_ACCEPTED, jax_env.CAUSE_NOT_HANDLED,
                   jax_env.CAUSE_OP_PLACEMENT, jax_env.CAUSE_SLA)
-    et = types.SimpleNamespace(n_srv=32)
+    # three job types x degrees (1, 2, 4): type 1's rows above degree 1
+    # and type 2's top row are ragged
+    et = types.SimpleNamespace(
+        n_srv=32, max_action=4, degrees=[1, 2, 4],
+        row_ragged=np.array([0, 0, 0, 0, 3, 3, 0, 0, 5]))
     ot = {"orig_seq_sum": np.array([3.0, 87.4, 9.6])}
     trace = {"jtype": np.array([[1, 0, 1], [2, 1, 0]]),
+             "action": np.array([[2, 4, 4], [4, 1, 0]]),
              "cause": np.array([[A, A, P], [S, A, A]]),
              "n_occupied": np.array([[0, 4, 8], [8, 8, 12]])}
     was = telemetry.enabled()
@@ -1355,6 +1369,10 @@ def test_decisions_on_the_longest_job_type_are_counted():
     assert counters["env.decisions.blocked_placement"] == 1
     assert counters["env.decisions.offered_longest"] == 3
     assert counters["env.decisions.accepted_longest"] == 2
+    # ragged rows chosen: (1, 2) accepted, (1, 4) placement-blocked,
+    # (2, 4) over its limit; (1, 1) is even and action 0 chose no row
+    assert counters["env.decisions.offered_ragged"] == 3
+    assert counters["env.decisions.accepted_ragged"] == 1
     assert counters["env.cluster.servers"] == 6 * 32
     assert (blocked["env.decisions.accepted"],
             blocked["env.decisions.blocked_placement"]) == (0, 3)
@@ -1830,3 +1848,570 @@ def test_stacked_tables_are_built_once_a_workload(tmp_path, monkeypatch):
     other = jax_env.build_episode_tables(env)
     assert other.types != first.types
     assert len(calls) == 2 * rows + 2 * 3 + 2 * 9
+
+
+# ========================================================== minicpm_sala
+SALA_FILE = "ddls_tpu/graphs/arch_configs/minicpm_sala.json"
+SALA_SHAPES = [(4096, 1), (8192, 4), (32768, 1), (131072, 1)]
+#: 4 layers (mixers A L L A by ``mixer_types``), hidden 64, DENSE (no
+#: expert key): softmax GQA at 4 q / 1 kv heads of 16 without RoPE, full
+#: up to 64 tokens and block-sparse beyond; Lightning at 4 / 4 heads of
+#: 16 in chunks of 16; every key MiniCPM-SALA's config states, under its
+#: own name; the sizes the config leaves unsaid in a ``modeling`` block
+TINY_SALA = {"model_type": "tinysala", "hidden_size": 64,
+             "num_hidden_layers": 4, "intermediate_size": 128,
+             "vocab_size": 256,
+             "mixer_types": ["minicpm4", "lightning-attn",
+                             "lightning-attn", "minicpm4"],
+             "num_attention_heads": 4, "num_key_value_heads": 1,
+             "head_dim": 16, "attn_use_rope": False,
+             "attn_use_output_gate": True,
+             "lightning_nh": 4, "lightning_nkv": 4,
+             "lightning_head_dim": 16, "lightning_use_rope": True,
+             "lightning_scale": "1/sqrt(d)", "qk_norm": True,
+             "use_output_gate": True, "use_output_norm": True,
+             "scale_emb": 12, "scale_depth": 1.4, "dim_model_base": 32,
+             "tie_word_embeddings": False}
+TINY_SALA_MODELING = {
+    "sparse_config": {"kernel_size": 8, "kernel_stride": 4, "block_size": 8,
+                      "topk": 2, "window_size": 16, "init_blocks": 1,
+                      "dense_len": 64},
+    "lightning_chunk_size": 16}
+TINY_SALA_BUILT = {**TINY_SALA, **TINY_SALA_MODELING}
+
+
+@pytest.fixture(scope="module")
+def sala():
+    return arch.load_arch_config(SALA_FILE)
+
+
+def _tiny_sala_arch_file(directory) -> str:
+    path = os.path.join(str(directory), "tinysala.json")
+    with open(path, "w") as fh:
+        json.dump({"source_url": "test-local", "training_state": STATE,
+                   "modeling": TINY_SALA_MODELING, "config": TINY_SALA}, fh)
+    return path
+
+
+@pytest.mark.parametrize("case", ["tiny_s8", "tiny_s64", "tiny_s203",
+                                  "sala_4k", "sala_8k", "sala_32k",
+                                  "sala_128k"])
+def test_sala_op_costs_equal_the_plain_count_op_by_op(sala, case):
+    """`plain_counts_sala` is written from ISSUE 39's equations and
+    imports nothing of the builder: op names, parameters, FLOPs, output
+    elements, bytes moved and the edge set agree on both sides of
+    ``dense_len`` (two graphs of one model), with whole and partial
+    chunks, at three tiny shapes and the cell's four — nothing is cut."""
+    from plain_arch_counts import plain_counts_sala
+
+    config, seq_len, micro_batch = {
+        "tiny_s8": (TINY_SALA_BUILT, 8, 3),
+        "tiny_s64": (TINY_SALA_BUILT, 64, 2),
+        "tiny_s203": (TINY_SALA_BUILT, 203, 2),
+        "sala_4k": (sala, 4096, 1), "sala_8k": (sala, 8192, 4),
+        "sala_32k": (sala, 32768, 1),
+        "sala_128k": (sala, 131072, 1)}[case]
+    built = arch.build_graph(config, seq_len, micro_batch)
+    plain, edges = plain_counts_sala(config, seq_len, micro_batch)
+    assert [o["op_type"] for o in built.ops] == [p[0] for p in plain]
+    for i, (o, (kind, params, flops, out, nbytes)) in enumerate(
+            zip(built.ops, plain)):
+        assert o["params"] == params, (i, kind)
+        assert o["flops"] == pytest.approx(flops, rel=1e-12), (i, kind)
+        assert o["out_elems"] == out, (i, kind)
+        assert o["bytes"] == pytest.approx(nbytes, rel=1e-12), (i, kind)
+    assert set(built.edges) == edges and len(built.edges) == len(edges)
+    sparse = seq_len > config["sparse_config"]["dense_len"]
+    layers = len(config["mixer_types"])
+    softmax = config["mixer_types"].count("minicpm4")
+    assert len(built.ops) == 1 + 7 * layers + 2 * softmax * sparse + 2
+
+
+@pytest.mark.parametrize("seq_len,sparse", [(8192, False), (8193, True)])
+def test_sala_graph_switches_at_dense_len(sala, tmp_path, seq_len, sparse):
+    """Up to ``sparse_config.dense_len`` tokens a `minicpm4` layer runs
+    the full causal core every other family counts; one token more and
+    it is `KCompress`, `BlockScoreTopK` and `BlockSparseAttnCore`: 227
+    forward ops and 322 edges, or 243 and 354. Every op but the first
+    has a producer and every op but the last a consumer."""
+    family = arch.load_arch_file(SALA_FILE)
+    assert family["modeling"]["sparse_config"]["dense_len"] == 8192
+    path, = arch.write_profiles(
+        str(tmp_path), sala, [{"seq_len": seq_len, "micro_batch": 1}],
+        training_state=family["training_state"])
+    assert os.path.basename(path) == f"minicpm_sala_s{seq_len}_b1.txt"
+    nodes, edges = _parse_pipedream_txt(path)
+    kinds = [n["op_type"] for n in nodes.values()]
+    core = ["KCompress", "BlockScoreTopK", "BlockSparseAttnCore"] \
+        if sparse else ["AttnCore"]
+
+    def layer(mixer):
+        return ["InputNorm", "QKVProj", "GateProj",
+                *(["LinearAttnCore"] if mixer == "lightning-attn"
+                  else core),
+                "OutProjResidual", "PostAttnNorm", "DenseMLPResidual"]
+
+    assert kinds == (["Embedding"]
+                     + sum((layer(m) for m in sala["mixer_types"]), [])
+                     + ["FinalNorm", "LMHeadLoss"])
+    assert kinds.count("LinearAttnCore") == 24
+    assert (len(kinds), len(edges)) == ((243, 354) if sparse
+                                        else (227, 322))
+    ids = set(nodes)
+    assert {v for _, v in edges} == ids - {"1"}
+    assert {u for u, _ in edges} == ids - {str(len(kinds))}
+    graph = read_graph_file(path)
+    assert (len(graph.forward_op_ids()), graph.n_ops, graph.n_deps) \
+        == ((243, 486, 709) if sparse else (227, 454, 645))
+    # the quadratic ops of either graph: 8 cores or 8 block scores
+    assert sum(k in arch.QUADRATIC_OPS for k in kinds) == 8
+    # a sparse query reads 64 blocks of 64, the window and a first block
+    if sparse:
+        per_pair = 32 * (4 * 128 + 5)
+        sparse_core = next(o for o in arch.op_costs(sala, seq_len, 1)
+                           if o["op_type"] == "BlockSparseAttnCore")
+        assert sparse_core["flops"] == per_pair * sum(
+            min(t, 64 * 64 + 2048 + 64) for t in range(1, seq_len + 1))
+
+
+@pytest.mark.parametrize("chunk", [64, 256])
+def test_linear_core_is_exactly_linear_in_the_sequence(sala, chunk):
+    """`LinearAttnCore` holds no parameter and its FLOPs and bytes are
+    exactly linear in S at a fixed chunk (whole chunks), where a full
+    core's grow as S (S + 1) / 2; the chunk is a shape of the scan: a
+    larger one pays more intra-chunk pairs a token."""
+    config = {**sala, "lightning_chunk_size": chunk}
+
+    def core(seq_len, kind="LinearAttnCore", cfg=config):
+        return next(o for o in arch.op_costs(cfg, seq_len, 1)
+                    if o["op_type"] == kind)
+
+    base = core(1024)
+    assert base["params"] == 0
+    for times in (2, 8, 128):
+        longer = core(1024 * times)
+        assert longer["flops"] == times * base["flops"]
+        assert longer["bytes"] == times * base["bytes"]
+        assert longer["out_elems"] == times * base["out_elems"]
+    # per token and head: (C + 1) / 2 pairs of 4 d + 1, 4 d^2 + 4 d, and
+    # the state's 2 d^2 a chunk
+    n, d = 32, 128
+    assert base["flops"] == 1024 * n * (
+        (chunk + 1) * (4 * d + 1) / 2 + 4 * d * d + 4 * d
+        + 2 * d * d / chunk)
+    assert core(4096, cfg={**sala, "lightning_chunk_size": 2 * chunk})[
+        "flops"] > core(4096)["flops"]
+    full = core(8192, "AttnCore")["flops"] / core(4096, "AttnCore")["flops"]
+    assert full == pytest.approx(4.0, rel=1e-3)
+
+
+def test_sala_whole_is_the_published_model(sala):
+    """All 32 layers and the whole untied vocabulary: 9.477 B
+    parameters from the config's keys (published 9B), of which the q/k
+    and output norms' weights are 0.26 M; no expert, so the cut is 32
+    dense layers and nothing follows."""
+    assert arch.resolve_cut(sala) == {
+        "leading_dense": 32, "following": 0, "experts_held": 0}
+    whole = arch.op_costs(sala, 4096, 1)
+    total = sum(o["params"] for o in whole)
+    H, I, V, nd = 4096, 16384, 73448, 32 * 128
+    matrices = 2 * V * H + 32 * 3 * H * I \
+        + 24 * (H * 3 * nd + 2 * H * nd) \
+        + 8 * (H * (nd + 2 * 2 * 128) + 2 * H * nd)
+    norms = (2 * 32 + 1) * H + 24 * (2 * nd + nd) + 8 * (nd + 2 * 128)
+    assert total == matrices + norms == 9_477_429_248
+    assert matrices == pytest.approx(9.477e9, rel=1e-4) and norms < 1e6
+    # the training state of a job, and with 2 x activations the job
+    assert 16 * total == pytest.approx(151.6e9, rel=1e-3)
+
+
+@pytest.mark.parametrize("case", [
+    "experts_held", "following", "unknown_mixer", "too_deep", "prefix",
+    "mtp", "old_families_untouched"])
+def test_dense_and_mixer_keys_are_checked(sala, trinity, case):
+    """A config with no expert key is dense throughout: stating
+    `experts_held` or a layer to follow the dense ones is refused;
+    `mixer_types` is the per-layer list: an unknown string and a cut
+    deeper than the list are refused."""
+    if case == "experts_held":
+        with pytest.raises(ValueError, match="experts_held"):
+            arch.resolve_cut(sala, experts_held=8)
+        with pytest.raises(ValueError, match="experts_held"):
+            arch.op_costs(sala, 64, 1, experts_held=1)
+        assert arch.resolve_cut(trinity, experts_held=8)[
+            "experts_held"] == 8
+    elif case == "following":
+        with pytest.raises(ValueError, match="no expert key"):
+            arch.resolve_cut(sala, {"leading_dense": 2, "following": 2})
+    elif case == "unknown_mixer":
+        types = ["minicpm4", "mamba2"] * 16
+        with pytest.raises(ValueError, match="mamba2"):
+            arch.op_costs({**sala, "mixer_types": types}, 64, 1)
+    elif case == "too_deep":
+        with pytest.raises(ValueError, match="mixer_types"):
+            arch.resolve_cut(sala, {"leading_dense": 33, "following": 0})
+    elif case == "prefix":
+        # the stack's first layers, in the list's order
+        kinds = [o["op_type"] for o in arch.op_costs(
+            sala, 64, 1, layers={"leading_dense": 10, "following": 0})]
+        assert [k for k in kinds if k.endswith("AttnCore")] == \
+            ["AttnCore"] + ["LinearAttnCore"] * 8 + ["AttnCore"]
+        assert kinds.count("DenseMLPResidual") == 10
+    elif case == "mtp":
+        with pytest.raises(ValueError, match="multi-token-prediction"):
+            arch.op_costs({**sala, "num_nextn_predict_layers": 1}, 64, 1)
+    else:
+        # no old family has a gate, a mixer list, a sparse_config or a
+        # muP scalar: none of the new ops is in its graph
+        kinds = {o["op_type"] for o in arch.op_costs(trinity, 16384, 1)}
+        assert not kinds & {"GateProj", "LinearAttnCore", "KCompress",
+                            "BlockScoreTopK", "BlockSparseAttnCore"}
+        assert arch.linear_layers(trinity) is None
+
+
+@pytest.mark.parametrize("key,op,delta", [
+    ("use_output_gate", "GateProj", None),
+    ("attn_use_output_gate", "GateProj", None),
+    ("use_output_norm", "OutProjResidual", "lightning_norm"),
+    ("qk_norm", "QKVProj", "qk_norm"),
+    ("attn_use_rope", "QKVProj", "attn_rope"),
+    ("lightning_use_rope", "QKVProj", "lightning_rope"),
+    ("scale_emb", "Embedding", "T*H"),
+    ("scale_depth", "DenseMLPResidual", "T*H"),
+    ("dim_model_base", "LMHeadLoss", "T*H")])
+def test_each_part_is_counted_where_a_key_states_it(sala, key, op, delta):
+    """An output gate, an output norm, q/k norms, RoPE and the muP
+    scalars are counted where MiniCPM-SALA's keys state them and not
+    where a key says no: the prefixed key speaks for its mixer, the
+    unprefixed `use_output_*` for the Lightning mixer."""
+    T, H, nd = 64, 4096, 32 * 128
+    flipped = {**sala, key: not sala[key]} \
+        if isinstance(sala[key], bool) \
+        else {k: v for k, v in sala.items() if k != key}
+
+    def ops(config):
+        return arch.op_costs(config, T, 1)
+
+    def total(config, field="flops"):
+        return sum(o[field] for o in ops(config) if o["op_type"] == op)
+
+    stated, other = ops(sala), ops(flipped)
+    if delta is None:
+        # the mixer's gate key: its layers lose GateProj, the gate's 3
+        # an element of o and the edge; the other mixer keeps its gate
+        gated = {"use_output_gate": 24, "attn_use_output_gate": 8}[key]
+        assert sum(o["op_type"] == "GateProj" for o in stated) == 32
+        assert sum(o["op_type"] == "GateProj" for o in other) == 32 - gated
+        assert sum(o["flops"] for o in stated) \
+            - sum(o["flops"] for o in other) \
+            == gated * (2 * T * H * nd + 3 * T * nd)
+        assert sum(o["params"] for o in stated) \
+            - sum(o["params"] for o in other) == gated * H * nd
+    elif delta == "lightning_norm":
+        assert total(sala) - total(flipped) == 24 * 4 * T * nd
+        assert total(sala, "params") - total(flipped, "params") == 24 * nd
+    elif delta == "qk_norm":
+        width = 24 * 2 * nd + 8 * (nd + 2 * 128)
+        assert total(sala) - total(flipped) == 4 * T * width
+        assert total(sala, "params") - total(flipped, "params") == width
+    elif delta == "attn_rope":
+        # stated false: turning it on adds RoPE to the 8 softmax layers
+        assert sala[key] is False
+        assert total(flipped) - total(sala) == 8 * 3 * T * (32 + 2) * 128
+    elif delta == "lightning_rope":
+        assert total(sala) - total(flipped) == 24 * 3 * T * 64 * 128
+    else:
+        count = {"Embedding": 1, "DenseMLPResidual": 32, "LMHeadLoss": 1}[op]
+        assert total(sala) - total(flipped) == count * T * H
+        if key == "scale_depth":
+            # the attention branch's too
+            assert sum(o["flops"] for o in stated) \
+                - sum(o["flops"] for o in other) == 2 * 32 * T * H
+
+
+SALA_SHA256 = {
+    (4096, 1):
+        "a88ead2af4d63170bdcc4d0f8d4a13f68aa886a495622fab0e0dd14f0428bb70",
+    (8192, 4):
+        "61371ad7db96903573e122f432749f01a2f3ded8e42bff7a03984b71f6fe8ffd",
+    (32768, 1):
+        "c9fd769c42aec16eb94c29f4a09f48defeca29cd9c67862d165768aa91470b38",
+    (131072, 1):
+        "0409af227c8303ff4aa16d322df817c9a63306bceee0029864138cfdf0c0ab2e"}
+
+
+@pytest.mark.parametrize("shape", SALA_SHAPES)
+def test_sala_profiles_are_pinned(shape):
+    """The four profiles of `sala_ramp32.train_fused`, byte for byte: a
+    later change to a shared count shows here."""
+    import hashlib
+
+    family = arch.load_arch_file(SALA_FILE)
+    text = arch.profile_text(arch.builder_config(family), *shape,
+                             training_state=family["training_state"])
+    assert hashlib.sha256(text.encode()).hexdigest() == SALA_SHA256[shape]
+
+
+def test_sala_env_yaml_states_what_its_comments_derive(sala):
+    """env_sala_32.yaml: NO cut, the shapes as data, the arrival gap and
+    horizon derived from the builder's graph, env_olmoe32's pads rule
+    over the LARGER of the two graphs, which ops make which rows
+    ragged, and nothing else changed from env_trinity_32."""
+    import math
+
+    from ddls_tpu.agents.partitioners import sip_ml_num_partitions
+    from ddls_tpu.config import load_config
+
+    def env(name):
+        return load_config(
+            os.path.join(REPO, "scripts/ramp_job_partitioning_configs"),
+            "rllib_config", [f"env_config={name}"])["env_config"]
+
+    cfg, base = env("env_sala_32"), env("env_trinity_32")
+    jobs = cfg["jobs_config"]
+    family = jobs["architecture"]
+    assert set(family) == {"config", "shapes"}      # whole: no cut keys
+    assert family["config"] == SALA_FILE
+    shapes = family["shapes"]
+    assert [(s["seq_len"], s["micro_batch"]) for s in shapes] == SALA_SHAPES
+    # the two longest contexts the model declares fit no 16 servers
+    assert sala["max_position_embeddings"] == 524288
+    steps = jobs["num_training_steps"]
+    costs = [arch.op_costs(sala, **s) for s in shapes]
+    lengths = [steps * (1 + arch.BACKWARD_OVER_FORWARD)
+               * sum(arch.forward_time(c) for c in ops) for ops in costs]
+    assert lengths == pytest.approx([35.541, 288.427, 292.514, 1186.004],
+                                    abs=1e-3)
+    # 4 x the tokens cost 4.05 x the time: nothing S^2 is over 3 %
+    assert lengths[3] / lengths[2] == pytest.approx(4.055, abs=1e-3)
+    gap = np.mean(lengths) / 25
+    two_figures = round(gap, 1 - int(math.floor(math.log10(gap))))
+    assert jobs["job_interarrival_time_dist"]["val"] == two_figures == 18
+    assert cfg["max_simulation_run_time"] == pytest.approx(400 * two_figures)
+    # two graph sizes in one bank: the pads hold the larger
+    assert [len(ops) for ops in costs] == [227, 227, 243, 243]
+    assert cfg["pad_obs_kwargs"] == {"max_nodes": 50 * -(-486 // 50),
+                                     "max_edges": 256 * -(-709 // 256)}
+    # ragged rows: the ops the SiP-ML rule splits fewer ways than 16
+    quantum, top = cfg["min_op_run_time_quantum"], cfg[
+        "max_partitions_per_op"]
+    ragged = [sorted((o["op_type"], sip_ml_num_partitions(
+        arch.forward_time(o), quantum, top)) for o in ops
+        if sip_ml_num_partitions(arch.forward_time(o), quantum, top) < top)
+        for ops in costs]
+    assert ragged[0] == sorted(
+        [("InputNorm", 4)] * 32 + [("PostAttnNorm", 4)] * 32
+        + [("Embedding", 4), ("FinalNorm", 4)]
+        + [("LinearAttnCore", 14)] * 24)
+    assert ragged[1] == []
+    assert ragged[2] == [("KCompress", 2)] * 8
+    assert ragged[3] == [("KCompress", 4)] * 8
+    # a job's memory: parameter state + 2 x activations
+    state = 16 * sum(o["params"] for o in costs[0])
+    jobs_gb = [(state + 2 * arch.ACT_BYTES
+                * sum(o["out_elems"] for o in ops)) / 1e9 for ops in costs]
+    assert jobs_gb == pytest.approx([171.3, 308.9, 309.1, 781.3], abs=0.05)
+    # the rest is env_trinity_32's
+    changed = {"jobs_config", "max_simulation_run_time", "pad_obs_kwargs"}
+    assert {k: v for k, v in cfg.items() if k not in changed} \
+        == {k: v for k, v in base.items() if k not in changed}
+    for key in set(jobs) - {"architecture", "job_interarrival_time_dist"}:
+        assert jobs[key] == base["jobs_config"][key], key
+
+
+#: a dense-path shape (S <= the tiny dense_len of 64: 31 forward ops)
+#: whose ops are 17-50 us — ragged rows at degrees 4, 6 and 8 — and a
+#: sparse-path shape (35 forward ops) with steps of 0.33 s and every op
+#: over 8 quanta: two graph sizes in one bank
+TINY_SALA_SHAPES = [{"seq_len": 32, "micro_batch": 4096},
+                    {"seq_len": 128, "micro_batch": 2 ** 17}]
+
+
+def _tiny_sala_env(arch_file, **over):
+    """The whole tiny model (no cut) on env_sala_32's cluster."""
+    jobs = dict(
+        architecture={"config": arch_file, "shapes": TINY_SALA_SHAPES},
+        job_interarrival_time_dist={
+            "_target_": "ddls_tpu.demands.distributions.Fixed", "val": 0.4},
+        max_acceptable_job_completion_time_frac_dist={
+            "_target_": "ddls_tpu.demands.distributions.Uniform",
+            "min_val": 0.1, "max_val": 1.0, "decimals": 2},
+        replication_factor=10, job_sampling_mode="remove_and_repeat",
+        shuffle_files=True, num_training_steps=20)
+    over.setdefault("max_partitions_per_op", 8)
+    return _tiny_env(arch_file, jobs_config=jobs,
+                     max_simulation_run_time=16.0,
+                     pad_obs_kwargs={"max_nodes": 100, "max_edges": 192},
+                     **over)
+
+
+#: beside the verdict, the accepted decisions that ran a RAGGED row (the
+#: 4,096-sequence shape above degree 2) while an earlier job still ran
+SALA_DRIVER = TRINITY_DRIVER.replace(
+    "t._tiny_trinity_env(", "t._tiny_sala_env(").replace(
+    'and e["degree"] > 1', 'and e["degree"] > 2')
+
+
+def test_tiny_sala_runs_the_host_env_with_a_ragged_row_mounted(tmp_path):
+    """A tiny STATED dense family, WHOLE (mixers A L L A), through
+    reader -> mirror -> `Job` -> the host env: one bank holds a 62-op
+    and a 70-op graph of ONE model, and a ragged row is accepted on a
+    cluster that holds a running job. `record_decisions` counts it from
+    the decisions' (job type, action, cause) alone:
+    `env.decisions.accepted_ragged` is 1 for that decision, and over
+    the episode what the partitioner's own splits say."""
+    from ddls_tpu import telemetry
+    from ddls_tpu.rl.fused import record_decisions
+    from ddls_tpu.scenarios.conformance import (decision_events,
+                                                run_recorded_episode)
+    from ddls_tpu.sim.jax_env import (CAUSE_ACCEPTED, CAUSE_STR_TO_CODE,
+                                      build_episode_tables,
+                                      build_partition_action)
+
+    env = _tiny_sala_env(_tiny_sala_arch_file(tmp_path))
+    events, actions = run_recorded_episode(env, 7, max_decisions=24)
+    graphs = {p.details["model"]: p.graph
+              for p in env.cluster.jobs_generator.sampler.prototypes}
+    assert {m: (g.n_ops, g.n_deps) for m, g in graphs.items()} == {
+        "tinysala_s32_b4096": (62, 85), "tinysala_s128_b131072": (70, 101)}
+    kinds = {m: set(g.meta["op_types"].values()) for m, g in graphs.items()}
+    assert "AttnCore" in kinds["tinysala_s32_b4096"]
+    assert {"KCompress", "BlockScoreTopK", "BlockSparseAttnCore"} \
+        <= kinds["tinysala_s128_b131072"]
+    for kind in kinds.values():
+        assert {"LinearAttnCore", "GateProj", "DenseMLPResidual"} <= kind
+        assert not kind & {"Router", "Experts"}
+    model = {e["job_idx"]: e["model"] for e in events
+             if e["kind"] == "job_arrived"}
+    host = decision_events(events)
+    assert len(host) == len(actions) == 24
+
+    def ragged(e):
+        splits = set(build_partition_action(
+            graphs[model[e["job_idx"]]], QUANTUM, e["degree"]).values())
+        return e["degree"] > 0 and len(splits) > 1
+
+    ends, mounted_ragged = [], []
+    for i, e in enumerate(host):
+        if e["accepted"]:
+            if ragged(e) and any(end > e["t"] for end in ends):
+                mounted_ragged.append(i)
+            ends.append(e["t"] + e["jct"])
+    assert mounted_ragged, [(model[e["job_idx"]], e["degree"], e["accepted"])
+                           for e in host]
+
+    et = build_episode_tables(env)
+    ot = {"orig_seq_sum": np.array(
+        [float(graphs[m].finalize()["compute"].sum()) for m in et.types])}
+
+    def counted(indices):
+        trace = {
+            "jtype": np.array([et.types.index(model[host[i]["job_idx"]])
+                               for i in indices]),
+            "action": np.array([host[i]["degree"] for i in indices]),
+            "cause": np.array([CAUSE_ACCEPTED if host[i]["accepted"]
+                               else CAUSE_STR_TO_CODE[host[i]["cause"]]
+                               for i in indices]),
+            "n_occupied": np.zeros(len(indices), np.int64)}
+        was = telemetry.enabled()
+        telemetry.enable()
+        telemetry.reset()
+        try:
+            record_decisions(trace, et, ot)
+            return dict(telemetry.snapshot()["counters"])
+        finally:
+            telemetry.reset()
+            if not was:
+                telemetry.disable()
+
+    one = counted(mounted_ragged[:1])
+    assert (one["env.decisions.offered_ragged"],
+            one["env.decisions.accepted_ragged"]) == (1, 1)
+    whole = counted(range(len(host)))
+    assert whole["env.decisions.offered"] == 24
+    assert whole["env.decisions.offered_ragged"] \
+        == sum(ragged(e) for e in host)
+    assert whole["env.decisions.accepted_ragged"] \
+        == sum(ragged(e) and e["accepted"] for e in host) >= 1
+    assert whole["env.decisions.accepted"] \
+        == sum(e["accepted"] for e in host)
+
+
+@pytest.mark.parametrize("x64,rtol", [(True, 1e-9), (False, 1e-4)],
+                         ids=["x64_1e-9", "f32_1e-4"])
+def test_sala_job_in_kernel_replays_the_host_oracle(tmp_path, x64, rtol):
+    """The same tiny family through the jitted episode kernel against
+    the float64 Python oracle — two graph sizes of one model under one
+    pad class: accepted and cause exactly, JCT to the tolerance, with a
+    ragged row accepted on a cluster that holds a running job."""
+    driver = SALA_DRIVER.format(
+        repo=REPO, tests=os.path.join(REPO, "tests"),
+        benchmarks=os.path.join(REPO, "tests", "benchmarks"),
+        arch_file=_tiny_sala_arch_file(tmp_path), seed=7, x64=x64,
+        rtol=rtol)
+    out = subprocess.run(
+        [sys.executable, "-c", driver], capture_output=True, text=True,
+        timeout=900, env={**os.environ, "JAX_PLATFORMS": "cpu",
+                          "JAX_ENABLE_X64": "1" if x64 else "0"})
+    assert out.returncode == 0, out.stderr[-3000:]
+    verdict = json.loads(out.stdout.strip().splitlines()[-1])
+    assert verdict["mismatch"] is None, verdict
+    assert verdict["decisions"] == 24
+    assert 0 < verdict["accepted"] < 24, verdict
+    assert verdict["ragged_loaded"] >= 1, verdict
+    assert len(verdict["causes"]) >= 2, verdict
+
+
+def test_generator_and_tables_set_the_sala_gauges(tmp_path):
+    """`graphs.arch.layers_linear` / `layers_block_sparse` /
+    `linear_time_share.<model>` from the profile's op names and times,
+    and `forward_ops` / `edges` / `ragged_ops` per SHAPE where one
+    model builds two graphs; the old families read 0 linear layers."""
+    from ddls_tpu.sim.jax_env import (build_episode_tables,
+                                      ragged_forward_ops)
+    from ddls_tpu.telemetry import startup
+
+    startup.registry().reset()
+    env = _tiny_sala_env(_tiny_sala_arch_file(tmp_path))
+    env.reset(seed=0)
+    gauges = startup.gauges()
+    short, long = "tinysala_s32_b4096", "tinysala_s128_b131072"
+    for m, forward, edges, sparse in ((short, 31, 85, 0), (long, 35, 101, 2)):
+        assert gauges[f"graphs.arch.forward_ops.{m}"] == forward
+        assert gauges[f"graphs.arch.edges.{m}"] == edges
+        assert gauges[f"graphs.arch.layers_linear.{m}"] == 2
+        assert gauges[f"graphs.arch.layers_block_sparse.{m}"] == sparse
+        assert gauges[f"graphs.arch.layers_full.{m}"] == 2 - sparse
+        assert gauges[f"graphs.arch.layers_window.{m}"] == 0
+        assert gauges[f"graphs.arch.shared_expert_layers.{m}"] == 0
+        assert 0 < gauges[f"graphs.arch.linear_time_share.{m}"] < 0.2
+        # the quadratic ops: two full cores, or two block scores
+        assert 0 < gauges[f"graphs.arch.quadratic_time_share.{m}"] < 0.2
+    et = build_episode_tables(env)
+    # the short shape's top row: 31 forward ops under 8 quanta, none of
+    # the long one's; by row, degrees (1, 2, 4, 6, 8) a type
+    assert ragged_forward_ops(et) == {long: 0, short: 31}
+    assert et.row_ragged.tolist() == [0, 0, 0, 0, 0, 0, 0, 14, 24, 31]
+    startup.registry().reset()
+    # no old family has a linear or a block-sparse layer
+    report = _arch_gauges(TRINITY_FILE, TRINITY_SHAPES[:1])
+    assert report["graphs.arch.layers_linear.afmoe_s8192_b4"] == 0
+    assert report["graphs.arch.layers_block_sparse.afmoe_s8192_b4"] == 0
+    assert report["graphs.arch.linear_time_share.afmoe_s8192_b4"] == 0
+    # the cell's own shapes: 24 linear layers; 8 full cores up to 8,192
+    # tokens, 8 block-sparse ones beyond
+    report = _arch_gauges(SALA_FILE, SALA_SHAPES)
+    for (s, b), sparse, share in zip(SALA_SHAPES, (0, 0, 8, 8),
+                                     (0.0054, 0.0053, 0.0052, 0.0052)):
+        m = f"minicpm_sala_s{s}_b{b}"
+        assert report[f"graphs.arch.layers_linear.{m}"] == 24
+        assert report[f"graphs.arch.layers_block_sparse.{m}"] == sparse
+        assert report[f"graphs.arch.layers_full.{m}"] == 8 - sparse
+        assert report[f"graphs.arch.forward_ops.{m}"] == 227 + 2 * sparse
+        assert report[f"graphs.arch.edges.{m}"] == 645 + 8 * sparse
+        assert report[f"graphs.arch.linear_time_share.{m}"] \
+            == pytest.approx(share, abs=1e-4)
+    shares = [report["graphs.arch.quadratic_time_share."
+                     f"minicpm_sala_s{s}_b{b}"] for s, b in SALA_SHAPES]
+    assert shares == pytest.approx([0.0144, 0.0284, 0.0035, 0.0140],
+                                   abs=1e-4)

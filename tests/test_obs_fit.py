@@ -46,6 +46,9 @@ CELLS = {
     "glm5_ramp32": ([(218, 347)] * 4, (250, 512), (250, 512)),
     "olmoe_ramp32": ([(262, 389)] * 4, (300, 512), (300, 512)),
     "trinity_ramp32": ([(570, 877)] * 4, (600, 1024), (600, 1024)),
+    # two graph sizes of ONE model: the pad holds the larger
+    "sala_ramp32": ([(486, 709)] * 2 + [(454, 645)] * 2, (500, 768),
+                    (500, 768)),
 }
 N_ACTIONS = 9
 #: the fields of an observation the tables hold a row of, a job type

@@ -682,6 +682,10 @@ def test_listed_counters_are_the_parents_on_the_same_trace(
     row = ep["jtype"][ran] * len(et.degrees) + column[ep["action"][ran]]
     longest = ep["jtype"] == int(np.argmax(ot["orig_seq_sum"]))
     accepted = ep["cause"] == CAUSE_ACCEPTED
+    every = ep["jtype"] * len(et.degrees) + column[ep["action"]]
+    ragged = ((np.asarray(et.tables["f_valid"])[every]
+               & (np.asarray(et.tables["f_split"])[every]
+                  < ep["action"][..., None])).sum(-1) > 0)
     parent = {
         "sim.lookahead.trips": int(own.sum()),
         "sim.lookahead.lockstep_trips": int(own.max(axis=1).sum()),
@@ -705,6 +709,10 @@ def test_listed_counters_are_the_parents_on_the_same_trace(
         "env.decisions.accepted_longest": int(accepted[longest].sum()),
         "env.cluster.occupied_servers": int(ep["n_occupied"].sum()),
         "env.cluster.servers": accepted.size * et.n_srv,
+        # PR 39's two, written out from the tables themselves: the
+        # chosen row has a forward op split fewer ways than its degree
+        "env.decisions.offered_ragged": int(ragged.sum()),
+        "env.decisions.accepted_ragged": int(accepted[ragged].sum()),
     }
     assert {k: counters[k] for k in parent} == parent
     # what is left of the listed names are start-up gauges counted once
