@@ -42,8 +42,8 @@ from ddls_tpu import telemetry
 from ddls_tpu.demands.jobs_generator import BANK_GAUGES
 from ddls_tpu.sim.jax_env import (CAUSE_ACCEPTED, CAUSE_OP_PLACEMENT,
                                   MASK_GAUGES)
-from ddls_tpu.sim.jax_lookahead import (MINOR_GAUGES, stage_trips,
-                                        stage_widths)
+from ddls_tpu.sim.jax_lookahead import (MINOR_GAUGES, narrow_stages,
+                                        stage_trips, stage_widths)
 from ddls_tpu.sim.jax_memo import MemoCounters
 from ddls_tpu.telemetry import scopes, startup
 
@@ -109,12 +109,13 @@ def stacked_job_banks(et, env, n_lanes: int, n_jobs: int,
 #: decision's job type and action, from which ``record_padding_fill``
 #: finds the (model, degree) row each decision ran, and its verdict and
 #: the occupied-server count it saw (``record_decisions``). ``la_trips``,
+#: ``la_rode`` (the servers the decision's job rode, beside its trips),
 #: ``jtype``, ``action``, ``cause`` and ``n_occupied`` are read only
 #: while telemetry is on and are NOT gated on it (ROADMAP D13): the
 #: traced run must be the program the untraced run measures
 EPISODE_TRACE_KEYS = ("done", "ep_return", "ep_blocked", "ep_completed",
-                      "ep_arrived", "la_trips", "jtype", "action",
-                      "cause", "n_occupied")
+                      "ep_arrived", "la_trips", "la_rode", "jtype",
+                      "action", "cause", "n_occupied")
 
 def _count_startup_gauges(names) -> None:
     """Add each set start-up gauge onto the telemetry counter of its
@@ -128,9 +129,10 @@ def _count_startup_gauges(names) -> None:
             telemetry.inc(name, value)
 
 
-def record_lookahead_trips(ep_trace, pads) -> None:
+def record_lookahead_trips(ep_trace, pads, num_workers: int) -> None:
     """Reduce a FETCHED ``[..., B, T]`` lookahead trip trace
-    (``la_trips``: each lane-step's own loop count) into the
+    (``la_trips``: each lane-step's own loop count; ``la_rode``: the
+    servers its job rode, 0 where it ran no trip) into the
     ``sim.lookahead.*`` telemetry counters: ``trips`` — the trips of the
     lane-steps whose lookahead ran (a memo hit and an action that runs
     no lookahead run none), summed;
@@ -144,9 +146,15 @@ def record_lookahead_trips(ep_trace, pads) -> None:
     stage ending at the trip after which the next width holds the
     lanes still live, which each step's own counts give
     (`stage_trips`); ``lockstep_lane_trips`` — each stage's trips times
-    its width, summed: the lane-trips the device paid for. From the
-    tables' ``pads`` (a ``ConfigPads``), once
-    per drained epoch trace: ``dep_slots`` — the dep slots a trip
+    its width, summed: the lane-trips the device paid for;
+    ``narrow_trips`` — the lockstep's trips that ran over the NARROW
+    channel table (`sim/jax_lookahead.py:channel_widths` of the
+    cluster's ``num_workers`` servers and the block side): those of the
+    stages in which every lane live at the stage's entry rode no more
+    servers than it holds (`narrow_stages`, the loop's own rule);
+    ``rode.<n>`` — the lane-steps that ran trips, by the servers their
+    job rode. From the tables' ``pads`` (a ``ConfigPads``), once per
+    drained epoch trace: ``dep_slots`` — the dep slots a trip
     passes over (blocks x split^2) — and ``dep_slots_used`` — the
     largest row's real deps; their ratio is what the block layout's
     padding costs. And from the lookahead's own start-up gauges, set
@@ -155,16 +163,23 @@ def record_lookahead_trips(ep_trace, pads) -> None:
     ``minor_slots`` — the minor-axis extent of the dep state the loop
     carries, in whole 128-wide registers — and ``minor_used`` — the
     real slots of it. The caller gates on ``telemetry.enabled()``."""
-    own = np.asarray(ep_trace["la_trips"])
-    widths = stage_widths(own.shape[-2], int(pads.max_split))
-    by_width = stage_trips(np.moveaxis(own, -2, -1), widths).reshape(
-        -1, len(widths)).sum(axis=0)
+    own = np.moveaxis(np.asarray(ep_trace["la_trips"]), -2, -1)
+    rode = np.moveaxis(np.asarray(ep_trace["la_rode"]), -2, -1)
+    side = int(pads.max_split)
+    widths = stage_widths(own.shape[-1], side)
+    trips = stage_trips(own, widths)
+    by_width = trips.reshape(-1, len(widths)).sum(axis=0)
     telemetry.inc("sim.lookahead.trips", int(own.sum()))
     telemetry.inc("sim.lookahead.lockstep_trips", int(by_width.sum()))
     telemetry.inc("sim.lookahead.lockstep_lane_trips",
                   int(by_width @ np.asarray(widths)))
-    for width, trips in zip(widths, by_width.tolist()):
-        telemetry.inc(f"sim.lookahead.stage_trips.{width}", trips)
+    for width, count in zip(widths, by_width.tolist()):
+        telemetry.inc(f"sim.lookahead.stage_trips.{width}", count)
+    telemetry.inc("sim.lookahead.narrow_trips", int(trips[narrow_stages(
+        own, rode, widths, num_workers, side)].sum()))
+    for servers, count in zip(*np.unique(rode[own > 0],
+                                         return_counts=True)):
+        telemetry.inc(f"sim.lookahead.rode.{servers}", int(count))
     telemetry.inc("sim.lookahead.dep_slots", int(pads.n_deps))
     telemetry.inc("sim.lookahead.dep_slots_used", int(pads.n_deps_used))
     _count_startup_gauges(MINOR_GAUGES)

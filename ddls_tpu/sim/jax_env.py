@@ -1253,7 +1253,11 @@ def _episode_kernels(et: EpisodeTables):
         it before pricing): either joins the memo's hit mask in the
         lookahead's ``skip``, so under ``vmap`` such a lane runs no trips
         (``ev["la_trips"]`` is the loop's own count: 0 for a skipped
-        lane), and neither probes nor enters the memo."""
+        lane; ``ev["la_rode"]`` the servers the job's sub-ops sit on
+        where it ran trips, 0 where it ran none — what decides the
+        width of the lookahead's channel table,
+        `sim/jax_lookahead.py:channel_widths`), and neither probes nor
+        enters the memo."""
         (t, mem, srv_job, chan_occ, slot_valid, slot_t_done, slot_mem,
          slot_servers, slot_chan) = carry
         dt = mem.dtype
@@ -1306,7 +1310,9 @@ def _episode_kernels(et: EpisodeTables):
         return {"ok_place": ok_place, "ok_chan": ok_chan,
                 "engine_ok": engine_ok, "sla_ok": sla_ok, "jct": jct,
                 "new_mem": new_mem, "srv_mask": srv_mask,
-                "chan_mask": chan_mask, "la_trips": trips}, memo
+                "chan_mask": chan_mask, "la_trips": trips,
+                "la_rode": jnp.where(trips > 0, srv_mask.sum(),
+                                     0).astype(jnp.int32)}, memo
 
     def price_all(bank, carry, row):
         """In-kernel candidate pricing: (placeable [n_deg], jct [n_deg])
@@ -1330,9 +1336,10 @@ def _episode_kernels(et: EpisodeTables):
 
     def decision(bank, carry, action, row, memo=None):
         """Decide one queued job; returns ``(carry', (reward, accept,
-        cause, jct, la_trips), memo')``. ``la_trips`` (i32) is the
-        lookahead loop's own trip count for this decision: 0 on a memo
-        hit and on an action that runs no lookahead."""
+        cause, jct, la_trips, la_rode), memo')``. ``la_trips`` (i32) is
+        the lookahead loop's own trip count for this decision: 0 on a
+        memo hit and on an action that runs no lookahead; ``la_rode``
+        (i32) the servers the job rode where it ran trips, else 0."""
         (t, mem, srv_job, chan_occ, slot_valid, slot_t_done, slot_mem,
          slot_servers, slot_chan) = carry
         dt = mem.dtype
@@ -1362,15 +1369,16 @@ def _episode_kernels(et: EpisodeTables):
                                               CAUSE_ACCEPTED))))
             return (accept, cause.astype(jnp.int32), ev["jct"],
                     ev["new_mem"], ev["srv_mask"], ev["chan_mask"],
-                    ev["la_trips"]), mm
+                    ev["la_trips"], ev["la_rode"]), mm
 
         def zero(mm):
             return (jnp.bool_(False), jnp.int32(CAUSE_NOT_HANDLED),
                     jnp.zeros((), dt), mem, jnp.zeros((n_srv,), bool),
-                    jnp.zeros((n_chan,), bool), jnp.int32(0)), mm
+                    jnp.zeros((n_chan,), bool), jnp.int32(0),
+                    jnp.int32(0)), mm
 
-        ((accept, cause, jct, new_mem, srv_mask, chan_mask, la_trips),
-         memo) = jax.lax.cond(action_ok, heavy, zero, memo)
+        ((accept, cause, jct, new_mem, srv_mask, chan_mask, la_trips,
+          la_rode), memo) = jax.lax.cond(action_ok, heavy, zero, memo)
 
         if scenario is not None:
             # inflate AFTER the accept/cause decision: admission is
@@ -1397,7 +1405,8 @@ def _episode_kernels(et: EpisodeTables):
 
         return ((t, mem2, srv_job2, chan_occ2, slot_valid2, slot_t_done2,
                  slot_mem2, slot_servers2, slot_chan2),
-                (reward.astype(dt), accept, cause, jct, la_trips), memo)
+                (reward.astype(dt), accept, cause, jct, la_trips, la_rode),
+                memo)
 
     def advance(bank, carry, queue_row, ptr, next_arrival, done,
                 completed):
@@ -1628,7 +1637,7 @@ def make_episode_fn(et: EpisodeTables,
             has_job = (queue_row >= 0) & ~done
 
             def run(mm):
-                new_carry, (reward, accept, cause, jct, _), mm = decision(
+                new_carry, (reward, accept, cause, jct, *_), mm = decision(
                     bank, carry, action, jnp.clip(queue_row, 0), mm)
                 return (new_carry, reward, accept, cause, jct), mm
 
@@ -1931,7 +1940,7 @@ def make_policy_episode_fn(et: EpisodeTables, ot: dict, model,
                     action = jax.random.categorical(
                         step_rng, logits).astype(jnp.int32)
                 logp = jax.nn.log_softmax(logits)[action]
-                new_carry, (reward, accept, cause, jct, _), mm = \
+                new_carry, (reward, accept, cause, jct, *_), mm = \
                     k.decision(bank, carry, action, row, mm)
                 return (new_carry, action, logp, value, reward, accept,
                         cause, jct), mm
@@ -2050,7 +2059,9 @@ def make_segment_fn(et: EpisodeTables, ot: dict, model, n_steps: int,
     that runs no lookahead). A program counter, not a simulation result
     — the collectors that drain it (``EPISODE_TRACE_KEYS``) ask for it;
     the host reduces it into the ``sim.lookahead.*`` telemetry counters
-    (`rl/fused.py:record_lookahead_trips`). With it rides ``cause``
+    (`rl/fused.py:record_lookahead_trips`), and ``la_rode`` (i32): the
+    servers that decision's job rode, 0 where its lookahead ran no
+    trip. With them rides ``cause``
     (i32, a ``CAUSE_*`` code), the decision's verdict (accepted where it
     is ``CAUSE_ACCEPTED``), which with the ``n_occupied`` field the
     decision saw becomes ``env.decisions.*`` / ``env.cluster.*``
@@ -2102,7 +2113,7 @@ def make_segment_fn(et: EpisodeTables, ot: dict, model, n_steps: int,
                                             logits).astype(jnp.int32)
             logp = jax.nn.log_softmax(logits)[action]
 
-            (new_carry, (reward, accept, cause, jct, la_trips),
+            (new_carry, (reward, accept, cause, jct, la_trips, la_rode),
              memo) = k.decision(bank, carry, action, row, memo)
             accepted, blocked, ret = counters
             # unlike the policy-episode kernel these counters need no
@@ -2148,6 +2159,7 @@ def make_segment_fn(et: EpisodeTables, ot: dict, model, n_steps: int,
                 out["obs"] = obs
             if trace_trips:
                 out["la_trips"] = la_trips
+                out["la_rode"] = la_rode
                 out["cause"] = cause
             if memo is not None:
                 out.update(jax_memo.memo_trace_counters(memo))
@@ -2290,7 +2302,7 @@ def make_oracle_episode_fn(et: EpisodeTables, ot: dict,
                     jnp.where(best_deg >= 0, best_deg, first_valid)
                 ).astype(jnp.int32)
 
-                new_carry, (reward, accept, cause, jct, _), mm = \
+                new_carry, (reward, accept, cause, jct, *_), mm = \
                     k.decision(bank, carry, action, row, mm)
                 return (new_carry, action, reward, accept, cause,
                         jct), mm

@@ -350,6 +350,10 @@ LANE_AXIS = "lookahead_lanes"
 #: dep state the loop carries, in whole registers, and its real slots
 MINOR_GAUGES = ("sim.lookahead.minor_slots", "sim.lookahead.minor_used")
 
+#: start-up gauge set beside them: the widths of the channel table the
+#: lockstep's lane-packed stages are built at (:func:`channel_widths`)
+CHANNEL_GAUGE = "sim.lookahead.channel_widths"
+
 
 class _Layout(NamedTuple):
     """What the tick body (:func:`_tick_loop`) leaves to the shape its
@@ -517,8 +521,43 @@ def _job_layout(op_worker, num_workers: int, dep_ops) -> _Layout:
                    loop=loop)
 
 
+def channel_widths(num_workers: int, side: int) -> tuple:
+    """The widths of the worker axis the lane-packed tick is built at,
+    narrowest first: the block side (what one block of a partitioned op
+    can span, and with parent co-location what a job RIDES but for a
+    ragged row on an empty cluster) and the cluster's servers. One
+    width where the cluster is no wider than a block."""
+    narrow = min(side, num_workers)
+    return (narrow,) if narrow == num_workers else (narrow, num_workers)
+
+
+def dense_servers(op_worker, op_valid, n_lanes: int, num_workers: int):
+    """Each lane's servers renumbered densely, from packed ``op_worker``
+    / ``op_valid`` [No, L*S]: ``(dense, rode)`` — per sub-op the rank of
+    its server among the servers its lane's valid sub-ops sit on, -1
+    where it was -1, and per lane [L] how many those are (the host's
+    ``len(set(job_op_to_worker.values()))`` of a placed job). A valid
+    unplaced sub-op counts as on server 0, which is what the tick's
+    ``clip`` makes of it, so the ranks are a bijection on every server
+    a lane's valid deps can name and channel pairs map one to one. By
+    comparison and reduction over [W] x [No, L*S]: no index per
+    element. Once a stage, outside the loop."""
+    import jax.numpy as jnp
+
+    S = op_worker.shape[1] // n_lanes
+    servers = jnp.arange(num_workers, dtype=jnp.int32)[:, None, None]
+    worker = jnp.clip(op_worker, 0)
+    used = jnp.any(((worker == servers) & op_valid).reshape(
+        num_workers, -1, n_lanes, S), axis=(1, 3))          # [W, L]
+    # a server's new id: the used servers under it
+    below = jnp.repeat(used, S, axis=-1)[:, None] & (servers < worker)
+    dense = jnp.sum(below, axis=0, dtype=jnp.int32)         # [No, L*S]
+    return (jnp.where(op_worker >= 0, dense, -1),
+            jnp.sum(used, axis=0, dtype=jnp.int32))
+
+
 def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
-                   num_workers: int) -> _Layout:
+                   num_workers: int, pinned: bool = False) -> _Layout:
     """L lanes of :class:`DepBlocks` tables, LANE-PACKED: op state is
     [No, L*S] and dep state [B, S_i, L*S_j], the minor axis holding
     (lane, shard) at ``lane*S + shard`` — the DESTINATION shard j for a
@@ -532,7 +571,11 @@ def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
     counts and max are order-free and every float op is elementwise per
     lane, so each lane's bits are the flat form's. ``op_worker`` comes
     packed; ``blocks`` holds [B, L] tables. Loop-invariant tables are
-    built here, outside the ``while_loop``."""
+    built here, outside the ``while_loop``; ``pinned`` holds them there
+    where the layout is one of two forms a ``cond`` picks from — XLA
+    otherwise moves each form's tables into its branch and keeps the
+    [B, No, L*S] one-hot they share between the two, 0.77 GB at 570
+    ops x 1,162 blocks x 16 lanes."""
     from functools import partial
 
     import jax
@@ -575,6 +618,8 @@ def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
 
     w_src = from_source(endpoint_worker(src))              # [B, S_i, L*S]
     w_dst = endpoint_worker(dst)                           # [B, L*S_j]
+    if pinned:
+        w_src, w_dst = jax.lax.optimization_barrier((w_src, w_dst))
 
     def src_done(op_done):
         return from_source(jnp.any((src[:, None] == rows) & op_done, axis=1))
@@ -637,13 +682,20 @@ def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
 
 def _tick_loop(lay: _Layout, op_remaining, op_valid, op_score, num_parents,
                dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
-               skip, max_iters: int, state=None, fit: int = 0):
+               skip, max_iters: int, state=None, fit: int = 0, narrow=None):
     """THE tick loop, in whatever shape ``lay`` carries its state;
-    returns (t, comm_oh, comp_oh, busy, ok, trips) per lane, and the
-    state the loop left. As a stage of the lane schedule
-    (:func:`stage_widths`) it starts from the ``state`` an earlier
-    stage left (None: a job's start) and stops once the next width
-    holds the live lanes (``fit``; 0: when none is live)."""
+    returns (t, comm_oh, comp_oh, busy, ok, trips) per lane, the state
+    the loop left, and whether it ran in the ``narrow`` form. As a
+    stage of the lane schedule (:func:`stage_widths`) it starts from
+    the ``state`` an earlier stage left (None: a job's start) and stops
+    once the next width holds the live lanes (``fit``; 0: when none is
+    live). ``narrow`` — (layout, fits) — is the same state's layout
+    over a narrower channel table and, per lane, whether the lane's
+    job fits it: the loop runs in that form iff every lane LIVE at its
+    entry fits (a frozen lane's state is never written, so the form it
+    is carried through cannot show), else in ``lay``'s."""
+    from functools import partial
+
     import jax
     import jax.numpy as jnp
 
@@ -659,7 +711,7 @@ def _tick_loop(lay: _Layout, op_remaining, op_valid, op_score, num_parents,
         live = (~all_done) & (it < max_iters) & (~stuck)
         return live if skip is None else live & ~skip
 
-    def body(state):
+    def body(state, lay=lay):
         (rem_op, rem_dep, op_done, dep_done, parent_done,
          t, comm_oh, comp_oh, busy, it, stuck) = state
 
@@ -725,12 +777,21 @@ def _tick_loop(lay: _Layout, op_remaining, op_valid, op_score, num_parents,
                  lay.lanes(jnp.zeros((), dt)), lay.lanes(jnp.zeros((), dt)),
                  lay.lanes(jnp.int32(0)), lay.lanes(jnp.bool_(False)))
     with jax.named_scope(scopes.SIM_LOOKAHEAD):
-        out = lay.loop(cond, body, state, fit)
+        if narrow is None:
+            took_narrow, out = None, lay.loop(cond, body, state, fit)
+        else:
+            form, fits = narrow
+            took_narrow = jnp.all(fits | ~cond(state))
+            out = jax.lax.cond(
+                took_narrow,
+                lambda s: form.loop(cond, partial(body, lay=form), s, fit),
+                lambda s: lay.loop(cond, body, s, fit), state)
     (_, _, op_done, dep_done, _, t, comm_oh, comp_oh, busy, it,
      stuck) = out
     finished = (lay.all(op_done | ~op_valid)
                 & lay.all(dep_done | ~dep_valid))
-    return (t, comm_oh, comp_oh, busy, finished & ~stuck, it), out
+    return ((t, comm_oh, comp_oh, busy, finished & ~stuck, it), out,
+            took_narrow)
 
 
 def stage_widths(n_lanes: int, side: int) -> list:
@@ -773,6 +834,27 @@ def stage_trips(own, widths):
     return np.diff(ends, axis=-1, prepend=0)
 
 
+def narrow_stages(own, rode, widths, num_workers: int, side: int):
+    """Which stages of ``widths`` ran over the NARROW channel table
+    (:func:`channel_widths`), [..., stages] bool, by the loop's own
+    rule: from every lane's OWN trip count (``own``, [..., lanes]) and
+    the servers its job rode (``rode``, alike; 0 where it ran no
+    trip). A lane is live at a stage's entry iff its own count passes
+    the trips run before the stage, and a lane-packed stage (under
+    :data:`REGISTER_WIDTH` lanes) is narrow iff no live lane rode more
+    than the narrow width. Where the table has one width, every stage
+    runs at it."""
+    own, rode = np.asarray(own), np.asarray(rode)
+    trips = stage_trips(own, widths)
+    channels = channel_widths(num_workers, side)
+    if len(channels) == 1:
+        return np.ones(trips.shape, bool)
+    before = np.cumsum(trips, axis=-1) - trips
+    live = own[..., None, :] > before[..., None]
+    widest = np.max(np.where(live, rode[..., None, :], 0), axis=-1)
+    return (widest <= channels[0]) & (np.asarray(widths) < REGISTER_WIDTH)
+
+
 def _lane_batched_lookahead(num_workers: int):
     """The block-path lookahead of L jobs at once: every argument
     carries a leading lane axis [L, ...] (``skip``: [L] or None) and so
@@ -799,8 +881,20 @@ def _lane_batched_lookahead(num_workers: int):
     once a stage, outside the loops — into the next stage's state. A
     lane's ticks do not depend on which lanes share its loop, so every
     lane's six results are the one-loop program's bits.
-    ``run.staged`` is the same function with each stage's trip count
-    beside the results, for tests."""
+
+    The worker x worker channel table of a lane-packed stage has two
+    widths (:func:`channel_widths`): what a job RIDES is at most a
+    block's side but for a ragged row spread over an empty cluster, so
+    each stage renumbers its lanes' servers densely
+    (:func:`dense_servers`, once, outside the loop) and runs over the
+    narrow table iff every lane live at its entry fits it — one
+    loop-invariant scalar a stage, a ``lax.cond`` around the stage's
+    loop — else over the cluster's width, the tick as it always was. A
+    bijection on the servers a lane uses maps channel pairs one to one,
+    so both forms give each lane the same bits. No option selects one.
+
+    ``run.staged`` is the same function with, beside the results, each
+    stage's trip count and the channel width it ran at, for tests."""
     from functools import partial
 
     import jax
@@ -817,19 +911,21 @@ def _lane_batched_lookahead(num_workers: int):
                 op_worker, blocks, E, num_workers)),
             op_remaining, op_valid, op_score, num_parents, dep_remaining,
             dep_valid, dep_mutual, dep_is_flow, dep_score, skip, N + E + 4,
-            state, fit)
+            state, fit)[:2]
 
     def one_loop(args, state=None, fit=0):
-        """The loop at ``args``' own lane count, in that count's form;
-        ``state`` comes and goes a lane a row (it goes only from a
-        stage that has a successor: ``fit``)."""
+        """The loop at ``args``' own lane count, in that count's form,
+        and the width of the channel table it ran at; ``state`` comes
+        and goes a lane a row (it goes only from a stage that has a
+        successor: ``fit``)."""
         (op_remaining, op_valid, op_worker, op_score, num_parents,
          dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
          blocks, skip) = args
         L, N = op_remaining.shape
         if L >= REGISTER_WIDTH:
-            return jax.vmap(partial(one_job, fit=fit), axis_name=LANE_AXIS)(
-                *args, state)
+            return (*jax.vmap(partial(one_job, fit=fit),
+                              axis_name=LANE_AXIS)(*args, state),
+                    jnp.int32(num_workers))
         E, B = dep_remaining.shape[1], blocks.src.shape[1]
         S = _block_side(E, B)
 
@@ -852,16 +948,28 @@ def _lane_batched_lookahead(num_workers: int):
             return (ops(rem_op), deps(rem_dep), ops(op_done), deps(dep_done),
                     ops(parent_done)) + tuple(state[5:])
 
-        op_worker = ops(op_worker)
-        out, left = _tick_loop(
-            _packed_layout(op_worker, DepBlocks(blocks.src.T, blocks.dst.T),
-                           L, num_workers),
-            ops(op_remaining), ops(op_valid), ops(op_score),
+        op_worker, op_valid = ops(op_worker), ops(op_valid)
+        blocks = DepBlocks(blocks.src.T, blocks.dst.T)
+        narrow, wide = channel_widths(num_workers, S)[0], num_workers
+        form = None
+        if narrow < wide:
+            # the servers a job RIDES fit a block's side but for a
+            # ragged row on an empty cluster: the same tick over each
+            # lane's own dense server ids and a narrow x narrow table
+            dense, rode = dense_servers(op_worker, op_valid, L, wide)
+            form = (_packed_layout(dense, blocks, L, narrow, pinned=True),
+                    rode <= narrow)
+        out, left, took_narrow = _tick_loop(
+            _packed_layout(op_worker, blocks, L, wide,
+                           pinned=form is not None),
+            ops(op_remaining), op_valid, ops(op_score),
             ops(num_parents), deps(dep_remaining), deps(dep_valid),
             deps(dep_mutual), deps(dep_is_flow), deps(dep_score),
             skip, N + E + 4,
-            None if state is None else pack(state, ops, deps), fit)
-        return out, pack(left, lane_ops, lane_deps) if fit else None
+            None if state is None else pack(state, ops, deps), fit, form)
+        return (out, pack(left, lane_ops, lane_deps) if fit else None,
+                jnp.int32(wide) if form is None
+                else jnp.where(took_narrow, narrow, wide))
 
     def rows(x, lanes):
         """Whole lanes of ``x``: ``lanes`` are distinct and in range."""
@@ -879,9 +987,11 @@ def _lane_batched_lookahead(num_workers: int):
                              "blocks")
         widths = stage_widths(L, S)
         if len(widths) == 1:
-            return one_loop(args)[0], ()
-        part, state = one_loop(args, fit=widths[1])
+            part, _, channels = one_loop(args)
+            return part, (), (channels,)
+        part, state, channels = one_loop(args, fit=widths[1])
         results, lanes, ran = part, jnp.arange(L), [jnp.max(part[5])]
+        took = [channels]
         for width, fit in zip(widths[1:], widths[2:] + [0]):
             # the lanes still live first, in their order, then as many
             # of the others (frozen: they carry their results along) as
@@ -892,15 +1002,16 @@ def _lane_batched_lookahead(num_workers: int):
                 live = live & ~rows(skip, lanes)
             keep = jnp.argsort(~live, stable=True)[:width]
             lanes = rows(lanes, keep)
-            part, state = one_loop(
+            part, state, channels = one_loop(
                 jax.tree_util.tree_map(lambda x: rows(x, lanes), args),
                 jax.tree_util.tree_map(lambda x: rows(x, keep), state), fit)
             ran.append(jnp.max(part[5] - rows(trips, keep)))
+            took.append(channels)
             results = tuple(
                 x.at[lanes].set(y, unique_indices=True,
                                 mode="promise_in_bounds")
                 for x, y in zip(results, part))
-        return results, tuple(ran)
+        return results, tuple(ran), tuple(took)
 
     @jax.custom_batching.custom_vmap
     def run(*args):
@@ -923,11 +1034,13 @@ def _lane_batched_lookahead(num_workers: int):
         # counters
         # (rl/fused.py:record_lookahead_trips): (lane, shard) slots, or
         # the lanes alone
-        minor = lanes if lanes >= REGISTER_WIDTH else \
-            lanes * _block_side(n_deps, n_blocks)
+        side = _block_side(n_deps, n_blocks)
+        minor = lanes if lanes >= REGISTER_WIDTH else lanes * side
         for name, value in zip(MINOR_GAUGES, (
                 -(-minor // REGISTER_WIDTH) * REGISTER_WIDTH, minor)):
             startup.set_gauge(name, value)
+        startup.set_gauge(CHANNEL_GAUGE,
+                          list(channel_widths(num_workers, side)))
         out = tuple(x.reshape((axis_size, -1) + x.shape[1:])
                     for x in run(*args))
         return out, (True,) * len(out)

@@ -1318,7 +1318,8 @@ class RLEpochLoop:
             with telemetry.span("train.telemetry_reduce"):
                 self._record_memo_drain(memo)
                 for ep in fetched:
-                    record_lookahead_trips(ep, harvester.et.pads)
+                    record_lookahead_trips(ep, harvester.et.pads,
+                                           harvester.et.n_srv)
                     record_padding_fill(ep, harvester.et, harvester.ot)
                     record_decisions(ep, harvester.et, harvester.ot)
         return fetched

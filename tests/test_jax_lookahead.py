@@ -212,10 +212,16 @@ class _BlockBuild:
         self.et = et = je.build_episode_tables(env, quantum=quantum)
         tb = et.tables
 
-        def arguments(cfg, other_free):
+        def arguments(cfg, other_free, scatter=0):
             mem = jnp.full((et.n_srv,), et.worker_mem, tb["dep_size"].dtype)
             ots, _, ok = je.jax_allocate_job(mem, other_free, cfg, tb,
                                              et.st, et.pads)
+            # ``scatter`` moves original op o's shards ``scatter * o``
+            # servers on, before pricing: a mounted graph no allocator
+            # makes, riding more servers than a block holds
+            ots = jnp.where(ots >= 0, (ots + scatter * (
+                jnp.arange(ots.shape[0]) // et.pads.max_split)) % et.n_srv,
+                ots)
             times, is_flow, _, op_score, dep_score, _ = \
                 je.jax_price_and_score(ots, cfg, tb, et.st, et.pads,
                                        et.comm)
@@ -448,8 +454,10 @@ def test_stages_run_the_trips_the_host_reckons(block_build, n_lanes, mix):
             else others + [longest]
     args, blocks, placed = _lane_arguments(block_build, lanes)
     want = _flat_per_lane(block_build, args, blocks, skip, lanes)
-    got, ran = _staged(block_build)(args, blocks, skip)
+    got, ran, took = _staged(block_build)(args, blocks, skip)
     _assert_same_bits(got, want, (n_lanes, mix))
+    # a cluster no wider than a block: one width of the channel table
+    assert {int(c) for c in took} == {block_build.et.n_srv}
     own = want[5]
     assert not np.asarray(placed).all()        # an unplaceable row
     assert (own > 0).sum() == {"all_skip": 0, "one_live": 1}.get(
@@ -465,6 +473,232 @@ def test_stages_run_the_trips_the_host_reckons(block_build, n_lanes, mix):
         assert sum(int(r) > 0 for r in ran) >= 3, ran  # stages that tick
     if mix in ("all_skip", "one_live"):
         assert [int(r) for r in ran[:-1]] == [0] * (len(widths) - 1)
+
+
+@pytest.fixture(scope="module")
+def wide_build(tmp_path_factory):
+    """`block_build`'s two tiny graphs on RAMP 4x4x2: 32 servers under
+    a block side of 16, so the lane-packed tick has TWO widths of
+    channel table (`channel_widths`) — at the small pads (192 op x
+    4,352 dep slots)."""
+    from ddls_tpu.graphs.synthetic import generate_pipedream_txt_files
+    from ddls_tpu.sim.jax_lookahead import channel_widths
+
+    out = tmp_path_factory.mktemp("wide_graphs")
+    generate_pipedream_txt_files(str(out), n_cnn=1, n_translation=1,
+                                 seed=31, min_ops=4, max_ops=5)
+    build = _BlockBuild(_ramp_env(str(out), (4, 4, 2), 16),
+                        quantum=_BLOCK_QUANTUM)
+    assert build.et.n_srv == 32 and build.et.pads.max_split == 16
+    assert channel_widths(32, 16) == (16, 32)
+    return build
+
+
+def _rode(args):
+    """Per lane, the servers its valid sub-ops sit on (numpy)."""
+    valid, worker = np.asarray(args[1]), np.asarray(args[2])
+    return np.asarray([len(set(w[v & (w >= 0)].tolist()))
+                       for v, w in zip(valid, worker)])
+
+
+#: a lane: (model, degree, cluster state, scatter). On the empty 32
+#: servers the rows ride as many servers as their degree — but the
+#: ragged ones, whose 4-way ops sit on another block: degree 6 rides 8,
+#: and degree 8 rides 10 beside another job (state 1). ``scatter`` 1
+#: spreads a degree-2 row over 18 servers (`_BlockBuild.arguments`)
+_SHORT, _MID, _LONG = (("cnn_0", 1, 0, 0), ("translation_0", 2, 0, 0),
+                       ("translation_0", 8, 0, 0))
+_RAGGED = [("translation_0", 6, 0, 0), ("translation_0", 8, 1, 0),
+           ("cnn_0", 6, 1, 0)]
+_OVER = ("cnn_0", 2, 0, 1)
+_MIXED = [_SHORT, _MID, _LONG, *_RAGGED, ("cnn_0", 8, 0, 0),
+          ("translation_0", 4, 1, 0)]
+#: case -> (lanes, skipped lanes): (a) every lane on <= 16 servers; (b)
+#: one live lane on 17-20; (c) a skipped lane on > 16, and one that
+#: finishes first and is carried along as a filler, beside live lanes
+#: on <= 16; (d) ragged rows alone (ops split 4 and 6 / 8 ways on
+#: different blocks)
+_CHANNEL_CASES = {
+    "all_narrow": ([_MIXED[i % len(_MIXED)] for i in range(24)], (5, 12)),
+    "one_wide_live": ([_OVER if i == 7 else _MIXED[i % len(_MIXED)]
+                       for i in range(24)], (5,)),
+    "wide_skipped": ([_OVER if i in (0, 9) else _MIXED[i % len(_MIXED)]
+                      for i in range(24)], (0, 9)),
+    "wide_finished_filler": ([_OVER] + [_MID] * 8 + [_LONG] * 15, ()),
+    "ragged_rows": ([_RAGGED[i % len(_RAGGED)] for i in range(24)], ()),
+}
+
+
+@pytest.mark.parametrize("case", _CHANNEL_CASES)
+def test_channel_table_width_follows_the_servers_ridden(wide_build, case):
+    """A cluster wider than a block: every lane's six results are the
+    UNBATCHED FLAT path's bits whichever width of channel table a stage
+    took, and each stage took the narrow one iff every lane live at its
+    entry rode no more servers than it holds — `narrow_stages`, what
+    `record_lookahead_trips` reckons on the host from the same counts."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddls_tpu.sim.jax_lookahead import (narrow_stages, stage_trips,
+                                            stage_widths)
+
+    build, (lanes, skipped) = wide_build, _CHANNEL_CASES[case]
+    n_lanes, S = len(lanes), wide_build.et.pads.max_split
+    cfgs = jnp.asarray([build.row(m, d) for m, d, _, _ in lanes], jnp.int32)
+    states = jnp.stack([build.states[s] for _, _, s, _ in lanes])
+    scatter = jnp.asarray([sc for *_, sc in lanes], jnp.int32)
+    args, blocks, placed = jax.vmap(build.arguments)(cfgs, states, scatter)
+    assert np.asarray(placed).all()
+    skip = jnp.zeros(n_lanes, bool).at[jnp.asarray(skipped, int)].set(True)
+    want = _flat_per_lane(build, args, blocks, skip, lanes)
+    got, ran, took = _staged(build)(args, blocks, skip)
+    _assert_same_bits(got, want, case)
+
+    own, rode = want[5], _rode(args)
+    widths = stage_widths(n_lanes, S)
+    assert widths == [24, 16, 8]
+    assert [int(r) for r in ran] == stage_trips(own, widths).tolist()
+    narrow = narrow_stages(own, np.where(own > 0, rode, 0), widths, 32, S)
+    assert [int(c) for c in took] == np.where(narrow, 16, 32).tolist()
+    over = rode > S
+    assert over.sum() == {"one_wide_live": 1, "wide_skipped": 2,
+                          "wide_finished_filler": 1}.get(case, 0)
+    assert ((rode[over] >= 17) & (rode[over] <= 20)).all()
+    if case in ("all_narrow", "wide_skipped", "ragged_rows"):
+        assert narrow.all()
+        assert (own[list(skipped)] == 0).all()
+    if case == "one_wide_live":
+        assert not narrow[0] and int(ran[0]) > 0
+    if case == "wide_finished_filler":
+        # the lane on 18 servers finishes first (stage one is wide),
+        # the eight next lanes end the stage together, and it is the
+        # sixteenth lane of a stage whose fifteen live lanes ride 8
+        w, m, l = own[0], own[1], own[9]
+        assert w < m < l and set(own[1:9]) == {m} and set(own[9:]) == {l}
+        assert [int(r) for r in ran] == [m, l - m, 0]
+        assert narrow.tolist() == [False, True, True]
+    if case == "ragged_rows":
+        splits = {tuple(sorted(set(np.asarray(
+            build.et.tables["f_split"][int(c)]).tolist()))) for c in cfgs}
+        assert splits == {(4, 6), (4, 8), (1, 2, 6)}
+        assert sorted(set(rode.tolist())) == [8, 10]   # wider than degree
+
+
+def test_one_width_where_the_cluster_is_no_wider_than_a_block(block_build):
+    """16 servers under a block side of 16: one form, no branch — the
+    lockstep's stages hold no ``cond`` and report the cluster's width."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddls_tpu.sim.jax_lookahead import channel_widths
+
+    assert channel_widths(block_build.et.n_srv, 16) == (16,)
+    assert channel_widths(8, 16) == (8,) and channel_widths(72, 16) == (16, 72)
+    lanes = _lanes(block_build, 24)
+    args, blocks, _ = _lane_arguments(block_build, lanes)
+    skip = jnp.zeros(24, bool)
+    traced = jax.make_jaxpr(_staged(block_build))(args, blocks, skip)
+    assert "cond[" not in str(traced)
+    _, _, took = _staged(block_build)(args, blocks, skip)
+    assert [int(c) for c in took] == [16, 16, 16]
+
+
+def test_wide_cluster_branches_once_a_packed_stage(wide_build):
+    """32 servers: each lane-packed stage is ONE ``cond`` on one scalar
+    around two loops; a stage of 128 lanes or more (one job a lane
+    under ``vmap``) has none and reports the cluster's width."""
+    import jax
+    import jax.numpy as jnp
+
+    def conds(n_lanes):
+        lanes = [_MIXED[i % len(_MIXED)] for i in range(n_lanes)]
+        cfgs = jnp.asarray([wide_build.row(m, d) for m, d, _, _ in lanes],
+                           jnp.int32)
+        states = jnp.stack([wide_build.states[s] for _, _, s, _ in lanes])
+        args, blocks, _ = jax.vmap(wide_build.arguments)(cfgs, states)
+        skip = jnp.zeros(n_lanes, bool)
+        staged = _staged(wide_build)
+        text = str(jax.make_jaxpr(staged)(args, blocks, skip))
+        return text.count(" cond["), [int(c) for c in
+                                      staged(args, blocks, skip)[2]]
+
+    assert conds(8) == (1, [16])
+    assert conds(24) == (3, [16, 16, 16])
+    n, took = conds(160)                    # 160 -> 80 -> 40 -> 24 -> 16 -> 8
+    assert n == 5 and took == [32, 16, 16, 16, 16, 16]
+
+
+def test_dense_servers_is_a_bijection_on_the_servers_a_lane_uses():
+    """The renumbering: per lane, ranks 0..rode-1 one to one on the
+    servers its valid sub-ops sit on, in server order; -1 stays -1; a
+    VALID unplaced sub-op counts as on server 0 (what the tick's clip
+    makes of it) and an invalid one counts for nothing."""
+    import jax.numpy as jnp
+
+    from ddls_tpu.sim.jax_lookahead import dense_servers
+
+    rng = np.random.default_rng(5)
+    L, No, S, W = 3, 7, 4, 32
+    worker = rng.integers(0, W, (L, No, S)).astype(np.int32)
+    worker[0] = rng.choice([3, 9, 30, 31], (No, S))     # no server 0
+    worker[1] = rng.permutation(np.arange(No * S) % 20 + 5).reshape(No, S)
+    valid = rng.random((L, No, S)) < 0.8
+    worker[~valid] = -1
+    worker[2, 0, 0], valid[2, 0, 0] = -1, True          # valid, unplaced
+    worker[2][worker[2] == 0] = 1
+
+    def packed(x):      # [L, No, S] -> [No, (l, k)]
+        return jnp.asarray(x.transpose(1, 0, 2).reshape(No, L * S))
+
+    dense, rode = dense_servers(packed(worker), packed(valid), L, W)
+    dense = np.asarray(dense).reshape(No, L, S).transpose(1, 0, 2)
+    for lane in range(L):
+        used = sorted(set(np.clip(worker[lane][valid[lane]], 0, None)
+                          .tolist()))
+        assert int(rode[lane]) == len(used)
+        rank = {server: i for i, server in enumerate(used)}
+        for w, d, v in zip(worker[lane].ravel(), dense[lane].ravel(),
+                           valid[lane].ravel()):
+            assert d == (-1 if w < 0 else rank[w]) or not v
+        assert (dense[lane][worker[lane] < 0] == -1).all()
+    assert int(rode[0]) == 4 and int(rode[1]) > 16
+    # server 0 counts for lane 2 through its valid unplaced sub-op alone
+    assert 0 not in set(worker[2][valid[2]].tolist())
+    assert int(rode[2]) == len(set(worker[2][valid[2]].tolist()))
+
+
+def test_rode_is_the_hosts_count_on_a_mounted_job(dataset_dir):
+    """`dense_servers`' ``rode`` of a mounted job's op -> server map
+    equals the host's ``len(set(job_op_to_worker.values()))``."""
+    import jax.numpy as jnp
+
+    from ddls_tpu.sim.jax_lookahead import dense_servers
+
+    env = _make_env(dataset_dir, max_partitions=8)
+    cluster, seen = env.cluster, []
+    workers = sorted(cluster.topology.workers)
+    orig = cluster._run_lookahead
+
+    def spy(job):
+        seen.append(list(cluster.job_op_to_worker[
+            job.details["job_idx"]].values()))
+        return orig(job)
+
+    cluster._run_lookahead = spy
+    obs, rng = env.reset(seed=0), np.random.RandomState(1)
+    while len(seen) < 6:
+        obs, _, done, _ = env.step(int(rng.choice(np.nonzero(
+            np.asarray(obs["action_mask"]))[0])))
+        assert not done
+    S = 8
+    for on in seen:
+        servers = [workers.index(w) for w in on]
+        worker = np.asarray(servers + [-1] * (-len(servers) % S),
+                            np.int32).reshape(-1, S)
+        _, rode = dense_servers(jnp.asarray(worker),
+                                jnp.asarray(worker >= 0), 1, len(workers))
+        assert int(rode[0]) == len(set(on))
+    assert len({len(set(on)) for on in seen}) > 1
 
 
 def _one_loop_program(num_workers):
@@ -487,11 +721,12 @@ def _one_loop_program(num_workers):
             return x.reshape(L, B, S, S).transpose(1, 2, 0, 3).reshape(
                 B, S, L * S)
 
+        op_worker, op_valid = ops(op_worker), ops(op_valid)
         return jl._tick_loop(
-            jl._packed_layout(ops(op_worker),
+            jl._packed_layout(op_worker,
                               jl.DepBlocks(blocks.src.T, blocks.dst.T), L,
                               num_workers),
-            ops(op_remaining), ops(op_valid), ops(op_score),
+            ops(op_remaining), op_valid, ops(op_score),
             ops(num_parents), deps(dep_remaining), deps(dep_valid),
             deps(dep_mutual), deps(dep_is_flow), deps(dep_score), skip,
             N + E + 4)[0]

@@ -21,6 +21,7 @@ pytestmark = pytest.mark.telemetry
 fused_dataset = test_fused.fused_dataset
 memo_env = test_jax_memo.memo_env
 block_build = test_jax_lookahead.block_build
+wide_build = test_jax_lookahead.wide_build
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -147,6 +148,58 @@ def test_a_discarded_lane_runs_no_trips(memo_env):
     assert la.tolist() == trips.tolist()
 
 
+def test_traced_rode_votes_the_width_the_lockstep_took(wide_build):
+    """32 servers under a block side of 16: a decision's ``la_rode`` is
+    the servers its job's sub-ops sit on where its lookahead ran trips
+    and 0 on the zero path, and `narrow_stages` — the host's reckoning
+    from the two traces — names the width each stage of the kernel's
+    own lockstep reports on the same lanes."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddls_tpu.sim import jax_env as je
+    from ddls_tpu.sim.jax_lookahead import narrow_stages, stage_widths
+
+    et = wide_build.et
+    k = je._episode_kernels(et)
+    bank = {key: jnp.asarray(v) for key, v in je.build_job_bank(et, [
+        {"model": "translation_0", "num_training_steps": 3,
+         "sla_frac": 1.0, "time_arrived": 0.0}]).items()}
+    carry, row = k.init_state(bank)[0], jnp.int32(0)
+    # 24 lanes: every placeable degree in turn, each seventh on the zero path
+    degrees = [d for d in et.degrees if d <= 8]
+    actions = jnp.asarray([0 if i % 7 == 3 else degrees[i % len(degrees)]
+                           for i in range(24)], jnp.int32)
+    trips, rode = (np.asarray(x) for x in jax.jit(jax.vmap(
+        lambda a: k.decision(bank, carry, a, row)[1][4:6]))(actions))
+    assert trips.dtype == rode.dtype == np.int32
+    assert ((trips > 0) == (np.asarray(actions) > 0)).all()
+    assert (rode[trips == 0] == 0).all()
+
+    live = np.asarray(actions) > 0
+    cfgs = jnp.asarray([wide_build.row("translation_0", int(a))
+                        for a in np.asarray(actions)[live]], jnp.int32)
+    args, blocks, placed = jax.vmap(
+        lambda c: wide_build.arguments(c, wide_build.states[0]))(cfgs)
+    assert np.asarray(placed).all()
+    assert rode[live].tolist() == test_jax_lookahead._rode(args).tolist()
+    assert set(rode[live].tolist()) == {1, 2, 4, 8}     # 6 rides 8
+
+    # the same lanes through the lookahead's own staged report
+    every = jax.vmap(lambda c: wide_build.arguments(
+        c, wide_build.states[0]))(jnp.asarray(
+            [wide_build.row("translation_0", max(int(a), 1))
+             for a in np.asarray(actions)], jnp.int32))
+    got, ran, took = test_jax_lookahead._staged(wide_build)(
+        every[0], every[1], jnp.asarray(~live))
+    assert np.asarray(got[5]).tolist() == trips.tolist()
+    widths = stage_widths(24, et.pads.max_split)
+    narrow = narrow_stages(trips, rode, widths, et.n_srv,
+                           et.pads.max_split)
+    assert [int(c) for c in took] == np.where(narrow, 16, 32).tolist()
+    assert narrow.all()
+
+
 def test_an_unplaced_job_runs_no_trips_and_stays_out_of_the_memo(memo_env):
     """The host drops a job it could not place before any lookahead; in
     the kernel such a lane is masked out of the loop and its probe is
@@ -224,6 +277,11 @@ _BENCH_PADS = dict(n_ops=480, n_deps=13312, n_fwd=15, n_parents=2,
                    max_split=16, n_groups=1, n_orig=30, n_blocks=52,
                    n_deps_used=13072)
 
+#: a fetched [U=1, B=3, T=2] trace: each lane-step's own trips, and the
+#: servers its job rode where it ran any
+_TRIPS_EP = {"la_trips": np.array([[[5, 7], [9, 0], [0, 0]]], np.int32),
+             "la_rode": np.array([[[8, 16], [20, 0], [0, 0]]], np.int32)}
+
 
 def test_trip_counters_are_the_hosts_reduction_of_the_trace():
     from ddls_tpu.rl.fused import record_lookahead_trips
@@ -231,15 +289,21 @@ def test_trip_counters_are_the_hosts_reduction_of_the_trace():
 
     # [U=1, B=3, T=2]: step 0 — lanes ran 5 and 9, one ran none (a hit
     # or an action without a lookahead); step 1 — a miss of 7 only
-    ep = {"la_trips": np.array([[[5, 7], [9, 0], [0, 0]]], np.int32)}
+    # ... on 8 and 20 servers (step 0) and on 16 (step 1): under 32
+    # servers and a block side of 16 the 9 trips of step 0 ran over the
+    # cluster-wide channel table, the 7 of step 1 over the narrow one
+    ep = dict(_TRIPS_EP)
     telemetry.enable()
-    record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS))
+    record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS), 32)
     snap = telemetry.snapshot()
     counted = {
         "sim.lookahead.trips": 21,
         "sim.lookahead.lockstep_trips": 16,
         "sim.lookahead.lockstep_lane_trips": 48,
         "sim.lookahead.stage_trips.3": 16,
+        "sim.lookahead.narrow_trips": 7,
+        "sim.lookahead.rode.8": 1, "sim.lookahead.rode.16": 1,
+        "sim.lookahead.rode.20": 1,
         "sim.lookahead.dep_slots": 13312,
         "sim.lookahead.dep_slots_used": 13072}
     assert {k: v for k, v in snap["counters"].items()
@@ -248,7 +312,7 @@ def test_trip_counters_are_the_hosts_reduction_of_the_trace():
     # registry when it ran in a trace (none has, in this test)
     startup.set_gauge("sim.lookahead.minor_slots", 128)
     startup.set_gauge("sim.lookahead.minor_used", 48)
-    record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS))
+    record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS), 32)
     assert {k: v for k, v in telemetry.snapshot()["counters"].items()
             if k.startswith("sim.lookahead.")} == {
         **{k: 2 * v for k, v in counted.items()},
@@ -292,8 +356,24 @@ def test_paid_lane_trips_are_the_stages_widths_times_their_trips(
     by_hand = np.sum([_lockstep_by_hand(own[u, :, t], widths)
                       for u in range(2) for t in range(3)], axis=0)
     telemetry.enable()
-    record_lookahead_trips({"la_trips": own}, ConfigPads(**_BENCH_PADS))
+    rode = np.where(own > 0, rng.integers(1, 21, size=own.shape), 0)
+    record_lookahead_trips({"la_trips": own, "la_rode": rode},
+                           ConfigPads(**_BENCH_PADS), 32)
     counters = telemetry.snapshot()["counters"]
+    # the trips that ran over the narrow channel table: those of the
+    # lane-packed stages entered with every live lane on <= 16 servers
+    narrow = 0
+    for u in range(2):
+        for t in range(3):
+            ran, before = _lockstep_by_hand(own[u, :, t], widths), 0
+            for width, trips in zip(widths, ran):
+                live = own[u, :, t] > before
+                if width < 128 and (rode[u, :, t][live] <= 16).all():
+                    narrow += trips
+                before += trips
+    assert counters["sim.lookahead.narrow_trips"] == narrow
+    assert sum(v for k, v in counters.items()
+               if k.startswith("sim.lookahead.rode.")) == (own > 0).sum()
     assert [counters[f"sim.lookahead.stage_trips.{w}"] for w in widths] \
         == by_hand.tolist()
     assert counters["sim.lookahead.lockstep_trips"] == by_hand.sum() \
@@ -313,12 +393,12 @@ def test_block_fill_metric_reads_the_dep_slot_counters():
     from ddls_tpu.rl.fused import record_lookahead_trips
     from ddls_tpu.sim.jax_env import ConfigPads
 
-    ep = {"la_trips": np.array([[[5, 7], [9, 0], [0, 0]]], np.int32)}
+    ep = dict(_TRIPS_EP)
     ctx = {"spans": {"bench": {"epoch": [(0.0, 1.0), (1.0, 2.0)]}}}
     telemetry.enable()
     assert harness.read_layer_metric("lookahead_block_fill", ctx) is None
     for _ in range(2):
-        record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS))
+        record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS), 32)
     assert harness.read_layer_metric("lookahead_dep_slots", ctx) == 13312
     assert harness.read_layer_metric("lookahead_block_fill", ctx) == \
         pytest.approx(100 * 13072 / 13312)
@@ -345,21 +425,22 @@ def test_minor_fill_metric_reads_what_the_traced_loop_carries(block_lanes):
     from ddls_tpu.sim.jax_env import ConfigPads
 
     build, (args, blocks, _) = block_lanes
-    ep = {"la_trips": np.array([[[5, 7], [9, 0], [0, 0]]], np.int32)}
+    ep = dict(_TRIPS_EP)
     ctx = {"spans": {"bench": {"epoch": [(0.0, 1.0), (1.0, 2.0)]}}}
     telemetry.enable()
     one = jax.tree_util.tree_map(lambda x: x[0], (args, blocks))
     jax.make_jaxpr(build.block_fn)(*one)
     assert startup.gauges() == {}
-    record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS))
+    record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS), 32)
     assert harness.read_layer_metric("lookahead_minor_fill", ctx) is None
 
     jax.make_jaxpr(jax.vmap(build.block_fn))(args, blocks)
     S = build.et.pads.max_split
     assert startup.gauges() == {"sim.lookahead.minor_slots": 128,
-                                "sim.lookahead.minor_used": S * 3}
+                                "sim.lookahead.minor_used": S * 3,
+                                "sim.lookahead.channel_widths": [16]}
     for _ in range(2):
-        record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS))
+        record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS), 32)
     assert harness.read_layer_metric("lookahead_minor_slots", ctx) == 128
     assert harness.read_layer_metric("lookahead_minor_fill", ctx) == \
         pytest.approx(100 * S * 3 / 128)
@@ -662,19 +743,22 @@ def test_listed_counters_are_the_parents_on_the_same_trace(
     rng = np.random.default_rng(34)
     shape = (2, 8, 3)
     ep = {"la_trips": rng.integers(0, 40, shape).astype(np.int32),
+          "la_rode": rng.integers(1, et.n_srv + 1, shape).astype(np.int32),
           "jtype": rng.integers(0, len(et.types), shape).astype(np.int32),
           "action": rng.choice(et.degrees, shape).astype(np.int32),
           "cause": rng.integers(0, 6, shape).astype(np.int32),
           "n_occupied": rng.integers(0, et.n_srv, shape).astype(np.int32)}
     ep["la_trips"][rng.random(shape) < 0.4] = 0
+    ep["la_rode"][ep["la_trips"] == 0] = 0
     telemetry.enable()
-    record_lookahead_trips(ep, et.pads)
+    record_lookahead_trips(ep, et.pads, et.n_srv)
     record_padding_fill(ep, et, ot)
     record_decisions(ep, et, ot)
     counters = telemetry.snapshot()["counters"]
 
     own, ran = ep["la_trips"], ep["la_trips"] > 0
     widths = stage_widths(8, int(et.pads.max_split))
+    assert widths == [8] and int(et.pads.max_split) < et.n_srv == 8
     by_width = stage_trips(np.moveaxis(own, -2, -1), widths).reshape(
         -1, len(widths)).sum(axis=0)
     column = np.zeros(et.max_action + 1, np.int64)
@@ -691,6 +775,11 @@ def test_listed_counters_are_the_parents_on_the_same_trace(
         "sim.lookahead.lockstep_trips": int(own.max(axis=1).sum()),
         "sim.lookahead.lockstep_lane_trips":
             int(by_width @ np.asarray(widths)),
+        # PR 40's: one stage of 8 lanes; a step's trips ran over the
+        # narrow channel table (a block's side of the cluster's 8
+        # servers) iff no lane that ran trips rode more servers
+        "sim.lookahead.narrow_trips": int(own.max(axis=1)[
+            ep["la_rode"].max(axis=1) <= int(et.pads.max_split)].sum()),
         "sim.lookahead.dep_slots": int(et.pads.n_deps),
         "sim.lookahead.dep_slots_used": int(et.pads.n_deps_used),
         "sim.lookahead.dep_slots_decided":
@@ -829,6 +918,8 @@ def test_build_run_leaves_each_startup_span_once(fused_dataset, tmp_path,
         assert gauges == {
             "sim.lookahead.minor_slots": -(-minor // 128) * 128,
             "sim.lookahead.minor_used": minor,
+            # 8 servers under a block side of 8: one width
+            "sim.lookahead.channel_widths": [8],
             "sim.price.dep_indexed_ops": 0,
             "sim.allocate.indexed_ops": 0,
             "env.mask.rows_offered": offered,
