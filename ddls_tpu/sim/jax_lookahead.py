@@ -354,6 +354,11 @@ MINOR_GAUGES = ("sim.lookahead.minor_slots", "sim.lookahead.minor_used")
 #: lockstep's lane-packed stages are built at (:func:`channel_widths`)
 CHANNEL_GAUGE = "sim.lookahead.channel_widths"
 
+#: ... and the elements a trip of the lockstep's first stage compares
+#: against the op-row iota on the vector unit to reach a block's source
+#: and destination op (:func:`endpoint_onehot_elems`)
+ENDPOINT_GAUGE = "sim.lookahead.endpoint_onehot_elems"
+
 
 class _Layout(NamedTuple):
     """What the tick body (:func:`_tick_loop`) leaves to the shape its
@@ -556,8 +561,23 @@ def dense_servers(op_worker, op_valid, n_lanes: int, num_workers: int):
             jnp.sum(used, axis=0, dtype=jnp.int32))
 
 
+def endpoint_onehots(blocks: DepBlocks, n_ops: int):
+    """The 0/1 endpoint matrices of lane-packed ``blocks`` ([B, L]
+    tables): ``(at_src, at_dst)``, each [L, B, No] int8 — is original
+    op o the source / the destination of lane l's block b (a padded
+    block, -1, is no op's). They depend on the tables alone, not on the
+    channel table's width and not on loop state: built once a stage,
+    outside the loop."""
+    import jax.numpy as jnp
+
+    ops = jnp.arange(n_ops, dtype=jnp.int32)
+    return tuple((x.T[:, :, None] == ops).astype(jnp.int8)
+                 for x in (blocks.src, blocks.dst))
+
+
 def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
-                   num_workers: int, pinned: bool = False) -> _Layout:
+                   num_workers: int, pinned: bool = False,
+                   onehots=None) -> _Layout:
     """L lanes of :class:`DepBlocks` tables, LANE-PACKED: op state is
     [No, L*S] and dep state [B, S_i, L*S_j], the minor axis holding
     (lane, shard) at ``lane*S + shard`` — the DESTINATION shard j for a
@@ -570,12 +590,26 @@ def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
     where it meets either state. Nothing indexes per dep; integer
     counts and max are order-free and every float op is elementwise per
     lane, so each lane's bits are the flat form's. ``op_worker`` comes
-    packed; ``blocks`` holds [B, L] tables. Loop-invariant tables are
-    built here, outside the ``while_loop``; ``pinned`` holds them there
-    where the layout is one of two forms a ``cond`` picks from — XLA
-    otherwise moves each form's tables into its branch and keeps the
-    [B, No, L*S] one-hot they share between the two, 0.77 GB at 570
-    ops x 1,162 blocks x 16 lanes."""
+    packed; ``blocks`` holds [B, L] tables.
+
+    A block's source and destination op are found on the MATRIX unit:
+    `src_done` and `count_parents` are one ``dot_general`` each, a lane
+    a batch, with the blocks' 0/1 endpoint matrices
+    (:func:`endpoint_onehots`, [L, B, No] int8; ``onehots`` where two
+    layouts of one stage share them) — [L, B, No] x [L, No, S] over the
+    ops, and [L, No, B] x [L, B, S] over the blocks — where selecting
+    one op row out of No by comparison costs B * No * L * S element
+    steps of the vector unit a primitive a trip (170 M at 570 ops x
+    1,162 blocks x 16 lanes, 36 x the dep state itself). Exact, not
+    approximate: the operands are 0/1 and whole numbers <= S in int8,
+    the products are summed in int32 (``preferred_element_type``, so no
+    result follows ``JAX_ENABLE_X64``), and a sum over a destination's
+    incoming blocks is a whole number however many they are.
+
+    Loop-invariant tables are built here, outside the ``while_loop``;
+    ``pinned`` holds them there where the layout is one of two forms a
+    ``cond`` picks from — XLA otherwise moves each form's tables into
+    its branch."""
     from functools import partial
 
     import jax
@@ -584,6 +618,9 @@ def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
     L, W = n_lanes, num_workers
     No, S = op_worker.shape[0], op_worker.shape[1] // L
     B = blocks.src.shape[0]
+    if S > 127:
+        raise ValueError(f"a block side of {S} passes int8: "
+                         "`count_parents` contracts counts up to it")
     rows = jnp.arange(No, dtype=jnp.int32)[:, None]        # [No, 1]
     workers = jnp.arange(W, dtype=jnp.int32)[:, None]      # [W, 1]
 
@@ -621,14 +658,26 @@ def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
     if pinned:
         w_src, w_dst = jax.lax.optimization_barrier((w_src, w_dst))
 
+    at_src, at_dst = (endpoint_onehots(blocks, No) if onehots is None
+                      else onehots)                        # [L, B, No]
+
+    def contract(onehot, x, over):
+        """``onehot`` [L, B, No] with lane-packed ``x`` [M, (l, k)]
+        along the one-hot's axis ``over`` (1: M = B blocks, 2: M = No
+        ops), a lane a batch: [the other axis, (l, k)] i32. 0/1 times
+        whole numbers <= S in int8, summed in int32: exact."""
+        out = jax.lax.dot_general(
+            onehot, x.reshape(-1, L, S).astype(jnp.int8),
+            (((over,), (0,)), ((0,), (1,))),
+            preferred_element_type=jnp.int32)              # [L, other, S]
+        return out.transpose(1, 0, 2).reshape(-1, L * S)
+
     def src_done(op_done):
-        return from_source(jnp.any((src[:, None] == rows) & op_done, axis=1))
+        return from_source(contract(at_src, op_done, 2) > 0)
 
     def count_parents(parent_done, inc):
         into = inc.sum(axis=1, dtype=inc.dtype)            # [B, L*S_j]
-        return parent_done + jnp.sum(
-            jnp.where(dst == rows[:, None], into, 0), axis=1,
-            dtype=inc.dtype)
+        return parent_done + contract(at_dst, into, 1)
 
     def nominate(dscores, flow_ready):
         # best[X, Y] over the deps whose source sits on worker X and
@@ -820,6 +869,18 @@ def stage_widths(n_lanes: int, side: int) -> list:
     return widths
 
 
+def endpoint_onehot_elems(n_lanes: int, n_ops: int, n_blocks: int,
+                          side: int) -> int:
+    """The elements a trip of the lockstep's first (widest) stage
+    compares against the op-row iota on the vector unit in EACH of
+    `src_done` and `count_parents`, over ``n_ops`` original ops and
+    ``n_blocks`` blocks: none while the stage is lane-packed (both are
+    contractions, :func:`_packed_layout`), blocks x ops x shards a lane
+    from :data:`REGISTER_WIDTH` lanes on (:func:`_block_dep_ops`)."""
+    return 0 if n_lanes < REGISTER_WIDTH else \
+        n_blocks * n_ops * n_lanes * side
+
+
 def stage_trips(own, widths):
     """The trips each stage of ``widths`` (:func:`stage_widths`) runs,
     [..., stages], from every lane's OWN trip count (``own``,
@@ -893,6 +954,14 @@ def _lane_batched_lookahead(num_workers: int):
     bijection on the servers a lane uses maps channel pairs one to one,
     so both forms give each lane the same bits. No option selects one.
 
+    Which op a block starts and ends at, a lane-packed stage asks the
+    matrix unit: both forms contract op state and completed-dep counts
+    with the stage's 0/1 endpoint matrices (:func:`endpoint_onehots`:
+    built from the block tables once a stage, outside the loop and the
+    ``cond``, and pinned there), in int8 with int32 sums — exact
+    (:func:`_packed_layout`). The >= 128-lane form keeps the compare:
+    its one-hots are a sixtieth of its trip.
+
     ``run.staged`` is the same function with, beside the results, each
     stage's trip count and the channel width it ran at, for tests."""
     from functools import partial
@@ -951,17 +1020,20 @@ def _lane_batched_lookahead(num_workers: int):
         op_worker, op_valid = ops(op_worker), ops(op_valid)
         blocks = DepBlocks(blocks.src.T, blocks.dst.T)
         narrow, wide = channel_widths(num_workers, S)[0], num_workers
-        form = None
+        form = onehots = None
         if narrow < wide:
             # the servers a job RIDES fit a block's side but for a
             # ragged row on an empty cluster: the same tick over each
             # lane's own dense server ids and a narrow x narrow table
             dense, rode = dense_servers(op_worker, op_valid, L, wide)
-            form = (_packed_layout(dense, blocks, L, narrow, pinned=True),
+            onehots = jax.lax.optimization_barrier(
+                endpoint_onehots(blocks, N // S))
+            form = (_packed_layout(dense, blocks, L, narrow, pinned=True,
+                                   onehots=onehots),
                     rode <= narrow)
         out, left, took_narrow = _tick_loop(
             _packed_layout(op_worker, blocks, L, wide,
-                           pinned=form is not None),
+                           pinned=form is not None, onehots=onehots),
             ops(op_remaining), op_valid, ops(op_score),
             ops(num_parents), deps(dep_remaining), deps(dep_valid),
             deps(dep_mutual), deps(dep_is_flow), deps(dep_score),
@@ -1041,6 +1113,8 @@ def _lane_batched_lookahead(num_workers: int):
             startup.set_gauge(name, value)
         startup.set_gauge(CHANNEL_GAUGE,
                           list(channel_widths(num_workers, side)))
+        startup.set_gauge(ENDPOINT_GAUGE, endpoint_onehot_elems(
+            lanes, args[0].shape[1] // side, n_blocks, side))
         out = tuple(x.reshape((axis_size, -1) + x.shape[1:])
                     for x in run(*args))
         return out, (True,) * len(out)

@@ -902,6 +902,8 @@ def test_env_lookahead_body_indexes_no_dep(block_build):
     lanes = jax.make_jaxpr(jax.vmap(block_build.block_fn))(args, blocks)
     assert _per_dep_indexing(_lookahead_body(lanes, (128, M)), M) == []
     assert startup.gauges()["sim.lookahead.minor_used"] == 128
+    assert startup.gauges()["sim.lookahead.endpoint_onehot_elems"] == \
+        B * (et.pads.n_ops // S) * 128 * S
 
     args, blocks, _ = block_build.arguments(0, block_build.states[0])
     flat = _lookahead_body(jax.make_jaxpr(block_build.flat_fn)(args, blocks),
@@ -914,6 +916,158 @@ def test_env_lookahead_body_indexes_no_dep(block_build):
         ((N,), "int32"), ((), "float32"), ((), "float32"), ((), "float32"),
         ((), "float32"), ((), "int32"), ((), "bool")]
     assert len(flat.eqns) == _FLAT_BODY_EQNS
+
+
+def _equation_shapes(jaxpr):
+    """(primitive name, shapes of its operands and results) of every
+    equation in ``jaxpr``, nested calls included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, [tuple(v.aval.shape)
+                                   for v in (*eqn.invars, *eqn.outvars)
+                                   if hasattr(v.aval, "shape")]
+        for _, inner in _sub_jaxprs(eqn):
+            yield from _equation_shapes(inner)
+
+
+@pytest.mark.parametrize("n_lanes", [1, 8, 32])
+def test_packed_body_reaches_endpoints_by_contraction(block_build, n_lanes):
+    """The engagement pin of the endpoint form: the lane-packed tick
+    body (the unbatched call's one lane; 8 and 32 lanes under `vmap`)
+    reaches a block's source and destination op through exactly TWO
+    ``dot_general``s, a lane a batch, over the [L, B, No] 0/1 tables —
+    `src_done` and `count_parents` — and holds NO equation over blocks
+    x ops x (lane, shard), the B * No * L * S elements either cost as a
+    compare-and-reduce pass; the gauge says the same."""
+    import jax
+
+    from ddls_tpu.sim.jax_lookahead import ENDPOINT_GAUGE
+    from ddls_tpu.telemetry import startup
+
+    pads = block_build.et.pads
+    B, S, No = pads.n_blocks, pads.max_split, pads.n_ops // pads.max_split
+    if n_lanes == 1:
+        args, blocks, _ = block_build.arguments(0, block_build.states[0])
+        traced = jax.make_jaxpr(block_build.block_fn)(args, blocks)
+    else:
+        args, blocks, _ = _lane_arguments(block_build,
+                                          _lanes(block_build, n_lanes))
+        traced = jax.make_jaxpr(jax.vmap(block_build.block_fn))(args, blocks)
+    body = _lookahead_body(traced, (B, S, S * n_lanes))
+    shapes = list(_equation_shapes(body))
+    dots = [ops for name, ops in shapes if name == "dot_general"]
+    assert [ops[0] for ops in dots] == [(n_lanes, B, No)] * 2
+    assert sorted(ops[-1] for ops in dots) == sorted(
+        [(n_lanes, B, S), (n_lanes, No, S)])
+    passes = {(B, No, n_lanes * S), (No, B, n_lanes * S)}
+    assert not [name for name, ops in shapes if passes & set(ops)]
+    if n_lanes > 1:
+        assert startup.gauges()[ENDPOINT_GAUGE] == 0
+
+
+#: (lanes, original ops, blocks, block side) -> elements a trip of the
+#: first stage compares with the op-row iota in each endpoint primitive
+_ENDPOINT_ELEMS = [
+    (1, 30, 52, 16, 0), (16, 570, 1162, 16, 0), (48, 218, 456, 16, 0),
+    (80, 114, 222, 16, 0), (127, 30, 52, 16, 0),
+    (128, 30, 52, 16, 3_194_880), (320, 30, 52, 16, 7_987_200)]
+
+
+@pytest.mark.parametrize("n_lanes,n_ops,n_blocks,side,elems", _ENDPOINT_ELEMS,
+                         ids=[f"{c[0]}x{c[1]}" for c in _ENDPOINT_ELEMS])
+def test_endpoint_onehot_elems(n_lanes, n_ops, n_blocks, side, elems):
+    """The gauge's table: a lane-packed first stage (under 128 lanes)
+    contracts, whatever the ops and blocks — there is no shape rule —
+    and the one-job-a-lane form from 128 lanes on compares blocks x ops
+    x shards a lane."""
+    from ddls_tpu.sim.jax_lookahead import (REGISTER_WIDTH,
+                                            endpoint_onehot_elems)
+
+    assert endpoint_onehot_elems(n_lanes, n_ops, n_blocks, side) == elems
+    assert (elems == 0) == (n_lanes < REGISTER_WIDTH)
+
+
+def _converging_blocks(n_lanes, n_ops, n_blocks, into):
+    """Hand-built [L, B] block tables in which lane l's first ``into +
+    l`` blocks all END at original op ``l`` (their sources the ops
+    after it, in turn), the rest chain op to op, and the last two are
+    padding."""
+    src = np.full((n_lanes, n_blocks), -1, np.int32)
+    dst = np.full((n_lanes, n_blocks), -1, np.int32)
+    for lane in range(n_lanes):
+        fan = into + lane
+        others = [o for o in range(n_ops) if o != lane]
+        for b in range(n_blocks - 2):
+            if b < fan:
+                src[lane, b], dst[lane, b] = others[b % len(others)], lane
+            else:
+                src[lane, b] = (b + lane) % n_ops
+                dst[lane, b] = (b + lane + 1) % n_ops
+    return src, dst
+
+
+@pytest.mark.parametrize("x64", [False, True], ids=["f32", "x64"])
+@pytest.mark.parametrize("into", [17, 40])
+def test_endpoint_contractions_are_exact_past_bf16(into, x64):
+    """Past bfloat16's whole numbers: ``into`` (>= 17) non-mutual
+    blocks of side 16 complete into ONE destination op in one trip, so
+    a sub-op's parent count rises by ``into`` x 16 >= 272 at once. The
+    lane-packed layout's `count_parents` and `src_done`, called as the
+    tick calls them, equal `_flat_dep_ops`' scatter-add and gather bit
+    for bit and dtype for dtype, on lanes with DIFFERENT tables, with
+    and without ``JAX_ENABLE_X64``."""
+    import jax
+    import jax.numpy as jnp
+
+    from flat_pricing import block_endpoint_slots
+
+    from ddls_tpu.sim.jax_lookahead import (DepBlocks, _flat_dep_ops,
+                                            _packed_layout)
+
+    L, No, S, W = 3, 24, 16, 8
+    B = into + L + 6
+    src, dst = _converging_blocks(L, No, B, into)
+    rng = np.random.default_rng(into)
+    op_done = rng.random((L, No * S)) < 0.5
+    parent_done = rng.integers(0, 5, (L, No * S)).astype(np.int32)
+    inc = np.ones((L, B * S * S), np.int32)      # every dep, this trip
+    inc[:, -2 * S * S:] = 0                       # but the padding's
+    inc[1, : S * S] = rng.integers(0, 2, S * S)   # and a ragged block
+
+    def ops(x):      # [L, (o, k)] -> [No, (l, k)]
+        return jnp.asarray(x.reshape(L, No, S).transpose(1, 0, 2).reshape(
+            No, L * S))
+
+    def deps(x):     # [L, (b, i, j)] -> [B, S_i, (l, j)]
+        return jnp.asarray(x.reshape(L, B, S, S).transpose(1, 2, 0, 3)
+                           .reshape(B, S, L * S))
+
+    with jax.enable_x64(x64):
+        lay = _packed_layout(
+            jnp.asarray(rng.integers(0, W, (No, L * S)), jnp.int32),
+            DepBlocks(jnp.asarray(src.T), jnp.asarray(dst.T)), L, W)
+        got_parents = np.asarray(jax.jit(lay.count_parents)(
+            ops(parent_done), deps(inc)))
+        got_done = np.asarray(jax.jit(lay.src_done)(ops(op_done)))
+        assert got_parents.dtype == np.int32 and got_done.dtype == bool
+        for lane in range(L):
+            dep_src, dep_dst = block_endpoint_slots(
+                jnp.asarray(src[lane]), jnp.asarray(dst[lane]), S)
+            flat_done, flat_parents, _ = _flat_dep_ops(
+                jnp.clip(dep_src, 0), jnp.clip(dep_dst, 0), None, 1)
+            valid = np.repeat(src[lane] >= 0, S * S)
+            want = np.asarray(flat_parents(
+                jnp.asarray(parent_done[lane]),
+                jnp.asarray(np.where(valid, inc[lane], 0))))
+            mine = got_parents.reshape(No, L, S)[:, lane].reshape(-1)
+            assert mine.dtype == want.dtype and (mine == want).all()
+            want = np.asarray(flat_done(jnp.asarray(op_done[lane])))
+            mine = got_done.reshape(B, S, L, S)[:, :, lane].reshape(-1)
+            assert (mine[valid] == want[valid]).all()
+            assert not mine[~valid].any()      # a padded block: no source
+        rose = (got_parents - np.asarray(ops(parent_done))).reshape(No, L, S)
+        ending = (dst[:, :, None] == np.arange(No)).sum(axis=1)    # [L, No]
+        assert (rose[2, 2] == ending[2, 2] * S).all()      # all-ones lane
+        assert rose.max() == ending.max() * S >= (into + L - 1) * S > 272
 
 
 def test_block_path_is_flat_path_at_the_benchmark_pads(tmp_path):

@@ -438,7 +438,8 @@ def test_minor_fill_metric_reads_what_the_traced_loop_carries(block_lanes):
     S = build.et.pads.max_split
     assert startup.gauges() == {"sim.lookahead.minor_slots": 128,
                                 "sim.lookahead.minor_used": S * 3,
-                                "sim.lookahead.channel_widths": [16]}
+                                "sim.lookahead.channel_widths": [16],
+                                "sim.lookahead.endpoint_onehot_elems": 0}
     for _ in range(2):
         record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS), 32)
     assert harness.read_layer_metric("lookahead_minor_slots", ctx) == 128
@@ -920,6 +921,8 @@ def test_build_run_leaves_each_startup_span_once(fused_dataset, tmp_path,
             "sim.lookahead.minor_used": minor,
             # 8 servers under a block side of 8: one width
             "sim.lookahead.channel_widths": [8],
+            # a lane-packed first stage: both endpoint primitives contract
+            "sim.lookahead.endpoint_onehot_elems": 0,
             "sim.price.dep_indexed_ops": 0,
             "sim.allocate.indexed_ops": 0,
             "env.mask.rows_offered": offered,
