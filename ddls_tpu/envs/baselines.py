@@ -129,7 +129,7 @@ class OracleJCT(AcceptableJCT):
 
     Consumes the batched candidate pricing the jax-lookahead go/no-go
     scoped (docs/jax_lookahead_gonogo.md point 2): all candidate degrees
-    priced per decision, one vmapped dispatch on an accelerator. No
+    priced per decision (sim/candidate_pricing.py, the C++ engine). No
     reference counterpart — the reference's heuristics never see real
     lookahead outcomes."""
 

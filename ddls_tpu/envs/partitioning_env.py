@@ -25,7 +25,8 @@ from ddls_tpu.envs import spaces
 from ddls_tpu.envs.obs import RampJobPartitioningObservation
 from ddls_tpu.envs.rewards import make_reward_function
 from ddls_tpu.sim.actions import Action, OpPartition
-from ddls_tpu.sim.cluster import RampClusterEnvironment
+from ddls_tpu.sim.cluster import (RampClusterEnvironment,
+                                  refuse_retired_kwargs)
 from ddls_tpu.telemetry import flight as _flight
 
 OP_PLACERS = {
@@ -65,13 +66,13 @@ class RampJobPartitioningEnvironment:
                  save_cluster_data: bool = False,
                  save_freq: int = 1,
                  use_sqlite_database: bool = False,
-                 use_jax_lookahead: bool = False,
                  use_native_lookahead: str | bool = "auto",
                  apply_action_mask: bool = True,
                  candidate_pricing: Optional[str] = None,
                  obs_include_candidate_prices: bool = False,
                  scenario_runtime=None,
                  **kwargs):
+        refuse_retired_kwargs(kwargs)
         self.topology_config = topology_config
         self.node_config = node_config
         self.jobs_config = jobs_config
@@ -81,14 +82,16 @@ class RampJobPartitioningEnvironment:
         self.job_queue_capacity = job_queue_capacity
         self.apply_action_mask = apply_action_mask
         # opt-in all-candidate lookahead pricing at each decision point
-        # (None | "native" | "jax" | "auto"): prices every valid partition
-        # degree of the queued job, exposes them as env.candidate_prices /
-        # info["candidate_prices"], and prefetches the lookahead memo so
-        # the chosen action's cluster.step lookahead is a cache hit. The
-        # jax backend batches all candidates into ONE vmapped dispatch
-        # (f32 — results carry f32 rounding into the memo cache, same
-        # trade as use_jax_lookahead); "auto" is the bit-exact C++ engine
-        # wherever it exists, with jax as the toolchain-less fallback.
+        # (None | "native" | "auto", both the bit-exact C++ engine):
+        # prices every valid partition degree of the queued job, exposes
+        # them as env.candidate_prices / info["candidate_prices"], and
+        # prefetches the lookahead memo so the chosen action's
+        # cluster.step lookahead is a cache hit. Refused HERE where the
+        # engine does not build: there is no slower backend to fall to.
+        if candidate_pricing:
+            from ddls_tpu.sim.candidate_pricing import check_backend
+
+            check_backend(candidate_pricing)
         self.candidate_pricing = candidate_pricing
         self.candidate_prices: dict = {}
         self.name = name
@@ -100,7 +103,6 @@ class RampJobPartitioningEnvironment:
             path_to_save=path_to_save if save_cluster_data else None,
             save_freq=save_freq,
             use_sqlite_database=use_sqlite_database,
-            use_jax_lookahead=use_jax_lookahead,
             use_native_lookahead=use_native_lookahead,
             suppress_warnings=suppress_warnings,
             scenario_runtime=scenario_runtime)

@@ -24,7 +24,8 @@ from ddls_tpu.envs.rewards import make_reward_function
 from ddls_tpu.envs.shaping_obs import (RampJobPlacementShapingObservation,
                                        shape_action_table)
 from ddls_tpu.sim.actions import Action, JobPlacementShape, OpPartition
-from ddls_tpu.sim.cluster import RampClusterEnvironment
+from ddls_tpu.sim.cluster import (RampClusterEnvironment,
+                                  refuse_retired_kwargs)
 
 OP_PARTITIONERS = {
     "sip_ml_op_partitioner": SipMlOpPartitioner,
@@ -68,10 +69,10 @@ class RampJobPlacementShapingEnvironment:
                  save_cluster_data: bool = False,
                  save_freq: int = 1,
                  use_sqlite_database: bool = False,
-                 use_jax_lookahead: bool = False,
                  use_native_lookahead: str | bool = "auto",
                  apply_action_mask: bool = True,
                  **kwargs):
+        refuse_retired_kwargs(kwargs)
         self.topology_config = topology_config
         self.node_config = node_config
         self.jobs_config = jobs_config
@@ -88,7 +89,6 @@ class RampJobPlacementShapingEnvironment:
             path_to_save=path_to_save if save_cluster_data else None,
             save_freq=save_freq,
             use_sqlite_database=use_sqlite_database,
-            use_jax_lookahead=use_jax_lookahead,
             use_native_lookahead=use_native_lookahead)
 
         if observation_function != "ramp_job_placement_shaping_observation":

@@ -184,8 +184,8 @@ def run_first_fit_block(shapes, meta_shape, ramp_shape, mem, blocked,
 
 
 def run_lookahead(arrays) -> Optional[Tuple[float, float, float, float]]:
-    """Run the C++ lookahead on a ``LookaheadArrays`` built with
-    ``dtype=np.float64`` and exact (unpadded) sizes. Returns
+    """Run the C++ lookahead on a ``LookaheadArrays``
+    (``native/arrays.py``: f64, exact unpadded sizes). Returns
     (t, comm_overhead, comp_overhead, busy) for ONE training step, or
     None when the library is unavailable or the engine could not finish
     (caller falls back to the host engine, which raises with

@@ -1,11 +1,11 @@
 // Native hot-path kernels for the RAMP cluster simulator.
 //
-// The Python host engine (ddls_tpu/sim/cluster.py:_run_lookahead) and the
-// jitted array engine (ddls_tpu/sim/jax_lookahead.py) pin the lookahead
-// semantics; this C++ engine reproduces them bit-for-bit in f64 so it can
-// substitute for the host engine without perturbing golden stats tests
-// (tests/test_stats_parity.py). Reference provenance: the tick loop models
-// ddls ramp_cluster_environment.py:686-800 (see SURVEY.md §3.5).
+// The Python host engine (ddls_tpu/sim/cluster.py:_run_lookahead) pins the
+// lookahead semantics (the in-kernel engine, ddls_tpu/sim/jax_lookahead.py,
+// mirrors them on the device); this C++ engine reproduces them bit-for-bit
+// in f64 so it can substitute for the host engine without perturbing golden
+// stats tests (tests/test_stats_parity.py). Reference provenance: the tick
+// loop models ddls ramp_cluster_environment.py:686-800 (see SURVEY.md §3.5).
 //
 // Semantics (must match cluster.py:_run_lookahead exactly):
 //  * per worker, the highest-score ready op is selected (score encodes
@@ -57,8 +57,8 @@ extern "C" {
 
 // One-training-step lookahead of a mounted job.
 //
-// Inputs are the exact (unpadded) arrays of
-// ddls_tpu.sim.jax_lookahead.build_lookahead_arrays in f64.
+// Inputs are the exact (unpadded) f64 arrays of
+// ddls_tpu.native.arrays.build_native_lookahead_arrays.
 // dep_channel is [n_deps, n_links] with -1 padding.
 // out = {t, comm_overhead, comp_overhead, busy, ok}; ok=0 means the engine
 // could not finish (no progress possible or guard exceeded) and the caller

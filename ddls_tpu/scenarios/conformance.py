@@ -5,14 +5,12 @@ Legs (per spec):
 
 - ``host_native``: seeded host episode vs the C++ lookahead engine
   replaying the same actions — flight traces BIT-exact (rtol 0).
-- ``host_jax``: host vs the jitted jax lookahead kernel — rtol 1e-4
-  (the array engine packs f32 by construction, x64 or not; this is the
-  tolerance tests/test_jax_lookahead.py pins).
 - ``host_jitted``: host decisions vs the fully-jitted episode kernel
-  (``sim/jax_env.py make_episode_fn``) replaying the host action
-  sequence — decision-level diff at 1e-9 (x64). Excluded (with reason)
-  off the dense single-channel complete topology, where the jitted
-  backend does not exist.
+  (``sim/jax_env.py make_episode_fn``, which runs the in-kernel
+  lookahead) replaying the host action sequence — decision-level diff
+  at 1e-9 (x64): the harness's jax backend. Excluded (with reason) off
+  the dense single-channel complete topology, where the jitted backend
+  does not exist.
 - ``golden``: the spec's fabric reproduces the hand-computed golden
   stats (tests/test_stats_parity.py) EXACTLY on a single-op job.
 - ``lint``: the lint engine's backend-surface-parity rule is clean —
@@ -32,9 +30,9 @@ from typing import List, Optional, Sequence
 from ddls_tpu.scenarios.spec import (ScenarioSpec, build_runtime,
                                      env_kwargs, spec_fingerprint)
 
-HOST_BACKENDS = ("host", "native", "jax")
-DEFAULT_LEGS = ("host_native", "host_jax", "host_jitted", "golden",
-                "lint")
+#: the lookahead engines a host env can be built with
+HOST_BACKENDS = ("host", "native")
+DEFAULT_LEGS = ("host_native", "host_jitted", "golden", "lint")
 
 
 def build_env(spec: ScenarioSpec, backend: str = "host",
@@ -52,7 +50,6 @@ def build_env(spec: ScenarioSpec, backend: str = "host",
     return RampJobPartitioningEnvironment(
         **env_kwargs(spec, dataset_dir=dataset_dir,
                      sim_seconds=sim_seconds),
-        use_jax_lookahead=(backend == "jax"),
         use_native_lookahead=(backend == "native"),
         scenario_runtime=runtime)
 
@@ -287,7 +284,7 @@ def run_conformance(spec: ScenarioSpec, seed: int = 0,
     }
 
     host_events = actions = host_env = None
-    if any(l in legs for l in ("host_native", "host_jax", "host_jitted")):
+    if any(l in legs for l in ("host_native", "host_jitted")):
         host_env = build_env(spec, "host", sim_seconds=sim_seconds)
         host_events, actions = run_recorded_episode(
             host_env, seed, max_decisions=max_decisions)
@@ -319,11 +316,6 @@ def run_conformance(spec: ScenarioSpec, seed: int = 0,
             else:
                 report["legs"].append(
                     trace_leg(leg_name, "native", rtol=0.0))
-        elif leg_name == "host_jax":
-            # the array engine packs f32 by construction (x64 changes
-            # nothing): compare at the tolerance the repo pins for it
-            report["legs"].append(
-                trace_leg(leg_name, "jax", rtol=1e-4))
         elif leg_name == "host_jitted":
             supported, reason = _jitted_supported(spec)
             if not supported:

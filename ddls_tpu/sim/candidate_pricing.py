@@ -7,10 +7,10 @@ job's partition degree wants the lookahead outcome of all ~16 candidate
 actions, not just the one it takes. Pricing them one-by-one through the
 host tick engine costs ~100 ms each at RAMP-32 scale (a CPU timing);
 here each candidate's control-plane (partition -> first-fit placement
--> SRPT schedules -> pricing) runs on host over the array pipeline, and the tick engines
-evaluate the batch — the C++ engine per candidate (~0.2 ms, bit-exact
-f64; the default everywhere), or the opt-in vmapped jitted call (kept
-for parity testing; host-dispatched, not measured on the current chip).
+-> SRPT schedules -> pricing) runs on host over the array pipeline, and
+the C++ engine evaluates each candidate (~0.2 ms, bit-exact f64): the
+host's one pricing backend. The in-kernel environment prices its
+candidates inside the device program instead (sim/jax_env.py).
 
 Every priced candidate is inserted into ``cluster.lookahead_cache`` under
 its exact memo key, so the subsequent ``env.step`` with any priced action
@@ -38,11 +38,12 @@ def price_candidate_degrees(env, degrees=None,
     Returns {degree: (jct, comm_oh, comp_oh, busy) | None} where None
     means the candidate is unplaceable (no worker block / busy channels).
     Values are scaled by ``num_training_steps`` exactly like the cluster's
-    own lookahead results.
+    own lookahead results. ``backend`` as :func:`check_backend` takes it.
     """
     from ddls_tpu.agents.placers import RandomOpPlacer
     from ddls_tpu.sim.actions import DepArrays, OpPartition
 
+    check_backend(backend)
     cluster = env.cluster
     if len(cluster.job_queue) == 0:
         return {}
@@ -107,7 +108,7 @@ def price_candidate_degrees(env, degrees=None,
 
     if pending:
         for (d, key, partitioned, _), res in zip(
-                pending, _evaluate(cluster, pending, backend)):
+                pending, _evaluate(cluster, pending)):
             if res is None:
                 results[d] = None
                 continue
@@ -119,60 +120,38 @@ def price_candidate_degrees(env, degrees=None,
     return results
 
 
-def _resolve_backend(backend: str) -> str:
-    if backend != "auto":
-        return backend
-    # auto is the C++ engine wherever it exists: host-dispatched jax
-    # pricing pays a dispatch per candidate batch and a retrace per
-    # distinct batch size. Toolchain-less hosts fall back to jax — slow
-    # prices beat every candidate silently reading "unplaceable". The
-    # jitted env (sim/jax_env.py) prices IN-kernel instead; this host
-    # helper's jax backend remains opt-in for parity tests.
+#: the values of ``candidate_pricing`` / ``backend``: both are the C++
+#: engine
+BACKENDS = ("auto", "native")
+
+
+def check_backend(backend: str) -> None:
+    """Raise ``ValueError`` for a ``backend`` outside :data:`BACKENDS`
+    and ``RuntimeError`` where the C++ engine does not build or load.
+    The env calls this when it is constructed, so a host without a C++
+    toolchain refuses candidate pricing there and not at its first
+    decision."""
+    if backend not in BACKENDS:
+        raise ValueError(f"unknown candidate-pricing backend {backend!r}"
+                         f" ({' | '.join(BACKENDS)}; 'jax', the "
+                         "host-dispatched jitted engine, was retired in "
+                         "PR 42)")
     from ddls_tpu.native import native_available
 
-    return "native" if native_available() else "jax"
+    if not native_available():
+        raise RuntimeError(
+            "candidate pricing runs on the C++ engine (ddls_tpu/native), "
+            "which did not build or load on this host: it needs g++ with "
+            "C++17 (the warning above has the compiler's own words). "
+            "Turn candidate_pricing off, or install the toolchain.")
 
 
-def _evaluate(cluster, pending, backend: str):
-    """Run the tick engine over the pending candidates; returns a list of
+def _evaluate(cluster, pending):
+    """Run the C++ engine over the pending candidates; returns a list of
     per-step (t, comm, comp, busy) tuples (None = engine failed)."""
-    from ddls_tpu.sim.jax_lookahead import (arrays_as_args,
-                                            batched_lookahead_fn,
-                                            build_lookahead_arrays,
-                                            build_native_lookahead_arrays)
+    from ddls_tpu.native import run_lookahead
+    from ddls_tpu.native.arrays import build_native_lookahead_arrays
 
-    backend = _resolve_backend(backend)
-    if backend == "native":
-        from ddls_tpu.native import run_lookahead
-
-        out = []
-        for _, _, partitioned, ctx in pending:
-            arrays = build_native_lookahead_arrays(cluster, partitioned,
-                                                   context=ctx)
-            out.append(run_lookahead(arrays))
-        return out
-    if backend != "jax":
-        raise ValueError(f"unknown candidate-pricing backend {backend!r}"
-                         " (native | jax | auto)")
-
-    def bucket(x: int) -> int:
-        size = 16
-        while size < x:
-            size *= 2
-        return size
-
-    pad_ops = bucket(max(p.graph.n_ops for _, _, p, _ in pending))
-    pad_deps = bucket(max(p.graph.n_deps for _, _, p, _ in pending))
-    batch = [build_lookahead_arrays(cluster, p, pad_ops, pad_deps,
-                                    context=ctx)
-             for _, _, p, ctx in pending]
-    num_workers = max(a.num_workers for a in batch)
-    num_channels = max(a.num_channels for a in batch)
-    fn = batched_lookahead_fn(num_workers, num_channels)
-    stacked = [np.stack(parts) for parts in
-               zip(*(arrays_as_args(a) for a in batch))]
-    t, comm, comp, busy, ok, _trips = (np.asarray(x)
-                                       for x in fn(*stacked))
-    return [((float(t[i]), float(comm[i]), float(comp[i]), float(busy[i]))
-             if bool(ok[i]) else None)
-            for i in range(len(pending))]
+    return [run_lookahead(build_native_lookahead_arrays(
+        cluster, partitioned, context=ctx))
+        for _, _, partitioned, ctx in pending]

@@ -55,6 +55,21 @@ from ddls_tpu.utils.common import save_logs_to_dir, snapshot_logs
 EdgeId = Tuple[str, str]
 
 
+def refuse_retired_kwargs(kwargs: dict) -> None:
+    """Raise ``TypeError`` for a constructor key that once chose something
+    and now chooses nothing. The envs above the cluster end in
+    ``**kwargs``, which would swallow a config that still sets one — and a
+    silently ignored engine choice is worse than the flag was."""
+    if "use_jax_lookahead" in kwargs:
+        raise TypeError(
+            "'use_jax_lookahead' is not an option any more: the "
+            "host-dispatched jitted lookahead engine was retired in PR 42 "
+            "(docs/jax_lookahead_gonogo.md). The host's engines are the C++ "
+            "one (use_native_lookahead) and the Python oracle; the jitted "
+            "lookahead runs inside the in-kernel environment "
+            "(sim/jax_env.py)")
+
+
 class RampClusterEnvironment:
     def __init__(self,
                  topology_config: dict,
@@ -64,7 +79,6 @@ class RampClusterEnvironment:
                  save_freq: int = 1,
                  use_sqlite_database: bool = False,
                  suppress_warnings: bool = True,
-                 use_jax_lookahead: bool = False,
                  use_native_lookahead: str | bool = "auto",
                  machine_epsilon: float = 1e-7,
                  scenario_runtime=None):
@@ -72,13 +86,11 @@ class RampClusterEnvironment:
         # scenario subsystem (ddls_tpu/scenarios, docs/scenarios.md):
         # deterministic failure windows + device-speed multipliers,
         # applied as completion-time inflation at lookahead REGISTRATION
-        # — every lookahead backend stays nominal, so host/C++/jax
+        # — every lookahead backend stays nominal, so host/C++/in-kernel
         # lookahead parity is untouched; None (the default) keeps the
         # legacy hot path byte-identical
         self.scenario_runtime = scenario_runtime
         self.use_sqlite_database = use_sqlite_database
-        # opt-in array-engine lookahead backend (docs/jax_lookahead_gonogo.md)
-        self.use_jax_lookahead = use_jax_lookahead
         # C++ lookahead engine (ddls_tpu/native): bit-exact with the host
         # engine, so "auto" enables it whenever the library builds/loads
         if use_native_lookahead == "auto":
@@ -548,18 +560,14 @@ class RampClusterEnvironment:
             # memo hit): telemetry counters + the flight lookahead event
             backend = "cache"
             if cached is None:
-                # explicit jax opt-in outranks the auto-enabled native
-                # engine; host engine is the always-correct fallback
+                # the C++ engine where it loads; the host engine is the
+                # always-correct fallback
                 backend = "host"
-                if self.use_jax_lookahead:
-                    cached = self._run_jax_lookahead(job)
-                    if cached is not None:
-                        backend = "jax"
-                if cached is None and self.use_native_lookahead:
+                if self.use_native_lookahead:
                     cached = self._run_native_lookahead(job)
                     if cached is not None:
                         backend = "native"
-                if cached is None:  # disabled, or padding/shape fallback
+                if cached is None:  # disabled, or the engine bailed
                     cached = self._run_lookahead(job)
                 self.lookahead_cache[key] = cached
                 if _telemetry.enabled():
@@ -568,7 +576,7 @@ class RampClusterEnvironment:
             elif _telemetry.enabled():
                 _telemetry.inc("sim.lookahead_cache.hit")
             # one simulated training step happened for this job, whichever
-            # backend (host/native/jax) served it and whether or not the
+            # backend (host/native) served it and whether or not the
             # memo cache did — keeps job.training_step_counter meaningful
             # independent of engine choice (RAMP-path completion itself is
             # event-driven off the lookahead JCT, not this counter)
@@ -587,51 +595,15 @@ class RampClusterEnvironment:
         identical semantics AND identical f64 arithmetic order to
         ``_run_lookahead``, so results are bit-exact with the host engine.
         Returns None when the library is unavailable or the engine bails
-        (caller falls through to jax/host paths)."""
+        (caller falls through to the host engine)."""
         from ddls_tpu.native import run_lookahead
-        from ddls_tpu.sim.jax_lookahead import build_native_lookahead_arrays
+        from ddls_tpu.native.arrays import build_native_lookahead_arrays
 
         arrays = build_native_lookahead_arrays(cluster=self, job=job)
         result = run_lookahead(arrays)
         if result is None:
             return None
         t, comm, comp, busy = result
-        steps = job.num_training_steps
-        return t * steps, comm * steps, comp * steps, busy
-
-    def _run_jax_lookahead(self, job: Job):
-        """Cache-miss lookahead on the jitted array engine (opt-in;
-        docs/jax_lookahead_gonogo.md). Pads op/dep counts up to power-of-two
-        buckets so distinct jobs share compiled kernels; returns None to
-        fall back to the host engine when assembly fails (e.g. more
-        channels per flow than the pad allows)."""
-        from ddls_tpu.sim.jax_lookahead import (arrays_as_args,
-                                                build_lookahead_arrays,
-                                                lookahead_fn)
-
-        def bucket(n: int) -> int:
-            size = 16
-            while size < n:
-                size *= 2
-            return size
-
-        try:
-            arrays = build_lookahead_arrays(
-                job=job, cluster=self,
-                pad_ops=bucket(job.graph.n_ops),
-                pad_deps=bucket(job.graph.n_deps),
-                pad_links=2)
-        except ValueError:
-            # padding overflow only; bookkeeping errors (KeyError) must
-            # crash as loudly as they would on the host path
-            return None
-        fn = lookahead_fn(arrays.num_workers, arrays.num_channels)
-        t, comm, comp, busy, ok, _trips = (float(x) for x in fn(
-            *arrays_as_args(arrays)))
-        if not ok:
-            raise RuntimeError(
-                f"jax lookahead failed to converge for job {job.job_id} "
-                "(engine bug)")
         steps = job.num_training_steps
         return t * steps, comm * steps, comp * steps, busy
 

@@ -38,8 +38,7 @@ order — CLAUDE.md invariant).
 
 Numerics: tables are built in f64; under ``JAX_ENABLE_X64=1`` the whole
 step runs in f64 and reproduces host decisions exactly (the parity
-drivers run that way); under default f32 results carry f32 rounding —
-same trade as ``use_jax_lookahead``.
+drivers run that way); under default f32 results carry f32 rounding.
 
 Scope (honest): the placement-shaping env's restricted meta blocks and
 multi-channel topologies stay host-side, and price-feature observations
@@ -775,7 +774,7 @@ def jax_price_and_score(sc, cfg, tables, st: ShapeTables,
     scores — the array mirror of `assign_dep_run_times`
     (sim/actions.py:436), `SRPTOpScheduler`/`SRPTDepScheduler`
     (agents/schedulers.py) and the score assembly in
-    `build_native_lookahead_arrays` (sim/jax_lookahead.py:186).
+    `build_native_lookahead_arrays` (native/arrays.py).
 
     Every operand reaches its dep through the block layout
     (`stack_config_tables`): a dep (b, i, j) runs from the server of
@@ -897,7 +896,8 @@ def jax_price_and_score(sc, cfg, tables, st: ShapeTables,
     dep_pri = jnp.argsort(order).astype(dt)
     # the lookahead engines read dep priorities off the channel mounts, so
     # only FLOW deps carry their SRPT rank; non-flows score with priority 0
-    # (build_native_lookahead_arrays:249-263 prices flow_idx only)
+    # (native/arrays.py:build_native_lookahead_arrays prices flow_idx
+    # only)
     dep_pri = jnp.where(is_flow, dep_pri, jnp.zeros_like(dep_pri))
     dep_score = dep_pri * (m + 1) + (
         m - tables["dep_sorted_rank"][cfg].astype(dt))
@@ -1287,11 +1287,11 @@ def _episode_kernels(et: EpisodeTables):
                 et.tables["op_compute"][cfg], op_valid,
                 jnp.where(op_valid, ots, -1), op_score,
                 et.tables["num_parents"][cfg], times,
-                et.tables["dep_valid"][cfg], None, None,
-                et.tables["dep_mutual"][cfg], is_flow, dep_score, None,
-                num_workers=n_srv, num_channels=n_chan, skip=skip,
-                blocks=DepBlocks(et.tables["blk_src"][cfg],
-                                 et.tables["blk_dst"][cfg]))
+                et.tables["dep_valid"][cfg],
+                et.tables["dep_mutual"][cfg], is_flow, dep_score,
+                DepBlocks(et.tables["blk_src"][cfg],
+                          et.tables["blk_dst"][cfg]),
+                num_workers=n_srv, skip=skip)
             return t_la, ok, trips
 
         if memo is None:

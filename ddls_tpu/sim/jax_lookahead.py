@@ -1,11 +1,15 @@
-"""Jittable (vmappable) lookahead tick engine over fixed-size padded arrays.
+"""The in-kernel lookahead: a jittable, vmappable tick engine over
+fixed-size padded arrays in the partitioner's block layout.
 
-The north-star prototype (SURVEY.md §3.5, §7.4.2): the host engine
-(``cluster._run_lookahead``) simulates one training step of a mounted job by
-dependency-driven ticking; this module reproduces those exact semantics as a
-``lax.while_loop`` over padded arrays so the lookahead can run inside jit —
-one step toward HBM-resident environment rollouts — and be vmapped over a
-batch of jobs.
+The host engine (``cluster._run_lookahead``; SURVEY.md §3.5, §7.4.2)
+simulates one training step of a mounted job by dependency-driven
+ticking; this module reproduces those exact semantics as a
+``lax.while_loop`` over padded arrays, for the in-kernel environment
+(``sim/jax_env.py``), which calls it inside its device program once per
+(job, degree) candidate. It holds the kernel and nothing of the host
+simulator: the C++ engine's packer is ``ddls_tpu/native/arrays.py``, and
+the per-dep gather/scatter form the block path is held to bit for bit is
+the tests' (``tests/flat_lookahead.py``).
 
 Semantics mirrored from the host engine (cluster.py ``_run_lookahead``):
 
@@ -30,287 +34,14 @@ static shapes; invalid slots carry ``valid=False`` masks.
 """
 from __future__ import annotations
 
-import dataclasses
 from fractions import Fraction
-from functools import lru_cache as _lru_cache
-from typing import Dict, NamedTuple, Tuple
+from typing import NamedTuple
 
 import numpy as np
 
 from ddls_tpu.telemetry import scopes
 
 BIG = np.float32(3.4e38)  # stands in for +inf inside the kernel
-
-
-@dataclasses.dataclass
-class LookaheadArrays:
-    """Padded single-job lookahead inputs (all numpy, ready for device).
-
-    Shapes: N = padded ops, E = padded deps, L = max channels per flow dep.
-    ``op_score``/``dep_score`` are priority-with-rank combined scores
-    (higher wins; distinct per valid slot). ``dep_channel`` holds channel
-    indices (-1 padding) into a dense per-job channel renumbering.
-    """
-    op_remaining: np.ndarray   # [N] f32
-    op_valid: np.ndarray       # [N] bool
-    op_worker: np.ndarray      # [N] i32 (dense worker index, -1 pad)
-    op_score: np.ndarray       # [N] f32
-    num_parents: np.ndarray    # [N] i32 (non-mutual parent deps)
-    dep_remaining: np.ndarray  # [E] f32
-    dep_valid: np.ndarray      # [E] bool
-    dep_src: np.ndarray        # [E] i32
-    dep_dst: np.ndarray        # [E] i32
-    dep_mutual: np.ndarray     # [E] bool
-    dep_is_flow: np.ndarray    # [E] bool
-    dep_score: np.ndarray      # [E] f32
-    dep_channel: np.ndarray    # [E, L] i32 (-1 pad)
-    num_workers: int           # static
-    num_channels: int          # static
-
-
-def build_lookahead_arrays(cluster, job, pad_ops: int, pad_deps: int,
-                           pad_links: int = 1,
-                           context: dict | None = None) -> LookaheadArrays:
-    """Assemble padded arrays for a job already mounted on the cluster
-    (the same inputs the host engine reads). f32: feeds the jitted engine
-    (the C++ engine has its own exact-size f64 packer,
-    :func:`build_native_lookahead_arrays`). ``context`` as in
-    :func:`build_native_lookahead_arrays` (candidate pricing of unmounted
-    placements)."""
-    job_idx = job.details["job_idx"]
-    graph = job.graph
-    arrays = graph.finalize()
-    n, m = graph.n_ops, graph.n_deps
-    if n > pad_ops or m > pad_deps:
-        raise ValueError(f"job needs ({n},{m}) > padding ({pad_ops},{pad_deps})")
-
-    topo = cluster.topology
-    op_to_worker = (context["op_to_worker"] if context is not None
-                    else cluster.job_op_to_worker[job_idx])
-    # dense per-job worker renumbering (only workers holding this job matter)
-    worker_ids = sorted({op_to_worker[op] for op in graph.op_ids})
-    worker_dense = {w: i for i, w in enumerate(worker_ids)}
-
-    op_remaining = np.zeros(pad_ops, np.float32)
-    op_remaining[:n] = arrays["compute"]
-    op_valid = np.zeros(pad_ops, bool)
-    op_valid[:n] = True
-    op_worker = np.full(pad_ops, -1, np.int32)
-    op_score = np.zeros(pad_ops, np.float32)
-    num_parents = np.zeros(pad_ops, np.int32)
-    num_parents[:n] = arrays["num_parents"]
-
-    # host tie-break: first op in sorted-id order among priority maxes
-    sorted_rank = {op: r for r, op in enumerate(sorted(graph.op_ids))}
-    ctx_op_pri = context.get("op_pri") if context is not None else None
-    for op_id in graph.op_ids:
-        i = arrays["op_index"][op_id]
-        w = op_to_worker[op_id]
-        op_worker[i] = worker_dense[w]
-        if ctx_op_pri is not None:
-            pri = ctx_op_pri.get(op_id, 0)
-        else:
-            pri = topo.workers[w].op_priority.get(job_idx, {}).get(op_id, 0)
-        op_score[i] = pri * (n + 1) + (n - sorted_rank[op_id])
-
-    dep_remaining = np.zeros(pad_deps, np.float32)
-    dep_valid = np.zeros(pad_deps, bool)
-    dep_valid[:m] = True
-    dep_src = np.zeros(pad_deps, np.int32)
-    dep_dst = np.zeros(pad_deps, np.int32)
-    dep_mutual = np.zeros(pad_deps, bool)
-    dep_mutual[:m] = arrays["edge_mutual"]
-    dep_is_flow = np.zeros(pad_deps, bool)
-    dep_score = np.zeros(pad_deps, np.float32)
-    dep_channel = np.full((pad_deps, pad_links), -1, np.int32)
-
-    # dense per-job channel renumbering
-    chan_dense: Dict[str, int] = {}
-    dep_sorted_rank = {e: r for r, e in enumerate(sorted(graph.edge_ids))}
-    worker_to_server = topo.worker_to_server
-    # array pipeline: channel/priority reads come off the DepArrays
-    # payload (the channel dicts stay empty on that path)
-    payload = (context.get("payload") if context is not None
-               else getattr(cluster, "job_dep_arrays", {}).get(job_idx))
-    if payload is not None:
-        chan_l = payload.chan.tolist()
-        pri_l = (payload.pri.tolist() if payload.pri is not None
-                 else [0] * len(chan_l))
-        edge_chan = {e: ((c,) if c >= 0 else ())
-                     for e, c in zip(payload.edge_ids, chan_l)}
-        edge_pri = dict(zip(payload.edge_ids, pri_l))
-    else:
-        edge_chan = edge_pri = None
-    # flow-ness comes from THE canonical predicate (OpGraph.flow_mask);
-    # the mask is aligned with finalize()'s edge order, which is exactly
-    # what arrays["edge_index"] indexes
-    _, edge_flow = graph.flow_mask(
-        [worker_to_server[op_to_worker[op]] for op in graph.op_ids])
-    for edge in graph.edge_ids:
-        ei = arrays["edge_index"][edge]
-        u, v = edge
-        dep_src[ei] = arrays["op_index"][u]
-        dep_dst[ei] = arrays["op_index"][v]
-        dep_remaining[ei] = job.dep_init_run_time.get(edge, 0.0)
-        is_flow = bool(edge_flow[ei])
-        dep_is_flow[ei] = is_flow
-        if is_flow:
-            if edge_chan is not None:
-                channels = edge_chan.get(edge, ())
-            else:
-                channels = sorted(cluster.job_dep_to_channels.get(
-                    job_idx, {}).get(edge, ()))
-            if len(channels) > pad_links:
-                raise ValueError(
-                    f"dep {edge} rides {len(channels)} channels > pad_links "
-                    f"{pad_links}")
-            for li, ch_id in enumerate(channels):
-                dep_channel[ei, li] = chan_dense.setdefault(
-                    ch_id, len(chan_dense))
-            if edge_pri is not None:
-                pri = edge_pri.get(edge, 0) if channels else 0
-            else:
-                ch = (topo.channel_id_to_channel[channels[0]]
-                      if channels else None)
-                pri = (ch.dep_priority.get(job_idx, {}).get(edge, 0)
-                       if ch is not None else 0)
-        else:
-            pri = 0
-        dep_score[ei] = pri * (m + 1) + (m - dep_sorted_rank[edge])
-
-    return LookaheadArrays(
-        op_remaining=op_remaining, op_valid=op_valid, op_worker=op_worker,
-        op_score=op_score, num_parents=num_parents,
-        dep_remaining=dep_remaining, dep_valid=dep_valid, dep_src=dep_src,
-        dep_dst=dep_dst, dep_mutual=dep_mutual, dep_is_flow=dep_is_flow,
-        dep_score=dep_score, dep_channel=dep_channel,
-        num_workers=max(len(worker_dense), 1),
-        num_channels=max(len(chan_dense), 1))
-
-
-def build_native_lookahead_arrays(cluster, job,
-                                  context: dict | None = None
-                                  ) -> LookaheadArrays:
-    """Exact-size f64 packing for the C++ engine (ddls_tpu/native).
-
-    Produces the same arrays as :func:`build_lookahead_arrays` (same score
-    formulas, so results are identical), but vectorised: the only Python
-    loops left are one O(n_ops) pass for worker/priority lookups and one
-    pass over *flow* deps for channel lists — the O(n_deps) per-edge dict
-    walk is replaced by index arithmetic on ``graph.finalize()`` arrays.
-
-    ``context`` supplies placement state for a job NOT mounted on the
-    cluster (candidate pricing): {"op_to_worker": {op: worker_id},
-    "op_pri": {op: pri}, "payload": DepArrays}. Without it, state is read
-    from the cluster's mounted structures.
-    """
-    job_idx = job.details["job_idx"]
-    graph = job.graph
-    arrays = graph.finalize()
-    n, m = graph.n_ops, graph.n_deps
-    topo = cluster.topology
-    op_ids = arrays["op_ids"]
-    if context is not None:
-        op_to_worker = context["op_to_worker"]
-        ctx_op_pri = context.get("op_pri") or {}
-    else:
-        op_to_worker = cluster.job_op_to_worker[job_idx]
-        ctx_op_pri = None
-    worker_to_server = topo.worker_to_server
-    workers = topo.workers
-
-    op_worker = np.empty(n, np.int32)
-    op_pri = np.zeros(n, np.float64)
-    server_of_op = []
-    worker_dense: Dict[str, int] = {}
-    pri_maps: Dict[str, Dict[str, int]] = {}
-    for i, op_id in enumerate(op_ids):
-        w = op_to_worker[op_id]
-        wi = worker_dense.get(w)
-        if wi is None:
-            wi = worker_dense.setdefault(w, len(worker_dense))
-            pri_maps[w] = (ctx_op_pri if ctx_op_pri is not None
-                           else workers[w].op_priority.get(job_idx, {}))
-        op_worker[i] = wi
-        server_of_op.append(worker_to_server[w])
-        pri = pri_maps[w].get(op_id, 0)
-        if pri:
-            op_pri[i] = pri
-
-    op_score = op_pri * (n + 1) + (n - arrays["op_sorted_rank"])
-
-    edge_src = arrays["edge_src"].astype(np.int32)
-    edge_dst = arrays["edge_dst"].astype(np.int32)
-    _, dep_is_flow = graph.flow_mask(server_of_op)
-
-    if getattr(job, "dep_init_run_time_arr", None) is not None:
-        dep_remaining = job.dep_init_run_time_arr
-    else:
-        dep_remaining = np.zeros(m, np.float64)
-        edge_index = arrays["edge_index"]
-        for edge, t in job.dep_init_run_time.items():
-            dep_remaining[edge_index[edge]] = t
-
-    # channels + priorities: flow deps only
-    dep_pri = np.zeros(m, np.float64)
-    edge_ids = arrays["edge_ids"]
-    flow_idx = np.nonzero(dep_is_flow)[0]
-    payload = (context.get("payload") if context is not None
-               else getattr(cluster, "job_dep_arrays", {}).get(job_idx))
-    if payload is not None:
-        # array pipeline: channels/priorities straight off the DepArrays
-        # payload; per-job local channel renumbering is one searchsorted
-        # (numbering order is irrelevant — channels only partition deps).
-        # pri=None (placement without a schedule) degrades to priority 0
-        # exactly like the host engine's zeros fallback
-        pri_src = (payload.pri if payload.pri is not None
-                   else np.zeros(m, np.int64))
-        dep_pri[flow_idx] = pri_src[flow_idx].astype(np.float64)
-        uniq = np.unique(payload.chan[flow_idx])
-        n_chan = len(uniq)
-        dep_channel = np.full((m, 1), -1, np.int32)
-        dep_channel[flow_idx, 0] = np.searchsorted(
-            uniq, payload.chan[flow_idx]).astype(np.int32)
-    else:
-        chan_dense: Dict[str, int] = {}
-        dep_to_channels = cluster.job_dep_to_channels.get(job_idx, {})
-        channel_id_to_channel = topo.channel_id_to_channel
-        flow_channels = []
-        links = 1
-        for ei in flow_idx:
-            edge = edge_ids[ei]
-            channels = sorted(dep_to_channels.get(edge, ()))
-            dense = []
-            for ch_id in channels:
-                ci = chan_dense.get(ch_id)
-                if ci is None:
-                    ci = chan_dense.setdefault(ch_id, len(chan_dense))
-                dense.append(ci)
-            flow_channels.append(dense)
-            if len(dense) > links:
-                links = len(dense)
-            if channels:
-                pri = channel_id_to_channel[channels[0]].dep_priority.get(
-                    job_idx, {}).get(edge, 0)
-                if pri:
-                    dep_pri[ei] = pri
-        n_chan = len(chan_dense)
-        dep_channel = np.full((m, links), -1, np.int32)
-        for ei, dense in zip(flow_idx, flow_channels):
-            dep_channel[ei, :len(dense)] = dense
-
-    dep_score = dep_pri * (m + 1) + (m - arrays["edge_sorted_rank"])
-
-    return LookaheadArrays(
-        op_remaining=arrays["compute"], op_valid=np.ones(n, bool),
-        op_worker=op_worker, op_score=op_score,
-        num_parents=arrays["num_parents"].astype(np.int32),
-        dep_remaining=dep_remaining, dep_valid=np.ones(m, bool),
-        dep_src=edge_src, dep_dst=edge_dst,
-        dep_mutual=arrays["edge_mutual"], dep_is_flow=dep_is_flow,
-        dep_score=dep_score, dep_channel=dep_channel,
-        num_workers=max(len(worker_dense), 1),
-        num_channels=max(n_chan, 1))
 
 
 class DepBlocks(NamedTuple):
@@ -322,9 +53,9 @@ class DepBlocks(NamedTuple):
     (b*S + i)*S + j over E = B*S*S, and every valid dep has ``dep_src``
     = src[b]*S + i and ``dep_dst`` = dst[b]*S + j. A flow dep's channel
     is its ordered (source worker, destination worker) pair — one
-    channel per direction of a server pair, workers clipped at 0 as the
-    caller's channel lookup clips them — and ``dep_channel`` is not
-    read."""
+    channel per direction of a server pair, an unplaced op's worker (-1)
+    read as 0, as the caller's channel lookup (``placement_masks``,
+    sim/jax_env.py) reads it: no per-dep channel table exists."""
     src: object  # [B] i32 original-op slot of each block's source, -1 pad
     dst: object  # [B] i32 ... of its destination
 
@@ -388,37 +119,6 @@ def _block_side(n_deps: int, n_blocks: int) -> int:
     return int(round((n_deps // n_blocks) ** 0.5))
 
 
-def _flat_dep_ops(dep_src, dep_dst, dep_channel, num_channels):
-    """The tick body's three dep primitives for an ARBITRARY graph: one
-    gather or scatter per dep (the mounted-graph callers, and the
-    reference the block forms are tested against)."""
-    import jax.numpy as jnp
-
-    def src_done(op_done):
-        return op_done[dep_src]
-
-    def count_parents(parent_done, inc):
-        return parent_done.at[dep_dst].add(inc)
-
-    def nominate(dscores, flow_ready):
-        # per-channel highest-score ready flow dep (scatter-max); a dep
-        # is nominated iff it is the best on at least one of its channels
-        ch_best = jnp.full((num_channels,), -1.0)
-        for li in range(dep_channel.shape[1]):
-            ch_idx = dep_channel[:, li]
-            contrib = jnp.where(ch_idx >= 0, dscores, -1.0)
-            ch_best = ch_best.at[jnp.clip(ch_idx, 0)].max(contrib)
-        nominated = jnp.zeros(dscores.shape, bool)
-        for li in range(dep_channel.shape[1]):
-            ch_idx = dep_channel[:, li]
-            nominated = nominated | (
-                (ch_idx >= 0) & flow_ready
-                & (dscores >= ch_best[jnp.clip(ch_idx, 0)]) & (dscores > 0))
-        return nominated
-
-    return src_done, count_parents, nominate
-
-
 def block_endpoints(op_worker, blocks: DepBlocks, side: int):
     """Where a block's deps start and end, without an index per dep:
     ``from_src`` [B, No] / ``into_dst`` [No, B] (is original op o the
@@ -426,8 +126,8 @@ def block_endpoints(op_worker, blocks: DepBlocks, side: int):
     [B, S_j], the worker of the sub-op on each of the block's rows and
     columns, selected from per-sub-op ``op_worker`` [No * S] by those
     one-hots. An unplaced op (-1) reads as worker 0 — it rides server
-    0's channels exactly as the flat forms' clipped lookups have it —
-    and so does every row of a padded block (-1)."""
+    0's channels, as a clipped per-dep lookup would have it — and so
+    does every row of a padded block (-1)."""
     import jax.numpy as jnp
 
     No = op_worker.shape[0] // side
@@ -445,8 +145,9 @@ def _block_dep_ops(op_worker, blocks: DepBlocks, n_deps: int,
     """The same three primitives over :class:`DepBlocks` tables: dep
     state is [B, S_i, S_j], op state [No, S], and nothing indexes per
     dep. Integer counts and max are order-free, so each returns the
-    flat form's bits. The one-hot masks are loop-invariant: built here,
-    outside the ``while_loop``."""
+    bits of a gather or scatter per dep (the tests' reference,
+    tests/flat_lookahead.py). The one-hot masks are loop-invariant:
+    built here, outside the ``while_loop``."""
     import jax
     import jax.numpy as jnp
 
@@ -491,9 +192,12 @@ def _block_dep_ops(op_worker, blocks: DepBlocks, n_deps: int,
 
 
 def _job_layout(op_worker, num_workers: int, dep_ops) -> _Layout:
-    """ONE job: op state [N], dep state [E], scalar accumulators, the
-    dep primitives either builder above gives. Batched by ``jax.vmap``
-    it stays one job a lane, and XLA lays the lanes minor."""
+    """ONE job: op state [N], dep state [E], scalar accumulators, and
+    ``dep_ops`` its three dep primitives — :func:`_block_dep_ops`' here;
+    a parameter because the tests' bitwise reference
+    (tests/flat_lookahead.py) runs this layout and the same tick body
+    over a gather or scatter per dep. Batched by ``jax.vmap`` it stays
+    one job a lane, and XLA lays the lanes minor."""
     import jax
     import jax.numpy as jnp
 
@@ -589,7 +293,7 @@ def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
     per-lane value is [L], and repeated over the shards (``spread``)
     where it meets either state. Nothing indexes per dep; integer
     counts and max are order-free and every float op is elementwise per
-    lane, so each lane's bits are the flat form's. ``op_worker`` comes
+    lane, so each lane's bits are the one-job form's. ``op_worker`` comes
     packed; ``blocks`` holds [B, L] tables.
 
     A block's source and destination op are found on the MATRIX unit:
@@ -645,8 +349,8 @@ def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
             (B, S, L, S)).reshape(B, S, L * S)
 
     # each block's endpoint rows, per (lane, shard) slot; an unplaced op
-    # (-1) rides server 0's channels exactly as the flat caller's
-    # clipped ``pair_channel`` lookup has it
+    # (-1) rides server 0's channels, as the caller's clipped
+    # ``pair_channel`` lookup has it
     src, dst = spread(blocks.src), spread(blocks.dst)      # [B, L*S]
     worker = jnp.clip(op_worker, 0)
 
@@ -1123,23 +827,19 @@ def _lane_batched_lookahead(num_workers: int):
 
 
 def jax_lookahead(op_remaining, op_valid, op_worker, op_score, num_parents,
-                  dep_remaining, dep_valid, dep_src, dep_dst, dep_mutual,
-                  dep_is_flow, dep_score, dep_channel,
-                  *, num_workers: int, num_channels: int, skip=None,
-                  blocks: DepBlocks | None = None):
-    """One-training-step lookahead; returns
+                  dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
+                  blocks: DepBlocks, *, num_workers: int, skip=None):
+    """One-training-step lookahead of a job laid out by block; returns
     (t, comm_oh, comp_oh, busy, ok, trips).
 
-    ``blocks`` chooses how the tick body reaches a dep's endpoints and
-    channel, and the shape its state is carried in: None — per-dep
-    gather/scatter through ``dep_src`` / ``dep_dst`` / ``dep_channel``
-    over flat [N] / [E] state, for any graph; a :class:`DepBlocks` —
-    broadcast and reduction over the partitioner's (block, i, j)
-    layout, for tables laid out that way (the in-kernel env's); under
-    ``vmap`` (any nest of them) the lanes run as ONE loop whose state
-    is lane-packed while the lanes are few
+    Op state is [N] and dep state [E] in the partitioner's (block, i, j)
+    layout, and ``blocks`` (:class:`DepBlocks`) says where each block's
+    deps start and end: the tick body reaches a dep's endpoints and
+    channel by broadcast and reduction over that layout, never through
+    an index per dep. Under ``vmap`` (any nest of them) the lanes run as
+    ONE loop whose state is lane-packed while the lanes are few
     (:func:`_lane_batched_lookahead`), and the unbatched call is that
-    loop at one lane. Same tick, same bits.
+    loop at one lane.
 
     ``trips`` is the loop's own iteration count (i32): 0 for a
     ``skip``-masked lane, and under ``vmap`` each lane's OWN count — the
@@ -1165,58 +865,9 @@ def jax_lookahead(op_remaining, op_valid, op_worker, op_score, num_parents,
     """
     import jax
 
-    if blocks is None:
-        N = op_remaining.shape[0]
-        E = dep_remaining.shape[0]
-        return _tick_loop(
-            _job_layout(op_worker, num_workers, _flat_dep_ops(
-                dep_src, dep_dst, dep_channel, num_channels)),
-            op_remaining, op_valid, op_score, num_parents, dep_remaining,
-            dep_valid, dep_mutual, dep_is_flow, dep_score, skip,
-            N + E + 4)[0]
     one_lane = jax.tree_util.tree_map(
         lambda x: x[None],
         (op_remaining, op_valid, op_worker, op_score, num_parents,
          dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
          blocks, skip))
     return tuple(x[0] for x in _lane_batched_lookahead(num_workers)(*one_lane))
-
-
-def lookahead_fn(num_workers: int, num_channels: int):
-    """Jitted single-job lookahead closure over static sizes (memoised
-    process-wide: identical (workers, channels) share one trace; array
-    shapes further specialise inside jax's own jit cache)."""
-    return _lookahead_fn_cached(num_workers, num_channels)
-
-
-@_lru_cache(maxsize=None)
-def _lookahead_fn_cached(num_workers: int, num_channels: int):
-    import jax
-    from functools import partial
-
-    return jax.jit(partial(jax_lookahead, num_workers=num_workers,
-                           num_channels=num_channels))
-
-
-def batched_lookahead_fn(num_workers: int, num_channels: int):
-    """vmapped+jitted lookahead over a batch of padded jobs (leading batch
-    axis on every array input). Memoised per static (workers, channels)
-    pair — a fresh jax.jit object would recompile on every call."""
-    return _batched_lookahead_fn_cached(num_workers, num_channels)
-
-
-@_lru_cache(maxsize=None)
-def _batched_lookahead_fn_cached(num_workers: int, num_channels: int):
-    import jax
-    from functools import partial
-
-    fn = partial(jax_lookahead, num_workers=num_workers,
-                 num_channels=num_channels)
-    return jax.jit(jax.vmap(fn))
-
-
-def arrays_as_args(a: LookaheadArrays) -> Tuple[np.ndarray, ...]:
-    return (a.op_remaining, a.op_valid, a.op_worker, a.op_score,
-            a.num_parents, a.dep_remaining, a.dep_valid, a.dep_src,
-            a.dep_dst, a.dep_mutual, a.dep_is_flow, a.dep_score,
-            a.dep_channel)

@@ -10,7 +10,7 @@ tick loop (sim/cluster.py, sim/actions.py, envs/partitioning_env.py).
 Traces feed three consumers:
 
 * ``scripts/trace_diff.py`` — run one scenario through two lookahead
-  backends (host / C++ / jax, or the fully-jitted episode kernels at
+  backends (host / C++, or the fully-jitted episode kernels at
   decision level) and report the FIRST divergent event, turning "parity
   test failed" into "event 412: lookahead jct 3.81 vs 3.84";
 * ``scripts/trace_export.py`` — Chrome-trace/Perfetto JSON, so an

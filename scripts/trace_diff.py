@@ -1,7 +1,7 @@
 """Cross-backend simulator trace diffing: find the FIRST divergent event.
 
-The build carries four semantics-locked simulator backends (host Python,
-C++ lookahead, jax lookahead, fully-jitted episode kernels) whose parity
+The build carries three semantics-locked simulator backends (host Python,
+C++ lookahead, fully-jitted episode kernels) whose parity
 tests pin endpoints only — this tool turns "parity failed" into "event
 412: lookahead jct 3.81 vs 3.84" by running ONE scenario through two
 backends with the flight recorder on (ddls_tpu/telemetry/flight.py) and
@@ -23,10 +23,8 @@ Usage::
     # diff two previously saved traces (e.g. from --save-a/--save-b)
     python scripts/trace_diff.py files a.jsonl b.jsonl
 
-Backends: ``host`` (pure-Python lookahead), ``native`` (C++ engine),
-``jax`` (jitted lookahead kernel — its array packers are f32 by
-construction, so pass ``--rtol 1e-4``, the tolerance
-tests/test_jax_lookahead.py pins), ``jitted`` (the whole-episode
+Backends: ``host`` (pure-Python lookahead), ``native`` (C++ engine)
+— ``conformance.HOST_BACKENDS`` — and ``jitted`` (the whole-episode
 kernel ``sim/jax_env.py:make_episode_fn`` replaying the host action
 sequence; compared at decision level — `action_decided` events only,
 mask context dropped since the replay kernel sees no observation).
@@ -53,7 +51,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # sim-only workload: pinned to the CPU whatever the caller exported
 os.environ["JAX_PLATFORMS"] = "cpu"
 
-HOST_BACKENDS = ("host", "native", "jax")
+from ddls_tpu.scenarios.conformance import HOST_BACKENDS  # noqa: E402
 
 
 def _report(div, label_a: str, label_b: str, n_a: int, n_b: int) -> int:

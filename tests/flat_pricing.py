@@ -216,7 +216,8 @@ def flat_price_and_score(sc, cfg, tables, st, pads, comm, pair_channel):
         jnp.arange(M, dtype=dt))
     # the lookahead engines read dep priorities off the channel mounts, so
     # only FLOW deps carry their SRPT rank; non-flows score with priority 0
-    # (build_native_lookahead_arrays:249-263 prices flow_idx only)
+    # (native/arrays.py:build_native_lookahead_arrays prices flow_idx
+    # only)
     dep_pri = jnp.where(is_flow, dep_pri, jnp.zeros_like(dep_pri))
     dep_score = dep_pri * (m + 1) + (
         m - tables["dep_sorted_rank"][cfg].astype(dt))

@@ -1,7 +1,7 @@
 """Batched candidate-degree pricing: parity with the cluster's own
-lookahead, memo-cache prefetching, the jax batched backend, and the
-OracleJCT consumer (docs/jax_lookahead_gonogo.md point 2; VERDICT r2 next
-#3)."""
+lookahead, memo-cache prefetching, the backends it takes and refuses,
+and the OracleJCT consumer (docs/jax_lookahead_gonogo.md point 2;
+VERDICT r2 next #3)."""
 import tempfile
 
 import numpy as np
@@ -121,25 +121,36 @@ def test_unplaceable_candidates_price_none(dataset_dir):
         assert busy > 0
 
 
-def test_jax_backend_matches_native_prices(dataset_dir):
-    """One vmapped dispatch over all candidates agrees with the bit-exact
-    C++ engine to f32 tolerance (the documented jax-engine trade)."""
+@pytest.mark.parametrize("backend", ["jax", "host"])
+def test_unknown_backend_raises(dataset_dir, backend):
+    """The C++ engine is the only pricing backend: the retired value
+    ``jax`` and any other string raise ``ValueError``, from the call and
+    from the env's constructor alike."""
     env = RampJobPartitioningEnvironment(**_env_kwargs(dataset_dir))
     env.reset(seed=5)
-    native = env.price_candidate_degrees(backend="native")
-    env2 = RampJobPartitioningEnvironment(**_env_kwargs(dataset_dir))
-    env2.reset(seed=5)
-    jaxp = env2.price_candidate_degrees(backend="jax")
-    assert set(native) == set(jaxp)
-    compared = 0
-    for a in native:
-        if native[a] is None:
-            assert jaxp[a] is None
-            continue
-        for lhs, rhs in zip(native[a][:3], jaxp[a][:3]):
-            assert rhs == pytest.approx(lhs, rel=2e-4, abs=1e-5)
-        compared += 1
-    assert compared >= 3
+    with pytest.raises(ValueError, match="candidate-pricing backend"):
+        env.price_candidate_degrees(backend=backend)
+    with pytest.raises(ValueError, match="candidate-pricing backend"):
+        RampJobPartitioningEnvironment(
+            **_env_kwargs(dataset_dir, candidate_pricing=backend))
+
+
+@pytest.mark.parametrize("backend", ["auto", "native"])
+def test_pricing_without_the_engine_is_refused_at_construction(
+        dataset_dir, monkeypatch, backend):
+    """Where the C++ engine does not build, ``candidate_pricing`` is
+    refused when the env is constructed, by an error that names the
+    engine and its toolchain: there is no slower backend to fall to."""
+    from ddls_tpu import native
+
+    monkeypatch.setattr(native, "native_available", lambda: False)
+    with pytest.raises(RuntimeError, match=r"C\+\+ engine.*g\+\+"):
+        RampJobPartitioningEnvironment(
+            **_env_kwargs(dataset_dir, candidate_pricing=backend,
+                          use_native_lookahead=False))
+    # ... and pricing off still constructs there
+    RampJobPartitioningEnvironment(
+        **_env_kwargs(dataset_dir, use_native_lookahead=False))
 
 
 def test_oracle_jct_respects_sla_better_than_approximation(dataset_dir):

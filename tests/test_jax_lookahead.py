@@ -1,15 +1,21 @@
-"""The jitted array lookahead must reproduce the host tick engine's
-JCT/overhead outputs on real mounted jobs (SURVEY.md §7.4.1: build the
-host oracle first, then property-test the array engine against it)."""
+"""The in-kernel lookahead (`sim/jax_lookahead.py`) against the host
+tick engine, by way of its flat reference (`tests/flat_lookahead.py`):
+the reference reproduces the host engine's JCT/overhead outputs on real
+mounted jobs (SURVEY.md §7.4.1: build the host oracle first, then
+property-test the array engine against it), and the block and
+lane-packed forms the package runs equal the reference bit for bit."""
 import numpy as np
 import pytest
 
 from ddls_tpu.envs.partitioning_env import RampJobPartitioningEnvironment
+from ddls_tpu.envs.placement_shaping_env import (
+    RampJobPlacementShapingEnvironment)
+from ddls_tpu.sim.cluster import RampClusterEnvironment
 
 
 def _make_env(dataset_dir, max_partitions=4):
     # the C++ engine (auto-enabled) would absorb every cache-miss lookahead
-    # before the host/jax engines under test here ever ran
+    # before the host engine under test here ever ran
     return RampJobPartitioningEnvironment(
         use_native_lookahead=False,
         topology_config={"type": "ramp", "kwargs": {
@@ -36,8 +42,9 @@ def _make_env(dataset_dir, max_partitions=4):
 
 def _collect_cases(env, actions, n_cases):
     """Step the env with the given action sequence, capturing
-    (host lookahead outputs, padded arrays) per successfully placed job."""
-    from ddls_tpu.sim.jax_lookahead import build_lookahead_arrays
+    (host lookahead outputs, the C++ engine's packing of the job) per
+    successfully placed job."""
+    from ddls_tpu.native.arrays import build_native_lookahead_arrays
 
     cases = []
     obs = env.reset(seed=0)
@@ -48,8 +55,7 @@ def _collect_cases(env, actions, n_cases):
     def spy(job):
         jct, comm, comp, busy = orig(job)
         steps = job.num_training_steps
-        arrays = build_lookahead_arrays(cluster, job, pad_ops=160,
-                                        pad_deps=520, pad_links=2)
+        arrays = build_native_lookahead_arrays(cluster, job)
         cases.append({"host": (jct / steps, comm / steps, comp / steps),
                       "host_busy": busy,
                       "arrays": arrays})
@@ -82,7 +88,12 @@ def _collect_cases(env, actions, n_cases):
 
 @pytest.mark.parametrize("actions", ["max", "random"])
 def test_matches_host_engine(dataset_dir, actions):
-    from ddls_tpu.sim.jax_lookahead import arrays_as_args, lookahead_fn
+    """The flat reference, in f32 at fixed pads, on the jobs a real
+    episode mounts: the host engine's outputs to f32 precision."""
+    from functools import partial
+
+    import jax
+    from flat_lookahead import flat_lookahead, padded_args
 
     env = _make_env(dataset_dir)
     cases = _collect_cases(env, actions, n_cases=6)
@@ -92,8 +103,10 @@ def test_matches_host_engine(dataset_dir, actions):
     for case in cases:
         a = case["arrays"]
         key = (a.num_workers, a.num_channels)
-        fn = fns.setdefault(key, lookahead_fn(*key))
-        t, comm, comp, busy, ok, _trips = fn(*arrays_as_args(a))
+        fn = fns.setdefault(key, jax.jit(partial(
+            flat_lookahead, num_workers=key[0], num_channels=key[1])))
+        t, comm, comp, busy, ok, _trips = fn(*padded_args(
+            a, pad_ops=160, pad_deps=520, pad_links=2))
         assert bool(ok), "array engine failed to converge"
         host_t, host_comm, host_comp = case["host"]
         assert float(t) == pytest.approx(host_t, rel=1e-4), \
@@ -104,53 +117,24 @@ def test_matches_host_engine(dataset_dir, actions):
                                             abs=1e-6)
 
 
-def test_vmapped_batch(dataset_dir):
-    """vmap over a batch of jobs padded to common shapes."""
-    from ddls_tpu.sim.jax_lookahead import (arrays_as_args,
-                                            batched_lookahead_fn)
-
-    env = _make_env(dataset_dir)
-    cases = _collect_cases(env, "random", n_cases=4)
-    # pad worker/channel statics to the max across the batch
-    W = max(c["arrays"].num_workers for c in cases)
-    C = max(c["arrays"].num_channels for c in cases)
-    fn = batched_lookahead_fn(W, C)
-    batch = [np.stack([arrays_as_args(c["arrays"])[k] for c in cases])
-             for k in range(13)]
-    t, comm, comp, busy, ok, _trips = fn(*batch)
-    assert bool(np.all(ok))
-    for bi, case in enumerate(cases):
-        assert float(t[bi]) == pytest.approx(case["host"][0], rel=1e-4)
-
-
-def test_cluster_opt_in_backend_matches_host(dataset_dir):
-    """use_jax_lookahead=True: a full episode's outcomes (JCTs, blocking,
-    overheads, utilisation) match the host engine's episode to f32
-    precision (docs/jax_lookahead_gonogo.md integration)."""
-    episodes = {}
-    for use_jax in (False, True):
-        env = _make_env(dataset_dir)
-        env.cluster.use_jax_lookahead = use_jax
-        obs = env.reset(seed=0)
-        done, steps = False, 0
-        while not done and steps < 60:
-            mask = np.asarray(obs["action_mask"])
-            a = int(np.nonzero(mask)[0][-1])  # max parallelism: misses cache
-            obs, _, done, _ = env.step(a)
-            steps += 1
-        episodes[use_jax] = env.cluster.episode_stats
-
-    host, jaxe = episodes[False], episodes[True]
-    assert jaxe["num_jobs_completed"] == host["num_jobs_completed"]
-    assert jaxe["num_jobs_blocked"] == host["num_jobs_blocked"]
-    assert jaxe["job_completion_time"] == pytest.approx(
-        host["job_completion_time"], rel=1e-4)
-    assert jaxe["job_communication_overhead_time"] == pytest.approx(
-        host["job_communication_overhead_time"], rel=1e-4, abs=1e-6)
-    assert jaxe["jobs_completed_mean_mounted_worker_utilisation_frac"] == (
-        pytest.approx(
-            host["jobs_completed_mean_mounted_worker_utilisation_frac"],
-            rel=1e-4))
+@pytest.mark.parametrize("make,is_env", [
+    (RampJobPartitioningEnvironment, True),
+    (RampJobPlacementShapingEnvironment, True),
+    (RampClusterEnvironment, False)],
+    ids=["partitioning", "shaping", "cluster"])
+def test_use_jax_lookahead_is_refused_by_name(make, is_env):
+    """The retired engine's flag is REFUSED, by name, whatever its
+    value: both envs end in ``**kwargs`` and would swallow a config that
+    still sets it (a silently ignored engine choice), the cluster's
+    signature no longer has it."""
+    kwargs = {"topology_config": {}, "node_config": {}}
+    if is_env:
+        kwargs["jobs_config"] = {}
+    for value in (True, False):
+        with pytest.raises(TypeError, match="use_jax_lookahead") as err:
+            make(**kwargs, use_jax_lookahead=value)
+        # the envs: by the retirement's own message, not by accident
+        assert not is_env or "retired in PR 42" in str(err.value)
 
 
 # ---------------------------------------------------------------------------
@@ -203,6 +187,7 @@ class _BlockBuild:
         import jax
         import jax.numpy as jnp
 
+        from flat_lookahead import block_arguments, flat_lookahead
         from flat_pricing import block_endpoint_slots
 
         from ddls_tpu.sim import jax_env as je
@@ -243,13 +228,12 @@ class _BlockBuild:
 
         def flat(args, blocks, skip=None):
             del blocks
-            return jax_lookahead(*args, num_workers=et.n_srv,
-                                 num_channels=et.n_chan, skip=skip)
+            return flat_lookahead(*args, num_workers=et.n_srv,
+                                  num_channels=et.n_chan, skip=skip)
 
         def block(args, blocks, skip=None):
-            return jax_lookahead(*args, num_workers=et.n_srv,
-                                 num_channels=et.n_chan, skip=skip,
-                                 blocks=blocks)
+            return jax_lookahead(*block_arguments(args), blocks,
+                                 num_workers=et.n_srv, skip=skip)
 
         self.flat_fn, self.block_fn = flat, block
         self.arguments = jax.jit(arguments)
@@ -406,7 +390,9 @@ def test_stage_widths(n_lanes, side, widths):
 def _block_arguments(args, blocks, skip):
     """``_lane_arguments``' outputs as the lane-batched lookahead takes
     them: without the flat path's per-dep endpoints and channel."""
-    return (*args[:7], *args[9:12], blocks, skip)
+    from flat_lookahead import block_arguments
+
+    return (*block_arguments(args), blocks, skip)
 
 
 def _staged(block_build):
@@ -1012,16 +998,16 @@ def test_endpoint_contractions_are_exact_past_bf16(into, x64):
     blocks of side 16 complete into ONE destination op in one trip, so
     a sub-op's parent count rises by ``into`` x 16 >= 272 at once. The
     lane-packed layout's `count_parents` and `src_done`, called as the
-    tick calls them, equal `_flat_dep_ops`' scatter-add and gather bit
+    tick calls them, equal `flat_dep_ops`' scatter-add and gather bit
     for bit and dtype for dtype, on lanes with DIFFERENT tables, with
     and without ``JAX_ENABLE_X64``."""
     import jax
     import jax.numpy as jnp
 
+    from flat_lookahead import flat_dep_ops
     from flat_pricing import block_endpoint_slots
 
-    from ddls_tpu.sim.jax_lookahead import (DepBlocks, _flat_dep_ops,
-                                            _packed_layout)
+    from ddls_tpu.sim.jax_lookahead import DepBlocks, _packed_layout
 
     L, No, S, W = 3, 24, 16, 8
     B = into + L + 6
@@ -1052,7 +1038,7 @@ def test_endpoint_contractions_are_exact_past_bf16(into, x64):
         for lane in range(L):
             dep_src, dep_dst = block_endpoint_slots(
                 jnp.asarray(src[lane]), jnp.asarray(dst[lane]), S)
-            flat_done, flat_parents, _ = _flat_dep_ops(
+            flat_done, flat_parents, _ = flat_dep_ops(
                 jnp.clip(dep_src, 0), jnp.clip(dep_dst, 0), None, 1)
             valid = np.repeat(src[lane] >= 0, S * S)
             want = np.asarray(flat_parents(

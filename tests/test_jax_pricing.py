@@ -22,7 +22,7 @@ assert jax.config.read("jax_enable_x64"), "driver needs JAX_ENABLE_X64=1"
 import tempfile
 from ddls_tpu.graphs.synthetic import generate_pipedream_txt_files
 from ddls_tpu.envs import RampJobPartitioningEnvironment
-from ddls_tpu.sim.jax_lookahead import build_native_lookahead_arrays
+from ddls_tpu.native.arrays import build_native_lookahead_arrays
 from ddls_tpu.sim.jax_env import (build_shape_tables, config_tables_for,
                                   table_slots)
 import flat_pricing

@@ -4,7 +4,7 @@ Random lookahead instances (random DAGs with mutual sync pairs, random
 worker assignment, multi-channel flow routing, permutation priority
 scores) are run through the C++ engine and through an independent,
 deliberately-naive numpy mirror of the pinned tick semantics
-(jax_lookahead.py module docstring). Outcomes must agree exactly in f64:
+(sim/jax_lookahead.py module docstring). Outcomes must agree exactly in f64:
 this exercises the engine's incremental data structures (lazy heaps,
 readiness staging, channel nomination) on tie-break and contention
 patterns that episode-captured cases may never produce.
@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from ddls_tpu.native import native_available, run_lookahead
-from ddls_tpu.sim.jax_lookahead import LookaheadArrays
+from ddls_tpu.native.arrays import LookaheadArrays
 
 pytestmark = pytest.mark.skipif(
     not native_available(), reason="native toolchain unavailable")

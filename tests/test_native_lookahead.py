@@ -112,7 +112,7 @@ def test_full_episode_outcomes_identical(tmp_path):
 def test_native_bails_to_none_on_livelock():
     """A non-flow dep with positive remaining can never finish (the host
     engine raises); the native engine must return None (fall back)."""
-    from ddls_tpu.sim.jax_lookahead import LookaheadArrays
+    from ddls_tpu.native.arrays import LookaheadArrays
 
     arrays = LookaheadArrays(
         op_remaining=np.array([1.0], np.float64),

@@ -205,8 +205,9 @@ def test_failure_events_deterministic_and_adjusted():
 
 def test_conformance_fast_legs_green_on_all_registry_specs():
     """host_native (bit-exact episodes), golden stats, and the lint
-    backend-surface rule — in-process; the jax/jitted legs need x64 and
-    ride the slow-marked CLI test below."""
+    backend-surface rule — in-process; the jitted leg needs x64 and
+    rides the CLI tests below (the canonical spec in tier-1, the whole
+    registry slow-marked)."""
     from ddls_tpu.native import native_available
     from ddls_tpu.scenarios.conformance import run_conformance
 
@@ -234,6 +235,30 @@ def test_multi_channel_spec_excludes_jitted_leg_with_reason():
     ok, reason = _jitted_supported(multi_channel_spec())
     assert not ok and "single-channel" in reason
     assert _jitted_supported(canonical_spec()) == (True, None)
+
+
+def test_conformance_canonical_every_leg_green():
+    """The canonical spec through EVERY default leg, as a user runs it:
+    scripts/conformance.py in its own process (it pins x64, which the
+    host_jitted leg needs), exit 0, each leg of DEFAULT_LEGS reported
+    once and ok, skipped or unavailable."""
+    from ddls_tpu.scenarios.conformance import DEFAULT_LEGS
+
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "scripts", "conformance.py"),
+         "--spec", "canonical", "--json", "--max-decisions", "30"],
+        capture_output=True, text=True, timeout=300, cwd=REPO)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    doc = json.loads(proc.stdout)
+    assert doc["ok"] and [r["spec"]["name"] for r in doc["specs"]] == [
+        "canonical"]
+    legs = doc["specs"][0]["legs"]
+    assert [leg["leg"] for leg in legs] == list(DEFAULT_LEGS)
+    for leg in legs:
+        assert leg["status"] in ("ok", "skipped", "unavailable"), leg
+        # x64 is on in that process and the canonical fabric is the
+        # dense one: the jitted leg really ran
+        assert leg["leg"] != "host_jitted" or leg["status"] == "ok", leg
 
 
 @pytest.mark.slow
