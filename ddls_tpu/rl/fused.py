@@ -42,8 +42,8 @@ from ddls_tpu import telemetry
 from ddls_tpu.demands.jobs_generator import BANK_GAUGES
 from ddls_tpu.sim.jax_env import (CAUSE_ACCEPTED, CAUSE_OP_PLACEMENT,
                                   MASK_GAUGES)
-from ddls_tpu.sim.jax_lookahead import (MINOR_GAUGES, narrow_stages,
-                                        stage_trips, stage_widths)
+from ddls_tpu.sim.jax_lookahead import (MINOR_GAUGES, channel_trips,
+                                        stage_widths)
 from ddls_tpu.sim.jax_memo import MemoCounters
 from ddls_tpu.telemetry import scopes, startup
 
@@ -147,11 +147,13 @@ def record_lookahead_trips(ep_trace, pads, num_workers: int) -> None:
     lanes still live, which each step's own counts give
     (`stage_trips`); ``lockstep_lane_trips`` — each stage's trips times
     its width, summed: the lane-trips the device paid for;
-    ``narrow_trips`` — the lockstep's trips that ran over the NARROW
-    channel table (`sim/jax_lookahead.py:channel_widths` of the
-    cluster's ``num_workers`` servers and the block side): those of the
-    stages in which every lane live at the stage's entry rode no more
-    servers than it holds (`narrow_stages`, the loop's own rule);
+    ``narrow_trips`` — the lockstep's trips that ran over a channel
+    table NARROWER than the cluster's (`sim/jax_lookahead.py:
+    channel_widths` of the cluster's ``num_workers`` servers and the
+    block side; every trip where the table has one width), and
+    ``narrowest_trips`` — those over its first rung: a lane-packed
+    stage ticks each trip over the narrowest table that holds what
+    every lane still live rode (`channel_trips`, the loop's own rule);
     ``rode.<n>`` — the lane-steps that ran trips, by the servers their
     job rode. From the tables' ``pads`` (a ``ConfigPads``), once per
     drained epoch trace: ``dep_slots`` — the dep slots a trip
@@ -167,16 +169,19 @@ def record_lookahead_trips(ep_trace, pads, num_workers: int) -> None:
     rode = np.moveaxis(np.asarray(ep_trace["la_rode"]), -2, -1)
     side = int(pads.max_split)
     widths = stage_widths(own.shape[-1], side)
-    trips = stage_trips(own, widths)
-    by_width = trips.reshape(-1, len(widths)).sum(axis=0)
+    # [stages, channel widths], summed over the steps
+    trips = channel_trips(own, rode, widths, num_workers, side)
+    trips = trips.reshape((-1,) + trips.shape[-2:]).sum(axis=0)
+    by_width, by_channel = trips.sum(axis=1), trips.sum(axis=0)
     telemetry.inc("sim.lookahead.trips", int(own.sum()))
     telemetry.inc("sim.lookahead.lockstep_trips", int(by_width.sum()))
     telemetry.inc("sim.lookahead.lockstep_lane_trips",
                   int(by_width @ np.asarray(widths)))
     for width, count in zip(widths, by_width.tolist()):
         telemetry.inc(f"sim.lookahead.stage_trips.{width}", count)
-    telemetry.inc("sim.lookahead.narrow_trips", int(trips[narrow_stages(
-        own, rode, widths, num_workers, side)].sum()))
+    telemetry.inc("sim.lookahead.narrow_trips", int(
+        by_channel[:-1].sum() if len(by_channel) > 1 else by_channel[0]))
+    telemetry.inc("sim.lookahead.narrowest_trips", int(by_channel[0]))
     for servers, count in zip(*np.unique(rode[own > 0],
                                          return_counts=True)):
         telemetry.inc(f"sim.lookahead.rode.{servers}", int(count))

@@ -64,6 +64,11 @@ class DepBlocks(NamedTuple):
 #: not a multiple of it, leaves lanes of every register empty
 REGISTER_WIDTH = 128
 
+#: ... and its sublanes: the second-minor axis of a 32-bit array is
+#: laid out in tiles of this many rows, so a channel table's worker
+#: axis narrower than this fills no fewer registers
+REGISTER_SUBLANES = 8
+
 #: what each next width of the lane schedule (:func:`stage_widths`) is
 #: of the last, before rounding up to whole registers, and how many
 #: widths under the first it may hold: every stage is one more loop to
@@ -100,7 +105,8 @@ class _Layout(NamedTuple):
     of op or dep state to one value per lane, ``lanes`` (a per-lane
     accumulator from a scalar), ``spread`` (a per-lane value, to what
     broadcasts against op and dep state) and ``loop`` (``while_loop``
-    over a per-lane ``live``)."""
+    over a per-lane ``live``; as one form of a cascade, only while a
+    live lane ``needs`` it)."""
     src_done: object
     count_parents: object
     nominate: object
@@ -213,7 +219,8 @@ def _job_layout(op_worker, num_workers: int, dep_ops) -> _Layout:
             (per_worker == best_score[:, None]) & (best_score[:, None] > 0)
             & (worker_onehot > 0), axis=0)
 
-    def loop(live, tick, init, fit):
+    def loop(live, tick, init, fit, needs=None):
+        del needs   # one form: the cluster's width
         if not fit:
             return jax.lax.while_loop(live, tick, init)
         # a stage of the lane schedule (:func:`stage_widths`), one job
@@ -232,12 +239,15 @@ def _job_layout(op_worker, num_workers: int, dep_ops) -> _Layout:
 
 def channel_widths(num_workers: int, side: int) -> tuple:
     """The widths of the worker axis the lane-packed tick is built at,
-    narrowest first: the block side (what one block of a partitioned op
-    can span, and with parent co-location what a job RIDES but for a
-    ragged row on an empty cluster) and the cluster's servers. One
-    width where the cluster is no wider than a block."""
-    narrow = min(side, num_workers)
-    return (narrow,) if narrow == num_workers else (narrow, num_workers)
+    narrowest first: half the block side, the block side (what one
+    block of a partitioned op can span, and with parent co-location
+    what a job RIDES but for a ragged row on an empty cluster) and the
+    cluster's servers. Read from the two shapes alone: a rung under a
+    register's sublanes (:data:`REGISTER_SUBLANES`) saves no register,
+    and a rung no narrower than the cluster is the cluster — one width
+    where the cluster is no wider than the first rung."""
+    return (*(rung for rung in (side // 2, side)
+              if REGISTER_SUBLANES <= rung < num_workers), num_workers)
 
 
 def dense_servers(op_worker, op_valid, n_lanes: int, num_workers: int):
@@ -279,10 +289,13 @@ def endpoint_onehots(blocks: DepBlocks, n_ops: int):
                  for x in (blocks.src, blocks.dst))
 
 
-def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
-                   num_workers: int, pinned: bool = False,
-                   onehots=None) -> _Layout:
-    """L lanes of :class:`DepBlocks` tables, LANE-PACKED: op state is
+def _packed_layouts(op_worker, blocks: DepBlocks, n_lanes: int,
+                    widths, onehots=None) -> tuple:
+    """L lanes of :class:`DepBlocks` tables, LANE-PACKED, one
+    :class:`_Layout` a width of ``widths`` — the same state under
+    channel tables of those many workers, every one over ``op_worker``'s
+    server ids and the SAME endpoint tables: only the worker iota that
+    `nominate` and `select_ops` compare against differs. Op state is
     [No, L*S] and dep state [B, S_i, L*S_j], the minor axis holding
     (lane, shard) at ``lane*S + shard`` — the DESTINATION shard j for a
     dep — so every select and reduction of a trip runs over L*S-wide
@@ -299,8 +312,8 @@ def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
     A block's source and destination op are found on the MATRIX unit:
     `src_done` and `count_parents` are one ``dot_general`` each, a lane
     a batch, with the blocks' 0/1 endpoint matrices
-    (:func:`endpoint_onehots`, [L, B, No] int8; ``onehots`` where two
-    layouts of one stage share them) — [L, B, No] x [L, No, S] over the
+    (:func:`endpoint_onehots`, [L, B, No] int8; ``onehots`` where the
+    layouts of two id spaces share them) — [L, B, No] x [L, No, S] over the
     ops, and [L, No, B] x [L, B, S] over the blocks — where selecting
     one op row out of No by comparison costs B * No * L * S element
     steps of the vector unit a primitive a trip (170 M at 570 ops x
@@ -310,23 +323,20 @@ def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
     result follows ``JAX_ENABLE_X64``), and a sum over a destination's
     incoming blocks is a whole number however many they are.
 
-    Loop-invariant tables are built here, outside the ``while_loop``;
-    ``pinned`` holds them there where the layout is one of two forms a
-    ``cond`` picks from — XLA otherwise moves each form's tables into
-    its branch."""
+    Loop-invariant tables are built here, outside the ``while_loop``s;
+    the endpoint-worker tables are pinned there."""
     from functools import partial
 
     import jax
     import jax.numpy as jnp
 
-    L, W = n_lanes, num_workers
+    L = n_lanes
     No, S = op_worker.shape[0], op_worker.shape[1] // L
     B = blocks.src.shape[0]
     if S > 127:
         raise ValueError(f"a block side of {S} passes int8: "
                          "`count_parents` contracts counts up to it")
     rows = jnp.arange(No, dtype=jnp.int32)[:, None]        # [No, 1]
-    workers = jnp.arange(W, dtype=jnp.int32)[:, None]      # [W, 1]
 
     def spread(x):
         """[..., L] -> [..., L*S]: a lane's value on each of its slots."""
@@ -359,8 +369,10 @@ def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
 
     w_src = from_source(endpoint_worker(src))              # [B, S_i, L*S]
     w_dst = endpoint_worker(dst)                           # [B, L*S_j]
-    if pinned:
-        w_src, w_dst = jax.lax.optimization_barrier((w_src, w_dst))
+    # held as built: left to XLA, every trip of every form copies
+    # ``w_src`` (as large as the dep state) into the layout its
+    # compare reads
+    w_src, w_dst = jax.lax.optimization_barrier((w_src, w_dst))
 
     at_src, at_dst = (endpoint_onehots(blocks, No) if onehots is None
                       else onehots)                        # [L, B, No]
@@ -383,35 +395,45 @@ def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
         into = inc.sum(axis=1, dtype=inc.dtype)            # [B, L*S_j]
         return parent_done + contract(at_dst, into, 1)
 
-    def nominate(dscores, flow_ready):
-        # best[X, Y] over the deps whose source sits on worker X and
-        # destination on Y: max over i into X, over b into Y, and last
-        # over the shards j — on [W, W, L*S], not on dep state. The
-        # one-hots are compared here, every trip: as loop invariants
-        # they would be W times the dep state, read twice a trip
-        on_src = w_src[:, :, None] == workers              # [B, S_i, W, L*S]
-        on_dst = w_dst[:, None, None] == workers           # [B, 1, W, L*S]
-        to_x = jnp.max(jnp.where(on_src, dscores[:, :, None], -1.0), axis=1)
-        best = spread(over_shards(jnp.max, jnp.max(
-            jnp.where(on_dst, to_x[:, :, None], -1.0), axis=0)))
-        # ... and back: each dep reads best[X(b, i), Y(b, j)]
-        of_y = jnp.max(jnp.where(on_dst, best, -1.0), axis=2)
-        mine = jnp.max(jnp.where(on_src, of_y[:, None], -1.0), axis=2)
-        return flow_ready & (dscores >= mine) & (dscores > 0)
+    def channel_ops(width):
+        """`nominate` and `select_ops` over a table of ``width`` workers."""
+        workers = jnp.arange(width, dtype=jnp.int32)[:, None]  # [W, 1]
 
-    def select_ops(scores, ops_ready):
-        mine = op_worker == workers[:, None]               # [W, No, L*S]
-        best = spread(over_shards(jnp.max, jnp.max(
-            jnp.where(mine, scores, 0.0), axis=1)))[:, None]
-        # an op is selected iff it is its worker's best ready op
-        return ops_ready & jnp.any(
-            mine & (scores == best) & (best > 0), axis=0)
+        def nominate(dscores, flow_ready):
+            # best[X, Y] over the deps whose source sits on worker X and
+            # destination on Y: max over i into X, over b into Y, and
+            # last over the shards j — on [W, W, L*S], not on dep state.
+            # The one-hots are compared here, every trip: as loop
+            # invariants they would be W times the dep state, read
+            # twice a trip
+            on_src = w_src[:, :, None] == workers          # [B, S_i, W, L*S]
+            on_dst = w_dst[:, None, None] == workers       # [B, 1, W, L*S]
+            to_x = jnp.max(jnp.where(on_src, dscores[:, :, None], -1.0),
+                           axis=1)
+            best = spread(over_shards(jnp.max, jnp.max(
+                jnp.where(on_dst, to_x[:, :, None], -1.0), axis=0)))
+            # ... and back: each dep reads best[X(b, i), Y(b, j)]
+            of_y = jnp.max(jnp.where(on_dst, best, -1.0), axis=2)
+            mine = jnp.max(jnp.where(on_src, of_y[:, None], -1.0), axis=2)
+            return flow_ready & (dscores >= mine) & (dscores > 0)
 
-    def loop(live, tick, init, fit):
+        def select_ops(scores, ops_ready):
+            mine = op_worker == workers[:, None]           # [W, No, L*S]
+            best = spread(over_shards(jnp.max, jnp.max(
+                jnp.where(mine, scores, 0.0), axis=1)))[:, None]
+            # an op is selected iff it is its worker's best ready op
+            return ops_ready & jnp.any(
+                mine & (scores == best) & (best > 0), axis=0)
+
+        return nominate, select_ops
+
+    def loop(live, tick, init, fit, needs=None):
         # what jax's batching makes of ``while_loop``: run while any
         # lane is live — as a stage of the lane schedule
         # (:func:`stage_widths`), while more are than the next width
-        # holds (``fit``) — and freeze the lanes that are not
+        # holds (``fit``); as one form of a cascade of channel widths,
+        # while a live lane is among those that need it (``needs``,
+        # [L]) — and freeze the lanes that are not
         def frozen_tick(state):
             on = live(state)
             on_slots = spread(on)
@@ -421,32 +443,40 @@ def _packed_layout(op_worker, blocks: DepBlocks, n_lanes: int,
 
         def more(state):
             on = live(state)
-            return jnp.sum(on, dtype=jnp.int32) > fit if fit else jnp.any(on)
+            go = jnp.sum(on, dtype=jnp.int32) > fit if fit else jnp.any(on)
+            return go if needs is None else go & jnp.any(on & needs)
 
         return jax.lax.while_loop(more, frozen_tick, init)
 
-    return _Layout(src_done, count_parents, nominate, select_ops,
-                   over_lane(jnp.any), over_lane(jnp.all),
-                   over_lane(jnp.min),
-                   over_lane(partial(jnp.sum, dtype=jnp.int32)),
-                   lanes=lambda x: jnp.broadcast_to(x, (L,)), spread=spread,
-                   loop=loop)
+    return tuple(
+        _Layout(src_done, count_parents, *channel_ops(width),
+                over_lane(jnp.any), over_lane(jnp.all), over_lane(jnp.min),
+                over_lane(partial(jnp.sum, dtype=jnp.int32)),
+                lanes=lambda x: jnp.broadcast_to(x, (L,)), spread=spread,
+                loop=loop)
+        for width in widths)
 
 
 def _tick_loop(lay: _Layout, op_remaining, op_valid, op_score, num_parents,
                dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
-               skip, max_iters: int, state=None, fit: int = 0, narrow=None):
+               skip, max_iters: int, state=None, fit: int = 0,
+               narrower=None):
     """THE tick loop, in whatever shape ``lay`` carries its state;
     returns (t, comm_oh, comp_oh, busy, ok, trips) per lane, the state
-    the loop left, and whether it ran in the ``narrow`` form. As a
-    stage of the lane schedule (:func:`stage_widths`) it starts from
-    the ``state`` an earlier stage left (None: a job's start) and stops
-    once the next width holds the live lanes (``fit``; 0: when none is
-    live). ``narrow`` — (layout, fits) — is the same state's layout
-    over a narrower channel table and, per lane, whether the lane's
-    job fits it: the loop runs in that form iff every lane LIVE at its
-    entry fits (a frozen lane's state is never written, so the form it
-    is carried through cannot show), else in ``lay``'s."""
+    the loop left, and the trips it ran in each form, narrowest first.
+    As a stage of the lane schedule (:func:`stage_widths`) it starts
+    from the ``state`` an earlier stage left (None: a job's start) and
+    stops once the next width holds the live lanes (``fit``; 0: when
+    none is live). ``narrower`` — (rode, forms) — is the servers each
+    lane's job rides, [lanes], and the same state's layouts over
+    narrower channel tables as (servers held, layout) pairs, widest
+    first. The stage is then a CASCADE of loops over one state, from
+    ``lay``'s down: each form ticks while the stage is on and some LIVE
+    lane rides more servers than the next form holds, the last to the
+    stage's end — so the table follows the lanes still live, and a form
+    no live lane needs runs no trip. Every live lane ticks in every
+    trip and a frozen lane's state is never written, so the forms a
+    lane is carried through cannot show in its bits."""
     from functools import partial
 
     import jax
@@ -529,22 +559,24 @@ def _tick_loop(lay: _Layout, op_remaining, op_valid, op_score, num_parents,
                  lay.lanes(jnp.zeros((), dt)), lay.lanes(jnp.zeros((), dt)),
                  lay.lanes(jnp.zeros((), dt)), lay.lanes(jnp.zeros((), dt)),
                  lay.lanes(jnp.int32(0)), lay.lanes(jnp.bool_(False)))
-    with jax.named_scope(scopes.SIM_LOOKAHEAD):
-        if narrow is None:
-            took_narrow, out = None, lay.loop(cond, body, state, fit)
-        else:
-            form, fits = narrow
-            took_narrow = jnp.all(fits | ~cond(state))
-            out = jax.lax.cond(
-                took_narrow,
-                lambda s: form.loop(cond, partial(body, lay=form), s, fit),
-                lambda s: lay.loop(cond, body, s, fit), state)
+    rode, forms = narrower or (None, ())
+    layouts = (lay, *(form for _, form in forms))
+    # what the next form down holds: who rides more needs this one
+    holds_below = (*(width for width, _ in forms), None)
+    out, ran = state, []
+    for form, below in zip(layouts, holds_below):
+        before = out[9]
+        with jax.named_scope(scopes.SIM_LOOKAHEAD):
+            out = form.loop(cond, partial(body, lay=form), out, fit,
+                            None if below is None else rode > below)
+        # every live lane ticks in every trip of a form's loop
+        ran.append(jnp.max(out[9] - before))
     (_, _, op_done, dep_done, _, t, comm_oh, comp_oh, busy, it,
      stuck) = out
     finished = (lay.all(op_done | ~op_valid)
                 & lay.all(dep_done | ~dep_valid))
     return ((t, comm_oh, comp_oh, busy, finished & ~stuck, it), out,
-            took_narrow)
+            jnp.stack(ran[::-1]))
 
 
 def stage_widths(n_lanes: int, side: int) -> list:
@@ -579,7 +611,7 @@ def endpoint_onehot_elems(n_lanes: int, n_ops: int, n_blocks: int,
     compares against the op-row iota on the vector unit in EACH of
     `src_done` and `count_parents`, over ``n_ops`` original ops and
     ``n_blocks`` blocks: none while the stage is lane-packed (both are
-    contractions, :func:`_packed_layout`), blocks x ops x shards a lane
+    contractions, :func:`_packed_layouts`), blocks x ops x shards a lane
     from :data:`REGISTER_WIDTH` lanes on (:func:`_block_dep_ops`)."""
     return 0 if n_lanes < REGISTER_WIDTH else \
         n_blocks * n_ops * n_lanes * side
@@ -599,25 +631,34 @@ def stage_trips(own, widths):
     return np.diff(ends, axis=-1, prepend=0)
 
 
-def narrow_stages(own, rode, widths, num_workers: int, side: int):
-    """Which stages of ``widths`` ran over the NARROW channel table
-    (:func:`channel_widths`), [..., stages] bool, by the loop's own
-    rule: from every lane's OWN trip count (``own``, [..., lanes]) and
-    the servers its job rode (``rode``, alike; 0 where it ran no
-    trip). A lane is live at a stage's entry iff its own count passes
-    the trips run before the stage, and a lane-packed stage (under
-    :data:`REGISTER_WIDTH` lanes) is narrow iff no live lane rode more
-    than the narrow width. Where the table has one width, every stage
-    runs at it."""
+def channel_trips(own, rode, widths, num_workers: int, side: int):
+    """The trips each stage of ``widths`` ran at each width of the
+    channel table (:func:`channel_widths`, narrowest first),
+    [..., stages, channel widths] (their sum over the last axis is
+    :func:`stage_trips`), by the loop's own rule: from every lane's OWN
+    trip count (``own``, [..., lanes]) and the servers its job rode
+    (``rode``, alike; 0 where it ran no trip). A lane is live at
+    lockstep trip t iff its own count passes t, and a lane-packed stage
+    (under :data:`REGISTER_WIDTH` lanes) ticks trip t over the
+    narrowest table that holds what every lane live at t rode — so a
+    width holds from the trip at which the last lane that rode more
+    ends, to the end. The stages of one job a lane keep the cluster's
+    width."""
     own, rode = np.asarray(own), np.asarray(rode)
     trips = stage_trips(own, widths)
+    ends = np.cumsum(trips, axis=-1)
+    starts = ends - trips
     channels = channel_widths(num_workers, side)
-    if len(channels) == 1:
-        return np.ones(trips.shape, bool)
-    before = np.cumsum(trips, axis=-1) - trips
-    live = own[..., None, :] > before[..., None]
-    widest = np.max(np.where(live, rode[..., None, :], 0), axis=-1)
-    return (widest <= channels[0]) & (np.asarray(widths) < REGISTER_WIDTH)
+    # the lockstep trip from which each width holds the live lanes
+    since = np.stack([np.max(np.where(rode > width, own, 0), axis=-1)
+                      for width in channels[:-1]]
+                     + [np.zeros(own.shape[:-1], own.dtype)], axis=-1)
+    cut = np.clip(since[..., None, :], starts[..., None], ends[..., None])
+    ran = np.concatenate([ends[..., None], cut[..., :-1]], axis=-1) - cut
+    unpacked = np.asarray(widths) >= REGISTER_WIDTH
+    ran[..., unpacked, :-1] = 0
+    ran[..., unpacked, -1] = trips[..., unpacked]
+    return ran
 
 
 def _lane_batched_lookahead(num_workers: int):
@@ -631,7 +672,7 @@ def _lane_batched_lookahead(num_workers: int):
     The shape of a loop's state is chosen on its lanes alone, by what
     fills a vector register (:data:`REGISTER_WIDTH`). While the lanes
     alone do not (under 128) the state is LANE-PACKED
-    (:func:`_packed_layout`): (lane, shard) merged on the minor axis.
+    (:func:`_packed_layouts`): (lane, shard) merged on the minor axis.
     From 128 lanes on the loop is one job's (:func:`_block_dep_ops`)
     under ``jax.vmap``, whose lanes XLA lays minor by itself: there the
     packed form is the slower one (its worker x worker tables carry the
@@ -647,27 +688,33 @@ def _lane_batched_lookahead(num_workers: int):
     lane's ticks do not depend on which lanes share its loop, so every
     lane's six results are the one-loop program's bits.
 
-    The worker x worker channel table of a lane-packed stage has two
-    widths (:func:`channel_widths`): what a job RIDES is at most a
-    block's side but for a ragged row spread over an empty cluster, so
-    each stage renumbers its lanes' servers densely
-    (:func:`dense_servers`, once, outside the loop) and runs over the
-    narrow table iff every lane live at its entry fits it — one
-    loop-invariant scalar a stage, a ``lax.cond`` around the stage's
-    loop — else over the cluster's width, the tick as it always was. A
-    bijection on the servers a lane uses maps channel pairs one to one,
-    so both forms give each lane the same bits. No option selects one.
+    The worker x worker channel table of a lane-packed stage follows
+    the lanes still LIVE, over up to three widths
+    (:func:`channel_widths`): what a job RIDES is at most a block's
+    side but for a ragged row spread over an empty cluster, and mostly
+    under half of it, so each stage renumbers its lanes' servers
+    densely (:func:`dense_servers`, once, outside the loops) and runs
+    as a cascade of ``while_loop``s over one state (:func:`_tick_loop`),
+    widest first: the tick over the cluster's own ids while a live lane
+    rides more than a block's side, then the same tick over the dense
+    ids and the block-side table while one rides more than half of it,
+    then over the half-side table to the stage's end. A form no live
+    lane needs runs no trip; there is no branch. A bijection on the
+    servers a lane uses maps channel pairs one to one, so every form
+    gives each lane the same bits. No option selects one.
 
     Which op a block starts and ends at, a lane-packed stage asks the
-    matrix unit: both forms contract op state and completed-dep counts
+    matrix unit: every form contracts op state and completed-dep counts
     with the stage's 0/1 endpoint matrices (:func:`endpoint_onehots`:
-    built from the block tables once a stage, outside the loop and the
-    ``cond``, and pinned there), in int8 with int32 sums — exact
-    (:func:`_packed_layout`). The >= 128-lane form keeps the compare:
-    its one-hots are a sixtieth of its trip.
+    built from the block tables once a stage, outside the loops), in
+    int8 with int32 sums — exact (:func:`_packed_layouts`). The >=
+    128-lane form keeps the compare: its one-hots are a sixtieth of its
+    trip.
 
-    ``run.staged`` is the same function with, beside the results, each
-    stage's trip count and the channel width it ran at, for tests."""
+    ``run.staged`` is the same function with, beside the results, the
+    trips each stage ran at each width of the channel table
+    (:func:`channel_trips` is the host's reckoning of them), for
+    tests."""
     from functools import partial
 
     import jax
@@ -688,19 +735,23 @@ def _lane_batched_lookahead(num_workers: int):
 
     def one_loop(args, state=None, fit=0):
         """The loop at ``args``' own lane count, in that count's form,
-        and the width of the channel table it ran at; ``state`` comes
-        and goes a lane a row (it goes only from a stage that has a
+        and the trips it ran at each width of the channel table
+        (:func:`channel_widths`, narrowest first); ``state`` comes and
+        goes a lane a row (it goes only from a stage that has a
         successor: ``fit``)."""
         (op_remaining, op_valid, op_worker, op_score, num_parents,
          dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
          blocks, skip) = args
         L, N = op_remaining.shape
-        if L >= REGISTER_WIDTH:
-            return (*jax.vmap(partial(one_job, fit=fit),
-                              axis_name=LANE_AXIS)(*args, state),
-                    jnp.int32(num_workers))
         E, B = dep_remaining.shape[1], blocks.src.shape[1]
         S = _block_side(E, B)
+        channels = channel_widths(num_workers, S)
+        if L >= REGISTER_WIDTH:
+            out, left = jax.vmap(partial(one_job, fit=fit),
+                                 axis_name=LANE_AXIS)(*args, state)
+            ran = jnp.max(out[5] - (0 if state is None else state[9]))
+            return out, left, jnp.zeros(len(channels), jnp.int32).at[-1].set(
+                ran)
 
         def ops(x):      # [L, (o, k)] -> [No, (l, k)]
             return x.reshape(L, N // S, S).transpose(1, 0, 2).reshape(
@@ -723,29 +774,23 @@ def _lane_batched_lookahead(num_workers: int):
 
         op_worker, op_valid = ops(op_worker), ops(op_valid)
         blocks = DepBlocks(blocks.src.T, blocks.dst.T)
-        narrow, wide = channel_widths(num_workers, S)[0], num_workers
-        form = onehots = None
-        if narrow < wide:
-            # the servers a job RIDES fit a block's side but for a
-            # ragged row on an empty cluster: the same tick over each
-            # lane's own dense server ids and a narrow x narrow table
-            dense, rode = dense_servers(op_worker, op_valid, L, wide)
-            onehots = jax.lax.optimization_barrier(
-                endpoint_onehots(blocks, N // S))
-            form = (_packed_layout(dense, blocks, L, narrow, pinned=True,
-                                   onehots=onehots),
-                    rode <= narrow)
-        out, left, took_narrow = _tick_loop(
-            _packed_layout(op_worker, blocks, L, wide,
-                           pinned=form is not None, onehots=onehots),
-            ops(op_remaining), op_valid, ops(op_score),
+        onehots = endpoint_onehots(blocks, N // S)
+        wide, = _packed_layouts(op_worker, blocks, L, channels[-1:], onehots)
+        narrower = None
+        if len(channels) > 1:
+            # the servers a job RIDES are far fewer than the cluster's:
+            # the same tick over each lane's own dense server ids and
+            # the narrower tables
+            dense, rode = dense_servers(op_worker, op_valid, L, num_workers)
+            narrower = (rode, tuple(zip(channels[:-1], _packed_layouts(
+                dense, blocks, L, channels[:-1], onehots)))[::-1])
+        out, left, ran = _tick_loop(
+            wide, ops(op_remaining), op_valid, ops(op_score),
             ops(num_parents), deps(dep_remaining), deps(dep_valid),
             deps(dep_mutual), deps(dep_is_flow), deps(dep_score),
             skip, N + E + 4,
-            None if state is None else pack(state, ops, deps), fit, form)
-        return (out, pack(left, lane_ops, lane_deps) if fit else None,
-                jnp.int32(wide) if form is None
-                else jnp.where(took_narrow, narrow, wide))
+            None if state is None else pack(state, ops, deps), fit, narrower)
+        return out, pack(left, lane_ops, lane_deps) if fit else None, ran
 
     def rows(x, lanes):
         """Whole lanes of ``x``: ``lanes`` are distinct and in range."""
@@ -763,11 +808,10 @@ def _lane_batched_lookahead(num_workers: int):
                              "blocks")
         widths = stage_widths(L, S)
         if len(widths) == 1:
-            part, _, channels = one_loop(args)
-            return part, (), (channels,)
-        part, state, channels = one_loop(args, fit=widths[1])
-        results, lanes, ran = part, jnp.arange(L), [jnp.max(part[5])]
-        took = [channels]
+            part, _, ran = one_loop(args)
+            return part, (ran,)
+        part, state, ran = one_loop(args, fit=widths[1])
+        results, lanes, ran = part, jnp.arange(L), [ran]
         for width, fit in zip(widths[1:], widths[2:] + [0]):
             # the lanes still live first, in their order, then as many
             # of the others (frozen: they carry their results along) as
@@ -778,16 +822,15 @@ def _lane_batched_lookahead(num_workers: int):
                 live = live & ~rows(skip, lanes)
             keep = jnp.argsort(~live, stable=True)[:width]
             lanes = rows(lanes, keep)
-            part, state, channels = one_loop(
+            part, state, by_channel = one_loop(
                 jax.tree_util.tree_map(lambda x: rows(x, lanes), args),
                 jax.tree_util.tree_map(lambda x: rows(x, keep), state), fit)
-            ran.append(jnp.max(part[5] - rows(trips, keep)))
-            took.append(channels)
+            ran.append(by_channel)
             results = tuple(
                 x.at[lanes].set(y, unique_indices=True,
                                 mode="promise_in_bounds")
                 for x, y in zip(results, part))
-        return results, tuple(ran), tuple(took)
+        return results, tuple(ran)
 
     @jax.custom_batching.custom_vmap
     def run(*args):

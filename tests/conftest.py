@@ -72,8 +72,10 @@ def dataset_dir(tmp_path_factory):
 #: they checked. A `benchmark` PR that rewrites their pins in
 #: ``test_bench_mimo.py``'s form (a prefix, found by name) drops this
 #: with ``tests/benchmarks/conftest.py`` (ROADMAP Y10). PR 40's metric
-#: has its own tests in ``tests/benchmarks/test_bench_narrow.py``.
-LISTED_FOR_OLD_CELLS_SINCE = ("lookahead_narrow_trip_share",)
+#: has its own tests in ``tests/benchmarks/test_bench_narrow.py``, PR
+#: 45's in ``test_bench_narrowest.py``.
+LISTED_FOR_OLD_CELLS_SINCE = ("lookahead_narrow_trip_share",
+                              "lookahead_narrowest_trip_share")
 PIN_OLD_CELLS_LISTS = ("test_bench_glm5", "test_bench_trinity",
                        "test_bench_sala")
 

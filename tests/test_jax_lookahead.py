@@ -417,12 +417,16 @@ def test_stages_run_the_trips_the_host_reckons(block_build, n_lanes, mix):
     """Lane mixes that cross every stage boundary (different rows, an
     unplaceable one among them; every lane skipped; all but one; the
     longest lane first, and last): every lane's six results are the
-    unbatched flat path's bits, and each stage's loop ran exactly the
+    unbatched flat path's bits, and each stage's loops ran exactly the
     trips `stage_trips` reckons from the lanes' own counts — what
-    `record_lookahead_trips` charges the device for."""
+    `record_lookahead_trips` charges the device for — at the widths of
+    the channel table `channel_trips` reckons from them and the servers
+    the lanes rode (16 servers under a block side of 16: two widths)."""
     import jax.numpy as jnp
 
-    from ddls_tpu.sim.jax_lookahead import stage_trips, stage_widths
+    from ddls_tpu.sim.jax_lookahead import (REGISTER_WIDTH, channel_trips,
+                                            channel_widths, stage_trips,
+                                            stage_widths)
 
     lanes = _lanes(block_build, n_lanes)
     skip = {"rows": jnp.arange(n_lanes) % 5 == 2,
@@ -440,11 +444,18 @@ def test_stages_run_the_trips_the_host_reckons(block_build, n_lanes, mix):
             else others + [longest]
     args, blocks, placed = _lane_arguments(block_build, lanes)
     want = _flat_per_lane(block_build, args, blocks, skip, lanes)
-    got, ran, took = _staged(block_build)(args, blocks, skip)
+    got, ran = _staged(block_build)(args, blocks, skip)
     _assert_same_bits(got, want, (n_lanes, mix))
-    # a cluster no wider than a block: one width of the channel table
-    assert {int(c) for c in took} == {block_build.et.n_srv}
-    own = want[5]
+    own, S = want[5], block_build.et.pads.max_split
+    widths = stage_widths(n_lanes, S)
+    assert channel_widths(block_build.et.n_srv, S) == (8, 16)
+    by_channel = np.asarray(ran)
+    assert by_channel.tolist() == channel_trips(
+        own, np.where(own > 0, _rode(args), 0), widths,
+        block_build.et.n_srv, S).tolist()
+    # one job a lane keeps the cluster's width
+    assert not by_channel[np.asarray(widths) >= REGISTER_WIDTH, 0].any()
+    ran = by_channel.sum(axis=1)
     assert not np.asarray(placed).all()        # an unplaceable row
     assert (own > 0).sum() == {"all_skip": 0, "one_live": 1}.get(
         mix, int((~np.asarray(skip)).sum()))
@@ -452,11 +463,10 @@ def test_stages_run_the_trips_the_host_reckons(block_build, n_lanes, mix):
         assert (own == own.max()).sum() == 1
         assert int(own.argmax()) == (0 if mix == "longest_first"
                                      else n_lanes - 1)
-    widths = stage_widths(n_lanes, block_build.et.pads.max_split)
-    assert [int(r) for r in ran] == stage_trips(own, widths).tolist()
-    assert sum(int(r) for r in ran) == own.max()
+    assert ran.tolist() == stage_trips(own, widths).tolist()
+    assert ran.sum() == own.max()
     if mix == "rows":
-        assert sum(int(r) > 0 for r in ran) >= 3, ran  # stages that tick
+        assert (ran > 0).sum() >= 3, ran               # stages that tick
     if mix in ("all_skip", "one_live"):
         assert [int(r) for r in ran[:-1]] == [0] * (len(widths) - 1)
 
@@ -464,7 +474,7 @@ def test_stages_run_the_trips_the_host_reckons(block_build, n_lanes, mix):
 @pytest.fixture(scope="module")
 def wide_build(tmp_path_factory):
     """`block_build`'s two tiny graphs on RAMP 4x4x2: 32 servers under
-    a block side of 16, so the lane-packed tick has TWO widths of
+    a block side of 16, so the lane-packed tick has THREE widths of
     channel table (`channel_widths`) — at the small pads (192 op x
     4,352 dep slots)."""
     from ddls_tpu.graphs.synthetic import generate_pipedream_txt_files
@@ -476,14 +486,15 @@ def wide_build(tmp_path_factory):
     build = _BlockBuild(_ramp_env(str(out), (4, 4, 2), 16),
                         quantum=_BLOCK_QUANTUM)
     assert build.et.n_srv == 32 and build.et.pads.max_split == 16
-    assert channel_widths(32, 16) == (16, 32)
+    assert channel_widths(32, 16) == (8, 16, 32)
     return build
 
 
 def _rode(args):
-    """Per lane, the servers its valid sub-ops sit on (numpy)."""
+    """Per lane, the servers its valid sub-ops sit on (numpy), a valid
+    unplaced one counting as on server 0 — `dense_servers`' count."""
     valid, worker = np.asarray(args[1]), np.asarray(args[2])
-    return np.asarray([len(set(w[v & (w >= 0)].tolist()))
+    return np.asarray([len(set(np.clip(w[v], 0, None).tolist()))
                        for v, w in zip(valid, worker)])
 
 
@@ -491,7 +502,8 @@ def _rode(args):
 #: servers the rows ride as many servers as their degree — but the
 #: ragged ones, whose 4-way ops sit on another block: degree 6 rides 8,
 #: and degree 8 rides 10 beside another job (state 1). ``scatter`` 1
-#: spreads a degree-2 row over 18 servers (`_BlockBuild.arguments`)
+#: spreads a degree-2 row over 18 servers and a degree-1 row over 12
+#: (`_BlockBuild.arguments`)
 _SHORT, _MID, _LONG = (("cnn_0", 1, 0, 0), ("translation_0", 2, 0, 0),
                        ("translation_0", 8, 0, 0))
 _RAGGED = [("translation_0", 6, 0, 0), ("translation_0", 8, 1, 0),
@@ -499,11 +511,20 @@ _RAGGED = [("translation_0", 6, 0, 0), ("translation_0", 8, 1, 0),
 _OVER = ("cnn_0", 2, 0, 1)
 _MIXED = [_SHORT, _MID, _LONG, *_RAGGED, ("cnn_0", 8, 0, 0),
           ("translation_0", 4, 1, 0)]
-#: case -> (lanes, skipped lanes): (a) every lane on <= 16 servers; (b)
-#: one live lane on 17-20; (c) a skipped lane on > 16, and one that
-#: finishes first and is carried along as a filler, beside live lanes
-#: on <= 16; (d) ragged rows alone (ops split 4 and 6 / 8 ways on
-#: different blocks)
+#: riders of 9-16 servers: a short one (12 servers, 23 trips), a middle
+#: one (10, 33) and a long one (10, 50); and rows on <= 8 servers
+_ON_12, _ON_10, _ON_10_LONG = (("translation_0", 1, 0, 1),
+                               ("cnn_0", 8, 1, 0), ("translation_0", 8, 1, 0))
+_ON_8 = [_SHORT, _MID, _LONG, ("translation_0", 6, 0, 0), ("cnn_0", 8, 0, 0),
+         ("translation_0", 4, 1, 0), ("cnn_0", 2, 0, 0)]
+#: case -> (lanes, skipped lanes): (a) every lane on <= 16 servers, the
+#: longest on 8; (b) one live lane on 17-20; (c) a skipped lane on
+#: > 16, and one that finishes first and is carried along as a filler,
+#: beside live lanes on <= 8; (d) ragged rows alone (ops split 4 and 6
+#: / 8 ways on different blocks); (e) every LIVE lane on <= 8, a
+#: skipped lane on 18 and one on 10 among them; (f) a 12-server rider
+#: that finishes first; (g) a 10-server rider that is the longest; (h)
+#: an 18-, a 10- and <= 8-server riders in one stage
 _CHANNEL_CASES = {
     "all_narrow": ([_MIXED[i % len(_MIXED)] for i in range(24)], (5, 12)),
     "one_wide_live": ([_OVER if i == 7 else _MIXED[i % len(_MIXED)]
@@ -512,20 +533,31 @@ _CHANNEL_CASES = {
                       for i in range(24)], (0, 9)),
     "wide_finished_filler": ([_OVER] + [_MID] * 8 + [_LONG] * 15, ()),
     "ragged_rows": ([_RAGGED[i % len(_RAGGED)] for i in range(24)], ()),
+    "all_on_8": ([_OVER if i == 3 else _ON_10_LONG if i == 11
+                  else _ON_8[i % len(_ON_8)] for i in range(24)], (3, 11)),
+    "mid_finishes_first": ([_ON_12] + [("cnn_0", 2, 0, 0)] * 8
+                           + [_LONG] * 15, ()),
+    "mid_is_longest": ([_ON_10_LONG if i == 13 else
+                        (_MID, ("cnn_0", 2, 0, 0),
+                         ("translation_0", 4, 0, 0))[i % 3]
+                        for i in range(24)], ()),
+    "three_forms_one_stage": ([_OVER, _ON_10] + [_MID] * 7 + [_LONG] * 15,
+                              ()),
 }
 
 
 @pytest.mark.parametrize("case", _CHANNEL_CASES)
 def test_channel_table_width_follows_the_servers_ridden(wide_build, case):
     """A cluster wider than a block: every lane's six results are the
-    UNBATCHED FLAT path's bits whichever width of channel table a stage
-    took, and each stage took the narrow one iff every lane live at its
-    entry rode no more servers than it holds — `narrow_stages`, what
-    `record_lookahead_trips` reckons on the host from the same counts."""
+    UNBATCHED FLAT path's bits whichever widths of channel table a
+    stage's cascade ran through, and each stage ran each trip over the
+    narrowest table that holds what every lane still LIVE rode —
+    `channel_trips`, what `record_lookahead_trips` reckons on the host
+    from the same counts."""
     import jax
     import jax.numpy as jnp
 
-    from ddls_tpu.sim.jax_lookahead import (narrow_stages, stage_trips,
+    from ddls_tpu.sim.jax_lookahead import (channel_trips, stage_trips,
                                             stage_widths)
 
     build, (lanes, skipped) = wide_build, _CHANNEL_CASES[case]
@@ -537,66 +569,111 @@ def test_channel_table_width_follows_the_servers_ridden(wide_build, case):
     assert np.asarray(placed).all()
     skip = jnp.zeros(n_lanes, bool).at[jnp.asarray(skipped, int)].set(True)
     want = _flat_per_lane(build, args, blocks, skip, lanes)
-    got, ran, took = _staged(build)(args, blocks, skip)
+    got, ran = _staged(build)(args, blocks, skip)
     _assert_same_bits(got, want, case)
 
     own, rode = want[5], _rode(args)
     widths = stage_widths(n_lanes, S)
     assert widths == [24, 16, 8]
-    assert [int(r) for r in ran] == stage_trips(own, widths).tolist()
-    narrow = narrow_stages(own, np.where(own > 0, rode, 0), widths, 32, S)
-    assert [int(c) for c in took] == np.where(narrow, 16, 32).tolist()
-    over = rode > S
+    ran = np.asarray(ran)                      # [stages, (8, 16, 32)]
+    assert ran.sum(axis=1).tolist() == stage_trips(own, widths).tolist()
+    assert ran.tolist() == channel_trips(
+        own, np.where(own > 0, rode, 0), widths, 32, S).tolist()
+    over, mid = rode > S, (rode > S // 2) & (rode <= S)
     assert over.sum() == {"one_wide_live": 1, "wide_skipped": 2,
-                          "wide_finished_filler": 1}.get(case, 0)
+                          "wide_finished_filler": 1, "all_on_8": 1,
+                          "three_forms_one_stage": 1}.get(case, 0)
     assert ((rode[over] >= 17) & (rode[over] <= 20)).all()
-    if case in ("all_narrow", "wide_skipped", "ragged_rows"):
-        assert narrow.all()
-        assert (own[list(skipped)] == 0).all()
+    assert (own[list(skipped)] == 0).all()
+    at_8, at_16, at_32 = ran.sum(axis=0)
+    if case in ("all_narrow", "wide_skipped"):
+        # lanes on 10 servers live for 50 trips beside one on 8 for 54
+        assert mid.any() and at_32 == 0
+        assert (at_8, at_16) == (own.max() - own[mid].max(), own[mid].max())
     if case == "one_wide_live":
-        assert not narrow[0] and int(ran[0]) > 0
+        assert ran[0, 2] == own[7] > 0 and at_32 == own[7]
     if case == "wide_finished_filler":
-        # the lane on 18 servers finishes first (stage one is wide),
-        # the eight next lanes end the stage together, and it is the
-        # sixteenth lane of a stage whose fifteen live lanes ride 8
+        # the lane on 18 servers finishes first (stage one starts
+        # wide), the eight next lanes end the stage together, and it
+        # is the sixteenth lane of a stage whose fifteen live lanes
+        # ride 8
         w, m, l = own[0], own[1], own[9]
         assert w < m < l and set(own[1:9]) == {m} and set(own[9:]) == {l}
-        assert [int(r) for r in ran] == [m, l - m, 0]
-        assert narrow.tolist() == [False, True, True]
+        assert ran.tolist() == [[m - w, 0, w], [l - m, 0, 0], [0, 0, 0]]
     if case == "ragged_rows":
         splits = {tuple(sorted(set(np.asarray(
             build.et.tables["f_split"][int(c)]).tolist()))) for c in cfgs}
         assert splits == {(4, 6), (4, 8), (1, 2, 6)}
         assert sorted(set(rode.tolist())) == [8, 10]   # wider than degree
+        assert at_32 == 0 and at_16 == own[rode == 10].max()
+    if case == "all_on_8":
+        # the 16- and the 32-wide loop run no trip: the lanes that
+        # would need them are skipped
+        assert mid.sum() == 1 and (rode[[3, 11]] > S // 2).all()
+        assert (at_8, at_16, at_32) == (own.max(), 0, 0)
+    if case == "mid_finishes_first":
+        # the stage hands over to the 8-wide table when the lane on 12
+        # servers ends, and carries it on as a filler that holds no form
+        w, m, l = own[0], own[1], own[9]
+        assert rode[0] == 12 and w < m < l
+        assert ran.tolist() == [[m - w, w, 0], [l - m, 0, 0], [0, 0, 0]]
+    if case == "mid_is_longest":
+        assert rode[13] == 10 and (own[13] > np.delete(own, 13)).all()
+        assert (at_8, at_16, at_32) == (0, own.max(), 0)
+        assert (ran[:, 1] > 0).sum() >= 2      # ... in every stage that ran
+    if case == "three_forms_one_stage":
+        w, m, e, l = own[0], own[1], own[2], own[9]
+        assert (rode[0], rode[1]) == (18, 10) and w < m < e < l
+        assert ran.tolist() == [[e - m, m - w, w], [l - e, 0, 0], [0, 0, 0]]
 
 
-def test_one_width_where_the_cluster_is_no_wider_than_a_block(block_build):
-    """16 servers under a block side of 16: one form, no branch — the
-    lockstep's stages hold no ``cond`` and report the cluster's width."""
+def _whiles_and_conds(staged, *arguments):
+    """How many ``while`` and ``cond`` equations ``staged`` traces to."""
     import jax
+
+    text = str(jax.make_jaxpr(staged)(*arguments))
+    return text.count(" while["), text.count(" cond[")
+
+
+def test_channel_widths_read_the_two_shapes_alone(block_build):
+    """Half the block side, the block side, the cluster: a cluster no
+    wider than a rung drops that rung and the ones above it, and a rung
+    under a register's eight sublanes is none. A packed stage is one
+    ``while`` a width and never a ``cond``: 16 servers under a block
+    side of 16 have two, 8 servers one."""
     import jax.numpy as jnp
 
-    from ddls_tpu.sim.jax_lookahead import channel_widths
+    from ddls_tpu.sim.jax_lookahead import (_lane_batched_lookahead,
+                                            channel_widths)
 
-    assert channel_widths(block_build.et.n_srv, 16) == (16,)
-    assert channel_widths(8, 16) == (8,) and channel_widths(72, 16) == (16, 72)
+    assert channel_widths(32, 16) == (8, 16, 32)
+    assert channel_widths(16, 16) == (8, 16)
+    assert channel_widths(8, 16) == (8,)
+    assert channel_widths(72, 16) == (8, 16, 72)
+    assert channel_widths(8, 8) == (8,) and channel_widths(16, 8) == (8, 16)
     lanes = _lanes(block_build, 24)
     args, blocks, _ = _lane_arguments(block_build, lanes)
     skip = jnp.zeros(24, bool)
-    traced = jax.make_jaxpr(_staged(block_build))(args, blocks, skip)
-    assert "cond[" not in str(traced)
-    _, _, took = _staged(block_build)(args, blocks, skip)
-    assert [int(c) for c in took] == [16, 16, 16]
+    staged = _staged(block_build)
+    assert _whiles_and_conds(staged, args, blocks, skip) == (3 * 2, 0)
+    _, ran = staged(args, blocks, skip)
+    assert np.asarray(ran).shape == (3, 2)
+    # the same lanes as an 8-server cluster's (traced, not run: the
+    # placements are a 16-server cluster's)
+    on_8 = _lane_batched_lookahead(8).staged
+    assert _whiles_and_conds(
+        lambda *a: on_8(*_block_arguments(*a)), args, blocks, skip) == (3, 0)
 
 
-def test_wide_cluster_branches_once_a_packed_stage(wide_build):
-    """32 servers: each lane-packed stage is ONE ``cond`` on one scalar
-    around two loops; a stage of 128 lanes or more (one job a lane
-    under ``vmap``) has none and reports the cluster's width."""
+def test_wide_cluster_cascades_three_loops_a_packed_stage(wide_build):
+    """32 servers: each lane-packed stage is THREE ``while``s over one
+    state, widest first, and no ``cond``; a stage of 128 lanes or more
+    (one job a lane under ``vmap``) is one loop at the cluster's
+    width."""
     import jax
     import jax.numpy as jnp
 
-    def conds(n_lanes):
+    def loops(n_lanes):
         lanes = [_MIXED[i % len(_MIXED)] for i in range(n_lanes)]
         cfgs = jnp.asarray([wide_build.row(m, d) for m, d, _, _ in lanes],
                            jnp.int32)
@@ -604,14 +681,18 @@ def test_wide_cluster_branches_once_a_packed_stage(wide_build):
         args, blocks, _ = jax.vmap(wide_build.arguments)(cfgs, states)
         skip = jnp.zeros(n_lanes, bool)
         staged = _staged(wide_build)
-        text = str(jax.make_jaxpr(staged)(args, blocks, skip))
-        return text.count(" cond["), [int(c) for c in
-                                      staged(args, blocks, skip)[2]]
+        return (*_whiles_and_conds(staged, args, blocks, skip),
+                np.asarray(staged(args, blocks, skip)[1]))
 
-    assert conds(8) == (1, [16])
-    assert conds(24) == (3, [16, 16, 16])
-    n, took = conds(160)                    # 160 -> 80 -> 40 -> 24 -> 16 -> 8
-    assert n == 5 and took == [32, 16, 16, 16, 16, 16]
+    whiles, conds, ran = loops(8)
+    assert (whiles, conds) == (3, 0) and ran.shape == (1, 3)
+    # the lanes on 10 servers end before the longest on 8
+    assert (ran > 0).tolist() == [[True, True, False]]
+    whiles, conds, ran = loops(24)
+    assert (whiles, conds) == (9, 0) and ran.shape == (3, 3)
+    whiles, conds, ran = loops(160)         # 160 -> 80 -> 40 -> 24 -> 16 -> 8
+    assert (whiles, conds) == (1 + 5 * 3, 0)
+    assert ran[0].tolist() == [0, 0, ran[0, 2]] and not ran[1:, 2].any()
 
 
 def test_dense_servers_is_a_bijection_on_the_servers_a_lane_uses():
@@ -708,11 +789,11 @@ def _one_loop_program(num_workers):
                 B, S, L * S)
 
         op_worker, op_valid = ops(op_worker), ops(op_valid)
+        blocks = jl.DepBlocks(blocks.src.T, blocks.dst.T)
+        lay, = jl._packed_layouts(op_worker, blocks, L, (num_workers,),
+                                  jl.endpoint_onehots(blocks, N // S))
         return jl._tick_loop(
-            jl._packed_layout(op_worker,
-                              jl.DepBlocks(blocks.src.T, blocks.dst.T), L,
-                              num_workers),
-            ops(op_remaining), op_valid, ops(op_score),
+            lay, ops(op_remaining), op_valid, ops(op_score),
             ops(num_parents), deps(dep_remaining), deps(dep_valid),
             deps(dep_mutual), deps(dep_is_flow), deps(dep_score), skip,
             N + E + 4)[0]
@@ -724,24 +805,35 @@ def _one_loop_program(num_workers):
 @pytest.mark.parametrize("n_lanes", [1, 8])
 def test_one_register_of_lanes_traces_the_one_loop_program(
         block_build, n_lanes, with_skip):
-    """Lanes that fit one vector register run no stages: the traced
-    program is the one loop's, text for text (the unbatched call — the
-    episode kernel, the fidelity replay — is that loop at one lane)."""
+    """Lanes that fit one vector register run no stages, and a cluster
+    the first rung of the channel table holds no cascade: the traced
+    program is the one loop's, text for text once the trip counts
+    `run.staged` reports beside the results are dropped as dead code
+    (the unbatched call — the episode kernel, the fidelity replay — is
+    that loop at one lane). Traced as an 8-server cluster's, not run:
+    the placements are a 16-server cluster's."""
     import jax
     import jax.numpy as jnp
+    from jax._src.interpreters import partial_eval as pe
 
     from ddls_tpu.sim.jax_lookahead import (_lane_batched_lookahead,
-                                            stage_widths)
+                                            channel_widths, stage_widths)
 
-    n_srv, S = block_build.et.n_srv, block_build.et.pads.max_split
+    def live_text(fn, *args):
+        traced = jax.make_jaxpr(fn)(*args)
+        jaxpr, _ = pe.dce_jaxpr(traced.jaxpr, [True] * len(traced.out_avals))
+        return str(jaxpr)
+
+    n_srv, S = 8, block_build.et.pads.max_split
     assert stage_widths(n_lanes, S) == [n_lanes]
+    assert channel_widths(n_srv, S) == (n_srv,)
     args, blocks, _ = _lane_arguments(block_build,
                                       _lanes(block_build, n_lanes))
     args = _block_arguments(
         args, blocks, jnp.arange(n_lanes) % 3 == 1 if with_skip else None)
     staged = _lane_batched_lookahead(n_srv).staged
-    assert str(jax.make_jaxpr(lambda *a: staged(*a)[0])(*args)) == \
-        str(jax.make_jaxpr(_one_loop_program(n_srv))(*args))
+    assert live_text(lambda *a: staged(*a)[0], *args) == \
+        live_text(_one_loop_program(n_srv), *args)
 
 
 def test_block_path_vmapped_with_unbatched_tables(block_build):
@@ -808,14 +900,19 @@ def _per_dep_indexing(jaxpr, n_deps):
     return found
 
 
-def _lookahead_body(closed_jaxpr, dep_state):
-    """The lookahead's tick body: the ``while`` whose carry holds the
-    per-dep remaining times and done flags (f32 and bool of shape
-    ``dep_state``)."""
+def _lookahead_bodies(closed_jaxpr, dep_state, forms=1):
+    """The lookahead's tick bodies: the ``while``s whose carry holds
+    the per-dep remaining times and done flags (f32 and bool of shape
+    ``dep_state``) — one a width of the channel table (``forms``) where
+    the state is lane-packed."""
     bodies = [b for b in _while_bodies(closed_jaxpr.jaxpr)
               if sum(v.aval.shape == dep_state for v in b.invars) >= 2]
-    assert len(bodies) == 1, len(bodies)
-    return bodies[0]
+    assert len(bodies) == forms, len(bodies)
+    return bodies
+
+
+def _lookahead_body(closed_jaxpr, dep_state):
+    return _lookahead_bodies(closed_jaxpr, dep_state)[0]
 
 
 def test_block_path_nested_vmaps_pack_as_one_loop(block_build):
@@ -843,8 +940,8 @@ def test_block_path_nested_vmaps_pack_as_one_loop(block_build):
     _assert_same_bits(got, want, "nested")
     pads = block_build.et.pads
     S, L = pads.max_split, len(cfgs) * len(states)
-    _lookahead_body(jax.make_jaxpr(nested)(cfgs, states),
-                    (pads.n_blocks, S, S * L))
+    _lookahead_bodies(jax.make_jaxpr(nested)(cfgs, states),
+                      (pads.n_blocks, S, S * L), forms=2)
 
 
 #: equations of the flat path's tick body as jax 0.9 traces it: the
@@ -877,12 +974,13 @@ def test_env_lookahead_body_indexes_no_dep(block_build):
     bank = {k: jnp.asarray(v) for k, v in bank.items()}
     episode = je.make_episode_fn(et)
     traced = jax.make_jaxpr(episode)(bank, jnp.asarray([16, 4], jnp.int32))
-    assert _per_dep_indexing(_lookahead_body(traced, (B, S, S)), M) == []
+    for body in _lookahead_bodies(traced, (B, S, S), forms=2):
+        assert _per_dep_indexing(body, M) == []
 
     args, blocks, _ = _lane_arguments(block_build, _lanes(block_build, 32))
     lanes = jax.make_jaxpr(jax.vmap(block_build.block_fn))(args, blocks)
-    assert _per_dep_indexing(
-        _lookahead_body(lanes, (B, S, 32 * S)), M) == []
+    for body in _lookahead_bodies(lanes, (B, S, 32 * S), forms=2):
+        assert _per_dep_indexing(body, M) == []
     assert startup.gauges()["sim.lookahead.minor_used"] == 32 * S
     args, blocks, _ = _lane_arguments(block_build, _lanes(block_build, 128))
     lanes = jax.make_jaxpr(jax.vmap(block_build.block_fn))(args, blocks)
@@ -938,14 +1036,14 @@ def test_packed_body_reaches_endpoints_by_contraction(block_build, n_lanes):
         args, blocks, _ = _lane_arguments(block_build,
                                           _lanes(block_build, n_lanes))
         traced = jax.make_jaxpr(jax.vmap(block_build.block_fn))(args, blocks)
-    body = _lookahead_body(traced, (B, S, S * n_lanes))
-    shapes = list(_equation_shapes(body))
-    dots = [ops for name, ops in shapes if name == "dot_general"]
-    assert [ops[0] for ops in dots] == [(n_lanes, B, No)] * 2
-    assert sorted(ops[-1] for ops in dots) == sorted(
-        [(n_lanes, B, S), (n_lanes, No, S)])
-    passes = {(B, No, n_lanes * S), (No, B, n_lanes * S)}
-    assert not [name for name, ops in shapes if passes & set(ops)]
+    for body in _lookahead_bodies(traced, (B, S, S * n_lanes), forms=2):
+        shapes = list(_equation_shapes(body))
+        dots = [ops for name, ops in shapes if name == "dot_general"]
+        assert [ops[0] for ops in dots] == [(n_lanes, B, No)] * 2
+        assert sorted(ops[-1] for ops in dots) == sorted(
+            [(n_lanes, B, S), (n_lanes, No, S)])
+        passes = {(B, No, n_lanes * S), (No, B, n_lanes * S)}
+        assert not [name for name, ops in shapes if passes & set(ops)]
     if n_lanes > 1:
         assert startup.gauges()[ENDPOINT_GAUGE] == 0
 
@@ -1007,7 +1105,7 @@ def test_endpoint_contractions_are_exact_past_bf16(into, x64):
     from flat_lookahead import flat_dep_ops
     from flat_pricing import block_endpoint_slots
 
-    from ddls_tpu.sim.jax_lookahead import DepBlocks, _packed_layout
+    from ddls_tpu.sim.jax_lookahead import DepBlocks, _packed_layouts
 
     L, No, S, W = 3, 24, 16, 8
     B = into + L + 6
@@ -1028,9 +1126,9 @@ def test_endpoint_contractions_are_exact_past_bf16(into, x64):
                            .reshape(B, S, L * S))
 
     with jax.enable_x64(x64):
-        lay = _packed_layout(
+        lay, = _packed_layouts(
             jnp.asarray(rng.integers(0, W, (No, L * S)), jnp.int32),
-            DepBlocks(jnp.asarray(src.T), jnp.asarray(dst.T)), L, W)
+            DepBlocks(jnp.asarray(src.T), jnp.asarray(dst.T)), L, (W,))
         got_parents = np.asarray(jax.jit(lay.count_parents)(
             ops(parent_done), deps(inc)))
         got_done = np.asarray(jax.jit(lay.src_done)(ops(op_done)))

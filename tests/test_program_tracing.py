@@ -151,14 +151,15 @@ def test_a_discarded_lane_runs_no_trips(memo_env):
 def test_traced_rode_votes_the_width_the_lockstep_took(wide_build):
     """32 servers under a block side of 16: a decision's ``la_rode`` is
     the servers its job's sub-ops sit on where its lookahead ran trips
-    and 0 on the zero path, and `narrow_stages` — the host's reckoning
-    from the two traces — names the width each stage of the kernel's
-    own lockstep reports on the same lanes."""
+    and 0 on the zero path, and `channel_trips` — the host's reckoning
+    from the two traces — gives the trips at each width of the channel
+    table that each stage of the kernel's own lockstep reports on the
+    same lanes."""
     import jax
     import jax.numpy as jnp
 
     from ddls_tpu.sim import jax_env as je
-    from ddls_tpu.sim.jax_lookahead import narrow_stages, stage_widths
+    from ddls_tpu.sim.jax_lookahead import channel_trips, stage_widths
 
     et = wide_build.et
     k = je._episode_kernels(et)
@@ -190,14 +191,15 @@ def test_traced_rode_votes_the_width_the_lockstep_took(wide_build):
         c, wide_build.states[0]))(jnp.asarray(
             [wide_build.row("translation_0", max(int(a), 1))
              for a in np.asarray(actions)], jnp.int32))
-    got, ran, took = test_jax_lookahead._staged(wide_build)(
+    got, ran = test_jax_lookahead._staged(wide_build)(
         every[0], every[1], jnp.asarray(~live))
     assert np.asarray(got[5]).tolist() == trips.tolist()
     widths = stage_widths(24, et.pads.max_split)
-    narrow = narrow_stages(trips, rode, widths, et.n_srv,
-                           et.pads.max_split)
-    assert [int(c) for c in took] == np.where(narrow, 16, 32).tolist()
-    assert narrow.all()
+    ran = np.asarray(ran)                      # [stages, (8, 16, 32)]
+    assert ran.tolist() == channel_trips(
+        trips, rode, widths, et.n_srv, et.pads.max_split).tolist()
+    # every lane on <= 8 servers: the 16- and the 32-wide loop ran none
+    assert ran[:, 0].sum() == trips.max() and not ran[:, 1:].any()
 
 
 def test_an_unplaced_job_runs_no_trips_and_stays_out_of_the_memo(memo_env):
@@ -280,7 +282,7 @@ _BENCH_PADS = dict(n_ops=480, n_deps=13312, n_fwd=15, n_parents=2,
 #: a fetched [U=1, B=3, T=2] trace: each lane-step's own trips, and the
 #: servers its job rode where it ran any
 _TRIPS_EP = {"la_trips": np.array([[[5, 7], [9, 0], [0, 0]]], np.int32),
-             "la_rode": np.array([[[8, 16], [20, 0], [0, 0]]], np.int32)}
+             "la_rode": np.array([[[20, 16], [8, 0], [0, 0]]], np.int32)}
 
 
 def test_trip_counters_are_the_hosts_reduction_of_the_trace():
@@ -289,9 +291,11 @@ def test_trip_counters_are_the_hosts_reduction_of_the_trace():
 
     # [U=1, B=3, T=2]: step 0 — lanes ran 5 and 9, one ran none (a hit
     # or an action without a lookahead); step 1 — a miss of 7 only
-    # ... on 8 and 20 servers (step 0) and on 16 (step 1): under 32
-    # servers and a block side of 16 the 9 trips of step 0 ran over the
-    # cluster-wide channel table, the 7 of step 1 over the narrow one
+    # ... on 20 and 8 servers (step 0) and on 16 (step 1): under 32
+    # servers and a block side of 16 the first 5 trips of step 0 ran
+    # over the cluster-wide channel table and, the lane on 20 servers
+    # done, its last 4 over the 8-wide one; the 7 of step 1 over the
+    # 16-wide one
     ep = dict(_TRIPS_EP)
     telemetry.enable()
     record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS), 32)
@@ -301,7 +305,8 @@ def test_trip_counters_are_the_hosts_reduction_of_the_trace():
         "sim.lookahead.lockstep_trips": 16,
         "sim.lookahead.lockstep_lane_trips": 48,
         "sim.lookahead.stage_trips.3": 16,
-        "sim.lookahead.narrow_trips": 7,
+        "sim.lookahead.narrow_trips": 11,
+        "sim.lookahead.narrowest_trips": 4,
         "sim.lookahead.rode.8": 1, "sim.lookahead.rode.16": 1,
         "sim.lookahead.rode.20": 1,
         "sim.lookahead.dep_slots": 13312,
@@ -322,15 +327,20 @@ def test_trip_counters_are_the_hosts_reduction_of_the_trace():
     assert "histograms" not in telemetry.snapshot()
 
 
-def _lockstep_by_hand(own, widths):
+def _lockstep_by_hand(own, rode, widths, channels=(8, 16, 32)):
     """Walk one call's lockstep trip by trip: the width steps down when
-    the next one holds the lanes still live. Trips run at each width."""
-    ran, stage, trip = [0] * len(widths), 0, 0
+    the next one holds the lanes still live, and a lane-packed stage
+    (under 128 lanes) ticks the trip over the narrowest channel table
+    that holds what every lane still live rode. Trips run at each
+    (width, channel width)."""
+    ran, stage, trip = np.zeros((len(widths), len(channels)), int), 0, 0
     while (own > trip).any():
         while stage + 1 < len(widths) and \
                 (own > trip).sum() <= widths[stage + 1]:
             stage += 1
-        ran[stage] += 1
+        widest = rode[own > trip].max()
+        ran[stage, -1 if widths[stage] >= 128 else
+            min(i for i, c in enumerate(channels) if widest <= c)] += 1
         trip += 1
     return ran
 
@@ -344,7 +354,9 @@ def test_paid_lane_trips_are_the_stages_widths_times_their_trips(
     paid: over every step of a [U, B, T] trace, each width of the
     kernel's own `stage_widths` times the trips a walk of that step's
     lockstep runs at it; `stage_trips.<W>` are those trips by width,
-    `lockstep_trips` their sum (the longest lane's count, as before)."""
+    `lockstep_trips` their sum (the longest lane's count, as before);
+    `narrow_trips` / `narrowest_trips` those that ran under the
+    cluster's 32-wide channel table / over the 8-wide one."""
     from ddls_tpu.rl.fused import record_lookahead_trips
     from ddls_tpu.sim.jax_env import ConfigPads
     from ddls_tpu.sim.jax_lookahead import stage_widths
@@ -353,25 +365,22 @@ def test_paid_lane_trips_are_the_stages_widths_times_their_trips(
     own = rng.integers(1, 153, size=(2, lanes, 3)).astype(np.int32)
     own[rng.random(own.shape) < hit_share] = 0
     widths = stage_widths(lanes, 16)
-    by_hand = np.sum([_lockstep_by_hand(own[u, :, t], widths)
-                      for u in range(2) for t in range(3)], axis=0)
-    telemetry.enable()
     rode = np.where(own > 0, rng.integers(1, 21, size=own.shape), 0)
+    by_channel = np.sum([_lockstep_by_hand(own[u, :, t], rode[u, :, t],
+                                           widths)
+                         for u in range(2) for t in range(3)], axis=0)
+    by_hand = by_channel.sum(axis=1)
+    telemetry.enable()
     record_lookahead_trips({"la_trips": own, "la_rode": rode},
                            ConfigPads(**_BENCH_PADS), 32)
     counters = telemetry.snapshot()["counters"]
-    # the trips that ran over the narrow channel table: those of the
-    # lane-packed stages entered with every live lane on <= 16 servers
-    narrow = 0
-    for u in range(2):
-        for t in range(3):
-            ran, before = _lockstep_by_hand(own[u, :, t], widths), 0
-            for width, trips in zip(widths, ran):
-                live = own[u, :, t] > before
-                if width < 128 and (rode[u, :, t][live] <= 16).all():
-                    narrow += trips
-                before += trips
-    assert counters["sim.lookahead.narrow_trips"] == narrow
+    assert counters["sim.lookahead.narrow_trips"] \
+        == by_channel[:, :2].sum()
+    assert counters["sim.lookahead.narrowest_trips"] \
+        == by_channel[:, 0].sum()
+    if (lanes, hit_share) == (24, 0.3):
+        assert 0 < by_channel[:, 0].sum() < by_channel[:, :2].sum() \
+            < by_channel.sum()
     assert sum(v for k, v in counters.items()
                if k.startswith("sim.lookahead.rode.")) == (own > 0).sum()
     assert [counters[f"sim.lookahead.stage_trips.{w}"] for w in widths] \
@@ -438,7 +447,7 @@ def test_minor_fill_metric_reads_what_the_traced_loop_carries(block_lanes):
     S = build.et.pads.max_split
     assert startup.gauges() == {"sim.lookahead.minor_slots": 128,
                                 "sim.lookahead.minor_used": S * 3,
-                                "sim.lookahead.channel_widths": [16],
+                                "sim.lookahead.channel_widths": [8, 16],
                                 "sim.lookahead.endpoint_onehot_elems": 0}
     for _ in range(2):
         record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS), 32)
@@ -776,11 +785,11 @@ def test_listed_counters_are_the_parents_on_the_same_trace(
         "sim.lookahead.lockstep_trips": int(own.max(axis=1).sum()),
         "sim.lookahead.lockstep_lane_trips":
             int(by_width @ np.asarray(widths)),
-        # PR 40's: one stage of 8 lanes; a step's trips ran over the
-        # narrow channel table (a block's side of the cluster's 8
-        # servers) iff no lane that ran trips rode more servers
-        "sim.lookahead.narrow_trips": int(own.max(axis=1)[
-            ep["la_rode"].max(axis=1) <= int(et.pads.max_split)].sum()),
+        # 8 servers: no rung of the channel table is under them (a
+        # rung under a register's 8 sublanes is none), so every trip
+        # ran over the one table there is
+        "sim.lookahead.narrow_trips": int(own.max(axis=1).sum()),
+        "sim.lookahead.narrowest_trips": int(own.max(axis=1).sum()),
         "sim.lookahead.dep_slots": int(et.pads.n_deps),
         "sim.lookahead.dep_slots_used": int(et.pads.n_deps_used),
         "sim.lookahead.dep_slots_decided":
