@@ -95,6 +95,11 @@ CHANNEL_GAUGE = "sim.lookahead.channel_widths"
 #: and destination op (:func:`endpoint_onehot_elems`)
 ENDPOINT_GAUGE = "sim.lookahead.endpoint_onehot_elems"
 
+#: ... and those it compares against a WORKER iota in `nominate`, at the
+#: narrowest width of the first stage's cascade
+#: (:func:`channel_onehot_elems`)
+ONEHOT_GAUGE = "sim.lookahead.channel_onehot_elems"
+
 
 class _Layout(NamedTuple):
     """What the tick body (:func:`_tick_loop`) leaves to the shape its
@@ -245,7 +250,12 @@ def channel_widths(num_workers: int, side: int) -> tuple:
     cluster's servers. Read from the two shapes alone: a rung under a
     register's sublanes (:data:`REGISTER_SUBLANES`) saves no register,
     and a rung no narrower than the cluster is the cluster — one width
-    where the cluster is no wider than the first rung."""
+    where the cluster is no wider than the first rung. The cluster's
+    width is a channel TABLE's; a rung under it says how many servers a
+    lane may ride to tick in a form that carries its state by SERVER
+    (:func:`server_slots`), where a dep's channel is its position and
+    there is no table — over the whole block side, or over the first
+    half of every block's rows alone."""
     return (*(rung for rung in (side // 2, side)
               if REGISTER_SUBLANES <= rung < num_workers), num_workers)
 
@@ -275,6 +285,150 @@ def dense_servers(op_worker, op_valid, n_lanes: int, num_workers: int):
             jnp.sum(used, axis=0, dtype=jnp.int32))
 
 
+def server_slots(op_worker, op_valid, n_lanes: int, num_workers: int):
+    """Where each sub-op goes when a lane's state is laid out by SERVER,
+    from packed ``op_worker`` / ``op_valid`` [No, L*S]: ``(slot, rides)``
+    — per sub-op the shard of its op's row it moves to, the rank of its
+    server among the lane's (:func:`dense_servers`), -1 for an invalid
+    one (it lands nowhere); and per lane [L] the servers its job rides.
+    Op slot (o, X) then holds the sub-op of op o on the lane's X-th
+    server, so a sub-op's worker is its position.
+
+    That needs the map to be one to one inside every op, which the
+    program checks on its input and does not assume: a lane that rides
+    more servers than a block has shards, holds a valid sub-op that is
+    unplaced, or whose op has two valid sub-ops on one server is AWAY —
+    it keeps every sub-op at its own shard (its state by server is its
+    state by shard, bit for bit) and ``rides`` more than a block's side,
+    so a cascade (:func:`_tick_loop`) holds the cluster's form, which
+    reads workers from a table, for as long as it is live. A placed
+    job's blocks sit on distinct servers (sim/jax_env.py:
+    jax_allocate_job), so only a ragged row spread over an empty cluster
+    is away among the lanes that tick. No index per element. Once a
+    call, outside the loops."""
+    import jax.numpy as jnp
+
+    No, S = op_worker.shape[0], op_worker.shape[1] // n_lanes
+    dense, rode = dense_servers(op_worker, op_valid, n_lanes, num_workers)
+    rank = jnp.where(op_valid, dense, -1)
+    shards = jnp.arange(S, dtype=jnp.int32)
+    landing = jnp.sum(rank.reshape(No, n_lanes, S, 1) == shards, axis=2,
+                      dtype=jnp.int32)                     # [No, L, S_X]
+    away = (jnp.any(landing > 1, axis=(0, 2)) | (rode > S)
+            | jnp.any((op_valid & (op_worker < 0)).reshape(
+                No, n_lanes, S), axis=(0, 2)))             # [L]
+    slot = jnp.where(jnp.repeat(away, S), jnp.tile(shards, n_lanes), rank)
+    return slot, jnp.where(away, jnp.maximum(rode, S + 1), rode)
+
+
+def _packing(n_lanes: int, n_blocks: int, side: int):
+    """Between a lane a row ([L, (o, k)] op state, [L, (b, i, j)] dep
+    state) and LANE-PACKED ([No, (l, k)], [B, S_i, (l, j)]:
+    :func:`_packed_layouts`): ``(ops, deps, lane_ops, lane_deps)``, the
+    last two the way back."""
+    L, B, S = n_lanes, n_blocks, side
+
+    def ops(x):
+        return x.reshape(L, -1, S).transpose(1, 0, 2).reshape(-1, L * S)
+
+    def deps(x):
+        return x.reshape(L, B, S, S).transpose(1, 2, 0, 3).reshape(
+            B, S, L * S)
+
+    def lane_ops(x):
+        return x.reshape(-1, L, S).transpose(1, 0, 2).reshape(L, -1)
+
+    def lane_deps(x):
+        return x.reshape(B, S, L, S).transpose(2, 0, 1, 3).reshape(L, -1)
+
+    return ops, deps, lane_ops, lane_deps
+
+
+def _contract(onehot, x, over: int, n_lanes: int):
+    """``onehot`` [L, B, No] with lane-packed ``x`` [M, (l, k)] along
+    the one-hot's axis ``over`` (1: M = B blocks, 2: M = No ops), a lane
+    a batch: [the other axis, (l, k)] i32. 0/1 times whole numbers <= S
+    in int8, summed in int32: exact."""
+    import jax
+    import jax.numpy as jnp
+
+    L = n_lanes
+    S = x.shape[-1] // L
+    out = jax.lax.dot_general(
+        onehot, x.reshape(-1, L, S).astype(jnp.int8),
+        (((over,), (0,)), ((0,), (1,))),
+        preferred_element_type=jnp.int32)                  # [L, other, S]
+    return out.transpose(1, 0, 2).reshape(-1, L * S)
+
+
+def _from_source(x, n_lanes: int):
+    """[B, (l, i)] — a value of each block's source op shard — to dep
+    state [B, i, (l, j)]: the same for every destination j."""
+    import jax.numpy as jnp
+
+    B, L = x.shape[0], n_lanes
+    S = x.shape[1] // L
+    return jnp.broadcast_to(x.reshape(B, L, S).transpose(0, 2, 1)[..., None],
+                            (B, S, L, S)).reshape(B, S, L * S)
+
+
+def by_server(slot, onehots, n_lanes: int):
+    """``(move_ops, move_deps)``: lane-packed op state [No, (l, k)] and
+    dep state [B, S_i, (l, j)] moved from shard to SERVER coordinates,
+    where every sub-op sits at ``slot`` (:func:`server_slots`) of its
+    op's row and the dep of block b from the sub-op at X to the sub-op
+    at Y at (b, X, (l, Y)); a slot nothing lands on reads 0 / False. By
+    select-and-sum against the shard iota, one axis at a time, each over
+    a WIDE minor axis — the destination shard is moved while it is the
+    row axis of the block's transpose: at most one source lands on a
+    target, so the sum is that source, exact in any float or whole
+    type. ``onehots`` are the lanes' :func:`endpoint_onehots`. No index
+    per element. Once a call, outside the loops: a dep array moved is
+    some thirty passes over itself."""
+    import jax.numpy as jnp
+
+    L = n_lanes
+    No, S = slot.shape[0], slot.shape[1] // L
+    at_src, at_dst = onehots
+    shards = jnp.arange(S, dtype=jnp.int32)
+
+    def whole(x):
+        return x.astype(jnp.int8) if x.dtype == bool else x
+
+    def move_ops(x):
+        kind, x = x.dtype, whole(x)
+        out = jnp.sum(jnp.where(slot.reshape(No, L, S, 1) == shards,
+                                x.reshape(No, L, S, 1), 0),
+                      axis=2, dtype=x.dtype)               # [No, L, S_X]
+        return out.reshape(No, L * S).astype(kind)
+
+    # where the sub-op on each row / column of a block goes: its op's
+    # row of ``slot``, through the blocks' 0/1 endpoint matrices (a
+    # padded block, no op's, reads -1: its deps land nowhere)
+    to_x, to_y = (_from_source(_contract(onehot, slot + 1, 2, L) - 1,
+                               L).astype(jnp.int8)[:, None]
+                  for onehot in (at_src, at_dst))          # [B, 1, S_r, L*S]
+    targets = jnp.arange(S, dtype=jnp.int8)[:, None, None]
+
+    def rows_moved(x, to):
+        """[B, S_r, (l, c)] -> the same with row r at row ``to``."""
+        return jnp.sum(jnp.where(to == targets, x[:, None], 0), axis=2,
+                       dtype=x.dtype)
+
+    def transposed(x):
+        """[B, i, (l, j)] <-> [B, j, (l, i)]."""
+        B = x.shape[0]
+        return x.reshape(B, S, L, S).transpose(0, 3, 2, 1).reshape(
+            B, S, L * S)
+
+    def move_deps(x):
+        out = rows_moved(transposed(rows_moved(transposed(whole(x)), to_y)),
+                         to_x)
+        return out.astype(x.dtype)
+
+    return move_ops, move_deps
+
+
 def endpoint_onehots(blocks: DepBlocks, n_ops: int):
     """The 0/1 endpoint matrices of lane-packed ``blocks`` ([B, L]
     tables): ``(at_src, at_dst)``, each [L, B, No] int8 — is original
@@ -290,30 +444,31 @@ def endpoint_onehots(blocks: DepBlocks, n_ops: int):
 
 
 def _packed_layouts(op_worker, blocks: DepBlocks, n_lanes: int,
-                    widths, onehots=None) -> tuple:
+                    widths, onehots=None, dep_rows=None) -> tuple:
     """L lanes of :class:`DepBlocks` tables, LANE-PACKED, one
     :class:`_Layout` a width of ``widths`` — the same state under
-    channel tables of those many workers, every one over ``op_worker``'s
-    server ids and the SAME endpoint tables: only the worker iota that
-    `nominate` and `select_ops` compare against differs. Op state is
-    [No, L*S] and dep state [B, S_i, L*S_j], the minor axis holding
-    (lane, shard) at ``lane*S + shard`` — the DESTINATION shard j for a
-    dep — so every select and reduction of a trip runs over L*S-wide
-    rows however few the lanes (16 shards x 32 lanes fill four vector
-    registers; 32 lanes alone a quarter of one). The lane is the MAJOR
-    part so that lanes sharded over devices stay sharded through the
-    merge (GSPMD splits a merged axis by its major factor only). A
-    per-lane value is [L], and repeated over the shards (``spread``)
-    where it meets either state. Nothing indexes per dep; integer
-    counts and max are order-free and every float op is elementwise per
-    lane, so each lane's bits are the one-job form's. ``op_worker`` comes
-    packed; ``blocks`` holds [B, L] tables.
+    channel tables of those many workers (None: under no table, by
+    position: below), every one over ``op_worker``'s server ids and the
+    SAME endpoint tables: only how `nominate` and `select_ops` find a
+    slot's worker differs. Op state is [No, L*S] and
+    dep state [B, S_i, L*S_j], the minor axis holding (lane, shard) at
+    ``lane*S + shard`` — the DESTINATION shard j for a dep — so every
+    select and reduction of a trip runs over L*S-wide rows however few
+    the lanes (16 shards x 32 lanes fill four vector registers; 32 lanes
+    alone a quarter of one). The lane is the MAJOR part so that lanes
+    sharded over devices stay sharded through the merge (GSPMD splits a
+    merged axis by its major factor only). A per-lane value is [L], and
+    repeated over the shards (``spread``) where it meets either state.
+    Nothing indexes per dep; integer counts and max are order-free and
+    every float op is elementwise per lane, so each lane's bits are the
+    one-job form's. ``op_worker`` comes packed; ``blocks`` holds [B, L]
+    tables.
 
     A block's source and destination op are found on the MATRIX unit:
     `src_done` and `count_parents` are one ``dot_general`` each, a lane
     a batch, with the blocks' 0/1 endpoint matrices
     (:func:`endpoint_onehots`, [L, B, No] int8; ``onehots`` where the
-    layouts of two id spaces share them) — [L, B, No] x [L, No, S] over the
+    layouts of a stage share them) — [L, B, No] x [L, No, S] over the
     ops, and [L, No, B] x [L, B, S] over the blocks — where selecting
     one op row out of No by comparison costs B * No * L * S element
     steps of the vector unit a primitive a trip (170 M at 570 ops x
@@ -322,6 +477,23 @@ def _packed_layouts(op_worker, blocks: DepBlocks, n_lanes: int,
     the products are summed in int32 (``preferred_element_type``, so no
     result follows ``JAX_ENABLE_X64``), and a sum over a destination's
     incoming blocks is a whole number however many they are.
+
+    A width of None: the state is laid out by SERVER
+    (:func:`server_slots`, :func:`by_server`) — op slot (o, X) is the
+    sub-op of op o on the lane's X-th server and dep slot (b, X, Y) the
+    dep of block b from the sub-op on X to the one on Y. The two
+    contractions do not change (a block's row X reads op (src[b], X),
+    its column Y adds onto op (dst[b], Y)), and there is no channel
+    table: a dep's channel is its POSITION, so `nominate` is one max
+    over the blocks and `select_ops` one max over the ops — a pass each,
+    where the table's two reductions and two read-backs against a
+    worker iota are 2 * B*S*W*L*S + 2 * B*W*W*L*S element steps
+    (:func:`channel_onehot_elems`: 64 passes over the dep state at W =
+    S = 16). Only lanes that are at home there may tick in it; a table
+    reads ``op_worker``'s ids whatever the layout. ``dep_rows``: the
+    dep state holds each block's first that many rows alone, [B,
+    dep_rows, L*S] — all a lane that rides no more servers can hold a
+    valid dep in; op state is whole.
 
     Loop-invariant tables are built here, outside the ``while_loop``s;
     the endpoint-worker tables are pinned there."""
@@ -336,6 +508,7 @@ def _packed_layouts(op_worker, blocks: DepBlocks, n_lanes: int,
     if S > 127:
         raise ValueError(f"a block side of {S} passes int8: "
                          "`count_parents` contracts counts up to it")
+    dep_rows = S if dep_rows is None else dep_rows
     rows = jnp.arange(No, dtype=jnp.int32)[:, None]        # [No, 1]
 
     def spread(x):
@@ -351,49 +524,35 @@ def _packed_layouts(op_worker, blocks: DepBlocks, n_lanes: int,
         return lambda x: over_shards(
             reduce, reduce(x, axis=tuple(range(x.ndim - 1))))
 
-    def from_source(x):
-        """[B, (l, i)] — a value of each block's source op shard — to
-        dep state [B, i, (l, j)]: the same for every destination j."""
-        return jnp.broadcast_to(
-            x.reshape(B, L, S).transpose(0, 2, 1)[..., None],
-            (B, S, L, S)).reshape(B, S, L * S)
+    from_source = partial(_from_source, n_lanes=L)
 
-    # each block's endpoint rows, per (lane, shard) slot; an unplaced op
-    # (-1) rides server 0's channels, as the caller's clipped
-    # ``pair_channel`` lookup has it
-    src, dst = spread(blocks.src), spread(blocks.dst)      # [B, L*S]
-    worker = jnp.clip(op_worker, 0)
+    if any(width is not None for width in widths):
+        # each block's endpoint rows, per (lane, shard) slot; an unplaced
+        # op (-1) rides server 0's channels, as the caller's clipped
+        # ``pair_channel`` lookup has it
+        src, dst = spread(blocks.src), spread(blocks.dst)  # [B, L*S]
+        worker = jnp.clip(op_worker, 0)
 
-    def endpoint_worker(row):
-        return jnp.max(jnp.where(row[:, None] == rows, worker, 0), axis=1)
+        def endpoint_worker(row):
+            return jnp.max(jnp.where(row[:, None] == rows, worker, 0),
+                           axis=1)
 
-    w_src = from_source(endpoint_worker(src))              # [B, S_i, L*S]
-    w_dst = endpoint_worker(dst)                           # [B, L*S_j]
-    # held as built: left to XLA, every trip of every form copies
-    # ``w_src`` (as large as the dep state) into the layout its
-    # compare reads
-    w_src, w_dst = jax.lax.optimization_barrier((w_src, w_dst))
+        w_src = from_source(endpoint_worker(src))          # [B, S_i, L*S]
+        w_dst = endpoint_worker(dst)                       # [B, L*S_j]
+        # held as built: left to XLA, every trip of every form copies
+        # ``w_src`` (as large as the dep state) into the layout its
+        # compare reads
+        w_src, w_dst = jax.lax.optimization_barrier((w_src, w_dst))
 
     at_src, at_dst = (endpoint_onehots(blocks, No) if onehots is None
                       else onehots)                        # [L, B, No]
 
-    def contract(onehot, x, over):
-        """``onehot`` [L, B, No] with lane-packed ``x`` [M, (l, k)]
-        along the one-hot's axis ``over`` (1: M = B blocks, 2: M = No
-        ops), a lane a batch: [the other axis, (l, k)] i32. 0/1 times
-        whole numbers <= S in int8, summed in int32: exact."""
-        out = jax.lax.dot_general(
-            onehot, x.reshape(-1, L, S).astype(jnp.int8),
-            (((over,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.int32)              # [L, other, S]
-        return out.transpose(1, 0, 2).reshape(-1, L * S)
-
     def src_done(op_done):
-        return from_source(contract(at_src, op_done, 2) > 0)
+        return from_source(_contract(at_src, op_done, 2, L) > 0)[:, :dep_rows]
 
     def count_parents(parent_done, inc):
         into = inc.sum(axis=1, dtype=inc.dtype)            # [B, L*S_j]
-        return parent_done + contract(at_dst, into, 1)
+        return parent_done + _contract(at_dst, into, 1, L)
 
     def channel_ops(width):
         """`nominate` and `select_ops` over a table of ``width`` workers."""
@@ -427,6 +586,21 @@ def _packed_layouts(op_worker, blocks: DepBlocks, n_lanes: int,
 
         return nominate, select_ops
 
+    def position_ops():
+        """`nominate` and `select_ops` of state laid out by server."""
+        def nominate(dscores, flow_ready):
+            # dep slot (b, X, (l, Y)) rides lane l's channel X -> Y
+            best = jnp.max(dscores, axis=0)                # [S_X, L*S_Y]
+            return flow_ready & (dscores >= best) & (dscores > 0)
+
+        def select_ops(scores, ops_ready):
+            # op slot (o, (l, X)) sits on lane l's server X: an op is
+            # selected iff it is its worker's best ready op
+            best = jnp.max(scores, axis=0)                 # [L*S_X]
+            return ops_ready & (scores == best) & (best > 0)
+
+        return nominate, select_ops
+
     def loop(live, tick, init, fit, needs=None):
         # what jax's batching makes of ``while_loop``: run while any
         # lane is live — as a stage of the lane schedule
@@ -449,7 +623,8 @@ def _packed_layouts(op_worker, blocks: DepBlocks, n_lanes: int,
         return jax.lax.while_loop(more, frozen_tick, init)
 
     return tuple(
-        _Layout(src_done, count_parents, *channel_ops(width),
+        _Layout(src_done, count_parents,
+                *(position_ops() if width is None else channel_ops(width)),
                 over_lane(jnp.any), over_lane(jnp.all), over_lane(jnp.min),
                 over_lane(partial(jnp.sum, dtype=jnp.int32)),
                 lanes=lambda x: jnp.broadcast_to(x, (L,)), spread=spread,
@@ -468,15 +643,17 @@ def _tick_loop(lay: _Layout, op_remaining, op_valid, op_score, num_parents,
     from the ``state`` an earlier stage left (None: a job's start) and
     stops once the next width holds the live lanes (``fit``; 0: when
     none is live). ``narrower`` — (rode, forms) — is the servers each
-    lane's job rides, [lanes], and the same state's layouts over
-    narrower channel tables as (servers held, layout) pairs, widest
-    first. The stage is then a CASCADE of loops over one state, from
-    ``lay``'s down: each form ticks while the stage is on and some LIVE
-    lane rides more servers than the next form holds, the last to the
-    stage's end — so the table follows the lanes still live, and a form
-    no live lane needs runs no trip. Every live lane ticks in every
-    trip and a frozen lane's state is never written, so the forms a
-    lane is carried through cannot show in its bits."""
+    lane's job rides, [lanes], and the same state's narrower layouts as
+    (servers held, layout) pairs, widest first. The stage is then a
+    CASCADE of loops over one state, from ``lay``'s down: each form
+    ticks while the stage is on and some LIVE lane rides more servers
+    than the next form holds, the last to the stage's end — so the form
+    follows the lanes still live, and a form no live lane needs runs no
+    trip. A last pair with no layout names a form the CALLER runs, over
+    another cut of the state: the cascade then stops where that form
+    holds the live lanes. Every live lane ticks in every trip and a
+    frozen lane's state is never written, so the forms a lane is
+    carried through cannot show in its bits."""
     from functools import partial
 
     import jax
@@ -560,7 +737,9 @@ def _tick_loop(lay: _Layout, op_remaining, op_valid, op_score, num_parents,
                  lay.lanes(jnp.zeros((), dt)), lay.lanes(jnp.zeros((), dt)),
                  lay.lanes(jnp.int32(0)), lay.lanes(jnp.bool_(False)))
     rode, forms = narrower or (None, ())
-    layouts = (lay, *(form for _, form in forms))
+    # a width with no layout is run by the caller, over another state:
+    # the cascade hands over to it, and does not run to the stage's end
+    layouts = (lay, *(form for _, form in forms if form is not None))
     # what the next form down holds: who rides more needs this one
     holds_below = (*(width for width, _ in forms), None)
     out, ran = state, []
@@ -571,12 +750,18 @@ def _tick_loop(lay: _Layout, op_remaining, op_valid, op_score, num_parents,
                             None if below is None else rode > below)
         # every live lane ticks in every trip of a form's loop
         ran.append(jnp.max(out[9] - before))
+    return (_results(lay, out, op_valid, dep_valid), out,
+            jnp.stack(ran[::-1]))
+
+
+def _results(lay: _Layout, state, op_valid, dep_valid):
+    """(t, comm_oh, comp_oh, busy, ok, trips) per lane, of the state a
+    tick loop left."""
     (_, _, op_done, dep_done, _, t, comm_oh, comp_oh, busy, it,
-     stuck) = out
+     stuck) = state
     finished = (lay.all(op_done | ~op_valid)
                 & lay.all(dep_done | ~dep_valid))
-    return ((t, comm_oh, comp_oh, busy, finished & ~stuck, it), out,
-            jnp.stack(ran[::-1]))
+    return t, comm_oh, comp_oh, busy, finished & ~stuck, it
 
 
 def stage_widths(n_lanes: int, side: int) -> list:
@@ -615,6 +800,22 @@ def endpoint_onehot_elems(n_lanes: int, n_ops: int, n_blocks: int,
     from :data:`REGISTER_WIDTH` lanes on (:func:`_block_dep_ops`)."""
     return 0 if n_lanes < REGISTER_WIDTH else \
         n_blocks * n_ops * n_lanes * side
+
+
+def channel_onehot_elems(n_lanes: int, n_blocks: int, side: int,
+                         num_workers: int) -> int:
+    """The elements a trip of the lockstep's first (widest) stage
+    compares against a WORKER iota in `nominate`, at the narrowest
+    width of its cascade: none where that width carries its state by
+    server (:func:`_packed_layouts`: lane-packed, with a width under
+    the cluster's), else the table's two reductions and two read-backs
+    over the cluster's W servers, 2 * B*S*W*L*S + 2 * B*W*W*L*S — the
+    one-job-a-lane form from :data:`REGISTER_WIDTH` lanes on
+    (:func:`_block_dep_ops`), and a cluster of one width."""
+    if n_lanes < REGISTER_WIDTH and len(channel_widths(num_workers,
+                                                       side)) > 1:
+        return 0
+    return 2 * n_blocks * side * num_workers * n_lanes * (side + num_workers)
 
 
 def stage_trips(own, widths):
@@ -688,20 +889,28 @@ def _lane_batched_lookahead(num_workers: int):
     lane's ticks do not depend on which lanes share its loop, so every
     lane's six results are the one-loop program's bits.
 
-    The worker x worker channel table of a lane-packed stage follows
-    the lanes still LIVE, over up to three widths
-    (:func:`channel_widths`): what a job RIDES is at most a block's
-    side but for a ragged row spread over an empty cluster, and mostly
-    under half of it, so each stage renumbers its lanes' servers
-    densely (:func:`dense_servers`, once, outside the loops) and runs
-    as a cascade of ``while_loop``s over one state (:func:`_tick_loop`),
-    widest first: the tick over the cluster's own ids while a live lane
-    rides more than a block's side, then the same tick over the dense
-    ids and the block-side table while one rides more than half of it,
-    then over the half-side table to the stage's end. A form no live
-    lane needs runs no trip; there is no branch. A bijection on the
-    servers a lane uses maps channel pairs one to one, so every form
-    gives each lane the same bits. No option selects one.
+    The form a lane-packed stage ticks in follows the lanes still LIVE,
+    over up to three widths (:func:`channel_widths`): what a job RIDES
+    is at most a block's side but for a ragged row spread over an empty
+    cluster, and mostly under half of it, and a placed job's sub-ops of
+    one op sit on distinct servers. So a call lays its packed state out
+    by SERVER — once, at its first lane-packed stage, outside every
+    loop (``to_servers``: :func:`server_slots`, :func:`by_server`);
+    where a sub-op goes depends on its own lane alone, so the later
+    stages gather whole lanes of what was moved — and each stage runs
+    as a cascade of ``while_loop``s over that state
+    (:func:`_tick_loop`), widest first: the tick over the cluster's
+    channel table while a live lane rides more than a block's side (or
+    is not one to one: the program checks, per lane), then the tick
+    with NO table — by server a dep's channel is its position, so the
+    channel's best is one max over the blocks and a worker's best op
+    one max over the ops — while one rides more than half a side, then
+    the same over the first half of every block's ROWS alone, cut
+    out of the state and laid back over it, to the stage's end. A form
+    no live lane needs runs no trip; there is no branch. Max and whole
+    counts are order-free and every float operation stays elementwise
+    per slot, so every form gives each lane the same bits. No option
+    selects one.
 
     Which op a block starts and ends at, a lane-packed stage asks the
     matrix unit: every form contracts op state and completed-dep counts
@@ -712,9 +921,8 @@ def _lane_batched_lookahead(num_workers: int):
     trip.
 
     ``run.staged`` is the same function with, beside the results, the
-    trips each stage ran at each width of the channel table
-    (:func:`channel_trips` is the host's reckoning of them), for
-    tests."""
+    trips each stage ran at each of those widths (:func:`channel_trips`
+    is the host's reckoning of them), for tests."""
     from functools import partial
 
     import jax
@@ -733,12 +941,14 @@ def _lane_batched_lookahead(num_workers: int):
             dep_valid, dep_mutual, dep_is_flow, dep_score, skip, N + E + 4,
             state, fit)[:2]
 
-    def one_loop(args, state=None, fit=0):
+    def one_loop(args, state=None, fit=0, rides=None):
         """The loop at ``args``' own lane count, in that count's form,
         and the trips it ran at each width of the channel table
         (:func:`channel_widths`, narrowest first); ``state`` comes and
         goes a lane a row (it goes only from a stage that has a
-        successor: ``fit``)."""
+        successor: ``fit``). ``rides`` [L]: ``args`` and ``state`` are
+        laid out by server (``to_servers``) and each lane's job rides
+        that many."""
         (op_remaining, op_valid, op_worker, op_score, num_parents,
          dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
          blocks, skip) = args
@@ -753,19 +963,7 @@ def _lane_batched_lookahead(num_workers: int):
             return out, left, jnp.zeros(len(channels), jnp.int32).at[-1].set(
                 ran)
 
-        def ops(x):      # [L, (o, k)] -> [No, (l, k)]
-            return x.reshape(L, N // S, S).transpose(1, 0, 2).reshape(
-                N // S, L * S)
-
-        def deps(x):     # [L, (b, i, j)] -> [B, S_i, (l, j)]
-            return x.reshape(L, B, S, S).transpose(1, 2, 0, 3).reshape(
-                B, S, L * S)
-
-        def lane_ops(x):     # ... and back
-            return x.reshape(N // S, L, S).transpose(1, 0, 2).reshape(L, N)
-
-        def lane_deps(x):
-            return x.reshape(B, S, L, S).transpose(2, 0, 1, 3).reshape(L, E)
+        ops, deps, lane_ops, lane_deps = _packing(L, B, S)
 
         def pack(state, ops, deps):
             rem_op, rem_dep, op_done, dep_done, parent_done = state[:5]
@@ -775,22 +973,87 @@ def _lane_batched_lookahead(num_workers: int):
         op_worker, op_valid = ops(op_worker), ops(op_valid)
         blocks = DepBlocks(blocks.src.T, blocks.dst.T)
         onehots = endpoint_onehots(blocks, N // S)
-        wide, = _packed_layouts(op_worker, blocks, L, channels[-1:], onehots)
-        narrower = None
-        if len(channels) > 1:
-            # the servers a job RIDES are far fewer than the cluster's:
-            # the same tick over each lane's own dense server ids and
-            # the narrower tables
-            dense, rode = dense_servers(op_worker, op_valid, L, num_workers)
-            narrower = (rode, tuple(zip(channels[:-1], _packed_layouts(
-                dense, blocks, L, channels[:-1], onehots)))[::-1])
+        # the servers a job RIDES are far fewer than the cluster's: the
+        # widths under it tick with no channel table, while every live
+        # lane is at home there (``rides``: :func:`server_slots`); the
+        # one over the whole block side shares the cluster's state
+        by_server = () if rides is None else channels[:-1]
+        sided, halved = S in by_server, bool(by_server) and by_server[0] < S
+        *under, wide = _packed_layouts(
+            op_worker, blocks, L, (None,) * sided + channels[-1:], onehots)
+        statics = (ops(op_remaining), op_valid, ops(op_score),
+                   ops(num_parents), deps(dep_remaining), deps(dep_valid),
+                   deps(dep_mutual), deps(dep_is_flow), deps(dep_score))
+        state = None if state is None else pack(state, ops, deps)
+        forms = tuple(zip((S,), under)) + (
+            ((by_server[0], None),) if halved else ())
         out, left, ran = _tick_loop(
-            wide, ops(op_remaining), op_valid, ops(op_score),
-            ops(num_parents), deps(dep_remaining), deps(dep_valid),
-            deps(dep_mutual), deps(dep_is_flow), deps(dep_score),
-            skip, N + E + 4,
-            None if state is None else pack(state, ops, deps), fit, narrower)
+            wide, *statics, skip, N + E + 4, state, fit,
+            (rides, forms) if forms else None)
+        if halved:
+            # ... and the first rung ticks the first half of every
+            # block's rows alone — a lane that rides no more has no
+            # valid dep beyond, nor in the columns beyond, which stay
+            # for the lanes' sake: at 8 lanes a halved minor axis would
+            # fill no fewer registers — cut out of that state and laid
+            # back over it, so the rows of a frozen lane that rides
+            # more are kept; op state is shared whole
+            h = by_server[0]
+            first, = _packed_layouts(op_worker, blocks, L, (None,), onehots,
+                                     dep_rows=h)
+
+            def half(x):
+                return x[:, :h] if x.ndim == 3 else x
+
+            _, halves, ran_first = _tick_loop(
+                first, *map(half, statics), skip, N + E + 4,
+                tuple(map(half, left)), fit)
+            left = tuple(
+                x if x.ndim < 3 else jnp.concatenate([x, full[:, h:]], 1)
+                for x, full in zip(halves, left))
+            out = _results(wide, left, op_valid, statics[5])
+            ran = jnp.concatenate([ran_first, ran])
         return out, pack(left, lane_ops, lane_deps) if fit else None, ran
+
+    def to_servers(args, state=None):
+        """A lane-packed stage's ``args`` and ``state`` (a lane a row)
+        laid out by SERVER, and the servers each lane's job rides
+        (:func:`server_slots`, :func:`by_server`): once a call, at its
+        first lane-packed stage — where a sub-op goes depends on its
+        own lane alone, so the later stages gather whole lanes of what
+        was moved here. The three dep masks move as one int8."""
+        (op_remaining, op_valid, op_worker, op_score, num_parents,
+         dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
+         blocks, skip) = args
+        L, B = op_remaining.shape[0], blocks.src.shape[1]
+        S = _block_side(dep_remaining.shape[1], B)
+        ops, deps, lane_ops, lane_deps = _packing(L, B, S)
+        slot, rides = server_slots(ops(op_worker), ops(op_valid), L,
+                                   num_workers)
+        move_ops, move_deps = by_server(slot, endpoint_onehots(
+            DepBlocks(blocks.src.T, blocks.dst.T), op_remaining.shape[1] // S),
+            L)
+
+        def op_state(x):
+            return lane_ops(move_ops(ops(x)))
+
+        def dep_state(x):
+            return lane_deps(move_deps(deps(x)))
+
+        masks = dep_state(dep_valid.astype(jnp.int8)
+                          + 2 * dep_mutual.astype(jnp.int8)
+                          + 4 * dep_is_flow.astype(jnp.int8))
+        args = (op_state(op_remaining), op_state(op_valid),
+                op_state(op_worker + 1) - 1, op_state(op_score),
+                op_state(num_parents), dep_state(dep_remaining),
+                masks % 2 > 0, masks // 2 % 2 > 0, masks // 4 > 0,
+                dep_state(dep_score), blocks, skip)
+        if state is not None:
+            rem_op, rem_dep, op_done, dep_done, parent_done = state[:5]
+            state = (op_state(rem_op), dep_state(rem_dep), op_state(op_done),
+                     dep_state(dep_done), op_state(parent_done),
+                     *state[5:])
+        return args, state, rides
 
     def rows(x, lanes):
         """Whole lanes of ``x``: ``lanes`` are distinct and in range."""
@@ -798,8 +1061,7 @@ def _lane_batched_lookahead(num_workers: int):
                                mode="promise_in_bounds")
 
     def staged(*args):
-        op_remaining, dep_remaining, blocks, skip = (args[0], args[5],
-                                                     args[10], args[11])
+        op_remaining, dep_remaining, blocks = args[0], args[5], args[10]
         (L, N), E, B = op_remaining.shape, dep_remaining.shape[1], \
             blocks.src.shape[1]
         S = _block_side(E, B)
@@ -807,24 +1069,35 @@ def _lane_batched_lookahead(num_workers: int):
             raise ValueError(f"({N}, {E}) is not a block layout of {B} "
                              "blocks")
         widths = stage_widths(L, S)
+        # a cluster of one width keeps its table, and its state by shard
+        by_server = len(channel_widths(num_workers, S)) > 1
+
+        def placed(width):
+            return by_server and width < REGISTER_WIDTH
+
+        state = rides = None
+        if placed(L):
+            args, state, rides = to_servers(args)
         if len(widths) == 1:
-            part, _, ran = one_loop(args)
+            part, _, ran = one_loop(args, rides=rides)
             return part, (ran,)
-        part, state, ran = one_loop(args, fit=widths[1])
+        part, state, ran = one_loop(args, fit=widths[1], rides=rides)
         results, lanes, ran = part, jnp.arange(L), [ran]
         for width, fit in zip(widths[1:], widths[2:] + [0]):
             # the lanes still live first, in their order, then as many
             # of the others (frozen: they carry their results along) as
             # fill the width
-            ok, trips, stuck = part[4], part[5], state[-1]
+            ok, trips, stuck, skip = part[4], part[5], state[-1], args[11]
             live = ~ok & ~stuck & (trips < N + E + 4)
             if skip is not None:
-                live = live & ~rows(skip, lanes)
+                live = live & ~skip
             keep = jnp.argsort(~live, stable=True)[:width]
             lanes = rows(lanes, keep)
-            part, state, by_channel = one_loop(
-                jax.tree_util.tree_map(lambda x: rows(x, lanes), args),
-                jax.tree_util.tree_map(lambda x: rows(x, keep), state), fit)
+            args, state, rides = jax.tree_util.tree_map(
+                lambda x: rows(x, keep), (args, state, rides))
+            if placed(width) and rides is None:
+                args, state, rides = to_servers(args, state)
+            part, state, by_channel = one_loop(args, state, fit, rides)
             ran.append(by_channel)
             results = tuple(
                 x.at[lanes].set(y, unique_indices=True,
@@ -862,6 +1135,8 @@ def _lane_batched_lookahead(num_workers: int):
                           list(channel_widths(num_workers, side)))
         startup.set_gauge(ENDPOINT_GAUGE, endpoint_onehot_elems(
             lanes, args[0].shape[1] // side, n_blocks, side))
+        startup.set_gauge(ONEHOT_GAUGE, channel_onehot_elems(
+            lanes, n_blocks, side, num_workers))
         out = tuple(x.reshape((axis_size, -1) + x.shape[1:])
                     for x in run(*args))
         return out, (True,) * len(out)

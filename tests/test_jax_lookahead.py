@@ -197,16 +197,27 @@ class _BlockBuild:
         self.et = et = je.build_episode_tables(env, quantum=quantum)
         tb = et.tables
 
-        def arguments(cfg, other_free, scatter=0):
+        def arguments(cfg, other_free, scatter=0, flip=0, collide=0):
             mem = jnp.full((et.n_srv,), et.worker_mem, tb["dep_size"].dtype)
             ots, _, ok = je.jax_allocate_job(mem, other_free, cfg, tb,
                                              et.st, et.pads)
             # ``scatter`` moves original op o's shards ``scatter * o``
             # servers on, before pricing: a mounted graph no allocator
             # makes, riding more servers than a block holds
-            ots = jnp.where(ots >= 0, (ots + scatter * (
-                jnp.arange(ots.shape[0]) // et.pads.max_split)) % et.n_srv,
-                ots)
+            S = et.pads.max_split
+            op, shard = jnp.divmod(jnp.arange(ots.shape[0]), S)
+            ots = jnp.where(ots >= 0, (ots + scatter * op) % et.n_srv, ots)
+            # ``flip`` puts every odd op's shards on its servers in the
+            # REVERSE order (co-location refused: the ops of one job on
+            # the same servers in different orders), and ``collide`` op
+            # 0's second shard on its first one's server — a placement
+            # the allocator never makes (a block's servers are distinct)
+            split = jnp.sum((ots >= 0).reshape(-1, S), axis=1)[op]
+            flipped = ots[op * S + jnp.clip(split - 1 - shard, 0)]
+            ots = jnp.where((flip > 0) & (op % 2 == 1) & (ots >= 0),
+                            flipped, ots)
+            ots = ots.at[1].set(jnp.where((collide > 0) & (ots[1] >= 0),
+                                          ots[0], ots[1]))
             times, is_flow, _, op_score, dep_score, _ = \
                 je.jax_price_and_score(ots, cfg, tb, et.st, et.pads,
                                        et.comm)
@@ -451,7 +462,7 @@ def test_stages_run_the_trips_the_host_reckons(block_build, n_lanes, mix):
     assert channel_widths(block_build.et.n_srv, S) == (8, 16)
     by_channel = np.asarray(ran)
     assert by_channel.tolist() == channel_trips(
-        own, np.where(own > 0, _rode(args), 0), widths,
+        own, np.where(own > 0, _rides(args, S), 0), widths,
         block_build.et.n_srv, S).tolist()
     # one job a lane keeps the cluster's width
     assert not by_channel[np.asarray(widths) >= REGISTER_WIDTH, 0].any()
@@ -474,9 +485,10 @@ def test_stages_run_the_trips_the_host_reckons(block_build, n_lanes, mix):
 @pytest.fixture(scope="module")
 def wide_build(tmp_path_factory):
     """`block_build`'s two tiny graphs on RAMP 4x4x2: 32 servers under
-    a block side of 16, so the lane-packed tick has THREE widths of
-    channel table (`channel_widths`) — at the small pads (192 op x
-    4,352 dep slots)."""
+    a block side of 16, so the lane-packed tick has THREE widths
+    (`channel_widths`): the cluster's channel table and, under it, the
+    state by server over the whole block side and over its first half
+    — at the small pads (192 op x 4,352 dep slots)."""
     from ddls_tpu.graphs.synthetic import generate_pipedream_txt_files
     from ddls_tpu.sim.jax_lookahead import channel_widths
 
@@ -498,11 +510,29 @@ def _rode(args):
                        for v, w in zip(valid, worker)])
 
 
-#: a lane: (model, degree, cluster state, scatter). On the empty 32
-#: servers the rows ride as many servers as their degree — but the
+def _rides(args, side):
+    """`_rode`, but more than a block's side for a lane the by-server
+    forms may not hold (`server_slots`: a valid sub-op nowhere — a job
+    that did not place, which the env never runs — or two valid sub-ops
+    of one op on one server): the cluster's form holds it while it is
+    live, so that is what `channel_trips` must be told it rode."""
+    valid, worker = np.asarray(args[1]), np.asarray(args[2])
+    rode = _rode(args)
+    for lane, (ok, on) in enumerate(zip(valid.reshape(len(rode), -1, side),
+                                        worker.reshape(len(rode), -1, side))):
+        if (on[ok] < 0).any() or any(
+                len(set(w[v].tolist())) < v.sum() for v, w in zip(ok, on)):
+            rode[lane] = max(rode[lane], side + 1)
+    return rode
+
+
+#: a lane: (model, degree, cluster state, scatter[, flip]). On the empty
+#: 32 servers the rows ride as many servers as their degree — but the
 #: ragged ones, whose 4-way ops sit on another block: degree 6 rides 8,
 #: and degree 8 rides 10 beside another job (state 1). ``scatter`` 1
-#: spreads a degree-2 row over 18 servers and a degree-1 row over 12
+#: spreads a degree-2 row over 18 servers and a degree-1 row over 12,
+#: ``scatter`` 2 a degree-2 or -4 row over 16, every op on ANOTHER set;
+#: ``flip`` lays every odd op on its servers in the reverse order
 #: (`_BlockBuild.arguments`)
 _SHORT, _MID, _LONG = (("cnn_0", 1, 0, 0), ("translation_0", 2, 0, 0),
                        ("translation_0", 8, 0, 0))
@@ -517,6 +547,14 @@ _ON_12, _ON_10, _ON_10_LONG = (("translation_0", 1, 0, 1),
                                ("cnn_0", 8, 1, 0), ("translation_0", 8, 1, 0))
 _ON_8 = [_SHORT, _MID, _LONG, ("translation_0", 6, 0, 0), ("cnn_0", 8, 0, 0),
          ("translation_0", 4, 1, 0), ("cnn_0", 2, 0, 0)]
+#: the ops of one job on different server sets and in different orders:
+#: no op's shard k sits on the server of rank k
+_SHUFFLED = [("translation_0", 4, 0, 2, 1), ("translation_0", 2, 0, 2, 1),
+             ("translation_0", 1, 0, 1, 1), ("translation_0", 8, 0, 0, 1),
+             ("translation_0", 6, 0, 0, 1), ("cnn_0", 8, 1, 0, 1),
+             ("cnn_0", 4, 0, 2, 1), ("translation_0", 8, 1, 0, 1)]
+#: rows no shape of RAMP 4x4x2 places: some ops sit nowhere (-1)
+_UNPLACED = [("translation_0", 16, 0, 0), ("cnn_0", 16, 1, 0)]
 #: case -> (lanes, skipped lanes): (a) every lane on <= 16 servers, the
 #: longest on 8; (b) one live lane on 17-20; (c) a skipped lane on
 #: > 16, and one that finishes first and is carried along as a filler,
@@ -524,7 +562,10 @@ _ON_8 = [_SHORT, _MID, _LONG, ("translation_0", 6, 0, 0), ("cnn_0", 8, 0, 0),
 #: / 8 ways on different blocks); (e) every LIVE lane on <= 8, a
 #: skipped lane on 18 and one on 10 among them; (f) a 12-server rider
 #: that finishes first; (g) a 10-server rider that is the longest; (h)
-#: an 18-, a 10- and <= 8-server riders in one stage
+#: an 18-, a 10- and <= 8-server riders in one stage; (i) every op of a
+#: job on its own servers in its own order, ragged rows among them;
+#: (j) two VOID lanes — a job that did not place, skipped as the env
+#: skips it — frozen beside live lanes
 _CHANNEL_CASES = {
     "all_narrow": ([_MIXED[i % len(_MIXED)] for i in range(24)], (5, 12)),
     "one_wide_live": ([_OVER if i == 7 else _MIXED[i % len(_MIXED)]
@@ -543,7 +584,26 @@ _CHANNEL_CASES = {
                         for i in range(24)], ()),
     "three_forms_one_stage": ([_OVER, _ON_10] + [_MID] * 7 + [_LONG] * 15,
                               ()),
+    "shuffled_servers": ([_SHUFFLED[i % len(_SHUFFLED)] for i in range(24)],
+                         ()),
+    "void_lanes": ([_UNPLACED[0] if i == 4 else _UNPLACED[1] if i == 10
+                    else _MIXED[i % len(_MIXED)] for i in range(24)],
+                   (4, 10)),
 }
+
+
+def _channel_lanes(build, lanes):
+    """`_BlockBuild.arguments` of ``lanes`` ((model, degree, cluster
+    state, scatter[, flip]) each) under one vmap."""
+    import jax
+    import jax.numpy as jnp
+
+    cfgs = jnp.asarray([build.row(m, d) for m, d, *_ in lanes], jnp.int32)
+    states = jnp.stack([build.states[lane[2]] for lane in lanes])
+    scatter = jnp.asarray([lane[3] for lane in lanes], jnp.int32)
+    flip = jnp.asarray([lane[4] if len(lane) > 4 else 0 for lane in lanes],
+                       jnp.int32)
+    return jax.vmap(build.arguments)(cfgs, states, scatter, flip)
 
 
 @pytest.mark.parametrize("case", _CHANNEL_CASES)
@@ -562,11 +622,10 @@ def test_channel_table_width_follows_the_servers_ridden(wide_build, case):
 
     build, (lanes, skipped) = wide_build, _CHANNEL_CASES[case]
     n_lanes, S = len(lanes), wide_build.et.pads.max_split
-    cfgs = jnp.asarray([build.row(m, d) for m, d, _, _ in lanes], jnp.int32)
-    states = jnp.stack([build.states[s] for _, _, s, _ in lanes])
-    scatter = jnp.asarray([sc for *_, sc in lanes], jnp.int32)
-    args, blocks, placed = jax.vmap(build.arguments)(cfgs, states, scatter)
-    assert np.asarray(placed).all()
+    args, blocks, placed = _channel_lanes(build, lanes)
+    void = [lane in _UNPLACED for lane in lanes]
+    assert (np.asarray(placed) != void).all()
+    assert set(np.nonzero(void)[0]) <= set(skipped)
     skip = jnp.zeros(n_lanes, bool).at[jnp.asarray(skipped, int)].set(True)
     want = _flat_per_lane(build, args, blocks, skip, lanes)
     got, ran = _staged(build)(args, blocks, skip)
@@ -602,7 +661,8 @@ def test_channel_table_width_follows_the_servers_ridden(wide_build, case):
         assert ran.tolist() == [[m - w, 0, w], [l - m, 0, 0], [0, 0, 0]]
     if case == "ragged_rows":
         splits = {tuple(sorted(set(np.asarray(
-            build.et.tables["f_split"][int(c)]).tolist()))) for c in cfgs}
+            build.et.tables["f_split"][build.row(m, d)]).tolist())))
+            for m, d, *_ in lanes}
         assert splits == {(4, 6), (4, 8), (1, 2, 6)}
         assert sorted(set(rode.tolist())) == [8, 10]   # wider than degree
         assert at_32 == 0 and at_16 == own[rode == 10].max()
@@ -625,6 +685,49 @@ def test_channel_table_width_follows_the_servers_ridden(wide_build, case):
         w, m, e, l = own[0], own[1], own[2], own[9]
         assert (rode[0], rode[1]) == (18, 10) and w < m < e < l
         assert ran.tolist() == [[e - m, m - w, w], [l - e, 0, 0], [0, 0, 0]]
+    if case == "shuffled_servers":
+        # every op of a job on another set of servers, odd ops in the
+        # reverse order: no lane's state by server is its state by shard
+        worker = np.asarray(args[2]).reshape(n_lanes, -1, S)
+        valid = np.asarray(args[1]).reshape(n_lanes, -1, S)
+        for on, ok in zip(worker, valid):
+            used = sorted(set(on[ok].tolist()))
+            at_rank = [(np.asarray([used.index(w) for w in row[v]])
+                        == np.arange(v.sum())).all()
+                       for row, v in zip(on, ok) if v.any()]
+            assert not all(at_rank)
+        assert 8 <= rode.min() < rode.max() == S
+        assert at_32 == 0 and at_16 > 0 and at_8 > 0
+    if case == "void_lanes":
+        # a job that did not place holds valid sub-ops nowhere: away,
+        # but skipped, so the cluster's form runs no trip for it
+        worker = np.asarray(args[2])[[4, 10]]
+        assert (worker[np.asarray(args[1])[[4, 10]]] < 0).any()
+        assert at_32 == 0 and at_8 + at_16 == own.max() > 0
+
+
+@pytest.mark.parametrize("case", ["shuffled_servers",
+                                  "three_forms_one_stage"])
+def test_by_server_forms_are_the_flat_path_under_x64(wide_build, case):
+    """``JAX_ENABLE_X64``: the same lanes with every time and score in
+    f64 — the move to server coordinates is a select-and-sum of one
+    source a target, exact in any float type — through all three forms
+    of the cascade: f64 results, the flat path's bits."""
+    import jax
+    import jax.numpy as jnp
+
+    build, (lanes, skipped) = wide_build, _CHANNEL_CASES[case]
+    args, blocks, _ = _channel_lanes(build, lanes)
+    skip = jnp.zeros(len(lanes), bool).at[
+        jnp.asarray(skipped, int)].set(True)
+    with jax.enable_x64(True):
+        args = tuple(jnp.asarray(np.asarray(x, np.float64))
+                     if x.dtype == jnp.float32 else x for x in args)
+        want = _flat_per_lane(build, args, blocks, skip, lanes)
+        got, ran = _staged(build)(args, blocks, skip)
+        _assert_same_bits(got, want, (case, "x64"))
+        assert [np.asarray(x).dtype for x in got[:4]] == [np.float64] * 4
+        assert (np.asarray(ran).sum(axis=0) > 0).sum() >= 2
 
 
 def _whiles_and_conds(staged, *arguments):
@@ -732,6 +835,112 @@ def test_dense_servers_is_a_bijection_on_the_servers_a_lane_uses():
     # server 0 counts for lane 2 through its valid unplaced sub-op alone
     assert 0 not in set(worker[2][valid[2]].tolist())
     assert int(rode[2]) == len(set(worker[2][valid[2]].tolist()))
+
+
+def _packed(x, n_ops, side):
+    """[L, (o, k)] -> [No, (l, k)], as the lane-packed stages carry it."""
+    import jax.numpy as jnp
+
+    L = x.shape[0]
+    return jnp.asarray(x).reshape(L, n_ops, side).transpose(1, 0, 2).reshape(
+        n_ops, L * side)
+
+
+@pytest.mark.parametrize("cluster", ["block_build", "wide_build"])
+def test_a_placed_jobs_ops_sit_on_distinct_servers(request, cluster):
+    """The precondition of the by-server layout, where it is PRODUCED:
+    over every (row, cluster state) of the small presets,
+    `jax_allocate_job` puts the valid sub-ops of every op of a job it
+    PLACED on distinct servers and none nowhere, so `server_slots` finds
+    the lane at home — at its servers' ranks, riding what it rode —
+    unless it rides more servers than a block has shards; a job it did
+    not place has valid sub-ops nowhere (the env runs no lookahead for
+    it: ``void``, tests/test_program_tracing.py) and is away."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddls_tpu.sim.jax_lookahead import server_slots
+
+    build = request.getfixturevalue(cluster)
+    pads = build.et.pads
+    S, No = pads.max_split, pads.n_ops // pads.max_split
+    rows = [(build.row(m, d), s) for m, d in _BLOCK_ROWS
+            for s in range(len(build.states))]
+    args, _, placed = _lane_arguments(build, rows)
+    placed = np.asarray(placed)
+    valid = np.asarray(args[1]).reshape(len(rows), No, S)
+    worker = np.asarray(args[2]).reshape(len(rows), No, S)
+    assert placed.any() and not placed.all()
+    for lane in np.nonzero(placed)[0]:
+        for on, ok in zip(worker[lane], valid[lane]):
+            assert (on[ok] >= 0).all()
+            assert len(set(on[ok].tolist())) == ok.sum()
+    for lane in np.nonzero(~placed)[0]:
+        assert (worker[lane][valid[lane]] < 0).any()
+    slot, rides = jax.jit(lambda w, v: server_slots(
+        w, v, len(rows), build.et.n_srv))(_packed(args[2], No, S),
+                                          _packed(args[1], No, S))
+    slot = np.asarray(slot).reshape(No, len(rows), S).transpose(1, 0, 2)
+    rode, rides = _rode(args), np.asarray(rides)
+    home = placed & (rode <= S)
+    assert home.sum() > len(rows) // 3
+    assert (rides[home] == rode[home]).all() and (rides[~home] > S).all()
+    for lane in range(len(rows)):
+        if not home[lane]:
+            assert (slot[lane] == np.arange(S)).all()      # where it was
+            continue
+        used = sorted(set(worker[lane][valid[lane]].tolist()))
+        for on, ok, to in zip(worker[lane], valid[lane], slot[lane]):
+            assert [used[x] for x in to[ok]] == on[ok].tolist()
+            assert (to[~ok] == -1).all()
+
+
+def test_a_lane_that_is_not_one_to_one_holds_the_clusters_form(wide_build):
+    """The guard: a lane whose op has two valid sub-ops on ONE server (a
+    placement no allocator makes) or a valid sub-op nowhere (a job that
+    did not place, run all the same) rides few servers, yet by server
+    two sub-ops would share a slot — `server_slots` finds both AWAY, the
+    stage ticks over the cluster's table for as long as either is live,
+    and every lane's six results are the flat path's bits."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddls_tpu.sim.jax_lookahead import (channel_widths, server_slots,
+                                            stage_trips, stage_widths)
+
+    build = wide_build
+    pads = build.et.pads
+    S, No = pads.max_split, pads.n_ops // pads.max_split
+    lanes = [_MIXED[i % len(_MIXED)] for i in range(24)]
+    lanes[2], lanes[5] = ("translation_0", 4, 0, 0), _UNPLACED[0]
+    cfgs = jnp.asarray([build.row(m, d) for m, d, *_ in lanes], jnp.int32)
+    states = jnp.stack([build.states[lane[2]] for lane in lanes])
+    none = jnp.zeros(24, jnp.int32)
+    args, blocks, placed = jax.vmap(build.arguments)(
+        cfgs, states, none, none, none.at[2].set(1))
+    assert np.asarray(placed).tolist() == [i != 5 for i in range(24)]
+    worker = np.asarray(args[2]).reshape(24, No, S)
+    assert worker[2, 0, 0] == worker[2, 0, 1] >= 0          # the collision
+    _, rides = server_slots(_packed(args[2], No, S), _packed(args[1], No, S),
+                            24, build.et.n_srv)
+    rode, rides = _rode(args), np.asarray(rides)
+    assert rode[2] == 4 and (rode <= S).all()        # few servers
+    away = np.arange(24) % 24 == 2
+    away[5] = True
+    assert (rides[away] == S + 1).all()
+    assert (rides[~away] == rode[~away]).all()
+
+    skip = jnp.zeros(24, bool)
+    want = _flat_per_lane(build, args, blocks, skip, lanes)
+    got, ran = _staged(build)(args, blocks, skip)
+    _assert_same_bits(got, want, "guard")
+    own, ran = want[5], np.asarray(ran)
+    assert len(channel_widths(build.et.n_srv, S)) == ran.shape[1] == 3
+    assert ran.sum(axis=1).tolist() == stage_trips(
+        own, stage_widths(24, S)).tolist()
+    # the cluster's form for as long as an away lane is live, then none
+    assert 0 < own[away].max() < own.max()
+    assert ran[:, -1].sum() == own[away].max()
 
 
 def test_rode_is_the_hosts_count_on_a_mounted_job(dataset_dir):
@@ -915,6 +1124,25 @@ def _lookahead_body(closed_jaxpr, dep_state):
     return _lookahead_bodies(closed_jaxpr, dep_state)[0]
 
 
+def _cascade_bodies(closed_jaxpr, n_blocks, side, n_lanes, servers):
+    """The tick bodies of one lane-packed stage's cascade, widest width
+    first (`channel_widths`): those over the stage's whole state [B, S,
+    L*S] — the cluster's table, then the state by server —, and the
+    first rung's over half the rows [B, S/2, L*S] where there is
+    one: ``(whole, half)``."""
+    from ddls_tpu.sim.jax_lookahead import channel_widths
+
+    channels = channel_widths(servers, side)
+    halved = len(channels) > 1 and channels[0] < side
+    whole = _lookahead_bodies(
+        closed_jaxpr, (n_blocks, side, side * n_lanes),
+        forms=len(channels) - halved)
+    half = _lookahead_bodies(
+        closed_jaxpr, (n_blocks, side // 2, side * n_lanes),
+        forms=1) if halved else []
+    return whole, half
+
+
 def test_block_path_nested_vmaps_pack_as_one_loop(block_build):
     """`price_all`'s shape — a vmap over the cfg axis, cluster state
     unbatched — inside a vmap over lanes (cluster states): ONE loop at
@@ -940,8 +1168,9 @@ def test_block_path_nested_vmaps_pack_as_one_loop(block_build):
     _assert_same_bits(got, want, "nested")
     pads = block_build.et.pads
     S, L = pads.max_split, len(cfgs) * len(states)
-    _lookahead_bodies(jax.make_jaxpr(nested)(cfgs, states),
-                      (pads.n_blocks, S, S * L), forms=2)
+    whole, half = _cascade_bodies(jax.make_jaxpr(nested)(cfgs, states),
+                                  pads.n_blocks, S, L, block_build.et.n_srv)
+    assert (len(whole), len(half)) == (1, 1)
 
 
 #: equations of the flat path's tick body as jax 0.9 traces it: the
@@ -974,12 +1203,14 @@ def test_env_lookahead_body_indexes_no_dep(block_build):
     bank = {k: jnp.asarray(v) for k, v in bank.items()}
     episode = je.make_episode_fn(et)
     traced = jax.make_jaxpr(episode)(bank, jnp.asarray([16, 4], jnp.int32))
-    for body in _lookahead_bodies(traced, (B, S, S), forms=2):
+    whole, half = _cascade_bodies(traced, B, S, 1, et.n_srv)
+    for body in whole + half:
         assert _per_dep_indexing(body, M) == []
 
     args, blocks, _ = _lane_arguments(block_build, _lanes(block_build, 32))
     lanes = jax.make_jaxpr(jax.vmap(block_build.block_fn))(args, blocks)
-    for body in _lookahead_bodies(lanes, (B, S, 32 * S), forms=2):
+    whole, half = _cascade_bodies(lanes, B, S, 32, et.n_srv)
+    for body in whole + half:
         assert _per_dep_indexing(body, M) == []
     assert startup.gauges()["sim.lookahead.minor_used"] == 32 * S
     args, blocks, _ = _lane_arguments(block_build, _lanes(block_build, 128))
@@ -1036,7 +1267,10 @@ def test_packed_body_reaches_endpoints_by_contraction(block_build, n_lanes):
         args, blocks, _ = _lane_arguments(block_build,
                                           _lanes(block_build, n_lanes))
         traced = jax.make_jaxpr(jax.vmap(block_build.block_fn))(args, blocks)
-    for body in _lookahead_bodies(traced, (B, S, S * n_lanes), forms=2):
+    whole, half = _cascade_bodies(traced, B, S, n_lanes,
+                                  block_build.et.n_srv)
+    assert (len(whole), len(half)) == (1, 1)
+    for body in whole + half:
         shapes = list(_equation_shapes(body))
         dots = [ops for name, ops in shapes if name == "dot_general"]
         assert [ops[0] for ops in dots] == [(n_lanes, B, No)] * 2
@@ -1046,6 +1280,87 @@ def test_packed_body_reaches_endpoints_by_contraction(block_build, n_lanes):
         assert not [name for name, ops in shapes if passes & set(ops)]
     if n_lanes > 1:
         assert startup.gauges()[ENDPOINT_GAUGE] == 0
+
+
+@pytest.mark.parametrize("n_lanes", [8, 32])
+def test_by_server_body_compares_with_no_worker_iota(wide_build, n_lanes):
+    """The engagement pin of the by-server form: under a cluster wider
+    than a block the first stage's cascade is the cluster's table form
+    and then the form under it, whose traced tick body holds NO
+    equation over more elements than the dep state itself — the
+    table's one-hots are B * S * W * L * S and B * W * W * L * S — and
+    no compare against a worker iota: a slot's worker is its position.
+    The cluster's form beside it holds both, and the gauge reads 0."""
+    import jax
+
+    from ddls_tpu.sim.jax_lookahead import ONEHOT_GAUGE, channel_widths
+    from ddls_tpu.telemetry import startup
+
+    pads = wide_build.et.pads
+    B, S, W = pads.n_blocks, pads.max_split, wide_build.et.n_srv
+    channels = channel_widths(W, S)
+    args, blocks, _ = _channel_lanes(
+        wide_build, [_MIXED[i % len(_MIXED)] for i in range(n_lanes)])
+    traced = jax.make_jaxpr(jax.vmap(wide_build.block_fn))(args, blocks)
+    (table, *by_server), half = _cascade_bodies(traced, B, S, n_lanes, W)
+    assert len(by_server) == len(half) == 1 and channels == (S // 2, S, W)
+    by_server += half
+    state = B * S * S * n_lanes
+
+    def over_state(body):
+        return [(name, shape) for name, ops in _equation_shapes(body)
+                for shape in ops if int(np.prod(shape)) > state]
+
+    def iota_compares(body):
+        """``eq`` into more than the op state — a [No, L*S] compare is
+        the selected op's ``scores == best`` —: a one-hot over the
+        worker iota."""
+        return [ops[-1] for name, ops in _equation_shapes(body)
+                if name == "eq" and int(np.prod(ops[-1]))
+                > pads.n_ops * n_lanes]
+
+    assert (B, S, W, S * n_lanes) in [shape for _, shape in over_state(table)]
+    assert set(iota_compares(table)) == {
+        (B, S, W, S * n_lanes), (B, 1, W, S * n_lanes),
+        (W, pads.n_ops // S, S * n_lanes)}
+    assert by_server
+    for body in by_server:
+        assert over_state(body) == [] and iota_compares(body) == []
+        dots = [ops for name, ops in _equation_shapes(body)
+                if name == "dot_general"]
+        assert len(dots) == 2                  # the endpoints, as before
+    assert startup.gauges()[ONEHOT_GAUGE] == 0
+
+
+#: (lanes, blocks, block side, servers) -> the elements a trip of the
+#: first stage compares with a worker iota in `nominate`, in its form
+#: over a table no wider than a block's side
+_ONEHOT_ELEMS = [
+    (16, 1162, 16, 32, 0), (48, 456, 16, 32, 0), (80, 222, 16, 32, 0),
+    (64, 52, 16, 32, 0), (127, 52, 16, 32, 0),
+    (128, 52, 16, 32, 2 * 52 * 16 * 32 * 128 * (16 + 32)),
+    (320, 52, 16, 32, 2 * 52 * 16 * 32 * 320 * (16 + 32)),
+    (32, 17, 16, 16, 0), (32, 17, 8, 16, 0),
+    (8, 20, 8, 8, 2 * 20 * 8 * 8 * 8 * (8 + 8)),
+    (4, 20, 16, 8, 2 * 20 * 16 * 8 * 4 * (16 + 8)),
+    (8, 20, 4, 8, 2 * 20 * 4 * 8 * 8 * (4 + 8))]
+
+
+@pytest.mark.parametrize("n_lanes,n_blocks,side,servers,elems", _ONEHOT_ELEMS,
+                         ids=[f"{c[0]}x{c[1]}on{c[3]}" for c in _ONEHOT_ELEMS])
+def test_channel_onehot_elems(n_lanes, n_blocks, side, servers, elems):
+    """The gauge's table: a lane-packed first stage whose cascade has a
+    width under the cluster's finds a dep's channel by its position
+    there (0); the one-job-a-lane form from 128 lanes on reduces onto
+    and reads back from the cluster's table (2 * B*S*W*L*S + 2 *
+    B*W*W*L*S), and so does a cluster of one width."""
+    from ddls_tpu.sim.jax_lookahead import (REGISTER_WIDTH,
+                                            channel_onehot_elems,
+                                            channel_widths)
+
+    assert channel_onehot_elems(n_lanes, n_blocks, side, servers) == elems
+    assert (elems == 0) == (n_lanes < REGISTER_WIDTH
+                            and len(channel_widths(servers, side)) > 1)
 
 
 #: (lanes, original ops, blocks, block side) -> elements a trip of the

@@ -448,7 +448,17 @@ def test_minor_fill_metric_reads_what_the_traced_loop_carries(block_lanes):
     assert startup.gauges() == {"sim.lookahead.minor_slots": 128,
                                 "sim.lookahead.minor_used": S * 3,
                                 "sim.lookahead.channel_widths": [8, 16],
-                                "sim.lookahead.endpoint_onehot_elems": 0}
+                                "sim.lookahead.endpoint_onehot_elems": 0,
+                                # a width under the cluster's: by server
+                                "sim.lookahead.channel_onehot_elems": 0}
+    args128, blocks128, _ = test_jax_lookahead._lane_arguments(
+        build, test_jax_lookahead._lanes(build, 128))
+    jax.make_jaxpr(jax.vmap(build.block_fn))(args128, blocks128)
+    # one job a lane: the cluster's table, 2 * B*S*W*L*S + 2 * B*W*W*L*S
+    B, W = build.et.pads.n_blocks, build.et.n_srv
+    assert startup.gauges()["sim.lookahead.channel_onehot_elems"] == \
+        2 * B * S * W * 128 * S + 2 * B * W * W * 128 * S > 0
+    jax.make_jaxpr(jax.vmap(build.block_fn))(args, blocks)
     for _ in range(2):
         record_lookahead_trips(ep, ConfigPads(**_BENCH_PADS), 32)
     assert harness.read_layer_metric("lookahead_minor_slots", ctx) == 128
@@ -901,7 +911,8 @@ def test_build_run_leaves_each_startup_span_once(fused_dataset, tmp_path,
         # lane-packed, the driver's (few) lanes x 16 shards on its minor
         # axis
         assert loop.fused.num_lanes < 128
-        minor = loop.fused.et.pads.max_split * loop.fused.num_lanes
+        pads = loop.fused.et.pads
+        minor = pads.max_split * loop.fused.num_lanes
         # ... and pricing and the channel / server checks of the
         # program's `eval_cfg` index no dep, nor its placement scan a
         # cell, a server or a sub-op
@@ -932,6 +943,11 @@ def test_build_run_leaves_each_startup_span_once(fused_dataset, tmp_path,
             "sim.lookahead.channel_widths": [8],
             # a lane-packed first stage: both endpoint primitives contract
             "sim.lookahead.endpoint_onehot_elems": 0,
+            # ... and one width has no form by server: `nominate`
+            # compares 2 * B*S*W*L*S + 2 * B*W*W*L*S elements with the
+            # worker iota a trip
+            "sim.lookahead.channel_onehot_elems": 2 * pads.n_blocks * (
+                pads.max_split * 8 * minor + 8 * 8 * minor),
             "sim.price.dep_indexed_ops": 0,
             "sim.allocate.indexed_ops": 0,
             "env.mask.rows_offered": offered,
