@@ -324,12 +324,24 @@ def layer_metrics(cell: Cell, ctx: Dict[str, Any]) -> Dict[str, dict]:
 # ----------------------------------------------------------- result line
 def result_line(correct: bool, attempted: int, failed: int,
                 metrics: Dict[str, dict], device: Dict[str, Any],
-                breakdown: Optional[dict] = None) -> str:
+                breakdown: Optional[dict] = None,
+                compared: Optional[Dict[str, dict]] = None) -> str:
+    """``compared``: what decided ``correct``, each number beside its
+    limit, under a key of its own that comes last."""
     line = {"correct": bool(correct), "attempted": int(attempted),
             "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    if compared is not None:
+        line["compared"] = compared
     return json.dumps(line)
+
+
+def compared_checks(checks: Dict[str, bool]) -> Dict[str, dict]:
+    """Yes-or-no checks as numbers beside their limit: 1 where the
+    check failed, and none may."""
+    return {name: {"value": int(not ok), "limit": 0}
+            for name, ok in checks.items()}
 
 
 def note(tag: str, payload: Any) -> None:

@@ -85,11 +85,12 @@ def instrument(loop, rec: harness.Recorder) -> None:
 def run_epoch(loop, rec: harness.Recorder) -> Dict[str, Any]:
     import jax
 
-    t0 = time.perf_counter()
+    t0, cpu0 = time.perf_counter(), time.process_time()
     with rec.span("epoch"):
         results = loop.run()
         jax.block_until_ready(loop.state)
     return {"start": t0, "seconds": time.perf_counter() - t0,
+            "cpu_s": time.process_time() - cpu0,
             "env_steps": int(results["env_steps_this_iter"]),
             "loss": float(results["learner"]["total_loss"])}
 
@@ -156,17 +157,66 @@ def steps_per_s(epochs: List[dict], statistic: str,
 #: an epoch loop that keeps raising is broken, not slow
 MAX_RAISED_EPOCHS = 3
 
+#: the window goes on past ``--seconds`` for a measured set of epochs
+#: that is not complete yet, to at most this many times ``--seconds``
+SET_OVERRUN = 1.5
+
+#: an epoch of the window is LONG when it took more than this many
+#: times the window's median epoch and more than this many seconds
+LONG_TIMES_MEDIAN = 3.0
+LONG_MIN_S = 1.0
+
+
+def measured_set(epochs: List[dict], measure_epochs) -> Optional[List[dict]]:
+    """The epochs ``[k0, k1)`` of the window, by index from its first;
+    None while the window has not run them all."""
+    k0, k1 = (int(k) for k in measure_epochs)
+    if not 0 <= k0 < k1:
+        raise ValueError(f"measure_epochs {measure_epochs!r}: want "
+                         f"0 <= k0 < k1")
+    return epochs[k0:k1] if len(epochs) >= k1 else None
+
+
+def long_epochs(epochs: List[dict], window: Tuple[float, float]
+                ) -> List[int]:
+    """Indices of the epochs that start inside ``window`` = (start,
+    seconds) and took over ``LONG_TIMES_MEDIAN`` x the median of those
+    epochs AND over ``LONG_MIN_S`` seconds. With the training seed
+    pinned the program's own long epochs (a cold memo: most lanes run
+    their lookahead) stand at the same indices in every run of a tree;
+    one at another index is the host's: it held the loop while the
+    device had nothing new to do."""
+    start, seconds = window
+    inside = [i for i, e in enumerate(epochs)
+              if e["start"] < start + seconds]
+    if not inside:
+        return []
+    median = statistics.median(epochs[i]["seconds"] for i in inside)
+    return [i for i in inside
+            if epochs[i]["seconds"] > max(LONG_TIMES_MEDIAN * median,
+                                          LONG_MIN_S)]
+
 
 def measure_window(loop, rec: harness.Recorder, seconds: float,
-                   trace_epochs: int):
+                   trace_epochs: int, measure_epochs=None):
     """Run epochs for as long as one STARTS inside ``seconds``; the
-    profiler (when on) covers the first ``trace_epochs`` of them.
+    profiler (when on) covers the first ``trace_epochs`` of them. Where
+    the mix names a set of ``measure_epochs`` that is not complete by
+    then, epochs go on until it is, to at most ``SET_OVERRUN`` x
+    ``seconds``: what is read over the set is read over the SAME epochs
+    in every run, or not at all.
     Returns (epochs, epochs that raised, start of the window)."""
     epochs: List[dict] = []
     raised = 0
+    need = int(measure_epochs[1]) if measure_epochs else 0
     t_window = time.perf_counter()
-    while (time.perf_counter() - t_window < seconds
-           and raised < MAX_RAISED_EPOCHS):
+
+    def goes_on() -> bool:
+        elapsed = time.perf_counter() - t_window
+        return elapsed < seconds or (len(epochs) < need
+                                     and elapsed < SET_OVERRUN * seconds)
+
+    while goes_on() and raised < MAX_RAISED_EPOCHS:
         try:
             epochs.append(run_epoch(loop, rec))
         except Exception as exc:  # an epoch that raised has failed
@@ -176,6 +226,36 @@ def measure_window(loop, rec: harness.Recorder, seconds: float,
             rec.stop_trace()
     rec.stop_trace()
     return epochs, raised, t_window
+
+
+def window_facts(epochs: List[dict], t_window: float, seconds: float,
+                 measure_epochs) -> Dict[str, Any]:
+    """What the window held, for the ``[bench] epochs`` note and the
+    per-layer readers: the long epochs (an epoch with no CPU time
+    waited, for the device or for the core) and what the measured set
+    of epochs read, began and ended at."""
+    window = (t_window, seconds)
+    facts: Dict[str, Any] = {
+        "in_window": sum(e["start"] < t_window + seconds for e in epochs),
+        "window_share": steps_per_s(epochs, "window_share", window),
+        "ratio_steps_per_s": steps_per_s(epochs, "ratio"),
+        "median_epoch_rate": steps_per_s(epochs, "median_epoch_rate"),
+        "long_epochs": [
+            {"index": i, "seconds": epochs[i]["seconds"],
+             "cpu_s": epochs[i]["cpu_s"],
+             "began_s": epochs[i]["start"] - t_window}
+            for i in long_epochs(epochs, window)]}
+    if measure_epochs:
+        chosen = measured_set(epochs, measure_epochs)
+        facts["measure_epochs"] = [int(k) for k in measure_epochs]
+        facts["set"] = None if chosen is None else {
+            "median_epoch_rate": steps_per_s(chosen, "median_epoch_rate"),
+            "ratio_steps_per_s": steps_per_s(chosen, "ratio"),
+            "wall_s": sum(e["seconds"] for e in chosen),
+            "began_s": chosen[0]["start"] - t_window,
+            "ended_s": (chosen[-1]["start"] + chosen[-1]["seconds"]
+                        - t_window)}
+    return facts
 
 
 def output_checks(cell: harness.Cell, cfg: dict, loop, before,
@@ -250,7 +330,8 @@ def run(cell: harness.Cell, args, rec: harness.Recorder,
             memo_before = memo_counts(loop)
             setup_s = time.perf_counter() - t_start
             epochs, raised, t_window = measure_window(
-                loop, rec, args.seconds, int(traffic["trace_epochs"]))
+                loop, rec, args.seconds, int(traffic["trace_epochs"]),
+                traffic.get("measure_epochs"))
             window_s = time.perf_counter() - t_window
             compile_window = harness.CompileMeter.delta(meter.totals(),
                                                         compile_setup)
@@ -289,23 +370,35 @@ def run(cell: harness.Cell, args, rec: harness.Recorder,
     harness.note("checks", checks)
     if not epochs:
         raise SystemExit("no epoch finished inside the window")
+    facts = window_facts(epochs, t_window, args.seconds,
+                         traffic.get("measure_epochs"))
     harness.note("epochs", {
         "seconds": [e["seconds"] for e in epochs],
+        "cpu_s": [e["cpu_s"] for e in epochs],
         "loss": [e["loss"] for e in epochs],
         "window_s": window_s, "overrun_s": window_s - args.seconds,
         "memo_before": memo_before, "memo_after": memo_after,
-        "median_epoch_rate": steps_per_s(epochs, "median_epoch_rate"),
-        "ratio_steps_per_s": steps_per_s(epochs, "ratio")})
+        **facts})
+    if args.trace and facts.get("measure_epochs") and facts["set"] is None:
+        # the traced run is where the set is read: no reading over
+        # fewer or other epochs takes its place
+        raise SystemExit(
+            f"measure_epochs {facts['measure_epochs']}: the window ran "
+            f"{len(epochs)} epochs in {window_s:.1f} s (it goes on to "
+            f"{SET_OVERRUN} x --seconds for the set), fewer than "
+            f"k1 = {facts['measure_epochs'][1]}: nothing to read")
 
     return {
         "correct": all(checks.values()),
         "attempted": len(epochs) + raised,
         "failed": raised + sum(not np.isfinite(e["loss"]) for e in epochs),
+        "compared": harness.compared_checks(checks),
         "end_to_end": {
             STEPS_METRIC: steps_per_s(epochs, traffic["statistic"],
                                       (t_window, args.seconds)),
             "setup_s": setup_s},
         "ctx": {"spans": {"bench": rec.spans, "program": program_spans},
+                "window": facts,
                 "counters": {
                     "program.scratch_bytes": float(scratch_bytes),
                     **{k: memo_after[k] - memo_before.get(k, 0.0)

@@ -97,8 +97,13 @@ def main(argv=None) -> int:
     missing = [k for k, v in metrics.items() if v["value"] is None]
     if missing:
         raise SystemExit(f"no value for {missing}")
+    compared = out.get("compared")
+    for name, pair in (compared or {}).items():
+        print(f"[compared] {name} {pair['value']} limit {pair['limit']}",
+              file=sys.stderr, flush=True)
     print(harness.result_line(out["correct"], out["attempted"],
-                              out["failed"], metrics, device, breakdown),
+                              out["failed"], metrics, device, breakdown,
+                              compared),
           flush=True)
     return 0
 

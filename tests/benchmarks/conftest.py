@@ -1,26 +1,10 @@
-"""``test_bench_trinity.py`` pins its cell as the LAST entry of
-``BENCHMARK.json`` (``workloads[-1]``, ``configs[-1]``, ``per_layer[-1]``
-and the last name of every list it joined), which no later PR that
-appends a cell can keep, and a PR that adds a cell may edit no file the
-benchmark already has. Its tests read the module's ``BENCH``: they are
-handed the benchmark as their own PR left it — every later cell, its
-configuration, its metrics and its name in the ``workloads`` lists taken
-away — so they go on checking what they checked (the older entries a
-prefix, in their order), and ``test_bench_sala.py`` checks that what came
-after was appended behind them. A `benchmark` PR that rewrites those
-two tests in ``test_bench_mimo.py``'s form (which leaves room) drops
-this file."""
-import pytest
-
-from bench_history import benchmark_as_of
-
-#: test modules that pin their cell as the benchmark's last
-PINNED_LAST = ("test_bench_trinity",)
-
-
-@pytest.fixture(autouse=True)
-def _benchmark_as_the_cells_pr_left_it(request, monkeypatch):
-    module = request.module
-    if module.__name__ in PINNED_LAST:
-        monkeypatch.setattr(module, "BENCH",
-                            benchmark_as_of(module.BENCH, module.CELL))
+"""No fixture lives here any more. Until PR 47 this file handed
+``test_bench_trinity.py`` the benchmark as its own PR left it, because
+that module pinned its cell as the benchmark's LAST entry
+(``workloads[-1]``, ``configs[-1]``, ``per_layer[-1]``); its pins now
+find their entries by name and hold what their PR appended as a prefix
+(``test_bench_mimo.py``'s form), and ``test_bench_room.py`` drives every
+such pin with a cell appended behind the last. The file itself stays
+only because documents this PR may not edit name its path
+(``tests/test_docs.py`` checks that they name files that exist); a PR
+that corrects them deletes it (PERF.md, Open questions)."""

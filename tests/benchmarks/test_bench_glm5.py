@@ -41,6 +41,10 @@ def test_cell_is_32_lanes_of_the_glm5_queue():
     assert mix["fidelity"]["rtol"] == 1e-4
     assert (mix["warmup_epochs"], mix["statistic"], mix["trace_epochs"],
             mix["train_seed"]) == (1, "window_share", 1, 0)
+    # the steadier reading beside it: a fixed set of the window's epochs
+    k0, k1 = mix["measure_epochs"]
+    assert 0 < k0 < k1 and k1 - k0 >= 50 and mix["why_measure_epochs"]
+    assert "program_spans" not in mix
     assert cell.config["composed_from"]["overrides"] == [
         "env_config=env_glm5_32"]
     assert cell.config["train_batch_size"] == lanes
@@ -104,14 +108,16 @@ def test_new_metrics_are_read_in_the_old_cells_too(metric):
 
 
 def test_old_cells_report_what_they_reported():
-    """This PR appends; the parent's per-layer list of every old cell
-    is a prefix of today's."""
+    """This PR appended: the parent's per-layer list of every old cell
+    (31 names) leads today's, the three new follow, and what later PRs
+    listed comes behind: nothing is pinned as the last."""
     new = set(NEW_METRICS)
-    for cell in OLD_CELLS:
+    for cell in (*OLD_CELLS, CELL):
         names = [m["name"] for m in harness.load_cell(cell).per_layer]
-        assert names[-3:] == list(NEW_METRICS)
-        assert len(names) == 34 and not new & set(names[:-3])
-    assert [m["name"] for m in harness.load_cell(CELL).per_layer] == names
+        assert names[31:34] == list(NEW_METRICS)
+        assert not new & set(names[:31]) and len(set(names)) == len(names)
+        assert names[:34] == [
+            m["name"] for m in harness.load_cell(CELL).per_layer][:34]
 
 
 def test_composed_tree_is_what_the_configuration_file_expects(tmp_path):

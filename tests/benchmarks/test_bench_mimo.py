@@ -57,6 +57,10 @@ def test_cell_is_80_lanes_of_the_mimo_queue():
                                "decisions": 48, "rtol": 1e-4}
     assert (mix["warmup_epochs"], mix["statistic"], mix["trace_epochs"],
             mix["train_seed"]) == (1, "window_share", 1, 0)
+    # the steadier reading beside it: a fixed set of the window's epochs
+    k0, k1 = mix["measure_epochs"]
+    assert 0 < k0 < k1 and k1 - k0 >= 50 and mix["why_measure_epochs"]
+    assert "program_spans" not in mix
     assert cell.config["composed_from"]["overrides"] == [
         "env_config=env_mimo_32"]
     assert cell.config["train_batch_size"] == lanes

@@ -35,7 +35,18 @@ PARTS = {"decisions_offered_ragged": "env.decisions.offered_ragged",
          "decisions_accepted_ragged": "env.decisions.accepted_ragged"}
 ARCH_FILE = "ddls_tpu/graphs/arch_configs/minicpm_sala.json"
 BENCH = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
-PARENT = benchmark_as_of(BENCH, PARENT_LAST)
+
+
+def parent_of(bench: dict) -> dict:
+    """The parent's benchmark: the later cells taken away, and the
+    metrics that stand behind this PR's own two (PRs only append)."""
+    parent = benchmark_as_of(bench, PARENT_LAST)
+    parent["per_layer"] = parent["per_layer"][
+        :[m["name"] for m in bench["per_layer"]].index(NEW_METRICS[0])]
+    return parent
+
+
+PARENT = parent_of(BENCH)
 
 
 def _entry(kind, name, bench=BENCH):
@@ -63,10 +74,17 @@ def test_cell_is_16_lanes_of_the_sala_queue():
     assert mix["fidelity"]["why_decisions"] and mix["fidelity"]["why_rtol"]
     assert (mix["warmup_epochs"], mix["statistic"], mix["trace_epochs"],
             mix["train_seed"]) == (1, "window_share", 1, 0)
-    # but for the lanes and the words, the mix is trinity's
+    # the steadier reading beside it: a fixed set of the window's epochs
+    k0, k1 = mix["measure_epochs"]
+    assert 0 < k0 < k1 and k1 - k0 >= 50 and mix["why_measure_epochs"]
+    assert "program_spans" not in mix
+    # but for the lanes, the words and the measured set of epochs (a
+    # window holds as many as its own epochs are long), the mix is
+    # trinity's
     other = harness.load_cell(PARENT_LAST).traffic
     same = set(mix) - {"name", "what", "why_this_shape", "fidelity",
-                       "overrides", "epoch"}
+                       "overrides", "epoch", "measure_epochs",
+                       "why_measure_epochs"}
     assert {k: mix[k] for k in same} == {k: other[k] for k in same}
     assert cell.config["composed_from"]["overrides"] == [
         "env_config=env_sala_32"]
@@ -152,10 +170,15 @@ def test_catalog_numbers_sit_at_the_top_level_under_the_same_keys():
 
 
 def test_cell_reports_every_metric_trinitys_does_and_the_two_new():
+    """Trinity's 41 of the parent's benchmark lead, the two new follow,
+    and whatever a later PR listed for both cells comes behind: nothing
+    is pinned as the last."""
     names = [m["name"] for m in harness.load_cell(CELL).per_layer]
     trinity = [m["name"] for m in harness.load_cell(PARENT_LAST).per_layer]
-    assert len(trinity) == 41
-    assert names == trinity + list(NEW_METRICS)
+    assert trinity[:41] == [m["name"] for m in PARENT["per_layer"]
+                            if PARENT_LAST in m["workloads"]]
+    assert names[:43] == trinity[:41] + list(NEW_METRICS)
+    assert set(trinity) <= set(names) and len(set(names)) == len(names)
 
 
 @pytest.mark.parametrize("metric", [
@@ -248,11 +271,13 @@ def test_this_pr_appended_and_old_cells_report_what_they_reported():
     for entry in (*PARENT["configs"], *PARENT["workloads"]):
         kind = "configs" if "file" in entry else "workloads"
         assert _entry(kind, entry["name"]) == entry
-    # so every old cell reports what it reported
+    # so every old cell reports what it reported, and then what later
+    # PRs listed for it
     for cell in OLD_CELLS:
         names = [m["name"] for m in harness.load_cell(cell).per_layer]
-        assert names == [m["name"] for m in PARENT["per_layer"]
-                         if cell in m["workloads"]]
+        reported = [m["name"] for m in PARENT["per_layer"]
+                    if cell in m["workloads"]]
+        assert names[:len(reported)] == reported
         assert not set(NEW_METRICS) & set(names)
 
 

@@ -150,6 +150,66 @@ def test_every_cell_resolves_to_files_that_exist(cell_entry):
     assert set(config_entry["reduced"]) == set(cell.config["reduced"])
 
 
+TRAIN_CELLS = [w["name"] for w in BENCH["workloads"]
+               if harness.load_cell(w["name"]).path == "train"]
+
+
+@pytest.mark.parametrize("cell_name", TRAIN_CELLS)
+def test_training_cell_names_the_set_of_epochs_its_steadier_reading_reads(
+        cell_name):
+    """`train_env_steps_per_s` stays what it was (`window_share`: all
+    the work inside the window over all of its time); beside it every
+    training cell's mix names a fixed set of at least 50 of the
+    window's epochs, behind the cold head, for `warm_epoch_rate_p50`,
+    says what it was set from, and lists both new metrics. Nothing
+    reads a `program_spans` key, so no mix carries one."""
+    cell = harness.load_cell(cell_name)
+    mix = cell.traffic
+    assert mix["statistic"] == "window_share" and mix["warmup_epochs"] == 1
+    k0, k1 = mix["measure_epochs"]
+    assert isinstance(k0, int) and isinstance(k1, int)
+    assert 0 < k0 < k1 and k1 - k0 >= 50
+    assert "my chip runs, PR 47" in mix["why_measure_epochs"]
+    assert "program_spans" not in mix
+    reported = {m["name"] for m in cell.per_layer}
+    assert {"warm_epoch_rate_p50", "warm_set_env_steps_per_s",
+            "long_epochs_in_window"} <= reported
+
+
+@pytest.mark.parametrize("name, fact, better", [
+    ("warm_epoch_rate_p50", "set.median_epoch_rate", "higher"),
+    ("warm_set_env_steps_per_s", "set.ratio_steps_per_s", "higher"),
+    ("long_epochs_in_window", "long_epochs", "lower")])
+def test_window_fact_metrics_are_data_beside_a_reader_that_exists(
+        name, fact, better):
+    entry, = [m for m in BENCH["per_layer"] if m["name"] == name]
+    spec = harness.read_json(os.path.join(
+        harness.BENCH_DIR, "layer_metrics", name + ".json"))
+    assert spec["source"]["kind"] == "window_fact"
+    assert spec["source"]["fact"] == fact
+    assert (entry["layer"], entry["moves"], entry["source"],
+            entry["better"]) == ("epoch loop", "train_env_steps_per_s",
+                                 "host_clock", better)
+    assert entry["workloads"][:len(TRAIN_CELLS)] == TRAIN_CELLS
+    reader = importlib.import_module("benchmarks.sources.window_fact")
+    window = {"set": {"median_epoch_rate": 77.5,
+                      "ratio_steps_per_s": 73.0},
+              "long_epochs": [{"index": 3}, {"index": 9}]}
+    assert reader.read(spec["source"], {"window": window}) \
+        == {"warm_epoch_rate_p50": 77.5, "warm_set_env_steps_per_s": 73.0,
+            "long_epochs_in_window": 2}[name]
+    assert reader.read(spec["source"], {}) is None
+    assert reader.read(spec["source"], {"window": {"set": None}}) is None
+
+
+@pytest.mark.parametrize("mix", sorted(
+    name[:-len(".json")] for name in os.listdir(
+        os.path.join(harness.BENCH_DIR, "traffic"))))
+def test_no_mix_carries_a_key_nothing_reads(mix):
+    assert "program_spans" not in harness.read_json(os.path.join(
+        harness.BENCH_DIR, "traffic", mix + ".json"))
+
+
 @pytest.mark.parametrize("config, traffic", [
     ("pacml_ramp32_dev", "train_fused_8x32"),
     ("pacml_ramp32_dev", "train_host_8x32"),

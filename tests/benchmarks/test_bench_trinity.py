@@ -14,7 +14,7 @@ import pytest
 import bench_tiny
 from benchmarks import harness
 from benchmarks.paths import train
-from test_bench_mimo import _add_cell
+from test_bench_mimo import PARENT_PER_LAYER, _add_cell
 from test_bench_run import (_argv, _check_line, _result,  # noqa: F401
                             restore_process_state, tiny_tree)
 
@@ -56,6 +56,10 @@ def test_cell_is_16_lanes_of_the_trinity_queue():
     assert mix["fidelity"]["why_decisions"] and mix["fidelity"]["why_rtol"]
     assert (mix["warmup_epochs"], mix["statistic"], mix["trace_epochs"],
             mix["train_seed"]) == (1, "window_share", 1, 0)
+    # the steadier reading beside it: a fixed set of the window's epochs
+    k0, k1 = mix["measure_epochs"]
+    assert 0 < k0 < k1 and k1 - k0 >= 50 and mix["why_measure_epochs"]
+    assert "program_spans" not in mix
     assert cell.config["composed_from"]["overrides"] == [
         "env_config=env_trinity_32"]
     assert cell.config["train_batch_size"] == lanes
@@ -132,11 +136,13 @@ def test_cell_reports_the_metric(metric):
 def test_new_metric_is_data_of_reader_kinds_that_exist():
     """A ratio of two telemetry counters: no benchmark code is added,
     and the count of drained traces cancels. It is in BENCHMARK.json for
-    the new cell alone, which reports the metric moved."""
+    the new cell first (no older cell lists it; a later one may join
+    behind), which reports the metric moved."""
     spec = harness.read_json(os.path.join(
         harness.BENCH_DIR, "layer_metrics", NEW_METRIC + ".json"))
     entry = _entry("per_layer", NEW_METRIC)
-    assert entry["workloads"] == [CELL] and entry["better"] == "lower"
+    assert entry["workloads"][0] == CELL and entry["better"] == "lower"
+    assert not set(OLD_CELLS) & set(entry["workloads"])
     assert (entry["layer"], entry["unit"], entry["moves"]) \
         == (spec["layer"], spec["unit"], spec["moves"]) \
         == ("device collection", "%", "train_env_steps_per_s")
@@ -154,33 +160,42 @@ def test_new_metric_is_data_of_reader_kinds_that_exist():
 
 
 def test_old_cells_report_what_they_reported():
-    """This PR appends: every old cell's per-layer list is what the
-    parent's was (the new metric is the new cell's alone), the new
-    cell's holds all of `mimo`'s and the new metric, and its name is
-    the LAST on each list it joined, the older names a prefix in their
-    order. Entries are found by name, never by position."""
+    """This PR appended, and leaves room for the next to: every old
+    cell's per-layer list starts with what the parent's was (`mimo`'s 34
+    shared names, then its own six), the new cell's holds all of
+    `mimo`'s and then the new metric, and its name joined each list
+    `mimo`'s is on, behind it. Entries are found by name, never by
+    position, and nothing is pinned as the benchmark's last."""
     mimo = [m["name"] for m in
             harness.load_cell("mimo_ramp32.train_fused").per_layer]
-    assert len(mimo) == 34 + len(MIMO_ONLY) and NEW_METRIC not in mimo
+    assert mimo[:34] == list(PARENT_PER_LAYER)
+    assert mimo[34:40] == list(MIMO_ONLY) and NEW_METRIC not in mimo
     for cell in OLD_CELLS[:4]:
         names = [m["name"] for m in harness.load_cell(cell).per_layer]
-        assert names == mimo[:34]
+        assert names[:34] == mimo[:34]
+        assert not {NEW_METRIC, *MIMO_ONLY} & set(names)
     names = [m["name"] for m in harness.load_cell(CELL).per_layer]
-    assert names == mimo + [NEW_METRIC]
+    assert names[:41] == mimo[:40] + [NEW_METRIC]
+    assert set(mimo) <= set(names) and len(set(names)) == len(names)
     joined = [_entry("end_to_end", "train_env_steps_per_s"),
-              *(_entry("per_layer", n) for n in mimo)]
+              *(_entry("per_layer", n) for n in mimo[:40])]
     assert len(joined) == 1 + 34 + 6
     for metric in joined:
-        old = metric["workloads"][:-1]
-        assert metric["workloads"][-1] == CELL
+        cells = metric["workloads"]
+        old = cells[:cells.index(CELL)]
         assert old == list(OLD_CELLS) or old == list(OLD_CELLS[-1:])
-    assert [w["name"] for w in BENCH["workloads"]] == [*OLD_CELLS, CELL]
-    assert BENCH["configs"][-1]["name"] == "trinity_mini_whole_ramp32"
-    assert BENCH["per_layer"][-1]["name"] == NEW_METRIC
+    assert _entry("per_layer", NEW_METRIC)["workloads"][0] == CELL
+    assert [w["name"] for w in BENCH["workloads"]][:6] \
+        == [*OLD_CELLS, CELL]
+    listed = [m["name"] for m in BENCH["per_layer"]]
+    assert listed.index(NEW_METRIC) == 1 + max(listed.index(n)
+                                               for n in mimo[:40])
     assert _entry("workloads", CELL)["config"] \
         == "trinity_mini_whole_ramp32"
     assert _entry("configs", "trinity_mini_whole_ramp32")["file"] \
         == "benchmarks/configs/trinity_mini_whole_ramp32.json"
+    assert [c["name"] for c in BENCH["configs"]].index(
+        "trinity_mini_whole_ramp32") == 5
 
 
 def test_composed_tree_is_what_the_configuration_file_expects(tmp_path):
