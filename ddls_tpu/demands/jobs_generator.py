@@ -42,8 +42,37 @@ from ddls_tpu.telemetry import startup
 #: start-up gauges of an ``architecture`` job source that the fused loop
 #: counts once per drained epoch trace: the sum over the bank's models of
 #: ``graphs.arch.quadratic_time_share.<model>`` and the models summed
-#: over, so that a window's counters give the bank's mean as a ratio
-BANK_GAUGES = ("graphs.arch.quadratic_time_shares", "graphs.arch.models")
+#: over, so that a window's counters give the bank's mean as a ratio;
+#: then the same sums of ``branch_time_share`` and ``zero_routed_share``
+BANK_GAUGES = ("graphs.arch.quadratic_time_shares", "graphs.arch.models",
+               "graphs.arch.branch_time_shares",
+               "graphs.arch.zero_routed_shares")
+
+
+def branch_time_share(graph) -> float:
+    """Share of a degree-1 forward pass in ops OFF the forward graph's
+    longest path by compute time: what runs beside the chain (0 on a
+    chain whose only extra edges are shortcuts). From the graph's
+    edges and times alone, no op name."""
+    ops = graph.forward_op_ids()
+    forward = set(ops)
+    # the longest path ending at each forward op, and the parent it
+    # comes through
+    reach, before = {}, {}
+    for op in graph.topo_order():
+        if op not in forward:
+            continue
+        best = max((p for p in graph.parents(op) if p in reach),
+                   key=reach.get, default=None)
+        before[op] = best
+        reach[op] = graph.compute_cost(op) + (
+            0.0 if best is None else reach[best])
+    end = max(ops, key=reach.get)
+    while end is not None:
+        forward.discard(end)
+        end = before[end]
+    return sum(graph.compute_cost(op) for op in forward) \
+        / sum(graph.compute_cost(op) for op in ops)
 
 
 class JobSampler:
@@ -241,6 +270,9 @@ class JobsGenerator:
                   for p in file_paths]
         if architecture is not None:
             shares = []
+            # the routed pairs that cost no expert FLOPs: the config's
+            zero_experts = arch.zero_experts(family[0])
+            zero_routed = arch.zero_routed_share(family[0])
             for g in graphs:
                 model = g.meta["model"]
                 startup.set_gauge(f"graphs.arch.forward_ops.{model}",
@@ -266,6 +298,8 @@ class JobsGenerator:
                         ("layers_window", "WindowAttnCore"),
                         ("layers_linear", "LinearAttnCore"),
                         ("layers_block_sparse", "BlockSparseAttnCore"),
+                        ("layers_latent", "LatentAttnCore"),
+                        ("shortcut_branches", "ShortcutCombineResidual"),
                         ("shared_expert_layers", "SharedExpert")):
                     startup.set_gauge(
                         f"graphs.arch.{gauge}.{model}",
@@ -281,9 +315,17 @@ class JobsGenerator:
                     f"graphs.arch.quadratic_time_share.{model}", share)
                 startup.set_gauge(f"graphs.arch.linear_time_share.{model}",
                                   time_share(("LinearAttnCore",)))
-                shares.append(share)
-            # the bank's mean share is the first over the second
-            for name, value in zip(BANK_GAUGES, (sum(shares), len(shares))):
+                # what runs beside the graph's longest path
+                beside = branch_time_share(g)
+                startup.set_gauge(f"graphs.arch.branch_time_share.{model}",
+                                  beside)
+                startup.set_gauge(f"graphs.arch.zero_experts.{model}",
+                                  zero_experts)
+                startup.set_gauge(f"graphs.arch.zero_routed_share.{model}",
+                                  zero_routed)
+                shares.append((share, 1, beside, zero_routed))
+            # the bank's mean of a share is its sum over the models
+            for name, value in zip(BANK_GAUGES, map(sum, zip(*shares))):
                 startup.set_gauge(name, value)
         return graphs, dataset_id
 

@@ -7,8 +7,11 @@ mirror -> ``Job`` stays the one path every job takes. What a layer is
 made of is chosen per LAYER from the config's KEYS, never from its name:
 
 * attention — ``kv_lora_rank`` present: multi-head latent attention
-  (low-rank q and kv paths), and with ``index_topk`` a learned-sparse
-  core behind an indexer (:func:`_latent_sparse_attention`); layer i of
+  (low-rank q and kv paths, one function: :func:`_latent_attention`;
+  each normed latent scaled where ``mla_scale_q_lora`` /
+  ``mla_scale_kv_lora`` say so), with ``index_topk`` a learned-sparse
+  core behind an indexer, without it a full causal core over the latent
+  keys (``LatentAttnCore``); layer i of
   a ``mixer_types`` list with ``"lightning-attn"``: linear attention
   (:func:`_linear_attention`: a d x d state a head scanned in chunks of
   ``lightning_chunk_size``, cost linear in the sequence, at the
@@ -44,7 +47,23 @@ made of is chosen per LAYER from the config's KEYS, never from its name:
   them when the key gives a number; ``scoring_func`` / ``score_func:
   sigmoid`` picks the bias-corrected sigmoid router (under the second
   name its selected weights are normalised where ``route_norm`` and
-  scaled where ``route_scale`` say so), otherwise softmax top-k;
+  scaled where ``route_scale`` say so), otherwise softmax top-k (its
+  weights scaled where ``routed_scaling_factor`` is stated, its
+  selection biased where the ``modeling`` block states
+  ``e_score_correction_bias``); ``zero_expert_num`` zero-compute
+  experts widen the router to E + Z outputs: the expert group sees
+  tokens x k x held / (E + Z) pairs and the combine adds w . x for the
+  tokens x k x Z / (E + Z) identity pairs, whole on every pod;
+* ``shortcut_sub_blocks`` (a ``modeling`` block's): a layer is that
+  many attention + dense-FFN sub-blocks and ONE expert block that reads
+  the first sub-block's normed state and joins the stream after the
+  last one's FFN (``ShortcutCombineResidual``) — Router -> Experts run
+  BESIDE every op between: the graph is not one chain;
+* depth, widths and experts a token are read under either family of
+  names (``num_hidden_layers`` / ``num_layers``, ``intermediate_size`` /
+  ``ffn_hidden_size``, ``moe_intermediate_size`` /
+  ``expert_ffn_hidden_size``, ``num_experts_per_tok`` / ``moe_topk``);
+  a config that states both and disagrees is refused;
 * ``num_nextn_predict_layers``: that many multi-token-prediction
   modules (the DeepSeek-V3 form) after the last layer;
 * muP scalars, elementwise where a key states them: ``scale_emb`` 1 an
@@ -52,7 +71,7 @@ made of is chosen per LAYER from the config's KEYS, never from its name:
   each residual branch, ``dim_model_base`` (hidden_size / it) 1 an
   element the head reads.
 
-Five families are built today: OLMoE (full attention, softmax router:
+Six families are built today: OLMoE (full attention, softmax router:
 embedding, L x [InputNorm, QKVProj, AttnCore, OutProjResidual,
 PostAttnNorm, Router, Experts, CombineResidual], FinalNorm, LMHeadLoss),
 ``glm_moe_dsa``, ``mimo_v2_flash`` (OLMoE's 8-op layer with
@@ -60,8 +79,12 @@ WindowAttnCore on the window layers and DenseMLPResidual on the dense
 one) and ``afmoe`` (Trinity: the same layer with a SharedExpert, 9 ops) and
 ``minicpm_sala`` (dense: [InputNorm, QKVProj, GateProj, LinearAttnCore
 or AttnCore or the three sparse ops, OutProjResidual, PostAttnNorm,
-DenseMLPResidual], 7 or 9 ops; equations beside each op below, ``x``
-the normed hidden state).
+DenseMLPResidual], 7 or 9 ops) and ``longcat_flash`` (a double layer of
+21 ops: 2 x [InputNorm, QAProj, QBProj, KVAProj, KVBProj,
+LatentAttnCore, OutProjResidual, PostAttnNorm, DenseMLPResidual] with
+Router and Experts behind the first PostAttnNorm and
+ShortcutCombineResidual at the end; equations beside each op below,
+``x`` the normed hidden state).
 Ops are at one granularity in all: each norm, each projection, the
 indexer's projections, index score + top-k, key compression, block
 score + top-k, the gate's projection, the attention core,
@@ -238,11 +261,78 @@ def linear_layers(config: dict) -> Optional[List[bool]]:
     return _layer_kinds(config, "mixer_types", MIXER_TYPES)
 
 
+_REQUIRED = object()
+
+
+def _stated(config: dict, names: Sequence[str], default=_REQUIRED):
+    """The value a config states under one of ``names`` (one name a
+    family of public configs); ``default`` where it states none, a
+    ``KeyError`` where none is given. A config that states two and
+    disagrees is refused."""
+    values = {name: config[name] for name in names
+              if config.get(name) is not None}
+    if len(set(values.values())) > 1:
+        raise ValueError(f"config states {values}: they disagree")
+    if not values and default is _REQUIRED:
+        raise KeyError(" / ".join(names))
+    return next(iter(values.values()), default)
+
+
 def routed_experts(config: dict) -> int:
     """Routed experts an expert layer has; 0 for a config with no expert
     key (a dense model: every layer's feed-forward is dense)."""
-    return int(config.get("n_routed_experts")
-               or config.get("num_experts") or 0)
+    return int(_stated(config, ("n_routed_experts", "num_experts"), 0))
+
+
+def stack_depth(config: dict) -> int:
+    """Layers of the published stack (``num_layers`` counts a
+    ``shortcut_sub_blocks`` layer once, as its config does)."""
+    return int(_stated(config, ("num_hidden_layers", "num_layers")))
+
+
+def dense_width(config: dict) -> int:
+    """Intermediate size of a dense SwiGLU."""
+    return int(_stated(config, ("intermediate_size", "ffn_hidden_size")))
+
+
+def expert_width(config: dict) -> int:
+    """Intermediate size of one routed expert (a config that states
+    none: the dense width, OLMoE's)."""
+    return int(_stated(config, ("moe_intermediate_size",
+                                "expert_ffn_hidden_size"), None)
+               or dense_width(config))
+
+
+def experts_per_token(config: dict) -> int:
+    """Router outputs selected a token; 0 where the config states none
+    (a dense model)."""
+    return int(_stated(config, ("num_experts_per_tok", "moe_topk"), 0))
+
+
+#: ``zero_expert_type`` values the builder knows: an ``identity`` expert
+#: returns its input (no FLOPs but the combine's weighted add)
+ZERO_EXPERT_TYPES = ("identity",)
+
+
+def zero_experts(config: dict) -> int:
+    """Zero-compute experts beside the routed ones (``zero_expert_num``;
+    0 where the config states none): the router is that much wider and
+    a pair routed to one costs no expert FLOPs, no parameter and no
+    dispatch. A ``zero_expert_type`` the builder does not know is
+    refused."""
+    zeros = int(config.get("zero_expert_num") or 0)
+    kind = config.get("zero_expert_type")
+    if zeros and kind not in ZERO_EXPERT_TYPES:
+        raise ValueError(f"zero_expert_type: unknown {kind!r} (known: "
+                         f"{list(ZERO_EXPERT_TYPES)})")
+    return zeros
+
+
+def zero_routed_share(config: dict) -> float:
+    """Share of a token's routed pairs that go to a zero-compute expert
+    under balanced routing over the router's outputs."""
+    experts, zeros = routed_experts(config), zero_experts(config)
+    return zeros / (experts + zeros) if zeros else 0.0
 
 
 def resolve_cut(config: dict, layers: Optional[dict] = None,
@@ -253,15 +343,16 @@ def resolve_cut(config: dict, layers: Optional[dict] = None,
     expert to hold (``experts_held`` 0; stating one is refused)."""
     freq = config.get("moe_layer_freq")
     experts = routed_experts(config)
+    depth = stack_depth(config)
     if not experts:
-        dense = int(config["num_hidden_layers"])
+        dense = depth
     elif isinstance(freq, list):
         dense = _leading_zeros(freq)
     else:
         dense = int(config.get("first_k_dense_replace")
                     or config.get("num_dense_layers") or 0)
     cut = {"leading_dense": dense,
-           "following": int(config["num_hidden_layers"]) - dense,
+           "following": depth - dense,
            "experts_held": experts}
     if layers is not None:
         unknown = set(layers) - {"leading_dense", "following"}
@@ -277,6 +368,10 @@ def resolve_cut(config: dict, layers: Optional[dict] = None,
     if not experts and cut["following"]:
         raise ValueError(f"architecture layers: {cut} of a config with "
                          f"no expert key (no layer follows the dense ones)")
+    if config.get("shortcut_sub_blocks") and cut["leading_dense"]:
+        raise ValueError(f"architecture layers: {cut} of a config whose "
+                         f"every layer holds its expert branch "
+                         f"(shortcut_sub_blocks: no dense layer leads)")
     if isinstance(freq, list):
         # kinds come from the list: the cut only says how many layers
         total = cut["leading_dense"] + cut["following"]
@@ -337,9 +432,10 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
     heads = int(config["num_attention_heads"])
     V = int(config["vocab_size"])
     E = routed_experts(config)          # 0: a dense model
-    k = int(config.get("num_experts_per_tok") or 0)
-    dense_inter = int(config["intermediate_size"])
-    expert_inter = int(config.get("moe_intermediate_size") or dense_inter)
+    Z = zero_experts(config)            # zero-compute router outputs
+    k = experts_per_token(config)
+    dense_inter = dense_width(config)
+    expert_inter = expert_width(config)
     shared_inter = int(config.get("n_shared_experts")
                        or config.get("num_shared_experts") or 0) * expert_inter
     sigmoid_router = "sigmoid" in (config.get("scoring_func"),
@@ -356,11 +452,16 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
     held = cut["experts_held"]
     S, B = int(seq_len), int(micro_batch)
     T = S * B                        # tokens of the step
-    # token-expert pairs the held experts see under balanced routing
-    pairs = 0
-    if E:
-        pairs = T * k * held // E if T * k * held % E == 0 \
-            else T * k * held / E
+    def share_of_pairs(outputs):
+        """Token-expert pairs ``outputs`` of the router's E + Z see
+        under balanced routing."""
+        whole, rest = divmod(T * k * outputs, E + Z)
+        return whole if not rest else T * k * outputs / (E + Z)
+
+    # pairs the FFN experts held here see, and the zero-compute experts'
+    # (whole on every pod: a token's own device adds them)
+    pairs = share_of_pairs(held) if E else 0
+    zero_pairs = share_of_pairs(Z) if Z else 0
     A = ACT_BYTES
     # muP's scalars, elementwise where a key states them: x scale_emb an
     # embedding element, x scale_depth / sqrt(layers) an element of each
@@ -538,19 +639,23 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
         return _out_proj(core, stream, gate_proj, n * d,
                          out_norm=bool(config.get("use_output_norm")))
 
-    def _latent_sparse_attention(stream):
-        """MLA with the DSA indexer and top-k sparse core; returns
+    def _latent_attention(stream):
+        """MLA: low-rank q and kv paths (one function for every config
+        with ``kv_lora_rank``), then — where the config states
+        ``index_topk`` — the DSA indexer and a top-k sparse core, else a
+        full causal core over the latent keys; returns
         OutProjResidual."""
         rq, rkv = int(config["q_lora_rank"]), int(config["kv_lora_rank"])
         dn, dr = (int(config["qk_nope_head_dim"]),
                   int(config["qk_rope_head_dim"]))
         dv = int(config["v_head_dim"])
-        ni, di = int(config["index_n_heads"]), int(config["index_head_dim"])
-        topk = int(config["index_topk"])
         dqk = dn + dr
+        # c . sqrt(H / rank), 1 an element, where the key says so
+        scale_q = T * rq * bool(config.get("mla_scale_q_lora"))
+        scale_kv = T * rkv * bool(config.get("mla_scale_kv_lora"))
         x = norm("InputNorm", [stream])
         # c_q = RMSNorm(x W_qa): H -> rq, norm 4 an element
-        c_q = g.add("QAProj", 2 * T * H * rq + 4 * T * rq,
+        c_q = g.add("QAProj", 2 * T * H * rq + 4 * T * rq + scale_q,
                     A * (T * H + H * rq + rq + T * rq), T * rq,
                     H * rq + rq, [x])
         # q = c_q W_qb: rq -> heads x (nope + rope), RoPE (3) on the rope
@@ -560,7 +665,8 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
         # [c_kv ; k_r] = x W_kva: H -> rkv + rope; RMSNorm (4) on c_kv,
         # RoPE (3) on the one k_r all heads share
         c_kv = g.add("KVAProj",
-                     2 * T * H * (rkv + dr) + 4 * T * rkv + 3 * T * dr,
+                     2 * T * H * (rkv + dr) + 4 * T * rkv + 3 * T * dr
+                     + scale_kv,
                      A * (T * H + H * (rkv + dr) + rkv + T * (rkv + dr)),
                      T * (rkv + dr), H * (rkv + dr) + rkv, [x])
         # [k_n ; v] = c_kv W_kvb: rkv -> heads x (nope + v)
@@ -568,74 +674,100 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
                    A * (T * rkv + rkv * heads * (dn + dv)
                         + T * heads * (dn + dv)),
                    T * heads * (dn + dv), rkv * heads * (dn + dv), [c_kv])
-        # indexer: q^I = c_q W_Iq (rq -> ni x di), k^I = Norm(x W_Ik)
-        # (H -> di, norm 4), w = x W_Iw (H -> ni); RoPE (3) on the rope
-        # part of each q^I head and of k^I
-        index_out = ni * di + di + ni
-        index_w = rq * ni * di + H * di + H * ni
-        idx = g.add("IndexerProj",
-                    2 * T * index_w + 4 * T * di + 3 * T * (ni + 1) * dr,
-                    A * (T * rq + T * H + index_w + di + T * index_out),
-                    T * index_out, index_w + di, [c_q, x])
-        # I[t,s] = sum_j w[t,j] ReLU(q^I[t,j] . k^I[s]) over the causal
-        # half of S x S (dot 2 di, ReLU and weighted sum 2), top-k of
-        # each row; the S x S scores are never written, out = indices
-        select = g.add("IndexScoreTopK", B * S * S / 2 * ni * (2 * di + 2),
-                       A * (T * index_out + T * min(S, topk)),
-                       T * min(S, topk), 0, [idx])
-        # o_t = sum_{s in S_t} softmax(q_t . [k_n,s ; k_r,s]) v_s: a
-        # query reads min(t, topk) keys; QK^T 2 dqk, PV 2 dv, softmax 5
-        core = g.add("SparseAttnCore",
-                     B * attended_keys(S, topk) * heads * (2 * dqk + 2 * dv + 5),
-                     A * (T * heads * dqk + T * heads * (dn + dv) + T * dr
-                          + T * min(S, topk) + T * heads * dv),
-                     T * heads * dv, 0, [q, kv, c_kv, select])
+        # QK^T 2 dqk, PV 2 dv, softmax 5 a key a query reads
+        pair = heads * (2 * dqk + 2 * dv + 5)
+        if "index_topk" in config:
+            ni, di = (int(config["index_n_heads"]),
+                      int(config["index_head_dim"]))
+            topk = int(config["index_topk"])
+            # indexer: q^I = c_q W_Iq (rq -> ni x di), k^I = Norm(x
+            # W_Ik) (H -> di, norm 4), w = x W_Iw (H -> ni); RoPE (3) on
+            # the rope part of each q^I head and of k^I
+            index_out = ni * di + di + ni
+            index_w = rq * ni * di + H * di + H * ni
+            idx = g.add("IndexerProj",
+                        2 * T * index_w + 4 * T * di
+                        + 3 * T * (ni + 1) * dr,
+                        A * (T * rq + T * H + index_w + di
+                             + T * index_out),
+                        T * index_out, index_w + di, [c_q, x])
+            # I[t,s] = sum_j w[t,j] ReLU(q^I[t,j] . k^I[s]) over the
+            # causal half of S x S (dot 2 di, ReLU and weighted sum 2),
+            # top-k of each row; the S x S scores are never written,
+            # out = indices
+            select = g.add("IndexScoreTopK",
+                           B * S * S / 2 * ni * (2 * di + 2),
+                           A * (T * index_out + T * min(S, topk)),
+                           T * min(S, topk), 0, [idx])
+            # o_t = sum_{s in S_t} softmax(q_t . [k_n,s ; k_r,s]) v_s: a
+            # query reads min(t, topk) keys
+            core = g.add("SparseAttnCore",
+                         B * attended_keys(S, topk) * pair,
+                         A * (T * heads * dqk + T * heads * (dn + dv)
+                              + T * dr + T * min(S, topk)
+                              + T * heads * dv),
+                         T * heads * dv, 0, [q, kv, c_kv, select])
+        else:
+            # o_t = sum_{s <= t} softmax_s(q_t . [k_n,s ; k_r,s] /
+            # sqrt(dqk)) v_s: FULL causal, a query reads its t keys
+            core = g.add("LatentAttnCore",
+                         B * attended_keys(S, S) * pair,
+                         A * (T * heads * dqk + T * heads * (dn + dv)
+                              + T * dr + T * heads * dv),
+                         T * heads * dv, 0, [q, kv, c_kv])
         return _out_proj(core, stream, None, heads * dv)
 
     window = window_layers(config)
     linear = linear_layers(config)
     freq = config.get("moe_layer_freq")
+    sub_blocks = int(config.get("shortcut_sub_blocks") or 0)
     if n_mtp and (window is not None or linear is not None):
         raise ValueError("a per-layer attention list gives no kind for a "
                          "multi-token-prediction module's layer")
 
-    def layer(stream, i=None):
-        """Decoder layer ``i`` of the published stack (None: an MTP
-        module's expert layer) on the residual stream op ``stream``;
-        returns the op that carries the stream out."""
+    def attention(stream, i):
+        """Layer ``i``'s attention on the residual stream op ``stream``;
+        returns OutProjResidual."""
         if "kv_lora_rank" in config:
-            out_proj = _latent_sparse_attention(stream)
-        elif linear is not None and linear[i]:
-            out_proj = _linear_attention(stream)
-        else:
-            out_proj = _gqa_attention(
-                stream, window=window is not None and window[i])
-        if not E:
-            dense = True
-        elif isinstance(freq, list):
-            dense = freq[i] == 0
-        else:
-            dense = i is not None and i < cut["leading_dense"]
-        x = norm("PostAttnNorm", [out_proj])
-        if dense:
-            # gate, up, down, silu * up (4 a value), + residual
-            return g.add("DenseMLPResidual",
-                         2 * T * 3 * H * dense_inter + 4 * T * dense_inter
-                         + T * H + branch_scale,
-                         A * (3 * T * H + 3 * H * dense_inter),
-                         T * H, 3 * H * dense_inter, [x, out_proj])
+            return _latent_attention(stream)
+        if linear is not None and linear[i]:
+            return _linear_attention(stream)
+        return _gqa_attention(stream,
+                              window=window is not None and window[i])
+
+    def dense_mlp(x, stream):
+        # gate, up, down, silu * up (4 a value), + residual
+        return g.add("DenseMLPResidual",
+                     2 * T * 3 * H * dense_inter + 4 * T * dense_inter
+                     + T * H + branch_scale,
+                     A * (3 * T * H + 3 * H * dense_inter),
+                     T * H, 3 * H * dense_inter, [x, stream])
+
+    # FLOPs of the softmax router's selected weights: x
+    # routed_scaling_factor where the config states one
+    softmax_weight = T * k * ("routed_scaling_factor" in config)
+    # the selection bias b of top-k(p + b), a parameter an output, where
+    # the ``modeling`` block states it
+    score_bias = (E + Z) * bool(config.get("e_score_correction_bias"))
+
+    def expert_block(x):
+        """Router, the always-on experts and the expert group on the
+        normed state ``x``; returns (Router, Experts, [SharedExpert])."""
+        R = E + Z                    # the router's outputs
         if sigmoid_router:
             # s = sigmoid(x W_r), top-k of s + b (5 a logit), weights
             # s_sel / sum s_sel x scale (3 a selected expert)
             router = g.add("Router",
-                           2 * T * H * E + 5 * T * E + routed_weight * T * k,
-                           A * (T * H + H * E + E + 2 * T * k), 2 * T * k,
-                           H * E + E, [x])
+                           2 * T * H * R + 5 * T * R + routed_weight * T * k,
+                           A * (T * H + H * R + R + 2 * T * k), 2 * T * k,
+                           H * R + R, [x])
         else:
-            # x W_r, softmax over E (5 a logit); out: k weights + indices
-            router = g.add("Router", 2 * T * H * E + 5 * T * E,
-                           A * (T * H + H * E + 2 * T * k), 2 * T * k,
-                           H * E, [x])
+            # x W_r, softmax over the outputs (5 a logit); out: k
+            # weights + indices
+            router = g.add("Router",
+                           2 * T * H * R + 5 * T * R + softmax_weight,
+                           A * (T * H + H * R + score_bias + 2 * T * k),
+                           2 * T * k, H * R + score_bias, [x])
         shared = []
         if shared_inter:
             # the always-on experts: gate, up, down, silu * up
@@ -646,22 +778,67 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
                 3 * H * shared_inter, [x])]
         # gate, up, down for the pairs routed to the experts held here,
         # silu * up (4 a value); balanced routing: every held expert
-        # that has a token is read once
+        # that has a token is read once. A pair routed to a zero-compute
+        # expert is not here: it costs the combine its weighted add
         experts = g.add("Experts",
                         2 * pairs * 3 * H * expert_inter
                         + 4 * pairs * expert_inter,
                         A * (2 * pairs * H
                              + min(held, pairs) * 3 * H * expert_inter),
                         pairs * H, held * 3 * H * expert_inter, [router])
-        # weighted sum of the routed outputs (+ the shared one) + residual
-        combine = g.add("CombineResidual",
-                        2 * pairs * H + T * H * (1 + len(shared))
-                        + branch_scale,
-                        A * (pairs * H + pairs
-                             + T * H * (2 + len(shared))),
-                        T * H, 0, [experts, out_proj, router, *shared])
+        return router, experts, shared
+
+    def combine(op_type, experts, stream, router, shared, x):
+        """Weighted sum of the routed outputs (+ the shared one; + w . x
+        for the pairs routed to an identity expert, which reads the
+        block's input ``x``) + residual."""
+        op = g.add(op_type,
+                   2 * (pairs + zero_pairs) * H + T * H * (1 + len(shared))
+                   + branch_scale,
+                   A * (pairs * H + pairs + zero_pairs
+                        + T * H * (2 + len(shared) + bool(Z))),
+                   T * H, 0,
+                   [experts, stream, router, *shared] + [x] * bool(Z))
+        # Experts reads x too; the edge is listed behind the combine's,
+        # where the pinned profiles of the older families have it
         g.edges.append((x, experts))
-        return combine
+        return op
+
+    def shortcut_layer(stream, i):
+        """A layer of ``shortcut_sub_blocks`` attention + dense-FFN
+        sub-blocks whose ONE expert block reads the first sub-block's
+        normed state and is added back after the last one's FFN
+        (shortcut-connected experts: Router -> Experts run beside every
+        op between)."""
+        for block in range(sub_blocks):
+            out_proj = attention(stream, i)
+            x = norm("PostAttnNorm", [out_proj])
+            if block == 0:
+                x_branch = x
+                router, experts, shared = expert_block(x)
+            stream = dense_mlp(x, out_proj)
+        return combine("ShortcutCombineResidual", experts, stream, router,
+                       shared, x_branch)
+
+    def layer(stream, i=None):
+        """Decoder layer ``i`` of the published stack (None: an MTP
+        module's expert layer) on the residual stream op ``stream``;
+        returns the op that carries the stream out."""
+        if sub_blocks:
+            return shortcut_layer(stream, i)
+        out_proj = attention(stream, i)
+        if not E:
+            dense = True
+        elif isinstance(freq, list):
+            dense = freq[i] == 0
+        else:
+            dense = i is not None and i < cut["leading_dense"]
+        x = norm("PostAttnNorm", [out_proj])
+        if dense:
+            return dense_mlp(x, out_proj)
+        router, experts, shared = expert_block(x)
+        return combine("CombineResidual", experts, out_proj, router,
+                       shared, x)
 
     embedding = g.add("Embedding", T * H * ("scale_emb" in config),
                       A * 2 * T * H + 4 * T, T * H, V * H)
@@ -706,10 +883,12 @@ def forward_time(cost: dict) -> float:
                cost["bytes"] / A100.memory_bandwidth)
 
 
-#: forward ops whose FLOPs grow as S^2: a full causal core, the sparse
-#: indexer's score and the block score over compressed keys (a windowed,
-#: top-k or linear core grows as S)
-QUADRATIC_OPS = ("AttnCore", "IndexScoreTopK", "BlockScoreTopK")
+#: forward ops whose FLOPs grow as S^2: a full causal core (over heads'
+#: own keys or over the latent ones), the sparse indexer's score and the
+#: block score over compressed keys (a windowed, top-k or linear core
+#: grows as S)
+QUADRATIC_OPS = ("AttnCore", "IndexScoreTopK", "BlockScoreTopK",
+                 "LatentAttnCore")
 
 
 def profile_text(config: dict, seq_len: int, micro_batch: int,

@@ -206,7 +206,12 @@ def record_padding_fill(ep_trace, et, ot) -> None:
     decision's own (model, degree) row (``et.row_deps``, the tables'
     host copy: nothing is fetched here), summed — beside
     ``sim.lookahead.dep_slots_offered`` — those decisions x the dep
-    slots every trip passes over (``pads.n_deps``). Over every step:
+    slots every trip passes over (``pads.n_deps``) — and
+    ``sim.lookahead.ops_decided`` — the ops (forward and mirrored) of
+    each such decision's job, from the static per-type node counts: the
+    lanes' own ``sim.lookahead.trips`` over it is the trips an op of a
+    decided job cost, what a graph's shape (a chain, a branch) does to
+    the event count. Over every step:
     ``env.obs.nodes_real`` — the queued job's graph nodes — beside
     ``env.obs.nodes_padded`` — steps x the observation's node pad, the
     GNN's padded work. The caller gates on ``telemetry.enabled()``."""
@@ -214,12 +219,13 @@ def record_padding_fill(ep_trace, et, ot) -> None:
     ran = np.asarray(ep_trace["la_trips"]) > 0
     row = _chosen_rows(et, jtype[ran],
                        np.asarray(ep_trace["action"])[ran])
+    nodes = ot["node_split"][:, 0]      # a job type's ops, unsplit
     telemetry.inc("sim.lookahead.dep_slots_decided",
                   int(et.row_deps[row].sum()))
     telemetry.inc("sim.lookahead.dep_slots_offered",
                   int(ran.sum()) * int(et.pads.n_deps))
-    telemetry.inc("env.obs.nodes_real",
-                  int(ot["node_split"][:, 0][jtype].sum()))
+    telemetry.inc("sim.lookahead.ops_decided", int(nodes[jtype[ran]].sum()))
+    telemetry.inc("env.obs.nodes_real", int(nodes[jtype].sum()))
     telemetry.inc("env.obs.nodes_padded",
                   jtype.size * int(ot["node_features"].shape[1]))
 

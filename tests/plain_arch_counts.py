@@ -1,9 +1,10 @@
 """Independent plain counts of a ``glm_moe_dsa``, of a
-``mimo_v2_flash``, of an ``afmoe`` (Trinity) and of a ``minicpm_sala``
-training step: per op the parameters, the forward FLOPs and the elements
-of the output tensor (for ``afmoe`` and ``minicpm_sala`` also the bytes
-moved and the edges), written straight from the layer equations (ISSUE
-30, 32, 36 and 39, Tentpole step 1) and importing nothing from
+``mimo_v2_flash``, of an ``afmoe`` (Trinity), of a ``minicpm_sala`` and
+of a ``longcat_flash`` training step: per op the parameters, the forward
+FLOPs and the elements of the output tensor (for ``afmoe``,
+``minicpm_sala`` and ``longcat_flash`` also the bytes moved and the
+edges), written straight from the layer equations (ISSUE 30, 32, 36, 39
+and 48, Tentpole step 1) and importing nothing from
 ``ddls_tpu/graphs/arch.py``, which ``tests/test_arch_graphs.py`` holds
 to them op by op.
 
@@ -400,5 +401,115 @@ def plain_counts_sala(c, S, B):
                      2 * (3 * T * H + 3 * H * I), [x, y])
     f = rmsnorm("FinalNorm", [stream])
     add("LMHeadLoss", H * V, 2 * T * H * V + 5 * T * V + T * H, T * V,
+        2 * (T * H + H * V + T * V), [f])
+    return ops, edges
+
+
+# ========================================================= longcat_flash
+def plain_counts_longcat(c, S, B, layers=None, held=None):
+    """``(ops, edges)`` of ``layers`` double layers (None: all
+    ``num_layers``) of a ``longcat_flash`` model holding ``held`` of its
+    FFN experts (None: all) over B sequences of S tokens, in the shape
+    :func:`plain_counts_sala` returns. ``c`` is the public config with
+    the architecture file's ``modeling`` block over it. Written from
+    ISSUE 48's equations; T = S B, H = hidden_size, ``x`` a normed
+    stream.
+
+    A layer is TWO sub-blocks — ``x = RMSNorm(h)``; ``c_q = RMSNorm(x
+    W_qa)`` (. sqrt(H / q_lora_rank) where ``mla_scale_q_lora``: 1 an
+    element); ``q = c_q W_qb`` to n heads of nope + rope, RoPE (3) on
+    the rope part; ``[c_kv ; k_r] = x W_kva``, ``c_kv`` RMS-normed (and
+    scaled where ``mla_scale_kv_lora``), RoPE on the one ``k_r``;
+    ``[k_n ; v] = c_kv W_kvb``; a FULL causal core, query t reading its
+    t keys at 2 (nope + rope) + 2 v + 5 a key and head; ``h += o W_o``;
+    ``x' = RMSNorm(h)``; ``h += SwiGLU(x')`` at ``ffn_hidden_size`` —
+    and ONE expert block that reads the FIRST sub-block's ``x'`` and is
+    added after the SECOND sub-block's FFN: ``p = softmax(x' W_r)`` over
+    R = ``n_routed_experts`` + ``zero_expert_num`` outputs (5 a logit),
+    top-``moe_topk`` of p + b (b a parameter an output where the
+    modeling block states ``e_score_correction_bias``), weights ``p[idx]
+    . routed_scaling_factor`` (1 a selected weight where the key is
+    stated, not renormalised); under balanced routing the ``held`` FFN
+    experts see T k held / R pairs (gate, up, down, silu . up's 4 a
+    value) and the identity experts T k Z / R pairs, WHOLE on every pod,
+    which cost the combine 2 H a pair (``w . x'``) like any other pair
+    and read ``x'`` (T H elements), no parameter."""
+    T, H, V = S * B, c["hidden_size"], c["vocab_size"]
+    n, rq, rkv = c["num_attention_heads"], c["q_lora_rank"], c["kv_lora_rank"]
+    dn, dr, dv = (c["qk_nope_head_dim"], c["qk_rope_head_dim"],
+                  c["v_head_dim"])
+    dqk = dn + dr
+    I, Ie = c["ffn_hidden_size"], c["expert_ffn_hidden_size"]
+    E, Z, k = c["n_routed_experts"], c.get("zero_expert_num", 0), c[
+        "moe_topk"]
+    R = E + Z
+    layers = c["num_layers"] if layers is None else layers
+    held = E if held is None else held
+    scale_q = T * rq if c.get("mla_scale_q_lora") else 0
+    scale_kv = T * rkv if c.get("mla_scale_kv_lora") else 0
+    bias = R if c.get("e_score_correction_bias") else 0
+    scaled = T * k if "routed_scaling_factor" in c else 0
+    pairs, zero_pairs = T * k * held / R, T * k * Z / R
+    ops, edges = [], set()
+
+    def add(kind, params, flops, out, nbytes, reads=()):
+        ops.append((kind, params, flops, out, nbytes))
+        edges.update((r, len(ops)) for r in reads)
+        return len(ops)
+
+    def rmsnorm(kind, reads):
+        return add(kind, H, 4 * T * H, T * H, 2 * (2 * T * H + H), reads)
+
+    stream = add("Embedding", V * H, 0, T * H, 2 * 2 * T * H + 4 * T)
+    for _ in range(layers):
+        for block in (0, 1):
+            x = rmsnorm("InputNorm", [stream])
+            c_q = add("QAProj", H * rq + rq,
+                      2 * T * H * rq + 4 * T * rq + scale_q, T * rq,
+                      2 * (T * H + H * rq + rq + T * rq), [x])
+            q = add("QBProj", rq * n * dqk,
+                    2 * T * rq * n * dqk + 3 * T * n * dr, T * n * dqk,
+                    2 * (T * rq + rq * n * dqk + T * n * dqk), [c_q])
+            c_kv = add("KVAProj", H * (rkv + dr) + rkv,
+                       2 * T * H * (rkv + dr) + 4 * T * rkv + 3 * T * dr
+                       + scale_kv, T * (rkv + dr),
+                       2 * (T * H + H * (rkv + dr) + rkv
+                            + T * (rkv + dr)), [x])
+            kv = add("KVBProj", rkv * n * (dn + dv),
+                     2 * T * rkv * n * (dn + dv), T * n * (dn + dv),
+                     2 * (T * rkv + rkv * n * (dn + dv)
+                          + T * n * (dn + dv)), [c_kv])
+            o = add("LatentAttnCore", 0,
+                    B * (S * (S + 1) // 2) * n * (2 * dqk + 2 * dv + 5),
+                    T * n * dv,
+                    2 * (T * n * dqk + T * n * (dn + dv) + T * dr
+                         + T * n * dv), [q, kv, c_kv])
+            y = add("OutProjResidual", n * dv * H,
+                    2 * T * n * dv * H + T * H, T * H,
+                    2 * (T * n * dv + n * dv * H + 2 * T * H), [o, stream])
+            xp = rmsnorm("PostAttnNorm", [y])
+            if block == 0:
+                x0 = xp
+                router = add("Router", H * R + bias,
+                             2 * T * H * R + 5 * T * R + scaled, 2 * T * k,
+                             2 * (T * H + H * R + bias + 2 * T * k), [xp])
+                experts = add("Experts", held * 3 * H * Ie,
+                              2 * pairs * 3 * H * Ie + 4 * pairs * Ie,
+                              pairs * H,
+                              2 * (2 * pairs * H
+                                   + min(held, pairs) * 3 * H * Ie),
+                              [router, xp])
+            stream = add("DenseMLPResidual", 3 * H * I,
+                         2 * T * 3 * H * I + 4 * T * I + T * H, T * H,
+                         2 * (3 * T * H + 3 * H * I), [xp, y])
+        # h = h + m, after the SECOND FFN: the routed outputs and, for
+        # the identity pairs, x'_0 itself, each under its weight
+        stream = add("ShortcutCombineResidual", 0,
+                     2 * (pairs + zero_pairs) * H + T * H, T * H,
+                     2 * (pairs * H + pairs + zero_pairs
+                          + (3 if Z else 2) * T * H),
+                     [experts, stream, router] + [x0] * (Z > 0))
+    f = rmsnorm("FinalNorm", [stream])
+    add("LMHeadLoss", H * V, 2 * T * H * V + 5 * T * V, T * V,
         2 * (T * H + H * V + T * V), [f])
     return ops, edges

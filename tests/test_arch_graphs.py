@@ -261,18 +261,24 @@ def test_generator_takes_an_architecture_like_any_other_source(tmp_path):
         == gen.workload_fingerprint          # another temp dir, same id
     gauges = startup.gauges()
     assert gauges.pop(BANK_GAUGES[1]) == 2
-    shares = {k: v for k, v in gauges.items() if "_time_share" in k}
+    shares = {k: v for k, v in gauges.items() if "_share" in k}
     assert {k: v for k, v in gauges.items()
             if "_bytes" not in k and k not in shares} == {
         f"graphs.arch.{what}.{m}": n for m in models
         for what, n in (("forward_ops", 19), ("edges", 53),
                         ("layers_full", 2), ("layers_window", 0),
                         ("layers_linear", 0), ("layers_block_sparse", 0),
+                        ("layers_latent", 0), ("shortcut_branches", 0),
+                        ("zero_experts", 0),
                         ("shared_expert_layers", 0))}
     assert sorted(shares) == sorted(
-        [BANK_GAUGES[0]]
-        + [f"graphs.arch.{kind}_time_share.{m}" for m in models
-           for kind in ("quadratic", "linear")])
+        [BANK_GAUGES[0], *BANK_GAUGES[2:]]
+        + [f"graphs.arch.{kind}_share.{m}" for m in models
+           for kind in ("quadratic_time", "linear_time", "branch_time",
+                        "zero_routed")])
+    # a chain with shortcut edges alone: nothing runs beside it, and no
+    # router output is a zero-compute expert
+    assert [gauges[name] for name in BANK_GAUGES[2:]] == [0, 0]
     # an unstated family: what a dep or a sync edge is sized by is the
     # largest op's whole memory cost
     for m in models:
@@ -1317,9 +1323,9 @@ def test_generator_sets_the_layer_kind_gauges(tmp_path):
               for m in models]
     assert shares == pytest.approx([0.8347, 0.0450], abs=1e-4)
     # the bank's mean as a ratio of two gauges, as the benchmark reads it
-    assert [gauges[name] for name in BANK_GAUGES] == [sum(shares), 2]
+    assert [gauges[name] for name in BANK_GAUGES[:2]] == [sum(shares), 2]
     report = json.loads(startup.report()[len("[startup] "):])
-    assert [report[name] for name in BANK_GAUGES] == [sum(shares), 2]
+    assert [report[name] for name in BANK_GAUGES[:2]] == [sum(shares), 2]
     startup.registry().reset()
 
 
@@ -2415,3 +2421,503 @@ def test_generator_and_tables_set_the_sala_gauges(tmp_path):
                      f"minicpm_sala_s{s}_b{b}"] for s, b in SALA_SHAPES]
     assert shares == pytest.approx([0.0144, 0.0284, 0.0035, 0.0140],
                                    abs=1e-4)
+
+
+# ========================================================= longcat_flash
+LONGCAT_FILE = "ddls_tpu/graphs/arch_configs/longcat_flash_omni.json"
+#: the deployment's cut (env_longcat_32.yaml): 4 of the 28 double layers,
+#: 128 of the 512 FFN experts
+LONGCAT_CUT = {"layers": {"leading_dense": 0, "following": 4},
+               "experts_held": 128}
+LONGCAT_SHAPES = [(8192, 1), (8192, 4), (32768, 1), (131072, 1)]
+#: 2 double layers, hidden 64, 8 FFN + 4 zero-compute experts (3 a
+#: token): every key LongCat-Flash's config states, under its own name —
+#: the THIRD family of key names (``num_layers``, ``ffn_hidden_size``,
+#: ``expert_ffn_hidden_size``, ``moe_topk``; no ``model_type``) — and
+#: what it leaves unsaid in a ``modeling`` block
+TINY_LONGCAT = {"hidden_size": 64, "num_attention_heads": 4,
+                "q_lora_rank": 32, "kv_lora_rank": 16,
+                "qk_nope_head_dim": 12, "qk_rope_head_dim": 4,
+                "v_head_dim": 16, "mla_scale_q_lora": True,
+                "mla_scale_kv_lora": True, "ffn_hidden_size": 128,
+                "expert_ffn_hidden_size": 32, "n_routed_experts": 8,
+                "zero_expert_num": 4, "zero_expert_type": "identity",
+                "moe_topk": 3, "routed_scaling_factor": 6,
+                "num_layers": 2, "vocab_size": 256}
+TINY_LONGCAT_MODELING = {"model_type": "tinylongcat",
+                         "shortcut_sub_blocks": 2,
+                         "e_score_correction_bias": True}
+TINY_LONGCAT_BUILT = {**TINY_LONGCAT, **TINY_LONGCAT_MODELING}
+#: the ops of one double layer, in profile order
+LONGCAT_SUB_BLOCK = ["InputNorm", "QAProj", "QBProj", "KVAProj", "KVBProj",
+                     "LatentAttnCore", "OutProjResidual", "PostAttnNorm"]
+LONGCAT_LAYER = (LONGCAT_SUB_BLOCK + ["Router", "Experts",
+                                      "DenseMLPResidual"]
+                 + LONGCAT_SUB_BLOCK + ["DenseMLPResidual",
+                                        "ShortcutCombineResidual"])
+
+
+@pytest.fixture(scope="module")
+def longcat():
+    return arch.load_arch_config(LONGCAT_FILE)
+
+
+def _tiny_longcat_arch_file(directory) -> str:
+    path = os.path.join(str(directory), "tinylongcat.json")
+    with open(path, "w") as fh:
+        json.dump({"source_url": "test-local", "training_state": STATE,
+                   "modeling": TINY_LONGCAT_MODELING,
+                   "config": TINY_LONGCAT}, fh)
+    return path
+
+
+@pytest.mark.parametrize("case", ["tiny_s8", "tiny_cut", "longcat_8k",
+                                  "longcat_8k_x4", "longcat_32k",
+                                  "longcat_128k"])
+def test_longcat_op_costs_equal_the_plain_count_op_by_op(longcat, case):
+    """`plain_counts_longcat` is written from ISSUE 48's equations and
+    imports nothing of the builder: op names, parameters, FLOPs, output
+    elements, bytes moved and the edge set agree at two tiny shapes
+    (whole, and a cut whose pairs are no whole number) and the cell's
+    four."""
+    from plain_arch_counts import plain_counts_longcat
+
+    cut = (4, 128)
+    config, seq_len, micro_batch, (layers, held) = {
+        "tiny_s8": (TINY_LONGCAT_BUILT, 8, 3, (None, None)),
+        "tiny_cut": (TINY_LONGCAT_BUILT, 37, 5, (1, 5)),
+        "longcat_8k": (longcat, 8192, 1, cut),
+        "longcat_8k_x4": (longcat, 8192, 4, cut),
+        "longcat_32k": (longcat, 32768, 1, cut),
+        "longcat_128k": (longcat, 131072, 1, cut)}[case]
+    built = arch.build_graph(
+        config, seq_len, micro_batch,
+        None if layers is None else {"leading_dense": 0,
+                                     "following": layers}, held)
+    plain, edges = plain_counts_longcat(config, seq_len, micro_batch,
+                                        layers, held)
+    assert [o["op_type"] for o in built.ops] == [p[0] for p in plain]
+    for i, (o, (kind, params, flops, out, nbytes)) in enumerate(
+            zip(built.ops, plain)):
+        assert o["params"] == params, (i, kind)
+        assert o["flops"] == pytest.approx(flops, rel=1e-12), (i, kind)
+        assert o["out_elems"] == pytest.approx(out, rel=1e-12), (i, kind)
+        assert o["bytes"] == pytest.approx(nbytes, rel=1e-12), (i, kind)
+    assert set(built.edges) == edges and len(built.edges) == len(edges)
+    depth = config["num_layers"] if layers is None else layers
+    # 21 ops and 33 edges a double layer: an edge for every true data
+    # dependency and no other
+    assert [o["op_type"] for o in built.ops] == (
+        ["Embedding"] + LONGCAT_LAYER * depth + ["FinalNorm", "LMHeadLoss"])
+    assert len(built.ops) == 1 + 21 * depth + 2
+    assert len(built.edges) == 33 * depth + 2
+
+
+@pytest.mark.parametrize("quantity", ["whole", "as_cut"])
+def test_longcat_whole_and_cut_are_the_published_model(longcat, quantity):
+    """Whole, the language model counts 560.66 B parameters (published
+    560B; the encoders and the codec decoder have no key and are left
+    out) and 27 B active a token; the stage the cell queues is 23.49 B:
+    87 forward ops, 134 forward edges."""
+    if quantity == "whole":
+        ops = arch.op_costs(longcat, 8192, 1)
+        assert len(ops) == 1 + 21 * 28 + 2
+        assert sum(o["params"] for o in ops) == 560_664_980_480
+        # a token's own parameters: everything but the experts it does
+        # not reach — 12 x 512 / 768 = 8 FFN experts a layer on average
+        expert = 3 * 6144 * 2048
+        active = sum(o["params"] for o in ops
+                     if o["op_type"] != "Experts") + 28 * 8 * expert
+        assert 26e9 < active - 2 * 131072 * 6144 + 131072 * 6144 < 28e9
+        assert arch.resolve_cut(longcat) == {
+            "leading_dense": 0, "following": 28, "experts_held": 512}
+    else:
+        graph = arch.build_graph(longcat, 8192, 1, **LONGCAT_CUT)
+        assert sum(o["params"] for o in graph.ops) == 23_493_469_184
+        assert (len(graph.ops), len(graph.edges)) == (87, 134)
+        # one attention 90.57 M, one dense FFN 226.49 M, the router
+        # 4.72 M, an expert 37.75 M
+        layer = graph.ops[1:22]
+        assert sum(o["params"] for o in layer[:7]) - 6144 \
+            == 90_572_800 == 6144 * 1536 + 1536 + 1536 * 64 * 192 \
+            + 6144 * 576 + 512 + 512 * 64 * 256 + 64 * 128 * 6144
+        assert layer[10]["params"] == 3 * 6144 * 12288 == 226_492_416
+        assert layer[8]["params"] == 6144 * 768 + 768 == 4_719_360
+        assert layer[9]["params"] == 128 * 37_748_736
+
+
+@pytest.mark.parametrize("case", [
+    "zero_expert_num_absent", "mla_scale_q_false", "mla_scale_kv_false",
+    "routed_scaling_factor_absent", "score_bias_unstated",
+    "index_topk_present", "no_shortcut"])
+def test_each_longcat_key_is_counted_where_it_is_stated(glm, case):
+    """Take a key away and exactly its count goes: the router narrows to
+    E and the pairs are today's; a scalar is 1 FLOP an element of the
+    latent it scales; with ``index_topk`` the SAME q/kv projections
+    feed GLM-5's indexer and sparse core; without ``shortcut_sub_blocks``
+    the same keys build the one-chain expert layer."""
+    S, B = 32, 2
+    T, H = S * B, 64
+    base = TINY_LONGCAT_BUILT
+    ops = {o["op_type"]: o for o in arch.op_costs(base, S, B)}
+    drop = lambda *keys: {k: v for k, v in base.items() if k not in keys}
+
+    def changed(config):
+        got = {o["op_type"]: o for o in arch.op_costs(config, S, B)}
+        return got, sorted(k for k in got if got[k] != ops.get(k))
+
+    if case == "zero_expert_num_absent":
+        got, differ = changed(drop("zero_expert_num", "zero_expert_type"))
+        # (the combine's bytes tie at these sizes: k Z / (E + Z) = 1, so
+        # the identity pairs' x'_0 is as many elements as the FFN pairs
+        # they displace; its edge below does not)
+        assert differ == ["Experts", "Router"]
+        E, k = 8, 3
+        assert got["Router"]["params"] == H * E + E
+        assert got["Router"]["flops"] == 2 * T * H * E + 5 * T * E + T * k
+        pairs = T * k                     # all 8 held: today's count
+        assert got["Experts"]["out_elems"] == pairs * H
+        assert ops["Experts"]["out_elems"] == pairs * H * 8 / 12
+        assert got["ShortcutCombineResidual"]["flops"] \
+            == ops["ShortcutCombineResidual"]["flops"] \
+            == 2 * pairs * H + T * H
+        # no identity pair reads x'_0: one edge and T H elements fewer
+        edges = lambda c: len(arch.build_graph(c, S, B).edges)
+        assert edges(base) - edges(drop("zero_expert_num")) == 2
+        with pytest.raises(ValueError, match="zero_expert_type"):
+            arch.op_costs({**base, "zero_expert_type": "copy"}, S, B)
+    elif case.startswith("mla_scale"):
+        key, op, rank = {"mla_scale_q_false": ("mla_scale_q_lora",
+                                               "QAProj", 32),
+                         "mla_scale_kv_false": ("mla_scale_kv_lora",
+                                                "KVAProj", 16)}[case]
+        got, differ = changed({**base, key: False})
+        assert differ == [op]
+        assert ops[op]["flops"] - got[op]["flops"] == T * rank
+        assert {k: v for k, v in got[op].items() if k != "flops"} \
+            == {k: v for k, v in ops[op].items() if k != "flops"}
+    elif case == "routed_scaling_factor_absent":
+        got, differ = changed(drop("routed_scaling_factor"))
+        assert differ == ["Router"]
+        assert ops["Router"]["flops"] - got["Router"]["flops"] == T * 3
+    elif case == "score_bias_unstated":
+        got, differ = changed(drop("e_score_correction_bias"))
+        assert differ == ["Router"]
+        assert ops["Router"]["params"] - got["Router"]["params"] == 12
+        assert ops["Router"]["bytes"] - got["Router"]["bytes"] == 2 * 12
+    elif case == "index_topk_present":
+        index = {k: TINY_GLM[k] for k in ("index_n_heads",
+                                          "index_head_dim", "index_topk")}
+        types = [o["op_type"] for o in arch.op_costs({**base, **index},
+                                                     S, B)]
+        assert "LatentAttnCore" not in types
+        assert types[1:10] == [
+            "InputNorm", "QAProj", "QBProj", "KVAProj", "KVBProj",
+            "IndexerProj", "IndexScoreTopK", "SparseAttnCore",
+            "OutProjResidual"]
+        # and GLM-5's own config, which states no scale, pays none
+        glm_ops = {o["op_type"]: o for o in arch.op_costs(
+            glm, 8192, 1, **GLM_CUT)}
+        T5, rq = 8192, glm["q_lora_rank"]
+        assert glm_ops["QAProj"]["flops"] \
+            == 2 * T5 * 6144 * rq + 4 * T5 * rq
+    else:
+        graph = arch.build_graph(drop("shortcut_sub_blocks"), S, B)
+        types = [o["op_type"] for o in graph.ops]
+        assert types[1:12] == LONGCAT_SUB_BLOCK + [
+            "Router", "Experts", "CombineResidual"]
+        assert len(types) == 1 + 11 * 2 + 2
+        # the zero-compute pairs read the block's input here too
+        combine = types.index("CombineResidual") + 1
+        assert (types.index("PostAttnNorm") + 1, combine) in graph.edges
+
+
+@pytest.mark.parametrize("case", ["depth", "dense_width", "expert_width",
+                                  "experts_per_token", "disagree",
+                                  "dense_layer_leads"])
+def test_either_family_of_key_names_is_read_and_a_disagreement_refused(
+        longcat, glm, case):
+    """Depth, widths and experts a token under `num_hidden_layers` /
+    `num_layers`, `intermediate_size` / `ffn_hidden_size`,
+    `moe_intermediate_size` / `expert_ffn_hidden_size`,
+    `num_experts_per_tok` / `moe_topk`: one reader each; a config that
+    states both names and disagrees is refused, one that agrees is
+    not."""
+    reader, old, new = {
+        "depth": (arch.stack_depth, "num_hidden_layers", "num_layers"),
+        "dense_width": (arch.dense_width, "intermediate_size",
+                        "ffn_hidden_size"),
+        "expert_width": (arch.expert_width, "moe_intermediate_size",
+                         "expert_ffn_hidden_size"),
+        "experts_per_token": (arch.experts_per_token,
+                              "num_experts_per_tok", "moe_topk"),
+    }.get(case, (None, None, None))
+    if reader is not None:
+        assert old in glm and old not in longcat
+        assert new in longcat and new not in glm
+        assert reader(glm) == glm[old] and reader(longcat) == longcat[new]
+        assert reader({**longcat, old: longcat[new]}) == longcat[new]
+        with pytest.raises(ValueError, match="disagree"):
+            reader({**longcat, old: longcat[new] + 1})
+    elif case == "disagree":
+        with pytest.raises(ValueError, match="disagree"):
+            arch.build_graph({**TINY_LONGCAT_BUILT,
+                              "num_hidden_layers": 4}, 8, 1)
+        with pytest.raises(KeyError, match="num_layers"):
+            arch.stack_depth({"hidden_size": 64})
+        # no size is defaulted in the builder
+        for key in ("ffn_hidden_size", "q_lora_rank", "moe_topk",
+                    "vocab_size"):
+            config = {k: v for k, v in TINY_LONGCAT_BUILT.items()
+                      if k != key}
+            if key == "moe_topk":     # 0 experts a token: no pair at all
+                experts = [o for o in arch.op_costs(config, 8, 1)
+                           if o["op_type"] == "Experts"]
+                assert all(o["flops"] == 0 for o in experts)
+            else:
+                with pytest.raises(KeyError):
+                    arch.build_graph(config, 8, 1)
+    else:
+        with pytest.raises(ValueError, match="shortcut_sub_blocks"):
+            arch.resolve_cut(TINY_LONGCAT_BUILT,
+                             {"leading_dense": 1, "following": 1})
+        assert arch.model_name(TINY_LONGCAT_BUILT, 8, 1) \
+            == "tinylongcat_s8_b1"
+        assert arch.model_name(longcat, 8192, 4) == "longcat_flash_s8192_b4"
+        assert "model_type" not in arch.load_arch_file(
+            LONGCAT_FILE)["config"]
+
+
+@pytest.mark.parametrize("seq_len", [1, 7, 64])
+def test_latent_core_reads_t_keys_a_query_and_grows_as_s_squared(seq_len):
+    """The indexer-less latent core is FULL causal: query t reads its t
+    keys (brute force), so it is one of `QUADRATIC_OPS`."""
+    core = next(o for o in arch.op_costs(TINY_LONGCAT_BUILT, seq_len, 3)
+                if o["op_type"] == "LatentAttnCore")
+    keys = sum(t for t in range(1, seq_len + 1))
+    assert core["flops"] == 3 * keys * 4 * (2 * 16 + 2 * 16 + 5)
+    assert "LatentAttnCore" in arch.QUADRATIC_OPS
+    assert "SparseAttnCore" not in arch.QUADRATIC_OPS
+
+
+LONGCAT_SHA256 = {
+    (8192, 1):
+        "3857fd1ba59deb417a50111c5462edfcc3211ea2255b44a46f6b0b1ff8f6f339",
+    (8192, 4):
+        "4382bc323282cdb7b78c9a227e46ad15d16b3c11317534d2ae83ec98d5707597",
+    (32768, 1):
+        "2fca134457fd734740121c5989fafb14ab893a85c9e41d5e24b4164efc26371e",
+    (131072, 1):
+        "55dda6a86e039ed9418120d74238d0b1e9419cebda5c8b68e46a6d5d09d7d0f0"}
+
+
+@pytest.mark.parametrize("shape", LONGCAT_SHAPES)
+def test_longcat_profiles_are_pinned(shape):
+    """The four profiles of `longcat_ramp32.train_fused`, byte for byte:
+    a later change to a shared count shows here."""
+    import hashlib
+
+    family = arch.load_arch_file(LONGCAT_FILE)
+    text = arch.profile_text(arch.builder_config(family), *shape,
+                             LONGCAT_CUT["layers"],
+                             LONGCAT_CUT["experts_held"],
+                             family["training_state"])
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == LONGCAT_SHA256[shape]
+
+
+def test_longcat_env_yaml_states_what_its_comments_derive(longcat):
+    """env_longcat_32.yaml: the cut and the shapes as data, the arrival
+    gap and horizon derived from the builder's graph, env_olmoe32's pads
+    rule, which row is ragged, and nothing else changed from
+    env_mimo_32."""
+    import math
+
+    from ddls_tpu.agents.partitioners import sip_ml_num_partitions
+    from ddls_tpu.config import load_config
+
+    def env(name):
+        return load_config(
+            os.path.join(REPO, "scripts/ramp_job_partitioning_configs"),
+            "rllib_config", [f"env_config={name}"])["env_config"]
+
+    cfg, base = env("env_longcat_32"), env("env_mimo_32")
+    jobs = cfg["jobs_config"]
+    family = jobs["architecture"]
+    assert family["config"] == LONGCAT_FILE
+    assert {k: family[k] for k in LONGCAT_CUT} == LONGCAT_CUT
+    shapes = family["shapes"]
+    assert [(s["seq_len"], s["micro_batch"]) for s in shapes] \
+        == LONGCAT_SHAPES
+    assert longcat["max_position_embeddings"] == 131072
+    steps = jobs["num_training_steps"]
+    costs = [arch.op_costs(longcat, **s, **LONGCAT_CUT) for s in shapes]
+    forward = [sum(arch.forward_time(c) for c in ops) for ops in costs]
+    assert forward == pytest.approx([0.550, 2.199, 3.222, 29.25], abs=5e-3)
+    lengths = [steps * (1 + arch.BACKWARD_OVER_FORWARD) * f
+               for f in forward]
+    assert lengths == pytest.approx([32.986, 131.944, 193.316, 1755.211],
+                                    abs=1e-3)
+    gap = np.mean(lengths) / 25
+    two_figures = round(gap, 1 - int(math.floor(math.log10(gap))))
+    assert jobs["job_interarrival_time_dist"]["val"] == two_figures == 21
+    assert cfg["max_simulation_run_time"] == pytest.approx(400 * two_figures)
+    assert [len(ops) for ops in costs] == [87] * 4
+    n_ops, n_deps = 174, 269
+    assert cfg["pad_obs_kwargs"] == {"max_nodes": 50 * -(-n_ops // 50),
+                                     "max_edges": 256 * -(-n_deps // 256)}
+    # what the header says of a forward pass: the eight latent cores and
+    # the branch (Router + Experts + the combine)
+    def share(ops, kinds):
+        return sum(arch.forward_time(o) for o in ops
+                   if o["op_type"] in kinds) / sum(
+            arch.forward_time(o) for o in ops)
+
+    assert [share(ops, ("LatentAttnCore",)) for ops in costs] \
+        == pytest.approx([0.155, 0.155, 0.423, 0.746], abs=5e-4)
+    branch = ("Router", "Experts", "ShortcutCombineResidual")
+    assert [share(ops, branch) for ops in costs] \
+        == pytest.approx([0.0754, 0.0754, 0.0515, 0.0227], abs=5e-5)
+    whole = arch.op_costs(longcat, 8192, 1, LONGCAT_CUT["layers"])
+    assert share(whole, branch) == pytest.approx(0.236, abs=5e-4)
+    # the one ragged row: the 8,192-token shape's norms and embedding at
+    # 11 quanta, rounded up to an even split
+    quantum, top = cfg["min_op_run_time_quantum"], cfg[
+        "max_partitions_per_op"]
+    ragged = [sorted({(o["op_type"], sip_ml_num_partitions(
+        arch.forward_time(o), quantum, top)) for o in ops
+        if sip_ml_num_partitions(arch.forward_time(o), quantum, top) < top})
+        for ops in costs]
+    assert ragged == [[("Embedding", 12), ("FinalNorm", 12),
+                       ("InputNorm", 12), ("PostAttnNorm", 12)], [], [], []]
+    assert min(arch.forward_time(o) for o in costs[0]) \
+        == pytest.approx(100.7e-6, abs=1e-7)
+    # a job's memory: parameter state + 2 x activations
+    state = 16 * sum(o["params"] for o in costs[0])
+    assert state == pytest.approx(375.9e9, abs=5e7)
+    jobs_gb = [(state + 2 * arch.ACT_BYTES
+                * sum(o["out_elems"] for o in ops)) / 1e9 for ops in costs]
+    assert jobs_gb == pytest.approx([399.7, 471.0, 471.0, 756.3], abs=0.05)
+    # the rest is env_mimo_32's
+    changed = {"jobs_config", "pad_obs_kwargs"}
+    assert {k: v for k, v in cfg.items() if k not in changed} \
+        == {k: v for k, v in base.items() if k not in changed}
+    for key in set(jobs) - {"architecture"}:
+        assert jobs[key] == base["jobs_config"][key], key
+
+
+@pytest.mark.parametrize("case", ["tiny", "longcat"])
+def test_the_four_longcat_shares_add_up_to_the_uncut_layer(longcat, case):
+    """Four pods hold a quarter of the FFN experts each: their expert
+    groups' FLOPs, parameters and outputs sum to the uncut layer's; the
+    identity pairs are WHOLE on every pod (a token's own device adds
+    them) and, like both attentions, both FFNs and the router, are the
+    uncut layer's own, counted once."""
+    config, seq_len, held = {"tiny": (TINY_LONGCAT_BUILT, 32, 2),
+                             "longcat": (longcat, 8192, 128)}[case]
+    layers = {"leading_dense": 0, "following": 1}
+    uncut = arch.op_costs(config, seq_len, 2, layers=layers)
+    share = arch.op_costs(config, seq_len, 2, layers=layers,
+                          experts_held=held)
+    assert [o["op_type"] for o in share] == [o["op_type"] for o in uncut]
+    assert 4 * held == config["n_routed_experts"]
+    T, H = seq_len * 2, config["hidden_size"]
+    k, E, Z = (config["moe_topk"], config["n_routed_experts"],
+               config["zero_expert_num"])
+    once = []
+    for a, b in zip(share, uncut):
+        if a["op_type"] == "Experts":
+            for key in ("flops", "params", "out_elems"):
+                assert 4 * a[key] == b[key], key
+            assert b["out_elems"] == T * k * E / (E + Z) * H
+        elif a["op_type"] == "ShortcutCombineResidual":
+            # the weighted sum runs over this pod's FFN pairs and ALL
+            # identity pairs; the residual is whole
+            ffn, zero = 2 * T * k * E / (E + Z) * H, 2 * T * k * Z / (
+                E + Z) * H
+            assert a["flops"] == ffn / 4 + zero + T * H
+            assert b["flops"] == ffn + zero + T * H
+            assert ffn + zero == 2 * T * k * H     # every routed pair
+        else:
+            assert a == b, a["op_type"]
+            once.append(a["op_type"])
+    assert (once.count("LatentAttnCore"), once.count("DenseMLPResidual"),
+            once.count("Router")) == (2, 2, 1)
+
+
+@pytest.mark.parametrize("family", ["olmoe", "glm", "longcat", "tiny"])
+def test_branch_time_share_is_what_runs_beside_the_longest_path(
+        tmp_path, family):
+    """`graphs.arch.branch_time_share.<model>` from the graph's edges
+    and times alone: 0 on OLMoE's chain (its extra edges are shortcuts),
+    GLM-5's known fan-outs and no more (the shorter low-rank path, the
+    indexer's projections, the shared expert, an MTP norm), and on the
+    double layer the expert branch on top of the shorter low-rank
+    path."""
+    def gauges(arch_file, shapes, **cut):
+        return {name[len("graphs.arch.branch_time_share."):]: value
+                for name, value in _arch_gauges(arch_file, shapes,
+                                                **cut).items()
+                if name.startswith("graphs.arch.branch_time_share.")}
+
+    def named(config, shape, cut, beside):
+        ops = arch.op_costs(config, *shape, **cut)
+        return sum(arch.forward_time(o) for o in ops
+                   if o["op_type"] in beside) / sum(
+            arch.forward_time(o) for o in ops)
+
+    if family == "olmoe":
+        assert gauges(OLMOE_FILE, [(4096, 1)]) == {"olmoe_s4096_b1": 0.0}
+    elif family == "glm":
+        got = gauges(GLM_FILE, [(8192, 1)], **GLM_CUT)[
+            "glm_moe_dsa_s8192_b1"]
+        config = arch.load_arch_config(GLM_FILE)
+        # at 8k the kv path and the indexer are the shorter arms, the
+        # shared expert runs beside Router -> Experts, one MTP norm
+        # beside the other
+        want = named(config, (8192, 1), GLM_CUT,
+                     ("KVAProj", "KVBProj", "IndexerProj",
+                      "IndexScoreTopK", "SharedExpert"))
+        assert want < got < want + 0.001 and got == pytest.approx(
+            0.1037, abs=1e-4)
+    elif family == "longcat":
+        got = gauges(LONGCAT_FILE, LONGCAT_SHAPES, **LONGCAT_CUT)
+        config = arch.load_arch_config(LONGCAT_FILE)
+        for shape in LONGCAT_SHAPES:
+            # Router -> Experts beside nine ops, and the kv path beside
+            # the longer q path; the combine is ON the path
+            want = named(config, shape, LONGCAT_CUT,
+                         ("Router", "Experts", "KVAProj", "KVBProj"))
+            model = "longcat_flash_s%d_b%d" % shape
+            assert got[model] == pytest.approx(want, rel=1e-12), model
+        assert [got["longcat_flash_s%d_b%d" % s] for s in LONGCAT_SHAPES] \
+            == pytest.approx([0.0954, 0.0954, 0.0652, 0.0287], abs=1e-4)
+    else:
+        got = gauges(_tiny_longcat_arch_file(tmp_path), [(32, 4096)],
+                     experts_held=4)
+        assert 0.05 < got["tinylongcat_s32_b4096"] < 0.5
+
+
+def test_generator_sets_the_longcat_gauges(tmp_path):
+    """`graphs.arch.{layers_latent,shortcut_branches,zero_experts,
+    branch_time_share,zero_routed_share}.<model>` and the bank's sums
+    beside `graphs.arch.models`, in the `[startup]` line."""
+    gauges = _arch_gauges(_tiny_longcat_arch_file(tmp_path),
+                          [(32, 4096), (32, 2 ** 19)], experts_held=4)
+    models = ("tinylongcat_s32_b4096", "tinylongcat_s32_b524288")
+    for m in models:
+        assert gauges[f"graphs.arch.forward_ops.{m}"] == 45
+        assert gauges[f"graphs.arch.edges.{m}"] == 2 * 68 + 1
+        assert gauges[f"graphs.arch.layers_latent.{m}"] == 4
+        assert gauges[f"graphs.arch.layers_full.{m}"] == 0
+        assert gauges[f"graphs.arch.shortcut_branches.{m}"] == 2
+        assert gauges[f"graphs.arch.zero_experts.{m}"] == 4
+        assert gauges[f"graphs.arch.zero_routed_share.{m}"] == 4 / 12
+        assert gauges[f"graphs.arch.quadratic_time_share.{m}"] > 0
+    shares = [gauges[f"graphs.arch.branch_time_share.{m}"] for m in models]
+    assert all(0.05 < s < 0.5 for s in shares)
+    assert [gauges[name] for name in BANK_GAUGES[1:]] == [
+        2, sum(shares), 2 * 4 / 12]
+    assert arch.zero_routed_share(arch.load_arch_config(LONGCAT_FILE)) \
+        == 256 / 768
+    assert arch.zero_routed_share(TINY_GLM) == 0.0

@@ -806,6 +806,11 @@ def test_listed_counters_are_the_parents_on_the_same_trace(
             int(np.asarray(et.tables["n_deps"])[row].sum()),
         "sim.lookahead.dep_slots_offered":
             int(ran.sum()) * int(et.pads.n_deps),
+        # PR 48's: the ops of each decided job, written out from the
+        # tables' degree-1 rows (an unsplit job's op slots)
+        "sim.lookahead.ops_decided":
+            int(np.asarray(et.tables["n_ops"])[
+                ep["jtype"][ran] * len(et.degrees) + column[1]].sum()),
         "env.obs.nodes_real":
             int(ot["node_split"][:, 0][ep["jtype"]].sum()),
         "env.obs.nodes_padded":
