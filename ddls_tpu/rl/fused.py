@@ -44,7 +44,7 @@ from ddls_tpu.sim.jax_env import (CAUSE_ACCEPTED, CAUSE_OP_PLACEMENT,
                                   MASK_GAUGES)
 from ddls_tpu.sim.jax_lookahead import (MINOR_GAUGES, channel_trips,
                                         stage_widths)
-from ddls_tpu.sim.jax_memo import MemoCounters
+from ddls_tpu.sim.jax_memo import TABLE_GAUGE, MemoCounters, table_bytes
 from ddls_tpu.telemetry import scopes, startup
 
 
@@ -351,6 +351,8 @@ class FusedEpochDriver(MemoCounters):
         # across fused_epoch calls like the collector's self._state
         self._state = jax.vmap(
             lambda b: segment_init(et, b, self.memo_cfg))(banks)
+        startup.set_gauge(TABLE_GAUGE, table_bytes(
+            self._state[1] if self.memo_cfg is not None else None))
         self._repl = repl
         if mesh is not None:
             # jax keys its jit cache on the MESH an input's sharding

@@ -1242,12 +1242,15 @@ def _episode_kernels(et: EpisodeTables):
         state: placement, dep pricing, channel check, lookahead, SLA —
         everything a decision needs, minus the commit. XLA dead-code
         eliminates the commit outputs when a caller (candidate pricing)
-        only reads (ok, jct). Returns ``(ev, memo)``; with ``memo`` (the
-        in-kernel lookahead memo table, sim/jax_memo.py) the lookahead is
-        probed under the host memo-key signature (cfg row, canonical
-        worker grouping, mounted dep times) and served from the table on
-        a bitwise full-key hit — memoised and recomputed results are
-        bit-identical by construction, any precision mode. ``discard``
+        only reads (ok, jct). Returns ``(ev, pending)``; with ``memo``
+        (the in-kernel lookahead memo table, sim/jax_memo.py) the
+        lookahead is probed under the host memo-key signature (cfg row,
+        canonical worker grouping, mounted dep times) and served from
+        the table on a bitwise full-key hit — memoised and recomputed
+        results are bit-identical by construction, any precision mode.
+        The table is only READ here: ``pending`` is the one entry the
+        probe would insert (`jax_memo.memo_probe`; None without a memo),
+        the caller's to `jax_memo.memo_commit`. ``discard``
         (bool) marks a lane whose result the caller will throw away, and
         a job that did not place has no lookahead to run (the host drops
         it before pricing): either joins the memo's hit mask in the
@@ -1295,12 +1298,12 @@ def _episode_kernels(et: EpisodeTables):
             return t_la, ok, trips
 
         if memo is None:
-            t_step, ok_la, trips = run_lookahead()
+            (t_step, ok_la, trips), pending = run_lookahead(), None
         else:
             with jax.named_scope(scopes.SIM_MEMO_PROBE):
                 groups = jax_memo.canonical_groups(
                     jnp.where(op_valid, ots, -1), op_valid)
-            (t_step, ok_la, trips), memo = jax_memo.memo_lookahead(
+            (t_step, ok_la, trips), pending = jax_memo.memo_probe(
                 memo, cfg, groups, times, run_lookahead, void)
         jct = t_step * steps
         max_jct = (bank["sla_frac"][row].astype(dt)
@@ -1312,7 +1315,7 @@ def _episode_kernels(et: EpisodeTables):
                 "new_mem": new_mem, "srv_mask": srv_mask,
                 "chan_mask": chan_mask, "la_trips": trips,
                 "la_rode": jnp.where(trips > 0, srv_mask.sum(),
-                                     0).astype(jnp.int32)}, memo
+                                     0).astype(jnp.int32)}, pending
 
     def price_all(bank, carry, row):
         """In-kernel candidate pricing: (placeable [n_deg], jct [n_deg])
@@ -1336,10 +1339,17 @@ def _episode_kernels(et: EpisodeTables):
 
     def decision(bank, carry, action, row, memo=None):
         """Decide one queued job; returns ``(carry', (reward, accept,
-        cause, jct, la_trips, la_rode), memo')``. ``la_trips`` (i32) is
-        the lookahead loop's own trip count for this decision: 0 on a
+        cause, jct, la_trips, la_rode), pending)``. ``la_trips`` (i32)
+        is the lookahead loop's own trip count for this decision: 0 on a
         memo hit and on an action that runs no lookahead; ``la_rode``
-        (i32) the servers the job rode where it ran trips, else 0."""
+        (i32) the servers the job rode where it ran trips, else 0.
+        ``memo`` is only READ: ``pending`` (None without a memo) is the
+        one entry this decision would insert, for the caller to
+        `jax_memo.memo_commit` once it stands under no ``lax.cond`` of
+        its own. NO ``cond`` returns a memo: under ``vmap`` a ``cond``
+        is both branches and a select over every output, and a table
+        among them was selected, and copied, whole on every lane-step
+        (2.2-2.9 GB over a cell's lanes; PERF.md section 6, PR 49)."""
         (t, mem, srv_job, chan_occ, slot_valid, slot_t_done, slot_mem,
          slot_servers, slot_chan) = carry
         dt = mem.dtype
@@ -1352,13 +1362,13 @@ def _episode_kernels(et: EpisodeTables):
         # another config row
         action_ok = (action > 0) & (deg_col[jnp.clip(action, 0)] >= 0)
 
-        def heavy(mm):
+        def heavy():
             # under vmap the cond below is a select and every lane runs
             # this branch: a lane on the zero path is masked out of the
             # lookahead loop, so the trips the batched loop executes are
             # the maximum over lanes whose result is used
-            ev, mm = eval_cfg(bank, carry, row, cfg, mm,
-                              discard=~action_ok)
+            ev, pending = eval_cfg(bank, carry, row, cfg, memo,
+                                   discard=~action_ok)
             accept = (ev["ok_place"] & ev["ok_chan"] & ev["sla_ok"]
                       & ev["engine_ok"])
             cause = jnp.where(
@@ -1369,16 +1379,16 @@ def _episode_kernels(et: EpisodeTables):
                                               CAUSE_ACCEPTED))))
             return (accept, cause.astype(jnp.int32), ev["jct"],
                     ev["new_mem"], ev["srv_mask"], ev["chan_mask"],
-                    ev["la_trips"], ev["la_rode"]), mm
+                    ev["la_trips"], ev["la_rode"]), pending
 
-        def zero(mm):
+        def zero():
             return (jnp.bool_(False), jnp.int32(CAUSE_NOT_HANDLED),
                     jnp.zeros((), dt), mem, jnp.zeros((n_srv,), bool),
                     jnp.zeros((n_chan,), bool), jnp.int32(0),
-                    jnp.int32(0)), mm
+                    jnp.int32(0)), jax_memo.memo_pending_none(memo)
 
         ((accept, cause, jct, new_mem, srv_mask, chan_mask, la_trips,
-          la_rode), memo) = jax.lax.cond(action_ok, heavy, zero, memo)
+          la_rode), pending) = jax.lax.cond(action_ok, heavy, zero)
 
         if scenario is not None:
             # inflate AFTER the accept/cause decision: admission is
@@ -1406,7 +1416,7 @@ def _episode_kernels(et: EpisodeTables):
         return ((t, mem2, srv_job2, chan_occ2, slot_valid2, slot_t_done2,
                  slot_mem2, slot_servers2, slot_chan2),
                 (reward.astype(dt), accept, cause, jct, la_trips, la_rode),
-                memo)
+                pending)
 
     def advance(bank, carry, queue_row, ptr, next_arrival, done,
                 completed):
@@ -1636,17 +1646,22 @@ def make_episode_fn(et: EpisodeTables,
             t = carry[0]
             has_job = (queue_row >= 0) & ~done
 
-            def run(mm):
-                new_carry, (reward, accept, cause, jct, *_), mm = decision(
-                    bank, carry, action, jnp.clip(queue_row, 0), mm)
-                return (new_carry, reward, accept, cause, jct), mm
+            # the cond hands out the decision's PENDING memo entry, the
+            # table never (`decision`); one commit after it
+            def run():
+                new_carry, (reward, accept, cause, jct, *_), pending = \
+                    decision(bank, carry, action, jnp.clip(queue_row, 0),
+                             memo)
+                return (new_carry, reward, accept, cause, jct), pending
 
-            def skip(mm):
+            def skip():
                 return (carry, jnp.zeros((), dt), jnp.bool_(False),
-                        jnp.int32(-1), jnp.zeros((), dt)), mm
+                        jnp.int32(-1), jnp.zeros((), dt)), \
+                    jax_memo.memo_pending_none(memo)
 
-            (new_carry, reward, accept, cause, jct), memo = jax.lax.cond(
-                has_job, run, skip, memo)
+            (new_carry, reward, accept, cause, jct), pending = \
+                jax.lax.cond(has_job, run, skip)
+            memo = jax_memo.memo_commit(memo, pending)
             accepted, blocked, ret = counters
             counters2 = (accepted + (has_job & accept),
                          blocked + (has_job & ~accept),
@@ -1899,9 +1914,11 @@ def make_policy_episode_fn(et: EpisodeTables, ot: dict, model,
             has_job = (queue_row >= 0) & ~done
             row = jnp.clip(queue_row, 0)
 
-            def run(mm):
+            def run():
                 # obs rebuild + GNN forward + sampling live INSIDE the
-                # cond so dead scan steps after episode end cost nothing
+                # cond so dead scan steps after episode end cost nothing;
+                # it hands out the decision's PENDING memo entry, the
+                # table never (`decision`): one commit after it
                 srv_job = carry[2]
                 slot_valid = carry[4]
                 price_feats = None
@@ -1940,19 +1957,21 @@ def make_policy_episode_fn(et: EpisodeTables, ot: dict, model,
                     action = jax.random.categorical(
                         step_rng, logits).astype(jnp.int32)
                 logp = jax.nn.log_softmax(logits)[action]
-                new_carry, (reward, accept, cause, jct, *_), mm = \
-                    k.decision(bank, carry, action, row, mm)
+                new_carry, (reward, accept, cause, jct, *_), pending = \
+                    k.decision(bank, carry, action, row, memo)
                 return (new_carry, action, logp, value, reward, accept,
-                        cause, jct), mm
+                        cause, jct), pending
 
-            def skip(mm):
+            def skip():
                 f32 = jnp.float32
                 return (carry, jnp.int32(0), f32(0.0), f32(0.0),
                         jnp.zeros((), dt), jnp.bool_(False),
-                        jnp.int32(-1), jnp.zeros((), dt)), mm
+                        jnp.int32(-1), jnp.zeros((), dt)), \
+                    jax_memo.memo_pending_none(memo)
 
             ((new_carry, action, logp, value, reward, accept, cause,
-              jct), memo) = jax.lax.cond(has_job, run, skip, memo)
+              jct), pending) = jax.lax.cond(has_job, run, skip)
+            memo = jax_memo.memo_commit(memo, pending)
             accepted, blocked, ret = counters
             counters2 = (accepted + (has_job & accept),
                          blocked + (has_job & ~accept),
@@ -2114,7 +2133,8 @@ def make_segment_fn(et: EpisodeTables, ot: dict, model, n_steps: int,
             logp = jax.nn.log_softmax(logits)[action]
 
             (new_carry, (reward, accept, cause, jct, la_trips, la_rode),
-             memo) = k.decision(bank, carry, action, row, memo)
+             pending) = k.decision(bank, carry, action, row, memo)
+            memo = jax_memo.memo_commit(memo, pending)
             accepted, blocked, ret = counters
             # unlike the policy-episode kernel these counters need no
             # has_job guard: every segment step has a queued job by
@@ -2264,7 +2284,9 @@ def make_oracle_episode_fn(et: EpisodeTables, ot: dict,
             has_job = (queue_row >= 0) & ~done
             row = jnp.clip(queue_row, 0)
 
-            def run(mm):
+            # the cond hands out the decision's PENDING memo entry, the
+            # table never (`decision`); one commit after it
+            def run():
                 srv_job = carry[2]
                 # the obs action mask restricted to the degree columns
                 mask = _kernel_action_mask(
@@ -2302,18 +2324,20 @@ def make_oracle_episode_fn(et: EpisodeTables, ot: dict,
                     jnp.where(best_deg >= 0, best_deg, first_valid)
                 ).astype(jnp.int32)
 
-                new_carry, (reward, accept, cause, jct, *_), mm = \
-                    k.decision(bank, carry, action, row, mm)
+                new_carry, (reward, accept, cause, jct, *_), pending = \
+                    k.decision(bank, carry, action, row, memo)
                 return (new_carry, action, reward, accept, cause,
-                        jct), mm
+                        jct), pending
 
-            def skip(mm):
+            def skip():
                 return (carry, jnp.int32(0), jnp.zeros((), dt),
                         jnp.bool_(False), jnp.int32(-1),
-                        jnp.zeros((), dt)), mm
+                        jnp.zeros((), dt)), \
+                    jax_memo.memo_pending_none(memo)
 
             ((new_carry, action, reward, accept, cause, jct),
-             memo) = jax.lax.cond(has_job, run, skip, memo)
+             pending) = jax.lax.cond(has_job, run, skip)
+            memo = jax_memo.memo_commit(memo, pending)
             accepted, blocked, ret = counters
             counters2 = (accepted + (has_job & accept),
                          blocked + (has_job & ~accept),

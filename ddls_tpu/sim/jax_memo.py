@@ -204,33 +204,47 @@ def _bits(x):
     return b.reshape(x.shape[:-1] + (-1,)) if b.ndim > x.ndim else b
 
 
-def memo_lookahead(memo: dict, cfg, groups, times,
-                   compute: Callable[..., Tuple], void=None):
-    """Probe-or-compute one lookahead under the memo key (cfg, groups,
-    times); returns ``((t, ok, *extra), memo')`` — whatever ``compute``
-    returns beyond ``(t, ok)`` (the loop's trip count, 0 on a masked
-    hit lane) passes through untouched. ``void`` (bool, optional) marks
-    a probe whose key does not determine the engine's inputs (a job
-    that did not place: the key holds the placed ops alone) or whose
-    result nobody reads: it counts as neither hit nor miss and inserts
-    nothing; what it returns is the caller's to throw away.
+#: the leaves of a probe's PENDING ENTRY (:func:`memo_probe`): the one
+#: row a lane-step would insert and the flags that gate it — what comes
+#: out of a decision's ``lax.cond`` in the table's place
+PENDING_KEYS = ("set_idx", "cfg", "groups", "times", "t", "ok", "hit",
+                "miss")
 
-    Probe (batched — the wide-vmap form, ISSUE 17): hash the key onto a
-    set, compare the FULL residual bitwise against every way, gather the
-    matching way's stored value, then call ``compute(hit)`` — the
-    caller must thread the flag into the lookahead while_loop's cond
-    (``jax_lookahead(..., skip=hit)``; :data:`WIDE_PROBE_SURFACE`) so a
-    hit lane exits before its first iteration — and where-select the
-    stored value over the (garbage) masked-out result. At lanes=1 a hit
-    costs one cond evaluation; under a multi-lane vmap the loop trips
-    to the max count over MISS lanes only. Miss: the computed (key,
-    value) is inserted at the set's round-robin way (deterministic
-    eviction — same decision stream, same table, every run; per-lane
-    ``.at[].set`` writes scatter back through vmap batching)."""
+
+def memo_pending_none(memo: Optional[dict]) -> Optional[dict]:
+    """The pending entry of a lane-step that probed nothing (the zero
+    path, ``has_job`` false): zeros with ``hit`` and ``miss`` false, so
+    :func:`memo_commit` rewrites set 0's round-robin way with what it
+    holds and adds 0 to every counter — every leaf stays bit-equal.
+    None where the memo is off (``memo`` None)."""
+    import jax.numpy as jnp
+
+    if memo is None:
+        return None
+    i32 = jnp.zeros((), jnp.int32)
+    no = jnp.zeros((), bool)
+    return {"set_idx": i32, "cfg": i32,
+            "groups": jnp.zeros(memo["key_groups"].shape[-1:], jnp.int32),
+            "times": jnp.zeros(memo["key_times"].shape[-1:],
+                               memo["key_times"].dtype),
+            "t": jnp.zeros((), memo["val_t"].dtype), "ok": no,
+            "hit": no, "miss": no}
+
+
+def memo_probe(memo: dict, cfg, groups, times,
+               compute: Callable[..., Tuple], void=None):
+    """The memo's READING half: probe-or-compute one lookahead under the
+    key (cfg, groups, times); returns ``((t, ok, *extra), pending)`` and
+    writes nothing. ``pending`` (:data:`PENDING_KEYS`) is the one entry
+    :func:`memo_commit` would insert, row-sized, so a caller under a
+    ``lax.cond`` hands IT out of the branch and never the tables (under
+    ``vmap`` a ``cond`` is both branches and a select over every
+    output: a table among them is selected, and copied, whole). The
+    contract is :func:`memo_lookahead`'s."""
     import jax
     import jax.numpy as jnp
 
-    S, W = memo["key_cfg"].shape
+    S = memo["key_cfg"].shape[0]
     n_groups = memo["key_groups"].shape[-1]
 
     with jax.named_scope(scopes.SIM_MEMO_PROBE):
@@ -269,11 +283,32 @@ def memo_lookahead(memo: dict, cfg, groups, times,
     with jax.named_scope(scopes.SIM_MEMO_PROBE):
         t = jnp.where(hit, memo["val_t"][set_idx, way_hit], t_c)
         ok = jnp.where(hit, memo["val_ok"][set_idx, way_hit], ok_c)
+    pending = {"set_idx": set_idx, "cfg": cfg,
+               "groups": groups.astype(jnp.int32), "times": times,
+               "t": t, "ok": ok, "hit": hit, "miss": miss}
+    return (t, ok, *extra), pending
 
-        # miss insert: round-robin way per set; the write is a pair of
-        # where-gated dynamic-update-slices, cheap either way (and dead
-        # on the hit path only in the sense that it rewrites identical
-        # state)
+
+def memo_commit(memo: Optional[dict],
+                pending: Optional[dict]) -> Optional[dict]:
+    """The memo's WRITING half: insert a probe's ``pending`` entry where
+    it missed, at its set's round-robin way (deterministic eviction —
+    same decision stream, same table, every run; per-lane ``.at[].set``
+    writes scatter back through vmap batching), and count the probe.
+    The write is a handful of where-gated row updates, cheap either way
+    (on a hit, a void probe and :func:`memo_pending_none` it rewrites
+    identical state). Called OUTSIDE every ``lax.cond``, on the carried
+    table itself, so the scatter lands in place. None where the memo
+    is off (``memo`` None)."""
+    import jax
+    import jax.numpy as jnp
+
+    if memo is None:
+        return None
+    W = memo["key_cfg"].shape[1]
+    set_idx, hit, miss = pending["set_idx"], pending["hit"], pending["miss"]
+
+    with jax.named_scope(scopes.SIM_MEMO_PROBE):
         way_ins = memo["rr"][set_idx] % jnp.int32(W)
         evict = miss & (memo["key_cfg"][set_idx, way_ins] >= 0)
 
@@ -281,18 +316,64 @@ def memo_lookahead(memo: dict, cfg, groups, times,
             old = arr[set_idx, way_ins]
             return arr.at[set_idx, way_ins].set(jnp.where(miss, val, old))
 
-        memo = {
-            "key_cfg": upd(memo["key_cfg"], cfg),
-            "key_groups": upd(memo["key_groups"], groups),
-            "key_times": upd(memo["key_times"], times),
-            "val_t": upd(memo["val_t"], t),
-            "val_ok": upd(memo["val_ok"], ok),
+        return {
+            "key_cfg": upd(memo["key_cfg"], pending["cfg"]),
+            "key_groups": upd(memo["key_groups"], pending["groups"]),
+            "key_times": upd(memo["key_times"], pending["times"]),
+            "val_t": upd(memo["val_t"], pending["t"]),
+            "val_ok": upd(memo["val_ok"], pending["ok"]),
             "rr": memo["rr"].at[set_idx].add(miss.astype(jnp.int32)),
             "hits": memo["hits"] + hit.astype(jnp.int32),
             "misses": memo["misses"] + miss.astype(jnp.int32),
             "evicts": memo["evicts"] + evict.astype(jnp.int32),
         }
-    return (t, ok, *extra), memo
+
+
+def memo_lookahead(memo: dict, cfg, groups, times,
+                   compute: Callable[..., Tuple], void=None):
+    """Probe-or-compute one lookahead under the memo key (cfg, groups,
+    times); returns ``((t, ok, *extra), memo')`` — whatever ``compute``
+    returns beyond ``(t, ok)`` (the loop's trip count, 0 on a masked
+    hit lane) passes through untouched. ``void`` (bool, optional) marks
+    a probe whose key does not determine the engine's inputs (a job
+    that did not place: the key holds the placed ops alone) or whose
+    result nobody reads: it counts as neither hit nor miss and inserts
+    nothing; what it returns is the caller's to throw away.
+
+    :func:`memo_probe` then :func:`memo_commit`, for a caller that
+    stands under no ``lax.cond`` (one that does commits after it: NO
+    ``cond`` returns a memo).
+
+    Probe (batched — the wide-vmap form, ISSUE 17): hash the key onto a
+    set, compare the FULL residual bitwise against every way, gather the
+    matching way's stored value, then call ``compute(hit)`` — the
+    caller must thread the flag into the lookahead while_loop's cond
+    (``jax_lookahead(..., skip=hit)``; :data:`WIDE_PROBE_SURFACE`) so a
+    hit lane exits before its first iteration — and where-select the
+    stored value over the (garbage) masked-out result. At lanes=1 a hit
+    costs one cond evaluation; under a multi-lane vmap the loop trips
+    to the max count over MISS lanes only. Miss: the computed (key,
+    value) is inserted at the set's round-robin way (deterministic
+    eviction — same decision stream, same table, every run; per-lane
+    ``.at[].set`` writes scatter back through vmap batching)."""
+    result, pending = memo_probe(memo, cfg, groups, times, compute, void)
+    return result, memo_commit(memo, pending)
+
+
+#: start-up gauge (`table_bytes`): what the lanes' memo tables hold on
+#: the device. The epoch program's scratch over it reads under a half
+#: (the program's other temporaries) while the tables are updated in
+#: place; near 1, a whole copy of them is back
+#: (a ``cond`` or a select that returns a memo: `memo_probe`)
+TABLE_GAUGE = "sim.memo.table_bytes"
+
+
+def table_bytes(memo: Optional[dict]) -> int:
+    """Bytes of a carried (possibly lane-stacked) memo state, 0 with the
+    memo off: its leaves' shapes, no trace and no fetch."""
+    import jax
+
+    return sum(x.nbytes for x in jax.tree_util.tree_leaves(memo))
 
 
 def memo_trace_counters(memo: dict) -> dict:

@@ -6,6 +6,21 @@ from typing import List, Sequence
 import numpy as np
 
 
+def equations(jaxpr, skip: Sequence[str] = ()):
+    """Every equation of ``jaxpr``, nested jaxprs included (a ``scan``'s
+    body, a ``cond``'s branches, a ``pjit``'s callee); equations of the
+    primitives in ``skip`` are left out, bodies and all."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in skip:
+            continue
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from equations(inner, skip)
+
+
 def indexed_ops(jaxpr, n_indices: int, skip: Sequence[str] = ()
                 ) -> List[str]:
     """The gather / scatter equations of ``jaxpr`` (nested jaxprs
@@ -14,17 +29,7 @@ def indexed_ops(jaxpr, n_indices: int, skip: Sequence[str] = ()
     each such vector is one serial address computation, so an equation
     of that many is a loop over them whatever else the program does. A
     row read of a table (one index, a row-long slice) is not one."""
-    found = []
-    for eqn in jaxpr.eqns:
-        name = eqn.primitive.name
-        if name in skip:
-            continue
-        if name == "gather" or name.startswith("scatter"):
-            if int(np.prod(eqn.invars[1].aval.shape[:-1])) >= n_indices:
-                found.append(name)
-        for value in eqn.params.values():
-            for sub in value if isinstance(value, (tuple, list)) else (value,):
-                inner = getattr(sub, "jaxpr", sub)
-                if hasattr(inner, "eqns"):
-                    found += indexed_ops(inner, n_indices, skip)
-    return found
+    return [eqn.primitive.name for eqn in equations(jaxpr, skip)
+            if (eqn.primitive.name == "gather"
+                or eqn.primitive.name.startswith("scatter"))
+            and int(np.prod(eqn.invars[1].aval.shape[:-1])) >= n_indices]

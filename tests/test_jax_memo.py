@@ -181,6 +181,113 @@ def test_canonical_groups_matches_host_canonicalisation():
         assert np.array_equal(got, want), (sc, valid, got, want)
 
 
+#: a probe stream that misses, hits, evicts and voids at every geometry
+#: below: (key seed, void) a step; a void probe of a resident key, of an
+#: absent one, and a key that comes back after its eviction
+_STREAM = ((1, False), (1, False), (2, False), (1, False), (3, True),
+           (3, False), (3, False), (4, False), (2, True), (5, False),
+           (1, False), (6, False), (2, False), (5, False), (7, True),
+           (1, False))
+
+
+@pytest.mark.parametrize("lanes", [0, 3])
+@pytest.mark.parametrize("n_sets,n_ways", [(1, 1), (2, 2), (64, 2)])
+def test_probe_then_commit_is_the_one_function_memo_leaf_for_leaf(
+        n_sets, n_ways, lanes):
+    """`memo_probe` + `memo_commit` against the form the package had up
+    to PR 48 (`tests/onefn_memo.py`, which returned the tables from the
+    probe's own body): the value served, every table leaf, `rr` and
+    the three counters equal after EVERY step of a stream that misses,
+    hits, evicts and voids — unbatched (``lanes`` 0) and under a
+    3-lane ``vmap`` whose lanes probe different keys. Only where the
+    write is issued moved."""
+    import jax
+    import jax.numpy as jnp
+
+    import onefn_memo
+    from ddls_tpu.sim import jax_memo
+
+    et = _EtStub()
+    memo0 = jax_memo.memo_init(et, jax_memo.MemoConfig(n_sets, n_ways))
+
+    def split(memo, cfg, groups, times, value, void):
+        (t, ok), pending = jax_memo.memo_probe(
+            memo, cfg, groups, times, lambda skip: (value, value > 2),
+            void)
+        assert tuple(pending) == jax_memo.PENDING_KEYS
+        return (t, ok), jax_memo.memo_commit(memo, pending)
+
+    def package(memo, cfg, groups, times, value, void):
+        return jax_memo.memo_lookahead(
+            memo, cfg, groups, times, lambda skip: (value, value > 2),
+            void)
+
+    def reference(memo, cfg, groups, times, value, void):
+        return onefn_memo.memo_lookahead(
+            memo, cfg, groups, times, lambda skip: (value, value > 2),
+            void)
+
+    forms = [split, package, reference]
+    if lanes:
+        memo0 = jax.tree_util.tree_map(
+            lambda x: jnp.stack([x] * lanes), memo0)
+        forms = [jax.vmap(f) for f in forms]
+    forms = [jax.jit(f) for f in forms]
+    memos = [memo0] * len(forms)
+    for step, (seed, void) in enumerate(_STREAM):
+        if lanes:
+            # lane l walks the stream l steps ahead, so one batched
+            # scatter holds hits, misses and voids side by side
+            rows = [_STREAM[(step + lane) % len(_STREAM)]
+                    for lane in range(lanes)]
+            keys = [_key(sd) for sd, _ in rows]
+            args = (jnp.stack([k[0] for k in keys]),
+                    jnp.stack([k[1] for k in keys]),
+                    jnp.stack([k[2] for k in keys]),
+                    jnp.full((lanes,), step + 0.5, jnp.float32),
+                    jnp.asarray([v for _, v in rows]))
+        else:
+            args = (*_key(seed), jnp.float32(step + 0.5),
+                    jnp.bool_(void))
+        outs = [f(m, *args) for f, m in zip(forms, memos)]
+        memos = [m for _, m in outs]
+        (t_ref, ok_ref), memo_ref = outs[-1]
+        for (t, ok), memo in outs[:-1]:
+            assert np.array_equal(np.asarray(t), np.asarray(t_ref)), step
+            assert np.array_equal(np.asarray(ok), np.asarray(ok_ref))
+            assert tuple(memo) == tuple(memo_ref)
+            for leaf in memo_ref:
+                assert np.array_equal(np.asarray(memo[leaf]),
+                                      np.asarray(memo_ref[leaf])), (
+                    step, leaf)
+    seen = {k: int(np.sum(np.asarray(memos[0][k])))
+            for k in jax_memo.COUNTER_KEYS}
+    assert seen["hits"] > 0 and seen["misses"] > 0, seen
+    if n_sets < 64:
+        assert seen["evicts"] > 0, seen
+    # a void probe counted nowhere
+    voids = sum(v for _, v in _STREAM) * max(lanes, 1)
+    assert (seen["hits"] + seen["misses"]
+            == len(_STREAM) * max(lanes, 1) - voids)
+
+
+def test_table_bytes_is_the_leaves_own_bytes():
+    """`sim.memo.table_bytes`' arithmetic: every leaf of the carried
+    (lane-stacked) state, from its shape; 0 with the memo off."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddls_tpu.sim.jax_memo import MemoConfig, memo_init, table_bytes
+
+    memo = memo_init(_EtStub(n_ops=4, n_deps=6), MemoConfig(8, 2))
+    one = (8 * 2 * (4 + 4 * 4 + 6 * 4 + 4 + 1)   # keys and values
+           + 8 * 4 + 3 * 4)                       # rr, three counters
+    assert table_bytes(memo) == one
+    stacked = jax.tree_util.tree_map(lambda x: jnp.stack([x] * 5), memo)
+    assert table_bytes(stacked) == 5 * one
+    assert table_bytes(None) == 0
+
+
 def test_memo_knob_rejected_loudly_without_device_collection():
     """Forcing the knob on a host-collection loop must fail before any
     env construction (the loud-rejection convention: a silent no-op
@@ -371,6 +478,170 @@ def _lane_banks(memo_env, n_lanes):
         banks.append({k: jnp.asarray(v)
                       for k, v in build_job_bank(et, recs).items()})
     return {k: jnp.stack([b[k] for b in banks]) for k in banks[0]}
+
+
+def _free_and_full_carries(memo_env):
+    """The kernel's fresh cluster state, and the same with every server
+    held by a running job: nothing places there."""
+    import jax.numpy as jnp
+
+    from ddls_tpu.sim.jax_env import _episode_kernels
+
+    k = _episode_kernels(memo_env["et"])
+    free = k.init_state(memo_env["bank"])[0]
+    full = (*free[:2], jnp.zeros_like(free[2]), *free[3:])
+    return k, free, full
+
+
+@pytest.mark.parametrize("lane", ["zero_path", "void", "no_job"])
+def test_a_lane_that_inserts_nothing_leaves_its_memo_bit_equal(
+        memo_env, lane):
+    """The three lanes whose pending entry has ``miss`` false — an
+    action on the zero path (`memo_pending_none` out of the decision's
+    ``cond``), a job that did not place (a void probe) and a scan step
+    with no queued job (`memo_pending_none` out of the episode
+    kernels' ``cond``) — beside a lane that misses, in ONE batched
+    commit: the first lane's every leaf and counter stay bit-equal,
+    the second's table takes its entry."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddls_tpu.sim import jax_env, jax_memo
+
+    k, free, full = _free_and_full_carries(memo_env)
+    bank = memo_env["bank"]
+    row = jnp.int32(0)
+    degrees = memo_env["et"].degrees
+    first, second = int(degrees[-1]), int(degrees[-2])
+
+    def lane_step(memo, carry, action, has_job):
+        # the episode kernels' form: the pending entry out of the cond,
+        # one commit after it
+        def run():
+            _, outs, pending = k.decision(bank, carry, action, row, memo)
+            return outs[2], pending
+
+        def skip():
+            return jnp.int32(-1), jax_memo.memo_pending_none(memo)
+
+        cause, pending = jax.lax.cond(has_job, run, skip)
+        return cause, jax_memo.memo_commit(memo, pending)
+
+    # a table that already holds an entry, so "unchanged" is not "empty"
+    memo0 = jax_memo.memo_init(memo_env["et"], jax_memo.MemoConfig(2, 1))
+    cause, memo1 = jax.jit(lane_step)(memo0, free, jnp.int32(first),
+                                      jnp.bool_(True))
+    assert int(cause) != jax_env.CAUSE_OP_PLACEMENT
+    assert int(memo1["misses"]) == 1
+
+    carry, action, has_job, want = {
+        "zero_path": (free, 0, True, jax_env.CAUSE_NOT_HANDLED),
+        "void": (full, first, True, jax_env.CAUSE_OP_PLACEMENT),
+        "no_job": (free, first, False, -1)}[lane]
+    stack = lambda a, b: jax.tree_util.tree_map(   # noqa: E731
+        lambda x, y: jnp.stack([x, y]), a, b)
+    causes, memos = jax.jit(jax.vmap(lane_step))(
+        stack(memo1, memo1), stack(carry, free),
+        jnp.asarray([action, second], jnp.int32),
+        jnp.asarray([has_job, True]))
+    assert int(causes[0]) == want
+    for leaf in memo1:
+        assert np.array_equal(np.asarray(memos[leaf][0]),
+                              np.asarray(memo1[leaf])), leaf
+    # the neighbour missed (another degree: another key) and its entry
+    # went in through the same batched scatter
+    assert int(memos["misses"][1]) == 2
+    assert int(memos["hits"][1]) == 0
+
+
+def _memo_shaped(jaxpr, shapes):
+    """The ``cond`` equations of ``jaxpr`` (nested jaxprs included) with
+    an output of one of ``shapes``, and the ``select_n`` equations with
+    one that CHOOSE: ``vmap``'s rule for a ``cond`` also wraps every
+    operand the branches read in ``select_n(pred, stop_gradient(x),
+    x)`` — both cases the one array, which the compiler folds to ``x``
+    — and those are not selects of anything."""
+    from ddls_tpu.utils.jaxprs import equations
+
+    found, same = [], {}
+    for eqn in equations(jaxpr):
+        name = eqn.primitive.name
+        if name == "stop_gradient":
+            same[eqn.outvars[0]] = same.get(eqn.invars[0], eqn.invars[0])
+        chooses = name == "cond" or (
+            name == "select_n"
+            and len({same.get(v, v) for v in eqn.invars[1:]}) > 1)
+        if chooses:
+            found += [(name, v.aval.shape) for v in eqn.outvars
+                      if v.aval.shape in shapes]
+    return found
+
+
+@pytest.mark.parametrize("lanes", [1, 3])
+@pytest.mark.parametrize("kernel", ["segment", "episode",
+                                    "policy_episode", "oracle_episode"])
+def test_no_cond_and_no_select_returns_a_memo_table(memo_env, kernel,
+                                                    lanes):
+    """NO ``cond`` returns a memo (PR 49): the traced program of each
+    of the four kernels — lane-batched as the fused driver, `es_device`
+    and the policy-episode collector run them, and at one lane, where
+    the ``cond`` stays a branch — holds no ``select_n`` and no ``cond``
+    with an output of a table leaf's shape. Under ``vmap`` a ``cond``
+    that returned the tables was both branches and a select over them,
+    whole, and a copy for the select to read: two passes over 2.2-2.9
+    GB an epoch's lanes on the chip. (On the parent every case fails:
+    six leaves out of the decision's ``cond``, six more out of an
+    episode kernel's ``has_job`` one.)"""
+    import jax
+    import jax.numpy as jnp
+
+    from ddls_tpu.sim import jax_env, jax_memo
+    from ddls_tpu.utils.jaxprs import equations
+
+    et, ot, params = memo_env["et"], memo_env["ot"], memo_env["params"]
+    model = memo_env["model"]
+    # a geometry no other array of the program shares
+    mc = jax_memo.MemoConfig(n_sets=37, n_ways=3)
+    banks = _lane_banks(memo_env, lanes)
+    rngs = jax.random.split(jax.random.PRNGKey(0), lanes)
+    if kernel == "segment":
+        fn = jax_env.make_segment_fn(et, ot, model, 2, memo_cfg=mc)
+        states = jax.vmap(lambda b: jax_env.segment_init(et, b, mc))(
+            banks)
+        axes, args = (0, None, 0, 0), (banks, params, states, rngs)
+    elif kernel == "episode":
+        fn = jax_env.make_episode_fn(et, memo_cfg=mc)
+        axes = (0, 0)
+        args = (banks, jnp.ones((lanes, 3), jnp.int32))
+    elif kernel == "policy_episode":
+        fn = jax_env.make_policy_episode_fn(et, ot, model, memo_cfg=mc)
+        axes, args = (0, None, 0), (banks, params, rngs)
+    else:
+        fn = jax_env.make_oracle_episode_fn(et, ot, memo_cfg=mc)
+        axes, args = (0,), (banks,)
+    if lanes == 1:
+        # one lane, no vmap: `vmap_segment_fn`'s squeeze
+        take = lambda t: jax.tree_util.tree_map(   # noqa: E731
+            lambda x: x[0], t)
+        args = tuple(a if ax is None else take(a)
+                     for a, ax in zip(args, axes))
+        traced = jax.make_jaxpr(fn)(*args)
+        lead = ()
+    else:
+        traced = jax.make_jaxpr(jax.vmap(fn, in_axes=axes))(*args)
+        lead = (lanes,)
+    table = jax_memo.memo_init(et, mc)
+    shapes = {lead + table[leaf].shape
+              for leaf in ("key_cfg", "key_groups", "key_times", "val_t",
+                           "val_ok", "rr")}
+    assert len(shapes) == 4
+    # the tables ARE in the program (a scatter writes each) ...
+    written = {v.aval.shape for eqn in equations(traced.jaxpr)
+               if eqn.primitive.name.startswith("scatter")
+               for v in eqn.outvars}
+    assert shapes <= written, shapes - written
+    # ... and no select and no branch hands one out
+    assert _memo_shaped(traced.jaxpr, shapes) == []
 
 
 @pytest.mark.parametrize("n_lanes", [2, 8])

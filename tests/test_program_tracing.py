@@ -218,9 +218,10 @@ def test_an_unplaced_job_runs_no_trips_and_stays_out_of_the_memo(memo_env):
 
     @jax.jit
     def probe(mem, memo):
-        ev, memo = k.eval_cfg(bank, (carry[0], mem) + carry[2:], row, cfg,
-                              memo)
-        return ev["ok_place"], ev["la_trips"], memo
+        ev, pending = k.eval_cfg(bank, (carry[0], mem) + carry[2:], row,
+                                 cfg, memo)
+        return (ev["ok_place"], ev["la_trips"],
+                jax_memo.memo_commit(memo, pending))
 
     ok, trips, memo = probe(jnp.zeros_like(carry[1]), memo0)
     assert not bool(ok) and int(trips) == 0
@@ -256,10 +257,11 @@ def test_a_placement_that_stops_short_never_answers_the_complete_one(
     @jax.jit
     def probe(n_servers, memo):
         mem = jnp.where(jnp.arange(et.n_srv) < n_servers, unit, 0.0)
-        ev, memo = k.eval_cfg(
+        ev, pending = k.eval_cfg(
             bank, (carry[0], mem.astype(carry[1].dtype)) + carry[2:], row,
             cfg, memo)
-        return (ev["ok_place"], ev["engine_ok"], ev["jct"]), memo
+        return ((ev["ok_place"], ev["engine_ok"], ev["jct"]),
+                jax_memo.memo_commit(memo, pending))
 
     enough = next(m for m in range(1, et.n_srv + 1)
                   if bool(probe(m, memo0)[0][0]))
@@ -938,6 +940,10 @@ def test_build_run_leaves_each_startup_span_once(fused_dataset, tmp_path,
         minibatch = min(loop.learner.cfg.sgd_minibatch_size, 4 * 2)
         incidence = minibatch * carried[0] * carried[1]
         offered = gauges["env.mask.rows_offered"]
+        # the arithmetic is tests/test_jax_memo.py's
+        table_bytes = sum(
+            leaf.nbytes for leaf in loop.fused._state[1].values())
+        assert table_bytes > loop.fused.num_lanes * 64 * 2 * pads.n_deps * 4
         assert offered == len(loop.fused.et.types) * sum(
             bool(loop.fused.ot["shapes_exist"][d]) or d == 1
             for d in loop.fused.et.degrees)
@@ -955,6 +961,10 @@ def test_build_run_leaves_each_startup_span_once(fused_dataset, tmp_path,
                 pads.max_split * 8 * minor + 8 * 8 * minor),
             "sim.price.dep_indexed_ops": 0,
             "sim.allocate.indexed_ops": 0,
+            # the lanes' memo tables, from their shapes: what the epoch
+            # program's scratch is read against (under a half of it:
+            # updated in place; near 1: a copy of the tables is back)
+            "sim.memo.table_bytes": table_bytes,
             "env.mask.rows_offered": offered,
             "env.mask.rows_placeable": offered,
             **dict(zip(jax_env.OBS_PAD_GAUGES, carried + configured)),
