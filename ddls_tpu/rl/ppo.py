@@ -275,12 +275,14 @@ class PPOLearner:
 
     # ----------------------------------------------------------- update
     def _minibatch_step(self, state, mb):
-        grad_fn = jax.value_and_grad(ppo_loss, has_aux=True)
-        (_, metrics), grads = grad_fn(state.params, self.apply_fn, mb,
-                                      state.kl_coeff, self.cfg)
-        updates, opt_state = self.tx.update(grads, state.opt_state,
-                                            state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(scopes.PPO_GRAD):
+            grad_fn = jax.value_and_grad(ppo_loss, has_aux=True)
+            (_, metrics), grads = grad_fn(state.params, self.apply_fn, mb,
+                                          state.kl_coeff, self.cfg)
+        with jax.named_scope(scopes.PPO_APPLY):
+            updates, opt_state = self.tx.update(grads, state.opt_state,
+                                                state.params)
+            params = optax.apply_updates(state.params, updates)
         state = state.replace(params=params, opt_state=opt_state,
                               step=state.step + 1)
         return state, metrics
@@ -296,12 +298,6 @@ class PPOLearner:
         epoch is dropped, as in standard JAX PPO implementations.
         """
         cfg = self.cfg
-        advs, targets = compute_gae(traj["rewards"], traj["values"],
-                                    traj["dones"], last_values,
-                                    cfg.gamma, cfg.gae_lambda)
-        if cfg.normalize_advantages:
-            advs = (advs - advs.mean()) / (advs.std() + 1e-8)
-
         T, B = traj["rewards"].shape
         n = T * B
         D = self.mesh.shape["dp"]  # static; B % D enforced by shard_batch
@@ -314,14 +310,20 @@ class PPOLearner:
             x = jnp.swapaxes(x, 0, 1)  # [B, T, ...]
             return x.reshape((D, n_loc) + x.shape[2:])
 
-        flat = {
-            "obs": jax.tree_util.tree_map(to_rows, traj["obs"]),
-            "actions": to_rows(traj["actions"]),
-            "old_logp": to_rows(traj["logp"]),
-            "old_values": to_rows(traj["values"]),
-            "advantages": to_rows(advs),
-            "value_targets": to_rows(targets),
-        }
+        with jax.named_scope(scopes.PPO_SHUFFLE):
+            advs, targets = compute_gae(traj["rewards"], traj["values"],
+                                        traj["dones"], last_values,
+                                        cfg.gamma, cfg.gae_lambda)
+            if cfg.normalize_advantages:
+                advs = (advs - advs.mean()) / (advs.std() + 1e-8)
+            flat = {
+                "obs": jax.tree_util.tree_map(to_rows, traj["obs"]),
+                "actions": to_rows(traj["actions"]),
+                "old_logp": to_rows(traj["logp"]),
+                "old_values": to_rows(traj["values"]),
+                "advantages": to_rows(advs),
+                "value_targets": to_rows(targets),
+            }
         # each minibatch takes mb_loc samples from every device's shard, so
         # shuffling happens per shard (a batched local gather) rather than
         # as a global permutation that would all-gather the whole batch
@@ -331,9 +333,6 @@ class PPOLearner:
         num_mb = n_loc // mb_loc
 
         def epoch(state, erng):
-            perms = jax.vmap(lambda k: jax.random.permutation(k, n_loc))(
-                jax.random.split(erng, D))
-
             def shuffle(x):
                 # drop the remainder of each shard so the minibatch grid is
                 # exact (num_mb * mb_loc <= n_loc)
@@ -342,23 +341,29 @@ class PPOLearner:
                 x = jnp.swapaxes(x, 0, 1)  # [num_mb, D, mb_loc, ...]
                 return x.reshape((num_mb, D * mb_loc) + x.shape[3:])
 
-            mbs = jax.tree_util.tree_map(shuffle, flat)
+            with jax.named_scope(scopes.PPO_SHUFFLE):
+                perms = jax.vmap(
+                    lambda k: jax.random.permutation(k, n_loc))(
+                        jax.random.split(erng, D))
+                mbs = jax.tree_util.tree_map(shuffle, flat)
             state, ms = jax.lax.scan(self._minibatch_step, state, mbs)
             # mean over the epoch's minibatches, so the KL driving the
             # adaptive coefficient is a batch-wide estimate (as in RLlib),
             # not one arbitrary minibatch
             return state, jax.tree_util.tree_map(jnp.mean, ms)
 
-        state, metrics_per_epoch = jax.lax.scan(
-            epoch, state, jax.random.split(rng, cfg.num_sgd_iter))
+        with jax.named_scope(scopes.PPO_SHUFFLE):
+            epoch_rngs = jax.random.split(rng, cfg.num_sgd_iter)
+        state, metrics_per_epoch = jax.lax.scan(epoch, state, epoch_rngs)
         metrics = jax.tree_util.tree_map(lambda m: m[-1], metrics_per_epoch)
 
         # RLlib-style adaptive KL coefficient update
         kl = metrics["kl"]
-        kl_coeff = jnp.where(
-            kl > 2.0 * cfg.kl_target, state.kl_coeff * 1.5,
-            jnp.where(kl < 0.5 * cfg.kl_target, state.kl_coeff * 0.5,
-                      state.kl_coeff))
+        with jax.named_scope(scopes.PPO_APPLY):
+            kl_coeff = jnp.where(
+                kl > 2.0 * cfg.kl_target, state.kl_coeff * 1.5,
+                jnp.where(kl < 0.5 * cfg.kl_target, state.kl_coeff * 0.5,
+                          state.kl_coeff))
         state = state.replace(kl_coeff=kl_coeff)
         metrics["kl_coeff"] = kl_coeff
         return state, metrics

@@ -1337,6 +1337,7 @@ def _episode_kernels(et: EpisodeTables):
         return (ev["ok_place"] & ev["ok_chan"] & ev["engine_ok"],
                 ev["jct"])
 
+    @jax.named_scope(scopes.SIM_DECIDE)
     def decision(bank, carry, action, row, memo=None):
         """Decide one queued job; returns ``(carry', (reward, accept,
         cause, jct, la_trips, la_rode), pending)``. ``la_trips`` (i32)
@@ -2109,6 +2110,7 @@ def make_segment_fn(et: EpisodeTables, ot: dict, model, n_steps: int,
                     "n_occupied": (srv_job >= 0).sum().astype(jnp.int32),
                     "n_running": slot_valid.sum().astype(jnp.int32)}
 
+    @jax.named_scope(scopes.SIM_SEGMENT)
     def segment(bank, params, sim_state, rng):
         dt = et.tables["dep_size"].dtype
         if memo_cfg is not None:

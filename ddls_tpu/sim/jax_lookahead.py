@@ -1183,9 +1183,16 @@ def jax_lookahead(op_remaining, op_valid, op_worker, op_score, num_parents,
     """
     import jax
 
-    one_lane = jax.tree_util.tree_map(
-        lambda x: x[None],
-        (op_remaining, op_valid, op_worker, op_score, num_parents,
-         dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
-         blocks, skip))
-    return tuple(x[0] for x in _lane_batched_lookahead(num_workers)(*one_lane))
+    # the whole call carries one name, so what runs AROUND the tick
+    # loops (the move by server, the stage gathers, the endpoint
+    # matrices, the scatter back — and, under ``vmap``, the fold into
+    # lanes, which is bound under the call's name stack) can be read
+    # apart from the loops, which keep ``SIM_LOOKAHEAD`` inside it
+    with jax.named_scope(scopes.SIM_LOOKAHEAD_CALL):
+        one_lane = jax.tree_util.tree_map(
+            lambda x: x[None],
+            (op_remaining, op_valid, op_worker, op_score, num_parents,
+             dep_remaining, dep_valid, dep_mutual, dep_is_flow, dep_score,
+             blocks, skip))
+        return tuple(
+            x[0] for x in _lane_batched_lookahead(num_workers)(*one_lane))

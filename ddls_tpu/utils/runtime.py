@@ -30,6 +30,13 @@ DEFAULT_CACHE_DIR = os.path.join(REPO_ROOT, ".jax_cache")
 _CACHE_SETTINGS = {
     "jax_persistent_cache_min_compile_time_secs": 0.5,
     "jax_persistent_cache_min_entry_size_bytes": 0,
+    # jax keys an entry on the module with its debug info STRIPPED, and
+    # a ``jax.named_scope`` is debug info: two builds that differ only
+    # in their scopes share a key, and the second is handed the first's
+    # executable, whose ``op_name`` paths (what a profile shows, and
+    # what every scope metric reads) are the FIRST build's. With the
+    # metadata in the key a profile's names are the build's own
+    "jax_compilation_cache_include_metadata_in_key": True,
 }
 
 

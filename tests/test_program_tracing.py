@@ -39,24 +39,165 @@ def _clean_registries():
 
 
 # ------------------------------------------------------------- scopes
-def test_every_scope_names_ops_of_the_fused_epoch_program(fused_dataset):
+def _compiled_text(dataset):
+    loop = test_fused._make_fused_loop(dataset)
+    try:
+        return loop.fused.lower(loop.state).compile().as_text()
+    finally:
+        loop.close()
+
+
+@pytest.fixture(scope="module")
+def program_text(fused_dataset):
+    """The compiled tiny fused epoch program (8 lanes), as text."""
+    return _compiled_text(fused_dataset)
+
+
+@pytest.fixture(scope="module")
+def program_paths(program_text):
+    import re
+
+    paths = set(re.findall(r'op_name="([^"]+)"', program_text))
+    assert paths, "no op_name metadata in the compiled program"
+    return paths
+
+
+def _holds(scope):
+    """Matches a path that has ``scope`` as a segment, bare or wrapped
+    by vmap/jvp/transpose: the benchmark's own rule."""
+    from benchmarks.reduce.op_scopes import scope_pattern
+
+    return scope_pattern([scope]).search
+
+
+def test_every_scope_names_ops_of_the_fused_epoch_program(program_paths):
     """Each name in ``telemetry/scopes.py`` is a path segment — bare or
     wrapped by vmap/jvp/transpose — of some operation's ``op_name`` in
     the lowered fused epoch program (the path a TPU profile carries per
     instruction)."""
+    for scope in scopes.ALL:
+        assert any(map(_holds(scope), program_paths)), scope
+    assert len(set(scopes.ALL)) == len(scopes.ALL)
+
+
+def _tree_names():
+    return sorted({c for cs in scopes.TREE.values() for c in cs}
+                  | (set(scopes.TREE) - {scopes.ROOT}))
+
+
+@pytest.mark.parametrize("scope", _tree_names())
+def test_every_name_of_the_tree_is_in_the_program_under_its_parent(
+        program_paths, scope):
+    """``scopes.TREE`` is the program's own: every name in it is a path
+    segment of some operation, and every path that holds a name holds
+    one of the parents the tree gives it, BEFORE it (the root — the
+    program — is held by all). A path the compiler kept only the tail
+    of (no ``jit(`` at its start) says nothing about what stood before
+    it."""
+    mine = [p for p in program_paths if _holds(scope)(p)]
+    assert mine, scope
+    parents = [p for p, children in scopes.TREE.items()
+               if scope in children]
+    assert parents, scope
+    if scopes.ROOT in parents:
+        return
+    for path in mine:
+        if not path.startswith("jit("):
+            continue
+        at = _holds(scope)(path).start()
+        assert any(m and m.start() < at
+                   for m in (_holds(p)(path) for p in parents)), path
+
+
+@pytest.mark.parametrize("parent", [p for p in scopes.TREE
+                                    if p is not scopes.ROOT])
+def test_an_enclosing_scope_has_operations_of_its_own(program_paths,
+                                                      parent):
+    """Each enclosing scope names operations that none of its children
+    names — what its self time is read from — and every child stands
+    DIRECTLY under it somewhere: no other name of the tree between."""
+    children = scopes.TREE[parent]
+    own = [p for p in program_paths if _holds(parent)(p)
+           and not any(_holds(c)(p) for c in children)]
+    assert own, parent
+    others = set(_tree_names()) - {parent}
+    for child in children:
+        def direct(path):
+            a, b = _holds(parent)(path), _holds(child)(path)
+            if not (a and b and a.end() <= b.start()):
+                return False
+            between = path[a.end():b.start()]
+            return not any(_holds(o)(between) for o in others - {child})
+        assert any(map(direct, program_paths)), (parent, child)
+
+
+def test_what_vmap_makes_of_the_decisions_cond_is_the_decisions(
+        program_paths):
+    """At 8 lanes the decision's ``lax.cond`` is both branches and a
+    select over every output, with the untaken branch's constants
+    broadcast over the lanes: those operations are bound under the
+    name stack of the CALL, so they carry ``sim_decide`` and no child
+    of it — the decision's self time, which no leaf scope could name."""
+    children = scopes.TREE[scopes.SIM_DECIDE]
+    glue = [p for p in program_paths if _holds(scopes.SIM_DECIDE)(p)
+            and not any(_holds(c)(p) for c in children)]
+    kinds = {p.rstrip(":").rsplit("/", 1)[-1] for p in glue}
+    assert {"select_n", "broadcast_in_dim"} <= kinds, kinds
+    # and no ``cond`` is left for a branch to hide in
+    assert not any("/cond/" in p for p in glue)
+
+
+def test_the_scopes_change_no_instruction(fused_dataset, program_text,
+                                          monkeypatch):
+    """A scope is metadata: the same program compiled with
+    ``jax.named_scope`` a null context gives the same text once each
+    instruction's ``metadata={...}`` is cut. (``ppo_update`` is bound
+    when ``rl/ppo.py`` is imported and stays; every scope of the tree
+    is opened at trace time and goes.)"""
+    import contextlib
     import re
 
-    loop = test_fused._make_fused_loop(fused_dataset)
-    try:
-        text = loop.fused.lower(loop.state).compile().as_text()
-    finally:
-        loop.close()
-    paths = set(re.findall(r'op_name="([^"]+)"', text))
-    assert paths, "no op_name metadata in the compiled program"
-    for scope in scopes.ALL:
-        rx = re.compile(rf"(?:^|[/;])(?:\w+\()*{scope}\)*(?:[/;]|$)")
-        assert any(rx.search(p) for p in paths), scope
-    assert len(set(scopes.ALL)) == len(scopes.ALL)
+    import jax
+
+    def bare(text):
+        """The instructions alone: no ``metadata={...}``, none of the
+        tables of files and frames it points into."""
+        blocks = [b for b in text.split("\n\n") if not b.startswith(
+            ("FileNames", "FunctionNames", "FileLocations", "StackFrames"))]
+        text = re.sub(r",? ?metadata=\{[^}]*\}", "", "\n\n".join(blocks))
+        # an instruction's NAME is made from its location and a
+        # counter: number the names by first appearance, so an operand
+        # still says which instruction it reads
+        seen = {}
+        return re.sub(
+            r"%[\w.\-]+",
+            lambda m: seen.setdefault(m.group(), f"%{len(seen)}"),
+            text).split("\n")
+
+    class no_scope(contextlib.ContextDecorator):
+        """Opens nothing, around a block or a function."""
+
+        def __init__(self, name):
+            pass
+
+        def __enter__(self):
+            pass
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax, "named_scope", no_scope)
+    plain = _compiled_text(fused_dataset)
+    assert scopes.SIM_SEGMENT in program_text
+    for name in _tree_names():
+        if name != scopes.PPO_UPDATE:
+            assert name not in plain, name
+    named, plain = bare(program_text), bare(plain)
+    assert len(named) == len(plain) > 1000
+    # (not ``named == plain``: pytest would diff two 10,000-line lists)
+    differ = [i for i, pair in enumerate(zip(named, plain))
+              if pair[0] != pair[1]]
+    assert not differ, (named[differ[0]], plain[differ[0]])
 
 
 # --------------------------------------------------------- trip counts
