@@ -623,7 +623,17 @@ def _select_row(table, index, fill):
     return jnp.where(hit, table, fill).max(axis=index.ndim)
 
 
-def jax_allocate_job(mem, other_free, cfg, tables, st: ShapeTables,
+def config_rows(tables: dict, cfg) -> dict:
+    """ONE config's rows: every ``[n_cfg, ...]`` leaf of the stacked
+    tables (`stack_config_tables`) read at the traced (model, degree)
+    row ``cfg``. The kernels below take these rows, never the tables: a
+    ``lax.cond`` on the lanes' path then carries ``[lanes, ...]`` rows
+    that are batched already, where a table its branch closed over was
+    written out once a lane (`_episode_kernels`' ``decision``)."""
+    return {name: table[cfg] for name, table in tables.items()}
+
+
+def jax_allocate_job(mem, other_free, rows, st: ShapeTables,
                      pads: ConfigPads):
     """Scan-ified `allocate_job` (agents/placers.py:103; reference
     placers/utils.py:532): walk the padded forward-op sequence in topo
@@ -644,7 +654,8 @@ def jax_allocate_job(mem, other_free, cfg, tables, st: ShapeTables,
 
     ``mem`` [n_srv] free memory per server; ``other_free`` [n_srv] bool
     (True = not occupied by another job; constant during one job's
-    allocation); ``cfg`` the traced (model, degree) config row. Returns
+    allocation); ``rows`` the (model, degree) config's rows
+    (`config_rows`). Returns
     (op_to_server [N] i32, -1 where unplaced, new_mem [n_srv], ok bool).
     On ok=False outputs are partial and must be discarded by the caller
     (the host returns None and the composite action drops the job)."""
@@ -664,7 +675,7 @@ def jax_allocate_job(mem, other_free, cfg, tables, st: ShapeTables,
     lane = jnp.arange(Smax)
     server = jnp.arange(n_srv)
 
-    f_split = tables["f_split"][cfg]
+    f_split = rows["f_split"]
     # every forward op's candidate shapes, in find_sub_block order
     f_shapes = _select_row(jnp.asarray(st.row), f_split, -1)
 
@@ -712,12 +723,12 @@ def jax_allocate_job(mem, other_free, cfg, tables, st: ShapeTables,
             jnp.full((F, Smax), -1, jnp.int32),
             jnp.zeros((F,), jnp.int32),
             jnp.bool_(True))
-    ops = (jnp.arange(F, dtype=jnp.int32), tables["f_valid"][cfg], f_split,
-           tables["f_mem"][cfg], tables["f_parents"][cfg], f_shapes)
+    ops = (jnp.arange(F, dtype=jnp.int32), rows["f_valid"], f_split,
+           rows["f_mem"], rows["f_parents"], f_shapes)
     (new_mem, op_servers, _, ok), _ = jax.lax.scan(body, init, ops)
     # shard k of an op (or of its backward mirror) sits on server k of
     # its forward slot's block: op slot (o, k) = o * Smax + k
-    ots = _select_row(op_servers, tables["op_fwd"][cfg], -1)
+    ots = _select_row(op_servers, rows["op_fwd"], -1)
     return ots.reshape(-1), new_mem, ok
 
 
@@ -768,7 +779,7 @@ def _jnp_all_reduce_time(msg, n_servers, n_racks, n_cgs, *, x, rate,
     return 2 * comm + comp
 
 
-def jax_price_and_score(sc, cfg, tables, st: ShapeTables,
+def jax_price_and_score(sc, rows, st: ShapeTables,
                         pads: ConfigPads, comm: dict):
     """Price every dep of one placed job and build the SRPT lookahead
     scores — the array mirror of `assign_dep_run_times`
@@ -783,7 +794,8 @@ def jax_price_and_score(sc, cfg, tables, st: ShapeTables,
     servers, never an index per dep or per sub-op (one gather or
     scatter of M elements runs element by element on the chip).
 
-    ``sc`` [N] per-op server codes (grid-flattened, -1 pads). Returns
+    ``sc`` [N] per-op server codes (grid-flattened, -1 pads); ``rows``
+    the placed config's rows (`config_rows`). Returns
     (times [M], is_flow [M], pair_used [n_srv, n_srv], op_score [N],
     dep_score [M], finite_ok): ``pair_used[x, y]`` — does a flow dep
     run from server x to server y — is all the channel checks need of
@@ -804,14 +816,14 @@ def jax_price_and_score(sc, cfg, tables, st: ShapeTables,
     r_of_np = (codes // S) % R
     s_of_np = codes % S
 
-    dep_valid = tables["dep_valid"][cfg].reshape(B, side, side)
-    dep_size = tables["dep_size"][cfg].reshape(B, side, side)
-    blk_kind = tables["blk_kind"][cfg]                    # [B]
-    blk_grp = tables["blk_grp"][cfg]
+    dep_valid = rows["dep_valid"].reshape(B, side, side)
+    dep_size = rows["dep_size"].reshape(B, side, side)
+    blk_kind = rows["blk_kind"]                           # [B]
+    blk_grp = rows["blk_grp"]
 
     # a dep's endpoints: the servers on its block's row and column
     _, _, src_rows, dst_rows = block_endpoints(
-        sc, DepBlocks(tables["blk_src"][cfg], tables["blk_dst"][cfg]), side)
+        sc, DepBlocks(rows["blk_src"], rows["blk_dst"]), side)
     sc_src, sc_dst = src_rows[:, :, None], dst_rows[:, None, :]
     same = sc_src == sc_dst
     # THE flow predicate, traced: mirrors OpGraph.flow_mask_from_codes
@@ -838,8 +850,8 @@ def jax_price_and_score(sc, cfg, tables, st: ShapeTables,
     # (u) and destinations (v), counted with multiplicity, are its
     # blocks' row and column servers times the deps on each row and
     # column: equal multisets of u- and v-codes = equal histograms
-    grp_valid = tables["grp_valid"][cfg]              # [G]
-    grp_msg = tables["grp_msg"][cfg]                  # [G]
+    grp_valid = rows["grp_valid"]                     # [G]
+    grp_msg = rows["grp_msg"]                         # [G]
     on_row = dep_valid.sum(2, dtype=jnp.int32)        # [B, S_i] deps a row
     on_col = dep_valid.sum(1, dtype=jnp.int32)        # [B, S_j]
     blk_u = jnp.sum(jnp.where(on_src, on_row[:, :, None], 0), 1)  # [B, W]
@@ -869,7 +881,7 @@ def jax_price_and_score(sc, cfg, tables, st: ShapeTables,
     def spans(comp):
         return jnp.where(comp(sc_src) == comp(sc_dst), 1.0, 2.0)
     sync_time = _jnp_all_reduce_time(
-        tables["blk_msg"][cfg][:, None, None], spans(lambda c: c % S),
+        rows["blk_msg"][:, None, None], spans(lambda c: c % S),
         spans(lambda c: (c // S) % R), spans(lambda c: c // (R * S)),
         x=x, rate=rate, prop=prop, io=io)
     sync_time = jnp.where(same, jnp.zeros_like(sync_time), sync_time)
@@ -886,11 +898,11 @@ def jax_price_and_score(sc, cfg, tables, st: ShapeTables,
 
     # ---- SRPT dep priorities: one stable descending argsort over the
     # priced costs in edge order (agents/schedulers.py:_srpt_priorities)
-    m = tables["n_deps"][cfg].astype(dt)
+    m = rows["n_deps"].astype(dt)
     cost_key = jnp.where(dep_valid, -times, jnp.asarray(jnp.inf, dt))
     # "edge order" is the HOST's: the tables are in block order, so ties
     # break on each slot's own edge index, not on its position
-    order = jnp.lexsort((tables["dep_edge"][cfg], cost_key))
+    order = jnp.lexsort((rows["dep_edge"], cost_key))
     # each dep's rank = the inverse permutation, by a second sort: a
     # scatter of M elements is the loop the first paragraph names
     dep_pri = jnp.argsort(order).astype(dt)
@@ -900,21 +912,21 @@ def jax_price_and_score(sc, cfg, tables, st: ShapeTables,
     # only)
     dep_pri = jnp.where(is_flow, dep_pri, jnp.zeros_like(dep_pri))
     dep_score = dep_pri * (m + 1) + (
-        m - tables["dep_sorted_rank"][cfg].astype(dt))
+        m - rows["dep_sorted_rank"].astype(dt))
 
     # ---- SRPT op priorities: per-worker stable sort by compute cost
     # descending, insertion (placement) order breaking ties
     # (agents/schedulers.py:29-38 + OpPlacement.worker_to_ops order)
-    op_valid = tables["op_valid"][cfg]
-    op_cost = tables["op_compute"][cfg]
-    ins = tables["insertion_rank"][cfg]
+    op_valid = rows["op_valid"]
+    op_cost = rows["op_compute"]
+    ins = rows["insertion_rank"]
     same_srv = (sc[:, None] == sc[None, :]) & (sc[:, None] >= 0)
     before = (op_cost[None, :] > op_cost[:, None]) | (
         (op_cost[None, :] == op_cost[:, None]) & (ins[None, :] < ins[:, None]))
     op_pri = (same_srv & before & op_valid[None, :]).sum(1).astype(dt)
-    n = tables["n_ops"][cfg].astype(dt)
+    n = rows["n_ops"].astype(dt)
     op_score = op_pri * (n + 1) + (
-        n - tables["op_sorted_rank"][cfg].astype(dt))
+        n - rows["op_sorted_rank"].astype(dt))
 
     # ---- channels (single-channel complete topology: a flow rides the
     # direct link of its ordered server pair): which pairs carry one,
@@ -1237,10 +1249,13 @@ def _episode_kernels(et: EpisodeTables):
             return inflate_duration_jax(t, jct, r0, sc_t0, sc_t1,
                                         sc_rate, affects)
 
-    def eval_cfg(bank, carry, row, cfg, memo=None, discard=None):
+    def eval_cfg(bank, carry, row, cfg, rows, memo=None, discard=None):
         """Evaluate ONE (job, degree) candidate against the live cluster
         state: placement, dep pricing, channel check, lookahead, SLA —
-        everything a decision needs, minus the commit. XLA dead-code
+        everything a decision needs, minus the commit. ``rows`` are the
+        candidate's rows of the config tables (`config_rows` at ``cfg``):
+        every kernel below reads them and none reads a table, and
+        ``cfg`` itself rides for the memo key alone. XLA dead-code
         eliminates the commit outputs when a caller (candidate pricing)
         only reads (ok, jct). Returns ``(ev, pending)``; with ``memo``
         (the in-kernel lookahead memo table, sim/jax_memo.py) the
@@ -1268,11 +1283,11 @@ def _episode_kernels(et: EpisodeTables):
         other_free = srv_job < 0
         with jax.named_scope(scopes.SIM_ALLOCATE):
             ots, new_mem, ok_place = jax_allocate_job(
-                mem, other_free, cfg, et.tables, st, pads)
+                mem, other_free, rows, st, pads)
         with jax.named_scope(scopes.SIM_PRICE):
             times, is_flow, pair_used, op_score, dep_score, finite_ok = \
-                jax_price_and_score(ots, cfg, et.tables, st, pads, et.comm)
-        op_valid = et.tables["op_valid"][cfg]
+                jax_price_and_score(ots, rows, st, pads, et.comm)
+        op_valid = rows["op_valid"]
         ok_chan, chan_mask, srv_mask = placement_masks(
             ots, op_valid, pair_used, pair_is_chan, chan_occ)
         # an unplaced job stays out of loop and memo alike: its key is
@@ -1287,13 +1302,11 @@ def _episode_kernels(et: EpisodeTables):
             # void lane is masked out the same way
             skip = void if skip is None else skip | void
             t_la, _, _, _, ok, trips = jax_lookahead(
-                et.tables["op_compute"][cfg], op_valid,
+                rows["op_compute"], op_valid,
                 jnp.where(op_valid, ots, -1), op_score,
-                et.tables["num_parents"][cfg], times,
-                et.tables["dep_valid"][cfg],
-                et.tables["dep_mutual"][cfg], is_flow, dep_score,
-                DepBlocks(et.tables["blk_src"][cfg],
-                          et.tables["blk_dst"][cfg]),
+                rows["num_parents"], times, rows["dep_valid"],
+                rows["dep_mutual"], is_flow, dep_score,
+                DepBlocks(rows["blk_src"], rows["blk_dst"]),
                 num_workers=n_srv, skip=skip)
             return t_la, ok, trips
 
@@ -1307,7 +1320,7 @@ def _episode_kernels(et: EpisodeTables):
                 memo, cfg, groups, times, run_lookahead, void)
         jct = t_step * steps
         max_jct = (bank["sla_frac"][row].astype(dt)
-                   * et.tables["seq_compute"][cfg].astype(dt) * steps)
+                   * rows["seq_compute"].astype(dt) * steps)
         sla_ok = ~(jct > max_jct)
         engine_ok = ok_la & finite_ok
         return {"ok_place": ok_place, "ok_chan": ok_chan,
@@ -1323,7 +1336,8 @@ def _episode_kernels(et: EpisodeTables):
         jitted counterpart of sim/candidate_pricing.py. One VMAPPED
         evaluation over the cfg batch (cfg only feeds gathers), so the
         traced program contains the placement/pricing/lookahead kernels
-        once, not n_deg times."""
+        once, not n_deg times; each column's rows are read inside the
+        ``vmap``."""
         jtype = bank["type"][row]
         cfgs = jtype * n_deg + jnp.arange(n_deg, dtype=jnp.int32)
         # memo-less on purpose: this vmap batches the CFG axis within
@@ -1332,8 +1346,8 @@ def _episode_kernels(et: EpisodeTables):
         # batches over LANES, each with its own table) — the host
         # counterpart keeps candidate pricing fast through its own
         # prefetch instead
-        ev, _ = jax.vmap(eval_cfg, in_axes=(None, None, None, 0))(
-            bank, carry, row, cfgs)
+        ev, _ = jax.vmap(lambda cfg: eval_cfg(
+            bank, carry, row, cfg, config_rows(et.tables, cfg)))(cfgs)
         return (ev["ok_place"] & ev["ok_chan"] & ev["engine_ok"],
                 ev["jct"])
 
@@ -1350,7 +1364,18 @@ def _episode_kernels(et: EpisodeTables):
         its own. NO ``cond`` returns a memo: under ``vmap`` a ``cond``
         is both branches and a select over every output, and a table
         among them was selected, and copied, whole on every lane-step
-        (2.2-2.9 GB over a cell's lanes; PERF.md section 6, PR 49)."""
+        (2.2-2.9 GB over a cell's lanes; PERF.md section 6, PR 49).
+        And the ``cond`` below takes the config's ROWS as its operand
+        (`config_rows`, read once, above it) and its branch closes over
+        NO ``[n_cfg, ...]`` table: ``vmap``'s rule for a ``cond`` with a
+        per-lane predicate gives every operand the lanes' axis first —
+        what a branch closes over is an operand too — so a table in the
+        branch was written out at ``[lanes, n_cfg, M]`` on every
+        lane-step only to be row-indexed a moment later (five dep
+        tables, 3.3-4.6 ms of every cell's epoch; PERF.md section 6,
+        PR 51), while the rows are ``[lanes, M]`` and batched already.
+        At one lane the ``cond`` stays a branch, and an action-0 step
+        pays the rows' dynamic slices."""
         (t, mem, srv_job, chan_occ, slot_valid, slot_t_done, slot_mem,
          slot_servers, slot_chan) = carry
         dt = mem.dtype
@@ -1362,13 +1387,14 @@ def _episode_kernels(et: EpisodeTables):
         # take the zero path instead of wrapping deg_col's -1 into
         # another config row
         action_ok = (action > 0) & (deg_col[jnp.clip(action, 0)] >= 0)
+        rows = config_rows(et.tables, cfg)
 
-        def heavy():
+        def heavy(rows):
             # under vmap the cond below is a select and every lane runs
             # this branch: a lane on the zero path is masked out of the
             # lookahead loop, so the trips the batched loop executes are
             # the maximum over lanes whose result is used
-            ev, pending = eval_cfg(bank, carry, row, cfg, memo,
+            ev, pending = eval_cfg(bank, carry, row, cfg, rows, memo,
                                    discard=~action_ok)
             accept = (ev["ok_place"] & ev["ok_chan"] & ev["sla_ok"]
                       & ev["engine_ok"])
@@ -1382,14 +1408,14 @@ def _episode_kernels(et: EpisodeTables):
                     ev["new_mem"], ev["srv_mask"], ev["chan_mask"],
                     ev["la_trips"], ev["la_rode"]), pending
 
-        def zero():
+        def zero(rows):
             return (jnp.bool_(False), jnp.int32(CAUSE_NOT_HANDLED),
                     jnp.zeros((), dt), mem, jnp.zeros((n_srv,), bool),
                     jnp.zeros((n_chan,), bool), jnp.int32(0),
                     jnp.int32(0)), jax_memo.memo_pending_none(memo)
 
         ((accept, cause, jct, new_mem, srv_mask, chan_mask, la_trips,
-          la_rode), pending) = jax.lax.cond(action_ok, heavy, zero)
+          la_rode), pending) = jax.lax.cond(action_ok, heavy, zero, rows)
 
         if scenario is not None:
             # inflate AFTER the accept/cause decision: admission is
@@ -1523,8 +1549,9 @@ def price_dep_indexed_ops(et: EpisodeTables) -> int:
         lambda: k.init_state({"arrival_t": jax.numpy.zeros((2,), dt)})[0])
     i32 = jax.ShapeDtypeStruct((), np.int32)
     traced = jax.make_jaxpr(
-        lambda bank, carry, row, cfg: k.eval_cfg(bank, carry, row, cfg)[0])(
-            bank, carry, i32, i32)
+        lambda bank, carry, row, cfg: k.eval_cfg(
+            bank, carry, row, cfg, config_rows(et.tables, cfg))[0])(
+                bank, carry, i32, i32)
     return len(dep_indexed_ops(traced.jaxpr,
                                et.pads.n_blocks * et.pads.max_split))
 
@@ -1546,7 +1573,7 @@ def allocate_indexed_ops(tables: dict, st: ShapeTables,
     n_srv = int(np.prod(st.ramp_shape))
     traced = jax.make_jaxpr(
         lambda mem, other_free, cfg: jax_allocate_job(
-            mem, other_free, cfg, tables, st, pads))(
+            mem, other_free, config_rows(tables, cfg), st, pads))(
         jax.ShapeDtypeStruct((n_srv,), tables["f_mem"].dtype),
         jax.ShapeDtypeStruct((n_srv,), bool),
         jax.ShapeDtypeStruct((), np.int32))

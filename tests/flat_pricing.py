@@ -279,10 +279,11 @@ class Forms:
         pc = jnp.asarray(pair_channel)
 
         def block(sc, cfg, chan_occ):
+            rows = je.config_rows(jt, cfg)
             times, is_flow, pair_used, op_score, dep_score, finite_ok = \
-                je.jax_price_and_score(sc, cfg, jt, st, pads, comm)
+                je.jax_price_and_score(sc, rows, st, pads, comm)
             return (times, is_flow, op_score, dep_score, finite_ok,
-                    *je.placement_masks(sc, jt["op_valid"][cfg], pair_used,
+                    *je.placement_masks(sc, rows["op_valid"], pair_used,
                                         pair_is_chan, chan_occ))
 
         def flat(sc, cfg, chan_occ):
@@ -294,8 +295,9 @@ class Forms:
 
         def allocate(cfg):
             mem = jnp.full((n_srv,), 1e30, jt["dep_size"].dtype)
-            return je.jax_allocate_job(mem, jnp.ones((n_srv,), bool), cfg,
-                                       jt, st, pads)[0]
+            return je.jax_allocate_job(mem, jnp.ones((n_srv,), bool),
+                                       je.config_rows(jt, cfg), st,
+                                       pads)[0]
 
         self.block_fn, self.flat_fn = block, flat
         self.block, self.flat = jax.jit(block), jax.jit(flat)

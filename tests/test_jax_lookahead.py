@@ -199,7 +199,8 @@ class _BlockBuild:
 
         def arguments(cfg, other_free, scatter=0, flip=0, collide=0):
             mem = jnp.full((et.n_srv,), et.worker_mem, tb["dep_size"].dtype)
-            ots, _, ok = je.jax_allocate_job(mem, other_free, cfg, tb,
+            rows = je.config_rows(tb, cfg)
+            ots, _, ok = je.jax_allocate_job(mem, other_free, rows,
                                              et.st, et.pads)
             # ``scatter`` moves original op o's shards ``scatter * o``
             # servers on, before pricing: a mounted graph no allocator
@@ -219,10 +220,10 @@ class _BlockBuild:
             ots = ots.at[1].set(jnp.where((collide > 0) & (ots[1] >= 0),
                                           ots[0], ots[1]))
             times, is_flow, _, op_score, dep_score, _ = \
-                je.jax_price_and_score(ots, cfg, tb, et.st, et.pads,
+                je.jax_price_and_score(ots, rows, et.st, et.pads,
                                        et.comm)
-            ov = tb["op_valid"][cfg]
-            blocks = DepBlocks(tb["blk_src"][cfg], tb["blk_dst"][cfg])
+            ov = rows["op_valid"]
+            blocks = DepBlocks(rows["blk_src"], rows["blk_dst"])
             # the flat path's per-dep endpoints and channel, which the
             # tables no longer carry (pricing reads the blocks)
             dep_src, dep_dst = block_endpoint_slots(
@@ -231,10 +232,10 @@ class _BlockBuild:
             chan = jnp.where(
                 is_flow, et.pair_channel[scp[jnp.clip(dep_src, 0)],
                                          scp[jnp.clip(dep_dst, 0)]], -1)
-            return ((tb["op_compute"][cfg], ov, jnp.where(ov, ots, -1),
-                     op_score, tb["num_parents"][cfg], times,
-                     tb["dep_valid"][cfg], dep_src, dep_dst,
-                     tb["dep_mutual"][cfg], is_flow, dep_score,
+            return ((rows["op_compute"], ov, jnp.where(ov, ots, -1),
+                     op_score, rows["num_parents"], times,
+                     rows["dep_valid"], dep_src, dep_dst,
+                     rows["dep_mutual"], is_flow, dep_score,
                      chan[:, None]), blocks, ok)
 
         def flat(args, blocks, skip=None):

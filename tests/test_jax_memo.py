@@ -397,7 +397,13 @@ class _ReplayPolicy:
     SAMPLED stream (the pins were first written against one, and its
     bits change with the jax version) legitimately reaches new keys in
     later episodes, which reads as a miss whether or not the table
-    survived the reset."""
+    survived the reset. ``masked=False`` rotates over ALL of
+    0 .. n - 1: the stream then also holds action 0 and the odd actions
+    the mask keeps out, which the kernel takes down the zero path
+    (`sim/jax_env.py:decision`'s ``action_ok``)."""
+
+    def __init__(self, masked: bool = True):
+        self.masked = masked
 
     def apply(self, params, obs):
         import jax.numpy as jnp
@@ -407,8 +413,10 @@ class _ReplayPolicy:
         rot = jnp.floor(obs["graph_features"].sum() * 97.0).astype(
             jnp.int32)
         pref = (jnp.arange(n, dtype=jnp.int32) + rot) % n
-        return (jnp.where(mask, pref.astype(jnp.float32) * 1e3, -1e9),
-                jnp.float32(0.0))
+        logits = pref.astype(jnp.float32) * 1e3
+        if self.masked:
+            logits = jnp.where(mask, logits, -1e9)
+        return logits, jnp.float32(0.0)
 
 
 def test_segment_memo_bitwise_parity_and_cross_reset_persistence(
@@ -642,6 +650,120 @@ def test_no_cond_and_no_select_returns_a_memo_table(memo_env, kernel,
     assert shapes <= written, shapes - written
     # ... and no select and no branch hands one out
     assert _memo_shaped(traced.jaxpr, shapes) == []
+
+
+@pytest.mark.parametrize("lanes", [1, 3])
+@pytest.mark.parametrize("memo", ["memo_on", "memo_off"])
+def test_no_cond_carries_a_config_table(memo_env, memo, lanes):
+    """The decision's ``cond`` takes one config's ROWS and closes over
+    no ``[n_cfg, ...]`` table (PR 51). Under the lanes' ``vmap`` a
+    ``cond`` with a per-lane predicate gives every operand the batch
+    axis first, closed-over constants included, so a table in its
+    branch was written out at ``[lanes, n_cfg, ...]`` on every
+    lane-step only to be row-indexed a moment later: no equation of the
+    fused path's traced kernel, at any depth, has an output of that
+    shape for any leaf of ``et.tables``. At one lane
+    (`vmap_segment_fn`'s squeeze) the ``cond`` stays a branch: its
+    operands hold the rows and no whole table. (On the parent all four
+    cases fail: 155 equations with a table-wide output at three lanes —
+    ``broadcast_in_dim``, then the rule's ``stop_gradient`` and
+    ``select_n`` — and 23 whole tables among the one-lane ``cond``'s
+    operands.)"""
+    import jax
+
+    from ddls_tpu.sim import jax_env, jax_memo
+    from ddls_tpu.utils.jaxprs import equations
+
+    et, ot, params = memo_env["et"], memo_env["ot"], memo_env["params"]
+    mc = jax_memo.MemoConfig(n_sets=37, n_ways=3) if memo == "memo_on" \
+        else None
+    fn = jax_env.make_segment_fn(et, ot, memo_env["model"], 2, memo_cfg=mc)
+    banks = _lane_banks(memo_env, lanes)
+    states = jax.vmap(lambda b: jax_env.segment_init(et, b, mc))(banks)
+    rngs = jax.random.split(jax.random.PRNGKey(0), lanes)
+    # a row's own shape is no table's: [n_cfg] beside [n_cfg, n_fwd]
+    # where the pads make n_fwd == n_cfg
+    row_shapes = {leaf.shape[1:] for leaf in et.tables.values()}
+    by_dep = {et.tables[name].shape for name in (
+        "dep_size", "dep_edge", "dep_sorted_rank", "dep_valid",
+        "dep_mutual")}
+    if lanes == 1:
+        take = lambda t: jax.tree_util.tree_map(   # noqa: E731
+            lambda x: x[0], t)
+        traced = jax.make_jaxpr(fn)(take(banks), params, take(states),
+                                    rngs[0])
+        whole = {leaf.shape for leaf in et.tables.values()} - row_shapes
+        assert by_dep <= whole
+        operands = [v.aval.shape for eqn in equations(traced.jaxpr)
+                    if eqn.primitive.name == "cond" for v in eqn.invars]
+        assert [shape for shape in operands if shape in whole] == []
+        assert et.tables["dep_size"].shape[1:] in operands
+        return
+    traced = jax.make_jaxpr(jax.vmap(fn, in_axes=(0, None, 0, 0)))(
+        banks, params, states, rngs)
+    wide = ({(lanes,) + leaf.shape for leaf in et.tables.values()}
+            - {(lanes,) + shape for shape in row_shapes})
+    assert {(lanes,) + shape for shape in by_dep} <= wide
+    assert [(eqn.primitive.name, v.aval.shape)
+            for eqn in equations(traced.jaxpr) for v in eqn.outvars
+            if v.aval.shape in wide] == []
+
+
+@pytest.mark.parametrize("memo", ["memo_on", "memo_off"])
+def test_three_kinds_of_lane_in_one_batch_equal_the_one_lane_path(
+        memo_env, memo):
+    """A valid degree, action 0 and an odd action outside the degree
+    set in ONE batch: ``vmap(segment)`` — the decision's ``cond`` a
+    select over rows read above it — against the one-lane path lane by
+    lane, where the ``cond`` is a branch: every trace key, the carried
+    state, the memo's leaves and the bootstrap fields bit for bit, over
+    two carried segments."""
+    import jax
+
+    from ddls_tpu.sim.jax_env import (make_segment_fn, segment_init,
+                                      vmap_segment_fn)
+    from ddls_tpu.sim.jax_memo import MemoConfig
+
+    n_lanes, n_steps = 6, 12
+    et, ot, params = memo_env["et"], memo_env["ot"], memo_env["params"]
+    mc = MemoConfig(n_sets=16, n_ways=2) if memo == "memo_on" else None
+    seg = make_segment_fn(et, ot, _ReplayPolicy(masked=False), n_steps,
+                          memo_cfg=mc, trace_trips=True)
+    wide = jax.jit(vmap_segment_fn(seg, n_lanes))
+    one = jax.jit(vmap_segment_fn(seg, 1))
+    banks = _lane_banks(memo_env, n_lanes)
+    states = jax.vmap(lambda b: segment_init(et, b, mc))(banks)
+    lane = lambda t, i: jax.tree_util.tree_map(    # noqa: E731
+        lambda x: x[i:i + 1], t)
+    lane_states = [lane(states, i) for i in range(n_lanes)]
+    rng = jax.random.PRNGKey(5)
+    kinds = set()
+    for _ in range(2):
+        rng, sub = jax.random.split(rng)
+        rngs = jax.random.split(sub, n_lanes)
+        states, trace, fields = wide(banks, params, states, rngs)
+        for i in range(n_lanes):
+            lane_states[i], lane_trace, lane_fields = one(
+                lane(banks, i), params, lane_states[i], rngs[i:i + 1])
+            for got, want in zip(
+                    jax.tree_util.tree_leaves_with_path(
+                        (lane(states, i), lane(trace, i),
+                         lane(fields, i))),
+                    jax.tree_util.tree_leaves(
+                        (lane_states[i], lane_trace, lane_fields))):
+                path, got = got
+                got, want = np.asarray(got), np.asarray(want)
+                assert got.dtype == want.dtype and np.array_equal(
+                    got, want), (i, jax.tree_util.keystr(path))
+        action = np.asarray(trace["action"])              # [B, T]
+        kind = np.where(action == 0, 0,
+                        np.where(np.isin(action, et.degrees), 1, 2))
+        kinds |= {frozenset(step) for step in kind.T}
+        cause = np.asarray(trace["cause"])
+        assert (cause[kind != 1] == 1).all()          # CAUSE_NOT_HANDLED
+        assert (cause[kind == 1] != 1).all()
+        assert (np.asarray(trace["la_trips"])[kind != 1] == 0).all()
+    assert frozenset({0, 1, 2}) in kinds, kinds
 
 
 @pytest.mark.parametrize("n_lanes", [2, 8])

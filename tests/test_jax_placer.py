@@ -15,9 +15,16 @@ import jax.numpy as jnp
 from ddls_tpu.agents.partitioners import build_partition_action
 from ddls_tpu.agents.placers import allocate_job
 from ddls_tpu.graphs.readers import read_graph_file
-from ddls_tpu.sim.jax_env import (build_shape_tables, config_tables_for,
-                                  jax_allocate_job, stack_config_tables,
-                                  table_slots)
+from ddls_tpu.sim.jax_env import (build_shape_tables, config_rows,
+                                  config_tables_for, jax_allocate_job,
+                                  stack_config_tables, table_slots)
+
+
+def _allocate_rows(mem, other_free, cfg, tables, st, pads):
+    """The package's scan under the indexed reference's signature (it
+    takes one config's rows, the reference the tables and the row)."""
+    return jax_allocate_job(mem, other_free, config_rows(tables, cfg), st,
+                            pads)
 
 
 def _write_profile(path, n_fwd, rng):
@@ -89,7 +96,7 @@ def test_full_job_parity_randomized(setup):
     import jax
 
     fn = jax.jit(lambda mem, free, cfg: jax_allocate_job(
-        mem, free, cfg, jtables, st, pads))
+        mem, free, config_rows(jtables, cfg), st, pads))
 
     rng = np.random.RandomState(0)
     n_checked_placed = 0
@@ -146,7 +153,7 @@ def test_memory_accounting_matches_host(setup):
     import jax
 
     fn = jax.jit(lambda mem, free, cfg: jax_allocate_job(
-        mem, free, cfg, jtables, st, pads))
+        mem, free, config_rows(jtables, cfg), st, pads))
     rng = np.random.RandomState(7)
     checked = 0
     for trial in range(30):
@@ -263,7 +270,7 @@ def test_scan_equals_the_indexed_scan_bit_for_bit(rows, batching, cluster,
                         mem, free, job * n_deg + jnp.arange(n_deg))
             return one
 
-        new = per_lane(jax_allocate_job)
+        new = per_lane(_allocate_rows)
         old = per_lane(indexed_placer.jax_allocate_job)
         if batching == "unbatched":
             lanes, cfgs = n_rows, np.arange(n_rows)
@@ -367,7 +374,7 @@ def test_scan_equals_the_indexed_scan_at_an_architecture_pad_class(arch):
     jt = {k: jnp.asarray(v) for k, v in tables.items()}
     new, old = (jax.jit(jax.vmap(
         lambda mem, free, cfg, fn=fn: fn(mem, free, cfg, jt, st, pads)))
-        for fn in (jax_allocate_job, indexed_placer.jax_allocate_job))
+        for fn in (_allocate_rows, indexed_placer.jax_allocate_job))
 
     rng = np.random.RandomState(3)
     lanes, n_srv = 9, 32

@@ -391,11 +391,13 @@ def small_env_kernels(dataset_dir):
     return et, k, bank, tuple(carry)
 
 
-def test_eval_cfg_masks_are_the_flat_forms(small_env_kernels):
-    """`eval_cfg` itself, on a cluster with taken servers and channels:
-    its `ok_chan` / `chan_mask` / `srv_mask` are the flat forms' over
-    the flat pricing of the allocator's own placement, column by
-    column, and `price_all`'s cfg vmap is the unbatched call's bits."""
+@pytest.mark.parametrize("cluster", ["taken", "free"])
+def test_eval_cfg_masks_are_the_flat_forms(small_env_kernels, cluster):
+    """`eval_cfg` itself, on a cluster with taken servers and channels
+    and on an empty one: its `ok_chan` / `chan_mask` / `srv_mask` are
+    the flat forms' over the flat pricing of the allocator's own
+    placement, column by column, and `price_all`'s cfg vmap (each
+    column's rows read inside it) is the unbatched call's bits."""
     import flat_pricing
     import jax
     import jax.numpy as jnp
@@ -403,13 +405,16 @@ def test_eval_cfg_masks_are_the_flat_forms(small_env_kernels):
     from ddls_tpu.sim import jax_env as je
 
     et, k, bank, carry = small_env_kernels
+    if cluster == "free":
+        carry = k.init_state(bank)[0]
     row = jnp.int32(0)
     n_deg = len(et.degrees)
     cfg0 = int(bank["type"][0]) * n_deg
 
     def flat_eval(cfg):
-        ots, _, _ = je.jax_allocate_job(carry[1], carry[2] < 0, cfg,
-                                        et.tables, et.st, et.pads)
+        ots, _, _ = je.jax_allocate_job(
+            carry[1], carry[2] < 0, je.config_rows(et.tables, cfg), et.st,
+            et.pads)
         dep_src, dep_dst = flat_pricing.block_endpoint_slots(
             et.tables["blk_src"][cfg], et.tables["blk_dst"][cfg],
             et.pads.max_split)
@@ -422,7 +427,8 @@ def test_eval_cfg_masks_are_the_flat_forms(small_env_kernels):
             ots, et.tables["op_valid"][cfg], is_flow, chan, carry[3],
             et.n_srv, et.n_chan)
 
-    block = jax.jit(lambda cfg: k.eval_cfg(bank, carry, row, cfg)[0])
+    block = jax.jit(lambda cfg: k.eval_cfg(
+        bank, carry, row, cfg, je.config_rows(et.tables, cfg))[0])
     flat = jax.jit(flat_eval)
     blocked, evs = 0, []
     for col in range(n_deg):
@@ -434,7 +440,10 @@ def test_eval_cfg_masks_are_the_flat_forms(small_env_kernels):
                 (col, name)
         blocked += int(not bool(ev["ok_chan"]))
         evs.append(ev)
-    assert 0 < blocked < n_deg, "a taken channel blocks some columns"
+    if cluster == "taken":
+        assert 0 < blocked < n_deg, "a taken channel blocks some columns"
+    else:
+        assert blocked == 0
     placeable, jct = jax.jit(lambda: k.price_all(bank, carry, row))()
     assert (np.asarray(placeable) == np.array(
         [bool(e["ok_place"] & e["ok_chan"] & e["engine_ok"])

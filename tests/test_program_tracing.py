@@ -14,6 +14,7 @@ import test_fused
 import test_jax_lookahead
 import test_jax_memo
 from ddls_tpu import telemetry
+from ddls_tpu.sim.jax_env import config_rows
 from ddls_tpu.telemetry import scopes, startup
 
 pytestmark = pytest.mark.telemetry
@@ -276,6 +277,7 @@ def test_a_discarded_lane_runs_no_trips(memo_env):
 
     def trips_before_the_select(discard):
         return k.eval_cfg(bank, carry, row, cfg,
+                          config_rows(et.tables, cfg),
                           discard=discard)[0]["la_trips"]
 
     discard = jnp.asarray([False, True, False, True])
@@ -360,7 +362,7 @@ def test_an_unplaced_job_runs_no_trips_and_stays_out_of_the_memo(memo_env):
     @jax.jit
     def probe(mem, memo):
         ev, pending = k.eval_cfg(bank, (carry[0], mem) + carry[2:], row,
-                                 cfg, memo)
+                                 cfg, config_rows(et.tables, cfg), memo)
         return (ev["ok_place"], ev["la_trips"],
                 jax_memo.memo_commit(memo, pending))
 
@@ -400,7 +402,7 @@ def test_a_placement_that_stops_short_never_answers_the_complete_one(
         mem = jnp.where(jnp.arange(et.n_srv) < n_servers, unit, 0.0)
         ev, pending = k.eval_cfg(
             bank, (carry[0], mem.astype(carry[1].dtype)) + carry[2:], row,
-            cfg, memo)
+            cfg, config_rows(et.tables, cfg), memo)
         return ((ev["ok_place"], ev["engine_ok"], ev["jct"]),
                 jax_memo.memo_commit(memo, pending))
 
