@@ -43,10 +43,13 @@ from ddls_tpu.telemetry import startup
 #: counts once per drained epoch trace: the sum over the bank's models of
 #: ``graphs.arch.quadratic_time_share.<model>`` and the models summed
 #: over, so that a window's counters give the bank's mean as a ratio;
-#: then the same sums of ``branch_time_share`` and ``zero_routed_share``
+#: then the same sums of ``branch_time_share``, ``zero_routed_share``,
+#: ``index_time_share`` and ``attended_keys_share``
 BANK_GAUGES = ("graphs.arch.quadratic_time_shares", "graphs.arch.models",
                "graphs.arch.branch_time_shares",
-               "graphs.arch.zero_routed_shares")
+               "graphs.arch.zero_routed_shares",
+               "graphs.arch.index_time_shares",
+               "graphs.arch.attended_keys_shares")
 
 
 def branch_time_share(graph) -> float:
@@ -273,6 +276,13 @@ class JobsGenerator:
             # the routed pairs that cost no expert FLOPs: the config's
             zero_experts = arch.zero_experts(family[0])
             zero_routed = arch.zero_routed_share(family[0])
+            # each model's sequence length (what the learned-sparse
+            # cores read of a full core's keys depends on it), and the
+            # position streams a token has
+            seq_len = {arch.model_name(family[0], s["seq_len"],
+                                       s["micro_batch"]): int(s["seq_len"])
+                       for s in family[1]}
+            streams = arch.position_streams(family[0])
             for g in graphs:
                 model = g.meta["model"]
                 startup.set_gauge(f"graphs.arch.forward_ops.{model}",
@@ -299,6 +309,7 @@ class JobsGenerator:
                         ("layers_linear", "LinearAttnCore"),
                         ("layers_block_sparse", "BlockSparseAttnCore"),
                         ("layers_latent", "LatentAttnCore"),
+                        ("layers_indexed", "IndexScoreTopK"),
                         ("shortcut_branches", "ShortcutCombineResidual"),
                         ("shared_expert_layers", "SharedExpert")):
                     startup.set_gauge(
@@ -323,7 +334,20 @@ class JobsGenerator:
                                   zero_experts)
                 startup.set_gauge(f"graphs.arch.zero_routed_share.{model}",
                                   zero_routed)
-                shares.append((share, 1, beside, zero_routed))
+                # the indexer's projections and score, the cores behind
+                # it, and what it buys: keys read of a full core's
+                indexer = time_share(("IndexerProj", "IndexScoreTopK"))
+                startup.set_gauge(f"graphs.arch.index_time_share.{model}",
+                                  indexer)
+                startup.set_gauge(
+                    f"graphs.arch.sparse_core_time_share.{model}",
+                    time_share(("SparseAttnCore",)))
+                keys = arch.attended_keys_share(family[0], seq_len[model])
+                startup.set_gauge(
+                    f"graphs.arch.attended_keys_share.{model}", keys)
+                startup.set_gauge(f"graphs.arch.position_streams.{model}",
+                                  streams)
+                shares.append((share, 1, beside, zero_routed, indexer, keys))
             # the bank's mean of a share is its sum over the models
             for name, value in zip(BANK_GAUGES, map(sum, zip(*shares))):
                 startup.set_gauge(name, value)

@@ -9,9 +9,22 @@ made of is chosen per LAYER from the config's KEYS, never from its name:
 * attention — ``kv_lora_rank`` present: multi-head latent attention
   (low-rank q and kv paths, one function: :func:`_latent_attention`;
   each normed latent scaled where ``mla_scale_q_lora`` /
-  ``mla_scale_kv_lora`` say so), with ``index_topk`` a learned-sparse
-  core behind an indexer, without it a full causal core over the latent
-  keys (``LatentAttnCore``); layer i of
+  ``mla_scale_kv_lora`` say so), with an indexer a learned-sparse
+  core behind it, without one a full causal core over the latent
+  keys (``LatentAttnCore``). AN INDEXER (the DeepSeek-Sparse-Attention
+  lightning indexer) is stated by ``index_topk`` with ``index_n_heads``
+  / ``index_head_dim``, or by an ``sa_config`` block (``topk``,
+  ``indexer_num_heads``, ``indexer_head_dim``; one reader a size:
+  :func:`index_topk`, :func:`index_heads`, :func:`index_head_dim`) and
+  is ONE function under both attention families (``_indexer``:
+  ``IndexerProj``, ``IndexScoreTopK``, ``SparseAttnCore``): the index
+  queries come from the q latent under latent attention and from the
+  normed stream itself under MHA/GQA, the ONE index key and the head
+  weights from the stream (``sa_config.indexer_num_kv_heads`` other
+  than 1, an indexer beside a ``sparse_config``, on a window layer or
+  beside a sink logit are refused; ``sa_config.q_chunk_size`` /
+  ``kv_chunk_size`` are the tiles the scores are evaluated in, have to
+  be positive and move no count); layer i of
   a ``mixer_types`` list with ``"lightning-attn"``: linear attention
   (:func:`_linear_attention`: a d x d state a head scanned in chunks of
   ``lightning_chunk_size``, cost linear in the sequence, at the
@@ -38,18 +51,27 @@ made of is chosen per LAYER from the config's KEYS, never from its name:
   ``causal_core_count: half_square`` (OLMoE's ``modeling`` block, whose
   profiles are pinned so) a full core as S x S / 2 keys, the triangle
   without its diagonal — every other config counts S (S + 1) / 2;
+  ``rope_scaling.mrope_section`` (M-RoPE: three position streams a
+  token, :func:`position_streams`) has to sum to half the rotary part
+  of a head and costs RoPE's FLOPs; ``use_sliding_window: false`` means
+  no window layer whatever ``sliding_window`` / ``max_window_layers``
+  hold, ``true`` without a per-layer list is refused;
 * feed-forward — dense SwiGLU at ``intermediate_size`` on every layer
   of a config with NO expert key (a dense model), else on layer i where
-  a ``moe_layer_freq`` LIST has a 0 (a scalar or no key: on the first
-  ``first_k_dense_replace`` / ``num_dense_layers`` layers); the others
-  route over ``n_routed_experts`` / ``num_experts`` SwiGLU experts, with
+  a ``moe_layer_freq`` LIST has a 0 or where ``decoder_sparse_step`` /
+  ``mlp_only_layers`` say so (:func:`routed_layers`: i routes iff it is
+  not in ``mlp_only_layers`` and (i + 1) mod the step is 0; a scalar or
+  no key: on the first ``first_k_dense_replace`` / ``num_dense_layers``
+  layers); the others route over ``n_routed_experts`` / ``num_experts``
+  / ``num_local_experts`` SwiGLU experts, with
   ``n_shared_experts`` / ``num_shared_experts`` always-on ones beside
   them when the key gives a number; ``scoring_func`` / ``score_func:
   sigmoid`` picks the bias-corrected sigmoid router (under the second
   name its selected weights are normalised where ``route_norm`` and
   scaled where ``route_scale`` say so), otherwise softmax top-k (its
-  weights scaled where ``routed_scaling_factor`` is stated, its
-  selection biased where the ``modeling`` block states
+  weights scaled where ``routed_scaling_factor`` is stated and
+  renormalised, 2 FLOPs a selected weight, where ``norm_topk_prob`` is
+  true, its selection biased where the ``modeling`` block states
   ``e_score_correction_bias``); ``zero_expert_num`` zero-compute
   experts widen the router to E + Z outputs: the expert group sees
   tokens x k x held / (E + Z) pairs and the combine adds w . x for the
@@ -71,7 +93,7 @@ made of is chosen per LAYER from the config's KEYS, never from its name:
   each residual branch, ``dim_model_base`` (hidden_size / it) 1 an
   element the head reads.
 
-Six families are built today: OLMoE (full attention, softmax router:
+Seven families are built today: OLMoE (full attention, softmax router:
 embedding, L x [InputNorm, QKVProj, AttnCore, OutProjResidual,
 PostAttnNorm, Router, Experts, CombineResidual], FinalNorm, LMHeadLoss),
 ``glm_moe_dsa``, ``mimo_v2_flash`` (OLMoE's 8-op layer with
@@ -83,8 +105,13 @@ DenseMLPResidual], 7 or 9 ops) and ``longcat_flash`` (a double layer of
 21 ops: 2 x [InputNorm, QAProj, QBProj, KVAProj, KVBProj,
 LatentAttnCore, OutProjResidual, PostAttnNorm, DenseMLPResidual] with
 Router and Experts behind the first PostAttnNorm and
-ShortcutCombineResidual at the end; equations beside each op below,
-``x`` the normed hidden state).
+ShortcutCombineResidual at the end) and ``KeyeVL2`` (every layer
+[InputNorm, QKVProj, IndexerProj, IndexScoreTopK, SparseAttnCore,
+OutProjResidual, PostAttnNorm, Router, Experts, CombineResidual]: GQA
+behind the indexer, which reads InputNorm BESIDE QKVProj, no shared
+expert, no dense layer; equations beside each op below, ``x`` the
+normed hidden state). A config's ``model_type`` names the job
+(:func:`model_name`) and chooses nothing.
 Ops are at one granularity in all: each norm, each projection, the
 indexer's projections, index score + top-k, key compression, block
 score + top-k, the gate's projection, the attention core,
@@ -236,16 +263,28 @@ def window_layers(config: dict) -> Optional[List[bool]]:
     ``layer_types`` (``"sliding_attention"``); None where the config has
     neither list (every core is full). An unknown ``layer_types`` string
     is refused, and so is a list that ``global_attn_every_n_layers``
-    (every n-th layer full, the others window) contradicts."""
+    (every n-th layer full, the others window) contradicts.
+    ``use_sliding_window: false`` means no window layer whatever
+    ``sliding_window`` / ``max_window_layers`` hold (a list that has one
+    is refused); ``true`` without a list that has one is refused."""
     pattern = config.get("hybrid_layer_pattern")
     if isinstance(pattern, list):
-        return [kind == 1 for kind in pattern]
-    window = _layer_kinds(config, "layer_types", LAYER_TYPES)
-    every = config.get("global_attn_every_n_layers")
-    if window is not None and every and window != [
-            (i + 1) % int(every) != 0 for i in range(len(window))]:
-        raise ValueError(f"layer_types {config['layer_types']} disagrees "
-                         f"with global_attn_every_n_layers {every}")
+        window = [kind == 1 for kind in pattern]
+    else:
+        window = _layer_kinds(config, "layer_types", LAYER_TYPES)
+        every = config.get("global_attn_every_n_layers")
+        if window is not None and every and window != [
+                (i + 1) % int(every) != 0 for i in range(len(window))]:
+            raise ValueError(
+                f"layer_types {config['layer_types']} disagrees with "
+                f"global_attn_every_n_layers {every}")
+    use = config.get("use_sliding_window")
+    if use is not None and bool(use) != bool(window and any(window)):
+        raise ValueError(
+            f"use_sliding_window {use} beside "
+            + ("a per-layer list with window layers" if not use else
+               "no per-layer list that says which layers (sliding_window "
+               "/ max_window_layers alone give no kind a layer)"))
     return window
 
 
@@ -264,13 +303,22 @@ def linear_layers(config: dict) -> Optional[List[bool]]:
 _REQUIRED = object()
 
 
+def _lookup(config: dict, name: str):
+    """``config[name]``, a dotted ``name`` read through nested blocks
+    (``sa_config.topk``); None where any part is absent."""
+    value = config
+    for part in name.split("."):
+        value = value.get(part) if isinstance(value, dict) else None
+    return value
+
+
 def _stated(config: dict, names: Sequence[str], default=_REQUIRED):
     """The value a config states under one of ``names`` (one name a
-    family of public configs); ``default`` where it states none, a
-    ``KeyError`` where none is given. A config that states two and
-    disagrees is refused."""
-    values = {name: config[name] for name in names
-              if config.get(name) is not None}
+    family of public configs, dotted where it sits in a nested block);
+    ``default`` where it states none, a ``KeyError`` where none is
+    given. A config that states two and disagrees is refused."""
+    values = {name: value for name in names
+              if (value := _lookup(config, name)) is not None}
     if len(set(values.values())) > 1:
         raise ValueError(f"config states {values}: they disagree")
     if not values and default is _REQUIRED:
@@ -281,7 +329,8 @@ def _stated(config: dict, names: Sequence[str], default=_REQUIRED):
 def routed_experts(config: dict) -> int:
     """Routed experts an expert layer has; 0 for a config with no expert
     key (a dense model: every layer's feed-forward is dense)."""
-    return int(_stated(config, ("n_routed_experts", "num_experts"), 0))
+    return int(_stated(config, ("n_routed_experts", "num_experts",
+                                "num_local_experts"), 0))
 
 
 def stack_depth(config: dict) -> int:
@@ -307,6 +356,111 @@ def experts_per_token(config: dict) -> int:
     """Router outputs selected a token; 0 where the config states none
     (a dense model)."""
     return int(_stated(config, ("num_experts_per_tok", "moe_topk"), 0))
+
+
+def index_heads(config: dict) -> int:
+    """Query heads of the learned-sparse indexer."""
+    return int(_stated(config, ("index_n_heads",
+                                "sa_config.indexer_num_heads")))
+
+
+def index_head_dim(config: dict) -> int:
+    """Size of an indexer head (queries and the one shared key)."""
+    return int(_stated(config, ("index_head_dim",
+                                "sa_config.indexer_head_dim")))
+
+
+def index_topk(config: dict) -> int:
+    """Keys the indexer selects a query; 0 where the config states no
+    indexer (neither ``index_topk`` nor an ``sa_config`` block). What an
+    indexer states and the builder cannot build is refused here: more
+    than the ONE shared index key head the score is counted for
+    (``sa_config.indexer_num_kv_heads``), a tile of the score's
+    evaluation that is not positive (``sa_config.q_chunk_size`` /
+    ``kv_chunk_size``: the scores are never written, so no FLOP and no
+    once-read byte depends on the tiles), and an indexer beside a
+    ``sparse_config`` (two selections of one core's keys)."""
+    block = config.get("sa_config")
+    if block is None and config.get("index_topk") is None:
+        return 0
+    block = block or {}
+    if int(block.get("indexer_num_kv_heads", 1)) != 1:
+        raise ValueError(
+            f"sa_config.indexer_num_kv_heads "
+            f"{block['indexer_num_kv_heads']}: the index score is "
+            f"counted for ONE key head all index heads share")
+    for key in ("q_chunk_size", "kv_chunk_size"):
+        if key in block and not int(block[key] or 0) > 0:
+            raise ValueError(f"sa_config.{key} {block[key]}: a tile of "
+                             f"the index score has to be positive")
+    if config.get("sparse_config"):
+        raise ValueError("an indexer (index_topk / sa_config) beside a "
+                         "sparse_config: two selections of one core's keys")
+    return int(_stated(config, ("index_topk", "sa_config.topk")))
+
+
+def attended_keys_share(config: dict, seq_len: int) -> float:
+    """Keys the learned-sparse cores read over one sequence of
+    ``seq_len`` tokens over the keys full causal cores would (what the
+    indexer buys); 1 where the config states no indexer."""
+    topk = index_topk(config)
+    return attended_keys(seq_len, topk) / attended_keys(seq_len, seq_len) \
+        if topk else 1.0
+
+
+def rotary_dim(config: dict, head_dim: int) -> int:
+    """Elements of a ``head_dim`` q/k head that RoPE turns:
+    ``partial_rotary_factor`` of it (absent: the whole head), none where
+    ``attn_use_rope`` is false."""
+    return round(float(config.get("partial_rotary_factor") or 1)
+                 * head_dim) * bool(config.get("attn_use_rope", True))
+
+
+def position_streams(config: dict) -> int:
+    """Position streams a token has: the sections of
+    ``rope_scaling.mrope_section`` (M-RoPE: each section of a head's
+    rotary pairs is turned by its own stream — time, height, width; the
+    count stays RoPE's 3 an element, the sections only pick which stream
+    turns a pair); 1 where the config states none. Sections that do not
+    sum to half the rotary part of a head are refused."""
+    sections = _lookup(config, "rope_scaling.mrope_section")
+    if sections is None:
+        return 1
+    rotary = int(config["qk_rope_head_dim"]) if "kv_lora_rank" in config \
+        else rotary_dim(config, int(
+            config.get("head_dim") or int(config["hidden_size"])
+            // int(config["num_attention_heads"])))
+    if 2 * sum(sections) != rotary:
+        raise ValueError(f"rope_scaling.mrope_section {sections} sums to "
+                         f"{sum(sections)}, not to half the {rotary} "
+                         f"elements of a head that RoPE turns")
+    return len(sections)
+
+
+def routed_layers(config: dict) -> Optional[List[int]]:
+    """Per layer of the published stack 1 where its feed-forward routes
+    and 0 where it is dense: a ``moe_layer_freq`` LIST, or — where
+    ``decoder_sparse_step`` / ``mlp_only_layers`` are stated — layer i
+    routes iff i is not in ``mlp_only_layers`` and (i + 1) mod
+    ``decoder_sparse_step`` is 0. None where the config states neither
+    (the dense layers lead: ``first_k_dense_replace`` /
+    ``num_dense_layers``); both, and unequal, is refused."""
+    freq = config.get("moe_layer_freq")
+    freq = freq if isinstance(freq, list) else None
+    step, only = (config.get("decoder_sparse_step"),
+                  config.get("mlp_only_layers"))
+    if step is None and only is None:
+        return freq
+    step = 1 if step is None else int(step)
+    if step < 1:
+        raise ValueError(f"decoder_sparse_step {step}: has to be >= 1")
+    stated = [int(i not in (only or ()) and (i + 1) % step == 0)
+              for i in range(stack_depth(config))]
+    if freq is not None and freq != stated:
+        raise ValueError(
+            f"config states moe_layer_freq {freq} and decoder_sparse_step "
+            f"{step} / mlp_only_layers {only}: they disagree")
+    return stated
 
 
 #: ``zero_expert_type`` values the builder knows: an ``identity`` expert
@@ -341,12 +495,12 @@ def resolve_cut(config: dict, layers: Optional[dict] = None,
     cut leaves out taken from the config. A config with no expert key is
     dense throughout: no layer follows the dense ones and there is no
     expert to hold (``experts_held`` 0; stating one is refused)."""
-    freq = config.get("moe_layer_freq")
+    freq = routed_layers(config)
     experts = routed_experts(config)
     depth = stack_depth(config)
     if not experts:
         dense = depth
-    elif isinstance(freq, list):
+    elif freq is not None:
         dense = _leading_zeros(freq)
     else:
         dense = int(config.get("first_k_dense_replace")
@@ -372,13 +526,14 @@ def resolve_cut(config: dict, layers: Optional[dict] = None,
         raise ValueError(f"architecture layers: {cut} of a config whose "
                          f"every layer holds its expert branch "
                          f"(shortcut_sub_blocks: no dense layer leads)")
-    if isinstance(freq, list):
+    if freq is not None:
         # kinds come from the list: the cut only says how many layers
         total = cut["leading_dense"] + cut["following"]
         if total > len(freq) \
                 or _leading_zeros(freq[:total]) != cut["leading_dense"]:
             raise ValueError(
                 f"architecture layers: {cut} departs from moe_layer_freq "
+                f"/ decoder_sparse_step / mlp_only_layers "
                 f"{freq[:total]} (of {len(freq)} layers)")
     types = config.get("layer_types")
     if isinstance(types, list):
@@ -479,8 +634,8 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
     def _projections(stream, n, kv_heads, d_qk, d_v, rotary, gate,
                      value_scale=False):
         """InputNorm, ``QKVProj`` and, where the mixer is gated,
-        ``GateProj``; returns (QKVProj, GateProj or None, the width of
-        [q ; k ; v])."""
+        ``GateProj``; returns (InputNorm, QKVProj, GateProj or None, the
+        width of [q ; k ; v])."""
         q, kk, vv = n * d_qk, kv_heads * d_qk, kv_heads * d_v
         qkv = q + kk + vv
         # RoPE (3 an element) on the rotary part of each q and k head;
@@ -502,7 +657,43 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
             gate_proj = g.add("GateProj", 2 * T * H * n * d_v,
                               A * (T * H + H * n * d_v + T * n * d_v),
                               T * n * d_v, H * n * d_v, [x])
-        return proj, gate_proj, qkv
+        return x, proj, gate_proj, qkv
+
+    def _indexer(x, q_source, q_width, rope, pair, reads, out, inputs):
+        """The DSA lightning indexer and the core behind it, ONE
+        function for both attention families: ``IndexerProj``,
+        ``IndexScoreTopK``, ``SparseAttnCore``; returns the core. The
+        index queries are projected from op ``q_source`` (``q_width``
+        wide: the q latent under latent attention, the normed stream
+        ``x`` itself under MHA/GQA), RoPE turns ``rope`` elements of
+        each index head; the core costs ``pair`` FLOPs a key a query
+        reads, reads ``reads`` elements from ops ``inputs`` besides the
+        indices and writes ``out``."""
+        ni, di, topk = (index_heads(config), index_head_dim(config),
+                        index_topk(config))
+        # indexer: q^I = q_source W_Iq (q_width -> ni x di), k^I =
+        # Norm(x W_Ik) (H -> di, norm 4: ONE key head), w = x W_Iw (H ->
+        # ni); RoPE (3) on the rope part of each q^I head and of k^I
+        index_out = ni * di + di + ni
+        index_w = q_width * ni * di + H * di + H * ni
+        sources = list(dict.fromkeys([q_source, x]))
+        idx = g.add("IndexerProj",
+                    2 * T * index_w + 4 * T * di + 3 * T * (ni + 1) * rope,
+                    A * (T * q_width * (q_source != x) + T * H + index_w
+                         + di + T * index_out),
+                    T * index_out, index_w + di, sources)
+        # I[t,s] = sum_j w[t,j] ReLU(q^I[t,j] . k^I[s]) over the causal
+        # half of S x S (dot 2 di, ReLU and weighted sum 2), top-k of
+        # each row; the S x S scores are never written, out = indices
+        select = g.add("IndexScoreTopK",
+                       B * S * S / 2 * ni * (2 * di + 2),
+                       A * (T * index_out + T * min(S, topk)),
+                       T * min(S, topk), 0, [idx])
+        # o_t = sum_{s in S_t} softmax_s(q_t . k_s) v_s: a query reads
+        # min(t, topk) keys (S <= topk: the full causal count)
+        return g.add("SparseAttnCore", B * attended_keys(S, topk) * pair,
+                     A * (reads + T * min(S, topk) + out), out, 0,
+                     [*inputs, select])
 
     def _out_proj(core, stream, gate_proj, o, out_norm=False):
         """y = o W_o (o -> H) + residual; where stated, o RMS-normed
@@ -519,10 +710,10 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
                      [core, stream] + [gate_proj] * gated)
 
     def _gqa_attention(stream, window):
-        """MHA/GQA with a full causal core, past a ``sparse_config``'s
-        ``dense_len`` a block-sparse one, or, with ``window``, a
-        sliding-window one at the ``swa_*`` sizes; returns
-        OutProjResidual."""
+        """MHA/GQA with a full causal core, behind an indexer a
+        learned-sparse one, past a ``sparse_config``'s ``dense_len`` a
+        block-sparse one, or, with ``window``, a sliding-window one at
+        the ``swa_*`` sizes; returns OutProjResidual."""
         def size(key, default=None):
             value = config.get("swa_" + key) if window else None
             return int(value or config.get(key) or default)
@@ -531,9 +722,8 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
         kv_heads = size("num_key_value_heads", n)
         d_qk = size("head_dim", H // n)
         d_v = size("v_head_dim", d_qk)
-        rotary = round(float(config.get("partial_rotary_factor") or 1)
-                       * d_qk) * bool(config.get("attn_use_rope", True))
-        proj, gate_proj, qkv = _projections(
+        rotary = rotary_dim(config, d_qk)
+        x, proj, gate_proj, qkv = _projections(
             stream, n, kv_heads, d_qk, d_v, rotary,
             gate=config.get("attn_use_output_gate"),
             value_scale="attention_value_scale" in config)
@@ -583,7 +773,18 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
                          T * n * d_v, 0, [proj, select])
 
         sparse = config.get("sparse_config")
-        if sparse and not window and S > int(sparse["dense_len"]):
+        if indexed:
+            if window or sinks:
+                raise ValueError(
+                    "an indexer (index_topk / sa_config) on a "
+                    + ("sliding-window layer" if window
+                       else "core with a sink logit") + ": not built")
+            # the index queries come from x itself (no q latent) and
+            # RoPE turns an index head as far as it turns a q/k head
+            core = _indexer(x, x, H, rotary_dim(config,
+                                                index_head_dim(config)),
+                            pair, T * qkv, T * n * d_v, [proj])
+        elif sparse and not window and S > int(sparse["dense_len"]):
             core = block_sparse_core(sparse)
         else:
             if window:
@@ -612,7 +813,7 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
                        int(config["lightning_nkv"]))
         d = int(config["lightning_head_dim"])
         chunk = int(config["lightning_chunk_size"])
-        proj, gate_proj, qkv = _projections(
+        _, proj, gate_proj, qkv = _projections(
             stream, n, kv_heads, d, d,
             rotary=d * bool(config.get("lightning_use_rope")),
             gate=config.get("use_output_gate"))
@@ -676,37 +877,13 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
                    T * heads * (dn + dv), rkv * heads * (dn + dv), [c_kv])
         # QK^T 2 dqk, PV 2 dv, softmax 5 a key a query reads
         pair = heads * (2 * dqk + 2 * dv + 5)
-        if "index_topk" in config:
-            ni, di = (int(config["index_n_heads"]),
-                      int(config["index_head_dim"]))
-            topk = int(config["index_topk"])
-            # indexer: q^I = c_q W_Iq (rq -> ni x di), k^I = Norm(x
-            # W_Ik) (H -> di, norm 4), w = x W_Iw (H -> ni); RoPE (3) on
-            # the rope part of each q^I head and of k^I
-            index_out = ni * di + di + ni
-            index_w = rq * ni * di + H * di + H * ni
-            idx = g.add("IndexerProj",
-                        2 * T * index_w + 4 * T * di
-                        + 3 * T * (ni + 1) * dr,
-                        A * (T * rq + T * H + index_w + di
-                             + T * index_out),
-                        T * index_out, index_w + di, [c_q, x])
-            # I[t,s] = sum_j w[t,j] ReLU(q^I[t,j] . k^I[s]) over the
-            # causal half of S x S (dot 2 di, ReLU and weighted sum 2),
-            # top-k of each row; the S x S scores are never written,
-            # out = indices
-            select = g.add("IndexScoreTopK",
-                           B * S * S / 2 * ni * (2 * di + 2),
-                           A * (T * index_out + T * min(S, topk)),
-                           T * min(S, topk), 0, [idx])
-            # o_t = sum_{s in S_t} softmax(q_t . [k_n,s ; k_r,s]) v_s: a
-            # query reads min(t, topk) keys
-            core = g.add("SparseAttnCore",
-                         B * attended_keys(S, topk) * pair,
-                         A * (T * heads * dqk + T * heads * (dn + dv)
-                              + T * dr + T * min(S, topk)
-                              + T * heads * dv),
-                         T * heads * dv, 0, [q, kv, c_kv, select])
+        if indexed:
+            # the index queries come from the q latent, RoPE turns the
+            # rope part of an index head; the core reads q, [k_n ; v]
+            # and the one k_r
+            core = _indexer(x, c_q, rq, dr, pair,
+                            T * heads * dqk + T * heads * (dn + dv) + T * dr,
+                            T * heads * dv, [q, kv, c_kv])
         else:
             # o_t = sum_{s <= t} softmax_s(q_t . [k_n,s ; k_r,s] /
             # sqrt(dqk)) v_s: FULL causal, a query reads its t keys
@@ -719,7 +896,9 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
 
     window = window_layers(config)
     linear = linear_layers(config)
-    freq = config.get("moe_layer_freq")
+    freq = routed_layers(config)
+    indexed = bool(index_topk(config))
+    position_streams(config)    # sections that do not fit are refused
     sub_blocks = int(config.get("shortcut_sub_blocks") or 0)
     if n_mtp and (window is not None or linear is not None):
         raise ValueError("a per-layer attention list gives no kind for a "
@@ -744,8 +923,10 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
                      T * H, 3 * H * dense_inter, [x, stream])
 
     # FLOPs of the softmax router's selected weights: x
-    # routed_scaling_factor where the config states one
-    softmax_weight = T * k * ("routed_scaling_factor" in config)
+    # routed_scaling_factor where the config states one, p_sel / sum
+    # p_sel (the sum and the divide) where ``norm_topk_prob`` is true
+    softmax_weight = T * k * (("routed_scaling_factor" in config)
+                              + 2 * bool(config.get("norm_topk_prob")))
     # the selection bias b of top-k(p + b), a parameter an output, where
     # the ``modeling`` block states it
     score_bias = (E + Z) * bool(config.get("e_score_correction_bias"))
@@ -829,7 +1010,7 @@ def build_graph(config: dict, seq_len: int, micro_batch: int,
         out_proj = attention(stream, i)
         if not E:
             dense = True
-        elif isinstance(freq, list):
+        elif freq is not None:
             dense = freq[i] == 0
         else:
             dense = i is not None and i < cut["leading_dense"]

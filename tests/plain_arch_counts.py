@@ -1,10 +1,11 @@
 """Independent plain counts of a ``glm_moe_dsa``, of a
-``mimo_v2_flash``, of an ``afmoe`` (Trinity), of a ``minicpm_sala`` and
-of a ``longcat_flash`` training step: per op the parameters, the forward
-FLOPs and the elements of the output tensor (for ``afmoe``,
-``minicpm_sala`` and ``longcat_flash`` also the bytes moved and the
-edges), written straight from the layer equations (ISSUE 30, 32, 36, 39
-and 48, Tentpole step 1) and importing nothing from
+``mimo_v2_flash``, of an ``afmoe`` (Trinity), of a ``minicpm_sala``, of
+a ``longcat_flash`` and of a ``KeyeVL2`` training step: per op the
+parameters, the forward FLOPs and the elements of the output tensor (for
+``afmoe``, ``minicpm_sala``, ``longcat_flash`` and ``KeyeVL2`` also the
+bytes moved and the edges), written straight from the layer equations
+(ISSUE 30, 32, 36, 39, 48 and 52, Tentpole step 1) and importing nothing
+from
 ``ddls_tpu/graphs/arch.py``, which ``tests/test_arch_graphs.py`` holds
 to them op by op.
 
@@ -509,6 +510,99 @@ def plain_counts_longcat(c, S, B, layers=None, held=None):
                      2 * (pairs * H + pairs + zero_pairs
                           + (3 if Z else 2) * T * H),
                      [experts, stream, router] + [x0] * (Z > 0))
+    f = rmsnorm("FinalNorm", [stream])
+    add("LMHeadLoss", H * V, 2 * T * H * V + 5 * T * V, T * V,
+        2 * (T * H + H * V + T * V), [f])
+    return ops, edges
+
+
+# =============================================================== KeyeVL2
+def plain_counts_keye(c, S, B, layers=None):
+    """``(ops, edges)`` of the first ``layers`` layers (None: all
+    ``num_hidden_layers``) of a ``KeyeVL2`` language model over B
+    sequences of S tokens, in the shape :func:`plain_counts_sala`
+    returns. ``c`` is the public config. Written from ISSUE 52's
+    equations; T = S B, H = hidden_size, ``x`` a normed stream.
+
+    A layer: ``x = RMSNorm(h)``; ``[q ; k ; v] = x W_qkv`` to n q and g
+    kv heads of d, M-RoPE (3 an element) on the whole of every q and k
+    head; the indexer ``q^I = x W_Iq`` (ni heads of di), ``k^I = Norm(x
+    W_Ik)`` (ONE key head: norm 4 an element, a weight an element), ``w
+    = x W_Iw`` (ni), RoPE on each q^I head and on k^I; ``I[t, s] =
+    sum_j w[t, j] ReLU(q^I[t, j] . k^I[s])`` for s <= t over the causal
+    half of S x S (2 di + 2 a head and pair), the top ``topk`` of each
+    row, out = the indices; ``o[t, h] = sum_{s in S_t} softmax_s(q[t,
+    h] . k[s, g(h)] / sqrt(d)) v[s, g(h)]``, query t reading min(t,
+    topk) keys at 2 d + 2 d + 5 a key and q head; ``y = o W_o + h``;
+    ``x' = RMSNorm(y)``; on a layer that routes ``p = softmax(x' W_r)``
+    over E (5 a logit), top k, weights ``p_sel / sum p_sel`` (2 a
+    selected weight where ``norm_topk_prob``), E SwiGLU experts at
+    ``moe_intermediate_size`` over T k balanced pairs (silu . up 4 a
+    value), ``h = sum w . expert + y``; on a dense one (i in
+    ``mlp_only_layers`` or (i + 1) mod ``decoder_sparse_step`` != 0)
+    SwiGLU at ``intermediate_size``."""
+    T, H, V = S * B, c["hidden_size"], c["vocab_size"]
+    n, g, d = (c["num_attention_heads"], c["num_key_value_heads"],
+               c["head_dim"])
+    sa = c["sa_config"]
+    ni, di, topk = (sa["indexer_num_heads"], sa["indexer_head_dim"],
+                    sa["topk"])
+    E, k = c["num_experts"], c["num_experts_per_tok"]
+    I, Ie = c["intermediate_size"], c["moe_intermediate_size"]
+    layers = c["num_hidden_layers"] if layers is None else layers
+    step, only = c.get("decoder_sparse_step", 1), c.get("mlp_only_layers",
+                                                        [])
+    renorm = 2 * T * k if c.get("norm_topk_prob") else 0
+    qkv = n * d + 2 * g * d
+    kept = min(S, topk)
+    pairs = T * k
+    ops, edges = [], set()
+
+    def add(kind, params, flops, out, nbytes, reads=()):
+        ops.append((kind, params, flops, out, nbytes))
+        edges.update((r, len(ops)) for r in reads)
+        return len(ops)
+
+    def rmsnorm(kind, reads):
+        return add(kind, H, 4 * T * H, T * H, 2 * (2 * T * H + H), reads)
+
+    stream = add("Embedding", V * H, 0, T * H, 2 * 2 * T * H + 4 * T)
+    for i in range(layers):
+        x = rmsnorm("InputNorm", [stream])
+        proj = add("QKVProj", H * qkv,
+                   2 * T * H * qkv + 3 * T * (n + g) * d, T * qkv,
+                   2 * (T * H + H * qkv + T * qkv), [x])
+        index_w = H * ni * di + H * di + H * ni
+        index_out = ni * di + di + ni
+        idx = add("IndexerProj", index_w + di,
+                  2 * T * index_w + 4 * T * di + 3 * T * (ni + 1) * di,
+                  T * index_out,
+                  2 * (T * H + index_w + di + T * index_out), [x])
+        select = add("IndexScoreTopK", 0,
+                     B * S * S / 2 * ni * (2 * di + 2), T * kept,
+                     2 * (T * index_out + T * kept), [idx])
+        o = add("SparseAttnCore", 0,
+                B * keys_read(S, topk) * n * (2 * d + 2 * d + 5),
+                T * n * d, 2 * (T * qkv + T * kept + T * n * d),
+                [proj, select])
+        y = add("OutProjResidual", n * d * H, 2 * T * n * d * H + T * H,
+                T * H, 2 * (T * n * d + n * d * H + 2 * T * H),
+                [o, stream])
+        xp = rmsnorm("PostAttnNorm", [y])
+        if i in only or (i + 1) % step:
+            stream = add("DenseMLPResidual", 3 * H * I,
+                         2 * T * 3 * H * I + 4 * T * I + T * H, T * H,
+                         2 * (3 * T * H + 3 * H * I), [xp, y])
+            continue
+        router = add("Router", H * E, 2 * T * H * E + 5 * T * E + renorm,
+                     2 * T * k, 2 * (T * H + H * E + 2 * T * k), [xp])
+        experts = add("Experts", E * 3 * H * Ie,
+                      2 * pairs * 3 * H * Ie + 4 * pairs * Ie, pairs * H,
+                      2 * (2 * pairs * H + min(E, pairs) * 3 * H * Ie),
+                      [router, xp])
+        stream = add("CombineResidual", 0, 2 * pairs * H + T * H, T * H,
+                     2 * (pairs * H + pairs + 2 * T * H),
+                     [experts, y, router])
     f = rmsnorm("FinalNorm", [stream])
     add("LMHeadLoss", H * V, 2 * T * H * V + 5 * T * V, T * V,
         2 * (T * H + H * V + T * V), [f])

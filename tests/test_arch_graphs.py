@@ -268,17 +268,20 @@ def test_generator_takes_an_architecture_like_any_other_source(tmp_path):
         for what, n in (("forward_ops", 19), ("edges", 53),
                         ("layers_full", 2), ("layers_window", 0),
                         ("layers_linear", 0), ("layers_block_sparse", 0),
-                        ("layers_latent", 0), ("shortcut_branches", 0),
-                        ("zero_experts", 0),
+                        ("layers_latent", 0), ("layers_indexed", 0),
+                        ("shortcut_branches", 0), ("zero_experts", 0),
+                        ("position_streams", 1),
                         ("shared_expert_layers", 0))}
     assert sorted(shares) == sorted(
         [BANK_GAUGES[0], *BANK_GAUGES[2:]]
         + [f"graphs.arch.{kind}_share.{m}" for m in models
            for kind in ("quadratic_time", "linear_time", "branch_time",
-                        "zero_routed")])
-    # a chain with shortcut edges alone: nothing runs beside it, and no
-    # router output is a zero-compute expert
-    assert [gauges[name] for name in BANK_GAUGES[2:]] == [0, 0]
+                        "zero_routed", "index_time", "sparse_core_time",
+                        "attended_keys")])
+    # a chain with shortcut edges alone: nothing runs beside it, no
+    # router output is a zero-compute expert, and with no indexer every
+    # key of a full core is read
+    assert [gauges[name] for name in BANK_GAUGES[2:]] == [0, 0, 0, 2]
     # an unstated family: what a dep or a sync edge is sized by is the
     # largest op's whole memory cost
     for m in models:
@@ -2916,8 +2919,691 @@ def test_generator_sets_the_longcat_gauges(tmp_path):
         assert gauges[f"graphs.arch.quadratic_time_share.{m}"] > 0
     shares = [gauges[f"graphs.arch.branch_time_share.{m}"] for m in models]
     assert all(0.05 < s < 0.5 for s in shares)
-    assert [gauges[name] for name in BANK_GAUGES[1:]] == [
+    assert [gauges[name] for name in BANK_GAUGES[1:4]] == [
         2, sum(shares), 2 * 4 / 12]
     assert arch.zero_routed_share(arch.load_arch_config(LONGCAT_FILE)) \
         == 256 / 768
     assert arch.zero_routed_share(TINY_GLM) == 0.0
+
+
+# =============================================================== KeyeVL2
+KEYE_FILE = "ddls_tpu/graphs/arch_configs/keye_vl_2_30b_a3b.json"
+#: the deployment's cut (env_keye_32.yaml): 24 of the 48 layers, all 128
+#: experts (no ``experts_held``)
+KEYE_CUT = {"layers": {"leading_dense": 0, "following": 24}}
+KEYE_SHAPES = [(8192, 4), (32768, 1), (65536, 1), (131072, 1)]
+#: 2 layers, hidden 64, 4 q / 2 kv heads of 16 (M-RoPE: 2 + 3 + 3 = 8
+#: pairs), an indexer of 2 heads of 8 over ONE key head whose top-16 is
+#: under the tiny sequence of 32 (both regimes of ``attended_keys`` in
+#: one graph), 8 experts (2 a token, renormalised), no shared expert,
+#: under KeyeVL2's key names
+TINY_KEYE = {"model_type": "tinykeye", "hidden_size": 64,
+             "num_attention_heads": 4, "num_key_value_heads": 2,
+             "head_dim": 16, "intermediate_size": 128,
+             "moe_intermediate_size": 32, "num_experts": 8,
+             "num_local_experts": 8, "num_experts_per_tok": 2,
+             "norm_topk_prob": True, "decoder_sparse_step": 1,
+             "mlp_only_layers": [], "num_hidden_layers": 2,
+             "rope_scaling": {"mrope_section": [2, 3, 3],
+                              "rope_type": "default", "type": "default"},
+             "sa_config": {"indexer_head_dim": 8, "indexer_num_heads": 2,
+                           "indexer_num_kv_heads": 1, "kv_chunk_size": 8,
+                           "q_chunk_size": 8, "topk": 16},
+             "sliding_window": None, "use_sliding_window": False,
+             "max_window_layers": 2, "vocab_size": 256}
+#: the ops of one layer, in profile order
+KEYE_LAYER = ["InputNorm", "QKVProj", "IndexerProj", "IndexScoreTopK",
+              "SparseAttnCore", "OutProjResidual", "PostAttnNorm",
+              "Router", "Experts", "CombineResidual"]
+
+
+@pytest.fixture(scope="module")
+def keye():
+    return arch.load_arch_config(KEYE_FILE)
+
+
+def _tiny_keye_arch_file(directory) -> str:
+    path = os.path.join(str(directory), "tinykeye.json")
+    with open(path, "w") as fh:
+        json.dump({"source_url": "test-local", "training_state": STATE,
+                   "config": TINY_KEYE}, fh)
+    return path
+
+
+def _assert_equals_plain(built, plain, edges):
+    assert [o["op_type"] for o in built.ops] == [p[0] for p in plain]
+    for i, (o, (kind, params, flops, out, nbytes)) in enumerate(
+            zip(built.ops, plain)):
+        assert o["params"] == params, (i, kind)
+        assert o["flops"] == pytest.approx(flops, rel=1e-12), (i, kind)
+        assert o["out_elems"] == pytest.approx(out, rel=1e-12), (i, kind)
+        assert o["bytes"] == pytest.approx(nbytes, rel=1e-12), (i, kind)
+    assert set(built.edges) == edges and len(built.edges) == len(edges)
+
+
+@pytest.mark.parametrize("case", ["tiny_s8", "tiny_s37", "keye_8k_x4",
+                                  "keye_32k", "keye_64k", "keye_128k"])
+def test_keye_op_costs_equal_the_plain_count_op_by_op(keye, case):
+    """`plain_counts_keye` is written from ISSUE 52's equations and
+    imports nothing of the builder: op names, parameters, FLOPs, output
+    elements, bytes moved and the edge set agree at two tiny shapes (a
+    sequence under the indexer's top-k and one over it) and the cell's
+    four."""
+    from plain_arch_counts import plain_counts_keye
+
+    config, seq_len, micro_batch, layers = {
+        "tiny_s8": (TINY_KEYE, 8, 3, None),
+        "tiny_s37": (TINY_KEYE, 37, 5, 1),
+        "keye_8k_x4": (keye, 8192, 4, 24),
+        "keye_32k": (keye, 32768, 1, 24),
+        "keye_64k": (keye, 65536, 1, 24),
+        "keye_128k": (keye, 131072, 1, 24)}[case]
+    built = arch.build_graph(
+        config, seq_len, micro_batch,
+        None if layers is None else {"leading_dense": 0,
+                                     "following": layers})
+    _assert_equals_plain(built, *plain_counts_keye(config, seq_len,
+                                                   micro_batch, layers))
+    depth = config["num_hidden_layers"] if layers is None else layers
+    # 10 ops and 15 edges a layer: an edge for every true data
+    # dependency and no other
+    assert [o["op_type"] for o in built.ops] == (
+        ["Embedding"] + KEYE_LAYER * depth + ["FinalNorm", "LMHeadLoss"])
+    assert len(built.edges) == 15 * depth + 2
+    first = [(u - 1, v - 1) for u, v in built.edges if v <= 11]
+    names = ["Embedding"] + KEYE_LAYER
+    assert sorted((names[u], names[v]) for u, v in first) == sorted([
+        ("Embedding", "InputNorm"), ("InputNorm", "QKVProj"),
+        ("InputNorm", "IndexerProj"), ("IndexerProj", "IndexScoreTopK"),
+        ("QKVProj", "SparseAttnCore"), ("IndexScoreTopK", "SparseAttnCore"),
+        ("SparseAttnCore", "OutProjResidual"),
+        ("Embedding", "OutProjResidual"),
+        ("OutProjResidual", "PostAttnNorm"), ("PostAttnNorm", "Router"),
+        ("Router", "Experts"), ("PostAttnNorm", "Experts"),
+        ("Experts", "CombineResidual"),
+        ("OutProjResidual", "CombineResidual"),
+        ("Router", "CombineResidual")])
+
+
+@pytest.mark.parametrize("quantity", ["whole", "stage"])
+def test_keye_whole_and_stage_are_the_published_model(keye, quantity):
+    """Whole, the language model counts 30.64 B parameters (published
+    30B; the vision tower has no key and is left out) and 3.46 B
+    active a token (3.15 B beside the embedding table: A3B); the stage the cell queues is 15.63 B: 243 forward
+    ops, 362 forward edges."""
+    if quantity == "whole":
+        ops = arch.op_costs(keye, 8192, 1)
+        assert len(ops) == 1 + 10 * 48 + 2 == 483
+        assert sum(o["params"] for o in ops) == 30_640_641_024 \
+            == 48 * 625_381_440 + 2 * 311_164_928 + 2048
+        expert = 3 * 2048 * 768
+        active = sum(o["params"] for o in ops
+                     if o["op_type"] != "Experts") + 48 * 8 * expert
+        # 3.46 B with the embedding table, 3.15 B beside it (A3B)
+        assert active == 3_461_551_104
+        assert 3.1e9 < active - 151936 * 2048 < 3.2e9
+        assert arch.resolve_cut(keye) == {
+            "leading_dense": 0, "following": 48, "experts_held": 128}
+        assert arch.routed_layers(keye) == [1] * 48
+    else:
+        graph = arch.build_graph(keye, 8192, 4, **KEYE_CUT)
+        assert sum(o["params"] for o in graph.ops) == 15_631_486_464
+        assert (len(graph.ops), len(graph.edges)) == (243, 362)
+        layer = {o["op_type"]: o["params"] for o in graph.ops[1:11]}
+        assert layer == {
+            "InputNorm": 2048, "QKVProj": 10_485_760,
+            "IndexerProj": 2_260_992 + 64, "IndexScoreTopK": 0,
+            "SparseAttnCore": 0, "OutProjResidual": 8_388_608,
+            "PostAttnNorm": 2048, "Router": 262_144,
+            "Experts": 603_979_776, "CombineResidual": 0}
+        assert sum(layer.values()) == 625_381_440
+        # the model name is the config's own model_type, nothing else is
+        assert arch.model_name(keye, 8192, 4) == "KeyeVL2_s8192_b4"
+        renamed = arch.build_graph({**keye, "model_type": "anything"},
+                                   8192, 4, **KEYE_CUT)
+        assert renamed.ops == graph.ops and renamed.edges == graph.edges
+        assert "modeling" not in arch.load_arch_file(KEYE_FILE)
+
+
+def test_glm5_and_keye_take_the_indexer_from_one_function(glm, keye):
+    """`IndexerProj`, `IndexScoreTopK` and `SparseAttnCore` are added in
+    ONE place, `build_graph`'s `_indexer`, which both attention families
+    call; at equal indexer sizes the score op is the same op whatever
+    the family (it reads nothing of the q/k/v path), the projections
+    differ by where the index queries come from (the q latent, the
+    normed stream) and the core by its family's head arithmetic."""
+    import inspect
+
+    source = inspect.getsource(arch.build_graph)
+    for op in ("IndexerProj", "IndexScoreTopK", "SparseAttnCore"):
+        assert source.count(f'"{op}"') == 1, op
+    body = source[source.index("def _indexer("):source.index(
+        "def _out_proj(")]
+    assert all(f'"{op}"' in body for op in (
+        "IndexerProj", "IndexScoreTopK", "SparseAttnCore"))
+    assert source.count("_indexer(") == 3       # the def and two calls
+    S, B = 32, 3
+    T, H = S * B, 64
+    by = lambda c: {o["op_type"]: o for o in arch.op_costs(c, S, B)}
+    tiny_glm, tiny_keye = by(TINY_GLM), by(TINY_KEYE)
+    assert tiny_glm["IndexScoreTopK"] == tiny_keye["IndexScoreTopK"]
+    ni, di, rq, dr = 2, 8, 32, 4
+    # q^I from the 32-wide q latent (RoPE on 4 of 8) | from x (all 8)
+    assert tiny_glm["IndexerProj"]["params"] \
+        == rq * ni * di + H * di + H * ni + di
+    assert tiny_keye["IndexerProj"]["params"] \
+        == H * ni * di + H * di + H * ni + di
+    assert tiny_glm["IndexerProj"]["flops"] - 3 * T * (ni + 1) * dr \
+        - 2 * T * rq * ni * di \
+        == tiny_keye["IndexerProj"]["flops"] - 3 * T * (ni + 1) * di \
+        - 2 * T * H * ni * di
+    keys = sum(min(t, 16) for t in range(1, S + 1))
+    assert tiny_glm["SparseAttnCore"]["flops"] \
+        == B * keys * 4 * (2 * 16 + 2 * 16 + 5) \
+        == tiny_keye["SparseAttnCore"]["flops"]
+    # at full size: one reader a size, both families of names
+    assert (arch.index_heads(glm), arch.index_head_dim(glm),
+            arch.index_topk(glm)) == (32, 128, 2048)
+    assert (arch.index_heads(keye), arch.index_head_dim(keye),
+            arch.index_topk(keye)) == (16, 64, 2048)
+    assert arch.index_topk(TINY) == 0
+
+    def edges(config, op):
+        """Op types the first layer's ``op`` reads."""
+        graph = arch.build_graph(config, S, B)
+        return {graph.ops[u - 1]["op_type"] for u, v in graph.edges
+                if graph.ops[v - 1]["op_type"] == op and v <= 12}
+
+    assert edges(TINY_GLM, "IndexerProj") == {"QAProj", "InputNorm"}
+    assert edges(TINY_KEYE, "IndexerProj") == {"InputNorm"}
+    assert edges(TINY_KEYE, "SparseAttnCore") == {"QKVProj",
+                                                  "IndexScoreTopK"}
+
+
+@pytest.mark.parametrize("seq_len", [1, 7, 16, 17, 64])
+def test_gqa_sparse_core_reads_min_t_topk_keys_a_query(seq_len):
+    """Under the top-k the learned-sparse core is the full causal count
+    (no second graph is needed), over it a query reads top-k keys."""
+    ops = {o["op_type"]: o for o in arch.op_costs(TINY_KEYE, seq_len, 3)}
+    keys = sum(min(t, 16) for t in range(1, seq_len + 1))
+    assert ops["SparseAttnCore"]["flops"] == 3 * keys * 4 * (4 * 16 + 5)
+    assert ops["IndexScoreTopK"]["out_elems"] == 3 * seq_len * min(
+        seq_len, 16)
+    full = {**TINY_KEYE}
+    del full["sa_config"]
+    core = next(o for o in arch.op_costs(full, seq_len, 3)
+                if o["op_type"] == "AttnCore")
+    assert (core["flops"] == ops["SparseAttnCore"]["flops"]) \
+        == (seq_len <= 16)
+    assert "IndexScoreTopK" in arch.QUADRATIC_OPS
+    assert "SparseAttnCore" not in arch.QUADRATIC_OPS
+
+
+def _keye_with(**over):
+    """TINY_KEYE with top-level keys replaced (None: dropped) and, under
+    ``sa``, entries of its ``sa_config``."""
+    sa = over.pop("sa", None)
+    config = {**TINY_KEYE, **over}
+    if sa is not None:
+        config["sa_config"] = {**TINY_KEYE["sa_config"], **sa}
+    return {k: v for k, v in config.items() if v is not None}
+
+
+@pytest.mark.parametrize("case, over, match", [
+    ("heads_stated_twice_unequal", {"index_n_heads": 3}, "disagree"),
+    ("head_dim_stated_twice_unequal", {"index_head_dim": 4}, "disagree"),
+    ("topk_stated_twice_unequal", {"index_topk": 8}, "disagree"),
+    ("two_index_key_heads", {"sa": {"indexer_num_kv_heads": 2}},
+     "indexer_num_kv_heads"),
+    ("q_chunk_zero", {"sa": {"q_chunk_size": 0}}, "q_chunk_size"),
+    ("kv_chunk_negative", {"sa": {"kv_chunk_size": -8}}, "kv_chunk_size"),
+    ("mrope_sections_do_not_sum", {"rope_scaling": {
+        "mrope_section": [2, 3, 4]}}, "mrope_section"),
+    ("beside_sparse_config", {"sparse_config": {"dense_len": 8}},
+     "sparse_config"),
+    ("on_a_window_layer", {
+        "use_sliding_window": True, "sliding_window": 8,
+        "layer_types": ["sliding_attention", "full_attention"]},
+     "sliding-window layer"),
+    ("beside_a_sink_logit", {"add_full_attention_sink_bias": True},
+     "sink logit"),
+    ("sliding_true_without_a_list", {"use_sliding_window": True,
+                                     "sliding_window": 8},
+     "use_sliding_window"),
+    ("sliding_false_beside_window_layers", {
+        "sliding_window": 8,
+        "layer_types": ["sliding_attention", "full_attention"]},
+     "use_sliding_window"),
+    ("expert_counts_unequal", {"num_local_experts": 4}, "disagree"),
+    ("sparse_step_zero", {"decoder_sparse_step": 0},
+     "decoder_sparse_step"),
+    ("freq_list_disagrees", {"moe_layer_freq": [0, 1]}, "disagree"),
+])
+def test_what_keye_states_and_cannot_be_built_is_refused(case, over, match):
+    """A stated key that cannot be built is REFUSED, never ignored: a
+    size stated under both families of names and unequal, more than one
+    index key head, a tile that is not positive, M-RoPE sections that do
+    not sum to half the head, an indexer beside a `sparse_config`, on a
+    window layer or beside a sink logit, `use_sliding_window` against
+    the per-layer list, two expert counts, two statements of which
+    layers route."""
+    arch.build_graph(TINY_KEYE, 8, 1)         # the base builds
+    with pytest.raises(ValueError, match=match):
+        arch.build_graph(_keye_with(**over), 8, 1)
+
+
+def test_keye_keys_that_agree_or_say_nothing_build_the_same_graph(keye):
+    """Stated twice and EQUAL is not refused; `use_sliding_window:
+    false` builds no window layer whatever `sliding_window` /
+    `max_window_layers` hold; the tiles move no count; `model_type`
+    names the job and nothing else; M-RoPE's three streams cost RoPE's
+    FLOPs."""
+    base = arch.build_graph(TINY_KEYE, 32, 2)
+    same = [_keye_with(index_n_heads=2, index_head_dim=8, index_topk=16),
+            _keye_with(sliding_window=4, max_window_layers=0),
+            _keye_with(sa={"q_chunk_size": 512, "kv_chunk_size": 1}),
+            _keye_with(rope_scaling=None),
+            _keye_with(moe_layer_freq=[1, 1]),
+            _keye_with(mlp_only_layers=None),
+            _keye_with(num_local_experts=None),
+            _keye_with(model_type="another")]
+    for config in same:
+        got = arch.build_graph(config, 32, 2)
+        assert got.ops == base.ops and got.edges == base.edges
+    assert "WindowAttnCore" not in {o["op_type"] for o in base.ops}
+    assert arch.position_streams(keye) == 3
+    assert arch.position_streams(TINY_KEYE) == 3
+    assert arch.position_streams(TINY_GLM) == 1
+    assert keye["rope_scaling"]["mrope_section"] == [16, 24, 24] \
+        and sum(keye["rope_scaling"]["mrope_section"]) == 128 // 2
+    assert arch.window_layers(keye) is None
+    # the flat keys alone (GLM-5's names) under grouped-query attention
+    flat = _keye_with(sa_config=None, index_n_heads=2, index_head_dim=8,
+                      index_topk=16)
+    got = arch.build_graph(flat, 32, 2)
+    assert got.ops == base.ops and got.edges == base.edges
+
+
+@pytest.mark.parametrize("case", ["step_2_only_0", "step_1_only_1",
+                                  "cut_departs"])
+def test_sparse_step_and_mlp_only_layers_say_which_layers_route(case):
+    """Layer i routes iff i is not in `mlp_only_layers` and (i + 1) mod
+    `decoder_sparse_step` is 0; the others are dense SwiGLU at
+    `intermediate_size`, through the per-layer path a `moe_layer_freq`
+    list takes (the cut keeps the stack's first layers)."""
+    from plain_arch_counts import plain_counts_keye
+
+    dense = KEYE_LAYER[:7] + ["DenseMLPResidual"]
+    if case == "step_2_only_0":
+        config = _keye_with(num_hidden_layers=4, decoder_sparse_step=2,
+                            mlp_only_layers=[0], max_window_layers=4)
+        assert arch.routed_layers(config) == [0, 1, 0, 1]
+        assert arch.resolve_cut(config) == {
+            "leading_dense": 1, "following": 3, "experts_held": 8}
+        built = arch.build_graph(config, 32, 2)
+        assert [o["op_type"] for o in built.ops] == (
+            ["Embedding"] + dense + KEYE_LAYER + dense + KEYE_LAYER
+            + ["FinalNorm", "LMHeadLoss"])
+        _assert_equals_plain(built, *plain_counts_keye(config, 32, 2))
+        mlp = next(o for o in built.ops
+                   if o["op_type"] == "DenseMLPResidual")
+        assert mlp["params"] == 3 * 64 * 128
+        # a stage of the first two layers: the dense one and one that
+        # routes
+        stage = arch.build_graph(config, 32, 2, {"leading_dense": 1,
+                                                 "following": 1})
+        assert [o["op_type"] for o in stage.ops] == (
+            ["Embedding"] + dense + KEYE_LAYER + ["FinalNorm",
+                                                  "LMHeadLoss"])
+    elif case == "step_1_only_1":
+        config = _keye_with(num_hidden_layers=3, mlp_only_layers=[1])
+        assert arch.routed_layers(config) == [1, 0, 1]
+        built = arch.build_graph(config, 32, 2)
+        assert [o["op_type"] for o in built.ops] == (
+            ["Embedding"] + KEYE_LAYER + dense + KEYE_LAYER
+            + ["FinalNorm", "LMHeadLoss"])
+        _assert_equals_plain(built, *plain_counts_keye(config, 32, 2))
+    else:
+        config = _keye_with(num_hidden_layers=4, decoder_sparse_step=2)
+        with pytest.raises(ValueError, match="decoder_sparse_step"):
+            arch.build_graph(config, 8, 1, {"leading_dense": 0,
+                                            "following": 2})
+        with pytest.raises(ValueError, match="decoder_sparse_step"):
+            arch.resolve_cut(config, {"leading_dense": 1, "following": 4})
+        # the older families state neither key: their lists are theirs
+        assert arch.routed_layers(TINY_GLM) is None
+        assert arch.routed_layers(TINY_MIMO) == TINY_MIMO["moe_layer_freq"]
+
+
+@pytest.mark.parametrize("family", ["keye", "olmoe_false", "longcat_absent",
+                                    "sigmoid"])
+def test_norm_topk_prob_is_two_flops_a_selected_weight_on_softmax(
+        olmoe, longcat, family):
+    """`norm_topk_prob: true` on a SOFTMAX router: the sum and the
+    divide of p_sel / sum p_sel, 2 FLOPs a selected weight. OLMoE states
+    `false`, LongCat nothing; the sigmoid routers (GLM-5, MiMo state it
+    `true`) count their three a weight as before."""
+    S, B = 32, 2
+    T, H = S * B, 64
+    router = lambda c: next(o for o in arch.op_costs(c, S, B)
+                            if o["op_type"] == "Router")
+    if family == "keye":
+        with_norm, without = (router(TINY_KEYE),
+                              router(_keye_with(norm_topk_prob=False)))
+        assert with_norm["flops"] - without["flops"] == 2 * T * 2
+        assert with_norm["flops"] == 2 * T * H * 8 + 5 * T * 8 + 2 * T * 2
+        assert {k: v for k, v in with_norm.items() if k != "flops"} \
+            == {k: v for k, v in without.items() if k != "flops"}
+    elif family == "olmoe_false":
+        assert olmoe["norm_topk_prob"] is False
+        assert router(TINY) == router({**TINY, "norm_topk_prob": False})
+        assert router({**TINY, "norm_topk_prob": True})["flops"] \
+            - router(TINY)["flops"] == 2 * T * TINY["num_experts_per_tok"]
+    elif family == "longcat_absent":
+        assert "norm_topk_prob" not in longcat
+        assert router(TINY_LONGCAT_BUILT)["flops"] \
+            == 2 * T * H * 12 + 5 * T * 12 + T * 3
+    else:
+        assert router({**TINY_GLM, "norm_topk_prob": True}) \
+            == router(TINY_GLM)
+
+
+#: the deployments of the six older families (their env yamls state the
+#: cut and the shapes as data) and the sha256 of each profile as the
+#: parent of PR 52 wrote it
+OLDER_PROFILES_SHA256 = {
+    ("env_olmoe32", 4096, 1):
+        "e37d732fdece58a9690ddecfee7e1b2c59e8a469047e40e1633973723963d263",
+    ("env_olmoe32", 4096, 2):
+        "89ce01d98f6416e189da20c491a8b1cbe80ceb430a9aa3a3d0ae5dde06915a0a",
+    ("env_olmoe32", 4096, 4):
+        "8aba20ad93284818802a2662483d29cafe48784ba07276e1c2de6260229824e0",
+    ("env_olmoe32", 4096, 8):
+        "433fef7186272ee4ca9bbf18c6d416d744267f3ef95029eb6958b15ebb96f305",
+    ("env_glm5_32", 8192, 1):
+        "a35b7d33627fa75c547574150e7600ff3e45eb34ecba07455c1f6225f2930f84",
+    ("env_glm5_32", 8192, 4):
+        "f7f69f829bc6fb32d96501e42f1de8055f4f1d5d604cc91c95a2f4fba79c6892",
+    ("env_glm5_32", 32768, 1):
+        "796597f437747b3801d9899db80e46b8f708a258cd7ec0e771eb1fa2f1ad75a4",
+    ("env_glm5_32", 65536, 1):
+        "9fb3efb29c5d2176570e6986500d4af38838d39d6c8097928826af318f4ad07e",
+    ("env_mimo_32", 8192, 4):
+        "b8d04ab4c6bdb8a0cee1f8b0dc568eb067f161cc2d92560676b05370df00b410",
+    ("env_mimo_32", 32768, 1):
+        "f11dd41b33879babe8018511e6d9735567bdff77629b317381a42b74f506752d",
+    ("env_mimo_32", 65536, 1):
+        "c7302b5ab73fdfe49a12919929bca036dcacfe2401ef2b24bb11bac50873ca8a",
+    ("env_mimo_32", 262144, 1):
+        "b898a48b56d5be2a35a2c33152ea6085ba377108ff04bc675161daf6f9899931",
+    ("env_trinity_32", 8192, 4):
+        "31cd86399c1e6a53f4099c6ece8596e3f027e10174e5e6bd24d795588a7cb8f0",
+    ("env_trinity_32", 32768, 1):
+        "d09d8fc5fd9cf136f4bcdb1070ba8745438b3ec3a82b7ccc8fc433257993156e",
+    ("env_trinity_32", 65536, 1):
+        "e4cf6a995a44a5ab0756d6db6c1c4375e6794bc8fb5a4da14414dcc4f5e45dad",
+    ("env_trinity_32", 131072, 1):
+        "5d15e437b33d882df9a99d771193362b292b7eee7c2e2fab2b10c599b089107a",
+    ("env_sala_32", 4096, 1):
+        "a88ead2af4d63170bdcc4d0f8d4a13f68aa886a495622fab0e0dd14f0428bb70",
+    ("env_sala_32", 8192, 4):
+        "61371ad7db96903573e122f432749f01a2f3ded8e42bff7a03984b71f6fe8ffd",
+    ("env_sala_32", 32768, 1):
+        "c9fd769c42aec16eb94c29f4a09f48defeca29cd9c67862d165768aa91470b38",
+    ("env_sala_32", 131072, 1):
+        "0409af227c8303ff4aa16d322df817c9a63306bceee0029864138cfdf0c0ab2e",
+    ("env_longcat_32", 8192, 1):
+        "3857fd1ba59deb417a50111c5462edfcc3211ea2255b44a46f6b0b1ff8f6f339",
+    ("env_longcat_32", 8192, 4):
+        "4382bc323282cdb7b78c9a227e46ad15d16b3c11317534d2ae83ec98d5707597",
+    ("env_longcat_32", 32768, 1):
+        "2fca134457fd734740121c5989fafb14ab893a85c9e41d5e24b4164efc26371e",
+    ("env_longcat_32", 131072, 1):
+        "55dda6a86e039ed9418120d74238d0b1e9419cebda5c8b68e46a6d5d09d7d0f0"}
+KEYE_SHA256 = {
+    (8192, 4):
+        "d2cde2c3262c5efa833d957c441c3f8fa5f60a678b8bba8d3df18f9b832679e4",
+    (32768, 1):
+        "e830d12a783ed57d078436ca520fb01e0882019be131a73c448728d461ffc7e4",
+    (65536, 1):
+        "9e35a97e5010fa409e0991fc9ff36d771075e4bb986b7b8d1a47173495c47b56",
+    (131072, 1):
+        "3ecf51aea096d6d7af644ec8470e8568abb4662919ac25fe35fe5110337c25e0"}
+
+
+def _env_config(name):
+    from ddls_tpu.config import load_config
+
+    return load_config(
+        os.path.join(REPO, "scripts/ramp_job_partitioning_configs"),
+        "rllib_config", [f"env_config={name}"])["env_config"]
+
+
+def _deployment_profile(env_name, seq_len, micro_batch) -> str:
+    """The profile `jobs_generator` writes for one shape of a
+    deployment, from its env yaml's own `jobs_config.architecture`."""
+    import hashlib
+
+    family = _env_config(env_name)["jobs_config"]["architecture"]
+    arch_file = arch.load_arch_file(family["config"])
+    text = arch.profile_text(
+        arch.builder_config(arch_file), seq_len, micro_batch,
+        family.get("layers"), family.get("experts_held"),
+        arch_file.get("training_state"))
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize(
+    "deployment", sorted(OLDER_PROFILES_SHA256),
+    ids=["%s_s%d_b%d" % d for d in sorted(OLDER_PROFILES_SHA256)])
+def test_the_24_older_profiles_are_byte_equal_to_the_parents(deployment):
+    """PR 52 moved the indexer out of `_latent_attention`, read the
+    indexer's sizes, the expert count and which layers route through new
+    readers and counted `norm_topk_prob` on the softmax router: every
+    profile of the six older families' deployments is the parent's,
+    byte for byte."""
+    env_name, seq_len, micro_batch = deployment
+    shapes = _env_config(env_name)["jobs_config"]["architecture"]["shapes"]
+    assert {"seq_len": seq_len, "micro_batch": micro_batch} in shapes
+    assert len(shapes) == 4
+    assert _deployment_profile(*deployment) \
+        == OLDER_PROFILES_SHA256[deployment]
+
+
+@pytest.mark.parametrize("shape", KEYE_SHAPES)
+def test_keye_profiles_are_pinned(shape):
+    """The four profiles of `keye_ramp32.train_fused`, byte for byte: a
+    later change to a shared count shows here."""
+    assert _deployment_profile("env_keye_32", *shape) == KEYE_SHA256[shape]
+
+
+def test_keye_env_yaml_states_what_its_comments_derive(keye):
+    """env_keye_32.yaml: the cut and the shapes as data, the arrival gap
+    and horizon derived from the builder's graph, env_olmoe32's pads
+    rule, which rows are ragged, and nothing else changed from
+    env_trinity_32."""
+    import math
+
+    from ddls_tpu.agents.partitioners import sip_ml_num_partitions
+
+    cfg, base = _env_config("env_keye_32"), _env_config("env_trinity_32")
+    jobs = cfg["jobs_config"]
+    family = jobs["architecture"]
+    assert family["config"] == KEYE_FILE
+    assert family["layers"] == KEYE_CUT["layers"]
+    assert "experts_held" not in family
+    shapes = family["shapes"]
+    assert [(s["seq_len"], s["micro_batch"]) for s in shapes] == KEYE_SHAPES
+    assert keye["max_position_embeddings"] == 262144
+    steps = jobs["num_training_steps"]
+    costs = [arch.op_costs(keye, **s, **KEYE_CUT) for s in shapes]
+    forward = [sum(arch.forward_time(c) for c in ops) for ops in costs]
+    assert forward == pytest.approx([1.1267, 1.3005, 3.0197, 7.6950],
+                                    abs=5e-4)
+    lengths = [steps * (1 + arch.BACKWARD_OVER_FORWARD) * f
+               for f in forward]
+    assert lengths == pytest.approx([67.599, 78.029, 181.181, 461.702],
+                                    abs=1e-3)
+    gap = np.mean(lengths) / 25
+    two_figures = round(gap, 1 - int(math.floor(math.log10(gap))))
+    assert jobs["job_interarrival_time_dist"]["val"] == two_figures == 7.9
+    assert cfg["max_simulation_run_time"] == pytest.approx(400 * two_figures)
+    assert [len(ops) for ops in costs] == [243] * 4
+    n_ops, n_deps = 486, 725
+    assert cfg["pad_obs_kwargs"] == {"max_nodes": 50 * -(-n_ops // 50),
+                                     "max_edges": 256 * -(-n_deps // 256)}
+
+    # what the header says of a forward pass: the indexer and the cores
+    def share(ops, kinds):
+        return sum(arch.forward_time(o) for o in ops
+                   if o["op_type"] in kinds) / sum(
+            arch.forward_time(o) for o in ops)
+
+    assert [share(ops, ("IndexerProj", "IndexScoreTopK")) for ops in costs] \
+        == pytest.approx([0.0700, 0.1796, 0.2912, 0.4429], abs=5e-5)
+    assert [share(ops, ("SparseAttnCore",)) for ops in costs] \
+        == pytest.approx([0.1592, 0.1527, 0.1336, 0.1057], abs=5e-5)
+    assert [arch.attended_keys(s["seq_len"], 2048)
+            / arch.attended_keys(s["seq_len"], s["seq_len"])
+            for s in shapes] == pytest.approx(
+        [0.4375, 0.1211, 0.0615, 0.0310], abs=5e-5)
+    # with full cores the longest job would be about four times as long
+    full = {k: v for k, v in keye.items() if k != "sa_config"}
+    assert sum(arch.forward_time(c) for c in arch.op_costs(
+        full, 131072, 1, **KEYE_CUT)) / forward[3] \
+        == pytest.approx(3.9, abs=0.1)
+    # the ragged rows: the norms, routers and embedding of a
+    # 32,768-token step at 13 quanta
+    quantum, top = cfg["min_op_run_time_quantum"], cfg[
+        "max_partitions_per_op"]
+    ragged = [sorted({(o["op_type"], sip_ml_num_partitions(
+        arch.forward_time(o), quantum, top)) for o in ops
+        if sip_ml_num_partitions(arch.forward_time(o), quantum, top) < top})
+        for ops in costs]
+    thirteen = [("Embedding", 14), ("FinalNorm", 14), ("InputNorm", 14),
+                ("PostAttnNorm", 14), ("Router", 14)]
+    assert ragged == [thirteen, thirteen, [], []]
+    assert sum(sip_ml_num_partitions(arch.forward_time(o), quantum, top)
+               < top for o in costs[0]) == 74
+    # a job's memory: parameter state + 2 x activations
+    state = 16 * sum(o["params"] for o in costs[0])
+    assert state == pytest.approx(250.1e9, abs=5e7)
+    jobs_gb = [(state + 2 * arch.ACT_BYTES
+                * sum(o["out_elems"] for o in ops)) / 1e9 for ops in costs]
+    assert jobs_gb == pytest.approx([386.8, 386.8, 523.5, 797.0], abs=0.05)
+    assert [math.ceil(gb / 80) for gb in jobs_gb] == [5, 5, 7, 10]
+    longest = arch.op_costs(keye, 262144, 1, **KEYE_CUT)
+    assert (state + 2 * arch.ACT_BYTES * sum(
+        o["out_elems"] for o in longest)) / 1e9 > 16 * 80
+    # the rest is env_trinity_32's
+    changed = {"jobs_config", "pad_obs_kwargs", "max_simulation_run_time"}
+    assert {k: v for k, v in cfg.items() if k not in changed} \
+        == {k: v for k, v in base.items() if k not in changed}
+    for key in set(jobs) - {"architecture", "job_interarrival_time_dist"}:
+        assert jobs[key] == base["jobs_config"][key], key
+
+
+def test_committed_keye_file_is_the_catalog_rows_config():
+    """The architecture file holds the row's `config` verbatim (the
+    benchmark's configuration file repeats it under `published`), the
+    family's training state and no modeling block."""
+    family = arch.load_arch_file(KEYE_FILE)
+    assert set(family) == {"name", "source_url", "what", "training_state",
+                           "config"}
+    assert family["training_state"] == STATE
+    assert "LEFT OUT" in family["what"]
+    config = family["config"]
+    assert (config["model_type"], config["hidden_size"],
+            config["num_hidden_layers"], config["num_attention_heads"],
+            config["num_key_value_heads"], config["head_dim"],
+            config["num_experts"], config["num_local_experts"],
+            config["num_experts_per_tok"], config["moe_intermediate_size"],
+            config["intermediate_size"], config["vocab_size"]) \
+        == ("KeyeVL2", 2048, 48, 32, 4, 128, 128, 128, 8, 768, 6144, 151936)
+    assert config["sa_config"] == {
+        "indexer_head_dim": 64, "indexer_num_heads": 16,
+        "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+        "q_chunk_size": 512, "topk": 2048}
+    assert (config["decoder_sparse_step"], config["mlp_only_layers"],
+            config["norm_topk_prob"], config["use_sliding_window"],
+            config["sliding_window"], config["max_window_layers"]) \
+        == (1, [], True, False, None, 48)
+    bench = json.load(open(os.path.join(
+        REPO, "benchmarks/configs/keye_vl2_30b_a3b_stage_ramp32.json")))
+    published = dict(bench["published"])
+    assert published.pop("train_batch_size") == 4000
+    assert published == config
+
+
+@pytest.mark.parametrize("family", ["tiny", "glm", "keye", "olmoe"])
+def test_generator_sets_the_indexer_gauges(tmp_path, family):
+    """`graphs.arch.{layers_indexed,index_time_share,
+    sparse_core_time_share,attended_keys_share,position_streams}.<model>`
+    and the bank's two new sums beside `graphs.arch.models`:
+    `layers_indexed` counts ops named `IndexScoreTopK`, so GLM-5's
+    graphs read it too."""
+    def of(gauges, what):
+        return {name[len(f"graphs.arch.{what}."):]: value
+                for name, value in gauges.items()
+                if name.startswith(f"graphs.arch.{what}.")}
+
+    if family == "tiny":
+        gauges = _arch_gauges(_tiny_keye_arch_file(tmp_path),
+                              [(32, 4096), (8, 2 ** 19)])
+        models = ("tinykeye_s32_b4096", "tinykeye_s8_b524288")
+        assert of(gauges, "layers_indexed") == dict.fromkeys(models, 2)
+        assert of(gauges, "position_streams") == dict.fromkeys(models, 3)
+        assert of(gauges, "layers_full") == dict.fromkeys(models, 0)
+        keys = of(gauges, "attended_keys_share")
+        assert keys == {models[0]: arch.attended_keys(32, 16)
+                        / arch.attended_keys(32, 32), models[1]: 1.0}
+        assert keys[models[0]] == (16 * 17 / 2 + 16 * 16) / (32 * 33 / 2)
+        index = of(gauges, "index_time_share")
+        assert all(0.01 < index[m] < 0.5 for m in models)
+        assert all(0.0 < v < 0.5
+                   for v in of(gauges, "sparse_core_time_share").values())
+        # QKVProj runs beside the indexer's two ops (or they beside it)
+        assert all(v > 0 for v in of(gauges, "branch_time_share").values())
+        assert [gauges[name] for name in BANK_GAUGES[4:]] == [
+            sum(index.values()), sum(keys.values())]
+        assert gauges[BANK_GAUGES[1]] == 2
+    elif family == "glm":
+        gauges = _arch_gauges(GLM_FILE, [(8192, 1), (65536, 1)], **GLM_CUT)
+        # 3 + 4 layers and the MTP module's
+        assert of(gauges, "layers_indexed") == {
+            "glm_moe_dsa_s8192_b1": 8, "glm_moe_dsa_s65536_b1": 8}
+        assert of(gauges, "position_streams") == {
+            "glm_moe_dsa_s8192_b1": 1, "glm_moe_dsa_s65536_b1": 1}
+        assert of(gauges, "attended_keys_share")[
+            "glm_moe_dsa_s65536_b1"] == pytest.approx(0.0615, abs=5e-5)
+        assert 0 < of(gauges, "index_time_share")[
+            "glm_moe_dsa_s8192_b1"] < of(gauges, "index_time_share")[
+            "glm_moe_dsa_s65536_b1"] < 1
+    elif family == "keye":
+        gauges = _arch_gauges(KEYE_FILE, KEYE_SHAPES, **KEYE_CUT)
+        models = ["KeyeVL2_s%d_b%d" % s for s in KEYE_SHAPES]
+        index, keys = (of(gauges, "index_time_share"),
+                       of(gauges, "attended_keys_share"))
+        assert [index[m] for m in models] == pytest.approx(
+            [0.0700, 0.1796, 0.2912, 0.4429], abs=5e-5)
+        assert [keys[m] for m in models] == pytest.approx(
+            [0.4375, 0.1211, 0.0615, 0.0310], abs=5e-5)
+        assert of(gauges, "layers_indexed") == dict.fromkeys(models, 24)
+        assert of(gauges, "position_streams") == dict.fromkeys(models, 3)
+        assert of(gauges, "forward_ops") == dict.fromkeys(models, 243)
+        assert of(gauges, "edges") == dict.fromkeys(models, 725)
+        # the bank's means, what the two new benchmark metrics read
+        assert gauges[BANK_GAUGES[4]] / gauges[BANK_GAUGES[1]] \
+            == pytest.approx(0.2459, abs=5e-5)
+        assert gauges[BANK_GAUGES[5]] / gauges[BANK_GAUGES[1]] \
+            == pytest.approx(0.1628, abs=5e-5)
+        # at long S the indexer's two ops ARE the longest path and
+        # QKVProj runs beside them
+        beside = of(gauges, "branch_time_share")
+        assert all(0.01 < beside[m] < 0.2 for m in models)
+    else:
+        gauges = _arch_gauges(OLMOE_FILE, [(4096, 1)])
+        assert of(gauges, "layers_indexed") == {"olmoe_s4096_b1": 0}
+        assert of(gauges, "index_time_share") == {"olmoe_s4096_b1": 0.0}
+        assert of(gauges, "attended_keys_share") == {"olmoe_s4096_b1": 1.0}
+        assert [gauges[name] for name in BANK_GAUGES[4:]] == [0.0, 1.0]
